@@ -237,19 +237,21 @@ class BinnedDataset:
         dropped, so this is the TPU-transfer layout, not a memory bomb)."""
         return self.X_bin.toarray() if self.is_sparse else self.X_bin
 
-    def dense_bins_T_device(self):
+    def dense_bins_T_device(self, sharding=None):
         """The feature-major [F, n] binned matrix ON DEVICE, cached on
         the dataset so every booster sharing this dataset — cv() folds,
         train_many() models — shares ONE device copy instead of
         uploading num_models duplicates (the forest-batching HBM
-        contract, docs/forest_batching.md)."""
-        cached = getattr(self, "_bins_T_device", None)
-        if cached is None:
-            import jax.numpy as jnp
+        contract, docs/forest_batching.md).  ``sharding`` (a mesh
+        learner's row layout) sends each shard from the host straight
+        to its device; None is the default device."""
+        cache = self.__dict__.setdefault("_bins_T_device", {})
+        if sharding not in cache:
+            import jax
 
-            cached = jnp.asarray(np.ascontiguousarray(self.dense_bins().T))
-            self._bins_T_device = cached
-        return cached
+            cache[sharding] = jax.device_put(
+                np.ascontiguousarray(self.dense_bins().T), sharding)
+        return cache[sharding]
 
     @property
     def num_data(self) -> int:

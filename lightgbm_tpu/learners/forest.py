@@ -77,21 +77,6 @@ from .serial import (
     TreeLearnerParams, _sr_row, grow_tree,
 )
 
-# jax 0.4.x ships no batching rule for optimization_barrier (the grow
-# loop's in-place-update fence, serial.py split_branch).  The barrier
-# is identity on every operand, so batched dims pass through unchanged
-# — vmap of the fence is the fence of the vmapped operands.  Without
-# this, vmapping grow_tree raises NotImplementedError.
-from jax._src.interpreters import batching as _batching
-from jax._src.lax import lax as _lax_internal
-
-_optbar_p = getattr(_lax_internal, "optimization_barrier_p", None)
-if _optbar_p is not None and _optbar_p not in _batching.primitive_batchers:
-    def _optbar_batcher(args, dims):
-        return _optbar_p.bind(*args), dims
-
-    _batching.primitive_batchers[_optbar_p] = _optbar_batcher
-
 # batch every per-tree operand; share the binned matrix and the
 # per-feature metadata across lanes.  TreeLearnerParams is batched
 # per-FIELD ([B] scalars) so train_many can give each model its own

@@ -62,6 +62,7 @@ _DIRECT_PLACE_ENV = _os.environ.get("LGBM_TPU_DIRECT_PLACE", "1") != "0"
 _TIER_SPACING_ENV = max(
     2, int(_os.environ.get("LGBM_TPU_TIER_SPACING", "2")))
 
+from ..device import on_tpu
 from ..models.tree import Tree
 from ..obs import telemetry
 from ..ops.histogram import histogram_by_leaf, histogram_feature_major
@@ -387,7 +388,7 @@ def grow_tree(
     # (~0.5 ms/split).  Only the default serial hook set qualifies;
     # parallel learners and the hybrid resume keep the canonical layout.
     _kern_env = _KERN_ENV
-    _interp = jax.default_backend() != "tpu"
+    _interp = not on_tpu()
     opt = (
         hist_fn_raw is not None
         and search_fn is None
@@ -431,7 +432,7 @@ def grow_tree(
     if search_fn is None:
         search_fn = default_search_fn
         if search2_fn is None:
-            use_kernel = jax.default_backend() == "tpu" and _kern_env
+            use_kernel = on_tpu() and _kern_env
 
             def search2_fn(hl, hr, lsg, lsh, lc, rsg, rsh, rc, can,
                            fmask, nbpf, is_cat, prm):

@@ -20,7 +20,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from .compat import enable_x64
+import jax
 
 # jaxlint: disable-file=f64-literal-in-traced — the eval_jax reductions
 # deliberately accumulate in f64 under the enable_x64 context installed
@@ -56,7 +56,7 @@ class Metric:
         increments drop below f32 spacing entirely)."""
         import jax
 
-        with enable_x64(True):
+        with jax.enable_x64(True):
             if self._jfn is None:
                 self._jfn = jax.jit(self.eval_jax)
             return self._jfn(scores)

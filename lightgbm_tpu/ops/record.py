@@ -472,6 +472,8 @@ def _hist_tile_body(tile, scal_i_ref, hacc_set, *, W, F, k, Bp,
     ``hacc_set(fi, contrib)`` accumulates [4, Bp] into feature row fi.
     scal_i layout: (.., .., .., .., f, thr, is_cat, pcnt) — indices 4-7.
     """
+    from .pallas_histogram import merge_stats, split_stats
+
     T = TILE
     shift = 32 // k
     mask_v = (1 << shift) - 1
@@ -483,8 +485,10 @@ def _hist_tile_body(tile, scal_i_ref, hacc_set, *, W, F, k, Bp,
     mrow = jax.lax.bitcast_convert_type(
         tile[Wb + 2: Wb + 3, :], jnp.float32)
     mw = mrow * govf  # bagging mask restricted to the left child
-    stats4 = jnp.concatenate(
-        [grow * mw, hrow * mw, mw, jnp.zeros_like(mw)], axis=0)
+    # exact three-piece bf16 split of the stat rows: one MXU pass at
+    # float32 accuracy (see pallas_histogram.split_stats)
+    stats = split_stats(jnp.concatenate(
+        [grow * mw, hrow * mw, mw, jnp.zeros_like(mw)], axis=0))
 
     iota_s = jax.lax.broadcasted_iota(jnp.int32, (Bp, T), 0)
     # caller-sized histogram block: the padded-feature fill below must
@@ -496,22 +500,22 @@ def _hist_tile_body(tile, scal_i_ref, hacc_set, *, W, F, k, Bp,
         w_idx, sh = fi // k, (fi % k) * shift
         row = jax.lax.shift_right_logical(
             tile[w_idx: w_idx + 1, :], sh) & mask_v
-        onehot = (row == iota_s).astype(jnp.float32)
-        contrib = jax.lax.dot_general(
-            stats4, onehot, (((1,), (1,)), ((), ())),
+        onehot = (row == iota_s).astype(jnp.bfloat16)
+        contrib = merge_stats(jax.lax.dot_general(
+            stats, onehot, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
+        ))
         hacc_set(fi, contrib)
     if Fp > F:
         # padded features: bin-0 totals, matching _prep_single_leaf's
         # zero-padded feature rows (subtract consistency with the
         # buffer's existing rows)
         zrow = jnp.zeros((1, T), jnp.int32)
-        onehot0 = (zrow == iota_s).astype(jnp.float32)
-        contrib0 = jax.lax.dot_general(
-            stats4, onehot0, (((1,), (1,)), ((), ())),
+        onehot0 = (zrow == iota_s).astype(jnp.bfloat16)
+        contrib0 = merge_stats(jax.lax.dot_general(
+            stats, onehot0, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
+        ))
         for fi in range(F, Fp):
             hacc_set(fi, contrib0)
 

@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
+from ..device import on_tpu
 from ..learners.depthwise import grow_tree_depthwise
 from ..learners.hybrid import HYBRID_STOP_FACTOR
 from ..learners.serial import grow_tree
@@ -195,7 +195,7 @@ def data_parallel_sharded(
         # env flip can't leave DP and serial searches in different modes.
         from ..learners.serial import _KERN_ENV
 
-        use_kernel_search = jax.default_backend() == "tpu" and _KERN_ENV
+        use_kernel_search = on_tpu() and _KERN_ENV
 
         def search2_fn(hl, hr, lsg, lsh, lc, rsg, rsh, rc, can,
                        _fm, _nb, _ic, prm):
@@ -283,7 +283,7 @@ def data_parallel_sharded(
             record_mode=record,
         )
 
-    return shard_map(
+    return jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(None, axis), P(axis), P(axis), P(axis), P(), P(), P(), P()),

@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..learners.serial import grow_tree
 from ..ops.histogram import histogram_feature_major
 from ..ops.split import SplitResult, find_best_split
@@ -78,7 +77,7 @@ def make_feature_parallel_grower(mesh, num_bins: int, max_leaves: int,
             record_mode=True,
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(), P(), P(), P()),

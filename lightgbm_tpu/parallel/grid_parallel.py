@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..compat import shard_map
 from ..learners.serial import grow_tree
 from ..ops.split import find_best_split
 from .split_comm import gather_and_combine
@@ -96,7 +95,7 @@ def make_grid_parallel_grower(mesh: Mesh, num_bins: int, max_leaves: int,
             record_mode=True,
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(None, ROW_AXIS), P(ROW_AXIS), P(ROW_AXIS), P(ROW_AXIS),

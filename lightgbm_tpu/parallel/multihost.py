@@ -65,12 +65,7 @@ def _already_distributed() -> bool:
     refuses to run ("must be called before any JAX calls") — probing via
     process_count would permanently break the machine_list_file bootstrap
     it is guarding."""
-    try:
-        from jax._src import distributed
-
-        return distributed.global_state.client is not None
-    except Exception:
-        return False
+    return jax.distributed.is_initialized()
 
 
 def initialize_from_config(cfg=None) -> bool:
@@ -178,14 +173,11 @@ def describe_topology() -> dict:
     # only query the live runtime when a backend already exists — the
     # probe must never initialize XLA as a side effect (that would
     # break the machine_list_file bootstrap _already_distributed guards)
-    backend_live = False
-    try:
-        from jax._src import xla_bridge
+    # private import: jax has no public "is a backend initialized yet?"
+    # (jax.extend.backend.backends() initializes one by asking)
+    from jax._src import xla_bridge
 
-        backend_live = bool(xla_bridge._backends)
-    except Exception:  # noqa: BLE001 — private API moved; stay on env
-        backend_live = _already_distributed()
-    if backend_live:
+    if xla_bridge.backends_are_initialized():
         try:
             topo["process_id"] = jax.process_index()
             topo["num_processes"] = jax.process_count()

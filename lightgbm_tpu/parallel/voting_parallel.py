@@ -24,7 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..compat import shard_map
 from ..learners.serial import grow_tree
 from ..ops.histogram import histogram_feature_major
 from ..ops.split import find_best_split
@@ -95,7 +94,7 @@ def make_voting_parallel_grower(
             record_mode=True,
         )
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         shard_body,
         mesh=mesh,
         in_specs=(P(None, axis), P(axis), P(axis), P(axis), P(), P(), P(), P()),

@@ -911,6 +911,10 @@ def train_fleet_from_config(cfg) -> int:
     os.makedirs(gang_dir, exist_ok=True)
     flightrec.configure_dir(gang_dir)
     slots = list(range(int(cfg.train_ranks)))
+    # one process for each chip: on a TPU host slot s trains on chip s
+    from ..device import chip_env, require_chips
+
+    chips = require_chips(len(slots), "train_fleet")
     gang_id = f"gang-{os.getpid()}"
     obs_dir = os.path.join(gang_dir, "obs")
     os.makedirs(obs_dir, exist_ok=True)
@@ -957,6 +961,8 @@ def train_fleet_from_config(cfg) -> int:
             "LGBM_TPU_RANK_OBS_DIR": obs_dir,
             "LGBM_TPU_FLIGHTREC_DIR": gang_dir,
         }
+        if chips:
+            env.update(chip_env(slot))
         if slot in fault_by_slot:
             env["LGBM_TPU_FAULT"] = fault_by_slot[slot]
         return SubprocessRank(slot, rank, argv, env, gang_dir,

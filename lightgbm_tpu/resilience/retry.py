@@ -3,7 +3,7 @@ that fails loudly instead of hanging.
 
 Two production failure shapes this covers:
 
-* **Transient errors** — a dropped TPU tunnel, a coordinator mid-restart,
+* **Transient errors** — a dropped connection, a coordinator mid-restart,
   a collective hitting a preempted peer.  These surface as exceptions
   whose messages carry the runtime's status vocabulary (``UNAVAILABLE``,
   ``DEADLINE_EXCEEDED``, ``connection reset`` …).  :func:`retry_transient`

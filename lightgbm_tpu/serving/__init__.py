@@ -5,7 +5,7 @@ into a *service*:
 
 * :mod:`engine`  — persistent on-device ensemble, padded-shape
   power-of-two bucketing, pre-warmed (recompile-free steady state by
-  construction), donated input buffers on TPU.
+  construction).
 * :mod:`queue`   — micro-batching request queue: concurrent ``submit``s
   coalesce into one bucketed dispatch under a max-latency / max-batch
   policy; results scatter back to futures.

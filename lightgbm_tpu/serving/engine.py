@@ -24,11 +24,6 @@ recompile on every new batch size — the exact failure mode the jaxlint
   serving path), so steady state is recompile-free *by construction*;
   the ``backend_compiles`` counter (analysis/recompile.py) pins it in
   tier-1 rather than as a bench claim.
-* **Donated input buffers** — on TPU the padded input buffer is donated
-  to the dispatch, so the transfer buffer is reused instead of held
-  alive across the program (donation is skipped on CPU, where XLA
-  cannot use it and warns).
-
 Output transform parity: the engine applies the SAME host-side f64
 sigmoid/softmax as ``GBDT.predict`` (shared ``transform_scores``), and
 the walk/matmul per-tree outputs are bitwise-identical (pinned by
@@ -65,25 +60,11 @@ def _raw_bucket_scores(tables, stacked, X):
 
 # one process-wide jitted dispatcher shared by every engine: the jit
 # cache then keys on (model tensor shapes, bucket) only — two engines
-# serving the same model shape share compiled programs.  Built lazily
-# so importing this module never initializes a jax backend (the
-# donation decision needs jax.default_backend()).
-_DISPATCH = None
-_DISPATCH_LOCK = lockcheck.make_lock("engine.dispatch_init")
-
-
-def _bucket_dispatch():
-    global _DISPATCH
-    if _DISPATCH is None:
-        with _DISPATCH_LOCK:
-            if _DISPATCH is None:
-                # donate the padded input buffer on TPU (serving's
-                # steady-state HBM win); CPU XLA ignores donation and
-                # warns, so skip it there
-                donate = (2,) if jax.default_backend() == "tpu" else ()
-                _DISPATCH = jax.jit(_raw_bucket_scores,
-                                    donate_argnums=donate)
-    return _DISPATCH
+# serving the same model shape share compiled programs.  The padded
+# input is NOT donated: [bucket, features] in can never alias
+# [K, bucket] out, and on the chip XLA says so once per bucket
+# ("donated buffers were not usable", PR 21).
+_bucket_dispatch = jax.jit(_raw_bucket_scores)
 
 
 def power_of_two_buckets(max_rows: int,
@@ -290,7 +271,7 @@ class ServingEngine:
             # chaos hook (oom_dispatch) + OOM post-mortem: same
             # classifier path a real RESOURCE_EXHAUSTED takes
             faults.maybe_oom_dispatch("serve")
-            out = _bucket_dispatch()(pm.tables, pm.stacked, Xj)
+            out = _bucket_dispatch(pm.tables, pm.stacked, Xj)
             lockcheck.note_host_sync("engine.dispatch_rows")
             res = np.asarray(out, np.float64)[:, :n]
         except Exception as e:
@@ -359,7 +340,7 @@ class ServingEngine:
         t0 = time.perf_counter()
         for b in self.buckets:
             Xz = jnp.asarray(np.zeros((b, pm.num_features), np.float32))
-            out = _bucket_dispatch()(pm.tables, pm.stacked, Xz)
+            out = _bucket_dispatch(pm.tables, pm.stacked, Xz)
             lockcheck.note_host_sync("engine.prewarm")
             out.block_until_ready()
             pm.warmed_buckets.add(b)

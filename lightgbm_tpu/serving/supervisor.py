@@ -123,10 +123,11 @@ class SubprocessReplica:
                 f"serve_host={self.host}", "serve_port=0",
                 f"serve_ready_file={self.ready_file}",
                 *self.extra_args]
+        # the environment is inherited unchanged (plus the slot's chip,
+        # see subprocess_factory): a replica runs where its parent would
         self._proc = subprocess.Popen(
             args, stdout=self._log_fh, stderr=subprocess.STDOUT,
-            env={**os.environ, "JAX_PLATFORMS":
-                 os.environ.get("JAX_PLATFORMS", "cpu"), **self.env})
+            env={**os.environ, **self.env})
         self.pid = self._proc.pid
         return self
 
@@ -671,7 +672,15 @@ class FleetFrontEnd:
 def subprocess_factory(cfg, workdir: str) -> Callable[[int], SubprocessReplica]:
     """Bind a Config's serving knobs into a SubprocessReplica factory:
     every replica serves the same model with the same admission/batch
-    policy, each on its own ephemeral port."""
+    policy, each on its own ephemeral port — and, on a TPU host, each on
+    its own chip: a chip belongs to one process, so a replica takes the
+    lowest chip whose last holder has exited (a restart gets its dead
+    predecessor's back)."""
+    from ..device import chip_env, require_chips
+
+    chips = require_chips(
+        max(cfg.serve_replicas, cfg.serve_max_replicas), "serve_fleet")
+    holder: Dict[int, SubprocessReplica] = {}
     extra = (f"serve_max_batch_rows={cfg.serve_max_batch_rows}",
              f"serve_max_delay_ms={cfg.serve_max_delay_ms}",
              f"serve_max_queue_rows={cfg.serve_max_queue_rows}",
@@ -680,8 +689,19 @@ def subprocess_factory(cfg, workdir: str) -> Callable[[int], SubprocessReplica]:
              f"verbose={cfg.verbose}")
 
     def factory(replica_id: int) -> SubprocessReplica:
-        return SubprocessReplica(cfg.input_model, replica_id, workdir,
-                                 host=cfg.serve_host, extra_args=extra)
+        env = {}
+        if chips:
+            chip = next(c for c in range(chips) if c not in holder
+                        or holder[c].exit_code() is not None)
+            env = chip_env(chip)
+        replica = SubprocessReplica(cfg.input_model, replica_id, workdir,
+                                    host=cfg.serve_host, extra_args=extra,
+                                    env=env)
+        if chips:
+            holder[chip] = replica
+            Log.info(f"fleet: replica {replica_id} on chip {chip} "
+                     f"of {chips}")
+        return replica
 
     return factory
 

@@ -1,6 +1,8 @@
 """The driver contract for bench.py: whatever happens, stdout's last
 line is ONE JSON object with metric/value/unit/vs_baseline keys (the
-round-1 failure mode was an unhandled backend crash printing nothing).
+round-1 failure mode was an unhandled backend crash printing nothing),
+and a run that failed — or found a platform nobody named — exits
+non-zero after printing it.
 
 Round-5 additions (VERDICT r5 item 4): every row self-describes its
 warm-up (iterations, discarded trees, compile counters), a RunManifest
@@ -12,8 +14,6 @@ import json
 import os
 import subprocess
 import sys
-
-import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -39,9 +39,10 @@ def test_bench_always_emits_json_line(tmp_path):
     assert out["value"] > 0, out
     assert out["platform"] == "cpu"
     # the headline must be the reference-parity growth mode on EVERY
-    # platform (VERDICT r2: a CPU-fallback bench may not advertise the
-    # approximate depthwise mode and its ~0.01 AUC gap as the result)
+    # platform (VERDICT r2: a bench may not advertise the approximate
+    # depthwise mode and its ~0.01 AUC gap as the result)
     assert out["growth"] == "leafwise"
+    assert out["stop_lag"] == 4  # the row says which stop check ran
     # self-description: warm-up + compile evidence inside the row
     for key in ("warmup_iters", "warm_trees_discarded", "compile_stable",
                 "compiles_warmup", "compiles_timed", "timed_trees"):
@@ -64,48 +65,34 @@ def test_bench_always_emits_json_line(tmp_path):
     assert isinstance(man.phases, dict)  # empty unless LGBM_TPU_TRACE
 
 
-def test_bench_r06_partition_phase_gate():
-    """CI contract for the prefix-routing rewrite (ISSUE 12): any newly
-    committed BENCH_r06.json must (a) pass tools/benchdiff.py against
-    BENCH_r05.json — no headline/phase/compile regression — and (b) not
-    regress the partition-phase share vs the committed one-hot baseline
-    (.bench/partition_phase_baseline.json).  Skips until a driver bench
-    commits BENCH_r06.json; from that moment the gate is armed — a
-    partition share at or above the one-hot era's ~87% means the
-    routing rewrite did not reach the chip."""
-    r06 = os.path.join(ROOT, "BENCH_r06.json")
-    if not os.path.exists(r06):
-        pytest.skip("no BENCH_r06.json committed yet (needs a TPU run)")
+def test_bench_refuses_a_platform_nobody_named(tmp_path):
+    """The bench measures the chip.  With no TPU and no BENCH_PLATFORM it
+    prints its row with the error and the platform it found, exits
+    non-zero, and trains nothing."""
+    env = dict(os.environ)
+    env.pop("BENCH_PLATFORM", None)
+    env.update(JAX_PLATFORMS="cpu", BENCH_MANIFEST_DIR=str(tmp_path))
     r = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "tools", "benchdiff.py"),
-         os.path.join(ROOT, "BENCH_r05.json"), r06],
-        capture_output=True, text=True, timeout=120, cwd=ROOT)
-    assert r.returncode == 0, (
-        f"benchdiff BENCH_r05 -> BENCH_r06 flagged:\n{r.stdout}\n{r.stderr}")
+        [sys.executable, os.path.join(ROOT, "bench.py")],
+        capture_output=True, text=True, timeout=120, env=env, cwd=ROOT,
+    )
+    assert r.returncode != 0, r.stdout[-400:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    assert out["platform"] == "none" and out["value"] == 0.0, out
+    assert "BENCH_PLATFORM" in out["error"], out
+    assert "binning" not in r.stderr, r.stderr[-400:]
 
-    with open(os.path.join(ROOT, ".bench",
-                           "partition_phase_baseline.json")) as fh:
-        base = json.load(fh)
-    with open(r06) as fh:
-        row = json.load(fh).get("parsed") or {}
-    phases = row.get("phases") or {}
-    part = float(phases.get("partition") or 0.0)
-    hist = float(phases.get("histogram") or 0.0)
-    if part <= 0 or hist <= 0:
-        pytest.skip("BENCH_r06 carries no partition+histogram phase "
-                    "attribution (capture one with LGBM_TPU_TRACE=<dir> "
-                    "bench.py)")
-    # SAME denominator as the baseline: partition / (partition +
-    # histogram) — the baseline's 0.87 was pinned from exactly those
-    # two phases, and a share over all phases would let an unchanged
-    # partition time sneak under the bar just because other phases
-    # exist in the new capture
-    share = part / (part + hist)
-    assert share < base["max_partition_share"], (
-        f"partition/(partition+histogram) share {share:.2f} has not "
-        f"improved on the one-hot baseline "
-        f"{base['partition_share']:.2f} — the routing rewrite "
-        f"regressed or never engaged", phases)
+
+def test_chip_smoke_refuses_without_a_chip():
+    """chip_smoke.py with no TPU: non-zero exit, the reason on stderr,
+    and no result line — a CPU rehearsal is an explicit argument."""
+    r = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, cwd=ROOT,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr, r.stderr[-400:]
+    assert r.stdout.strip() == "", r.stdout[-400:]
 
 
 def _inprocess_bench_run(bench):

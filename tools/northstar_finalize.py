@@ -28,8 +28,6 @@ sys.path.insert(0, REPO)
 
 import bench  # noqa: E402
 
-bench.apply_tuned_defaults()
-
 import numpy as np  # noqa: E402
 
 BENCH_DIR = os.path.join(REPO, ".bench")

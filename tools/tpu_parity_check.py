@@ -1,19 +1,10 @@
-"""On-chip kernel parity checks (ADVICE r3 item 2).
+"""On-chip kernel parity checks, alone (chip_smoke.py runs them too).
 
-The CPU test suite pins kernel parity in INTERPRET mode only; Mosaic
-compilation is a different code path (layout, MXU accumulation order,
-select legalization).  This tool runs the Pallas kernels on the REAL
-chip against their jnp reference implementations:
+Runs lightgbm_tpu.analysis.kernel_parity — the Mosaic-compiled Pallas
+kernels against their references, hardware-only branches included — and
+exits non-zero on any mismatch or when there is no TPU.
 
-  search    — search2_pallas_raw vs find_best_split_leaves: integer-
-              exact histograms (any summation order exact -> bitwise
-              comparable decisions) plus float histograms at tolerance
-  split     — split_step_window (mega kernel) vs partition_window +
-              histogram_single_leaf_raw + search2_update_pallas
-  writeback — write_window (aliased DMA) vs dynamic_update_slice
-
-Exits non-zero on any mismatch; prints one summary line per check.
-Run when a TPU window is live:  python tools/tpu_parity_check.py
+Run through the chip tool:  python tools/tpu_parity_check.py
 """
 
 from __future__ import annotations
@@ -21,297 +12,19 @@ from __future__ import annotations
 import os
 import sys
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
-
-import numpy as np  # noqa: E402
-
-
-def log(msg):
-    print(msg, flush=True)
-
-
-def check_search(rng) -> bool:
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.ops.pallas_search import search2_pallas_raw
-    from lightgbm_tpu.ops.split import find_best_split_leaves
-    from lightgbm_tpu.learners.serial import TreeLearnerParams
-    from lightgbm_tpu.config import Config
-
-    F, B = 12, 64
-    Fp, Bp = 16, 128
-    ok = True
-    for trial, integer in ((0, True), (1, True), (2, False)):
-        if integer:  # exact under ANY accumulation order
-            hg = rng.randint(-8, 9, (2, F, B)).astype(np.float32)
-            hh = rng.randint(1, 5, (2, F, B)).astype(np.float32)
-        else:
-            hg = rng.randn(2, F, B).astype(np.float32)
-            hh = (rng.rand(2, F, B) + 0.1).astype(np.float32)
-        hc = rng.randint(1, 50, (2, F, B)).astype(np.float32)
-        # tie case: duplicate the best feature's histogram onto a higher
-        # index — the smaller feature must win (split_info.hpp:98-103)
-        hg[:, 7] = hg[:, 3]
-        hh[:, 7] = hh[:, 3]
-        hc[:, 7] = hc[:, 3]
-        h2 = np.zeros((2, Fp, 4, Bp), np.float32)
-        h2[:, :F, 0, :B] = hg
-        h2[:, :F, 1, :B] = hh
-        h2[:, :F, 2, :B] = hc
-        sums = h2.sum(axis=3)  # [2, Fp, 4]
-        lsg, lsh, lc = sums[0, :F, 0].sum() / F, sums[0, :F, 1].sum() / F, \
-            sums[0, :F, 2].sum() / F
-        rsg, rsh, rc = sums[1, :F, 0].sum() / F, sums[1, :F, 1].sum() / F, \
-            sums[1, :F, 2].sum() / F
-        prm = TreeLearnerParams.from_config(
-            Config(min_data_in_leaf=5, min_sum_hessian_in_leaf=1e-3))
-        args = (jnp.float32(lsg), jnp.float32(lsh), jnp.float32(lc),
-                jnp.float32(rsg), jnp.float32(rsh), jnp.float32(rc))
-        fmask = jnp.ones(F, bool)
-        nbpf = jnp.full(F, B, jnp.int32)
-        iscat = jnp.zeros(F, bool)
-        rl, rr = search2_pallas_raw(
-            jnp.asarray(h2), *args, jnp.bool_(True), fmask, nbpf, iscat,
-            prm.min_data_in_leaf, prm.min_sum_hessian_in_leaf,
-            prm.lambda_l1, prm.lambda_l2, prm.min_gain_to_split,
-            interpret=False)
-        hist = jnp.asarray(
-            np.stack([np.stack([hg[c], hh[c], hc[c]], -1) for c in (0, 1)]))
-        ref = find_best_split_leaves(
-            hist, jnp.asarray([lsg, rsg]), jnp.asarray([lsh, rsh]),
-            jnp.asarray([lc, rc]), fmask, nbpf, iscat,
-            prm.min_data_in_leaf, prm.min_sum_hessian_in_leaf,
-            prm.lambda_l1, prm.lambda_l2, prm.min_gain_to_split,
-            jnp.asarray([True, True]))
-        for c, r in ((0, rl), (1, rr)):
-            f_k, t_k = int(r.feature), int(r.threshold)
-            f_j, t_j = int(ref.feature[c]), int(ref.threshold[c])
-            g_k, g_j = float(r.gain), float(ref.gain[c])
-            if integer:
-                same = (f_k == f_j and t_k == t_j)
-            else:  # float: decisions may differ only at near-ties
-                same = (f_k == f_j and t_k == t_j) or abs(
-                    g_k - g_j) <= 1e-4 * max(1.0, abs(g_j))
-            if not same:
-                log(f"  search MISMATCH trial {trial} child {c}: "
-                    f"kernel (f={f_k}, t={t_k}, g={g_k}) vs "
-                    f"jnp (f={f_j}, t={t_j}, g={g_j})")
-                ok = False
-    log(f"search parity: {'OK' if ok else 'FAIL'}")
-    return ok
-
-
-def check_split(rng) -> bool:
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.ops.pallas_histogram import histogram_single_leaf_raw
-    from lightgbm_tpu.ops.pallas_search import (
-        _pack_meta, _pack_scal, search2_update_pallas)
-    from lightgbm_tpu.ops.record import (
-        TILE, bins_per_word, build_record, extract_feature,
-        partition_window, round_up, split_step_window)
-
-    F, n, num_bins, L = 11, 5000, 37, 7
-    bins = rng.randint(0, num_bins, (F, n)).astype(np.uint8)
-    g = rng.randn(n).astype(np.float32)
-    h = (rng.rand(n) + 0.5).astype(np.float32)
-    bag = (rng.rand(n) > 0.2).astype(np.float32)
-    k = bins_per_word(jnp.uint8)
-    cap = round_up(n, TILE)
-    rec = build_record(jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h),
-                       jnp.asarray(bag), cap + TILE)
-    Fp, Bp = round_up(F, 8), round_up(num_bins, 128)
-    hists_np = np.zeros((L, Fp, 4, Bp), np.float32)
-    hists_np[0] = np.asarray(histogram_single_leaf_raw(
-        jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h),
-        jnp.asarray(bag), num_bins=num_bins))
-    f, thr = 4, 11
-    fv = extract_feature(rec, jnp.int32(f), jnp.int32(0), cap, k)
-    go = (fv <= thr).astype(jnp.int32)
-    meta = _pack_meta(jnp.ones(F, bool), jnp.full(F, num_bins, jnp.int32),
-                      jnp.zeros(F, bool), Fp)
-    scal_args = [jnp.float32(x) for x in
-                 (1.0, 1., 2., 300., -1., 2., 300.)]
-    lim_args = [jnp.float32(x) for x in (20., 1e-3, 0., 0., 0.)]
-    scal = _pack_scal(*(scal_args + lim_args))
-
-    recA, nlA = partition_window(
-        rec, go, jnp.int32(0), jnp.int32(n), jnp.bool_(True), cap)
-    govm = np.asarray(go).astype(bool) & (np.arange(cap) < n)
-    from lightgbm_tpu.ops.record import unpack_window
-    import jax
-    win = jax.lax.dynamic_slice(rec, (0, 0), (rec.shape[0], cap))
-    bw, gw, hw, mw = unpack_window(win, F, k, jnp.uint8)
-    h_left = histogram_single_leaf_raw(
-        bw, gw, hw, jnp.asarray(np.asarray(mw) * govm), num_bins=num_bins)
-    histsA, resLA, resRA = search2_update_pallas(
-        jnp.asarray(hists_np), h_left, jnp.int32(0), jnp.int32(1),
-        jnp.bool_(True), jnp.bool_(True), *scal_args[1:],
-        jnp.float32(1.0), jnp.ones(F, bool),
-        jnp.full(F, num_bins, jnp.int32), jnp.zeros(F, bool), *lim_args)
-
-    histsB, recB, nlB, res = split_step_window(
-        jnp.asarray(hists_np), rec, jnp.int32(0), jnp.int32(n),
-        jnp.bool_(True), jnp.int32(f), jnp.int32(thr), jnp.bool_(False),
-        jnp.int32(0), jnp.int32(1), scal, meta, F=F, cap=cap, k=k)
-
-    ok = True
-    if int(nlA) != int(nlB):
-        log(f"  split nleft mismatch: {int(nlA)} vs {int(nlB)}")
-        ok = False
-    # data rows must match exactly; the mega path additionally stamps
-    # the leaf-id row, which partition_window (leaf_row=None) left at 0
-    W = rec.shape[0]
-    from lightgbm_tpu.ops.record import num_words
-    lr = num_words(F, k) + 4
-    ra, rb = np.asarray(recA), np.asarray(recB)
-    rows = [r for r in range(W) if r != lr]
-    if not np.array_equal(ra[rows], rb[rows]):
-        log("  split record data rows mismatch")
-        ok = False
-    d = float(np.abs(np.asarray(histsA) - np.asarray(histsB)).max())
-    if d > 2e-2:  # different accumulation grouping on real floats
-        log(f"  split hists row diff {d}")
-        ok = False
-    from lightgbm_tpu.ops.pallas_search import _unpack
-    for c, (a, b) in enumerate(
-            ((resLA, _unpack(res, 0)), (resRA, _unpack(res, 1)))):
-        fa, fb = int(a.feature), int(b.feature)
-        if fa != fb:  # float accumulation may flip only exact ties
-            log(f"  split child {c} feature mismatch: {fa} vs {fb} "
-                f"(gains {float(a.gain):.6g} vs {float(b.gain):.6g})")
-            ok = ok and abs(float(a.gain) - float(b.gain)) <= 1e-4 * max(
-                1.0, abs(float(a.gain)))
-    log(f"split parity: {'OK' if ok else 'FAIL'} "
-        f"(nleft={int(nlB)}, hist maxdiff={d:.2e})")
-    return ok
-
-
-def check_writeback(rng) -> bool:
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.ops.record import TILE, write_window
-
-    rec = jnp.asarray(
-        rng.randint(-2**30, 2**30, (16, 8 * TILE)).astype(np.int32))
-    out = jnp.asarray(
-        rng.randint(-2**30, 2**30, (16, 2 * TILE)).astype(np.int32))
-    ok = True
-    for begin in (0, 1, 37, 500, TILE - 1):
-        got = np.asarray(write_window(rec, out, jnp.int32(begin), 2 * TILE))
-        ref = np.asarray(rec).copy()
-        ref[:, begin:begin + 2 * TILE] = np.asarray(out)
-        if not np.array_equal(got, ref):
-            bad = np.argwhere(got != ref)
-            log(f"  writeback MISMATCH at begin={begin}: "
-                f"{len(bad)} cells, first {bad[:3].tolist()}")
-            ok = False
-    log(f"writeback parity: {'OK' if ok else 'FAIL'}")
-    return ok
-
-
-def check_place(rng) -> bool:
-    """place_runs (aliased placement kernel) vs the XLA scan-of-DUS
-    reference it replaces — the hardware-only path (interpret falls
-    back to the reference)."""
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.ops.record import (
-        TILE, bins_per_word, build_record, extract_feature, num_words,
-        partition_window, place_runs, round_up, split_step_window)
-    from lightgbm_tpu.ops.pallas_search import _pack_meta, _pack_scal
-
-    # the last trial runs with a tiny LGBM_TPU_PLACE_CHUNK so the
-    # multi-launch chunk-boundary path (forced adv=1 per launch) is
-    # pinned at test size — its unique shape forces a fresh trace with
-    # the env value baked in (the knob is read at trace time)
-    ok = True
-    for trial, (F, n, num_bins, begin_off, frac) in enumerate((
-            (9, 5000, 33, 0, 0.5),
-            (9, 5000, 33, 777, 0.2),   # unaligned begin, unbalanced
-            (9, 5000, 33, 1291, 0.97),  # nearly-all-left
-            (5, 2000, 16, 300, 0.0),   # all-right
-            (7, 3000, 17, 133, 0.4),   # multi-chunk placement
-    )):
-        os.environ["LGBM_TPU_PLACE_CHUNK"] = "8" if trial == 4 else "16384"
-        bins = rng.randint(0, num_bins, (F, n)).astype(np.uint8)
-        g = rng.randn(n).astype(np.float32)
-        h = (rng.rand(n) + 0.5).astype(np.float32)
-        bag = np.ones(n, np.float32)
-        k = bins_per_word(jnp.uint8)
-        total = round_up(n + begin_off, TILE) + TILE
-        rec = build_record(
-            jnp.asarray(np.pad(bins, ((0, 0), (begin_off, 0)))),
-            jnp.asarray(np.pad(g, (begin_off, 0))),
-            jnp.asarray(np.pad(h, (begin_off, 0))),
-            jnp.asarray(np.pad(bag, (begin_off, 0))), total)
-        cap = round_up(n, TILE)
-        thr = int(num_bins * frac)
-        f = 2
-        begin = jnp.int32(begin_off)
-        fv = extract_feature(rec, jnp.int32(f), begin, cap, k)
-        go = (fv <= thr).astype(jnp.int32)
-        lr = num_words(F, k) + 4
-
-        # reference: partition_window (scan-of-DUS) with leaf stamping
-        recA, nlA = partition_window(
-            rec, go, begin, jnp.int32(n), jnp.bool_(True), cap,
-            left_leaf=jnp.int32(3), right_leaf=jnp.int32(5),
-            leaf_row=lr)
-        # kernel path: compacted tiles -> place_runs
-        Fp, Bp = round_up(F, 8), round_up(num_bins, 128)
-        # slots 3 and 5 are written by the kernel's hists index maps —
-        # allocate past them (Pallas does not bounds-check index maps)
-        hists = jnp.zeros((7, Fp, 4, Bp), jnp.float32)
-        meta = _pack_meta(jnp.ones(F, bool),
-                          jnp.full(F, num_bins, jnp.int32),
-                          jnp.zeros(F, bool), Fp)
-        scal = _pack_scal(*[jnp.float32(x) for x in
-                            (1., 0., 1., 9., 0., 1., 9., 1., 1e-3,
-                             0., 0., 0.)])
-        _, comp, nlB, _, clB, crB, _rp = split_step_window(
-            hists, rec, begin, jnp.int32(n), jnp.bool_(True),
-            jnp.int32(f), jnp.int32(thr), jnp.bool_(False),
-            jnp.int32(3), jnp.int32(5), scal, meta, F=F, cap=cap, k=k,
-            return_comp=True)
-        recB = place_runs(
-            jnp.array(rec), comp, go, begin, jnp.int32(n), nlB,
-            jnp.bool_(True), jnp.int32(3), jnp.int32(5), cap=cap,
-            leaf_row=lr)
-        # kernel-emitted counts must reproduce the go-derived ones
-        govm2 = np.asarray(go).astype(np.int64) * (np.arange(cap) < n)
-        want_cl = govm2.reshape(-1, TILE).sum(axis=1)
-        if not np.array_equal(np.asarray(clB), want_cl):
-            log(f"  place trial {trial}: kernel cl mismatch")
-            ok = False
-        if int(nlA) != int(nlB):
-            log(f"  place trial {trial}: nleft {int(nlA)} vs {int(nlB)}")
-            ok = False
-        ra, rb = np.asarray(recA), np.asarray(recB)
-        if not np.array_equal(ra, rb):
-            bad = [r for r in range(ra.shape[0])
-                   if not np.array_equal(ra[r], rb[r])]
-            log(f"  place trial {trial}: record rows differ {bad}")
-            ok = False
-    log(f"place parity: {'OK' if ok else 'FAIL'}")
-    return ok
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> None:
     import jax
 
+    from lightgbm_tpu.analysis import kernel_parity
+
     plat = jax.devices()[0].platform
-    log(f"platform: {plat}")
+    print(f"platform: {plat}", flush=True)
     if plat != "tpu":
-        log("NOT on TPU — this tool validates Mosaic compilation; "
-            "run it in a live-chip window")
-        sys.exit(2)
-    rng = np.random.RandomState(0)
-    results = [check_writeback(rng), check_search(rng), check_split(rng),
-               check_place(rng)]
-    os.environ.pop("LGBM_TPU_PLACE_CHUNK", None)
-    sys.exit(0 if all(results) else 1)
+        sys.exit("not on a TPU: these checks validate Mosaic compilation")
+    sys.exit(0 if all(kernel_parity.run_all().values()) else 1)
 
 
 if __name__ == "__main__":
