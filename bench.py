@@ -377,45 +377,30 @@ def ours_sec_per_tree(X, y, growth: str, Xv=None, yv=None,
     compiles_warmup = cc_phase.delta()
     cc_phase.reset()
 
-    # optional device-time attribution: LGBM_TPU_TRACE=<dir> captures a
-    # profiler trace of the timed loop and buckets it into the grow-loop
-    # phases (obs.device_time).  Off by default — the profiler is NOT
-    # near-zero-overhead, so it must never silently tax the headline.
-    import contextlib
-
-    from lightgbm_tpu.obs.device_time import trace_phases
-
-    trace_dir = os.environ.get("LGBM_TPU_TRACE", "")
-    tracer = trace_phases(trace_dir) if trace_dir else None
-
     done = 0
-    # the with-block guarantees stop_trace on ANY exit: a booster crash
-    # mid-loop must not leave the profiler taxing the rest of the
-    # process (and poisoning the next trace_phases with a double-start)
-    with (tracer if tracer is not None else contextlib.nullcontext()):
-        t0 = time.perf_counter()
-        with telemetry.span("bench.timed_loop"):
-            for i in range(TREES):
-                t_iter = time.perf_counter()
-                booster.train_one_iter()
-                # sync only every 5 trees (for the budget check): a
-                # per-tree block_until_ready stalls the dispatch
-                # pipeline each iteration (cost on this machine: not
-                # measured)
-                done += 1
-                if i % 5 == 4:
-                    telemetry.host_sync()
-                    _ = np.asarray(booster._scores[0, :1])
-                # per-tree reservoir (manifest p50/p99): dispatch wall
-                # for 4 of 5 trees, the 5th absorbs the sync — the p50
-                # tracks dispatch cost, the p99 the sync'd envelope
-                telemetry.record_value(reservoir,
-                                       time.perf_counter() - t_iter)
-                if i % 5 == 4 and time.perf_counter() - t0 > BUDGET_S:
-                    log(f"budget hit after {done} trees")
-                    break
-        _ = np.asarray(booster._scores)
-        elapsed = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    with telemetry.span("bench.timed_loop"):
+        for i in range(TREES):
+            t_iter = time.perf_counter()
+            booster.train_one_iter()
+            # sync only every 5 trees (for the budget check): a
+            # per-tree block_until_ready stalls the dispatch
+            # pipeline each iteration (cost on this machine: not
+            # measured)
+            done += 1
+            if i % 5 == 4:
+                telemetry.host_sync()
+                _ = np.asarray(booster._scores[0, :1])
+            # per-tree reservoir (manifest p50/p99): dispatch wall
+            # for 4 of 5 trees, the 5th absorbs the sync — the p50
+            # tracks dispatch cost, the p99 the sync'd envelope
+            telemetry.record_value(reservoir,
+                                   time.perf_counter() - t_iter)
+            if i % 5 == 4 and time.perf_counter() - t0 > BUDGET_S:
+                log(f"budget hit after {done} trees")
+                break
+    _ = np.asarray(booster._scores)
+    elapsed = time.perf_counter() - t0
     compiles_timed = cc_phase.delta()
     booster.finish_lagged_stop()
     auc = booster.eval_at(0).get("auc", float("nan"))
@@ -438,8 +423,6 @@ def ours_sec_per_tree(X, y, growth: str, Xv=None, yv=None,
         "compiles_timed": compiles_timed,
         "timed_trees": done,
     }
-    if tracer is not None and tracer.phases:
-        info["phases"] = tracer.phases
     return elapsed / done, auc, valid_auc, info
 
 
@@ -472,7 +455,6 @@ def _emit_result(out: dict, info: dict, key: str) -> None:
                     "learning_rate": LEARNING_RATE, "min_data": MIN_DATA,
                     "growth": out.get("growth")},
             result=out,
-            phases=info.get("phases"),
             warmup={k: info[k] for k in (
                 "warmup_iters", "warm_trees_discarded", "compile_stable",
                 "compiles_warmup", "compiles_timed") if k in info},
@@ -517,14 +499,6 @@ def main() -> None:
         out.update({k: info[k] for k in (
             "warmup_iters", "warm_trees_discarded", "compile_stable",
             "compiles_warmup", "compiles_timed", "timed_trees")})
-        # phase breakdown ships INSIDE the row too (when LGBM_TPU_TRACE
-        # captured one): benchdiff.normalize already reads row["phases"]
-        # from driver BENCH artifacts, and the partition-phase gate
-        # (tests/test_bench_contract.py) arms off the committed
-        # BENCH_r0N.json's parsed row — a manifest-only breakdown would
-        # leave both blind, since the driver captures only stdout's row
-        if info.get("phases"):
-            out["phases"] = info["phases"]
         out["stop_lag"] = int(os.environ["LGBM_TPU_STOP_LAG"])
         out["train_auc"] = round(float(auc), 4)
         if Xv is not None:

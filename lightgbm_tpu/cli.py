@@ -280,8 +280,9 @@ def _write_train_manifest(cfg: Config, booster: GBDT, train_s: float,
     """RunManifest next to the saved model (``<output_model>.manifest
     .json``): every CLI training run leaves the same self-describing
     evidence as a bench run.  When ``profile=true`` captured a trace,
-    the grow-loop phase breakdown is bucketed out of it; otherwise
-    phases stay empty (host timers cannot see inside the jitted loop).
+    ``phases`` is its device seconds by ``lgbm.*`` scope
+    (obs/device_time.py); otherwise it stays empty (host timers cannot
+    see inside the jitted loop).
     Best-effort: a manifest failure must not fail a finished training
     run.
 
@@ -295,9 +296,10 @@ def _write_train_manifest(cfg: Config, booster: GBDT, train_s: float,
     try:
         phases = {}
         if profile_dir:
-            from .obs.device_time import phase_breakdown_from_trace
+            from .obs import device_time
 
-            phases = phase_breakdown_from_trace(profile_dir)
+            xplane = device_time.newest_xplane(profile_dir)
+            phases = device_time.seconds_by_scope(xplane) if xplane else {}
         ranks: list = []
         extra: dict = {}
         from .obs import dist

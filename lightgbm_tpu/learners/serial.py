@@ -65,6 +65,7 @@ _TIER_SPACING_ENV = max(
 from ..device import on_tpu
 from ..models.tree import Tree
 from ..obs import telemetry
+from ..obs.device_time import phase_scope
 from ..ops.histogram import histogram_by_leaf, histogram_feature_major
 from ..ops.split import (
     SplitResult, find_best_split, find_best_split_leaves, K_MIN_SCORE)
@@ -538,8 +539,10 @@ def grow_tree(
         direct_place = fuse_hist and _DIRECT_PLACE_ENV
         if fuse_hist:
             # constant per tree: the search kernel's [Fp, 4] meta block
-            _mega_meta = _search_pack_meta(
-                feature_mask, num_bins_per_feature, is_categorical, _Fp)
+            with phase_scope("grow.root"):
+                _mega_meta = _search_pack_meta(
+                    feature_mask, num_bins_per_feature, is_categorical,
+                    _Fp)
     if child_counts_fn is None:
         _sum = (lambda x: x) if reduce_fn is None else reduce_fn
         _max = (lambda x: x) if reduce_max_fn is None else reduce_max_fn
@@ -570,169 +573,172 @@ def grow_tree(
                       params),
         )
 
-    if init_tree is None:
-        # ---- root (BeforeTrain / LeafSplits::Init, leaf_splits.hpp:51-92)
-        hist0 = hist_fn(bins_T, grad, hess, bag_mask)
-        # root Σg/Σh via a ONE-segment segment-sum, not jnp.sum: scatter
-        # accumulates per row in order, so a masked-out row adds an exact
-        # ±0.0 that never perturbs the accumulator.  jnp.sum's reduction
-        # tree regroups with n, making the root sums depend on how many
-        # DEAD rows ride along — which would break the base-row-mask
-        # parity contract (cv bin-once trains fold boosters on the full
-        # matrix and pins their metrics bitwise to subset-trained ones)
-        # and the batched forest grower's stacked-vs-loop parity pin.
-        # cnt0 stays jnp.sum: counts are exact small integers in any
-        # grouping.
-        gh0 = jax.ops.segment_sum(
-            jnp.stack([grad * bag_mask, hess * bag_mask], axis=-1),
-            jnp.zeros(grad.shape[0], jnp.int32),
-            num_segments=1,
-        )[0]
-        sum_g0, sum_h0 = gh0[0], gh0[1]
-        cnt0 = jnp.sum(bag_mask)
-        if reduce_fn is not None:
-            # one stacked collective for the tree-start allreduce
-            s = reduce_fn(jnp.stack([sum_g0, sum_h0, cnt0]))
-            sum_g0, sum_h0, cnt0 = s[0], s[1], s[2]
-        # hist0's feature extent may be a shard of F (feature-parallel
-        # learner); accumulation dtype follows grad/hess — float64 when
-        # Config.hist_dtype asks for the reference's double accumulation
-        # (include/LightGBM/bin.h:21-22)
-        acc_dt = hist0.dtype
-    else:
-        acc_dt = jnp.promote_types(grad.dtype, jnp.float32)
-    pooled = 0 < hist_pool < L
-    P = max(hist_pool, 2) if pooled else L
-    if init_tree is not None:
-        assert not pooled, "init_tree resume is unpooled"
-        K0 = init_tree.num_leaves.astype(jnp.int32)
-        lid = init_leaf_id.astype(jnp.int32)
-        # leaf-sorted permutation + contiguous per-leaf ranges from the
-        # row->leaf map (stable: preserves row order within a leaf);
-        # under row sharding these are LOCAL ranges, while the fused
-        # histogram/search below see GLOBAL stats through the hooks
-        order0 = jnp.argsort(lid, stable=True).astype(jnp.int32)
-        counts = jnp.zeros(L, jnp.int32).at[lid].add(1)
-        begin0 = jnp.concatenate(
-            [jnp.zeros(1, jnp.int32), jnp.cumsum(counts)[:-1].astype(jnp.int32)]
-        )
-        gate0 = counts if reduce_max_fn is None else reduce_max_fn(counts)
-        # every live leaf's histogram in ONE fused pass, through the same
-        # level-histogram kernel the depthwise phase used (the Pallas MXU
-        # sorted kernel on TPU; init_hist_fn has the depthwise hist_fn
-        # signature)
-        if init_hist_fn is None:
-            fused = histogram_by_leaf(
-                bins_T, lid, grad, hess, bag_mask,
-                num_bins=num_bins, num_leaves=L,
-            ).astype(acc_dt)
+    with phase_scope("grow.root"):
+        if init_tree is None:
+            # ---- root (BeforeTrain / LeafSplits::Init, leaf_splits.hpp:51-92)
+            hist0 = hist_fn(bins_T, grad, hess, bag_mask)
+            # root Σg/Σh via a ONE-segment segment-sum, not jnp.sum: scatter
+            # accumulates per row in order, so a masked-out row adds an exact
+            # ±0.0 that never perturbs the accumulator.  jnp.sum's reduction
+            # tree regroups with n, making the root sums depend on how many
+            # DEAD rows ride along — which would break the base-row-mask
+            # parity contract (cv bin-once trains fold boosters on the full
+            # matrix and pins their metrics bitwise to subset-trained ones)
+            # and the batched forest grower's stacked-vs-loop parity pin.
+            # cnt0 stays jnp.sum: counts are exact small integers in any
+            # grouping.
+            gh0 = jax.ops.segment_sum(
+                jnp.stack([grad * bag_mask, hess * bag_mask], axis=-1),
+                jnp.zeros(grad.shape[0], jnp.int32),
+                num_segments=1,
+            )[0]
+            sum_g0, sum_h0 = gh0[0], gh0[1]
+            cnt0 = jnp.sum(bag_mask)
+            if reduce_fn is not None:
+                # one stacked collective for the tree-start allreduce
+                s = reduce_fn(jnp.stack([sum_g0, sum_h0, cnt0]))
+                sum_g0, sum_h0, cnt0 = s[0], s[1], s[2]
+            # hist0's feature extent may be a shard of F (feature-parallel
+            # learner); accumulation dtype follows grad/hess — float64 when
+            # Config.hist_dtype asks for the reference's double accumulation
+            # (include/LightGBM/bin.h:21-22)
+            acc_dt = hist0.dtype
         else:
-            fused = init_hist_fn(
-                bins_T, lid, grad, hess, bag_mask, L
-            ).astype(acc_dt)
-        leaf_tot = jnp.sum(fused[:, 0, :, :], axis=1)  # [L, 3]
-        live = jnp.arange(L, dtype=jnp.int32) < K0
-        can0 = live & (
-            (params.max_depth <= 0)
-            | (init_tree.leaf_depth < params.max_depth)
-        )
-        if init_search_fn is not None:
-            # sharded-search learners search their feature shard of the
-            # fused histogram and combine winners in one collective
-            best0 = init_search_fn(
-                fused, leaf_tot[:, 0], leaf_tot[:, 1], leaf_tot[:, 2],
-                can0, feature_mask, num_bins_per_feature, is_categorical,
-                params,
+            acc_dt = jnp.promote_types(grad.dtype, jnp.float32)
+        pooled = 0 < hist_pool < L
+        P = max(hist_pool, 2) if pooled else L
+        if init_tree is not None:
+            assert not pooled, "init_tree resume is unpooled"
+            K0 = init_tree.num_leaves.astype(jnp.int32)
+            lid = init_leaf_id.astype(jnp.int32)
+            # leaf-sorted permutation + contiguous per-leaf ranges from the
+            # row->leaf map (stable: preserves row order within a leaf);
+            # under row sharding these are LOCAL ranges, while the fused
+            # histogram/search below see GLOBAL stats through the hooks
+            order0 = jnp.argsort(lid, stable=True).astype(jnp.int32)
+            counts = jnp.zeros(L, jnp.int32).at[lid].add(1)
+            begin0 = jnp.concatenate(
+                [jnp.zeros(1, jnp.int32),
+                 jnp.cumsum(counts)[:-1].astype(jnp.int32)]
             )
+            gate0 = counts if reduce_max_fn is None else reduce_max_fn(counts)
+            # every live leaf's histogram in ONE fused pass, through the same
+            # level-histogram kernel the depthwise phase used (the Pallas MXU
+            # sorted kernel on TPU; init_hist_fn has the depthwise hist_fn
+            # signature)
+            if init_hist_fn is None:
+                fused = histogram_by_leaf(
+                    bins_T, lid, grad, hess, bag_mask,
+                    num_bins=num_bins, num_leaves=L,
+                ).astype(acc_dt)
+            else:
+                fused = init_hist_fn(
+                    bins_T, lid, grad, hess, bag_mask, L
+                ).astype(acc_dt)
+            leaf_tot = jnp.sum(fused[:, 0, :, :], axis=1)  # [L, 3]
+            live = jnp.arange(L, dtype=jnp.int32) < K0
+            can0 = live & (
+                (params.max_depth <= 0)
+                | (init_tree.leaf_depth < params.max_depth)
+            )
+            if init_search_fn is not None:
+                # sharded-search learners search their feature shard of the
+                # fused histogram and combine winners in one collective
+                best0 = init_search_fn(
+                    fused, leaf_tot[:, 0], leaf_tot[:, 1], leaf_tot[:, 2],
+                    can0, feature_mask, num_bins_per_feature, is_categorical,
+                    params,
+                )
+            else:
+                best0 = find_best_split_leaves(
+                    fused, leaf_tot[:, 0], leaf_tot[:, 1], leaf_tot[:, 2],
+                    feature_mask, num_bins_per_feature, is_categorical,
+                    params.min_data_in_leaf, params.min_sum_hessian_in_leaf,
+                    params.lambda_l1, params.lambda_l2,
+                    params.min_gain_to_split, can0,
+                )
+            _pad1 = lambda a: jnp.concatenate(  # noqa: E731
+                [a, jnp.zeros(1, a.dtype)])
+            state = _GrowState(
+                order=jnp.concatenate(
+                    [order0, jnp.full(order_pad, n, jnp.int32)]
+                ),
+                pos_mat=jnp.stack([begin0, counts, gate0]),
+                hists=fused,
+                slot_of=jnp.zeros(0, jnp.int32),
+                slot_leaf=jnp.zeros(0, jnp.int32),
+                slot_last=jnp.zeros(0, jnp.int32),
+                best_mat=jnp.concatenate([
+                    _sr_row(best0, acc_dt),
+                    init_tree.leaf_value[None].astype(acc_dt),
+                    init_tree.leaf_count[None].astype(acc_dt),
+                    init_tree.leaf_parent[None].astype(acc_dt),
+                    init_tree.leaf_depth[None].astype(acc_dt),
+                    jnp.zeros((_BROWS - 15, L), acc_dt),
+                ]),
+                tree_i=jnp.stack([
+                    _pad1(init_tree.split_feature),
+                    _pad1(init_tree.threshold_bin),
+                    _pad1(init_tree.decision_type),
+                    _pad1(init_tree.left_child),
+                    _pad1(init_tree.right_child),
+                ]),
+                tree_f=jnp.stack([
+                    _pad1(init_tree.split_gain),
+                    _pad1(init_tree.internal_value),
+                    _pad1(init_tree.internal_count),
+                ]),
+                nleaves=K0,
+            )
+            start_step = K0 - 1
         else:
-            best0 = find_best_split_leaves(
-                fused, leaf_tot[:, 0], leaf_tot[:, 1], leaf_tot[:, 2],
-                feature_mask, num_bins_per_feature, is_categorical,
-                params.min_data_in_leaf, params.min_sum_hessian_in_leaf,
-                params.lambda_l1, params.lambda_l2, params.min_gain_to_split,
-                can0,
+            root_best = best_for(
+                # raw-layout root histogram -> canonical view for the
+                # (once-per-tree) jnp root search
+                hist0[:F, :3, :num_bins].transpose(0, 2, 1) if opt else hist0,
+                sum_g0, sum_h0, cnt0, jnp.int32(0),
             )
-        _pad1 = lambda a: jnp.concatenate(  # noqa: E731
-            [a, jnp.zeros(1, a.dtype)])
-        state = _GrowState(
-            order=jnp.concatenate(
-                [order0, jnp.full(order_pad, n, jnp.int32)]
-            ),
-            pos_mat=jnp.stack([begin0, counts, gate0]),
-            hists=fused,
-            slot_of=jnp.zeros(0, jnp.int32),
-            slot_leaf=jnp.zeros(0, jnp.int32),
-            slot_last=jnp.zeros(0, jnp.int32),
-            best_mat=jnp.concatenate([
-                _sr_row(best0, acc_dt),
-                init_tree.leaf_value[None].astype(acc_dt),
-                init_tree.leaf_count[None].astype(acc_dt),
-                init_tree.leaf_parent[None].astype(acc_dt),
-                init_tree.leaf_depth[None].astype(acc_dt),
-                jnp.zeros((_BROWS - 15, L), acc_dt),
-            ]),
-            tree_i=jnp.stack([
-                _pad1(init_tree.split_feature),
-                _pad1(init_tree.threshold_bin),
-                _pad1(init_tree.decision_type),
-                _pad1(init_tree.left_child),
-                _pad1(init_tree.right_child),
-            ]),
-            tree_f=jnp.stack([
-                _pad1(init_tree.split_gain),
-                _pad1(init_tree.internal_value),
-                _pad1(init_tree.internal_count),
-            ]),
-            nleaves=K0,
-        )
-        start_step = K0 - 1
-    else:
-        root_best = best_for(
-            # raw-layout root histogram -> canonical view for the
-            # (once-per-tree) jnp root search
-            hist0[:F, :3, :num_bins].transpose(0, 2, 1) if opt else hist0,
-            sum_g0, sum_h0, cnt0, jnp.int32(0),
-        )
-        best_mat0 = (
-            jnp.zeros((_BROWS, L), acc_dt)
-            .at[_BG].set(K_MIN_SCORE)
-            .at[_BF].set(-1.0)
-            .at[_BLPAR].set(-1.0)  # empty_tree's leaf_parent = -1
-        )
-        best_mat0 = jax.lax.dynamic_update_slice(
-            best_mat0, _sr_row(root_best, acc_dt)[:, None], (0, 0))
-        state = _GrowState(
-            # record mode: the "order" leaf carries the [W, n_pad]
-            # packed record; otherwise the flat row permutation
-            order=build_record(
-                bins_T, grad, hess, bag_mask,
-                _round_up(n, _REC_TILE) + order_pad,
+            best_mat0 = (
+                jnp.zeros((_BROWS, L), acc_dt)
+                .at[_BG].set(K_MIN_SCORE)
+                .at[_BF].set(-1.0)
+                .at[_BLPAR].set(-1.0)  # empty_tree's leaf_parent = -1
             )
-            if rec
-            else jnp.concatenate(
-                [
-                    jnp.arange(n, dtype=jnp.int32),
-                    jnp.full(order_pad, n, jnp.int32),
-                ]
-            ),
-            # root gate: every shard's padded local row count is the
-            # same n (rows: leaf_begin, pos_cnt, gate_cnt)
-            pos_mat=jnp.zeros((3, L), jnp.int32)
-            .at[1, 0].set(n).at[2, 0].set(n),
-            hists=jnp.zeros((P,) + hist0.shape, acc_dt).at[0].set(hist0),
-            slot_of=(jnp.full(L, -1, jnp.int32).at[0].set(0) if pooled
-                     else jnp.zeros(0, jnp.int32)),
-            slot_leaf=(jnp.full(P, -1, jnp.int32).at[0].set(0) if pooled
-                       else jnp.zeros(0, jnp.int32)),
-            slot_last=(jnp.full(P, -1, jnp.int32).at[0].set(0) if pooled
-                       else jnp.zeros(0, jnp.int32)),
-            best_mat=best_mat0,
-            tree_i=jnp.zeros((5, L), jnp.int32).at[0].set(-1),
-            tree_f=jnp.zeros((3, L), jnp.float32),
-            nleaves=jnp.int32(1),
-        )
-        start_step = 0
+            best_mat0 = jax.lax.dynamic_update_slice(
+                best_mat0, _sr_row(root_best, acc_dt)[:, None], (0, 0))
+            state = _GrowState(
+                # record mode: the "order" leaf carries the [W, n_pad]
+                # packed record; otherwise the flat row permutation
+                order=build_record(
+                    bins_T, grad, hess, bag_mask,
+                    _round_up(n, _REC_TILE) + order_pad,
+                )
+                if rec
+                else jnp.concatenate(
+                    [
+                        jnp.arange(n, dtype=jnp.int32),
+                        jnp.full(order_pad, n, jnp.int32),
+                    ]
+                ),
+                # root gate: every shard's padded local row count is the
+                # same n (rows: leaf_begin, pos_cnt, gate_cnt)
+                pos_mat=jnp.zeros((3, L), jnp.int32)
+                .at[1, 0].set(n).at[2, 0].set(n),
+                hists=jnp.zeros((P,) + hist0.shape, acc_dt).at[0].set(hist0),
+                slot_of=(jnp.full(L, -1, jnp.int32).at[0].set(0) if pooled
+                         else jnp.zeros(0, jnp.int32)),
+                slot_leaf=(jnp.full(P, -1, jnp.int32).at[0].set(0) if pooled
+                           else jnp.zeros(0, jnp.int32)),
+                slot_last=(jnp.full(P, -1, jnp.int32).at[0].set(0) if pooled
+                           else jnp.zeros(0, jnp.int32)),
+                best_mat=best_mat0,
+                tree_i=jnp.zeros((5, L), jnp.int32).at[0].set(-1),
+                tree_f=jnp.zeros((3, L), jnp.float32),
+                nleaves=jnp.int32(1),
+            )
+            start_step = 0
 
+    @phase_scope("grow.book")
     def split_branch(state, step, best_leaf, do_split):
         """One split step with MASKED writes: when ``do_split`` is false
         every store preserves the old value, so the state round-trips
@@ -747,30 +753,32 @@ def grow_tree(
         # ---- ALL per-leaf scalar reads come from four column slices
         # (parent + prospective-new-leaf columns of the two packed
         # matrices) instead of ~40 individual [L]-array gathers.
-        z0 = jnp.int32(0)
-        bcol = jax.lax.dynamic_slice(
-            state.best_mat, (z0, best_leaf), (_BROWS, 1))[:, 0]
-        bcolN = jax.lax.dynamic_slice(
-            state.best_mat, (z0, new_leaf), (_BROWS, 1))[:, 0]
-        pcol = jax.lax.dynamic_slice(
-            state.pos_mat, (z0, best_leaf), (3, 1))[:, 0]
-        pcolN = jax.lax.dynamic_slice(
-            state.pos_mat, (z0, new_leaf), (3, 1))[:, 0]
+        with phase_scope("grow.select"):
+            z0 = jnp.int32(0)
+            bcol = jax.lax.dynamic_slice(
+                state.best_mat, (z0, best_leaf), (_BROWS, 1))[:, 0]
+            bcolN = jax.lax.dynamic_slice(
+                state.best_mat, (z0, new_leaf), (_BROWS, 1))[:, 0]
+            pcol = jax.lax.dynamic_slice(
+                state.pos_mat, (z0, best_leaf), (3, 1))[:, 0]
+            pcolN = jax.lax.dynamic_slice(
+                state.pos_mat, (z0, new_leaf), (3, 1))[:, 0]
 
-        f = bcol[_BF].astype(jnp.int32)
-        thr = bcol[_BT].astype(jnp.int32)
-        is_cat = is_categorical[jnp.maximum(f, 0)]
-        lsg, lsh, lc = bcol[_BLSG], bcol[_BLSH], bcol[_BLC]
-        rsg, rsh, rc = bcol[_BRSG], bcol[_BRSH], bcol[_BRC]
-        depth_child = bcol[_BLDEP].astype(jnp.int32) + 1
+            f = bcol[_BF].astype(jnp.int32)
+            thr = bcol[_BT].astype(jnp.int32)
+            is_cat = is_categorical[jnp.maximum(f, 0)]
+            lsg, lsh, lc = bcol[_BLSG], bcol[_BLSH], bcol[_BLC]
+            rsg, rsh, rc = bcol[_BRSG], bcol[_BRSH], bcol[_BRC]
+            depth_child = bcol[_BLDEP].astype(jnp.int32) + 1
 
-        # ---- partition the parent's range in place (DataPartition::Split).
-        # The tier gate (cross-shard max of the parent's positional count)
-        # was stored at the split that CREATED this leaf — no collective
-        # here.
-        begin = pcol[0]
-        pcnt = pcol[1]
-        gate = pcol[2]
+            # ---- partition the parent's range in place
+            # (DataPartition::Split).
+            # The tier gate (cross-shard max of the parent's positional count)
+            # was stored at the split that CREATED this leaf — no collective
+            # here.
+            begin = pcol[0]
+            pcnt = pcol[1]
+            gate = pcol[2]
         mega_res = None
         if opt_fused and fuse_hist:
             # MEGA split step: compaction + left-child histogram + both
@@ -778,15 +786,16 @@ def grow_tree(
             # round-4 profile showed the loop bound by per-split
             # dispatch, not op work).  depth gate + per-split scalars
             # for the in-kernel search:
-            can_k = (params.max_depth <= 0) | (
-                depth_child < params.max_depth)
-            scal_f = _search_pack_scal(
-                can_k.astype(jnp.float32),
-                lsg, lsh, lc, rsg, rsh, rc,
-                params.min_data_in_leaf, params.min_sum_hessian_in_leaf,
-                params.lambda_l1, params.lambda_l2,
-                params.min_gain_to_split,
-            )
+            with phase_scope("grow.select"):
+                can_k = (params.max_depth <= 0) | (
+                    depth_child < params.max_depth)
+                scal_f = _search_pack_scal(
+                    can_k.astype(jnp.float32),
+                    lsg, lsh, lc, rsg, rsh, rc,
+                    params.min_data_in_leaf, params.min_sum_hessian_in_leaf,
+                    params.lambda_l1, params.lambda_l2,
+                    params.min_gain_to_split,
+                )
 
             def _mega_rec(cap):
                 # the decision AND the tile counts live in the kernel
@@ -812,9 +821,10 @@ def grow_tree(
                 )
                 return mh, rec2, nl, res
 
-            mega_hists, order, nleft, mega_res = _tier_chain(
-                p_tiers, gate, _mega_rec
-            )
+            with phase_scope("grow.tier.split"):
+                mega_hists, order, nleft, mega_res = _tier_chain(
+                    p_tiers, gate, _mega_rec
+                )
         elif rec:
 
             def _part_rec(cap):
@@ -827,16 +837,18 @@ def grow_tree(
                     interpret=_interp,
                 )
 
-            order, nleft = _tier_chain(p_tiers, gate, _part_rec)
+            with phase_scope("grow.tier.part"):
+                order, nleft = _tier_chain(p_tiers, gate, _part_rec)
         else:
-            order, nleft = _tier_chain(
-                p_tiers,
-                gate,
-                lambda cap: _partition_branch(
-                    state.order, bins_T, f, thr, is_cat, begin, pcnt,
-                    do_split, cap
-                ),
-            )
+            with phase_scope("grow.tier.part"):
+                order, nleft = _tier_chain(
+                    p_tiers,
+                    gate,
+                    lambda cap: _partition_branch(
+                        state.order, bins_T, f, thr, is_cat, begin, pcnt,
+                        do_split, cap
+                    ),
+                )
         nright = pcnt - nleft
 
         # ---- smaller-child histogram from its contiguous range; sibling
@@ -887,16 +899,18 @@ def grow_tree(
                 ).astype(m_w.dtype)
                 return hist_fn(bins_w, g_w, h_w, m_w)
 
-            h_small = _tier_chain(h_tiers, cnt_s_gate, _hist_rec)
+            with phase_scope("grow.tier.hist"):
+                h_small = _tier_chain(h_tiers, cnt_s_gate, _hist_rec)
         else:
-            h_small = _tier_chain(
-                h_tiers,
-                cnt_s_gate,
-                lambda cap: _child_hist_branch(
-                    hist_fn, order, bins_T, grad, hess, bag_mask,
-                    begin_s, cnt_s, cap,
-                ),
-            )
+            with phase_scope("grow.tier.hist"):
+                h_small = _tier_chain(
+                    h_tiers,
+                    cnt_s_gate,
+                    lambda cap: _child_hist_branch(
+                        hist_fn, order, bins_T, grad, hess, bag_mask,
+                        begin_s, cnt_s, cap,
+                    ),
+                )
         if pooled:
             # ---- HistogramPool residency (feature_histogram.hpp:337-481):
             # the parent's histogram may have been LRU-evicted since the
@@ -908,19 +922,20 @@ def grow_tree(
             # deterministic), so collectives inside the cond are safe.
             ps = state.slot_of[best_leaf]
             resident = ps >= 0
-            h_parent = jax.lax.cond(
-                resident,
-                lambda _: state.hists[jnp.maximum(ps, 0)],
-                lambda _: _tier_chain(
-                    h_tiers,
-                    gate,
-                    lambda cap: _child_hist_branch(
-                        hist_fn, order, bins_T, grad, hess, bag_mask,
-                        begin, pcnt, cap,
-                    ),
-                ).astype(acc_dt),
-                None,
-            )
+            with phase_scope("grow.tier.hist"):
+                h_parent = jax.lax.cond(
+                    resident,
+                    lambda _: state.hists[jnp.maximum(ps, 0)],
+                    lambda _: _tier_chain(
+                        h_tiers,
+                        gate,
+                        lambda cap: _child_hist_branch(
+                            hist_fn, order, bins_T, grad, hess, bag_mask,
+                            begin, pcnt, cap,
+                        ),
+                    ).astype(acc_dt),
+                    None,
+                )
             # LRU slot choice: overwrite the parent's slot for the left
             # child when resident; otherwise the least-recently-used slot
             # (free slots carry last-use -1 and win argmin).  The right
@@ -1109,63 +1124,67 @@ def grow_tree(
         )
 
     def body(step, state):
-        gain_row = state.best_mat[_BG]
-        best_leaf = jnp.argmax(gain_row).astype(jnp.int32)
-        do_split = gain_row[best_leaf] > 0.0
+        with phase_scope("grow.select"):
+            gain_row = state.best_mat[_BG]
+            best_leaf = jnp.argmax(gain_row).astype(jnp.int32)
+            do_split = gain_row[best_leaf] > 0.0
         return split_branch(state, jnp.int32(step), best_leaf, do_split)
 
-    state = jax.lax.fori_loop(start_step, L - 1, body, state)
+    with phase_scope("grow.loop"):
+        state = jax.lax.fori_loop(start_step, L - 1, body, state)
 
     # ---- unpack the Tree pytree from the packed node/leaf tables (one
     # set of static row slices per TREE, replacing the ~30 per-SPLIT
     # masked stores of the unpacked representation)
-    li = L - 1
-    tree = Tree(
-        num_leaves=state.nleaves,
-        split_feature=state.tree_i[0, :li],
-        split_feature_real=(
-            init_tree.split_feature_real if init_tree is not None
-            else jnp.full(li, -1, jnp.int32)),
-        threshold_bin=state.tree_i[1, :li],
-        threshold_real=(
-            init_tree.threshold_real if init_tree is not None
-            else jnp.zeros(li, jnp.float32)),
-        decision_type=state.tree_i[2, :li],
-        left_child=state.tree_i[3, :li],
-        right_child=state.tree_i[4, :li],
-        split_gain=state.tree_f[0, :li],
-        internal_value=state.tree_f[1, :li],
-        internal_count=state.tree_f[2, :li],
-        leaf_value=state.best_mat[_BLV].astype(jnp.float32),
-        leaf_count=state.best_mat[_BLCNT].astype(jnp.float32),
-        leaf_parent=state.best_mat[_BLPAR].astype(jnp.int32),
-        leaf_depth=state.best_mat[_BLDEP].astype(jnp.int32),
-    )
+    with phase_scope("grow.unpack"):
+        li = L - 1
+        tree = Tree(
+            num_leaves=state.nleaves,
+            split_feature=state.tree_i[0, :li],
+            split_feature_real=(
+                init_tree.split_feature_real if init_tree is not None
+                else jnp.full(li, -1, jnp.int32)),
+            threshold_bin=state.tree_i[1, :li],
+            threshold_real=(
+                init_tree.threshold_real if init_tree is not None
+                else jnp.zeros(li, jnp.float32)),
+            decision_type=state.tree_i[2, :li],
+            left_child=state.tree_i[3, :li],
+            right_child=state.tree_i[4, :li],
+            split_gain=state.tree_f[0, :li],
+            internal_value=state.tree_f[1, :li],
+            internal_count=state.tree_f[2, :li],
+            leaf_value=state.best_mat[_BLV].astype(jnp.float32),
+            leaf_count=state.best_mat[_BLCNT].astype(jnp.float32),
+            leaf_parent=state.best_mat[_BLPAR].astype(jnp.int32),
+            leaf_depth=state.best_mat[_BLDEP].astype(jnp.int32),
+        )
 
-    # ---- per-row leaf assignment from the final ranges: leaves own
-    # disjoint contiguous [begin, begin+count) spans of ``order``, so the
-    # leaf of a position is a searchsorted over the (few) sorted begins,
-    # then one unique-index scatter maps positions back to rows.
-    if rec:
-        # record mode: the partition stamped every position's leaf id
-        # into the record's leaf-id row — one contiguous read replaces
-        # the searchsorted over leaf ranges (~75 ms/tree of
-        # binary-search gathers in the round-4 profile)
-        leaf_of_pos = state.order[_leaf_row, :n]
-        rows = jnp.minimum(state.order[_row_id_row, :n], n - 1)
-    else:
-        idxL = jnp.arange(L, dtype=jnp.int32)
-        valid_leaf = (idxL < tree.num_leaves) & (state.pos_mat[1] > 0)
-        key = jnp.where(
-            valid_leaf, state.pos_mat[0], jnp.int32(n + order_pad))
-        perm = jnp.argsort(key).astype(jnp.int32)
-        sb = key[perm]
-        leaf_of_pos = perm[
-            jnp.searchsorted(
-                sb, jnp.arange(n, dtype=jnp.int32), side="right") - 1
-        ]
-        rows = jnp.minimum(state.order[:n], n - 1)
-    leaf_id = (
-        jnp.zeros(n, jnp.int32).at[rows].set(leaf_of_pos, unique_indices=True)
-    )
+        # ---- per-row leaf assignment from the final ranges: leaves own
+        # disjoint contiguous [begin, begin+count) spans of ``order``, so the
+        # leaf of a position is a searchsorted over the (few) sorted begins,
+        # then one unique-index scatter maps positions back to rows.
+        if rec:
+            # record mode: the partition stamped every position's leaf id
+            # into the record's leaf-id row — one contiguous read replaces
+            # the searchsorted over leaf ranges (~75 ms/tree of
+            # binary-search gathers in the round-4 profile)
+            leaf_of_pos = state.order[_leaf_row, :n]
+            rows = jnp.minimum(state.order[_row_id_row, :n], n - 1)
+        else:
+            idxL = jnp.arange(L, dtype=jnp.int32)
+            valid_leaf = (idxL < tree.num_leaves) & (state.pos_mat[1] > 0)
+            key = jnp.where(
+                valid_leaf, state.pos_mat[0], jnp.int32(n + order_pad))
+            perm = jnp.argsort(key).astype(jnp.int32)
+            sb = key[perm]
+            leaf_of_pos = perm[
+                jnp.searchsorted(
+                    sb, jnp.arange(n, dtype=jnp.int32), side="right") - 1
+            ]
+            rows = jnp.minimum(state.order[:n], n - 1)
+        leaf_id = (
+            jnp.zeros(n, jnp.int32).at[rows].set(
+                leaf_of_pos, unique_indices=True)
+        )
     return tree, leaf_id

@@ -659,7 +659,11 @@ class GBDT:
         host can feed the device, not device time (the distinction the
         jaxlint ``wallclock-without-sync`` rule exists to protect).
         Synced per-tree times come from the bench harness's own timed
-        loop; device phase attribution from obs.device_time traces."""
+        loop; device time by scope from obs.device_time.  The four host
+        phases of an iteration are spans (``lgbm.host.gradients``,
+        ``.grow``, ``.stop_check``, ``.post_grow``): with a profiler
+        session open they stand on the trace's host plane, where
+        obs.device_time puts the device's idle gaps down to them."""
         t0 = time.perf_counter()
         try:
             # chaos hook (LGBM_TPU_FAULT=oom_dispatch): fake
@@ -777,14 +781,17 @@ class GBDT:
         ):
             old = self._pending_stop.pop(0)
             telemetry.host_sync()  # lagged, so ~free — but still a sync
-            if int(old) <= 1:
+            with telemetry.span("lgbm.host.stop_check"):
+                terminal = int(old) <= 1
+            if terminal:
                 for _ in range(len(self._pending_stop)):
                     self.rollback_one_iter()
                 self._pending_stop.clear()
                 return "stop"
         if grad is None or hess is None:
             scores = self._scores if K > 1 else self._scores[0]
-            grad, hess = self.objective.get_gradients(scores)
+            with telemetry.span("lgbm.host.gradients"):
+                grad, hess = self.objective.get_gradients(scores)
             if K == 1:
                 grad, hess = grad[None, :], hess[None, :]
         else:
@@ -824,7 +831,8 @@ class GBDT:
         valid-score updates, model append.  Returns could_split."""
         K = self.num_class
         if self._stop_lag <= 0 or K != 1:
-            could_split = int(tree.num_leaves) > 1
+            with telemetry.span("lgbm.host.stop_check"):
+                could_split = int(tree.num_leaves) > 1
         else:
             # lagged stop check (LGBM_TPU_STOP_LAG): int(num_leaves)
             # every iteration blocks the host on the WHOLE tree
@@ -849,15 +857,16 @@ class GBDT:
         # shrink + score apply + threshold finalization as ONE
         # dispatch (each eager jnp op is its own launch; the host-side
         # finalize_thresholds even forced a full device sync per tree)
-        tree, self._scores = _post_grow_step(
-            tree, self._scores, jnp.int32(k),
-            leaf_id, jnp.float32(self.learning_rate),
-            self._bounds_mat, self._real_feat_dev,
-        )
-        for vi in range(len(self.valid_sets)):
-            self._valid_scores[vi] = self._valid_scores[vi].at[k].add(
-                predict_binned(tree, self._valid_bins[vi])
+        with telemetry.span("lgbm.host.post_grow"):
+            tree, self._scores = _post_grow_step(
+                tree, self._scores, jnp.int32(k),
+                leaf_id, jnp.float32(self.learning_rate),
+                self._bounds_mat, self._real_feat_dev,
             )
+            for vi in range(len(self.valid_sets)):
+                self._valid_scores[vi] = self._valid_scores[vi].at[k].add(
+                    predict_binned(tree, self._valid_bins[vi])
+                )
         self.models.append(tree)
         return could_split
 
@@ -897,11 +906,12 @@ class GBDT:
 
             params_lanes = jax.tree.map(
                 lambda x: jnp.broadcast_to(x, (K,)), self._learner_params)
-            trees_b, lids = self._grow_forest_batched(
-                grad, hess,
-                jnp.broadcast_to(self._bag_mask, (K, self.num_data)),
-                jnp.stack(fmasks), params_lanes,
-            )
+            with telemetry.span("lgbm.host.grow"):
+                trees_b, lids = self._grow_forest_batched(
+                    grad, hess,
+                    jnp.broadcast_to(self._bag_mask, (K, self.num_data)),
+                    jnp.stack(fmasks), params_lanes,
+                )
             grown = [(forest.unstack_tree(trees_b, k), lids[k])
                      for k in range(K)]
             return self._forest_finish_iter(grown, nf_snap)
@@ -909,30 +919,26 @@ class GBDT:
         could_split_any = False
         for k in range(K):
             fmask = fmasks[k]
-            if self._use_f64_hist:
-                with jax.enable_x64(True):
-                    gk = grad[k].astype(jnp.float64)
-                    hk = hess[k].astype(jnp.float64)
+            with telemetry.span("lgbm.host.grow"):
+                if self._use_f64_hist:
+                    with jax.enable_x64(True):
+                        gk = grad[k].astype(jnp.float64)
+                        hk = hess[k].astype(jnp.float64)
+                        tree, leaf_id = self._grow(
+                            self._bins_T, gk, hk, self._bag_mask, fmask,
+                            self._nbpf, self._is_cat, self._learner_params,
+                        )
+                        tree = jax.tree.map(
+                            lambda a: a.astype(jnp.float32)
+                            if a.dtype == jnp.float64 else a,
+                            tree,
+                        )
+                else:
                     tree, leaf_id = self._grow(
-                        self._bins_T, gk, hk, self._bag_mask, fmask,
-                        self._nbpf, self._is_cat, self._learner_params,
+                        self._bins_T, grad[k], hess[k], self._bag_mask,
+                        fmask, self._nbpf, self._is_cat,
+                        self._learner_params,
                     )
-                    tree = jax.tree.map(
-                        lambda a: a.astype(jnp.float32)
-                        if a.dtype == jnp.float64 else a,
-                        tree,
-                    )
-            else:
-                tree, leaf_id = self._grow(
-                    self._bins_T,
-                    grad[k],
-                    hess[k],
-                    self._bag_mask,
-                    fmask,
-                    self._nbpf,
-                    self._is_cat,
-                    self._learner_params,
-                )
             if self._forest_finish_tree(k, tree, leaf_id):
                 could_split_any = True
         self.iter_ += 1
@@ -953,7 +959,9 @@ class GBDT:
         while self._pending_stop:
             old = self._pending_stop.pop(0)
             telemetry.host_sync()
-            if int(old) <= 1:
+            with telemetry.span("lgbm.host.stop_check"):
+                terminal = int(old) <= 1
+            if terminal:
                 for _ in range(len(self._pending_stop)):
                     self.rollback_one_iter()
                 self._pending_stop.clear()

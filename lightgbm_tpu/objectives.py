@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .obs.device_time import phase_scope
+
 
 class ObjectiveFunction:
     """Base: mirrors ObjectiveFunction (objective_function.h:13-49)."""
@@ -50,6 +52,7 @@ class RegressionL2(ObjectiveFunction):
 
 
 @jax.jit
+@phase_scope("gradients")
 def _l2_grads(score, label, weights):
     g = score - label
     h = jnp.ones_like(score)
@@ -101,6 +104,7 @@ class BinaryLogloss(ObjectiveFunction):
 
 
 @jax.jit
+@phase_scope("gradients")
 def _binary_grads(score, label, weights, sigmoid, w_neg, w_pos):
     is_pos = label > 0
     sign = jnp.where(is_pos, 1.0, -1.0)
@@ -130,6 +134,7 @@ class MulticlassSoftmax(ObjectiveFunction):
 
 
 @jax.jit
+@phase_scope("gradients")
 def _multiclass_grads(scores, label, weights):
     # scores [K, n]
     p = jax.nn.softmax(scores, axis=0)

@@ -31,6 +31,7 @@ import numpy as np
 
 from .dcg import label_gains_from_config, max_dcg_at_k, position_discounts
 from .objectives import ObjectiveFunction
+from .obs.device_time import phase_scope
 
 
 class LambdarankNDCG(ObjectiveFunction):
@@ -103,6 +104,7 @@ class LambdarankNDCG(ObjectiveFunction):
 
 
 @functools.partial(jax.jit, static_argnames=("num_data", "chunk"))
+@phase_scope("gradients")
 def _lambdarank_grads(
     scores,
     pad_idx,

@@ -6,10 +6,11 @@ The runtime half of ROADMAP item 1's "make perf un-regressable"
 
 * :mod:`~lightgbm_tpu.obs.telemetry` — always-on spans / counters /
   per-tree reservoirs (near-zero overhead; no jax import).
-* :mod:`~lightgbm_tpu.obs.device_time` — ``phase_scope`` annotations on
-  the hot ops + profiler-trace bucketing into histogram / split-search
-  / partition / leaf-update (imports jax; loaded lazily so tools that
-  only read manifests don't pay for it).
+* :mod:`~lightgbm_tpu.obs.device_time` — ``phase_scope`` writes the
+  ``lgbm.*`` names onto the grower, its kernels and the boosting driver;
+  ``read`` / ``attribute`` read them back from a profiler trace
+  (``.xplane.pb``): every device op by scope and cause, the copy ledger,
+  idle gaps by host span.  ``python -m lightgbm_tpu.obs.device_time``.
 * :mod:`~lightgbm_tpu.obs.manifest` — ``RunManifest`` written next to
   every bench result artifact; diffed by ``tools/benchdiff.py``.
 * :mod:`~lightgbm_tpu.obs.tracing` — per-request ``TraceContext``
@@ -70,14 +71,12 @@ from .telemetry import (  # noqa: F401
 )
 from .tracing import TraceContext  # noqa: F401
 
-_LAZY = ("phase_scope", "host_annotation", "bucket_events",
-         "classify_event", "phase_breakdown_from_trace",
-         "load_trace_events", "trace_phases", "PHASES", "SCOPE_TO_PHASE")
+_LAZY = ("phase_scope", "SCOPES")
 
 
 def __getattr__(name):
-    # device_time imports jax; bridge it lazily so manifest/telemetry
-    # consumers (benchdiff, lint tooling) stay jax-free
+    # loaded on first use, like every reader of a trace: manifest and
+    # telemetry consumers (benchdiff, lint tooling) never need it
     if name in _LAZY or name == "device_time":
         from . import device_time
 

@@ -1,34 +1,44 @@
-"""Phase-attributed device time for the grow loop.
+"""Device time by program scope and cause, read from a profiler trace.
 
-Host timers cannot see inside the jitted ``fori_loop`` — by the time
-``train_one_iter`` returns, the chip may not even have started, and
-every split of every leaf runs inside one compiled program.  Attribution
-therefore comes from two cooperating halves:
+One system in two halves.  :func:`phase_scope` *writes* names: the
+grower, its kernels and the boosting driver wrap what they trace in
+``jax.named_scope("lgbm.<scope>")``, which costs a name-stack push at
+trace time and nothing at run time, and lands in every XLA op's
+``op_name``.  :func:`read` and :func:`attribute` *read them back* from
+the ``.xplane.pb`` that ``jax.profiler`` writes (jax 0.9.0 writes no
+chrome trace): the device planes' "XLA Ops" / "XLA Modules" events,
+the per-instruction event metadata (``tf_op`` = JAX's ``op_name`` path,
+``source``, ``bytes_accessed``, ``program_id`` ...), the host plane's
+``TraceMe`` events, and the optimized ``HloProto`` of every program that
+ran (plane ``/host:metadata``).  ``jax.profiler.ProfileData`` exposes an
+event's own statistics only, and the names live in the event *metadata*,
+so the file is read as protobuf wire format here: seven ``XSpace``
+messages and four of ``HloProto``, no TensorFlow, xprof or protobuf
+import.
 
-1. **Scope annotations at trace time** (:func:`phase_scope`): the hot
-   ops (``ops/record.py``, ``ops/pallas_histogram.py``,
-   ``ops/histogram.py``, ``ops/split.py``, ``ops/predict_matmul.py``,
-   the post-grow update in ``models/gbdt.py``) wrap their lowered
-   computations in ``jax.named_scope`` so every XLA op's metadata
-   carries an ``lgbm.<phase>`` path that survives fusion into the
-   profiler trace's event names/args.  ``jax.named_scope`` costs a name
-   stack push at *trace* time and literally nothing at run time, so the
-   always-on telemetry constraint holds.
-2. **Trace bucketing at read time** (:func:`bucket_events`,
-   :func:`phase_breakdown_from_trace`): parse a ``jax.profiler`` trace
-   (chrome-trace JSON, the format ``jax.profiler.trace`` writes under
-   ``<dir>/plugins/profile/<run>/*.trace.json.gz``) and bucket complete
-   events into the four grow-loop phases — histogram / split-search /
-   partition / leaf-update — plus predict, falling back to kernel-name
-   patterns for ops that lost their scope path in fusion naming
-   (promotes the ad-hoc breakdown logic of ``tools/tpu_breakdown.py``
-   into the library).
+Every leaf op gets a *scope* (the innermost ``lgbm.*`` component of its
+``op_name``; failing that, of the ``while`` / ``conditional`` / ``call``
+that encloses it, walking the HLO upward; failing that, of its consumer;
+failing that ``unattributed``) and a *cause*: ``program`` for an op the
+program wrote, else ``from > into`` read off the HLO, for the copies and
+bitcast fusions XLA inserts.  *from*: ``kernel`` (a Mosaic call's
+output), ``cond`` / ``loop`` (a get-tuple-element of a conditional / a
+while), ``carry`` (the while body's parameter), ``arg`` (an entry
+parameter), ``op``.  *into*: ``branch_result`` (root tuple of a
+conditional's branch), ``carry`` (root tuple of the while body, or the
+while's operand), ``result`` (the entry's root), ``kernel`` (only Mosaic
+calls use it), ``cond_arg``, ``op``.  `` layout`` marks a copy whose
+dimensions are its operand's and whose layout or memory space is not.
 
-Capture is opt-in (``with trace_phases(dir) as result: ...`` or the
-``LGBM_TPU_TRACE=<dir>`` env consumed by bench.py): running the
-profiler is NOT near-zero-overhead, so the always-on layer records only
-scopes and counters, and a trace is taken when someone asks where the
-device time went.
+    python -m lightgbm_tpu.obs.device_time <file.xplane.pb>
+        [--program jit_grow_tree] [--window <host span>] [--top N] [--json]
+
+prints, per program, time by scope x cause; the copy ledger (every op
+the program did not write that takes 1% of its program or more, with
+bytes, shape, producer, consumer and the chain of enclosing control
+flow); and the device's idle gaps by the innermost ``lgbm.*`` host span
+(``telemetry.span`` opens a ``TraceAnnotation`` beside its timer).
+``benchmarks/run.py --keep-trace`` writes the file this takes.
 """
 
 from __future__ import annotations
@@ -38,205 +48,813 @@ import gzip
 import json
 import os
 import re
-from typing import Dict, Iterable, List, Optional
+import struct
+import sys
+from typing import NamedTuple
 
-import jax
-
-# The four grow-loop phases (plus predict for the inference path and
-# the unattributed remainder).  Keys are the manifest schema.
-PHASES = ("histogram", "split-search", "partition", "leaf-update",
-          "predict")
-
-# named_scope path -> phase.  The split-step mega kernel fuses child
-# histogram accumulation INTO the partition pass (ops/record.py); its
-# device time is bucketed as partition because the row-routing work,
-# not the binning math, dominated it (the round-5 one-hot profile —
-# ~85% of device FLOPs; the prefix-sum routing default exists to close
-# exactly that gap, and keeping the bucket stable lets benchdiff
-# compare partition share across the routing change).
-SCOPE_TO_PHASE: Dict[str, str] = {
-    "lgbm.histogram": "histogram",
-    "lgbm.split_search": "split-search",
-    "lgbm.partition": "partition",
-    "lgbm.split_step": "partition",
-    "lgbm.leaf_update": "leaf-update",
-    "lgbm.predict": "predict",
-}
-
-# kernel-name fallbacks, first match wins — for events whose fusion
-# name kept the op stem but lost the scope path
-_KERNEL_PATTERNS = (
-    (re.compile(r"hist", re.I), "histogram"),
-    (re.compile(r"split_step|place|compact|partition|route|write_window"
-                r"|compress_half|lane_cumsum", re.I), "partition"),
-    (re.compile(r"best_split|search|gain", re.I), "split-search"),
-    (re.compile(r"post_grow|leaf_value|shrink", re.I), "leaf-update"),
-    (re.compile(r"predict|ensemble|path_table|tree_hit", re.I), "predict"),
+# The one table of scope names: (scope, what it covers).  A component
+# ``lgbm.split_step.cap4096`` belongs to the scope ``lgbm.split_step``
+# and carries the tail ``cap4096`` (a Mosaic call's tier capacity: the
+# instruction takes its name from the innermost scope, so the tail is
+# what makes ``%lgbm.split_step.cap4096.N`` say which tier it is).
+SCOPES = (
+    ("lgbm.histogram", "histogram kernels (on the fused path: the root's) "
+     "and the XLA ops that pad and split their operands"),
+    ("lgbm.split_search", "jax.numpy split search (ops/split.py)"),
+    ("lgbm.split_step", "fused split step: routing, compaction, child "
+     "histogram and both searches in one Mosaic call (ops/record.py)"),
+    ("lgbm.partition", "build_record, compaction, place_runs, "
+     "write_window: Mosaic calls and the XLA ops around them"),
+    ("lgbm.leaf_update", "_post_grow_step: shrinkage, score update, "
+     "thresholds (models/gbdt.py)"),
+    ("lgbm.gradients", "the objective's jitted gradient programs"),
+    ("lgbm.predict", "matmul prediction (ops/predict_matmul.py)"),
+    ("lgbm.grow.root", "grow_tree before the loop: root totals, root "
+     "histogram, first search, initial state"),
+    ("lgbm.grow.loop", "the fori_loop itself: its carry and whatever of "
+     "the body no inner scope names"),
+    ("lgbm.grow.select", "body's argmax and the column reads and scalar "
+     "packing of split_branch"),
+    ("lgbm.grow.tier", "a _tier_chain call: the cond nest and what XLA "
+     "puts at its boundaries (tails: split, part, hist)"),
+    ("lgbm.grow.book", "split_branch after the kernels: best_mat, "
+     "pos_mat, tree_i, tree_f column updates, pool bookkeeping"),
+    ("lgbm.grow.unpack", "grow_tree after the loop: Tree unpack, leaf_id"),
 )
+SCOPE_NAMES = tuple(s for s, _ in SCOPES)
+UNATTRIBUTED = "unattributed"
+
+_OPS_LINE, _MODULES_LINE = "XLA Ops", "XLA Modules"
+_DEVICE_PREFIX, _HOST_PLANE, _HLO_PLANE = (
+    "/device:TPU:", "/host:CPU", "/host:metadata")
+_MOSAIC = "tpu_custom_call"
 
 
 def phase_scope(phase: str):
-    """Trace-time scope for a grow-loop phase: ops wrap their traced
-    bodies in ``with phase_scope("histogram"): ...`` (or use it as a
-    decorator under the ``jax.jit`` one) so XLA op metadata — and thus
-    profiler event names — carries ``lgbm.<phase>``.  Zero run-time
-    cost: it only pushes the tracing name stack.  Dashes normalize to
-    underscores so scope names match :data:`SCOPE_TO_PHASE` keys."""
+    """Trace-time scope ``lgbm.<phase>`` (dashes become underscores), as
+    a context manager or a decorator under ``jax.jit``.  An ambient
+    scope does not reach into a jitted callee: put it inside the jit."""
+    import jax
+
     return jax.named_scope("lgbm." + phase.replace("-", "_"))
 
 
-def host_annotation(name: str):
-    """Host-side profiler annotation (``jax.profiler.TraceAnnotation``)
-    for eager regions — shows up as a TraceMe on the host track.  Used
-    around host phases (binning, eval) when a trace is being captured;
-    unlike :func:`phase_scope` it has a (tiny) run-time cost, so call
-    sites keep it out of per-split paths."""
-    return jax.profiler.TraceAnnotation(name)
+# ----------------------------------------------------------- wire format
+
+def _varint(buf, i):
+    r = s = 0
+    while True:
+        b = buf[i]
+        i += 1
+        r |= (b & 0x7F) << s
+        if b < 0x80:
+            return r, i
+        s += 7
 
 
-def classify_event(name: str, long_name: str = "") -> Optional[str]:
-    """Phase for one trace event, or None when unattributable."""
-    hay = f"{name} {long_name}"
-    for scope, phase in SCOPE_TO_PHASE.items():
-        if scope in hay:
-            return phase
-    for pat, phase in _KERNEL_PATTERNS:
-        if pat.search(hay):
-            return phase
+def _fields(buf, i, end):
+    """``(field, wire_type, value)`` of one message: an int for a varint,
+    ``(start, end)`` for a length-delimited field, raw bytes for fixed."""
+    while i < end:
+        key, i = _varint(buf, i)
+        wt = key & 7
+        if wt == 0:
+            v, i = _varint(buf, i)
+        elif wt == 2:
+            n, i = _varint(buf, i)
+            v = (i, i + n)
+            i += n
+        elif wt in (1, 5):
+            n = 8 if wt == 1 else 4
+            v = buf[i:i + n]
+            i += n
+        else:
+            raise ValueError(f"wire type {wt} at byte {i}: not a protobuf")
+        yield key >> 3, wt, v
+
+
+def _text(buf, span) -> str:
+    return buf[span[0]:span[1]].decode("utf-8", "replace")
+
+
+def _ints(buf, wt, v) -> list:
+    """A repeated int64 field, packed or not."""
+    if wt == 0:
+        return [v]
+    out, i = [], v[0]
+    while i < v[1]:
+        x, i = _varint(buf, i)
+        out.append(x)
+    return out
+
+
+def _map_entry(buf, span):
+    key = val = None
+    for f, _, v in _fields(buf, *span):
+        if f == 1:
+            key = v
+        elif f == 2:
+            val = v
+    return key, val
+
+
+def _stat(buf, span, stat_names: dict):
+    """One ``XStat`` as ``(name, value)``; a ``ref_value`` is the string
+    that ``stat_metadata`` holds once under that id."""
+    name = value = None
+    for f, wt, v in _fields(buf, *span):
+        if f == 1:
+            name = stat_names.get(v, str(v))
+        elif f == 2:
+            value = struct.unpack("<d", v)[0]
+        elif f in (3, 4):
+            value = v - (1 << 64) if f == 4 and v >> 63 else v
+        elif f == 5:
+            value = _text(buf, v)
+        elif f == 6:
+            value = buf[v[0]:v[1]]
+        elif f == 7:
+            value = stat_names.get(v, "")
+    return name, value
+
+
+# ---------------------------------------------------------------- XSpace
+
+class Op(NamedTuple):
+    """What the event metadata holds of one HLO instruction."""
+
+    name: str  # "%copy.618"
+    opcode: str
+    operands: tuple  # operand names, from the instruction's text
+    shape: str  # result shape with layout
+    operand_shape: str  # first operand's, "" if the text has none
+    tf_op: str  # JAX's op_name path, "" if none
+    source: str  # file:line, "" if none
+    category: str
+    bytes: int
+    program_id: int
+
+
+_INSTR = re.compile(r"^(%\S+) = (.*?) ([\w\-]+)\(")
+_NAME = re.compile(r"%[\w.\-]+")
+
+
+def _parse_op(text: str, stats: dict) -> Op:
+    m = _INSTR.match(text)
+    if not m:  # a module's or a step's metadata: no instruction text
+        return Op(text, "", (), "", "", "", "", "", 0,
+                  int(stats.get("program_id", 0)))
+    depth, j = 1, m.end()
+    while j < len(text) and depth:
+        depth += {"(": 1, ")": -1}.get(text[j], 0)
+        j += 1
+    inner = text[m.end():j - 1]
+    first = _NAME.search(inner)
+    return Op(
+        m.group(1), m.group(3), tuple(_NAME.findall(inner)), m.group(2),
+        inner[:first.start()].strip() if first else "",
+        str(stats.get("tf_op", "")), str(stats.get("source", "")),
+        str(stats.get("hlo_category", "")),
+        int(stats.get("bytes_accessed", 0)),
+        int(stats.get("program_id", 0)))
+
+
+def _plane(buf, span) -> dict:
+    out = {"name": "", "lines": [], "meta": {}, "stat_names": {}}
+    for f, _, v in _fields(buf, *span):
+        if f == 2:
+            out["name"] = _text(buf, v)
+        elif f == 3:
+            out["lines"].append(v)
+        elif f == 4:
+            key, val = _map_entry(buf, v)
+            out["meta"][key] = val
+        elif f == 5:
+            key, val = _map_entry(buf, v)
+            for f2, _, v2 in _fields(buf, *val):
+                if f2 == 2:
+                    out["stat_names"][key] = _text(buf, v2)
+    return out
+
+
+def _event_metadata(buf, span, stat_names: dict):
+    """``(name, stats)`` of one ``XEventMetadata``."""
+    name, stats = "", {}
+    for f, _, v in _fields(buf, *span):
+        if f == 2:
+            name = _text(buf, v)
+        elif f == 5:
+            k, val = _stat(buf, v, stat_names)
+            stats[k] = val
+    return name, stats
+
+
+def _line(buf, span):
+    """``(name, [(start_ns, end_ns, metadata_id)])`` on the clock
+    ``ProfileData`` gives, whole nanoseconds cut from picoseconds, so
+    that this reader and ``benchmarks/xplane.py`` see the same nesting."""
+    name, t0, events = "", 0, []
+    for f, _, v in _fields(buf, *span):
+        if f == 2:
+            name = _text(buf, v)
+        elif f == 3:
+            t0 = v
+        elif f == 4:
+            events.append(v)
+    out = []
+    for ev in events:
+        mid = off = dur = 0
+        for f, _, v in _fields(buf, *ev):
+            if f == 1:
+                mid = v
+            elif f == 2:
+                off = v
+            elif f == 3:
+                dur = v
+        start = float(t0 + off // 1000)
+        out.append((start, start + dur // 1000, mid))
+    return name, out
+
+
+# -------------------------------------------------------------- HloProto
+
+class Instr(NamedTuple):
+    name: str
+    opcode: str
+    operands: tuple  # instruction ids
+    called: tuple  # computation ids
+    op_name: str
+    source: str
+    comp: int
+    target: str  # custom_call_target
+    index: int  # tuple_index of a get-tuple-element
+
+
+class Program(NamedTuple):
+    name: str
+    instrs: dict  # id -> Instr
+    by_name: dict  # name -> id
+    roots: dict  # computation id -> root instruction id
+    entry: int  # entry computation id
+    users: dict  # id -> [ids of its users]
+    caller: dict  # computation id -> id of the instruction that calls it
+    flow_names: frozenset  # op_names of while / conditional / call
+
+    def of(self, op_name: str):
+        return self.by_name.get(op_name.lstrip("%"))
+
+
+def _instruction(buf, span, comp: int):
+    name = opcode = op_name = src_file = target = ""
+    src_line = iid = index = 0
+    operands, called = [], []
+    for f, wt, v in _fields(buf, *span):
+        if f == 1:
+            name = _text(buf, v)
+        elif f == 2:
+            opcode = _text(buf, v)
+        elif f == 7:
+            for f2, _, v2 in _fields(buf, *v):
+                if f2 == 2:
+                    op_name = _text(buf, v2)
+                elif f2 == 3:
+                    src_file = _text(buf, v2)
+                elif f2 == 4:
+                    src_line = v2
+        elif f == 13:
+            index = v
+        elif f == 28:
+            target = _text(buf, v)
+        elif f == 35:
+            iid = v
+        elif f == 36:
+            operands += _ints(buf, wt, v)
+        elif f == 38:
+            called += _ints(buf, wt, v)
+    source = f"{src_file}:{src_line}" if src_file else ""
+    return iid, Instr(name, opcode, tuple(operands), tuple(called), op_name,
+                      source, comp, target, index)
+
+
+def _program(buf, span) -> Program:
+    """Of an ``HloProto``: the module's computations and, per instruction,
+    name, opcode, operands, called computations and metadata."""
+    name, entry, comps = "", 0, []
+    for f, _, v in _fields(buf, *span):
+        if f == 1:  # hlo_module
+            for f2, _, v2 in _fields(buf, *v):
+                if f2 == 1:
+                    name = _text(buf, v2)
+                elif f2 == 3:
+                    comps.append(v2)
+                elif f2 == 6:
+                    entry = v2
+    instrs, roots = {}, {}
+    for comp in comps:
+        cid, root, spans = 0, 0, []
+        for f, _, v in _fields(buf, *comp):
+            if f == 2:
+                spans.append(v)
+            elif f == 5:
+                cid = v
+            elif f == 6:
+                root = v
+        roots[cid] = root
+        for sp in spans:
+            iid, ins = _instruction(buf, sp, cid)
+            instrs[iid] = ins
+    users, caller = {}, {}
+    for iid, ins in instrs.items():
+        for o in ins.operands:
+            users.setdefault(o, []).append(iid)
+        for c in ins.called:
+            caller[c] = iid
+    return Program(name, instrs, {i.name: k for k, i in instrs.items()},
+                   roots, entry, users, caller, frozenset(
+                       i.op_name for i in instrs.values()
+                       if i.opcode in ("while", "conditional", "call")))
+
+
+# ------------------------------------------------------------------ read
+
+def read(path: str) -> dict:
+    """``{"devices": {plane: {"ops": [(start_ns, end_ns, Op)], "modules":
+    [(start_ns, end_ns, name, program_id)]}}, "host": [(start_ns, end_ns,
+    name)], "programs": {program_id: Program}}`` of one ``.xplane.pb``
+    (or ``.gz`` of one)."""
+    opener = gzip.open if path.endswith(".gz") else open
+    with opener(path, "rb") as fh:
+        buf = fh.read()
+    devices, host, programs = {}, [], {}
+    for f, _, v in _fields(buf, 0, len(buf)):
+        if f != 1:
+            continue
+        plane = _plane(buf, v)
+        names = plane["stat_names"]
+        if plane["name"].startswith(_DEVICE_PREFIX):
+            ops_of = {}
+            found = {_OPS_LINE: [], _MODULES_LINE: []}
+            for span in plane["lines"]:
+                name, events = _line(buf, span)
+                if name not in found:
+                    continue
+                for s, e, mid in events:
+                    if mid not in ops_of:
+                        ops_of[mid] = _parse_op(*_event_metadata(
+                            buf, plane["meta"][mid], names))
+                    found[name].append((s, e, ops_of[mid]))
+            if found[_OPS_LINE]:
+                devices[plane["name"]] = {
+                    "ops": found[_OPS_LINE],
+                    "modules": [(s, e, op.name, op.program_id)
+                                for s, e, op in found[_MODULES_LINE]]}
+        elif plane["name"] == _HOST_PLANE:
+            texts = {}
+            for span in plane["lines"]:
+                for s, e, mid in _line(buf, span)[1]:
+                    if mid not in texts:
+                        texts[mid] = _event_metadata(
+                            buf, plane["meta"][mid], names)[0]
+                    host.append((s, e, texts[mid]))
+        elif plane["name"] == _HLO_PLANE:
+            for pid, meta in plane["meta"].items():
+                for f2, _, v2 in _fields(buf, *meta):
+                    if f2 != 5:
+                        continue
+                    for f3, _, v3 in _fields(buf, *v2):
+                        if f3 == 6:  # bytes_value: the HloProto
+                            programs[pid] = _program(buf, v3)
+    return {"devices": devices, "host": sorted(host), "programs": programs}
+
+
+# ------------------------------------------------------------- intervals
+
+def union(events: list) -> list:
+    merged = []
+    for s, e in sorted((ev[0], ev[1]) for ev in events):
+        if merged and s <= merged[-1][1]:
+            merged[-1][1] = max(merged[-1][1], e)
+        else:
+            merged.append([s, e])
+    return merged
+
+
+def busy_ns(events: list) -> float:
+    return float(sum(e - s for s, e in union(events)))
+
+
+def leaves_only(events: list) -> list:
+    """Drop events that wholly contain another: a ``while`` or a ``call``
+    spans the ops of its body (the rule of ``benchmarks/xplane.py``; a
+    tier-1 test holds the two readers to the same numbers)."""
+    out, stack = [], []
+    for ev in sorted(events, key=lambda ev: (ev[0], -ev[1])):
+        while stack and stack[-1][1] <= ev[0]:
+            out.append(stack.pop())
+        if stack and ev[1] <= stack[-1][1]:
+            stack.pop()
+        stack.append(ev)
+    return out + stack
+
+
+def _span_of(trace: dict, name: str):
+    found = [h for h in trace["host"] if h[2] == name]
+    if not found:
+        raise ValueError(f"no host span named {name!r} in the trace")
+    return found[0][0], found[0][1]
+
+
+def _clip(events: list, lo: float, hi: float) -> list:
+    out = []
+    for ev in events:
+        s, e = max(ev[0], lo), min(ev[1], hi)
+        if e > s:
+            out.append((s, e) + tuple(ev[2:]))
+    return out
+
+
+# ------------------------------------------------------------- attribute
+
+class Row(NamedTuple):
+    start_ns: float
+    end_ns: float
+    program: str
+    instruction: str
+    opcode: str
+    scope: str
+    tail: str  # "cap4096" of lgbm.split_step.cap4096
+    cause: str  # "program", or "from > into[ layout]"
+    bytes: int
+    source: str
+
+
+def scope_of(op_name: str):
+    """``(scope, tail)`` of the innermost ``lgbm.*`` component, or None.
+    A component under no name of :data:`SCOPES` is its own scope."""
+    for comp in reversed(op_name.rstrip(":").split("/")):
+        if comp.startswith("lgbm."):
+            fits = [s for s in SCOPE_NAMES
+                    if comp == s or comp.startswith(s + ".")]
+            if not fits:
+                return comp, ""
+            best = max(fits, key=len)
+            return best, comp[len(best) + 1:]
     return None
 
 
-def _event_long_name(ev: dict) -> str:
-    args = ev.get("args")
-    if not isinstance(args, dict):
-        return ""
-    return " ".join(
-        str(args.get(k, "")) for k in ("long_name", "tf_op", "hlo_op",
-                                       "name", "hlo_module"))
+def _wrote(prog: Program, op_name: str) -> bool:
+    """Did the program write the op of this ``op_name``?  XLA gives what
+    it inserts no name, or that of the control flow it serves (a copy
+    of the loop's carry reads ``jit(grow_tree)/while:``, as the
+    ``while`` itself does)."""
+    name = op_name.rstrip(":")
+    return bool(name) and name not in prog.flow_names
 
 
-def _is_xla_event(ev: dict) -> bool:
-    """Does this event describe XLA/device work (vs a host Python
-    TraceMe)?  XLA-emitted events carry op args; host TraceMes
-    ('$builtins isinstance', 'TfrtCpuExecutable::Execute', ...) don't."""
-    args = ev.get("args")
-    if isinstance(args, dict) and any(
-            k in args for k in ("hlo_op", "hlo_module", "tf_op",
-                                "long_name")):
-        return True
-    return False
+def _enclosing_scope(prog: Program, iid: int):
+    """Scope of the nearest ``while`` / ``conditional`` / ``call`` above
+    the instruction whose ``op_name`` holds one."""
+    at = prog.caller.get(prog.instrs[iid].comp)
+    while at is not None:
+        found = scope_of(prog.instrs[at].op_name)
+        if found:
+            return found
+        at = prog.caller.get(prog.instrs[at].comp)
+    return None
 
 
-def bucket_events(events: Iterable[dict]) -> Dict[str, float]:
-    """Bucket chrome-trace complete events into phase -> seconds.
+def _resolve_scope(prog, iid, op_name: str):
+    found = scope_of(op_name)
+    if found:
+        return found
+    if iid is None:
+        return UNATTRIBUTED, ""
+    found = _enclosing_scope(prog, iid)
+    if found:
+        return found
+    for user in prog.users.get(iid, ()):
+        found = (scope_of(prog.instrs[user].op_name)
+                 or _enclosing_scope(prog, user))
+        if found:
+            return found
+    return UNATTRIBUTED, ""
 
-    Only ``ph == "X"`` events with a duration participate.  Device
-    tracks are detected from the ``process_name`` metadata (TPU/XLA/GPU
-    device pids); when track metadata is absent (synthetic tests, CPU
-    traces) every timed event is considered.  Unmatched XLA time is
-    reported under ``"unattributed"`` so a breakdown can never silently
-    claim full coverage; events that match no phase AND carry no XLA op
-    args (host-side Python TraceMes) are dropped entirely.
 
-    Backend caveat: op-level attribution needs a profiler that exports
-    the HLO ``op_name`` metadata path into event args (the TPU plugin
-    does).  The CPU tracer emits bare thunk names, so CPU traces bucket
-    almost everything to ``unattributed`` — the scopes are still in the
-    compiled HLO (pinned by tests), the CPU profiler just doesn't
-    surface them.
-    """
-    events = list(events)
-    device_pids = set()
-    have_meta = False
-    for ev in events:
-        if ev.get("ph") == "M" and ev.get("name") == "process_name":
-            have_meta = True
-            pname = str((ev.get("args") or {}).get("name", ""))
-            if re.search(r"TPU|XLA|/device|GPU", pname, re.I):
-                device_pids.add(ev.get("pid"))
-    out: Dict[str, float] = {}
-    for ev in events:
-        if ev.get("ph") != "X" or "dur" not in ev:
+def _kind_of_comp(prog: Program, comp: int):
+    """``("entry" | "while" | "branch" | "call", caller id, branch no)``."""
+    if comp == prog.entry:
+        return "entry", None, 0
+    at = prog.caller.get(comp)
+    if at is None:
+        return "call", None, 0
+    ins = prog.instrs[at]
+    kind = {"while": "while", "conditional": "branch"}.get(ins.opcode, "call")
+    return kind, at, ins.called.index(comp)
+
+
+def _from(prog: Program, iid: int, hops: int = 16, path: tuple = ()):
+    """``(kind, id)`` of where the value of instruction ``iid`` comes
+    from, through bitcasts, async starts, tuples and branch parameters;
+    ``path`` holds the tuple indices still to be taken of it."""
+    ins = prog.instrs[iid]
+    if hops == 0:
+        return "op", iid
+    if ins.opcode == "get-tuple-element":
+        return _from(prog, ins.operands[0], hops - 1, (ins.index,) + path)
+    if ins.opcode == "tuple" and path:
+        return _from(prog, ins.operands[path[0]], hops - 1, path[1:])
+    if ins.operands and (ins.opcode == "bitcast"
+                         or ins.opcode.endswith("-start")):
+        return _from(prog, ins.operands[0], hops - 1, path)
+    if ins.opcode == "custom-call" and ins.target == _MOSAIC:
+        return "kernel", iid
+    if ins.opcode in ("conditional", "while"):
+        return {"conditional": "cond", "while": "loop"}[ins.opcode], iid
+    if ins.opcode == "parameter":
+        kind, at, no = _kind_of_comp(prog, ins.comp)
+        if kind == "entry":
+            return "arg", iid
+        if kind == "while":
+            return "carry", at
+        if kind == "branch":  # operand 0 is the predicate / the index
+            return _from(prog, prog.instrs[at].operands[no + 1], hops - 1,
+                         path)
+    return "op", iid
+
+
+def _into(prog: Program, iid: int, hops: int = 8):
+    """``(kind, id)`` of what consumes the value of instruction ``iid``."""
+    users = prog.users.get(iid, [])
+    found = []
+    for u in users:
+        ins = prog.instrs[u]
+        if hops and (ins.opcode == "bitcast" or ins.opcode.endswith("-done")):
+            found.append(_into(prog, u, hops - 1))
+        elif ins.opcode == "tuple" and prog.roots.get(ins.comp) == u:
+            kind, at, _ = _kind_of_comp(prog, ins.comp)
+            found.append(({"entry": "result", "while": "carry",
+                           "branch": "branch_result"}.get(kind, "op"),
+                          u if at is None else at))
+        elif hops and ins.opcode == "tuple":
+            found.append(_into(prog, u, hops - 1))
+        elif ins.opcode == "while":
+            found.append(("carry", u))
+        elif ins.opcode == "conditional":
+            found.append(("cond_arg", u))
+        elif ins.opcode == "custom-call" and ins.target == _MOSAIC:
+            found.append(("kernel", u))
+        else:
+            found.append(("op", u))
+    for want in ("branch_result", "carry", "result", "cond_arg"):
+        for kind, u in found:
+            if kind == want:
+                return kind, u
+    if found and all(kind == "kernel" for kind, _ in found):
+        return found[0]
+    return ("op", found[0][1]) if found else ("op", None)
+
+
+def _dims(shape: str) -> str:
+    return shape.split("{", 1)[0]
+
+
+def cause_of(prog: Program, iid: int, op: Op):
+    """``(cause, producer id, consumer id)`` of an op the program did not
+    write."""
+    ins = prog.instrs[iid]
+    src, producer = (_from(prog, ins.operands[0]) if ins.operands
+                     else ("op", None))
+    dst, consumer = _into(prog, iid)
+    layout = (op.opcode == "copy" and op.operand_shape
+              and _dims(op.shape) == _dims(op.operand_shape)
+              and op.shape != op.operand_shape)
+    return (f"{src} > {dst}" + (" layout" if layout else ""),
+            producer, consumer)
+
+
+def attribute(trace: dict, window: str | None = None) -> list:
+    """One :class:`Row` for every leaf op of every device plane, inside
+    the host span ``window`` if one is named."""
+    rows = []
+    lo, hi = _span_of(trace, window) if window else (-1e30, 1e30)
+    resolved = {}
+    for dev in trace["devices"].values():
+        for s, e, op in leaves_only(_clip(dev["ops"], lo, hi)):
+            key = (op.program_id, op.name)
+            if key not in resolved:
+                prog = trace["programs"].get(op.program_id)
+                iid = prog.of(op.name) if prog else None
+                name = op.tf_op or (
+                    prog.instrs[iid].op_name if iid is not None else "")
+                scope, tail = _resolve_scope(prog, iid, name)
+                cause = ("program" if iid is None or _wrote(prog, name)
+                         or prog.instrs[iid].target == _MOSAIC
+                         else cause_of(prog, iid, op)[0])
+                source = op.source or (
+                    prog.instrs[iid].source if iid is not None else "")
+                resolved[key] = (prog.name if prog else str(op.program_id),
+                                 scope, tail, cause, source)
+            program, scope, tail, cause, source = resolved[key]
+            rows.append(Row(s, e, program, op.name, op.opcode, scope, tail,
+                            cause, op.bytes, source))
+    return rows
+
+
+def newest_xplane(log_dir: str):
+    """The ``.xplane.pb`` of the newest capture under a
+    ``jax.profiler.start_trace`` directory (each capture gets a
+    timestamped directory of its own), or None."""
+    found = sorted(glob.glob(os.path.join(
+        log_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    return found[-1] if found else None
+
+
+def seconds_by_scope(path: str) -> dict:
+    """``{scope: device seconds}`` of a trace, ``unattributed`` among
+    them: what the CLI's manifest records under ``phases``."""
+    out = {}
+    for r in attribute(read(path)):
+        out[r.scope] = out.get(r.scope, 0.0) + (r.end_ns - r.start_ns) / 1e9
+    return {k: round(v, 6) for k, v in sorted(out.items())}
+
+
+# ---------------------------------------------------------------- report
+
+def _runs(trace: dict, lo: float, hi: float) -> dict:
+    """Program name -> how many of its runs start inside the window."""
+    runs = {}
+    for dev in trace["devices"].values():
+        for s, _, name, pid in dev["modules"]:
+            if lo <= s < hi:
+                prog = trace["programs"].get(pid)
+                key = prog.name if prog else name.split("(")[0]
+                runs[key] = runs.get(key, 0) + 1
+    return runs
+
+
+def _chain(prog: Program, iid: int) -> str:
+    """``conditional.100[1] < ... < while.57`` above an instruction."""
+    out, comp = [], prog.instrs[iid].comp
+    while True:
+        kind, at, no = _kind_of_comp(prog, comp)
+        if at is None:
+            return " < ".join(out) or "(entry)"
+        out.append(prog.instrs[at].name
+                   + (f"[{no}]" if kind == "branch" else ""))
+        comp = prog.instrs[at].comp
+
+
+def copy_ledger(trace: dict, rows: list, program: str,
+                share: float = 0.01) -> list:
+    """Every op of ``program`` the program did not write that takes
+    ``share`` of the program's leaf-op time or more, longest first."""
+    mine = [r for r in rows if r.program == program]
+    total = sum(r.end_ns - r.start_ns for r in mine)
+    by = {}
+    for r in mine:
+        if r.cause != "program":
+            t = by.setdefault(r.instruction, [0.0, 0, r])
+            t[0] += r.end_ns - r.start_ns
+            t[1] += 1
+    prog = next((p for p in trace["programs"].values()
+                 if p.name == program), None)
+    ops = {op.name: op for dev in trace["devices"].values()
+           for _, _, op in dev["ops"] if prog and
+           trace["programs"].get(op.program_id) is prog}
+    out = []
+    for name, (ns, count, r) in sorted(by.items(), key=lambda kv: -kv[1][0]):
+        if not total or ns / total < share:
+            break
+        line = {"instruction": name, "ns": ns, "share": ns / total,
+                "count": count, "scope": r.scope, "tail": r.tail,
+                "cause": r.cause, "bytes": r.bytes}
+        iid = prog.of(name) if prog else None
+        if iid is not None:
+            _, producer, consumer = cause_of(prog, iid, ops[name])
+            named = ["" if i is None else prog.instrs[i].name
+                     for i in (producer, consumer)]
+            line.update(
+                shape=ops[name].shape, operand_shape=ops[name].operand_shape,
+                producer=named[0], consumer=named[1],
+                chain=_chain(prog, iid),
+                # the HloProto of this XLA keeps stack-frame ids, not
+                # files: the neighbour's own event has the line
+                source=next((ops["%" + n].source for n in named
+                             if "%" + n in ops and ops["%" + n].source),
+                            r.source))
+        out.append(line)
+    return out
+
+
+def idle_by_host_span(trace: dict, lo: float, hi: float) -> dict:
+    """Idle time of the device inside ``[lo, hi]`` by the innermost
+    ``lgbm.*`` host span that overlaps it; the rest is ``(no span)``."""
+    ops = [ev for dev in trace["devices"].values()
+           for ev in _clip(dev["ops"], lo, hi)]
+    spans = [h for h in trace["host"] if h[2].startswith("lgbm.")]
+    out, at = {}, lo
+    for s, e in union(ops) + [[hi, hi]]:
+        if s > at:
+            cuts = sorted({at, s} | {t for h in spans for t in h[:2]
+                                     if at < t < s})
+            for a, b in zip(cuts, cuts[1:]):
+                over = [h for h in spans if h[0] <= a and h[1] >= b]
+                name = max(over)[2] if over else "(no span)"
+                out[name] = out.get(name, 0.0) + (b - a)
+        at = max(at, e)
+    return out
+
+
+def report(trace: dict, program: str | None = None,
+           window: str | None = None, top: int = 40) -> dict:
+    rows = attribute(trace, window)
+    if not rows:
+        raise ValueError("no device plane with an 'XLA Ops' line: only a "
+                         "trace taken on a TPU can be attributed")
+    lo, hi = _span_of(trace, window) if window else (
+        min(r.start_ns for r in rows), max(r.end_ns for r in rows))
+    runs = _runs(trace, lo, hi)
+    programs = {}
+    for r in rows:
+        if program and r.program != program:
             continue
-        if have_meta and device_pids and ev.get("pid") not in device_pids:
-            continue
-        sec = float(ev["dur"]) / 1e6  # chrome trace durations are us
-        phase = classify_event(str(ev.get("name", "")),
-                               _event_long_name(ev))
-        if phase is None and not _is_xla_event(ev):
-            continue
-        key = phase if phase is not None else "unattributed"
-        out[key] = out.get(key, 0.0) + sec
-    return {k: round(v, 6) for k, v in out.items()}
+        p = programs.setdefault(r.program, {"ns": 0.0, "by": {}, "un": {}})
+        ns = r.end_ns - r.start_ns
+        p["ns"] += ns
+        key = (r.scope, r.cause)
+        p["by"][key] = p["by"].get(key, 0.0) + ns
+        if r.scope == UNATTRIBUTED:
+            p["un"][r.instruction] = p["un"].get(r.instruction, 0.0) + ns
+    out = {"window_ns": hi - lo, "programs": {}, "ledger": {},
+           "idle_ns": idle_by_host_span(trace, lo, hi)}
+    for name, p in sorted(programs.items(), key=lambda kv: -kv[1]["ns"]):
+        n = max(runs.get(name, 1), 1)
+        out["programs"][name] = {
+            "runs": n, "ms_per_run": p["ns"] / 1e6 / n,
+            "attributed_share": 1.0 - sum(p["un"].values()) / p["ns"],
+            "scope_cause": [
+                [scope, cause, ns / 1e6 / n, ns / p["ns"]]
+                for (scope, cause), ns in sorted(
+                    p["by"].items(), key=lambda kv: -kv[1])[:top]],
+            "unattributed": [
+                [instr, ns / 1e6 / n] for instr, ns in sorted(
+                    p["un"].items(), key=lambda kv: -kv[1])[:top]]}
+        out["ledger"][name] = copy_ledger(trace, rows, name)[:top]
+    return out
 
 
-def load_trace_events(trace_dir: str) -> List[dict]:
-    """Trace events of the NEWEST capture under a ``jax.profiler.trace``
-    output dir.  The profiler writes a fresh timestamped
-    ``plugins/profile/<run>/`` per capture and never cleans old ones,
-    so a reused trace dir holds several runs — summing across them
-    would double phase seconds (and benchdiff would then flag phantom
-    per-phase regressions).  Only files from the latest run directory
-    (timestamped names sort lexicographically) are read."""
-    paths = sorted(
-        glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
-                  recursive=True)
-        + glob.glob(os.path.join(trace_dir, "**", "*.trace.json"),
-                    recursive=True)
-    )
-    if paths:
-        newest_run = max(os.path.dirname(p) for p in paths)
-        paths = [p for p in paths if os.path.dirname(p) == newest_run]
-    events: List[dict] = []
-    for p in paths:
-        opener = gzip.open if p.endswith(".gz") else open
-        try:
-            with opener(p, "rt", encoding="utf-8") as fh:
-                data = json.load(fh)
-        except Exception:
-            continue
-        evs = data.get("traceEvents") if isinstance(data, dict) else data
-        if isinstance(evs, list):
-            events.extend(e for e in evs if isinstance(e, dict))
-    return events
+def _print(rep: dict) -> None:
+    for name, p in rep["programs"].items():
+        print(f"\n== {name}: {p['ms_per_run']:.3f} ms/run over {p['runs']} "
+              f"run(s), {100 * p['attributed_share']:.2f}% attributed")
+        print(f"{'scope':<20}{'cause':<36}{'ms/run':>12}{'share':>9}")
+        for scope, cause, ms, share in p["scope_cause"]:
+            print(f"{scope:<20}{cause:<36}{ms:>12.3f}{100 * share:>8.2f}%")
+        shown = [(i, ms) for i, ms in p["unattributed"] if ms >= 0.0005]
+        for instr, ms in shown:
+            print(f"  unattributed {instr}: {ms:.3f} ms/run")
+        if len(shown) < len(p["unattributed"]):
+            print(f"  unattributed: {len(p['unattributed']) - len(shown)} "
+                  "more of the top under 0.0005 ms/run each (--json)")
+        if rep["ledger"][name]:
+            print(f"-- copy ledger of {name} (ops the program did not "
+                  "write, 1% of it or more)")
+        for c in rep["ledger"][name]:
+            n = p["runs"]
+            print(f"{c['instruction']}  {c['ns'] / 1e6 / n:.3f} ms/run "
+                  f"({100 * c['share']:.2f}%) x{c['count'] / n:g}/run  "
+                  f"{c['cause']}  [{c['scope']}"
+                  f"{'.' + c['tail'] if c['tail'] else ''}]")
+            if "shape" in c:
+                print(f"    {c['bytes']} B  {c['shape']} <- "
+                      f"{c['operand_shape'] or '?'}")
+                print(f"    from {c['producer'] or '?'}  into "
+                      f"{c['consumer'] or '?'}  near {c['source'] or '?'}")
+                print(f"    in {c['chain']}")
+    print(f"\n== device idle in the window ({rep['window_ns'] / 1e6:.3f} ms)")
+    for name, ns in sorted(rep["idle_ns"].items(), key=lambda kv: -kv[1]):
+        print(f"{name:<28}{ns / 1e6:>12.3f} ms")
 
 
-def phase_breakdown_from_trace(trace_dir: str) -> Dict[str, float]:
-    """Phase -> device seconds for a captured trace directory."""
-    return bucket_events(load_trace_events(trace_dir))
+def main(argv=None) -> int:
+    import argparse
+
+    ap = argparse.ArgumentParser(
+        prog="python -m lightgbm_tpu.obs.device_time",
+        description="device time by program scope and cause")
+    ap.add_argument("xplane", help="a .xplane.pb (or .gz of one)")
+    ap.add_argument("--program", help="only this program, e.g. jit_grow_tree")
+    ap.add_argument("--window", help="only inside this host span")
+    ap.add_argument("--top", type=int, default=40)
+    ap.add_argument("--json", action="store_true")
+    a = ap.parse_args(argv)
+    rep = report(read(a.xplane), a.program, a.window, a.top)
+    if a.json:
+        json.dump(rep, sys.stdout, indent=1)
+    else:
+        _print(rep)
+    return 0
 
 
-class trace_phases:
-    """Capture a profiler trace around a block and bucket it:
-
-        with trace_phases("/tmp/lgbm_trace") as result:
-            run_timed_loop()
-        print(result.phases)   # {"histogram": ..., "partition": ...}
-
-    Failure to start/stop the profiler (no TensorFlow profiler plugin,
-    double-start) degrades to an empty breakdown rather than killing
-    the run — a bench harness whose failure mode is "no number" is
-    itself a defect (bench.py module docstring).
-    """
-
-    def __init__(self, trace_dir: str) -> None:
-        self.trace_dir = trace_dir
-        self.phases: Dict[str, float] = {}
-        self._started = False
-
-    def __enter__(self) -> "trace_phases":
-        try:
-            jax.profiler.start_trace(self.trace_dir)
-            self._started = True
-        except Exception:
-            self._started = False
-        return self
-
-    def __exit__(self, *exc) -> None:
-        if not self._started:
-            return
-        try:
-            jax.profiler.stop_trace()
-            self.phases = phase_breakdown_from_trace(self.trace_dir)
-        except Exception:
-            self.phases = {}
+if __name__ == "__main__":
+    sys.exit(main())

@@ -202,20 +202,32 @@ class Histogram:
 
 
 class _Span:
-    """Context manager recording one timed region into a Telemetry."""
+    """Context manager recording one timed region into a Telemetry, and
+    the same region as a ``jax.profiler.TraceAnnotation``: with a
+    profiler session open the span stands on the trace's host plane,
+    on the device's clock (obs/device_time.py reads it back); with none
+    the annotation is a flag test.  jax is never imported here: a
+    process that has not loaded it has nothing to profile."""
 
-    __slots__ = ("_tel", "_name", "_t0")
+    __slots__ = ("_tel", "_name", "_t0", "_note")
 
     def __init__(self, tel: "Telemetry", name: str) -> None:
         self._tel = tel
         self._name = name
 
     def __enter__(self) -> "_Span":
+        jax = sys.modules.get("jax")
+        self._note = jax and jax.profiler.TraceAnnotation(self._name)
+        if self._note:
+            self._note.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self._tel._record_span(self._name, time.perf_counter() - self._t0)
+        dt = time.perf_counter() - self._t0
+        if self._note:
+            self._note.__exit__(*exc)
+        self._tel._record_span(self._name, dt)
 
 
 class _NullSpan:

@@ -229,12 +229,14 @@ def _hist_pallas_call(
                 (1, Fp, 4, B), lambda c, leaf_ref: (leaf_ref[c], 0, 0, 0)
             ),
         )
-        out = pl.pallas_call(
-            kernel,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((out_leaves, Fp, 4, B), jnp.float32),
-            interpret=interpret,
-        )(leaf_of_chunk, bins_buf, stats_buf)
+        with phase_scope(f"histogram.cap{n_chunks * C}"):
+            out = pl.pallas_call(
+                kernel,
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct(
+                    (out_leaves, Fp, 4, B), jnp.float32),
+                interpret=interpret,
+            )(leaf_of_chunk, bins_buf, stats_buf)
         if raw:
             return out  # [L, Fp, 4, B] kernel-native
         return out.transpose(0, 1, 3, 2)  # -> [L, Fp, B, 4]
@@ -256,12 +258,14 @@ def _hist_pallas_call(
             lambda fg, c, leaf_ref: (leaf_ref[c], fg, 0, 0),
         ),
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((out_leaves, Fp, B, 4), jnp.float32),
-        interpret=interpret,
-    )(leaf_of_chunk, bins_buf, stats_buf)
+    with phase_scope(f"histogram.cap{n_chunks * C}"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(
+                (out_leaves, Fp, B, 4), jnp.float32),
+            interpret=interpret,
+        )(leaf_of_chunk, bins_buf, stats_buf)
 
 
 @functools.partial(

@@ -656,13 +656,14 @@ def write_window(rec, out_win, begin, cap: int, interpret: bool = False):
         out_specs=pl.BlockSpec(
             (W, T), lambda i, s: (0, jnp.minimum(s[0] + i, s[2]))),
     )
-    return pl.pallas_call(
-        functools.partial(_write_window_kernel, nt=nt),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(rec.shape, rec.dtype),
-        input_output_aliases={3: 0},  # rec (incl. the prefetch arg)
-        interpret=interpret,
-    )(scal, out_win, out_win, rec)
+    with phase_scope(f"partition.write.cap{cap}"):
+        return pl.pallas_call(
+            functools.partial(_write_window_kernel, nt=nt),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct(rec.shape, rec.dtype),
+            input_output_aliases={3: 0},  # rec (incl. the prefetch arg)
+            interpret=interpret,
+        )(scal, out_win, out_win, rec)
 
 
 def _split_tile(tile, scal_i_ref, j, comp_ref, cnt_ref, hacc_ref, *,
@@ -978,13 +979,14 @@ def place_runs(
             ],
             out_specs=pl.BlockSpec((W, T), lambda i, sp: (0, sp[0, i])),
         )
-        rec = pl.pallas_call(
-            functools.partial(_place_kernel, W=W, leaf_row=leaf_row),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((W, n_pad), jnp.int32),
-            input_output_aliases={2: 0},  # rec (incl. the prefetch arg)
-            interpret=interpret,
-        )(sl.T, comp, rec)
+        with phase_scope(f"partition.place.cap{cap}"):
+            rec = pl.pallas_call(
+                functools.partial(_place_kernel, W=W, leaf_row=leaf_row),
+                grid_spec=grid_spec,
+                out_shape=jax.ShapeDtypeStruct((W, n_pad), jnp.int32),
+                input_output_aliases={2: 0},  # rec (incl. the prefetch arg)
+                interpret=interpret,
+            )(sl.T, comp, rec)
     return rec
 
 
@@ -1113,15 +1115,16 @@ def split_step_window(
     if direct_read:
         out_shape.append(jax.ShapeDtypeStruct((W, n_pad), jnp.int32))
         aliases[2] = 4  # recA -> rec pass-through
-    outs = pl.pallas_call(
-        functools.partial(
-            _split_step_kernel, W=W, F=F, k=k, Bp=Bp, nt=nt,
-            fgroup=fgroup, direct_read=direct_read, routing=routing),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(scal_i, scal_f, *data_in, hists, meta)
+    with phase_scope(f"split_step.cap{cap}"):
+        outs = pl.pallas_call(
+            functools.partial(
+                _split_step_kernel, W=W, F=F, k=k, Bp=Bp, nt=nt,
+                fgroup=fgroup, direct_read=direct_read, routing=routing),
+            grid_spec=grid_spec,
+            out_shape=out_shape,
+            input_output_aliases=aliases,
+            interpret=interpret,
+        )(scal_i, scal_f, *data_in, hists, meta)
     if direct_read:
         hists_new, comp, res, cnt, rec_pass = outs
     else:
@@ -1205,30 +1208,21 @@ def partition_window(
     if _resolve_routing(routing) == "prefix":
         # go flags ride ROW 0 of a sublane-aligned [8, cap] operand
         # (see _compact_kernel_prefix); rows 1-7 are zero padding
-        gov8 = jnp.pad(gov[None], ((0, 7), (0, 0)))
-        comp = pl.pallas_call(
-            functools.partial(_compact_kernel_prefix, W=W),
-            grid=(nt,),
-            in_specs=[
-                pl.BlockSpec((W, T), lambda i: (0, i)),
-                pl.BlockSpec((8, T), lambda i: (0, i)),
-            ],
-            out_specs=pl.BlockSpec((1, W, 2 * T), lambda i: (i, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((nt, W, 2 * T), jnp.int32),
-            interpret=interpret,
-        )(win, gov8)
+        kernel, flags = _compact_kernel_prefix, jnp.pad(
+            gov[None], ((0, 7), (0, 0)))
+        flag_spec = pl.BlockSpec((8, T), lambda i: (0, i))
     else:
+        kernel, flags = _compact_kernel, gov.reshape(cap, 1)
+        flag_spec = pl.BlockSpec((T, 1), lambda i: (i, 0))
+    with phase_scope(f"partition.compact.cap{cap}"):
         comp = pl.pallas_call(
-            functools.partial(_compact_kernel, W=W),
+            functools.partial(kernel, W=W),
             grid=(nt,),
-            in_specs=[
-                pl.BlockSpec((W, T), lambda i: (0, i)),
-                pl.BlockSpec((T, 1), lambda i: (i, 0)),
-            ],
+            in_specs=[pl.BlockSpec((W, T), lambda i: (0, i)), flag_spec],
             out_specs=pl.BlockSpec((1, W, 2 * T), lambda i: (i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((nt, W, 2 * T), jnp.int32),
             interpret=interpret,
-        )(win, gov.reshape(cap, 1))
+        )(win, flags)
 
     if direct and not interpret:
         # aliased in-kernel placement: no scan-of-DUS and no copy of

@@ -62,7 +62,7 @@ def test_bench_always_emits_json_line(tmp_path):
     assert "backend_compiles" in man.telemetry["counters"]
     assert man.warmup["compiles_warmup"] >= 1
     assert man.per_tree.get("count") == out["timed_trees"]
-    assert isinstance(man.phases, dict)  # empty unless LGBM_TPU_TRACE
+    assert man.phases == {}  # bench.py takes no trace
 
 
 def test_bench_refuses_a_platform_nobody_named(tmp_path):

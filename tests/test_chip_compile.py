@@ -9,6 +9,8 @@ refuse every kernel.  A refusal then fails here, not in a chip call.
 This says nothing about what the kernels compute (chip_smoke.py does).
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -23,7 +25,8 @@ from lightgbm_tpu.models.gbdt import GBDT
 from lightgbm_tpu.objectives import create_objective
 
 
-def _v5e_topology():
+@pytest.fixture(scope="module")
+def topo():
     from jax.experimental import topologies
 
     try:
@@ -33,8 +36,9 @@ def _v5e_topology():
         pytest.skip(f"libtpu gives no v5e topology here: {e}")
 
 
-def test_serial_grower_compiles_for_v5e():
-    topo = _v5e_topology()
+@pytest.fixture(scope="module")
+def grower(topo):
+    """``(lowered, compiled)`` of the serial grower for one v5e chip."""
     n, F = 100_000, 28
     rng = np.random.RandomState(0)
     X = rng.randn(n, F).astype(np.float32)
@@ -54,7 +58,40 @@ def test_serial_grower_compiles_for_v5e():
              jnp.zeros(n, jnp.float32), gbdt._bag_mask, jnp.ones(F, bool),
              gbdt._nbpf, gbdt._is_cat, gbdt._learner_params))
         lowered = grow.func.lower(*args, **grow.keywords)
+    return lowered, lowered.compile()  # Mosaic refuses a kernel here, or not
+
+
+def test_serial_grower_compiles_for_v5e(grower):
+    lowered, compiled = grower
     mosaic_calls = lowered.as_text().count("tpu_custom_call")
     assert mosaic_calls >= 10, mosaic_calls  # 19 at this shape
-    compiled = lowered.compile()  # Mosaic refuses a kernel here, or not
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_scopes_and_kernel_names_in_the_compiled_grower(grower):
+    """What obs/device_time reads back, and the harness's layer files
+    match, as the chip's compiler leaves it: every scope the grower
+    writes is in some ``op_name``, none is outside the table, and every
+    Mosaic call's instruction is named after its kernel and capacity
+    (an instruction takes the name of its innermost scope: read off
+    compiled programs, not documented, hence this test)."""
+    from lightgbm_tpu.obs import device_time as dt
+
+    text = grower[1].as_text()
+    found = {dt.scope_of(name) for name in
+             set(re.findall(r'op_name="([^"]*)"', text))} - {None}
+    scopes = {scope for scope, _ in found}
+    assert scopes <= set(dt.SCOPE_NAMES), scopes - set(dt.SCOPE_NAMES)
+    assert scopes >= {s for s in dt.SCOPE_NAMES if ".grow." in s} | {
+        "lgbm.histogram", "lgbm.split_step", "lgbm.partition",
+        "lgbm.split_search"}
+    assert {tail for scope, tail in found if scope == "lgbm.grow.tier"} == {
+        "split"}  # the fused path has the one chain
+    calls = re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+        text, re.M)
+    assert len(calls) >= 10, calls
+    for name in calls:
+        assert re.fullmatch(
+            r"lgbm\.(split_step|partition\.place|histogram)"
+            r"\.cap\d+(\.\d+)?", name), name

@@ -106,24 +106,29 @@ def test_train_loop_feeds_telemetry():
     assert res is not None and len(res) >= 3
 
 
-def test_phase_scope_lands_in_compiled_hlo():
-    """End-to-end static proof of phase attribution: the split-search
-    op metadata in the COMPILED program carries the lgbm scope path the
-    trace bucketer keys on."""
-    import jax
+@pytest.mark.parametrize("scope", ["lgbm.split_search", "lgbm.gradients"])
+def test_phase_scope_lands_in_compiled_hlo(scope):
+    """Static proof of attribution: op metadata in the COMPILED program
+    carries the lgbm scope that obs/device_time reads back from a trace
+    (the grower's own scopes: tests/test_chip_compile.py)."""
     import jax.numpy as jnp
 
+    from lightgbm_tpu.objectives import _l2_grads
     from lightgbm_tpu.ops.split import find_best_split
 
     F, B = 4, 8
     hist = jnp.zeros((F, B, 3), jnp.float32)
-    args = (hist, jnp.float32(0), jnp.float32(1), jnp.float32(8),
+    fn, args = {
+        "lgbm.split_search": (find_best_split, (
+            hist, jnp.float32(0), jnp.float32(1), jnp.float32(8),
             jnp.ones(F, bool), jnp.full(F, B, jnp.int32),
             jnp.zeros(F, bool), jnp.float32(1), jnp.float32(1e-3),
             jnp.float32(0), jnp.float32(0), jnp.float32(0),
-            jnp.bool_(True))
-    txt = find_best_split.lower(*args).compile().as_text()
-    assert "lgbm.split_search" in txt
+            jnp.bool_(True))),
+        "lgbm.gradients": (_l2_grads, (
+            jnp.zeros(8, jnp.float32), jnp.ones(8, jnp.float32), None)),
+    }[scope]
+    assert scope in fn.lower(*args).compile().as_text()
 
 
 def test_emit_json_line_shape(capsys):
@@ -158,46 +163,6 @@ ENTRY %main (p0: f32[64,32]) -> f32[64,32] {
     body = stats["by_computation"]["%body"]
     # variadic result: both tuple components count toward payload
     assert body["payload_bytes"] == (16 * 4 + 4 * 4) + 128 * 4
-
-
-# -------------------------------------------------------- trace bucketing
-
-def test_bucket_events_by_scope_and_kernel_name():
-    from lightgbm_tpu.obs.device_time import bucket_events, classify_event
-
-    evs = [
-        {"ph": "X", "name": "fusion.7", "dur": 2000,
-         "args": {"long_name": "jit(f)/lgbm.histogram/dot_general"}},
-        {"ph": "X", "name": "fusion.8", "dur": 1000,
-         "args": {"long_name": "jit(f)/lgbm.split_search/reduce"}},
-        {"ph": "X", "name": "split_step_kernel", "dur": 500},
-        {"ph": "X", "name": "copy.3", "dur": 250,
-         "args": {"hlo_op": "copy.3"}},  # XLA op, unknown phase
-        {"ph": "X", "name": "$builtins isinstance", "dur": 9000},  # host
-        {"ph": "M", "name": "thread_name"},  # metadata: ignored
-    ]
-    out = bucket_events(evs)
-    assert out["histogram"] == pytest.approx(0.002)
-    assert out["split-search"] == pytest.approx(0.001)
-    assert out["partition"] == pytest.approx(0.0005)
-    # unknown XLA op -> unattributed; host Python TraceMe -> dropped
-    assert out["unattributed"] == pytest.approx(0.00025)
-    # device-track filtering: with process metadata present, host-track
-    # events are excluded
-    evs_meta = [
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "name": "process_name", "pid": 2,
-         "args": {"name": "python host"}},
-        {"ph": "X", "pid": 1, "name": "x", "dur": 1000,
-         "args": {"long_name": "lgbm.leaf_update/add"}},
-        {"ph": "X", "pid": 2, "name": "lgbm.histogram/host-noise",
-         "dur": 9000},
-    ]
-    out = bucket_events(evs_meta)
-    assert out == {"leaf-update": pytest.approx(0.001)}
-    assert classify_event("whatever", "lgbm.predict/dot") == "predict"
-    assert classify_event("unrelated.op") is None
 
 
 # --------------------------------------------------------------- manifest

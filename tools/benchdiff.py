@@ -898,9 +898,8 @@ def diff(old: dict, new: dict, headline_pct: float = HEADLINE_PCT,
         for ph in shared:
             o, n = float(op[ph]), float(np_[ph])
             if o <= 0 or n <= 0:
-                # a 0.0 side has no meaningful percent (bucket_events
-                # keeps 0.0-second entries); only a real appearance is
-                # worth a word
+                # a 0.0 side has no meaningful percent; only a real
+                # appearance is worth a word
                 if max(o, n) > 0.05:
                     warnings.append(
                         f"phase '{ph}' {o:.3f}s -> {n:.3f}s (no "
@@ -915,8 +914,8 @@ def diff(old: dict, new: dict, headline_pct: float = HEADLINE_PCT,
                 improvements.append(
                     f"phase '{ph}' {o:.3f}s -> {n:.3f}s ({d:.1f}%)")
     else:
-        warnings.append("no phase breakdown on either side (capture one "
-                        "with LGBM_TPU_TRACE=<dir> bench.py)")
+        warnings.append("no phase breakdown on either side (a CLI "
+                        "train run with profile=true records one)")
 
     # compile hygiene of the NEW run (the round-5 mechanism: lazy
     # compiles inside the timed loop)
