@@ -249,7 +249,11 @@ def _split_step_inputs():
         begin=jnp.int32(0), pcnt=jnp.int32(n),
         do_split=jnp.bool_(True), f=jnp.int32(1), thr=jnp.int32(3),
         is_cat=jnp.bool_(False), parent_slot=jnp.int32(0),
-        new_slot=jnp.int32(1))
+        new_slot=jnp.int32(1),
+        # the grower's form of the two launches: the tile count is an
+        # operand (here every tile of the window), the grid a run-time
+        # bound
+        live_tiles=jnp.int32(cap // T))
     return rec, hists, scal_f, meta, scalars, cap, k
 
 
@@ -262,7 +266,8 @@ def _measure_split_step_window() -> dict:
     lowered = split_step_window.lower(
         hists, rec, s["begin"], s["pcnt"], s["do_split"], s["f"],
         s["thr"], s["is_cat"], s["parent_slot"], s["new_slot"],
-        scal_f, meta, F=_F, cap=cap, k=k, interpret=True)
+        scal_f, meta, F=_F, cap=cap, k=k, interpret=True,
+        live_tiles=s["live_tiles"])
     ops, has_alias, dwarn, mem = _compile_entry(lowered)
     return {"ops": ops, "donation": has_alias and not dwarn,
             "donation_warnings": dwarn, "has_alias": has_alias,
@@ -283,7 +288,7 @@ def _measure_split_step_record_chain() -> dict:
             hists_, rec_, s["begin"], s["pcnt"], s["do_split"], s["f"],
             s["thr"], s["is_cat"], s["parent_slot"], s["new_slot"],
             scal_f, meta, F=_F, cap=cap, k=k, return_comp=True,
-            interpret=False)
+            interpret=False, live_tiles=s["live_tiles"])
 
     jaxpr = jax.make_jaxpr(run)(rec, hists)
     uses = _jaxpr_use_count(jaxpr, 0)
@@ -307,7 +312,8 @@ def _measure_place_runs() -> dict:
     go = jnp.zeros(cap, jnp.int32)
     args = (comp, go, s["begin"], s["pcnt"], jnp.int32(cap // 2),
             s["do_split"], s["parent_slot"], s["new_slot"])
-    kw = dict(cap=cap, leaf_row=rec_mod.num_words(_F, k) + 4)
+    kw = dict(cap=cap, leaf_row=rec_mod.num_words(_F, k) + 4,
+              live_tiles=s["live_tiles"])
 
     lowered = rec_mod.place_runs.lower(rec, *args, interpret=True, **kw)
     ops, has_alias, dwarn, mem = _compile_entry(lowered)

@@ -20,7 +20,9 @@ legalisation), so these checks run the compiled kernels against their
               un-annotated one-hot dots shows there)
   writeback — write_window (aliased) vs a numpy slice assignment
   place     — place_runs (aliased placement) vs partition_window's XLA
-              scan-of-DUS placement
+              scan-of-DUS placement, at the static tile count and, as
+              the grower launches it, over a wider window at a run-time
+              tile count (dynamic Mosaic grids, parked chunks)
 
 All run at the import-default routing (``ops.record.ROUTING``).  Each
 check prints one summary line through ``log`` and returns True/False.
@@ -272,7 +274,8 @@ def check_place(rng, log=print, interpret=False) -> bool:
             h = (rng.rand(n) + 0.5).astype(np.float32)
             bag = np.ones(n, np.float32)
             k = bins_per_word(jnp.uint8)
-            total = round_up(n + begin_off, TILE) + TILE
+            # room for the wider window of the run-time-count launch
+            total = round_up(n + begin_off, TILE) + 3 * TILE
             rec = build_record(
                 jnp.asarray(np.pad(bins, ((0, 0), (begin_off, 0)))),
                 jnp.asarray(np.pad(g, (begin_off, 0))),
@@ -320,11 +323,34 @@ def check_place(rng, log=print, interpret=False) -> bool:
             if int(nlA) != int(nlB):
                 log(f"  place trial {trial}: nleft {int(nlA)} vs {int(nlB)}")
                 ok = False
-            ra, rb = np.asarray(recA), np.asarray(recB)
-            if not np.array_equal(ra, rb):
-                bad = [r for r in range(ra.shape[0])
-                       if not np.array_equal(ra[r], rb[r])]
-                log(f"  place trial {trial}: record rows differ {bad}")
+            # the grower's launch pair: a window two tiles wider than
+            # the leaf, visited over the live tiles only (a run-time
+            # grid; chunks past the live steps run one parked step)
+            cap2, live = cap + 2 * TILE, jnp.int32(cap // TILE)
+            _, comp2, nlC, _, clC, crC, rp2 = split_step_window(
+                jnp.zeros((7, Fp, 4, Bp), jnp.float32), rec, begin, jnp.int32(n), jnp.bool_(True),
+                jnp.int32(f), jnp.int32(thr), jnp.bool_(False),
+                jnp.int32(3), jnp.int32(5), scal, meta, F=F, cap=cap2,
+                k=k, return_comp=True, interpret=interpret,
+                live_tiles=live)
+            recC = place_runs(
+                jnp.array(rp2), comp2, None, begin, jnp.int32(n), nlC,
+                jnp.bool_(True), jnp.int32(3), jnp.int32(5), cap=cap2,
+                leaf_row=lr, interpret=interpret, counts=(clC, crC),
+                live_tiles=live)
+            ra = np.asarray(recA)
+            for what, got in (("", recB), (" (live tiles)", recC)):
+                rb = np.asarray(got)
+                if not np.array_equal(ra, rb):
+                    bad = [r for r in range(ra.shape[0])
+                           if not np.array_equal(ra[r], rb[r])]
+                    log(f"  place trial {trial}{what}: record rows "
+                        f"differ {bad}")
+                    ok = False
+            if int(nlC) != int(nlA) or np.asarray(clC)[cap // TILE:].any():
+                log(f"  place trial {trial} (live tiles): nleft "
+                    f"{int(nlC)} vs {int(nlA)}, or counts past the "
+                    f"live tiles")
                 ok = False
     finally:
         record.PLACE_CHUNK = chunk0

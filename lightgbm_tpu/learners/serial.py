@@ -179,7 +179,12 @@ def _hist_tiers(n: int):
     <4x gather waste.  Measured XLA:CPU compile at n=1M, L=255, B=255
     (segment hist): spacing=2 (9 tiers) 9.5s, spacing=4 (5 tiers)
     13.8s — tier count is NOT the compile bottleneck off-TPU; the knob
-    exists for the Mosaic per-kernel compile path."""
+    exists for the Mosaic per-kernel compile path.
+
+    The default fused TPU path (split_step_window + place_runs) takes
+    only the LARGEST capacity from here, to size its buffers: its
+    kernels' tile counts are run-time operands and no tier gets a body
+    of its own, so the spacing does not reach it."""
     step = _TIER_SPACING_ENV
     caps = {max(512, _round_up(n, 128))}
     frac = 2
@@ -797,19 +802,18 @@ def grow_tree(
                     params.min_gain_to_split,
                 )
 
-            def _mega_rec(cap):
+            def _mega_rec(cap, live_tiles=None):
                 # the decision AND the tile counts live in the kernel
                 # (_tile_go + the cnt output): no XLA-side read of the
                 # record at all, so the aliased placement updates it in
-                # place across the tier conds (the materialized window
-                # + go vector previously forced a full-record copy per
-                # split — ~1 s/tree at 10M rows)
+                # place (a materialized window + go vector forced a
+                # full-record copy per split — ~1 s/tree at 10M rows)
                 out = split_step_window(
                     state.hists, state.order, begin, pcnt,
                     do_split, f, thr, is_cat, best_leaf, new_leaf,
                     scal_f, _mega_meta, F=F, cap=cap, k=k_pack,
                     fgroup=_FGROUP, return_comp=direct_place,
-                    interpret=_interp,
+                    interpret=_interp, live_tiles=live_tiles,
                 )
                 if not direct_place:
                     return out
@@ -818,13 +822,26 @@ def grow_tree(
                     rec_pass, comp, None, begin, pcnt, nl, do_split,
                     best_leaf, new_leaf, cap=cap, leaf_row=_leaf_row,
                     interpret=_interp, counts=(cl, cr),
+                    live_tiles=live_tiles,
                 )
                 return mh, rec2, nl, res
 
-            with phase_scope("grow.tier.split"):
-                mega_hists, order, nleft, mega_res = _tier_chain(
-                    p_tiers, gate, _mega_rec
-                )
+            if direct_place:
+                # ONE launch pair at the largest capacity whose tile
+                # count is a run-time operand, outside any lax.cond:
+                # the record and hists go kernel > kernel > carry
+                # through aliased calls.  A conditional's result is a
+                # buffer of its own, and the tier chain here cost two
+                # whole-record copies a split (PERF.md, PR 26/27).
+                mega_hists, order, nleft, mega_res = _mega_rec(
+                    p_tiers[-1], -(-pcnt // _REC_TILE))
+            else:
+                # LGBM_TPU_DIRECT_PLACE=0: the XLA placement costs
+                # O(cap) a split, so it keeps a capacity per tier
+                with phase_scope("grow.tier.split"):
+                    mega_hists, order, nleft, mega_res = _tier_chain(
+                        p_tiers, gate, _mega_rec
+                    )
         elif rec:
 
             def _part_rec(cap):

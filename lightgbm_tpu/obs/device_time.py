@@ -53,10 +53,12 @@ import sys
 from typing import NamedTuple
 
 # The one table of scope names: (scope, what it covers).  A component
-# ``lgbm.split_step.cap4096`` belongs to the scope ``lgbm.split_step``
-# and carries the tail ``cap4096`` (a Mosaic call's tier capacity: the
+# ``lgbm.partition.compact.cap4096`` belongs to the scope
+# ``lgbm.partition`` and carries the tail ``compact.cap4096`` (a Mosaic
+# call's kernel and, where each capacity tier has a body of its own, its
+# capacity; ``dyn`` where the tile count is a run-time operand: the
 # instruction takes its name from the innermost scope, so the tail is
-# what makes ``%lgbm.split_step.cap4096.N`` say which tier it is).
+# what makes ``%lgbm.split_step.dyn.N`` say which call it is).
 SCOPES = (
     ("lgbm.histogram", "histogram kernels (on the fused path: the root's) "
      "and the XLA ops that pad and split their operands"),
@@ -76,7 +78,8 @@ SCOPES = (
     ("lgbm.grow.select", "body's argmax and the column reads and scalar "
      "packing of split_branch"),
     ("lgbm.grow.tier", "a _tier_chain call: the cond nest and what XLA "
-     "puts at its boundaries (tails: split, part, hist)"),
+     "puts at its boundaries (tails: part, hist, split; the default "
+     "fused path has none)"),
     ("lgbm.grow.book", "split_branch after the kernels: best_mat, "
      "pos_mat, tree_i, tree_f column updates, pool bookkeeping"),
     ("lgbm.grow.unpack", "grow_tree after the loop: Tree unpack, leaf_id"),
@@ -288,6 +291,7 @@ class Instr(NamedTuple):
     comp: int
     target: str  # custom_call_target
     index: int  # tuple_index of a get-tuple-element
+    shape: str  # "s32[32,15000576]", a tuple's in parentheses; no layout
 
 
 class Program(NamedTuple):
@@ -304,8 +308,30 @@ class Program(NamedTuple):
         return self.by_name.get(op_name.lstrip("%"))
 
 
+# xla_data.proto PrimitiveType, as HLO text spells it
+_PRIMITIVE = {1: "pred", 2: "s8", 3: "s16", 4: "s32", 5: "s64", 6: "u8",
+              7: "u16", 8: "u32", 9: "u64", 10: "f16", 11: "f32", 12: "f64",
+              15: "c64", 16: "bf16", 17: "token", 18: "c128"}
+
+
+def _shape(buf, span) -> str:
+    """A ``ShapeProto`` as HLO text writes it, without layout."""
+    etype, dims, parts = 0, [], []
+    for f, wt, v in _fields(buf, *span):
+        if f == 2:
+            etype = v
+        elif f == 3:
+            dims += _ints(buf, wt, v)
+        elif f == 4:
+            parts.append(_shape(buf, v))
+    if etype == 13:
+        return "(" + ", ".join(parts) + ")"
+    return (_PRIMITIVE.get(etype, f"type{etype}")
+            + "[" + ",".join(map(str, dims)) + "]")
+
+
 def _instruction(buf, span, comp: int):
-    name = opcode = op_name = src_file = target = ""
+    name = opcode = op_name = src_file = target = shape = ""
     src_line = iid = index = 0
     operands, called = [], []
     for f, wt, v in _fields(buf, *span):
@@ -313,6 +339,8 @@ def _instruction(buf, span, comp: int):
             name = _text(buf, v)
         elif f == 2:
             opcode = _text(buf, v)
+        elif f == 3:
+            shape = _shape(buf, v)
         elif f == 7:
             for f2, _, v2 in _fields(buf, *v):
                 if f2 == 2:
@@ -333,22 +361,36 @@ def _instruction(buf, span, comp: int):
             called += _ints(buf, wt, v)
     source = f"{src_file}:{src_line}" if src_file else ""
     return iid, Instr(name, opcode, tuple(operands), tuple(called), op_name,
-                      source, comp, target, index)
+                      source, comp, target, index, shape)
 
 
 def _program(buf, span) -> Program:
-    """Of an ``HloProto``: the module's computations and, per instruction,
-    name, opcode, operands, called computations and metadata."""
+    """Of an ``HloProto``: its ``hlo_module``, read by :func:`_module`."""
+    empty = (span[0], span[0])
+    return _module(buf, next(
+        (v for f, _, v in _fields(buf, *span) if f == 1), empty))
+
+
+def program_of_module(module_proto: bytes) -> Program:
+    """The :class:`Program` of a serialized ``HloModuleProto``, which is
+    what a compiled executable gives with no trace at all
+    (``compiled.runtime_executable().hlo_modules()[0]
+    .as_serialized_hlo_module_proto()``): tests/test_chip_compile.py
+    reads the deviceless compile's while body with it."""
+    return _module(module_proto, (0, len(module_proto)))
+
+
+def _module(buf, span) -> Program:
+    """Of an ``HloModuleProto``: the computations and, per instruction,
+    name, opcode, shape, operands, called computations and metadata."""
     name, entry, comps = "", 0, []
     for f, _, v in _fields(buf, *span):
-        if f == 1:  # hlo_module
-            for f2, _, v2 in _fields(buf, *v):
-                if f2 == 1:
-                    name = _text(buf, v2)
-                elif f2 == 3:
-                    comps.append(v2)
-                elif f2 == 6:
-                    entry = v2
+        if f == 1:
+            name = _text(buf, v)
+        elif f == 3:
+            comps.append(v)
+        elif f == 6:
+            entry = v
     instrs, roots = {}, {}
     for comp in comps:
         cid, root, spans = 0, 0, []
@@ -482,7 +524,7 @@ class Row(NamedTuple):
     instruction: str
     opcode: str
     scope: str
-    tail: str  # "cap4096" of lgbm.split_step.cap4096
+    tail: str  # "dyn" of lgbm.split_step.dyn
     cause: str  # "program", or "from > into[ layout]"
     bytes: int
     source: str

@@ -85,7 +85,7 @@ if TILE <= 0 or TILE % 128 != 0:
 # place_runs step-table chunk per launch: a [8, steps] i32 SMEM prefetch
 # block is 32B/step (SMEM pads the minor dim to 128 lanes per ROW, hence
 # the transpose), and the 1MB SMEM budget caps one launch at ~16k steps
-# — the 10M top tier has ~78k.  Read at IMPORT like the other kernel
+# — a 10M-row window has ~78k.  Read at IMPORT like the other kernel
 # knobs (ADVICE r4): place_runs reads it at trace time, so a mid-process
 # flip would silently not apply to already-traced caps.
 PLACE_CHUNK = int(_os.environ.get("LGBM_TPU_PLACE_CHUNK", "16384"))
@@ -134,20 +134,32 @@ def rec_height(F: int, k: int) -> int:
 
 
 def pack_bins(bins_T: jax.Array, n_pad: int) -> jax.Array:
-    """[F, n] u8/u16 -> [Wb, n_pad] i32, k features per word."""
+    """[F, n] u8/u16 -> [Wb, n_pad] i32, k features per word (feature
+    ``w*k + j`` in byte/half ``j`` of word-row ``w``).
+
+    Widened to i32 eight word-rows at a time and padded after packing:
+    widening the whole matrix at ``n_pad`` first made XLA hold an
+    ``[F, n_pad]`` and a ``[Wb, k, n_pad]`` i32 buffer at the root —
+    all 11.4 GiB of the grow program's scratch at 7.5M x 100 (buffer
+    assignment of the deviceless compile, PERF.md PR 27).  A group is
+    8*k feature rows, whole sublane tiles of the narrow matrix."""
     F, n = bins_T.shape
     k = bins_per_word(bins_T.dtype)
     shift = 32 // k
-    Wb = num_words(F, k)
-    x = bins_T.astype(jnp.int32)
+    groups = []
+    for lo in range(0, F, 8 * k):
+        x = bins_T[lo: lo + 8 * k].astype(jnp.int32)
+        wg = num_words(x.shape[0], k)
+        if x.shape[0] % k:
+            x = jnp.pad(x, ((0, wg * k - x.shape[0]), (0, 0)))
+        x = x.reshape(wg, k, n)
+        out = x[:, 0, :]
+        for j in range(1, k):
+            out = out | (x[:, j, :] << (shift * j))
+        groups.append(out)
+    out = jnp.concatenate(groups)
     if n_pad > n:
-        x = jnp.pad(x, ((0, 0), (0, n_pad - n)))
-    if F % k:
-        x = jnp.pad(x, ((0, Wb * k - F), (0, 0)))
-    x = x.reshape(Wb, k, n_pad)
-    out = x[:, 0, :]
-    for j in range(1, k):
-        out = out | (x[:, j, :] << (shift * j))
+        out = jnp.pad(out, ((0, 0), (0, n_pad - n)))
     return out
 
 
@@ -687,17 +699,26 @@ def _split_tile(tile, scal_i_ref, j, comp_ref, cnt_ref, hacc_ref, *,
 
 def _split_step_kernel(
     scal_i_ref, scal_f_ref, *refs,
-    W, F, k, Bp, nt, fgroup=8, direct_read=False, routing=None,
+    W, F, k, Bp, fgroup=8, direct_read=False, routing=None,
 ):
     """The WHOLE split step in one launch: per-tile MXU compaction +
     left-child histogram accumulation (steps 0..nt-1), then subtract +
     two-child search + in-place histogram-buffer row updates (steps nt
     and nt+1) — the union of the tile compaction and
-    pallas_search._fused_kernel, eliminating one ~0.35 ms launch floor
-    plus the [Fp, 4, Bp] h_small round trip through HBM per split.
+    pallas_search._fused_kernel, eliminating one launch (13-14 us on a
+    v5e for a Mosaic call of zero or one grid step, measured back to
+    back in a fori_loop: PERF.md, PR 27) plus the [Fp, 4, Bp] h_small
+    round trip through HBM per split.
 
-    scal_i [10]: (parent_slot, left_slot, new_slot, do_split, f, thr,
-                 is_cat, pcnt, begin//T, begin%T)
+    ``nt`` is the LIVE tile count, a run-time value (scal_i[10]; the
+    grid is ``nt + 2 (+1 direct)`` steps, a dynamic bound): one body
+    serves every window size, so the grower launches it outside any
+    ``lax.cond`` and the record stays in the loop's carry.  Tiles past
+    ``nt`` are never visited — their ``comp``/``cnt`` blocks keep
+    whatever the buffer held, and split_step_window masks them.
+
+    scal_i [11]: (parent_slot, left_slot, new_slot, do_split, f, thr,
+                 is_cat, pcnt, begin//T, begin%T, live tiles)
     scal_f [16]: pallas_search._pack_scal layout
     win_ref    : the [W, T] window tile (non-direct mode).  With
                  ``direct_read`` the RECORD itself is the (single,
@@ -734,6 +755,7 @@ def _split_step_kernel(
     T = TILE
     i = pl.program_id(0)
     do_split = scal_i_ref[3] > 0
+    nt = scal_i_ref[10]
     off = 1 if direct_read else 0  # pipeline offset of the tile steps
     search_step = nt + off
     last_step = nt + 1 + off
@@ -822,20 +844,21 @@ def _place_kernel(sp_ref, comp_ref, rec_in_ref, rec_out_ref, *,
     """Placement-only kernel: stream the compacted left/right runs into
     the ALIASED record at their (arbitrary, unaligned) destinations —
     replacing the XLA scan-of-DUS + roll/merge chain AND the full-record
-    copy its dynamic-update-slice forced at the tier-cond boundary.
+    copy its dynamic-update-slice forced at a cond boundary.
 
-    Step table sp [4*nt, 8] i32 (see _place_table): per step one run
+    Step table sp [8, steps] i32 (see _place_table): per step one run
     half lands in one T-lane rec block; block indices are monotone, so
     each block is flushed exactly once after its last write.  On an
     index advance the merge base is the freshly fetched block; on a
     revisit it is the still-resident out block.  Child leaf ids are
     stamped into the record's leaf-id row as part of the same write.
+    The grid (how many of the table's steps run) is a run-time value.
     """
     T = TILE
     i = pl.program_id(0)
     # the table is stored TRANSPOSED [8, steps]: a [steps, 8] SMEM
     # prefetch array pads its minor dim to 128 lanes (16x the bytes);
-    # huge tiers additionally CHUNK the table across multiple launches
+    # huge windows additionally CHUNK the table across multiple launches
     # to stay inside the 1MB SMEM budget (see place_runs)
     en = sp_ref[6, i] > 0
 
@@ -862,20 +885,26 @@ def _place_kernel(sp_ref, comp_ref, rec_in_ref, rec_out_ref, *,
 
     @pl.when((i == 0) & jnp.logical_not(en))
     def _():
-        # a fully disabled table (no-op split) must still write the
-        # parked block once or the grid-end flush emits garbage
+        # a launch whose first step is disabled (a no-op split, or a
+        # chunk wholly past the live steps) must still write the parked
+        # block once or the grid-end flush emits garbage
         rec_out_ref[...] = rec_in_ref[...]
 
 
-def _place_table(begin, pcnt, nleft, cl, cr, loff, roff,
-                 left_leaf, right_leaf, do_split, nt):
-    """[4*nt, 8] i32 placement step table (columns documented on
-    _place_kernel).  Lefts stream to [begin, begin+nleft), rights to
-    [begin+nleft, begin+pcnt); each tile's run may straddle two blocks
-    (lower + upper step).  Block indices are forward-filled monotone."""
+def _place_table(begin, nleft, cl, cr, loff, roff,
+                 left_leaf, right_leaf, do_split, nt, live):
+    """[8, 4*nt] i32 placement step table, one COLUMN a step (rows:
+    0 rec block, 1 comp tile*2 + half, 2 roll, 3/4 lane range, 5 merge
+    from the fetched block, 6 enabled, 7 leaf id).  Lefts stream to
+    [begin, begin+nleft), rights to [begin+nleft, begin+pcnt); each
+    tile's run may straddle two blocks (lower + upper step).  The
+    ``2*live`` left steps come first and the right steps follow them
+    directly, so the ``4*live`` live steps are a PREFIX of the table
+    (what lets place_runs run a run-time number of them); block indices
+    are forward-filled monotone."""
     T = TILE
 
-    def run_rows(gbase, counts, offs, half_flag, leaf_val):
+    def run_steps(gbase, counts, offs, half_flag, leaf_val):
         g = gbase + offs
         b = g // T
         s_ = g % T
@@ -885,30 +914,32 @@ def _place_table(begin, pcnt, nleft, cl, cr, loff, roff,
         has_up = (spill > 0).astype(jnp.int32)
         j2 = jnp.arange(nt, dtype=jnp.int32) * 2 + half_flag
         zeros = jnp.zeros_like(b)
-        lower = jnp.stack([
-            b, j2, s_, s_, jnp.minimum(end, T), zeros, has_lo,
-            jnp.full_like(b, leaf_val)], axis=1)
-        upper = jnp.stack([
-            b + has_up, j2, s_, zeros, jnp.maximum(spill, 0), zeros,
-            has_up, jnp.full_like(b, leaf_val)], axis=1)
-        return jnp.stack([lower, upper], axis=1).reshape(2 * nt, 8)
+        leaf = jnp.full_like(b, leaf_val)
+        lower = (b, j2, s_, s_, jnp.minimum(end, T), zeros, has_lo, leaf)
+        upper = (b + has_up, j2, s_, zeros, jnp.maximum(spill, 0), zeros,
+                 has_up, leaf)
+        # [8, nt, 2] -> [8, 2*nt]: a tile's lower step, then its upper
+        return jnp.stack(
+            [jnp.stack(lower), jnp.stack(upper)], axis=2).reshape(8, 2 * nt)
 
-    rowsL = run_rows(begin, cl, loff, 0, left_leaf)
-    rowsR = run_rows(begin + nleft, cr, roff, 1, right_leaf)
-    rows = jnp.concatenate([rowsL, rowsR])
-    enable = rows[:, 6] * do_split.astype(jnp.int32)
+    stepsL = run_steps(begin, cl, loff, 0, left_leaf)
+    stepsR = run_steps(begin + nleft, cr, roff, 1, right_leaf)
+    # counts past the live tiles are zero (_tile_counts), so every left
+    # step the right block overwrites, and every right step that lands
+    # past 4*live, is a disabled one
+    steps = jax.lax.dynamic_update_slice(
+        jnp.concatenate([stepsL, jnp.zeros_like(stepsR)], axis=1),
+        stepsR, (0, 2 * live))
+    enable = steps[6] * do_split.astype(jnp.int32)
     park = (begin // T).astype(jnp.int32)
-    idx_seq = jnp.where(enable > 0, rows[:, 0], -1)
+    idx_seq = jnp.where(enable > 0, steps[0], -1)
     idx_ff = jax.lax.cummax(
         jnp.concatenate([park[None], idx_seq])[None], axis=1)[0][1:]
     adv = (jnp.concatenate([park[None], idx_ff])[:-1] != idx_ff
            ).astype(jnp.int32)
-    # (each launch's first enabled row is forced to adv=1 in place_runs'
-    # chunk loop — chunk 0 covers the park-index case)
-    rows = rows.at[:, 0].set(idx_ff)
-    rows = rows.at[:, 5].set(adv)
-    rows = rows.at[:, 6].set(enable)
-    return rows
+    # (each launch's first enabled step is forced to adv=1 in
+    # place_runs' chunk loop — chunk 0 covers the park-index case)
+    return steps.at[0].set(idx_ff).at[5].set(adv).at[6].set(enable)
 
 
 @functools.partial(
@@ -926,16 +957,23 @@ def place_runs(
     leaf_row: int,
     interpret: bool = False,
     counts=None,  # (cl [nt], cr [nt]) from the split kernel's cnt out
+    live_tiles=None,  # run-time tile count <= cap // TILE (None = all)
 ):
-    """Scatter the compacted runs into the record in ONE aliased launch.
-    Interpret mode falls back to the (bit-identical, slower) XLA
-    scan-of-DUS placement so CPU tests stay meaningful; hardware parity
-    of the kernel path is pinned by tools/tpu_parity_check.py."""
+    """Scatter the compacted runs into the record, aliased in place.
+    ``live_tiles`` (an operand, as in split_step_window: it must cover
+    ``pcnt``, and ``counts`` past it must be zero) bounds how many of
+    the step table's ``4 * cap // TILE`` steps run: each launch takes a
+    run-time step count, and a table chunk wholly past the live steps
+    runs one parked step.  Interpret mode falls back to the
+    (bit-identical, slower) XLA scan-of-DUS placement so CPU tests stay
+    meaningful; hardware parity of the kernel path is pinned by
+    analysis/kernel_parity.py."""
     W, n_pad = rec.shape
     T = TILE
     nt = cap // T
     iota = jnp.arange(cap, dtype=jnp.int32)
     valid = (iota < pcnt).astype(jnp.int32)
+    live = _live_tiles(live_tiles, nt)
     if counts is not None:
         cl, cr = counts
     else:
@@ -953,24 +991,22 @@ def place_runs(
             begin, cap, leaf_row=leaf_row, left_leaf=left_leaf,
             right_leaf=right_leaf)
 
-    rows = _place_table(begin, pcnt, nleft, cl, cr, loff, roff,
-                        left_leaf, right_leaf, do_split, nt)
-    CHUNK = PLACE_CHUNK
+    steps = _place_table(begin, nleft, cl, cr, loff, roff,
+                         left_leaf, right_leaf, do_split, nt, live)
     total = 4 * nt
-    n_chunks = -(-total // CHUNK)
-    for c in range(n_chunks):
-        lo = c * CHUNK
-        sl = rows[lo: lo + CHUNK]
-        en_c = sl[:, 6]
-        # each launch's first enabled row must merge from the freshly
+    for lo in range(0, total, PLACE_CHUNK):
+        sl = steps[:, lo: lo + PLACE_CHUNK]
+        en_c = sl[6]
+        # each launch's first enabled step must merge from the freshly
         # fetched block: the previous launch's writes are flushed to
         # HBM at ITS grid end, not resident in this launch's windows
         first_c = ((jnp.cumsum(en_c) == 1) & (en_c > 0)).astype(jnp.int32)
-        sl = sl.at[:, 5].set(jnp.maximum(sl[:, 5], first_c))
-        steps = sl.shape[0]
+        sl = sl.at[5].set(jnp.maximum(sl[5], first_c))
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(steps,),
+            # at least one step, the parked block's identity write (a
+            # zero-step launch costs 13.4 us against this one's 14.3)
+            grid=(jnp.clip(4 * live - lo, 1, sl.shape[1]),),
             in_specs=[
                 pl.BlockSpec(
                     (1, W, 2 * T),
@@ -979,15 +1015,39 @@ def place_runs(
             ],
             out_specs=pl.BlockSpec((W, T), lambda i, sp: (0, sp[0, i])),
         )
-        with phase_scope(f"partition.place.cap{cap}"):
+        with phase_scope("partition.place.dyn"):
             rec = pl.pallas_call(
                 functools.partial(_place_kernel, W=W, leaf_row=leaf_row),
                 grid_spec=grid_spec,
                 out_shape=jax.ShapeDtypeStruct((W, n_pad), jnp.int32),
                 input_output_aliases={2: 0},  # rec (incl. the prefetch arg)
                 interpret=interpret,
-            )(sl.T, comp, rec)
+            )(sl, comp, rec)
     return rec
+
+
+def _live_tiles(live_tiles, nt: int):
+    """The run-time tile count as an i32 scalar in [1, nt]; ``None`` (a
+    caller whose window is static) means every tile.  At least one tile
+    always runs, so the kernels' block walks never see an empty range;
+    a tile past ``pcnt`` contributes masked zeros."""
+    if live_tiles is None:
+        return jnp.int32(nt)
+    return jnp.clip(jnp.asarray(live_tiles, jnp.int32), 1, nt)
+
+
+def _tile_counts(cnt, pcnt, live, nt: int):
+    """(cl [nt], cr [nt], nleft) from the split kernel's count row
+    [1, nt*128] (lane 0 of each 128-lane group = that tile's LEFT
+    count): per-tile valid counts come from pcnt alone — no go vector,
+    no record read.  Groups past ``live`` were never written by the
+    launch and hold whatever the buffer did: masked to zero here, which
+    is what a visited tile past pcnt would have counted."""
+    tile = jnp.arange(nt, dtype=jnp.int32)
+    cl = jnp.where(tile < live, cnt.reshape(nt, 128)[:, 0], 0)
+    vt = jnp.clip(pcnt - tile * TILE, 0, TILE)
+    cr = vt - cl
+    return cl, cr, jnp.sum(cl, dtype=jnp.int32)
 
 
 @functools.partial(
@@ -1010,6 +1070,7 @@ def split_step_window(
     return_comp: bool = False,
     interpret: bool = False,
     routing: str | None = None,  # compaction routing (None = ROUTING)
+    live_tiles=None,  # run-time tile count <= cap // TILE (None = all)
 ):
     """One-launch split step over window [begin, begin+cap): compaction
     + left-child histogram + subtract + two-child search + in-place
@@ -1024,8 +1085,19 @@ def split_step_window(
     record only through the kernel's block reads (on hardware, two
     T-aligned blocks roll-merged per tile — no materialized window
     slice), which is what lets the aliased placement (place_runs)
-    update the record in place across the tier-cond chain instead of
-    paying a full-record copy per split.
+    update the record in place instead of paying a full-record copy
+    per split.
+
+    ``cap`` sizes the buffers (``comp`` [cap // TILE, W, 2T], the
+    count row); ``live_tiles`` is how many of those tiles the launch
+    visits, an OPERAND: the grid is a dynamic bound, so one compiled
+    body serves every leaf size and the grower needs no ``lax.cond``
+    over capacities (whose result buffer cost two whole-record copies a
+    split, PERF.md PR 26/27).  It must cover ``pcnt``
+    (``ceil(pcnt / TILE)``; clamped to [1, cap // TILE] here); a caller
+    with a static window passes nothing and gets ``cap // TILE``.
+    Tiles past the live count are never written, so their counts are
+    masked here before anything reads them.
 
     The child leaf ids are stamped into the record's leaf-id row (see
     rec_height).  With ``return_comp`` the XLA placement (scan-of-DUS +
@@ -1044,20 +1116,21 @@ def split_step_window(
     i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
     b0 = i32(begin) // T
     roff_in = i32(begin) % T
+    live = _live_tiles(live_tiles, nt)
     scal_i = jnp.stack([
         i32(parent_slot), i32(parent_slot), i32(new_slot), i32(do_split),
         jnp.maximum(i32(f), 0), i32(thr), i32(is_cat), i32(pcnt),
-        b0, roff_in])
+        b0, roff_in, live])
 
     direct_read = not interpret
     off = 1 if direct_read else 0  # pipeline offset (see the kernel)
     # block walk of the single aliased record view: b0, b0+1, ..,
-    # b0+nt (clamped), parked on the last block for the tail steps
+    # b0+live (clamped), parked on the last block for the tail steps
     def _rec_idx(i, si, sf):
-        return (0, jnp.minimum(si[8] + jnp.minimum(i, nt), nblocks - 1))
+        return (0, jnp.minimum(si[8] + jnp.minimum(i, si[10]), nblocks - 1))
 
-    def _tile_idx(i):  # comp/cnt block for the tile processed at step i
-        return jnp.clip(i - off, 0, nt - 1)
+    def _tile_idx(i, si):  # comp/cnt block of the tile done at step i
+        return jnp.clip(i - off, 0, si[10] - 1)
 
     if direct_read:
         data_in = [rec]
@@ -1068,32 +1141,33 @@ def split_step_window(
         data_in = [jax.lax.dynamic_slice(rec, (0, begin), (W, cap))]
         data_specs = [
             pl.BlockSpec(
-                (W, T), lambda i, si, sf: (0, jnp.minimum(i, nt - 1))),
+                (W, T),
+                lambda i, si, sf: (0, jnp.minimum(i, si[10] - 1))),
         ]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nt + 2 + off,),
+        grid=(live + 2 + off,),  # a DYNAMIC bound: see the docstring
         in_specs=data_specs + [
             pl.BlockSpec(
                 (1, Fp, 4, Bp),
-                lambda i, si, sf: (jnp.where(i <= nt + off, si[0], si[2]),
-                                   0, 0, 0)),
+                lambda i, si, sf: (
+                    jnp.where(i <= si[10] + off, si[0], si[2]), 0, 0, 0)),
             pl.BlockSpec((Fp, 4), lambda i, si, sf: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec(
                 (1, Fp, 4, Bp),
-                lambda i, si, sf: (jnp.where(i <= nt + off, si[1], si[2]),
-                                   0, 0, 0)),
+                lambda i, si, sf: (
+                    jnp.where(i <= si[10] + off, si[1], si[2]), 0, 0, 0)),
             pl.BlockSpec((1, W, 2 * T),
-                         lambda i, si, sf: (_tile_idx(i), 0, 0)),
+                         lambda i, si, sf: (_tile_idx(i, si), 0, 0)),
             pl.BlockSpec((2, 16), lambda i, si, sf: (0, 0)),
             # counts ride the LANE axis: a (1, 128) block on [1, nt*128]
             # is Mosaic-legal (major dim == array dim), a [nt, 128]
             # row-per-tile layout is not (sublane dim 1)
             pl.BlockSpec((1, 128),
-                         lambda i, si, sf: (0, _tile_idx(i))),
+                         lambda i, si, sf: (0, _tile_idx(i, si))),
         ] + ([
             # aliased identity pass-through of the record (same block
             # walk as the input view): the output VALUE feeds
@@ -1115,10 +1189,10 @@ def split_step_window(
     if direct_read:
         out_shape.append(jax.ShapeDtypeStruct((W, n_pad), jnp.int32))
         aliases[2] = 4  # recA -> rec pass-through
-    with phase_scope(f"split_step.cap{cap}"):
+    with phase_scope("split_step.dyn"):
         outs = pl.pallas_call(
             functools.partial(
-                _split_step_kernel, W=W, F=F, k=k, Bp=Bp, nt=nt,
+                _split_step_kernel, W=W, F=F, k=k, Bp=Bp,
                 fgroup=fgroup, direct_read=direct_read, routing=routing),
             grid_spec=grid_spec,
             out_shape=out_shape,
@@ -1131,12 +1205,7 @@ def split_step_window(
         hists_new, comp, res, cnt = outs
         rec_pass = rec
 
-    # tile counts from the KERNEL: cl from the cnt output, per-tile
-    # valid counts from pcnt alone — no go vector, no record read
-    cl = cnt.reshape(nt, 128)[:, 0]
-    vt = jnp.clip(pcnt - jnp.arange(nt, dtype=jnp.int32) * T, 0, T)
-    cr = vt - cl
-    nleft = jnp.sum(cl, dtype=jnp.int32)
+    cl, cr, nleft = _tile_counts(cnt, pcnt, live, nt)
 
     if return_comp:
         return hists_new, comp, nleft, res, cl, cr, rec_pass
