@@ -71,6 +71,7 @@ import jax.numpy as jnp
 from ..models.tree import Tree
 from ..obs import telemetry
 from ..ops.split import K_MIN_SCORE, find_best_split, find_best_split_leaves
+from ..ops.totals import root_totals
 from .serial import (
     _BF, _BG, _BLC, _BLDEP, _BLO, _BLPAR, _BLSG, _BLSH, _BLV, _BLCNT,
     _BRC, _BRO, _BROWS, _BRSG, _BRSH, _BT,
@@ -181,15 +182,11 @@ def make_grow_forest(num_bins: int, max_leaves: int, impl: str = "batched",
 
         # ---- root (mirrors serial.py's BeforeTrain block, one lane each)
         hist0 = _batched_hist(bT, grad, hess, bag_mask, num_bins)
-        # per-lane ONE-segment segment-sums, mirroring serial.py's root:
-        # scatter order makes the sums invariant to interleaved zero-mask
-        # rows, which the parity pins (stacked-vs-loop, cv bin-once)
-        # depend on; jnp.sum's shape-dependent reduction tree is not
-        gh0 = jax.vmap(
-            lambda x: jax.ops.segment_sum(
-                x, jnp.zeros(x.shape[0], jnp.int32), num_segments=1)[0]
-        )(jnp.stack([grad * bag_mask, hess * bag_mask], axis=-1))
-        sum_g0, sum_h0 = gh0[:, 0], gh0[:, 1]
+        # per-lane root totals, the serial root's (ops/totals.py): exact
+        # up to a fixed grid, so accurate and the same bits whatever
+        # zero-mask rows are interleaved -- the parity pins
+        # (stacked-vs-loop, cv bin-once) depend on that
+        sum_g0, sum_h0 = root_totals(grad, hess, bag_mask)
         cnt0 = jnp.sum(bag_mask, axis=1)
         can0 = (params.max_depth <= 0) | (0 < params.max_depth)
         root_best = _search_root(

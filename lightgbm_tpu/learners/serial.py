@@ -69,6 +69,7 @@ from ..obs.device_time import phase_scope
 from ..ops.histogram import histogram_by_leaf, histogram_feature_major
 from ..ops.split import (
     SplitResult, find_best_split, find_best_split_leaves, K_MIN_SCORE)
+from ..ops.totals import root_totals
 
 
 # leaf_count/internal_count ride the histogram count channel, which is
@@ -519,7 +520,7 @@ def grow_tree(
             _pack_scal as _search_pack_scal,
         )
         # mega split-step kernel (ops/record.py split_step_window):
-        # compaction + LEFT-child histogram + both searches + in-place
+        # compaction + SMALLER-child histogram + both searches + in-place
         # buffer updates in ONE launch, dropping the separate
         # smaller-child histogram launch and its whole h_tier cond
         # chain.  Gated on the hist block fitting comfortably in VMEM
@@ -582,22 +583,21 @@ def grow_tree(
         if init_tree is None:
             # ---- root (BeforeTrain / LeafSplits::Init, leaf_splits.hpp:51-92)
             hist0 = hist_fn(bins_T, grad, hess, bag_mask)
-            # root Σg/Σh via a ONE-segment segment-sum, not jnp.sum: scatter
-            # accumulates per row in order, so a masked-out row adds an exact
-            # ±0.0 that never perturbs the accumulator.  jnp.sum's reduction
-            # tree regroups with n, making the root sums depend on how many
-            # DEAD rows ride along — which would break the base-row-mask
-            # parity contract (cv bin-once trains fold boosters on the full
-            # matrix and pins their metrics bitwise to subset-trained ones)
-            # and the batched forest grower's stacked-vs-loop parity pin.
+            # root Σg/Σh: exact up to a fixed grid (ops/totals.py), so
+            # ACCURATE -- the root's gain and every categorical
+            # ``total - bin`` read these two, and a row-by-row float32
+            # accumulation read a varying hessian 0.13% high at 9M rows,
+            # which the first leaf's chain inherited whole -- and
+            # INDEPENDENT OF ORDER: a masked-out row adds an exact 0.0
+            # wherever it rides along, which the base-row-mask contract
+            # (cv bin-once trains fold boosters on the full matrix and
+            # pins their metrics bitwise to subset-trained ones) and the
+            # batched forest grower's stacked-vs-loop pin rest on.
+            # tests/test_root_totals.py holds both properties,
+            # tests/test_reference_agreement.py the leaves that follow.
             # cnt0 stays jnp.sum: counts are exact small integers in any
             # grouping.
-            gh0 = jax.ops.segment_sum(
-                jnp.stack([grad * bag_mask, hess * bag_mask], axis=-1),
-                jnp.zeros(grad.shape[0], jnp.int32),
-                num_segments=1,
-            )[0]
-            sum_g0, sum_h0 = gh0[0], gh0[1]
+            sum_g0, sum_h0 = root_totals(grad, hess, bag_mask)
             cnt0 = jnp.sum(bag_mask)
             if reduce_fn is not None:
                 # one stacked collective for the tree-start allreduce
@@ -786,7 +786,7 @@ def grow_tree(
             gate = pcol[2]
         mega_res = None
         if opt_fused and fuse_hist:
-            # MEGA split step: compaction + left-child histogram + both
+            # MEGA split step: compaction + smaller-child histogram + both
             # searches + in-place hists-row updates, ONE launch (the
             # round-4 profile showed the loop bound by per-split
             # dispatch, not op work).  depth gate + per-split scalars

@@ -71,8 +71,10 @@ SCOPES = (
      "thresholds (models/gbdt.py)"),
     ("lgbm.gradients", "the objective's jitted gradient programs"),
     ("lgbm.predict", "matmul prediction (ops/predict_matmul.py)"),
-    ("lgbm.grow.root", "grow_tree before the loop: root totals, root "
-     "histogram, first search, initial state"),
+    ("lgbm.grow.root", "grow_tree before the loop: root histogram, first "
+     "search, initial state"),
+    ("lgbm.root_totals", "the root's sum of gradients and hessians "
+     "(ops/totals.py), inside lgbm.grow.root"),
     ("lgbm.grow.loop", "the fori_loop itself: its carry and whatever of "
      "the body no inner scope names"),
     ("lgbm.grow.select", "body's argmax and the column reads and scalar "

@@ -45,6 +45,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..device import on_tpu
 from ..obs.device_time import phase_scope
+from .totals import two_sum
 
 DEFAULT_CHUNK = 1024
 FGROUP = 8  # feature rows per kernel loop step (int8 sublane-pack aligned)
@@ -113,7 +114,13 @@ def _kernel_variant(variant: str | None = None) -> str:
     return v
 
 
-def _hist_kernel_v1(leaf_of_chunk, bins_ref, stats_ref, out_ref, *, num_f, num_b, chunk):
+# Chunks between two folds of the v1 kernel's small accumulator into the
+# output block (see _hist_kernel_v1): 8,192 rows at the 512-row chunk.
+FOLD_CHUNKS = 16
+
+
+def _hist_kernel_v1(leaf_of_chunk, bins_ref, stats_ref, out_ref, acc_ref,
+                    lo_ref, *, num_f, num_b, chunk):
     """One grid step = one C-row chunk of a single leaf.
 
     bins_ref:  [F, C] uint8 (this chunk's bins, feature-major)
@@ -121,14 +128,30 @@ def _hist_kernel_v1(leaf_of_chunk, bins_ref, stats_ref, out_ref, *, num_f, num_b
     out_ref:   [1, F, 4, B] f32 block at row ``leaf_of_chunk[c]`` —
                revisited (and therefore VMEM-resident) across all chunks
                of the same leaf.
+    acc_ref:   [F, 4, B] f32 scratch — the last few chunks' sum
+    lo_ref:    [F, 4, B] f32 scratch — what the folds' roundings lost
+
+    The chunks do not add into ``out_ref`` one by one: at nine million
+    rows that is 17,000 roundings at the size a bin has reached, and a
+    bin of four million rows ends tens of ulps off — the error every
+    sibling-by-subtraction below inherits (PERF.md, PR 28).  They add
+    into ``acc_ref``; every FOLD_CHUNKS chunks that folds into
+    ``out_ref`` with the rounding's error kept in ``lo_ref``
+    (ops/totals.py two_sum), and the leaf's last chunk adds ``lo_ref`` back: the bin is
+    the correctly rounded sum of the chunks' partial sums.  The
+    per-feature loop is as it was.
     """
     c = pl.program_id(0)
-    prev = leaf_of_chunk[jnp.maximum(c - 1, 0)]
-    is_first = (c == 0) | (leaf_of_chunk[c] != prev)
+    last = pl.num_programs(0) - 1
+    leaf = leaf_of_chunk[c]
+    is_first = (c == 0) | (leaf != leaf_of_chunk[jnp.maximum(c - 1, 0)])
+    is_last = (c == last) | (leaf != leaf_of_chunk[jnp.minimum(c + 1, last)])
 
     @pl.when(is_first)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        lo_ref[...] = jnp.zeros_like(lo_ref)
 
     stats = stats_ref[...]  # [STAT_ROWS, C]
     iota_b = jax.lax.broadcasted_iota(jnp.int32, (chunk, num_b), 1)
@@ -150,10 +173,18 @@ def _hist_kernel_v1(leaf_of_chunk, bins_ref, stats_ref, out_ref, *, num_f, num_b
                 stats, onehot, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ))  # [4, B]
-            out_ref[0, g * FGROUP + i] = out_ref[0, g * FGROUP + i] + contrib
+            acc_ref[g * FGROUP + i] = acc_ref[g * FGROUP + i] + contrib
         return 0
 
     jax.lax.fori_loop(0, num_groups, group_body, 0)
+
+    @pl.when(is_last | (c % FOLD_CHUNKS == FOLD_CHUNKS - 1))
+    def _():
+        t, err = two_sum(out_ref[0], acc_ref[...])
+        lo = lo_ref[...] + err
+        out_ref[0] = jnp.where(is_last, t + lo, t)
+        lo_ref[...] = lo
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
 
 def _hist_kernel_bsub(leaf_of_chunk, bins_ref, stats_ref, out_ref, *, num_b, chunk):
@@ -228,6 +259,7 @@ def _hist_pallas_call(
             out_specs=pl.BlockSpec(
                 (1, Fp, 4, B), lambda c, leaf_ref: (leaf_ref[c], 0, 0, 0)
             ),
+            scratch_shapes=[pltpu.VMEM((Fp, 4, B), jnp.float32)] * 2,
         )
         with phase_scope(f"histogram.cap{n_chunks * C}"):
             out = pl.pallas_call(

@@ -67,6 +67,16 @@ def _tail_of(x, tri):
     )
 
 
+def _head_of(x, tri):
+    """Inclusive prefix sums along bins: head[., t] = sum_{b<=t} x[., b],
+    the same dot against the complement of ``tri``.  A numerical left
+    side is read from here and not as ``total - tail``: a small left
+    child under a large node would keep the absolute rounding of the
+    node's totals, and of every ancestor's down a chain of left children
+    (ops/split.py does the same with a cumsum)."""
+    return _tail_of(x, 1.0 - tri)
+
+
 def _pack_meta(feature_mask, num_bins_per_feature, is_categorical, Fp):
     """[F] feature metadata -> the kernels' [Fp, 4] i32 operand (padded
     features get feature_mask 0 and never validate)."""
@@ -82,10 +92,12 @@ def _pack_meta(feature_mask, num_bins_per_feature, is_categorical, Fp):
     return meta
 
 
-def _child_search(c, hg, hh, hc, tg, th, tc, scal_ref, meta_ref, out_ref,
-                  F, B):
-    """One child's full search given its stat planes [F, B] and their
-    exclusive suffix sums; writes the child's [1, 16] result row.
+def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
+                  out_ref, F, B):
+    """One child's full search given its stat planes [F, B], their
+    exclusive suffix sums and the inclusive prefix sums of gradient and
+    hessian (the count's prefix is ``cnt_t - tc``, exact either way);
+    writes the child's [1, 16] result row.
 
     Mosaic-friendly shapes only: [F, B] / [F, 1] vectors, TRUE scalars
     from the SMEM-prefetched ``scal_ref`` (scalar splats broadcast
@@ -117,8 +129,8 @@ def _child_search(c, hg, hh, hc, tg, th, tc, scal_ref, meta_ref, out_ref,
     sh_t = scal_ref[4 * c + 2]
     cnt_t = scal_ref[4 * c + 3]
 
-    left_g = jnp.where(iscat, hg, sg_t - tg)
-    left_h = jnp.where(iscat, hh, sh_t - th)
+    left_g = jnp.where(iscat, hg, pg)
+    left_h = jnp.where(iscat, hh, ph)
     left_c = jnp.where(iscat, hc, cnt_t - tc)
     right_g = jnp.where(iscat, sg_t - hg, tg)
     right_h = jnp.where(iscat, sh_t - hh, th)
@@ -183,9 +195,11 @@ def _search2_kernel(scal_ref, hist_ref, meta_ref, out_ref, *, F, B):
     """
     h = hist_ref[...]  # [6F, B]
     # tail[row, t] = sum_{b > t} h[row, b] for ALL six (child, stat) rows
-    tail = _tail_of(h, _tri(B))  # [6F, B]
+    tri = _tri(B)
+    tail = _tail_of(h, tri)  # [6F, B]
     for c in range(2):
         base = c * 3 * F
+        head = _head_of(h[base:base + 2 * F], tri)  # gradient, hessian
         _child_search(
             c,
             h[base:base + F], h[base + F:base + 2 * F],
@@ -193,6 +207,7 @@ def _search2_kernel(scal_ref, hist_ref, meta_ref, out_ref, *, F, B):
             tail[base:base + F],
             tail[base + F:base + 2 * F] + K_EPSILON,  # kEpsilon seed
             tail[base + 2 * F:base + 3 * F],
+            head[:F], head[F:],
             scal_ref, meta_ref, out_ref, F, B,
         )
 
@@ -211,7 +226,7 @@ def _search2_kernel_raw(scal_ref, hist_ref, meta_ref, out_ref, *, F, B):
         _child_search(
             c, hg, hh, hc,
             _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
-            _tail_of(hc, tri),
+            _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
             scal_ref, meta_ref, out_ref, F, B,
         )
 
@@ -346,7 +361,7 @@ def _fused_kernel(scal_i_ref, scal_f_ref, hrow_ref, hsmall_ref, meta_ref,
             _child_search(
                 cc, hg, hh, hc,
                 _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
-                _tail_of(hc, tri),
+                _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
                 scal_f_ref, meta_ref, res_ref, F, B,
             )
 

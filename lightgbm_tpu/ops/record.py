@@ -68,6 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..obs.device_time import phase_scope
+from .totals import two_sum
 
 import os as _os
 
@@ -230,7 +231,9 @@ def _tile_go(tile, scal_i_ref, i, *, F, k):
     (profiled ~300 ms/tree at 10M rows: {0,1:T(1,128)} ->
     {1,0:T(8,128)} relayouts of every tier's column).
 
-    Returns [1, T] f32: 1.0 = left AND valid (rows past pcnt are 0).
+    Returns two [1, T] f32 rows: 1.0 = left AND valid (rows past pcnt
+    are 0), and the validity row itself (the rows of the parent that go
+    RIGHT are ``valid - go``).
     scal_i layout: (.., .., .., .., f, thr, is_cat, pcnt) — indices 4-7.
     """
     T = TILE
@@ -254,7 +257,7 @@ def _tile_go(tile, scal_i_ref, i, *, F, k):
     # ARITHMETIC select: an i1-on-i1 arith.select fails legalization
     go = is_cat * (fv == thr).astype(jnp.int32) + (1 - is_cat) * (
         fv <= thr).astype(jnp.int32)
-    return (go * valid).astype(jnp.float32)
+    return (go * valid).astype(jnp.float32), valid.astype(jnp.float32)
 
 
 def _resolve_routing(routing):
@@ -474,12 +477,12 @@ def _compact_kernel_prefix(win_ref, grow_ref, out_ref, *, W):
 
 def _hist_tile_body(tile, scal_i_ref, hacc_set, *, W, F, k, Bp,
                     govf, fgroup=8):
-    """Shared left-child histogram accumulation over one [W, T] record
-    tile (used by _split_step_kernel via _split_tile).  The split
-    decision ``govf`` is the SAME [1, T] row the compaction used
-    (_tile_go); stats stack on sublanes; the one-hot is born transposed
-    against a sublane iota and contracts the shared lane axis on the
-    MXU — no relayouts.
+    """Shared child histogram accumulation over one [W, T] record
+    tile (used by _split_step_kernel via _split_tile).  ``govf`` [1, T]
+    flags the rows of the child that is accumulated: the smaller one's,
+    from the SAME decision row the compaction used (_tile_go); stats
+    stack on sublanes; the one-hot is born transposed against a sublane
+    iota and contracts the shared lane axis on the MXU — no relayouts.
 
     ``hacc_set(fi, contrib)`` accumulates [4, Bp] into feature row fi.
     scal_i layout: (.., .., .., .., f, thr, is_cat, pcnt) — indices 4-7.
@@ -496,7 +499,7 @@ def _hist_tile_body(tile, scal_i_ref, hacc_set, *, W, F, k, Bp,
         tile[Wb + 1: Wb + 2, :], jnp.float32)
     mrow = jax.lax.bitcast_convert_type(
         tile[Wb + 2: Wb + 3, :], jnp.float32)
-    mw = mrow * govf  # bagging mask restricted to the left child
+    mw = mrow * govf  # bagging mask restricted to the accumulated child
     # exact three-piece bf16 split of the stat rows: one MXU pass at
     # float32 accuracy (see pallas_histogram.split_stats)
     stats = split_stats(jnp.concatenate(
@@ -678,14 +681,37 @@ def write_window(rec, out_win, begin, cap: int, interpret: bool = False):
         )(scal, out_win, out_win, rec)
 
 
-def _split_tile(tile, scal_i_ref, j, comp_ref, cnt_ref, hacc_ref, *,
+# Tiles between two folds of the split step's small accumulator into
+# its two-float running sum (see _fold_hacc): 8,192 rows at TILE=512.
+FOLD_TILES = 16
+
+
+def _fold_hacc(hacc_ref, hhi_ref, hlo_ref):
+    """Fold the tiles accumulated in ``hacc_ref`` into the running sum
+    ``hhi + hlo`` and clear it.  A float32 accumulator that takes every
+    tile itself rounds once a tile at the size the bin has reached, and
+    a bin of a million rows is then off by tens of ulps, which the
+    sibling (parent minus this child) and every small leaf below
+    inherit whole (PERF.md, PR 28).  Here the roundings at full size
+    happen once in FOLD_TILES tiles and each one's error is kept
+    (ops/totals.py two_sum), so the bin the search reads is the correctly rounded sum
+    of the tiles' exact partial sums.  The hot per-feature loop is
+    unchanged: it still adds into ``hacc_ref`` alone."""
+    hhi_ref[...], err = two_sum(hhi_ref[...], hacc_ref[...])
+    hlo_ref[...] = hlo_ref[...] + err
+    hacc_ref[...] = jnp.zeros_like(hacc_ref)
+
+
+def _split_tile(tile, scal_i_ref, small_left, j, comp_ref, cnt_ref,
+                hacc_ref, hhi_ref, hlo_ref, *,
                 W, F, k, Bp, fgroup, routing=None):
     """Per-tile work of the split step: ONE in-kernel go computation
     (no [cap, 1] column operand from XLA — see _tile_go) shared by the
     compaction (prefix or one-hot, per ``routing``), the per-tile
-    left-count output, and the left-child histogram accumulation.
-    ``j`` is the tile ordinal (validity)."""
-    govf = _tile_go(tile, scal_i_ref, j, F=F, k=k)
+    left-count output, and the histogram accumulation of the SMALLER
+    child (``small_left`` 1.0 or 0.0: the left-going rows, or the valid
+    rows that do not go left).  ``j`` is the tile ordinal (validity)."""
+    govf, valid = _tile_go(tile, scal_i_ref, j, F=F, k=k)
     comp_ref[0] = _compact_body(tile, govf, W, routing=routing)
     cnt_ref[...] = jnp.zeros((1, 128), jnp.int32) + jnp.sum(
         govf).astype(jnp.int32)
@@ -694,7 +720,13 @@ def _split_tile(tile, scal_i_ref, j, comp_ref, cnt_ref, hacc_ref, *,
         hacc_ref[fi] = hacc_ref[fi] + contrib
 
     _hist_tile_body(tile, scal_i_ref, hacc_set, W=W, F=F, k=k,
-                    Bp=Bp, fgroup=fgroup, govf=govf)
+                    Bp=Bp, fgroup=fgroup,
+                    govf=small_left * govf
+                    + (1.0 - small_left) * (valid - govf))
+
+    @pl.when(j % FOLD_TILES == FOLD_TILES - 1)
+    def _():
+        _fold_hacc(hacc_ref, hhi_ref, hlo_ref)
 
 
 def _split_step_kernel(
@@ -702,7 +734,7 @@ def _split_step_kernel(
     W, F, k, Bp, fgroup=8, direct_read=False, routing=None,
 ):
     """The WHOLE split step in one launch: per-tile MXU compaction +
-    left-child histogram accumulation (steps 0..nt-1), then subtract +
+    smaller-child histogram accumulation (steps 0..nt-1), then subtract +
     two-child search + in-place histogram-buffer row updates (steps nt
     and nt+1) — the union of the tile compaction and
     pallas_search._fused_kernel, eliminating one launch (13-14 us on a
@@ -739,18 +771,27 @@ def _split_step_kernel(
     cnt_ref    : [1, 128] i32 per tile — lane 0 carries this tile's
                  LEFT count, so the XLA side derives cl/cr/nleft with
                  no go vector (and no record read) at all
-    hacc_ref   : VMEM scratch — left-child histogram accumulator, then
-                 the right-child stash between the last two steps
+    hacc_ref   : VMEM scratch — the smaller child's histogram over the
+                 last few tiles (which child: the one with fewer bagged
+                 rows by the search's own exact counts, scal_f[3] and
+                 [7], as LightGBM takes the smaller leaf's rows and its
+                 sibling by subtraction: a sibling got from the LARGER
+                 child keeps the absolute rounding of two large sums in
+                 bins a hundredth their size), then the right-child
+                 stash between the last two steps
+    hhi_ref, hlo_ref : VMEM scratch — that histogram's running sum as
+                 two floats (_fold_hacc)
     """
-    from .pallas_search import K_EPSILON, _child_search, _tail_of, _tri
+    from .pallas_search import (
+        K_EPSILON, _child_search, _head_of, _tail_of, _tri)
 
     if direct_read:
         (rec_ref, hrow_ref, meta_ref, hists_out_ref,
          comp_ref, res_ref, cnt_ref, rec_out_ref, hacc_ref,
-         prev_ref) = refs
+         hhi_ref, hlo_ref, prev_ref) = refs
     else:
         (win_ref, hrow_ref, meta_ref, hists_out_ref, comp_ref,
-         res_ref, cnt_ref, hacc_ref) = refs
+         res_ref, cnt_ref, hacc_ref, hhi_ref, hlo_ref) = refs
 
     T = TILE
     i = pl.program_id(0)
@@ -760,9 +801,15 @@ def _split_step_kernel(
     search_step = nt + off
     last_step = nt + 1 + off
 
+    small_left_b = scal_f_ref[3] <= scal_f_ref[7]
+    small_left = jnp.where(small_left_b, 1.0, 0.0)
+    sums = (hacc_ref, hhi_ref, hlo_ref)
+
     @pl.when(i == 0)
     def _():
         hacc_ref[...] = jnp.zeros_like(hacc_ref)
+        hhi_ref[...] = jnp.zeros_like(hhi_ref)
+        hlo_ref[...] = jnp.zeros_like(hlo_ref)
 
     if direct_read:
         @pl.when(i <= nt)
@@ -788,9 +835,9 @@ def _split_step_kernel(
                 lane = jax.lax.broadcasted_iota(jnp.int32, (W, T), 1)
                 m = (lane < (T - r)).astype(jnp.int32)
                 tile = ra * m + rb * (1 - m)
-                _split_tile(tile, scal_i_ref, i - 1, comp_ref, cnt_ref,
-                            hacc_ref, W=W, F=F, k=k, Bp=Bp,
-                            fgroup=fgroup, routing=routing)
+                _split_tile(tile, scal_i_ref, small_left, i - 1,
+                            comp_ref, cnt_ref, *sums, W=W, F=F, k=k,
+                            Bp=Bp, fgroup=fgroup, routing=routing)
 
             prev_ref[...] = cur
     else:
@@ -802,9 +849,9 @@ def _split_step_kernel(
             # step) is an identity write, never garbage over a row the
             # search still needs
             hists_out_ref[0] = hrow_ref[0]
-            _split_tile(win_ref[...], scal_i_ref, i, comp_ref, cnt_ref,
-                        hacc_ref, W=W, F=F, k=k, Bp=Bp, fgroup=fgroup,
-                        routing=routing)
+            _split_tile(win_ref[...], scal_i_ref, small_left, i,
+                        comp_ref, cnt_ref, *sums, W=W, F=F, k=k, Bp=Bp,
+                        fgroup=fgroup, routing=routing)
 
     @pl.when(i >= nt + off)
     def _():
@@ -817,8 +864,11 @@ def _split_step_kernel(
     @pl.when(i == search_step)
     def _():
         parent = hrow_ref[0]  # [Fp, 4, Bp]
-        h_left = hacc_ref[...]
-        h_right = parent - h_left
+        _fold_hacc(*sums)
+        h_small = hhi_ref[...] + hlo_ref[...]
+        h_large = parent - h_small
+        h_left = jnp.where(small_left_b, h_small, h_large)
+        h_right = jnp.where(small_left_b, h_large, h_small)
         hists_out_ref[0] = jnp.where(do_split, h_left, parent)
         hacc_ref[...] = h_right  # stash for the final step
 
@@ -830,7 +880,7 @@ def _split_step_kernel(
             _child_search(
                 cc, hg, hh, hc,
                 _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
-                _tail_of(hc, tri),
+                _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
                 scal_f_ref, meta_ref, res_ref, hacc_ref.shape[0], B,
             )
 
@@ -1073,7 +1123,7 @@ def split_step_window(
     live_tiles=None,  # run-time tile count <= cap // TILE (None = all)
 ):
     """One-launch split step over window [begin, begin+cap): compaction
-    + left-child histogram + subtract + two-child search + in-place
+    + smaller-child histogram + subtract + two-child search + in-place
     hists-row updates.  Returns (hists', rec', nleft, res[2, 16]) — or,
     with ``return_comp``, (hists', comp, nleft, res, cl, cr, rec_pass)
     where ``rec_pass`` is the kernel's aliased record pass-through that
@@ -1175,7 +1225,7 @@ def split_step_window(
             # single-use — see the kernel docstring's copy note
             pl.BlockSpec((W, T), _rec_idx),
         ] if direct_read else []),
-        scratch_shapes=[pltpu.VMEM((Fp, 4, Bp), jnp.float32)] + (
+        scratch_shapes=[pltpu.VMEM((Fp, 4, Bp), jnp.float32)] * 3 + (
             [pltpu.VMEM((W, T), jnp.int32)] if direct_read else []),
     )
     hists_idx = 2 + len(data_in)  # incl. the 2 prefetch args

@@ -7,8 +7,11 @@ FindBestThresholdForCategorical, feature_histogram.hpp:187-246) with one
 masked reduction over the whole [F, B] candidate grid:
 
 * numerical: right-side sums via reverse cumulative sums over the bin
-  axis; left = leaf totals - right (exactly the reference's accumulation
-  order, including the kEpsilon seed on the right hessian).
+  axis (the reference's accumulation order, including the kEpsilon seed
+  on the right hessian); left-side gradient and hessian via forward
+  cumulative sums, where the reference takes ``leaf totals - right`` in
+  float64: in float32 that subtraction hands a node's absolute rounding
+  to a small left child.  Counts are exact and stay ``total - right``.
 * categorical: one-vs-rest — "left" is the single bin == threshold.
 * gain/leaf-output formulas with L1/L2 regularization mirror
   GetLeafSplitGain / CalculateSplittedLeafOutput
@@ -109,7 +112,15 @@ def find_best_split(
 
     # ---- categorical one-vs-rest: "left" is the single bin t
     is_cat3 = is_categorical[:, None, None]
-    left = jnp.where(is_cat3, hist, tot - tail)  # [F, B, 3]
+    # a numerical left side's gradient and hessian are the histogram's
+    # own prefix, not ``tot - tail``: a small left child under a large
+    # node would otherwise keep the absolute rounding of the node's
+    # totals, and of every ancestor's down a chain of left children
+    # (PERF.md, PR 28).  The count is exact either way and stays
+    # ``tot - tail`` (one prefix fewer in the kernels, which mirror this)
+    head = jnp.concatenate(
+        [jnp.cumsum(hist[..., :2], axis=1), (tot - tail)[..., 2:]], axis=-1)
+    left = jnp.where(is_cat3, hist, head)  # [F, B, 3]
     right = jnp.where(is_cat3, tot - hist, tail)
 
     left_h, left_c = left[..., 1], left[..., 2]

@@ -93,7 +93,7 @@ def test_scopes_and_kernel_names_in_the_compiled_grower(grower):
     assert scopes >= {s for s in dt.SCOPE_NAMES if ".grow." in s} - {
         "lgbm.grow.tier"} | {
         "lgbm.histogram", "lgbm.split_step", "lgbm.partition",
-        "lgbm.split_search"}
+        "lgbm.split_search", "lgbm.root_totals"}
     assert "lgbm.grow.tier" not in scopes
     calls = re.findall(
         r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"",

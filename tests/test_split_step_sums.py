@@ -1,0 +1,125 @@
+"""Where a child's sums come from (PR 28): the fused split step takes the
+SMALLER child's histogram from its rows and the sibling by subtraction,
+its accumulator folds into a two-float running sum, and a numerical left
+side's gradient and hessian are the histogram's own prefix.  Each was a
+way for a small leaf under a large node to inherit the large node's
+absolute rounding once hessians vary (PERF.md, PR 28).  Interpret mode,
+on the CPU.
+"""
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+import lightgbm_tpu.ops.record as R
+from lightgbm_tpu.ops.pallas_search import (
+    _pack_meta, _pack_scal, search2_pallas)
+from lightgbm_tpu.ops.split import find_best_split
+
+_F, _B = 6, 16
+_T = R.TILE
+_K = R.bins_per_word(jnp.uint8)
+_FP, _BP = R.round_up(_F, 8), R.round_up(_B, 128)
+
+
+def _window(n, thr, seed=0):
+    """A record of ``n`` live rows whose split ``feature 2 <= thr``
+    sends few rows one way, with hessians like a second binary tree's."""
+    rng = np.random.RandomState(seed)
+    bins = rng.randint(0, _B, (_F, n)).astype(np.uint8)
+    g = rng.randn(n).astype(np.float32)
+    h = (0.9987 + 1e-3 * rng.randn(n)).astype(np.float32)
+    rec = R.build_record(jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h),
+                         jnp.ones(n, jnp.float32), R.round_up(n, _T) + _T)
+    left = bins[2] <= thr
+    return rec, bins, g, h, left
+
+
+def _hist64(bins, g, h, rows):
+    """[F, 3, B] float64 histogram of ``rows`` (bool)."""
+    out = np.zeros((_F, 3, _B))
+    for f in range(_F):
+        for s, v in enumerate((g, h, np.ones_like(g))):
+            out[f, s] = np.bincount(bins[f, rows], v[rows].astype(np.float64),
+                                    _B)
+    return out
+
+
+def _step(rec, parent, n, thr, lc, rc):
+    hists = np.zeros((3, _FP, 4, _BP), np.float32)
+    hists[0, :_F, :3, :_B] = parent
+    scal_f = _pack_scal(*[jnp.float32(x) for x in (
+        1., 0., 1., lc, 0., 1., rc, 1., 0., 0., 0., 0.)])
+    meta = _pack_meta(jnp.ones(_F, bool), jnp.full(_F, _B, jnp.int32),
+                      jnp.zeros(_F, bool), _FP)
+    cap = R.round_up(n, _T)
+    hs, _, nleft, _ = R.split_step_window(
+        jnp.asarray(hists), rec, jnp.int32(0), jnp.int32(n), jnp.bool_(True),
+        jnp.int32(2), jnp.int32(thr), jnp.bool_(False), jnp.int32(0),
+        jnp.int32(2), scal_f, meta, F=_F, cap=cap, k=_K, interpret=True)
+    hs = np.asarray(hs)
+    return hs[0, :_F, :3, :_B], hs[2, :_F, :3, :_B], int(nleft)
+
+
+@pytest.mark.parametrize("small", ["left", "right"])
+def test_the_smaller_child_is_summed_and_the_larger_subtracted(small):
+    n = 6 * _T + 17
+    thr = 0 if small == "left" else _B - 2
+    rec, bins, g, h, left = _window(n, thr)
+    parent = _hist64(bins, g, h, np.ones(n, bool)).astype(np.float32)
+    hl, hr, nleft = _step(rec, parent, n, thr, left.sum(), (~left).sum())
+    assert nleft == left.sum() and min(nleft, n - nleft) < n // 8
+    got_small, got_large = (hl, hr) if small == "left" else (hr, hl)
+    want = _hist64(bins, g, h, left if small == "left" else ~left)
+    # the smaller child to float32's own rounding of ITS bins (against
+    # the sum of magnitudes: gradients cancel) ...
+    size = _hist64(bins, np.abs(g), h, left if small == "left" else ~left)
+    assert (np.abs(got_small - want) <= 3e-7 * size).all()
+    # (by subtraction it would carry the parent's: bins 16x the size)
+    # ... and the larger by subtraction from the parent, bit for bit
+    assert (parent - got_small).tobytes() == got_large.tobytes()
+
+
+def test_a_bin_of_many_tiles_is_rounded_once():
+    """More tiles than one fold holds, every row in one bin of feature
+    0: the bin is the float64 sum rounded to float32, not a chain of
+    float32 additions."""
+    n = (2 * R.FOLD_TILES + 3) * _T
+    rec, bins, g, h, left = _window(n, _B // 2, seed=3)
+    parent = _hist64(bins, g, h, np.ones(n, bool)).astype(np.float32)
+    hl, hr, nleft = _step(rec, parent, n, _B // 2, left.sum(), (~left).sum())
+    small, rows = (hl, left) if left.sum() <= (~left).sum() else (hr, ~left)
+    want = _hist64(bins, g, h, rows)
+    assert np.abs(small[:, 1] - want[:, 1].astype(np.float32)).max() <= \
+        np.spacing(np.float32(want[:, 1].max()))
+    np.testing.assert_array_equal(small[:, 2], want[:, 2])
+
+
+@pytest.mark.parametrize("impl", ["jnp", "pallas"])
+def test_a_small_left_side_keeps_its_own_digits(impl):
+    """Two bins: 1e-3 of hessian left of the threshold, a million right
+    of it.  ``total - right`` in float32 would answer 0 or 0.0625."""
+    F, B = 8, 16
+    hist = np.zeros((F, B, 3), np.float32)
+    hist[:, 0] = (-0.25e-3, 1.0e-3, 5.0)
+    hist[:, 1] = (3.0e5, 1.0e6, 5.0)
+    tot = hist[0].sum(axis=0, dtype=np.float64).astype(np.float32)
+    fmask, nbpf, iscat = np.ones(F, bool), np.full(F, 2, np.int32), \
+        np.zeros(F, bool)
+    z, one = jnp.float32(0.0), jnp.float32(1.0)
+    if impl == "jnp":
+        res = find_best_split(
+            jnp.asarray(hist), *map(jnp.float32, tot), jnp.asarray(fmask),
+            jnp.asarray(nbpf), jnp.asarray(iscat), one, z, z, z, z,
+            jnp.asarray(True))
+    else:
+        res = search2_pallas(
+            jnp.asarray(hist), jnp.asarray(hist),
+            *map(jnp.float32, tot), *map(jnp.float32, tot),
+            jnp.asarray(True), jnp.asarray(fmask), jnp.asarray(nbpf),
+            jnp.asarray(iscat), one, z, z, z, z, interpret=True)[0]
+    assert int(res.feature) == 0 and int(res.threshold) == 0
+    assert float(res.left_sum_hess) == np.float32(1.0e-3)
+    assert float(res.left_sum_grad) == np.float32(-0.25e-3)
+    assert float(res.left_count) == 5.0 and float(res.right_count) == 5.0
