@@ -505,20 +505,19 @@ class GBDT:
         """Raw-layout ([Fp, 4, Bp]) single-leaf kernel for the serial
         leaf-wise opt path: the split step then never leaves the
         histogram kernel's native layout (grow_tree ``opt`` mode).
-        v1-variant TPU only; LGBM_TPU_OPT_HISTS=0 disables."""
-        from ..ops.pallas_histogram import _kernel_variant
-
+        TPU only; LGBM_TPU_OPT_HISTS=0 disables."""
         if (
             self._use_pallas_hist()
             and on_tpu()
-            and _kernel_variant() == "v1"
             and os.environ.get("LGBM_TPU_OPT_HISTS", "1") != "0"
         ):
-            from ..ops.pallas_histogram import make_single_hist_fn_raw
+            from ..ops.pallas_histogram import (
+                SINGLE_LEAF_CHUNK, make_single_hist_fn_raw)
 
             return make_single_hist_fn_raw(
                 self._num_bins,
-                chunk=int(os.environ.get("LGBM_TPU_HIST_CHUNK", "512")),
+                chunk=int(os.environ.get(
+                    "LGBM_TPU_HIST_CHUNK", SINGLE_LEAF_CHUNK)),
             )
         return None
 

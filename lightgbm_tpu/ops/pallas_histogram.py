@@ -18,25 +18,18 @@ This module reformulates the histogram as dense MXU work:
 Total work is O(n x F x B) MACs per tree LEVEL — independent of the
 number of leaves — plus one stable sort of the leaf ids.
 
-Two kernel variants (LGBM_TPU_HIST_KERNEL env selects; default "v1"
-until bsub has real-chip timings; pass ``variant=`` explicitly when
-benchmarking — the env var is only read at TRACE time, so flipping it
-between calls of identical shapes hits the jit cache and is ignored):
-
-* ``bsub`` — the one-hot is built TRANSPOSED (``[B, C]``) by comparing a
-  ``[1, C]`` feature row against a SUBLANE iota, then
-  ``onehot[B, C] @ stats[C, .] -> [B, 4]``.  The feature row stays in
-  the lane dimension end to end — no relayout.
-* ``v1`` — the historical form: each feature row is reshaped to
-  ``[C, 1]`` (a lane->sublane relayout, one per feature per chunk —
-  measured to dominate kernel time) and ``stats[., C] @ onehot[C, B]
-  -> [4, B]``.
+One kernel.  The one-hot is built TRANSPOSED (``[B, C]``): a ``[1, C]``
+feature row stays in the lanes and is compared against a SUBLANE iota,
+and ``stats[16, C]`` and ``onehot[B, C]`` contract the shared lane axis
+on the MXU -> ``[16, B]``, the fused split step's form
+(ops/record.py _hist_tile_body).  Reshaping the row to ``[C, 1]``
+against a lane iota instead is a lane->sublane relayout per feature per
+chunk (PERF.md, PR 29); tests/test_chip_compile.py keeps it out.
 """
 
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -48,15 +41,15 @@ from ..obs.device_time import phase_scope
 from .totals import two_sum
 
 DEFAULT_CHUNK = 1024
-FGROUP = 8  # feature rows per kernel loop step (int8 sublane-pack aligned)
-# bsub feature-group block height: the [STAT_ROWS, C] stats block is re-fetched
-# once per (feature-group, chunk) grid step, so wider groups amortize
-# that HBM traffic, while narrower groups waste less padding when F is
-# just past a multiple.  At 16 the (1, 16, B=256, 4->128 lanes)
-# accumulator block is ~2.1MB of VMEM — ample headroom, but 16 already
-# makes stats traffic (32B/row at F<=32) comparable to the bins traffic.
-FGROUP_BSUB = 16
-_VARIANTS = ("v1", "bsub")
+FGROUP = 8  # the feature axis is padded to a multiple of this
+# Feature rows per step of the kernel's loop.  Measured alone on a v5e at
+# 7.5M x 100 and 8.92M x 81 (PERF.md, PR 29): 8 rows a step 153.1 / 154.2
+# ms, 32 rows 138.3 / 138.8, every row unrolled 133.6 / 134.6.
+LOOP_ROWS = 4 * FGROUP
+# Rows per grid step of the single-leaf calls (the depth-wise call keeps
+# DEFAULT_CHUNK).  Same measurement, 32 rows a step: 512 138.3 / 138.8
+# ms, 2048 133.3 / 134.1.
+SINGLE_LEAF_CHUNK = 2048
 # The one-hot histogram dots carry float32 gradient/hessian sums, and
 # Mosaic runs an un-annotated float32 dot as ONE bf16 MXU pass: measured
 # on a v5e (jax 0.9.0, libtpu 0.0.34) the kernels then disagreed with a
@@ -97,30 +90,13 @@ def merge_stats(o, axis=0):
     return p[0] + p[1] + p[2]
 
 
-# read ONCE at import (jaxlint env-read-at-trace): _kernel_variant is
-# called from inside jitted histogram fns, where an environ read bakes
-# per trace while the jit cache keys only on static args
-_VARIANT_ENV = os.environ.get("LGBM_TPU_HIST_KERNEL", "v1")
+# Rows between two folds of the kernel's small accumulator into the
+# output block (see _hist_kernel), whatever the chunk.
+FOLD_ROWS = 8192
 
 
-def _kernel_variant(variant: str | None = None) -> str:
-    # default stays on the chip-proven v1: bsub has never been compiled
-    # by Mosaic nor timed on TPU hardware
-    v = variant or _VARIANT_ENV
-    if v not in _VARIANTS:
-        raise ValueError(
-            f"unknown histogram kernel variant {v!r}; expected one of {_VARIANTS}"
-        )
-    return v
-
-
-# Chunks between two folds of the v1 kernel's small accumulator into the
-# output block (see _hist_kernel_v1): 8,192 rows at the 512-row chunk.
-FOLD_CHUNKS = 16
-
-
-def _hist_kernel_v1(leaf_of_chunk, bins_ref, stats_ref, out_ref, acc_ref,
-                    lo_ref, *, num_f, num_b, chunk):
+def _hist_kernel(leaf_of_chunk, bins_ref, stats_ref, out_ref, acc_ref,
+                 lo_ref, *, num_f, num_b, chunk):
     """One grid step = one C-row chunk of a single leaf.
 
     bins_ref:  [F, C] uint8 (this chunk's bins, feature-major)
@@ -135,11 +111,11 @@ def _hist_kernel_v1(leaf_of_chunk, bins_ref, stats_ref, out_ref, acc_ref,
     rows that is 17,000 roundings at the size a bin has reached, and a
     bin of four million rows ends tens of ulps off — the error every
     sibling-by-subtraction below inherits (PERF.md, PR 28).  They add
-    into ``acc_ref``; every FOLD_CHUNKS chunks that folds into
+    into ``acc_ref``; every FOLD_ROWS rows that folds into
     ``out_ref`` with the rounding's error kept in ``lo_ref``
-    (ops/totals.py two_sum), and the leaf's last chunk adds ``lo_ref`` back: the bin is
-    the correctly rounded sum of the chunks' partial sums.  The
-    per-feature loop is as it was.
+    (ops/totals.py two_sum), and the leaf's last chunk adds ``lo_ref``
+    back: the bin is the correctly rounded sum of the chunks' partial
+    sums.
     """
     c = pl.program_id(0)
     last = pl.num_programs(0) - 1
@@ -154,76 +130,47 @@ def _hist_kernel_v1(leaf_of_chunk, bins_ref, stats_ref, out_ref, acc_ref,
         lo_ref[...] = jnp.zeros_like(lo_ref)
 
     stats = stats_ref[...]  # [STAT_ROWS, C]
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (chunk, num_b), 1)
+    iota_s = jax.lax.broadcasted_iota(jnp.int32, (num_b, chunk), 0)
 
     # int8 VMEM rows are 4-packed per sublane, so a dynamically-indexed
     # SINGLE-row vector.load cannot be proven aligned by Mosaic ("index
     # in dimension 0 is a multiple of 4").  Instead the loop walks the
-    # feature axis in groups of FGROUP rows — the dynamic start g*FGROUP
-    # is provably aligned — and slices rows statically within the group,
-    # keeping compiled code size O(FGROUP), not O(num_f).
-    num_groups = num_f // FGROUP  # caller pads F to a FGROUP multiple
-
-    def group_body(g, _):
-        blk = bins_ref[pl.ds(g * FGROUP, FGROUP), :].astype(jnp.int32)
-        for i in range(FGROUP):
-            row = blk[i, :].reshape(chunk, 1)
-            onehot = (row == iota_b).astype(jnp.bfloat16)  # [C, B]
+    # feature axis LOOP_ROWS rows at a time (one packed uint8 tile: the
+    # dynamic start is provably aligned) and slices rows statically
+    # within the step; the rows past the last whole step (num_f is a
+    # FGROUP multiple, not a LOOP_ROWS one) follow at a static start.
+    # Compiled code size stays O(LOOP_ROWS), not O(num_f).
+    def rows(f0, count):
+        blk = bins_ref[pl.ds(f0, count), :].astype(jnp.int32)
+        for i in range(count):
+            row = blk[i: i + 1, :]  # [1, C] — stays in the lanes
+            onehot = (row == iota_s).astype(jnp.bfloat16)  # [B, C]
             contrib = merge_stats(jax.lax.dot_general(
-                stats, onehot, (((1,), (0,)), ((), ())),
+                stats, onehot, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ))  # [4, B]
-            acc_ref[g * FGROUP + i] = acc_ref[g * FGROUP + i] + contrib
+            acc_ref[f0 + i] = acc_ref[f0 + i] + contrib
+
+    steps, rest = divmod(num_f, LOOP_ROWS)
+
+    def step_body(s, _):
+        rows(s * LOOP_ROWS, LOOP_ROWS)
         return 0
 
-    jax.lax.fori_loop(0, num_groups, group_body, 0)
+    if steps:  # (a loop of no steps is traced all the same)
+        jax.lax.fori_loop(0, steps, step_body, 0)
+    if rest:
+        rows(steps * LOOP_ROWS, rest)
 
-    @pl.when(is_last | (c % FOLD_CHUNKS == FOLD_CHUNKS - 1))
+    fold = max(1, FOLD_ROWS // chunk)
+
+    @pl.when(is_last | (c % fold == fold - 1))
     def _():
         t, err = two_sum(out_ref[0], acc_ref[...])
         lo = lo_ref[...] + err
         out_ref[0] = jnp.where(is_last, t + lo, t)
         lo_ref[...] = lo
         acc_ref[...] = jnp.zeros_like(acc_ref)
-
-
-def _hist_kernel_bsub(leaf_of_chunk, bins_ref, stats_ref, out_ref, *, num_b, chunk):
-    """Relayout-free variant: one grid step = one C-row chunk of one leaf
-    x one FGROUP-wide feature group (grid (F_groups, n_chunks), chunk
-    MINOR so the accumulation block stays VMEM-resident across a leaf's
-    chunks).
-
-    bins_ref:  [FGROUP_BSUB, C] uint8 (feature-major; C in LANES)
-    stats_ref: [STAT_ROWS, C] bf16 (split_stats)
-    out_ref:   [1, FGROUP_BSUB, B, 4] f32 block at (leaf_of_chunk[c], fg) —
-               bounded VMEM whatever the full feature count is (the
-               minor 4 pads to 128 lanes, so a full-F block would be
-               F x B x 128 floats).
-
-    The [1, C] feature row broadcasts across SUBLANES against a [B, C]
-    sublane iota, so the one-hot is born transposed and the row never
-    leaves the lane dimension; ``onehot[B, C]`` and ``stats[16, C]``
-    contract the shared lane axis on the MXU.
-    """
-    c = pl.program_id(1)
-    prev = leaf_of_chunk[jnp.maximum(c - 1, 0)]
-    is_first = (c == 0) | (leaf_of_chunk[c] != prev)
-
-    @pl.when(is_first)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    stats = stats_ref[...]  # [STAT_ROWS, C]
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (num_b, chunk), 0)
-    blk = bins_ref[...].astype(jnp.int32)  # [FGROUP_BSUB, C]
-    for i in range(FGROUP_BSUB):
-        row = blk[i : i + 1, :]  # [1, C] — stays in lanes
-        onehot = (row == iota_s).astype(jnp.bfloat16)  # [B, C]
-        contrib = merge_stats(jax.lax.dot_general(
-            onehot, stats, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ), axis=1)  # [B, 4]
-        out_ref[0, i] = out_ref[0, i] + contrib
 
 
 def _pad_pow(b: int) -> int:
@@ -235,74 +182,44 @@ def _pad_pow(b: int) -> int:
 
 def _hist_pallas_call(
     leaf_of_chunk, bins_buf, stats_buf, out_leaves, Fp, B, C, n_chunks,
-    interpret, variant=None, raw=False,
+    interpret, raw=False,
 ):
-    """Shared pallas_call scaffolding for both kernels: one grid step per
-    C-row chunk, output block indexed by the scalar-prefetched
-    chunk->leaf map.  Returns hist[out_leaves, Fp, B, 4] in the
-    CANONICAL bin-major layout whichever kernel variant ran — or, with
-    ``raw=True`` (v1 only), the kernel's NATIVE [out_leaves, Fp, 4, B]
+    """The one pallas_call: one grid step per C-row chunk, output block
+    indexed by the scalar-prefetched chunk->leaf map.  Returns
+    hist[out_leaves, Fp, B, 4] in the CANONICAL bin-major layout — or,
+    with ``raw=True``, the kernel's NATIVE [out_leaves, Fp, 4, B]
     layout with no relayout at all: the round-3 profile showed the
     per-split transpose to the canonical layout radiating ~0.5 ms/split
     of layout-churn fusions through the whole split step."""
-    if raw:
-        assert _kernel_variant(variant) == "v1", "raw layout is v1-only"
-    if _kernel_variant(variant) == "v1":
-        kernel = functools.partial(_hist_kernel_v1, num_f=Fp, num_b=B, chunk=C)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(n_chunks,),
-            in_specs=[
-                pl.BlockSpec((Fp, C), lambda c, leaf_ref: (0, c)),
-                pl.BlockSpec((STAT_ROWS, C), lambda c, leaf_ref: (0, c)),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, Fp, 4, B), lambda c, leaf_ref: (leaf_ref[c], 0, 0, 0)
-            ),
-            scratch_shapes=[pltpu.VMEM((Fp, 4, B), jnp.float32)] * 2,
-        )
-        with phase_scope(f"histogram.cap{n_chunks * C}"):
-            out = pl.pallas_call(
-                kernel,
-                grid_spec=grid_spec,
-                out_shape=jax.ShapeDtypeStruct(
-                    (out_leaves, Fp, 4, B), jnp.float32),
-                interpret=interpret,
-            )(leaf_of_chunk, bins_buf, stats_buf)
-        if raw:
-            return out  # [L, Fp, 4, B] kernel-native
-        return out.transpose(0, 1, 3, 2)  # -> [L, Fp, B, 4]
-
-    # bsub: feature groups ride the OUTER grid axis (chunk minor), so the
-    # (leaf, fg) accumulation block stays VMEM-resident across a leaf's
-    # consecutive chunks and VMEM is bounded at FGROUP_BSUB x B x 128
-    # floats regardless of the feature count
-    kernel = functools.partial(_hist_kernel_bsub, num_b=B, chunk=C)
+    kernel = functools.partial(_hist_kernel, num_f=Fp, num_b=B, chunk=C)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(Fp // FGROUP_BSUB, n_chunks),
+        grid=(n_chunks,),
         in_specs=[
-            pl.BlockSpec((FGROUP_BSUB, C), lambda fg, c, leaf_ref: (fg, c)),
-            pl.BlockSpec((STAT_ROWS, C), lambda fg, c, leaf_ref: (0, c)),
+            pl.BlockSpec((Fp, C), lambda c, leaf_ref: (0, c)),
+            pl.BlockSpec((STAT_ROWS, C), lambda c, leaf_ref: (0, c)),
         ],
         out_specs=pl.BlockSpec(
-            (1, FGROUP_BSUB, B, 4),
-            lambda fg, c, leaf_ref: (leaf_ref[c], fg, 0, 0),
+            (1, Fp, 4, B), lambda c, leaf_ref: (leaf_ref[c], 0, 0, 0)
         ),
+        scratch_shapes=[pltpu.VMEM((Fp, 4, B), jnp.float32)] * 2,
     )
     with phase_scope(f"histogram.cap{n_chunks * C}"):
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(
-                (out_leaves, Fp, B, 4), jnp.float32),
+                (out_leaves, Fp, 4, B), jnp.float32),
             interpret=interpret,
         )(leaf_of_chunk, bins_buf, stats_buf)
+    if raw:
+        return out  # [L, Fp, 4, B] kernel-native
+    return out.transpose(0, 1, 3, 2)  # -> [L, Fp, B, 4]
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("num_bins", "num_leaves", "chunk", "interpret", "variant"),
+    static_argnames=("num_bins", "num_leaves", "chunk", "interpret"),
 )
 @phase_scope("histogram")
 def histogram_by_leaf_sorted(
@@ -315,7 +232,6 @@ def histogram_by_leaf_sorted(
     num_leaves: int,
     chunk: int = DEFAULT_CHUNK,
     interpret: bool = False,
-    variant: str | None = None,
 ) -> jax.Array:
     """Drop-in equivalent of ops.histogram.histogram_by_leaf:
     returns hist[num_leaves, F, num_bins, 3] = (sum_grad, sum_hess, count).
@@ -324,8 +240,7 @@ def histogram_by_leaf_sorted(
     L = num_leaves
     C = chunk
     B = _pad_pow(num_bins)
-    fg = FGROUP if _kernel_variant(variant) == "v1" else FGROUP_BSUB
-    Fp = ((F + fg - 1) // fg) * fg  # pad to the selected kernel's grouping
+    Fp = ((F + FGROUP - 1) // FGROUP) * FGROUP  # the kernel's grouping
     if Fp != F:
         bins_T = jnp.pad(bins_T, ((0, Fp - F), (0, 0)))
 
@@ -371,13 +286,13 @@ def histogram_by_leaf_sorted(
 
     out = _hist_pallas_call(
         leaf_of_chunk, bins_buf, stats_buf, L + 1, Fp, B, C, n_chunks,
-        interpret, variant,
+        interpret,
     )  # [L+1, Fp, B, 4]
     return out[:L, :F, :num_bins, :3]
 
 
 @functools.partial(
-    jax.jit, static_argnames=("num_bins", "chunk", "interpret", "variant")
+    jax.jit, static_argnames=("num_bins", "chunk", "interpret")
 )
 @phase_scope("histogram")
 def histogram_single_leaf(
@@ -386,9 +301,8 @@ def histogram_single_leaf(
     hess: jax.Array,  # [cap]
     mask: jax.Array,  # [cap] 0/1 validity
     num_bins: int,
-    chunk: int = 512,
+    chunk: int = SINGLE_LEAF_CHUNK,
     interpret: bool = False,
-    variant: str | None = None,
 ) -> jax.Array:
     """hist[F, num_bins, 3] for a single row set — the leaf-wise
     learner's per-split histogram (DenseBin::ConstructHistogram over the
@@ -398,25 +312,24 @@ def histogram_single_leaf(
     scatter — just O(cap x B x F) dense MACs.
     """
     F, cap = bins_T.shape
-    fg = FGROUP if _kernel_variant(variant) == "v1" else FGROUP_BSUB
     bins_T, stats, n_chunks, Fp, B, C = _prep_single_leaf(
-        bins_T, grad, hess, mask, num_bins, chunk, fg)
+        bins_T, grad, hess, mask, num_bins, chunk)
     out = _hist_pallas_call(
         jnp.zeros(n_chunks, jnp.int32), bins_T, stats, 1, Fp, B, C,
-        n_chunks, interpret, variant,
+        n_chunks, interpret,
     )  # [1, Fp, B, 4]
     return out[0, :F, :num_bins, :3]
 
 
-def _prep_single_leaf(bins_T, grad, hess, mask, num_bins, chunk, fg):
+def _prep_single_leaf(bins_T, grad, hess, mask, num_bins, chunk):
     """Shared single-leaf padding/stat prep: lane-aligned chunk width
-    (an unaligned int8 block is the Mosaic failure class the FGROUP
-    loop exists to avoid), features padded to the kernel grouping, and
+    (an unaligned int8 block is the Mosaic failure class the kernel's
+    row loop exists to avoid), features padded to the kernel grouping, and
     the split (g*m, h*m, m, 0) stat rows."""
     F, cap = bins_T.shape
     C = max(128, (chunk // 128) * 128)
     B = _pad_pow(num_bins)
-    Fp = ((F + fg - 1) // fg) * fg
+    Fp = ((F + FGROUP - 1) // FGROUP) * FGROUP
     if Fp != F:
         bins_T = jnp.pad(bins_T, ((0, Fp - F), (0, 0)))
     pad = (-cap) % C
@@ -442,23 +355,23 @@ def histogram_single_leaf_raw(
     hess: jax.Array,  # [cap]
     mask: jax.Array,  # [cap] 0/1 validity
     num_bins: int,
-    chunk: int = 512,
+    chunk: int = SINGLE_LEAF_CHUNK,
     interpret: bool = False,
 ) -> jax.Array:
     """histogram_single_leaf in the KERNEL-NATIVE [Fp, 4, Bp] layout
     (stat rows g/h/count/zero, bins in lanes, features padded to the
-    v1 grouping) — zero post-processing, so the whole split step can
-    stay in one layout (see _hist_pallas_call raw)."""
+    kernel's grouping) — zero post-processing, so the whole split step
+    can stay in one layout (see _hist_pallas_call raw)."""
     bins_T, stats, n_chunks, Fp, B, C = _prep_single_leaf(
-        bins_T, grad, hess, mask, num_bins, chunk, FGROUP)
+        bins_T, grad, hess, mask, num_bins, chunk)
     out = _hist_pallas_call(
         jnp.zeros(n_chunks, jnp.int32), bins_T, stats, 1, Fp, B, C,
-        n_chunks, interpret, variant="v1", raw=True,
+        n_chunks, interpret, raw=True,
     )  # [1, Fp, 4, B]
     return out[0]
 
 
-def make_single_hist_fn_raw(num_bins: int, chunk: int = 512):
+def make_single_hist_fn_raw(num_bins: int, chunk: int = SINGLE_LEAF_CHUNK):
     """hist_fn for the leaf-wise grower's RAW-layout path (signature:
     bins_T, grad, hess, mask -> [Fp, 4, Bp])."""
     return _single_hist_fn_raw(num_bins, chunk, not on_tpu())
@@ -475,7 +388,7 @@ def _single_hist_fn_raw(num_bins: int, chunk: int, interpret: bool):
     return hist_fn
 
 
-def make_single_hist_fn(num_bins: int, chunk: int = 512):
+def make_single_hist_fn(num_bins: int, chunk: int = SINGLE_LEAF_CHUNK):
     """hist_fn for the leaf-wise grower (signature: bins_T, grad, hess,
     mask -> [F, B, 3]) backed by the single-leaf MXU kernel.  Cached per
     config so repeated boosters reuse the jit cache (see

@@ -38,7 +38,8 @@ def topo():
 
 @pytest.fixture(scope="module")
 def grower(topo):
-    """``(lowered, compiled)`` of the serial grower for one v5e chip."""
+    """``(lowered, compiled, jaxpr)`` of the serial grower for one v5e
+    chip."""
     n, F = 100_000, 28
     rng = np.random.RandomState(0)
     X = rng.randn(n, F).astype(np.float32)
@@ -57,12 +58,14 @@ def grower(topo):
             (gbdt._bins_T, jnp.zeros(n, jnp.float32),
              jnp.zeros(n, jnp.float32), gbdt._bag_mask, jnp.ones(F, bool),
              gbdt._nbpf, gbdt._is_cat, gbdt._learner_params))
-        lowered = grow.func.lower(*args, **grow.keywords)
-    return lowered, lowered.compile()  # Mosaic refuses a kernel here, or not
+        traced = grow.func.trace(*args, **grow.keywords)
+        lowered = traced.lower()
+    # Mosaic refuses a kernel here, or not
+    return lowered, lowered.compile(), traced.jaxpr.jaxpr
 
 
 def test_serial_grower_compiles_for_v5e(grower):
-    lowered, compiled = grower
+    lowered, compiled, _ = grower
     mosaic_calls = lowered.as_text().count("tpu_custom_call")
     # the root histogram, ONE split step and one placement launch per
     # chunk of its step table (19 calls here when each capacity tier
@@ -104,6 +107,42 @@ def test_scopes_and_kernel_names_in_the_compiled_grower(grower):
             r"lgbm\.(split_step\.dyn|partition\.place\.dyn"
             r"|histogram\.cap\d+)(\.\d+)?", name), name
     assert sum(n.startswith("lgbm.split_step") for n in calls) == 1
+    assert sum(n.startswith("lgbm.histogram.cap") for n in calls) == 1
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def test_the_one_histogram_kernel_keeps_the_bins_row_in_the_lanes(grower):
+    """The grower holds ONE standalone histogram call, and its body
+    builds the one-hot transposed: every dot contracts the lane axis of
+    ``stats[16, C]`` and ``onehot[B, C]``, and nothing in it has the
+    ``[C, 1]`` shape of a bins row turned onto the sublanes: that
+    relayout, once a feature a chunk, made the root histogram three
+    times the price (453 -> 138 ms alone at 7.5M x 100: PERF.md, PR 29).
+    Read from the traced program's jaxpr: the Mosaic body in the lowered
+    text is serialized bytecode."""
+    hist = [e for e in _eqns(grower[2]) if e.primitive.name == "pallas_call"
+            and "lgbm.histogram.cap" in str(e.source_info.name_stack)]
+    assert len(hist) == 1, [str(e.source_info.name_stack) for e in hist]
+    bins_block = hist[0].params["grid_mapping"].block_mappings[0].block_shape
+    C = bins_block[1].block_size
+    body = list(_eqns(hist[0].params["jaxpr"]))
+    shapes = {v.aval.shape for e in body for v in e.outvars}
+    assert (C, 1) not in shapes, sorted(s for s in shapes if s[-1:] == (1,))
+    dots = [e for e in body if e.primitive.name == "dot_general"]
+    assert dots and all(
+        e.params["dimension_numbers"] == (((1,), (1,)), ((), ()))
+        and [v.aval.shape for v in e.invars] == [(16, C), (256, C)]
+        for e in dots), [e.params["dimension_numbers"] for e in dots]
 
 
 def _reachable(prog, comps):

@@ -1,5 +1,5 @@
-"""A/B the partition-routing strategies, histogram kernel variants and
-end-to-end growth modes.
+"""A/B the partition-routing strategies, the smaller-child gather
+layouts and the end-to-end growth modes.
 
 Routing A/B (runs first, works on CPU AND TPU — the parity and FLOP
 halves of ISSUE 12's acceptance):
@@ -14,16 +14,13 @@ reports the HLO-cost-analysis FLOP ratio and wall-clock per routing,
 and writes the artifact to ``.bench/kernel_ab_routing.json``
 (atomic writer, PR 11 conventions).
 
-Histogram/e2e A/B (TPU; the original tool):  python tools/kernel_ab.py [rows]
+Gather/e2e A/B (TPU; the original tool):  python tools/kernel_ab.py [rows]
 
 Times, at bench shapes (F=28, B=255, L=255):
-  1. sorted level kernel, v1 vs bsub
-  2. single-leaf kernel (n/4 and n/16 rows), v1 vs bsub
-  3. leafwise + depthwise end-to-end s/tree for the variant selected by
-     LGBM_TPU_HIST_KERNEL (read ONCE at import of ops.pallas_histogram
-     — jaxlint env-read-at-trace hoist — so EXPORT it before launching
-     and run the script once per variant to get both end-to-end
-     numbers; a mid-process os.environ flip is ignored)
+  1. the smaller-child gather: column take against row take + transpose
+  2. leafwise + depthwise end-to-end s/tree
+(The histogram kernel has one body since PR 29, so the stages that set
+two against each other are gone: BASELINE.md.)
 """
 
 import os
@@ -176,21 +173,17 @@ def main():
         jax.config.update("jax_platforms", plat)
     import jax.numpy as jnp
 
-    from lightgbm_tpu.ops.pallas_histogram import (
-        histogram_by_leaf_sorted, histogram_single_leaf)
-
     print("devices:", jax.devices(), flush=True)
     if jax.default_backend() != "tpu" and not plat:
         sys.exit(f"backend is {jax.default_backend()!r}, not tpu; set "
                  "BENCH_PLATFORM to name another platform explicitly")
-    interpret = jax.default_backend() != "tpu"
 
     # partition-routing A/B first: cheap, runs on any backend, and its
     # parity assert is the thing that must never regress silently.
     # Guarded like every other section — if Mosaic rejects the prefix
     # kernel on a real chip (the documented risk; routing="prefix" is
     # explicit here, so the LGBM_TPU_REC_ROUTING=onehot escape hatch
-    # cannot skip it), the histogram/e2e A/B below must still get its
+    # cannot skip it), the gather/e2e A/B below must still get its
     # chip window.  --routing-only keeps the loud failure.
     try:
         routing_ab(ROWS)
@@ -205,34 +198,8 @@ def main():
         return
 
     rng = np.random.RandomState(0)
-    F, B, L = 28, 255, 255
+    F, B = 28, 255
     bins = jnp.asarray(rng.randint(0, B, (F, ROWS)).astype(np.uint8))
-    leaf = jnp.asarray(rng.randint(0, 128, ROWS).astype(np.int32))
-    g = jnp.asarray(rng.randn(ROWS).astype(np.float32))
-    ones = jnp.ones(ROWS, jnp.float32)
-
-    for variant in ("v1", "bsub"):
-        try:
-            ms = t(lambda: histogram_by_leaf_sorted(
-                bins, leaf, g, ones, ones, num_bins=B, num_leaves=L,
-                interpret=interpret, variant=variant))
-            print(f"sorted level kernel [{variant}]: {ms:.1f} ms", flush=True)
-        except Exception as e:
-            print(f"sorted level kernel [{variant}] FAILED: "
-                  f"{type(e).__name__}: {str(e)[:300]}", flush=True)
-        for frac in (4, 16):
-            m = ROWS // frac
-            for chunk in (512, 1024, 2048):
-                try:
-                    ms = t(lambda: histogram_single_leaf(
-                        bins[:, :m], g[:m], ones[:m], ones[:m], num_bins=B,
-                        chunk=chunk, interpret=interpret, variant=variant))
-                    print(f"single-leaf n/{frac} chunk={chunk} [{variant}]: "
-                          f"{ms:.1f} ms", flush=True)
-                except Exception as e:
-                    print(f"single-leaf n/{frac} chunk={chunk} [{variant}] "
-                          f"FAILED: {type(e).__name__}: {str(e)[:300]}",
-                          flush=True)
 
     # gather-layout A/B: the leafwise smaller-child gather is currently a
     # minor-dim column take of [F, n]; the alternative keeps a row-major
@@ -258,7 +225,7 @@ def main():
             print(f"gather cap={cap} FAILED: {type(e).__name__}: "
                   f"{str(e)[:200]}", flush=True)
 
-    # end-to-end growth modes (uses LGBM_TPU_HIST_KERNEL env default).
+    # end-to-end growth modes.
     # KERNEL_AB_SKIP_E2E=1 stops here: the end-to-end leafwise compile is
     # the giant one (~9 tier bodies), and bench.py covers end-to-end —
     # the micro numbers above are this tool's unique output.
@@ -290,9 +257,8 @@ def main():
         _ = np.asarray(booster._scores)
         t_tree = (time.perf_counter() - t0) / trees
         auc = booster.eval_at(0).get("auc", float("nan"))
-        print(f"{growth} [{os.environ.get('LGBM_TPU_HIST_KERNEL', 'v1')}]: "
-              f"compile+1st {t_compile:.1f}s, {t_tree*1000:.0f} ms/tree, "
-              f"AUC {auc:.4f}", flush=True)
+        print(f"{growth}: compile+1st {t_compile:.1f}s, "
+              f"{t_tree*1000:.0f} ms/tree, AUC {auc:.4f}", flush=True)
 
 
 if __name__ == "__main__":
