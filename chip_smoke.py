@@ -138,11 +138,10 @@ def leg_device(rehearsal: bool):
 
 def leg_kernels(rehearsal: bool):
     from lightgbm_tpu.analysis import kernel_parity
-    from lightgbm_tpu.ops.record import ROUTING
 
-    say(f"kernels against references (routing={ROUTING}"
-        + (", INTERPRETED: hardware branches not exercised)" if rehearsal
-           else ")"))
+    say("kernels against references"
+        + (" (INTERPRETED: hardware branches not exercised)" if rehearsal
+           else ""))
     times = []
     for _ in range(2):
         t0 = time.perf_counter()

@@ -1,6 +1,6 @@
 """jaxlint stage 2: compiled-artifact audit of the hot entry points.
 
-Traces the serial grow loop, the mega split kernel (interpret mode on
+Traces the serial grow loop, the fused split step (interpret mode on
 CPU — the interpreter lowers the Pallas grid to real XLA HLO, so the
 SURROUNDING program structure the budgets guard is the real thing),
 the aliased placement kernel, and the matmul predictor, then checks:
@@ -38,6 +38,7 @@ a red gate green without a bench row justifying the new count).
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import os
 import re
@@ -258,17 +259,30 @@ def _split_step_inputs():
 
 
 def _measure_split_step_window() -> dict:
-    """The mega split kernel, interpret mode: donation of the hists
-    buffer plus the op budget of the surrounding XLA program."""
-    from ..ops.record import split_step_window
+    """The fused grower's launch pair (split step, then placement),
+    interpret mode: donation of the hists buffer plus the op budget of
+    the surrounding XLA program."""
+    import jax
+
+    from ..ops.record import num_words, place_runs, split_step_window
 
     rec, hists, scal_f, meta, s, cap, k = _split_step_inputs()
-    lowered = split_step_window.lower(
-        hists, rec, s["begin"], s["pcnt"], s["do_split"], s["f"],
-        s["thr"], s["is_cat"], s["parent_slot"], s["new_slot"],
-        scal_f, meta, F=_F, cap=cap, k=k, interpret=True,
-        live_tiles=s["live_tiles"])
-    ops, has_alias, dwarn, mem = _compile_entry(lowered)
+
+    @functools.partial(jax.jit, donate_argnums=(0,))
+    def split(hists_, rec_):
+        hists2, comp, nleft, res, cl, cr, rec_pass = split_step_window(
+            hists_, rec_, s["begin"], s["pcnt"], s["do_split"], s["f"],
+            s["thr"], s["is_cat"], s["parent_slot"], s["new_slot"],
+            scal_f, meta, F=_F, cap=cap, k=k, interpret=True,
+            live_tiles=s["live_tiles"])
+        rec2 = place_runs(
+            rec_pass, comp, (cl, cr), s["begin"], s["pcnt"], nleft,
+            s["do_split"], s["parent_slot"], s["new_slot"], cap=cap,
+            leaf_row=num_words(_F, k) + 4, interpret=True,
+            live_tiles=s["live_tiles"])
+        return hists2, rec2, nleft, res
+
+    ops, has_alias, dwarn, mem = _compile_entry(split.lower(hists, rec))
     return {"ops": ops, "donation": has_alias and not dwarn,
             "donation_warnings": dwarn, "has_alias": has_alias,
             "memory": mem}
@@ -287,7 +301,7 @@ def _measure_split_step_record_chain() -> dict:
         return split_step_window(
             hists_, rec_, s["begin"], s["pcnt"], s["do_split"], s["f"],
             s["thr"], s["is_cat"], s["parent_slot"], s["new_slot"],
-            scal_f, meta, F=_F, cap=cap, k=k, return_comp=True,
+            scal_f, meta, F=_F, cap=cap, k=k,
             interpret=False, live_tiles=s["live_tiles"])
 
     jaxpr = jax.make_jaxpr(run)(rec, hists)
@@ -309,8 +323,8 @@ def _measure_place_runs() -> dict:
     nt = cap // T
     W = rec.shape[0]
     comp = jnp.zeros((nt, W, 2 * T), jnp.int32)
-    go = jnp.zeros(cap, jnp.int32)
-    args = (comp, go, s["begin"], s["pcnt"], jnp.int32(cap // 2),
+    counts = (jnp.full(nt, T // 2, jnp.int32),) * 2
+    args = (comp, counts, s["begin"], s["pcnt"], jnp.int32(cap // 2),
             s["do_split"], s["parent_slot"], s["new_slot"])
     kw = dict(cap=cap, leaf_row=rec_mod.num_words(_F, k) + 4,
               live_tiles=s["live_tiles"])
@@ -330,11 +344,9 @@ def _measure_place_runs() -> dict:
 
 
 def _measure_partition_window() -> dict:
-    """The standalone partition compaction kernel (the record-mode
-    hooks path), at its import-default routing — since PR 12 that is
-    the prefix-sum network, so its copy/convert counts are gated from
-    day one (a routing rework that reintroduces layout churn around
-    the compaction shows up here before any bench run)."""
+    """The standalone partition compaction kernel and its placement
+    (the record-mode hooks path): a rework that reintroduces layout
+    churn around the compaction shows up here before any bench run."""
     import jax.numpy as jnp
 
     from ..ops import record as rec_mod
@@ -347,8 +359,7 @@ def _measure_partition_window() -> dict:
         leaf_row=rec_mod.num_words(_F, k) + 4, interpret=True)
     ops, has_alias, dwarn, mem = _compile_entry(lowered)
     return {"ops": ops, "donation": None, "donation_warnings": dwarn,
-            "has_alias": has_alias, "routing": rec_mod.ROUTING,
-            "memory": mem}
+            "has_alias": has_alias, "memory": mem}
 
 
 def _measure_predict_matmul() -> dict:

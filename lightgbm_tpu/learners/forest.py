@@ -55,9 +55,9 @@ vmapped — reductions stay per-lane over the same axes.
 
 Kernel note: the batched path always uses the jnp reference search and
 segment-sum histograms.  Whether vmap pessimizes the Pallas
-search/histogram kernels is a ``tools/kernel_ab.py`` question for the
-next chip window — the eligibility gate in models/gbdt.py falls back
-to the sequential learner whenever a kernel path is selected.
+search/histogram kernels has not been measured on the chip — the
+eligibility gate in models/gbdt.py falls back to the sequential
+learner whenever a kernel path is selected.
 """
 
 from __future__ import annotations
@@ -72,10 +72,10 @@ from ..models.tree import Tree
 from ..obs import telemetry
 from ..ops.split import K_MIN_SCORE, find_best_split, find_best_split_leaves
 from ..ops.totals import root_totals
-from .serial import (
+from .serial import TreeLearnerParams, grow_tree
+from .tables import (
     _BF, _BG, _BLC, _BLDEP, _BLO, _BLPAR, _BLSG, _BLSH, _BLV, _BLCNT,
-    _BRC, _BRO, _BROWS, _BRSG, _BRSH, _BT,
-    TreeLearnerParams, _sr_row, grow_tree,
+    _BRC, _BRO, _BROWS, _BRSG, _BRSH, _BT, _sr_row,
 )
 
 # batch every per-tree operand; share the binned matrix and the

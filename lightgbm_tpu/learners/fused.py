@@ -1,0 +1,196 @@
+"""The fused leaf-wise grower: what one TPU chip runs.
+
+The same best-first growth as learners/serial.py (SerialTreeLearner,
+serial_tree_learner.cpp:116-150), with every per-split access made a
+contiguous one and every buffer updated in place:
+
+* the rows live in a leaf-sorted PACKED RECORD ``[W, n_pad]``
+  (ops/record.py), the TPU's DataPartition: a leaf's rows are one
+  contiguous window of it;
+* the per-leaf histograms stay in the histogram kernel's native
+  ``[Fp, 4, Bp]`` layout from the root to the last split -- that layout
+  exists nowhere else in the library;
+* a split is ONE launch pair: ``split_step_window`` (stable compaction of
+  the parent's window, the smaller child's histogram, the sibling by
+  subtraction, both children's split search, the two ``hists`` rows
+  written in place) and ``place_runs`` (the compacted runs streamed back
+  into the record, leaf ids stamped).  Both take the window's TILE COUNT
+  as an operand and are sized once, at the largest capacity.
+
+There is no conditional here, and there must not be one round the
+record or ``hists``: a conditional's result is a buffer of its own, so a
+capacity-tier conditional round these kernels cost two whole-record
+copies a split, 3,132 of 5,704 ms/tree (PERF.md, PR 26/27;
+tests/test_chip_compile.py holds the compiled program to none).  With the
+tile count an operand the record and ``hists`` go kernel > kernel >
+carry through aliased calls and are never copied.
+
+The grower takes no hooks, no resume, no pool and no row-mask mode:
+learners/serial.py serves those, and ``models/gbdt.py select_grower`` is
+the one place that chooses between the two.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+
+from ..device import on_tpu
+from ..models.tree import Tree
+from ..obs import telemetry
+from ..obs.device_time import phase_scope
+from ..ops.pallas_histogram import FGROUP, make_single_hist_fn_raw
+from ..ops.pallas_search import _pack_meta, _pack_scal
+from ..ops import record
+from ..ops.record import (
+    bins_per_word, build_record, num_words, place_runs, round_up,
+    split_step_window,
+)
+from . import tables
+from .serial import TreeLearnerParams, default_search_fn
+
+# The whole [Fp, 4, Bp] block of a leaf is resident in the split step's
+# VMEM (five of them): past this many bytes of one block the kernel is
+# not offered.  The constant admits every Fp <= 1024 at 256 bins while
+# the deviceless compile refuses F = 384 (ROADMAP queue 2, item 3).
+HIST_BLOCK_BYTES_MAX = 1 << 22
+
+
+def hist_block_fits(num_features: int, num_bins: int) -> bool:
+    """Does a leaf's ``[Fp, 4, Bp]`` float32 block pass the split step's
+    VMEM gate?"""
+    return (round_up(num_features, FGROUP) * round_up(num_bins, 128) * 16
+            <= HIST_BLOCK_BYTES_MAX)
+
+
+class _State(NamedTuple):
+    """Loop carry (tables: learners/tables.py)."""
+
+    rec: jax.Array  # [W, n_pad] i32 leaf-sorted packed record
+    pos_mat: jax.Array
+    hists: jax.Array  # [L, Fp, 4, Bp] f32, every leaf resident
+    best_mat: jax.Array
+    tree_i: jax.Array
+    tree_f: jax.Array
+    nleaves: jax.Array  # scalar int32 used-leaf count
+
+
+# The benchmark reads the program by this function's name
+# (``jit_grow_tree``) and its ops by the ``lgbm.*`` scopes below.
+@functools.partial(jax.jit, static_argnames=("num_bins", "max_leaves"))
+def grow_tree(
+    bins_T: jax.Array,  # [F, n] feature-major binned matrix
+    grad: jax.Array,  # [n] f32
+    hess: jax.Array,  # [n] f32
+    bag_mask: jax.Array,  # [n] 0/1 bagging mask
+    feature_mask: jax.Array,  # [F] bool, feature_fraction sample
+    num_bins_per_feature: jax.Array,  # [F] int32
+    is_categorical: jax.Array,  # [F] bool
+    params: TreeLearnerParams,
+    num_bins: int,
+    max_leaves: int,
+) -> Tuple[Tree, jax.Array]:
+    """Grow one tree; returns (tree, final leaf_id per row)."""
+    # Python here runs once per TRACE: counts grow-program retraces
+    telemetry.count("grow_traces")
+    assert grad.dtype == jnp.float32, grad.dtype
+    F, n = bins_T.shape
+    L = max_leaves
+    interpret = not on_tpu()
+    hist_fn = make_single_hist_fn_raw(num_bins)
+    k = bins_per_word(bins_T.dtype)
+    T = record.TILE
+    # every row in one window: the root split's, and the buffers' size
+    cap = max(T, round_up(n, T))
+
+    with phase_scope("grow.root"):
+        # constant per tree: the search's [Fp, 4] meta block
+        meta = _pack_meta(
+            feature_mask, num_bins_per_feature, is_categorical,
+            round_up(F, FGROUP))
+        hist0 = hist_fn(bins_T, grad, hess, bag_mask)  # [Fp, 4, Bp]
+        sum_g0, sum_h0, cnt0 = tables.root_sums(grad, hess, bag_mask)
+        # the once-a-tree root search reads the canonical view
+        root_best = default_search_fn(
+            hist0[:F, :3, :num_bins].transpose(0, 2, 1),
+            sum_g0, sum_h0, cnt0,
+            (params.max_depth <= 0) | (jnp.int32(0) < params.max_depth),
+            feature_mask, num_bins_per_feature, is_categorical, params,
+        )
+        best_mat, pos_mat, tree_i, tree_f = tables.root_tables(
+            root_best, hist0.dtype, L, n)
+        state = _State(
+            rec=build_record(
+                bins_T, grad, hess, bag_mask, round_up(n, T) + cap),
+            pos_mat=pos_mat,
+            hists=jnp.zeros((L,) + hist0.shape, hist0.dtype).at[0].set(hist0),
+            best_mat=best_mat,
+            tree_i=tree_i,
+            tree_f=tree_f,
+            nleaves=jnp.int32(1),
+        )
+
+    @phase_scope("grow.book")
+    def split(state, step, best_leaf, do_split):
+        new_leaf = step + 1
+        c = tables.read_split_columns(
+            state.best_mat, state.pos_mat, best_leaf, new_leaf,
+            is_categorical)
+        with phase_scope("grow.select"):
+            # depth gate + per-split scalars for the in-kernel search
+            can = (params.max_depth <= 0) | (
+                c.depth_child < params.max_depth)
+            scal_f = _pack_scal(
+                can.astype(jnp.float32),
+                c.lsg, c.lsh, c.lc, c.rsg, c.rsh, c.rc,
+                params.min_data_in_leaf, params.min_sum_hessian_in_leaf,
+                params.lambda_l1, params.lambda_l2,
+                params.min_gain_to_split,
+            )
+        # the decision AND the tile counts live in the kernel: no
+        # XLA-side read of the record at all, so the aliased placement
+        # updates it in place (a materialized window + go vector forced
+        # a full-record copy per split, ~1 s/tree at 10M rows)
+        live_tiles = -(-c.pcnt // T)
+        hists, comp, nleft, res, cl, cr, rec_pass = split_step_window(
+            state.hists, state.rec, c.begin, c.pcnt, do_split,
+            c.f, c.thr, c.is_cat, best_leaf, new_leaf,
+            scal_f, meta, F=F, cap=cap, k=k, fgroup=FGROUP,
+            interpret=interpret, live_tiles=live_tiles,
+        )
+        rec = place_runs(
+            rec_pass, comp, (cl, cr), c.begin, c.pcnt, nleft, do_split,
+            best_leaf, new_leaf, cap=cap, leaf_row=num_words(F, k) + 4,
+            interpret=interpret, live_tiles=live_tiles,
+        )
+        nright = c.pcnt - nleft
+        # the search results come out of the kernel ALREADY in the
+        # best_mat row layout -- no unpack/repack
+        dt = c.bcol.dtype
+        best_mat, pos_mat, tree_i, tree_f = tables.write_split(
+            state.best_mat, state.pos_mat, state.tree_i, state.tree_f, c,
+            step, best_leaf, new_leaf, do_split,
+            res[0, :11].astype(dt), res[1, :11].astype(dt),
+            nleft, nright, nleft, nright,
+        )
+        return _State(
+            rec=rec, pos_mat=pos_mat, hists=hists, best_mat=best_mat,
+            tree_i=tree_i, tree_f=tree_f,
+            nleaves=state.nleaves + do_split.astype(jnp.int32),
+        )
+
+    def body(step, state):
+        best_leaf, do_split = tables.pick_leaf(state.best_mat)
+        return split(state, jnp.int32(step), best_leaf, do_split)
+
+    with phase_scope("grow.loop"):
+        state = jax.lax.fori_loop(0, L - 1, body, state)
+
+    with phase_scope("grow.unpack"):
+        tree = tables.unpack_tree(
+            state.nleaves, state.best_mat, state.tree_i, state.tree_f, L)
+        leaf_id = tables.leaf_ids_from_record(state.rec, F, k, n)
+    return tree, leaf_id

@@ -1,10 +1,19 @@
-"""Serial (single-device) leaf-wise tree learner, fully jittable.
+"""The canonical leaf-wise tree learner, fully jittable.
 
 TPU-native re-design of SerialTreeLearner
 (src/treelearner/serial_tree_learner.cpp:116-150): the same best-first
 growth — repeatedly split the leaf with the globally best gain until the
 ``num_leaves`` budget or no positive gain remains — expressed as a
-fixed-shape ``lax.fori_loop``:
+fixed-shape ``lax.fori_loop``.
+
+There are TWO leaf-wise growers, and ``models/gbdt.py select_grower`` is
+the one place that picks: learners/fused.py is what a TPU chip runs for
+serial float32 training (packed record, one launch pair a split, no
+``lax.cond``); THIS one serves everything else — the CPU, float64
+accumulation, the parallel learners' hooks, the hybrid resume, the cv
+row mask and ``histogram_pool_size`` — and is the reference the fused
+one is pinned to.  Histograms are in the canonical ``[F, B, 3]`` layout
+throughout.
 
 * the row partition is a PERSISTENT leaf-sorted permutation ``order``
   plus per-leaf ``(begin, count)`` ranges — the reference's
@@ -13,14 +22,17 @@ fixed-shape ``lax.fori_loop``:
   capacity-tiered ``dynamic_slice`` (a ``lax.cond`` chain picks the
   smallest static capacity that fits), so per-split work is
   O(|parent|), not O(n): the whole tree costs O(n * depth) partition
-  work like the reference, instead of O(n * num_leaves).
+  work like the reference, instead of O(n * num_leaves).  Under
+  ``record_mode`` (every parallel learner) the partition is the
+  leaf-sorted packed record of ops/record.py instead, under the same
+  tier chain.
 * per split, only the SMALLER child's histogram is built from data —
   its rows are one contiguous ``dynamic_slice`` of ``order`` (the
   ordered-gradients gather, serial_tree_learner.cpp:259-315); the
   larger child is parent - smaller (the Subtract trick,
   feature_histogram.hpp:97-106).  Histograms for every live leaf stay
-  resident in HBM (``hists[L, F, B, 3]``) — the LRU HistogramPool
-  (feature_histogram.hpp:337-481) is unnecessary at TPU memory sizes.
+  resident in HBM (``hists[L, F, B, 3]``) unless ``hist_pool`` bounds
+  them (the LRU HistogramPool, feature_histogram.hpp:337-481).
 * leaf numbering matches the reference exactly (left child keeps the
   parent's leaf index, right child gets the next fresh index,
   tree.cpp:78-89), so trees are comparable node-for-node.
@@ -38,38 +50,22 @@ parallel == serial trees (split_info.hpp:98-103 semantics).
 from __future__ import annotations
 
 import functools
-import os as _os
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-
-# Read ONCE at import (like ops.record.TILE): grow_tree reads this at
-# trace time but the jit cache keys only on static args, so a mid-process
-# env flip would silently not apply to already-traced shapes (ADVICE r3).
-_KERN_ENV = _os.environ.get("LGBM_TPU_SEARCH_KERNEL", "pallas") != "jnp"
-_FUSE_HIST_ENV = _os.environ.get("LGBM_TPU_FUSE_HIST", "1") != "0"
-# direct in-kernel placement (ops/record.py place_runs): replaces the
-# XLA scan-of-DUS + roll/merge chain and the full-record tier-cond copy.
-# Chip-validated by tools/tpu_parity_check.py (1M: 0.473 -> 0.399
-# s/tree); interpret mode uses the bit-identical XLA fallback.
-_DIRECT_PLACE_ENV = _os.environ.get("LGBM_TPU_DIRECT_PLACE", "1") != "0"
-# geometric step between hist/partition tier capacities (see
-# _hist_tiers); read ONCE at import like every other kernel knob — a
-# trace-time read bakes the value per trace while the jit cache keys
-# only on static args, so a mid-process env flip silently applied to
-# SOME shapes and not others (jaxlint env-read-at-trace)
-_TIER_SPACING_ENV = max(
-    2, int(_os.environ.get("LGBM_TPU_TIER_SPACING", "2")))
 
 from ..device import on_tpu
 from ..models.tree import Tree
 from ..obs import telemetry
 from ..obs.device_time import phase_scope
 from ..ops.histogram import histogram_by_leaf, histogram_feature_major
-from ..ops.split import (
-    SplitResult, find_best_split, find_best_split_leaves, K_MIN_SCORE)
-from ..ops.totals import root_totals
+from ..ops.split import SplitResult, find_best_split, find_best_split_leaves
+from . import tables
+from .tables import _BROWS, _sr_row
+
+# geometric step between hist/partition tier capacities (_hist_tiers)
+TIER_SPACING = 2
 
 
 # leaf_count/internal_count ride the histogram count channel, which is
@@ -116,50 +112,19 @@ class TreeLearnerParams(NamedTuple):
 
 
 class _GrowState(NamedTuple):
-    """Loop carry of the best-first growth.  All per-leaf scalar state is
-    PACKED into a few [rows, L] matrices so one split updates two
-    matrix COLUMNS instead of ~60 individual [L] arrays — the round-5
-    profile at the 100k/63-leaf shape showed HALF the device time was
-    per-op launch gaps from the unpacked representation's ~100 tiny
-    dynamic-slice/DUS/select ops per split."""
+    """Loop carry of the best-first growth (tables: learners/tables.py)."""
 
-    order: jax.Array  # [n + max_cap] leaf-sorted row permutation (pad = n)
+    order: jax.Array  # [n + max_cap] leaf-sorted row permutation (pad = n),
+    # or the [W, n_pad] packed record under record_mode
     pos_mat: jax.Array  # [3, L] i32 rows: (leaf_begin, pos_cnt, gate_cnt)
     hists: jax.Array  # [L, F, B, 3] resident, or [P, F, B, 3] pooled
     slot_of: jax.Array  # [L] int32 pool slot per leaf, -1 = evicted ([0] off)
     slot_leaf: jax.Array  # [P] int32 leaf occupying each slot, -1 = free
     slot_last: jax.Array  # [P] int32 last-use step per slot, -1 = free
-    best_mat: jax.Array  # [16, L] acc_dt — see _B* row constants
-    tree_i: jax.Array  # [5, L] i32 node table: feat, thr, dtype, lch, rch
-    tree_f: jax.Array  # [3, L] f32 node table: gain, int_value, int_count
+    best_mat: jax.Array  # [16, L] acc_dt
+    tree_i: jax.Array  # [5, L] i32
+    tree_f: jax.Array  # [3, L] f32
     nleaves: jax.Array  # scalar int32 used-leaf count
-
-
-# best_mat row indices.  Rows 0-10 are EXACTLY the Pallas search
-# kernels' packed [2, 16] result layout (ops/pallas_search._unpack), so
-# a kernel result row drops into a best_mat column unchanged; rows
-# 11-14 carry the per-leaf half of the Tree so the same two column
-# writes cover split state AND leaf bookkeeping.  Feature/threshold/
-# counts ride as floats — exact to 2^24, the same envelope the f32
-# kernel result already imposes.
-_BG, _BF, _BT = 0, 1, 2
-_BLSG, _BLSH, _BLC = 3, 4, 5
-_BRSG, _BRSH, _BRC = 6, 7, 8
-_BLO, _BRO = 9, 10
-_BLV, _BLCNT, _BLPAR, _BLDEP = 11, 12, 13, 14
-_BROWS = 16
-
-
-def _sr_row(sr: SplitResult, dt):
-    """SplitResult -> kernel-result row layout [11(, L)]."""
-    return jnp.stack([
-        sr.gain.astype(dt), sr.feature.astype(dt), sr.threshold.astype(dt),
-        sr.left_sum_grad.astype(dt), sr.left_sum_hess.astype(dt),
-        sr.left_count.astype(dt),
-        sr.right_sum_grad.astype(dt), sr.right_sum_hess.astype(dt),
-        sr.right_count.astype(dt),
-        sr.left_output.astype(dt), sr.right_output.astype(dt),
-    ])
 
 
 def _round_up(x: int, m: int) -> int:
@@ -173,32 +138,17 @@ def _hist_tiers(n: int):
     n_local (global balance says nothing about one shard's split), so
     ceil(n/2) is not a guaranteed fit there.
 
-    LGBM_TPU_TIER_SPACING (read ONCE at import, see _TIER_SPACING_ENV;
-    default 2) sets the geometric step between capacities: 2 wastes
+    TIER_SPACING sets the geometric step between capacities: 2 wastes
     <2x gather work per split but instantiates ~9 tier bodies (one
-    Mosaic kernel compile each on TPU); 4 halves the tier count for
-    <4x gather waste.  Measured XLA:CPU compile at n=1M, L=255, B=255
-    (segment hist): spacing=2 (9 tiers) 9.5s, spacing=4 (5 tiers)
-    13.8s — tier count is NOT the compile bottleneck off-TPU; the knob
-    exists for the Mosaic per-kernel compile path.
-
-    The default fused TPU path (split_step_window + place_runs) takes
-    only the LARGEST capacity from here, to size its buffers: its
-    kernels' tile counts are run-time operands and no tier gets a body
-    of its own, so the spacing does not reach it."""
-    step = _TIER_SPACING_ENV
+    Mosaic kernel compile each on TPU); 4 would halve the tier count
+    for <4x gather waste."""
+    step = TIER_SPACING
     caps = {max(512, _round_up(n, 128))}
     frac = 2
     while frac <= 256:  # step=2 reproduces the original 2,4,...,256 set
         caps.add(max(512, _round_up(-(-n // frac), 128)))
         frac *= step
     return tuple(sorted(caps))
-
-
-def _part_tiers(n: int):
-    """Capacities for the parent-range partition slice (the root split
-    spans every row; _hist_tiers already tops out at full n)."""
-    return _hist_tiers(n)
 
 
 def _tier_chain(caps, gate_cnt, branch_fn):
@@ -301,7 +251,7 @@ def default_search_fn(
     static_argnames=(
         "num_bins", "max_leaves", "hist_fn", "reduce_fn", "search_fn",
         "reduce_max_fn", "child_counts_fn", "search2_fn", "hist_pool",
-        "init_hist_fn", "init_search_fn", "hist_fn_raw", "record_mode",
+        "init_hist_fn", "init_search_fn", "record_mode",
         "choice_by_mask_counts",
     ),
 )
@@ -327,7 +277,6 @@ def grow_tree(
     init_leaf_id=None,
     init_hist_fn=None,
     init_search_fn=None,
-    hist_fn_raw=None,
     record_mode: bool = False,
     choice_by_mask_counts: bool = False,
 ) -> Tuple[Tree, jax.Array]:
@@ -356,6 +305,14 @@ def grow_tree(
       combine the two results in a single all_gather.  Default: two
       ``search_fn`` calls.
 
+    ``record_mode``: the parallel learners choose the leaf-sorted
+    packed-record partition (ops/record.py; the reference's parallel
+    learners inherit the serial hot loop, parallel_tree_learner.h:46-90).
+    Histograms of a child's window still flow through ``hist_fn`` (which
+    reduce-scatters across the mesh) and searches through the hooks;
+    only the partition and the contiguous-window child access change.
+    Float32, unpooled, no resume: otherwise the row permutation runs.
+
     ``init_tree``/``init_leaf_id`` resume best-first growth from an
     existing partial tree (the hybrid growth mode, learners/hybrid.py):
     the persistent partition is rebuilt from the row->leaf map, per-leaf
@@ -374,6 +331,9 @@ def grow_tree(
     (feature_histogram.hpp:337-481, serial_tree_learner.cpp:25-32)
     re-cast for static shapes.  ``0`` (default) keeps every leaf
     resident.
+
+    ``choice_by_mask_counts``: base-row-mask mode (cv bin-once,
+    gbdt.set_base_row_mask), see the split step below.
     """
     # Python here runs once per TRACE, so this counts grow-program
     # retraces exactly (obs: a timed loop whose grow_traces counter
@@ -382,86 +342,33 @@ def grow_tree(
     telemetry.count("grow_traces")
     F, n = bins_T.shape
     L = max_leaves
-    h_tiers = _hist_tiers(n)
-    p_tiers = _part_tiers(n)
+    # the partition's capacities are the histogram's (the root split
+    # spans every row; _hist_tiers tops out at full n)
+    h_tiers = p_tiers = _hist_tiers(n)
     order_pad = max(p_tiers + h_tiers)
+    pooled = 0 < hist_pool < L
 
     if hist_fn is None:
         hist_fn = functools.partial(histogram_feature_major, num_bins=num_bins)
-    # ---- opt mode: the whole split step stays in the histogram
-    # kernel's NATIVE [Fp, 4, Bp] layout (raw hist kernel -> subtract ->
-    # raw search kernel), eliminating the per-split layout-churn fusions
-    # the round-3 profile showed radiating from the [F, B, 3] transpose
-    # (~0.5 ms/split).  Only the default serial hook set qualifies;
-    # parallel learners and the hybrid resume keep the canonical layout.
-    _kern_env = _KERN_ENV
-    _interp = not on_tpu()
-    opt = (
-        hist_fn_raw is not None
-        and search_fn is None
-        and search2_fn is None
-        and init_tree is None
-        and grad.dtype == jnp.float32
-        # the raw layout REQUIRES the raw search kernel, so the
-        # LGBM_TPU_SEARCH_KERNEL=jnp escape hatch disables opt wholesale
-        and _kern_env
-    )
-    # fused split step (subtract + search + in-place buffer update in
-    # one launch) — unpooled only: the left child reuses the parent's
-    # buffer row
-    opt_fused = opt and not (0 < hist_pool < max_leaves)
-    if choice_by_mask_counts and opt:
-        # the raw-layout fused kernels pick the small child positionally
-        # INSIDE the launch; callers that set a base row mask (cv
-        # bin-once) are gated to the canonical path before reaching here
-        raise NotImplementedError(
-            "choice_by_mask_counts requires the canonical (non-raw-"
-            "kernel) grow path"
-        )
-    # ``record_mode``: PARALLEL learners (search hooks present) opt into
-    # the leaf-sorted packed-record partition — the round-3/4 fast path
-    # was previously serial-only, leaving every distributed run on the
-    # per-index-gather partition (VERDICT r4 item 1; the reference's
-    # parallel learners inherit the serial hot loop,
-    # parallel_tree_learner.h:46-90).  Histograms of a child's window
-    # still flow through ``hist_fn`` (which reduce-scatters across the
-    # mesh) and searches through the hooks; only the partition and the
-    # contiguous-window child access change.
-    rec_hooks = (
+    rec = (
         record_mode
-        and not opt
         and grad.dtype == jnp.float32
         and init_tree is None
-        and not (0 < hist_pool < max_leaves)
+        and not pooled
     )
-    rec = opt_fused or rec_hooks
-    fuse_hist = False  # set below when the record path qualifies
     if search_fn is None:
         search_fn = default_search_fn
         if search2_fn is None:
-            use_kernel = on_tpu() and _kern_env
+            use_kernel = on_tpu()
 
             def search2_fn(hl, hr, lsg, lsh, lc, rsg, rsh, rc, can,
                            fmask, nbpf, is_cat, prm):
                 # TPU: the whole two-child search is ONE Pallas launch
-                # (ops/pallas_search.py) — the round-3 profile showed
-                # the jnp search compiling to ~60 small fusions per
-                # split (~1.6 ms, 4x the histogram kernel), all per-op
-                # overhead no jnp restructuring removes.  The jnp path
-                # stays the reference implementation (CPU, float64).
-                if opt:
-                    from ..ops.pallas_search import search2_pallas_raw
-
-                    return search2_pallas_raw(
-                        jnp.stack([hl, hr]),
-                        lsg, lsh, lc, rsg, rsh, rc, can,
-                        fmask, nbpf, is_cat,
-                        prm.min_data_in_leaf,
-                        prm.min_sum_hessian_in_leaf,
-                        prm.lambda_l1, prm.lambda_l2,
-                        prm.min_gain_to_split,
-                        interpret=_interp,
-                    )
+                # (ops/pallas_search.py) — the jnp search compiles to
+                # ~60 small fusions per split (~1.6 ms, 4x the histogram
+                # kernel), all per-op overhead no jnp restructuring
+                # removes.  The jnp path stays the reference
+                # implementation (CPU, float64).
                 if use_kernel and hl.dtype == jnp.float32:
                     from ..ops.pallas_search import search2_pallas
 
@@ -487,68 +394,28 @@ def grow_tree(
                     SplitResult(*[a[0] for a in res]),
                     SplitResult(*[a[1] for a in res]),
                 )
-    if opt:
-        # every in-loop histogram (children + pooled parent recompute)
-        # is built in the raw layout
-        hist_fn = hist_fn_raw
     if rec:
         # record mode: the loop state carries the leaf-sorted PACKED
         # RECORD [W, n_pad] (ops/record.py) instead of the row
         # permutation — every per-split access becomes a contiguous
-        # slice and the partition runs as the MXU block-compaction
-        # kernel.  The round-3 profile showed the order-based path's
-        # per-index gathers/scatters costing ~0.4 s/tree at 1M rows.
+        # slice and the partition runs as the block-compaction kernel
+        # (the order-based path's per-index gathers/scatters cost
+        # ~0.4 s/tree at 1M rows).
+        from ..ops import record as _record
         from ..ops.record import (
-            TILE as _REC_TILE,
             bins_per_word, build_record, extract_feature, num_words,
-            partition_window, place_runs, rec_height,
-            split_step_window, unpack_window,
+            partition_window, rec_height, unpack_window,
         )
 
+        _interp = not on_tpu()
+        _T = _record.TILE
         k_pack = bins_per_word(bins_T.dtype)
         Wrec = rec_height(F, k_pack)
-        _row_id_row = num_words(F, k_pack) + 3
         _leaf_row = num_words(F, k_pack) + 4
         bin_dt = bins_T.dtype
-        h_tiers = tuple(sorted({_round_up(c, _REC_TILE) for c in h_tiers}))
-        p_tiers = tuple(sorted({_round_up(c, _REC_TILE) for c in p_tiers}))
+        h_tiers = tuple(sorted({_round_up(c, _T) for c in h_tiers}))
+        p_tiers = tuple(sorted({_round_up(c, _T) for c in p_tiers}))
         order_pad = max(p_tiers + h_tiers)
-    if opt_fused:
-        from ..ops.pallas_histogram import FGROUP as _FGROUP
-        from ..ops.pallas_search import (
-            _pack_meta as _search_pack_meta,
-            _pack_scal as _search_pack_scal,
-        )
-        # mega split-step kernel (ops/record.py split_step_window):
-        # compaction + SMALLER-child histogram + both searches + in-place
-        # buffer updates in ONE launch, dropping the separate
-        # smaller-child histogram launch and its whole h_tier cond
-        # chain.  Gated on the hist block fitting comfortably in VMEM
-        # next to the routing matrices.
-        _Bp = _round_up(num_bins, 128)
-        _Fp = _round_up(F, _FGROUP)
-        # LGBM_TPU_FUSE_HIST=0 is the A/B escape hatch (read at import
-        # like the other kernel knobs — see _KERN_ENV)
-        # VMEM gate, routing-dependent.  onehot: at Fp=248/Bp=256 (a
-        # one-hot categorical bench shape) the mega kernel's scoped
-        # VMEM measured 16.16M against the 16M limit — the hist block
-        # must stay well clear of the ~12MB routing matrices + search
-        # temporaries, so cap it at 512KB (Fp*Bp*16B); wider shapes
-        # take the 2-kernel path.  prefix: the routing matrices are
-        # gone (the compress network's temporaries are [W+1, TILE]
-        # rows, ~KBs), so the gate loosens to 4MB and shapes like
-        # Fp=248/Bp=256 (1.0MB) keep the one-launch split step.
-        from ..ops.record import ROUTING as _REC_ROUTING
-
-        _vmem_cap = (1 << 22) if _REC_ROUTING == "prefix" else (1 << 19)
-        fuse_hist = _FUSE_HIST_ENV and _Fp * _Bp * 16 <= _vmem_cap
-        direct_place = fuse_hist and _DIRECT_PLACE_ENV
-        if fuse_hist:
-            # constant per tree: the search kernel's [Fp, 4] meta block
-            with phase_scope("grow.root"):
-                _mega_meta = _search_pack_meta(
-                    feature_mask, num_bins_per_feature, is_categorical,
-                    _Fp)
     if child_counts_fn is None:
         _sum = (lambda x: x) if reduce_fn is None else reduce_fn
         _max = (lambda x: x) if reduce_max_fn is None else reduce_max_fn
@@ -580,40 +447,10 @@ def grow_tree(
         )
 
     with phase_scope("grow.root"):
-        if init_tree is None:
-            # ---- root (BeforeTrain / LeafSplits::Init, leaf_splits.hpp:51-92)
-            hist0 = hist_fn(bins_T, grad, hess, bag_mask)
-            # root Σg/Σh: exact up to a fixed grid (ops/totals.py), so
-            # ACCURATE -- the root's gain and every categorical
-            # ``total - bin`` read these two, and a row-by-row float32
-            # accumulation read a varying hessian 0.13% high at 9M rows,
-            # which the first leaf's chain inherited whole -- and
-            # INDEPENDENT OF ORDER: a masked-out row adds an exact 0.0
-            # wherever it rides along, which the base-row-mask contract
-            # (cv bin-once trains fold boosters on the full matrix and
-            # pins their metrics bitwise to subset-trained ones) and the
-            # batched forest grower's stacked-vs-loop pin rest on.
-            # tests/test_root_totals.py holds both properties,
-            # tests/test_reference_agreement.py the leaves that follow.
-            # cnt0 stays jnp.sum: counts are exact small integers in any
-            # grouping.
-            sum_g0, sum_h0 = root_totals(grad, hess, bag_mask)
-            cnt0 = jnp.sum(bag_mask)
-            if reduce_fn is not None:
-                # one stacked collective for the tree-start allreduce
-                s = reduce_fn(jnp.stack([sum_g0, sum_h0, cnt0]))
-                sum_g0, sum_h0, cnt0 = s[0], s[1], s[2]
-            # hist0's feature extent may be a shard of F (feature-parallel
-            # learner); accumulation dtype follows grad/hess — float64 when
-            # Config.hist_dtype asks for the reference's double accumulation
-            # (include/LightGBM/bin.h:21-22)
-            acc_dt = hist0.dtype
-        else:
-            acc_dt = jnp.promote_types(grad.dtype, jnp.float32)
-        pooled = 0 < hist_pool < L
         P = max(hist_pool, 2) if pooled else L
         if init_tree is not None:
             assert not pooled, "init_tree resume is unpooled"
+            acc_dt = jnp.promote_types(grad.dtype, jnp.float32)
             K0 = init_tree.num_leaves.astype(jnp.int32)
             lid = init_leaf_id.astype(jnp.int32)
             # leaf-sorted permutation + contiguous per-leaf ranges from the
@@ -697,26 +534,25 @@ def grow_tree(
             )
             start_step = K0 - 1
         else:
-            root_best = best_for(
-                # raw-layout root histogram -> canonical view for the
-                # (once-per-tree) jnp root search
-                hist0[:F, :3, :num_bins].transpose(0, 2, 1) if opt else hist0,
-                sum_g0, sum_h0, cnt0, jnp.int32(0),
-            )
-            best_mat0 = (
-                jnp.zeros((_BROWS, L), acc_dt)
-                .at[_BG].set(K_MIN_SCORE)
-                .at[_BF].set(-1.0)
-                .at[_BLPAR].set(-1.0)  # empty_tree's leaf_parent = -1
-            )
-            best_mat0 = jax.lax.dynamic_update_slice(
-                best_mat0, _sr_row(root_best, acc_dt)[:, None], (0, 0))
+            # ---- root (BeforeTrain / LeafSplits::Init, leaf_splits.hpp:51-92)
+            hist0 = hist_fn(bins_T, grad, hess, bag_mask)
+            sum_g0, sum_h0, cnt0 = tables.root_sums(grad, hess, bag_mask)
+            if reduce_fn is not None:
+                # one stacked collective for the tree-start allreduce
+                s = reduce_fn(jnp.stack([sum_g0, sum_h0, cnt0]))
+                sum_g0, sum_h0, cnt0 = s[0], s[1], s[2]
+            # hist0's feature extent may be a shard of F (feature-parallel
+            # learner); accumulation dtype follows grad/hess — float64 when
+            # Config.hist_dtype asks for the reference's double accumulation
+            # (include/LightGBM/bin.h:21-22)
+            acc_dt = hist0.dtype
+            root_best = best_for(hist0, sum_g0, sum_h0, cnt0, jnp.int32(0))
+            best_mat0, pos_mat0, tree_i0, tree_f0 = tables.root_tables(
+                root_best, acc_dt, L, n)
             state = _GrowState(
-                # record mode: the "order" leaf carries the [W, n_pad]
-                # packed record; otherwise the flat row permutation
                 order=build_record(
                     bins_T, grad, hess, bag_mask,
-                    _round_up(n, _REC_TILE) + order_pad,
+                    _round_up(n, _T) + order_pad,
                 )
                 if rec
                 else jnp.concatenate(
@@ -725,10 +561,7 @@ def grow_tree(
                         jnp.full(order_pad, n, jnp.int32),
                     ]
                 ),
-                # root gate: every shard's padded local row count is the
-                # same n (rows: leaf_begin, pos_cnt, gate_cnt)
-                pos_mat=jnp.zeros((3, L), jnp.int32)
-                .at[1, 0].set(n).at[2, 0].set(n),
+                pos_mat=pos_mat0,
                 hists=jnp.zeros((P,) + hist0.shape, acc_dt).at[0].set(hist0),
                 slot_of=(jnp.full(L, -1, jnp.int32).at[0].set(0) if pooled
                          else jnp.zeros(0, jnp.int32)),
@@ -737,8 +570,8 @@ def grow_tree(
                 slot_last=(jnp.full(P, -1, jnp.int32).at[0].set(0) if pooled
                            else jnp.zeros(0, jnp.int32)),
                 best_mat=best_mat0,
-                tree_i=jnp.zeros((5, L), jnp.int32).at[0].set(-1),
-                tree_f=jnp.zeros((3, L), jnp.float32),
+                tree_i=tree_i0,
+                tree_f=tree_f0,
                 nleaves=jnp.int32(1),
             )
             start_step = 0
@@ -752,97 +585,17 @@ def grow_tree(
         [L, F, B, 3] histogram buffer every iteration (O(L^2*F*B) traffic
         per tree), which dominated the run time.  Masked straight-line
         writes keep every buffer update in place."""
-        node = step
         new_leaf = step + 1
+        c = tables.read_split_columns(
+            state.best_mat, state.pos_mat, best_leaf, new_leaf,
+            is_categorical)
+        f, thr, is_cat = c.f, c.thr, c.is_cat
+        lsg, lsh, lc, rsg, rsh, rc = c.lsg, c.lsh, c.lc, c.rsg, c.rsh, c.rc
+        begin, pcnt, gate = c.begin, c.pcnt, c.gate
 
-        # ---- ALL per-leaf scalar reads come from four column slices
-        # (parent + prospective-new-leaf columns of the two packed
-        # matrices) instead of ~40 individual [L]-array gathers.
-        with phase_scope("grow.select"):
-            z0 = jnp.int32(0)
-            bcol = jax.lax.dynamic_slice(
-                state.best_mat, (z0, best_leaf), (_BROWS, 1))[:, 0]
-            bcolN = jax.lax.dynamic_slice(
-                state.best_mat, (z0, new_leaf), (_BROWS, 1))[:, 0]
-            pcol = jax.lax.dynamic_slice(
-                state.pos_mat, (z0, best_leaf), (3, 1))[:, 0]
-            pcolN = jax.lax.dynamic_slice(
-                state.pos_mat, (z0, new_leaf), (3, 1))[:, 0]
-
-            f = bcol[_BF].astype(jnp.int32)
-            thr = bcol[_BT].astype(jnp.int32)
-            is_cat = is_categorical[jnp.maximum(f, 0)]
-            lsg, lsh, lc = bcol[_BLSG], bcol[_BLSH], bcol[_BLC]
-            rsg, rsh, rc = bcol[_BRSG], bcol[_BRSH], bcol[_BRC]
-            depth_child = bcol[_BLDEP].astype(jnp.int32) + 1
-
-            # ---- partition the parent's range in place
-            # (DataPartition::Split).
-            # The tier gate (cross-shard max of the parent's positional count)
-            # was stored at the split that CREATED this leaf — no collective
-            # here.
-            begin = pcol[0]
-            pcnt = pcol[1]
-            gate = pcol[2]
-        mega_res = None
-        if opt_fused and fuse_hist:
-            # MEGA split step: compaction + smaller-child histogram + both
-            # searches + in-place hists-row updates, ONE launch (the
-            # round-4 profile showed the loop bound by per-split
-            # dispatch, not op work).  depth gate + per-split scalars
-            # for the in-kernel search:
-            with phase_scope("grow.select"):
-                can_k = (params.max_depth <= 0) | (
-                    depth_child < params.max_depth)
-                scal_f = _search_pack_scal(
-                    can_k.astype(jnp.float32),
-                    lsg, lsh, lc, rsg, rsh, rc,
-                    params.min_data_in_leaf, params.min_sum_hessian_in_leaf,
-                    params.lambda_l1, params.lambda_l2,
-                    params.min_gain_to_split,
-                )
-
-            def _mega_rec(cap, live_tiles=None):
-                # the decision AND the tile counts live in the kernel
-                # (_tile_go + the cnt output): no XLA-side read of the
-                # record at all, so the aliased placement updates it in
-                # place (a materialized window + go vector forced a
-                # full-record copy per split — ~1 s/tree at 10M rows)
-                out = split_step_window(
-                    state.hists, state.order, begin, pcnt,
-                    do_split, f, thr, is_cat, best_leaf, new_leaf,
-                    scal_f, _mega_meta, F=F, cap=cap, k=k_pack,
-                    fgroup=_FGROUP, return_comp=direct_place,
-                    interpret=_interp, live_tiles=live_tiles,
-                )
-                if not direct_place:
-                    return out
-                mh, comp, nl, res, cl, cr, rec_pass = out
-                rec2 = place_runs(
-                    rec_pass, comp, None, begin, pcnt, nl, do_split,
-                    best_leaf, new_leaf, cap=cap, leaf_row=_leaf_row,
-                    interpret=_interp, counts=(cl, cr),
-                    live_tiles=live_tiles,
-                )
-                return mh, rec2, nl, res
-
-            if direct_place:
-                # ONE launch pair at the largest capacity whose tile
-                # count is a run-time operand, outside any lax.cond:
-                # the record and hists go kernel > kernel > carry
-                # through aliased calls.  A conditional's result is a
-                # buffer of its own, and the tier chain here cost two
-                # whole-record copies a split (PERF.md, PR 26/27).
-                mega_hists, order, nleft, mega_res = _mega_rec(
-                    p_tiers[-1], -(-pcnt // _REC_TILE))
-            else:
-                # LGBM_TPU_DIRECT_PLACE=0: the XLA placement costs
-                # O(cap) a split, so it keeps a capacity per tier
-                with phase_scope("grow.tier.split"):
-                    mega_hists, order, nleft, mega_res = _tier_chain(
-                        p_tiers, gate, _mega_rec
-                    )
-        elif rec:
+        # ---- partition the parent's range in place
+        # (DataPartition::Split), at the smallest tier its gate fits
+        if rec:
 
             def _part_rec(cap):
                 fv = extract_feature(state.order, f, begin, cap, k_pack)
@@ -850,8 +603,7 @@ def grow_tree(
                 return partition_window(
                     state.order, go, begin, pcnt, do_split, cap,
                     left_leaf=best_leaf, right_leaf=new_leaf,
-                    leaf_row=_leaf_row, direct=_DIRECT_PLACE_ENV,
-                    interpret=_interp,
+                    leaf_row=_leaf_row, interpret=_interp,
                 )
 
             with phase_scope("grow.tier.part"):
@@ -897,11 +649,7 @@ def grow_tree(
         cnt_s = jnp.where(small_is_left, nleft, nright)
         cnt_s_gate = jnp.where(small_is_left, nleft_gate, nright_gate)
         begin_s = jnp.where(small_is_left, begin, begin + nleft)
-        if opt_fused and fuse_hist:
-            # mega path: histogram, subtract, search AND buffer update
-            # all happened inside split_step_window already
-            pass
-        elif rec:
+        if rec:
             # record mode: the child's rows are a CONTIGUOUS slice of
             # the leaf-sorted record — unpack (vector shifts) + kernel,
             # no indexed access at all.  Under hooks, hist_fn carries
@@ -966,81 +714,50 @@ def grow_tree(
             ).astype(jnp.int32)
             h_prev_new = state.hists[s2]
         else:
-            h_parent = None if opt_fused else state.hists[best_leaf]
-            h_prev_new = None if opt_fused else state.hists[new_leaf]
-        if mega_res is not None:
-            # mega path: results come straight out of split_step_window
-            # ALREADY in the best_mat row layout — no unpack/repack
-            hists = mega_hists
-            rowL = mega_res[0, :11].astype(bcol.dtype)
-            rowR = mega_res[1, :11].astype(bcol.dtype)
-        elif opt_fused:
-            # ---- ONE launch: subtract + child routing + both searches
-            # + in-place buffer row updates (ops/pallas_search.py
-            # _fused_kernel).  No [F, B]-sized intermediate exists as an
-            # XLA value, so there is nothing to relayout and no barrier
-            # is needed — the aliased custom-call IS the buffer update.
-            from ..ops.pallas_search import search2_update_pallas
+            h_parent = state.hists[best_leaf]
+            h_prev_new = state.hists[new_leaf]
+        h_large = h_parent - h_small
+        h_left = jnp.where(small_is_left, h_small, h_large)
+        h_right = jnp.where(small_is_left, h_large, h_small)
 
-            can = (params.max_depth <= 0) | (depth_child < params.max_depth)
-            hists, best_l_new, best_r_new = search2_update_pallas(
-                state.hists, h_small, best_leaf, new_leaf,
-                do_split,
-                small_is_left,
-                lsg, lsh, lc, rsg, rsh, rc, can,
-                feature_mask, num_bins_per_feature, is_categorical,
-                params.min_data_in_leaf, params.min_sum_hessian_in_leaf,
-                params.lambda_l1, params.lambda_l2,
-                params.min_gain_to_split,
-                interpret=_interp,
+        # ---- child best splits (FindBestThresholds on the two new
+        # leaves) — computed BEFORE the buffer update so that every
+        # read of state.hists is finished by then (see barrier below)
+        best_l_new, best_r_new = best2_for(
+            h_left, h_right, lsg, lsh, lc, rsg, rsh, rc, c.depth_child
+        )
+
+        # ---- in-place buffer update.  Everything derived from reads
+        # of state.hists (the stacked new rows and the child
+        # searches) goes through ONE optimization_barrier together
+        # with the buffer itself: after the barrier the buffer has no
+        # other live readers, so XLA's copy insertion lets the
+        # two-row scatter update it in place.  (Without this, the
+        # compiled while body copied the full [L, F, B, 3] buffer
+        # twice per split — measured in the HLO.)
+        if pooled:
+            # preserve the slots' old contents when the step no-ops
+            new_rows = jnp.stack(
+                [
+                    jnp.where(do_split, h_left, state.hists[s1]),
+                    jnp.where(do_split, h_right, h_prev_new),
+                ]
             )
-            rowL = _sr_row(best_l_new, bcol.dtype)
-            rowR = _sr_row(best_r_new, bcol.dtype)
+            rows_idx = jnp.stack([s1, s2])
         else:
-            h_large = h_parent - h_small
-            h_left = jnp.where(small_is_left, h_small, h_large)
-            h_right = jnp.where(small_is_left, h_large, h_small)
-
-            # ---- child best splits (FindBestThresholds on the two new
-            # leaves) — computed BEFORE the buffer update so that every
-            # read of state.hists is finished by then (see barrier below)
-            best_l_new, best_r_new = best2_for(
-                h_left, h_right, lsg, lsh, lc, rsg, rsh, rc, depth_child
+            new_rows = jnp.stack(
+                [
+                    jnp.where(do_split, h_left, h_parent),
+                    jnp.where(do_split, h_right, h_prev_new),
+                ]
             )
-
-            # ---- in-place buffer update.  Everything derived from reads
-            # of state.hists (the stacked new rows and the child
-            # searches) goes through ONE optimization_barrier together
-            # with the buffer itself: after the barrier the buffer has no
-            # other live readers, so XLA's copy insertion lets the
-            # two-row scatter update it in place.  (Without this, the
-            # compiled while body copied the full [L, F, B, 3] buffer
-            # twice per split — measured in the HLO.)
-            if pooled:
-                # preserve the slots' old contents when the step no-ops
-                new_rows = jnp.stack(
-                    [
-                        jnp.where(do_split, h_left, state.hists[s1]),
-                        jnp.where(do_split, h_right, h_prev_new),
-                    ]
-                )
-                rows_idx = jnp.stack([s1, s2])
-            else:
-                new_rows = jnp.stack(
-                    [
-                        jnp.where(do_split, h_left, h_parent),
-                        jnp.where(do_split, h_right, h_prev_new),
-                    ]
-                )
-                rows_idx = jnp.stack([best_leaf, new_leaf])
-            new_rows, best_l_new, best_r_new, hists_in = (
-                jax.lax.optimization_barrier(
-                    (new_rows, best_l_new, best_r_new, state.hists)
-                )
+            rows_idx = jnp.stack([best_leaf, new_leaf])
+        new_rows, best_l_new, best_r_new, hists_in = (
+            jax.lax.optimization_barrier(
+                (new_rows, best_l_new, best_r_new, state.hists)
             )
-            hists = hists_in.at[rows_idx].set(new_rows, unique_indices=True)
-            rowL = _sr_row(best_l_new, bcol.dtype)
-            rowR = _sr_row(best_r_new, bcol.dtype)
+        )
+        hists = hists_in.at[rows_idx].set(new_rows, unique_indices=True)
 
         if pooled:
             # residency bookkeeping, all masked on do_split: evicted
@@ -1065,68 +782,13 @@ def grow_tree(
             slot_leaf = state.slot_leaf
             slot_last = state.slot_last
 
-        # ---- packed column updates: per-leaf split state + the leaf
-        # half of the tree ride best_mat (two column writes); partition
-        # ranges ride pos_mat (two column writes); the node half of the
-        # tree rides tree_i/tree_f (three column read-modify-writes).
-        dt = bcol.dtype
-        node_f = node.astype(dt)
-        dep_f = depth_child.astype(dt)
-        zero = jnp.zeros((), dt)
-        tailL = jnp.stack([bcol[_BLO], lc, node_f, dep_f, zero])
-        tailR = jnp.stack([bcol[_BRO], rc, node_f, dep_f, zero])
-        colL = jnp.where(do_split, jnp.concatenate([rowL, tailL]), bcol)
-        colR = jnp.where(do_split, jnp.concatenate([rowR, tailR]), bcolN)
-        best_mat = jax.lax.dynamic_update_slice(
-            state.best_mat, colL[:, None], (z0, best_leaf))
-        best_mat = jax.lax.dynamic_update_slice(
-            best_mat, colR[:, None], (z0, new_leaf))
-
-        pcL = jnp.where(do_split, jnp.stack([begin, nleft, nleft_gate]), pcol)
-        pcR = jnp.where(
-            do_split, jnp.stack([begin + nleft, nright, nright_gate]), pcolN)
-        pos_mat = jax.lax.dynamic_update_slice(
-            state.pos_mat, pcL[:, None], (z0, best_leaf))
-        pos_mat = jax.lax.dynamic_update_slice(
-            pos_mat, pcR[:, None], (z0, new_leaf))
-
-        # ---- tree bookkeeping (Tree::Split, tree.cpp:52-96): fix up the
-        # parent's child pointer (the split leaf keeps its node id ~leaf
-        # until it becomes internal node ``node``), then write the new
-        # node's column.  pidx < node always, so the two writes never
-        # collide.
-        parent = bcol[_BLPAR].astype(jnp.int32)
-        has_parent = parent >= 0
-        pidx = jnp.maximum(parent, 0)
-        colP = jax.lax.dynamic_slice(state.tree_i, (z0, pidx), (5, 1))[:, 0]
-        was_left = colP[3] == ~best_leaf
-        colP = colP.at[3].set(
-            jnp.where(do_split & has_parent & was_left, node, colP[3]))
-        colP = colP.at[4].set(
-            jnp.where(do_split & has_parent & ~was_left, node, colP[4]))
-        tree_i = jax.lax.dynamic_update_slice(
-            state.tree_i, colP[:, None], (z0, pidx))
-        colNd = jax.lax.dynamic_slice(tree_i, (z0, node), (5, 1))[:, 0]
-        colNd = jnp.where(
-            do_split,
-            jnp.stack(
-                [f, thr, is_cat.astype(jnp.int32), ~best_leaf, ~new_leaf]),
-            colNd,
+        dt = c.bcol.dtype
+        best_mat, pos_mat, tree_i, tree_f = tables.write_split(
+            state.best_mat, state.pos_mat, state.tree_i, state.tree_f, c,
+            step, best_leaf, new_leaf, do_split,
+            _sr_row(best_l_new, dt), _sr_row(best_r_new, dt),
+            nleft, nright, nleft_gate, nright_gate,
         )
-        tree_i = jax.lax.dynamic_update_slice(
-            tree_i, colNd[:, None], (z0, node))
-
-        colTf = jax.lax.dynamic_slice(state.tree_f, (z0, node), (3, 1))[:, 0]
-        colTf = jnp.where(
-            do_split,
-            # cast explicitly: under hist_dtype=float64 the split stats
-            # are f64 while tree buffers stay f32
-            jnp.stack([bcol[_BG], bcol[_BLV], lc + rc]).astype(jnp.float32),
-            colTf,
-        )
-        tree_f = jax.lax.dynamic_update_slice(
-            state.tree_f, colTf[:, None], (z0, node))
-
         return _GrowState(
             order=order,
             pos_mat=pos_mat,
@@ -1141,54 +803,27 @@ def grow_tree(
         )
 
     def body(step, state):
-        with phase_scope("grow.select"):
-            gain_row = state.best_mat[_BG]
-            best_leaf = jnp.argmax(gain_row).astype(jnp.int32)
-            do_split = gain_row[best_leaf] > 0.0
+        best_leaf, do_split = tables.pick_leaf(state.best_mat)
         return split_branch(state, jnp.int32(step), best_leaf, do_split)
 
     with phase_scope("grow.loop"):
         state = jax.lax.fori_loop(start_step, L - 1, body, state)
 
-    # ---- unpack the Tree pytree from the packed node/leaf tables (one
-    # set of static row slices per TREE, replacing the ~30 per-SPLIT
-    # masked stores of the unpacked representation)
     with phase_scope("grow.unpack"):
-        li = L - 1
-        tree = Tree(
-            num_leaves=state.nleaves,
-            split_feature=state.tree_i[0, :li],
-            split_feature_real=(
-                init_tree.split_feature_real if init_tree is not None
-                else jnp.full(li, -1, jnp.int32)),
-            threshold_bin=state.tree_i[1, :li],
-            threshold_real=(
-                init_tree.threshold_real if init_tree is not None
-                else jnp.zeros(li, jnp.float32)),
-            decision_type=state.tree_i[2, :li],
-            left_child=state.tree_i[3, :li],
-            right_child=state.tree_i[4, :li],
-            split_gain=state.tree_f[0, :li],
-            internal_value=state.tree_f[1, :li],
-            internal_count=state.tree_f[2, :li],
-            leaf_value=state.best_mat[_BLV].astype(jnp.float32),
-            leaf_count=state.best_mat[_BLCNT].astype(jnp.float32),
-            leaf_parent=state.best_mat[_BLPAR].astype(jnp.int32),
-            leaf_depth=state.best_mat[_BLDEP].astype(jnp.int32),
-        )
-
-        # ---- per-row leaf assignment from the final ranges: leaves own
-        # disjoint contiguous [begin, begin+count) spans of ``order``, so the
-        # leaf of a position is a searchsorted over the (few) sorted begins,
-        # then one unique-index scatter maps positions back to rows.
+        tree = tables.unpack_tree(
+            state.nleaves, state.best_mat, state.tree_i, state.tree_f, L)
+        if init_tree is not None:
+            tree = tree._replace(
+                split_feature_real=init_tree.split_feature_real,
+                threshold_real=init_tree.threshold_real)
         if rec:
-            # record mode: the partition stamped every position's leaf id
-            # into the record's leaf-id row — one contiguous read replaces
-            # the searchsorted over leaf ranges (~75 ms/tree of
-            # binary-search gathers in the round-4 profile)
-            leaf_of_pos = state.order[_leaf_row, :n]
-            rows = jnp.minimum(state.order[_row_id_row, :n], n - 1)
+            leaf_id = tables.leaf_ids_from_record(state.order, F, k_pack, n)
         else:
+            # ---- per-row leaf assignment from the final ranges: leaves
+            # own disjoint contiguous [begin, begin+count) spans of
+            # ``order``, so the leaf of a position is a searchsorted over
+            # the (few) sorted begins, then one unique-index scatter maps
+            # positions back to rows.
             idxL = jnp.arange(L, dtype=jnp.int32)
             valid_leaf = (idxL < tree.num_leaves) & (state.pos_mat[1] > 0)
             key = jnp.where(
@@ -1199,9 +834,6 @@ def grow_tree(
                 jnp.searchsorted(
                     sb, jnp.arange(n, dtype=jnp.int32), side="right") - 1
             ]
-            rows = jnp.minimum(state.order[:n], n - 1)
-        leaf_id = (
-            jnp.zeros(n, jnp.int32).at[rows].set(
-                leaf_of_pos, unique_indices=True)
-        )
+            leaf_id = tables.scatter_leaf_ids(
+                jnp.minimum(state.order[:n], n - 1), leaf_of_pos, n)
     return tree, leaf_id

@@ -192,6 +192,7 @@ class GBDT:
         # mesh.  None = everything on the default device (serial).
         self._bins_sharding = self._row_sharding = None
         self._learner_devices = 1
+        self._grower = self.select_grower()
         self._grow = self._create_tree_learner()
         # device copy cached ON the dataset: cv folds / train_many models
         # constructed over the same BinnedDataset share one upload.  Under
@@ -235,6 +236,61 @@ class GBDT:
         obs_memory.phase_boundary("binning")
         # rollback support: keep per-iteration train score deltas off-device?
         # cheaper: recompute on rollback from stored trees (rare path).
+
+    def select_grower(self, row_mask: bool = False):
+        """The ONE place that chooses between the two leaf-wise growers,
+        from what it can observe.  Returns ``(which, why)``: ``"fused"``
+        (learners/fused.py: serial float32 training on a TPU chip) or
+        ``"canonical"`` (learners/serial.py: everything else) with the
+        first condition that ruled the fused one out.  The raw
+        ``[Fp, 4, Bp]`` histogram layout exists only inside the fused
+        grower, so ``histogram_pool_size`` selects the canonical one.
+        (learners/fused.py, and Pallas with it, is imported where it is
+        first needed, as every kernel module is.)"""
+        cfg = self.config
+        F = self.train_set.num_features
+        if not on_tpu():
+            why = f"platform={device_platform()}"
+        elif jax.process_count() > 1 or not (
+                cfg.tree_learner == "serial" or len(jax.devices()) == 1):
+            why = (f"tree_learner={cfg.tree_learner} over "
+                   f"{jax.device_count()} devices")
+        elif cfg.tree_growth != "leafwise":
+            why = f"tree_growth={cfg.tree_growth}"
+        elif not self._use_pallas_hist():
+            why = f"hist_dtype={cfg.hist_dtype} hist_impl={cfg.hist_impl}"
+        elif float(cfg.histogram_pool_size) > 0:
+            why = f"histogram_pool_size={cfg.histogram_pool_size}"
+        elif row_mask:
+            why = "base row mask"
+        else:
+            from ..learners import fused
+
+            if fused.hist_block_fits(F, self._num_bins):
+                return "fused", ""
+            why = (f"a leaf's histogram block at {F} features x "
+                   f"{self._num_bins} bins is over the split step's "
+                   f"{fused.HIST_BLOCK_BYTES_MAX} bytes")
+        return "canonical", why
+
+    def _serial_leafwise_grower(self):
+        """The grow callable of ``self._grower`` for serial leaf-wise
+        growth."""
+        if self._grower[0] == "fused":
+            from ..learners import fused
+
+            return functools.partial(
+                fused.grow_tree,
+                num_bins=self._num_bins,
+                max_leaves=self.max_leaves,
+            )
+        return functools.partial(
+            grow_tree,
+            num_bins=self._num_bins,
+            max_leaves=self.max_leaves,
+            hist_fn=self._leafwise_hist_fn(),
+            hist_pool=self._hist_pool_slots(),
+        )
 
     def _create_tree_learner(self):
         """TreeLearner::CreateTreeLearner (tree_learner.cpp:8-20): map
@@ -303,14 +359,7 @@ class GBDT:
                     hist_fn=self._leafwise_hist_fn(),
                     level_hist_fn=self._depthwise_hist_fn(),
                 )
-            return functools.partial(
-                grow_tree,
-                num_bins=self._num_bins,
-                max_leaves=self.max_leaves,
-                hist_fn=self._leafwise_hist_fn(),
-                hist_pool=self._hist_pool_slots(),
-                hist_fn_raw=self._leafwise_hist_fn_raw(),
-            )
+            return self._serial_leafwise_grower()
         from ..parallel import (
             data_mesh,
             make_data_parallel_grower,
@@ -413,28 +462,26 @@ class GBDT:
         runs and where — so a log shows a CPU run (segment-sum
         histograms, interpreted kernels) for what it is, and a serial
         learner on a multi-chip host as the one-chip run it is."""
-        from ..learners.serial import _KERN_ENV
         from ..log import Log
-        from ..ops.record import ROUTING
 
-        raw = self._leafwise_hist_fn_raw() is not None
+        which, why = self._grower
         serial = self._learner_devices == 1
         if self.config.tree_growth != "leafwise":
             hist = "pallas sorted" if self._use_pallas_hist() else "segment-sum"
             search, part = "jnp", "leaf-id vector"
-        elif raw and serial:
+        elif which == "fused":
             hist, search = "pallas raw-layout", "pallas (in the split step)"
-            part = f"packed record, {ROUTING} routing"
+            part = "packed record"
         else:
             hist = "pallas" if self._use_pallas_hist() else "segment-sum"
-            search = ("pallas" if on_tpu() and _KERN_ENV
-                      and not self._use_f64_hist else "jnp")
-            part = ("row permutation" if serial
-                    else f"packed record, {ROUTING} routing")
+            search = ("pallas" if on_tpu() and not self._use_f64_hist
+                      else "jnp")
+            part = "row permutation" if serial else "packed record"
         msg = (f"platform={device_platform()} "
                f"devices={self._learner_devices} of {jax.device_count()} "
                f"tree_learner={self.config.tree_learner} "
-               f"growth={self.config.tree_growth}: "
+               f"growth={self.config.tree_growth} "
+               f"grower={which}{f' ({why})' if why else ''}: "
                f"histogram={hist}, search={search}, partition={part}"
                + (", pallas kernels interpreted" if not on_tpu()
                   and ("pallas" in hist or "record" in part) else ""))
@@ -461,19 +508,11 @@ class GBDT:
                 "transient histograms; the hybrid resume runs unpooled)"
             )
             return 0
+        # a pool selects the canonical grower (select_grower), whose
+        # slots are [F, B, 3]
         itemsize = 8 if self._use_f64_hist else 4
-        F = int(self._bins_T.shape[0])
-        if self._leafwise_hist_fn_raw() is not None:
-            # raw-layout residency: each slot is the PADDED kernel-native
-            # [Fp, 4, Bp] buffer, not F*num_bins*3.  (Parallel learners
-            # keep the canonical layout; sizing them by the larger raw
-            # slot just errs on the safe side of the MB bound.)
-            from ..ops.pallas_histogram import FGROUP, _pad_pow
-
-            Fp = ((F + FGROUP - 1) // FGROUP) * FGROUP
-            per_leaf = Fp * 4 * _pad_pow(self._num_bins) * itemsize
-        else:
-            per_leaf = F * self._num_bins * 3 * itemsize
+        per_leaf = (self.train_set.num_features * self._num_bins * 3
+                    * itemsize)
         slots = int(mb * 1024 * 1024 / max(per_leaf, 1))
         return max(2, min(slots, self.max_leaves))
 
@@ -500,26 +539,6 @@ class GBDT:
 
             return select_single_hist_fn(self._num_bins, True)
         return None  # grower's default segment_sum path
-
-    def _leafwise_hist_fn_raw(self):
-        """Raw-layout ([Fp, 4, Bp]) single-leaf kernel for the serial
-        leaf-wise opt path: the split step then never leaves the
-        histogram kernel's native layout (grow_tree ``opt`` mode).
-        TPU only; LGBM_TPU_OPT_HISTS=0 disables."""
-        if (
-            self._use_pallas_hist()
-            and on_tpu()
-            and os.environ.get("LGBM_TPU_OPT_HISTS", "1") != "0"
-        ):
-            from ..ops.pallas_histogram import (
-                SINGLE_LEAF_CHUNK, make_single_hist_fn_raw)
-
-            return make_single_hist_fn_raw(
-                self._num_bins,
-                chunk=int(os.environ.get(
-                    "LGBM_TPU_HIST_CHUNK", SINGLE_LEAF_CHUNK)),
-            )
-        return None
 
     def _depthwise_hist_fn(self):
         """Histogram implementation for depthwise growth (config.hist_impl):
@@ -585,21 +604,23 @@ class GBDT:
         are bitwise the subset-trained ones (same nonzero contributions
         in the same row order; engine.cv, docs/forest_batching.md).
 
-        Requires the canonical serial leaf-wise grower: the child-choice
-        criterion switches to masked counts (choice_by_mask_counts in
-        learners/serial.py explains why positional counts would break
-        the subset-parity contract)."""
-        if getattr(self._grow, "func", None) is not grow_tree:
+        Requires serial leaf-wise growth, and selects the canonical
+        grower for it: the child-choice criterion switches to masked
+        counts (choice_by_mask_counts in learners/serial.py explains why
+        positional counts would break the subset-parity contract)."""
+        if not (self._grower[0] == "fused"
+                or getattr(self._grow, "func", None) is grow_tree):
             raise ValueError(
                 "set_base_row_mask requires the serial leaf-wise tree "
-                "learner (canonical path)"
+                "learner"
             )
         m = jnp.asarray(mask, jnp.float32)
         self._base_row_mask = m
         self._bag_mask = self._bag_mask * m
         self._bag_cnt = int(jnp.sum(self._bag_mask))
+        self._grower = self.select_grower(row_mask=True)
         self._grow = functools.partial(
-            self._grow, choice_by_mask_counts=True)
+            self._serial_leafwise_grower(), choice_by_mask_counts=True)
 
     def _update_bagging(self) -> None:
         """GBDT::Bagging (gbdt.cpp:157-208): every bagging_freq iterations
@@ -711,11 +732,11 @@ class GBDT:
         (learners/forest.py)?  Mirrors the canonical serial branch of
         _create_tree_learner: single-process leaf-wise growth with the
         segment-sum histograms and jnp search — the op set the explicit
-        batched loop reproduces bitwise.  Kernel paths (Pallas hist /
-        raw-layout opt mode), f64 accumulation, pooled histograms, and
-        parallel learners fall back to the sequential grower; whether
-        vmap pessimizes those kernels is a tools/kernel_ab.py question
-        for the next chip window (docs/forest_batching.md)."""
+        batched loop reproduces bitwise.  Kernel paths (the fused
+        grower, Pallas histograms), f64 accumulation, pooled histograms
+        and parallel learners fall back to the sequential grower;
+        whether vmap pessimizes those kernels has not been measured on
+        the chip (docs/forest_batching.md)."""
         cfg = self.config
         knob = getattr(cfg, "forest_batching", "auto")
         if knob == "off":
@@ -726,8 +747,8 @@ class GBDT:
             return False
         if self._use_f64_hist or self._hist_pool_slots():
             return False
-        if (self._leafwise_hist_fn() is not None
-                or self._leafwise_hist_fn_raw() is not None):
+        if (self._grower[0] == "fused"
+                or self._leafwise_hist_fn() is not None):
             return False
         if knob == "on":
             return True

@@ -65,8 +65,10 @@ SCOPES = (
     ("lgbm.split_search", "jax.numpy split search (ops/split.py)"),
     ("lgbm.split_step", "fused split step: routing, compaction, child "
      "histogram and both searches in one Mosaic call (ops/record.py)"),
-    ("lgbm.partition", "build_record, compaction, place_runs, "
-     "write_window: Mosaic calls and the XLA ops around them"),
+    ("lgbm.partition", "build_record, place_runs (tail place.dyn) and, "
+     "in the canonical grower's record mode, partition_window's "
+     "compaction (tail compact.cap<N>): Mosaic calls and the XLA ops "
+     "around them"),
     ("lgbm.leaf_update", "_post_grow_step: shrinkage, score update, "
      "thresholds (models/gbdt.py)"),
     ("lgbm.gradients", "the objective's jitted gradient programs"),
@@ -77,13 +79,14 @@ SCOPES = (
      "(ops/totals.py), inside lgbm.grow.root"),
     ("lgbm.grow.loop", "the fori_loop itself: its carry and whatever of "
      "the body no inner scope names"),
-    ("lgbm.grow.select", "body's argmax and the column reads and scalar "
-     "packing of split_branch"),
-    ("lgbm.grow.tier", "a _tier_chain call: the cond nest and what XLA "
-     "puts at its boundaries (tails: part, hist, split; the default "
-     "fused path has none)"),
-    ("lgbm.grow.book", "split_branch after the kernels: best_mat, "
-     "pos_mat, tree_i, tree_f column updates, pool bookkeeping"),
+    ("lgbm.grow.select", "body's argmax and a split's column reads "
+     "and scalar packing"),
+    ("lgbm.grow.tier", "a _tier_chain call of the canonical grower "
+     "(learners/serial.py): the cond nest and what XLA puts at its "
+     "boundaries (tails: part, hist; the fused grower has none)"),
+    ("lgbm.grow.book", "a split after the kernels: best_mat, pos_mat, "
+     "tree_i, tree_f column updates (learners/tables.py), pool "
+     "bookkeeping"),
     ("lgbm.grow.unpack", "grow_tree after the loop: Tree unpack, leaf_id"),
 )
 SCOPE_NAMES = tuple(s for s, _ in SCOPES)

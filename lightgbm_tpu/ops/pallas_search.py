@@ -212,25 +212,6 @@ def _search2_kernel(scal_ref, hist_ref, meta_ref, out_ref, *, F, B):
         )
 
 
-def _search2_kernel_raw(scal_ref, hist_ref, meta_ref, out_ref, *, F, B):
-    """Raw-layout variant: hist_ref [2, F, 4, B] is the histogram
-    buffer's KERNEL-NATIVE layout (ops/pallas_histogram raw path), so
-    the split step never converts layouts.  Stat planes come from
-    static rank-4 indexing (supported by this Mosaic); everything else
-    is the shared per-child search."""
-    h = hist_ref[...]  # [2, F, 4, B]
-    tri = _tri(B)
-
-    for c in range(2):
-        hg, hh, hc = h[c, :, 0, :], h[c, :, 1, :], h[c, :, 2, :]
-        _child_search(
-            c, hg, hh, hc,
-            _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
-            _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
-            scal_ref, meta_ref, out_ref, F, B,
-        )
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def search2_pallas(
     h_left, h_right,  # [F, B, 3] f32
@@ -312,165 +293,3 @@ def _pack_scal(canf, lsg, lsh, lc, rsg, rsh, rc,
         f32(min_data), f32(min_hess), f32(l1), f32(l2), f32(min_gain),
         f32(0), f32(0), f32(0),
     ])  # [16] SMEM scalar-prefetch
-
-
-def _fused_kernel(scal_i_ref, scal_f_ref, hrow_ref, hsmall_ref, meta_ref,
-                  hists_out_ref, res_ref, scratch_ref, *, F, B):
-    """Fused subtract + child-select + search + histogram-buffer update.
-
-    Two sequential grid steps over ONE aliased histogram buffer:
-
-      step 0: hrow_ref = the PARENT row (index map reads slot si[0]).
-        Compute h_large = parent - h_small, route small/large to
-        left/right, run the full two-child search (res_ref), write the
-        left child's row in place of the parent (slot si[1]), stash the
-        right child's row in VMEM scratch.
-      step 1: hrow_ref = the OLD row of the new leaf's slot (si[2]).
-        Write where(do_split, stashed right row, old row).
-
-    The parent slot is never the new slot (si[1] == si[0] != si[2] in
-    unpooled mode), so step 1's input prefetch cannot race step 0's
-    writeback.  With input_output_aliasing the buffer is updated in
-    place and NO [F, B]-sized histogram intermediate ever exists as an
-    XLA value — the round-3 profile showed those intermediates' layout
-    churn costing ~0.5 ms/split.
-
-    scal_i [8] i32 SMEM: (parent_slot, left_slot, new_slot, do_split,
-                          small_is_left, 0, 0, 0)
-    scal_f [16] f32 SMEM: as _pack_scal
-    """
-    c = pl.program_id(0)
-    do_split = scal_i_ref[3] > 0
-    small_left = scal_i_ref[4] > 0
-
-    @pl.when(c == 0)
-    def _():
-        parent = hrow_ref[0]  # [F, 4, B]
-        hs = hsmall_ref[...]
-        h_large = parent - hs
-        # where on f32 tensors with a scalar pred: splat-select
-        h_left = jnp.where(small_left, hs, h_large)
-        h_right = jnp.where(small_left, h_large, hs)
-        hists_out_ref[0] = jnp.where(do_split, h_left, parent)
-        scratch_ref[...] = h_right
-
-        tri = _tri(B)
-        for cc in range(2):
-            side = (h_left, h_right)[cc]
-            hg, hh, hc = side[:, 0, :], side[:, 1, :], side[:, 2, :]
-            _child_search(
-                cc, hg, hh, hc,
-                _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
-                _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
-                scal_f_ref, meta_ref, res_ref, F, B,
-            )
-
-    @pl.when(c == 1)
-    def _():
-        hists_out_ref[0] = jnp.where(do_split, scratch_ref[...],
-                                     hrow_ref[0])
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
-def search2_update_pallas(
-    hists,  # [P, Fp, 4, Bp] f32 — DONATED, updated in place
-    h_small,  # [Fp, 4, Bp] f32 — the smaller child's histogram
-    parent_slot, new_slot,  # i32 row indices (parent/left reuse parent_slot)
-    do_split, small_is_left,  # scalar bools
-    lsg, lsh, lc, rsg, rsh, rc,  # scalars (left/right child totals)
-    can,
-    feature_mask, num_bins_per_feature, is_categorical,  # [F] (unpadded)
-    min_data_in_leaf, min_sum_hessian_in_leaf,
-    lambda_l1, lambda_l2, min_gain_to_split,
-    interpret: bool = False,
-):
-    """One launch: subtract trick + child routing + two-child search +
-    in-place histogram-buffer row updates.  Returns (hists, resL, resR).
-    Unpooled layout only: the left child reuses the parent's slot."""
-    P, Fp, _, Bp = hists.shape
-    F = feature_mask.shape[0]
-    meta = _pack_meta(
-        feature_mask, num_bins_per_feature, is_categorical, Fp)
-    scal_f = _pack_scal(
-        jnp.asarray(can, jnp.float32), lsg, lsh, lc, rsg, rsh, rc,
-        min_data_in_leaf, min_sum_hessian_in_leaf,
-        lambda_l1, lambda_l2, min_gain_to_split)
-    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
-    scal_i = jnp.stack([
-        i32(parent_slot), i32(parent_slot), i32(new_slot),
-        i32(do_split), i32(small_is_left), i32(0), i32(0), i32(0)])
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(2,),
-        in_specs=[
-            # step 0 reads the parent's row, step 1 the new slot's row
-            pl.BlockSpec(
-                (1, Fp, 4, Bp),
-                lambda i, si, sf: (jnp.where(i == 0, si[0], si[2]),
-                                   0, 0, 0)),
-            pl.BlockSpec((Fp, 4, Bp), lambda i, si, sf: (0, 0, 0)),
-            pl.BlockSpec((Fp, 4), lambda i, si, sf: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(
-                (1, Fp, 4, Bp),
-                lambda i, si, sf: (jnp.where(i == 0, si[1], si[2]),
-                                   0, 0, 0)),
-            pl.BlockSpec((2, 16), lambda i, si, sf: (0, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((Fp, 4, Bp), jnp.float32)],
-    )
-    hists_new, out = pl.pallas_call(
-        functools.partial(_fused_kernel, F=Fp, B=Bp),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((P, Fp, 4, Bp), jnp.float32),
-            jax.ShapeDtypeStruct((2, 16), jnp.float32),
-        ],
-        input_output_aliases={2: 0},  # hists (after the 2 prefetch args)
-        interpret=interpret,
-    )(scal_i, scal_f, hists, h_small, meta)
-    return hists_new, _unpack(out, 0), _unpack(out, 1)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def search2_pallas_raw(
-    h2,  # [2, Fp, 4, Bp] f32 — the raw-layout histogram rows
-    lsg, lsh, lc, rsg, rsh, rc,  # scalars
-    can,  # scalar bool
-    feature_mask, num_bins_per_feature, is_categorical,  # [F] (unpadded)
-    min_data_in_leaf, min_sum_hessian_in_leaf,
-    lambda_l1, lambda_l2, min_gain_to_split,
-    interpret: bool = False,
-):
-    """search2_pallas over kernel-native [2, Fp, 4, Bp] histogram rows:
-    no layout conversion anywhere between the histogram kernel, the
-    subtract trick, and this search.  Padded features are masked out
-    via the padded feature_mask; padded bins exceed nbpf and never
-    validate."""
-    _, Fp, _, Bp = h2.shape
-    F = feature_mask.shape[0]
-    meta = _pack_meta(
-        feature_mask, num_bins_per_feature, is_categorical, Fp)
-    scal = _pack_scal(
-        jnp.asarray(can, jnp.float32), lsg, lsh, lc, rsg, rsh, rc,
-        min_data_in_leaf, min_sum_hessian_in_leaf,
-        lambda_l1, lambda_l2, min_gain_to_split)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((2, Fp, 4, Bp), lambda i, s: (0, 0, 0, 0)),
-            pl.BlockSpec((Fp, 4), lambda i, s: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((2, 16), lambda i, s: (0, 0)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_search2_kernel_raw, F=Fp, B=Bp),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((2, 16), jnp.float32),
-        interpret=interpret,
-    )(scal, h2.astype(jnp.float32), meta)
-    return _unpack(out, 0), _unpack(out, 1)

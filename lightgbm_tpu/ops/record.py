@@ -1,15 +1,14 @@
 """Leaf-sorted packed training record: the TPU-native DataPartition.
 
-Round-3 on-chip profiling (tools/profile_split.py, BASELINE.md) showed
-the leaf-wise split loop bound by per-index gather/scatter work on
-[n]-sized arrays (~30 ns/element): the partition's feature-row gather
-and order scatter plus the smaller-child bins/grad/hess takes total
-~42M indexed elements per 1M-row 255-leaf tree — almost the whole
-measured s/tree — while contiguous streams run ~40x faster.  The
-reference's DataPartition (data_partition.hpp:91-139) leans on CPU
-caches to make indices()-indirected histogram reads cheap; the TPU
-analog keeps the DATA ITSELF physically leaf-ordered so every per-split
-access is a contiguous slice.
+The leaf-wise split loop is bound by per-index gather/scatter work on
+[n]-sized arrays (~30 ns/element on the chip): a row permutation's
+feature-row gather and order scatter plus the smaller child's
+bins/grad/hess takes total ~42M indexed elements per 1M-row 255-leaf
+tree, while contiguous streams run ~40x faster.  The reference's
+DataPartition (data_partition.hpp:91-139) leans on CPU caches to make
+indices()-indirected histogram reads cheap; the TPU analog keeps the
+DATA ITSELF physically leaf-ordered so every per-split access is a
+contiguous slice.
 
 Storage: one i32 record matrix [W, n_pad] whose word-rows are
 
@@ -19,43 +18,41 @@ Storage: one i32 record matrix [W, n_pad] whose word-rows are
     row  Wb+1    : hessian   (f32 bitcast)
     row  Wb+2    : bagging mask (f32 bitcast)
     row  Wb+3    : original row id (int32; n past the valid prefix)
+    row  Wb+4    : leaf id (stamped by every placement)
 
-Split-step primitives:
+Who calls what:
 
- *  ``extract_feature`` — split-feature bin values of a leaf's
-    contiguous range: dynamic word-row + contiguous slice + shift.
- *  ``partition_window`` — stable partition of a leaf's range by the
-    split decision.  Per-tile stable compaction runs in a Pallas
-    kernel under one of TWO routing strategies (``LGBM_TPU_REC_ROUTING``,
-    read once at import; kernels also take an explicit ``routing=``
-    static arg so tools/kernel_ab.py can A/B both in one process):
+ *  the FUSED grower (learners/fused.py, what a TPU chip runs) splits a
+    leaf with one launch pair: ``split_step_window`` — per [W, TILE]
+    tile of the leaf's window the go flags, a stable compaction, the
+    tile's left count and the smaller child's histogram, then the
+    sibling by subtraction, both children's split search and the two
+    ``hists`` rows in place — and ``place_runs``, in-order sliced DMA
+    landing each tile's left/right runs at their global offsets in the
+    ALIASED record (later tiles overwrite earlier garbage tails because
+    TPU grids execute sequentially).  Both take the window's tile count
+    as an OPERAND (a dynamic Mosaic grid), so one compiled body serves
+    every leaf size and no ``lax.cond`` stands round them.
+ *  the canonical grower under ``record_mode`` (learners/serial.py, the
+    parallel learners) partitions with ``partition_window`` — the same
+    compaction as a kernel of its own plus ``place_runs`` — and reads a
+    child's window back with ``unpack_window`` for its ``hist_fn``.
 
-    - ``prefix`` (DEFAULT): per-tile prefix-sum routing.  A lane
-      cumsum over the go bitmask yields each column's destination
-      offset directly — left rows land at ``cumsum(go)-1``, right rows
-      at ``cumsum(1-go)-1`` in the right half — and the columns move
-      through an LSB-first staged-shift compress network (Hacker's
-      Delight 7-4), ``2*ceil(log2(TILE))`` roll+select steps on the
-      VPU: O(TILE*log TILE) work per tile, O(n*log TILE) per level.
-    - ``onehot``: the round-3 design this replaced.  Destination
-      positions via strict-triangular MXU dots (no cumsum lowering), a
-      one-hot routing matrix applied to the four i32 byte planes
-      (bytes and 0/1 flags are exact in bf16, f32 accumulation — the
-      dots are EXACT at default MXU precision): O(TILE^2) MXU work per
-      tile, O(n*TILE) per level — ~85% of device FLOPs at 10M rows
-      moved rows instead of binning them (PR 10 phase attribution).
-      Kept selectable as the chip-validated fallback and A/B baseline.
+The compaction is per-tile prefix-sum routing: a lane cumsum over the
+go bitmask yields each column's destination offset directly, and the
+columns move through an LSB-first staged-shift compress network
+(Hacker's Delight 7-4), ``2*ceil(log2(TILE))`` roll+select steps on the
+VPU.  (A one-hot routing matrix on the MXU, O(TILE^2) a tile, was the
+first design; it read 8.3% and 9.7% slower a tree than this one in the
+benchmark's two cells, PERF.md PR 30, and is gone.)  Zero per-element
+descriptors anywhere.
 
-    Both routings produce BITWISE-IDENTICAL final partitions (pinned
-    by tests/test_partition_routing.py and tools/kernel_ab.py): the
-    runs' garbage tails differ, but every consumer masks or overwrites
-    garbage lanes by the run counts.  Placement is in-order sliced
-    async DMA landing each tile's left/right runs at their global
-    offsets — later tiles overwrite earlier garbage tails because TPU
-    grids execute sequentially.  Zero per-element descriptors anywhere.
- *  ``unpack_window`` — a child's contiguous [W, cap] slice back to
-    (bins, grad, hess, mask) for the histogram kernels: vectorized
-    shifts, no indexed access.
+Off the chip (``interpret=True``) two branches differ from what the
+chip runs: ``split_step_window`` reads a materialised window slice
+where the chip roll-merges two aligned blocks of the aliased record,
+and ``place_runs`` returns the XLA reference placement (``_xla_place``)
+where the chip runs its kernel.  analysis/kernel_parity.py holds both
+to numpy on the chip.
 """
 
 from __future__ import annotations
@@ -70,44 +67,16 @@ from jax.experimental.pallas import tpu as pltpu
 from ..obs.device_time import phase_scope
 from .totals import two_sum
 
-import os as _os
-
 # partition tile width; larger tiles halve the placement-scan step
-# count at more routing work per tile — quadratically more MXU dots
-# under onehot routing, one extra compress stage per doubling under
-# prefix routing (see ROUTING below)
-TILE = int(_os.environ.get("LGBM_TPU_REC_TILE", "512"))
-if TILE <= 0 or TILE % 128 != 0:
-    raise ValueError(
-        f"LGBM_TPU_REC_TILE must be a positive multiple of 128 (Mosaic "
-        f"lane alignment; the compaction kernel's DMA offsets and the "
-        f"cap%TILE assert both require it), got {TILE}"
-    )
+# count at one extra compress stage per doubling.  A positive multiple
+# of 128 (Mosaic lane alignment: the kernels' DMA offsets and the
+# cap % TILE asserts both require it).
+TILE = 512
 # place_runs step-table chunk per launch: a [8, steps] i32 SMEM prefetch
 # block is 32B/step (SMEM pads the minor dim to 128 lanes per ROW, hence
 # the transpose), and the 1MB SMEM budget caps one launch at ~16k steps
-# — a 10M-row window has ~78k.  Read at IMPORT like the other kernel
-# knobs (ADVICE r4): place_runs reads it at trace time, so a mid-process
-# flip would silently not apply to already-traced caps.
-PLACE_CHUNK = int(_os.environ.get("LGBM_TPU_PLACE_CHUNK", "16384"))
-if PLACE_CHUNK <= 0:
-    raise ValueError(
-        f"LGBM_TPU_PLACE_CHUNK must be positive, got {PLACE_CHUNK}")
-# partition compaction routing strategy (module docstring): "prefix" =
-# lane-cumsum destination offsets + staged-shift compress network
-# (O(TILE*log TILE)/tile), "onehot" = the [TILE, 2*TILE] MXU routing
-# dots (O(TILE^2)/tile, the round-3 design, kept as A/B baseline and
-# chip-validated fallback).  Read ONCE at import like the other kernel
-# knobs (ADVICE r4): the kernels read it at trace time, and jit caches
-# key only on shapes/static args, so a mid-process env flip would
-# silently half-apply.  The kernels' explicit ``routing=`` static arg
-# is the in-process override for A/B tooling.
-ROUTING = _os.environ.get("LGBM_TPU_REC_ROUTING", "prefix")
-if ROUTING not in ("onehot", "prefix"):
-    raise ValueError(
-        f"LGBM_TPU_REC_ROUTING must be 'onehot' or 'prefix', "
-        f"got {ROUTING!r}")
-
+# — a 10M-row window has ~78k.  place_runs reads it when it traces.
+PLACE_CHUNK = 16384
 
 def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
@@ -260,17 +229,6 @@ def _tile_go(tile, scal_i_ref, i, *, F, k):
     return (go * valid).astype(jnp.float32), valid.astype(jnp.float32)
 
 
-def _resolve_routing(routing):
-    """None -> the import default; anything else must be a known
-    strategy (an unrecognized string silently meaning 'onehot' would
-    make A/B tooling lie)."""
-    routing = routing or ROUTING
-    if routing not in ("onehot", "prefix"):
-        raise ValueError(
-            f"routing must be 'onehot' or 'prefix', got {routing!r}")
-    return routing
-
-
 def _lane_cumsum(g):
     """Inclusive prefix sum along the LANE axis of a [1, T] i32 row:
     ceil(log2(T)) Hillis-Steele roll+mask stages.  Mosaic has no
@@ -307,8 +265,7 @@ def _compress_half(tile, live, shift, nbits):
     carry stale values but a dead live flag, and dead lanes can never
     move or be kept.  Returns [R, T] with the live columns compacted to
     [0, count) in original order and GARBAGE beyond — every consumer
-    masks or overwrites garbage lanes via the run counts (same contract
-    as the one-hot path's zero lanes, which were equally meaningless).
+    masks or overwrites garbage lanes via the run counts.
     """
     R = tile.shape[0]
     T = tile.shape[-1]
@@ -328,16 +285,15 @@ def _compress_half(tile, live, shift, nbits):
     return work[:R]
 
 
-def _prefix_compact_body(tile, g, W):
-    """Prefix-sum routing (the ``routing="prefix"`` default): the
-    O(TILE*log TILE) replacement for the one-hot MXU compaction below.
-    A lane cumsum of the go row yields destination offsets directly —
-    lefts land at ``cumsum(go)-1``, everything else (the invalid tail
-    included, exactly like the one-hot path) at ``cumsum(1-go)-1`` in
-    the right half — and the columns move through two compress
-    networks (2*ceil(log2(T)) roll+select stages) instead of [T, 2T]
-    routing dots.  The i32 words move untouched (no bf16 byte-plane
-    round trip), so routed content is exact by construction.
+def _compact_body(tile, g):
+    """Stable compaction of one tile by prefix sums (shared by the
+    plain and the fused kernel).  A lane cumsum of the go row yields
+    destination offsets directly — lefts land at ``cumsum(go)-1``,
+    everything else (the invalid tail included) at ``cumsum(1-go)-1``
+    in the right half — and the columns move through two compress
+    networks (2*ceil(log2(T)) roll+select stages on the VPU:
+    O(TILE*log TILE) work a tile).  The i32 words move untouched, so
+    routed content is exact by construction.
 
     tile [W, T] i32, g [1, T] 0/1 row (f32 or i32; 1 = left AND valid)
     -> [W, 2T]: lefts compacted to [0, T), everything else to [T, 2T),
@@ -356,123 +312,14 @@ def _prefix_compact_body(tile, g, W):
     return jnp.concatenate([left, right], axis=1)
 
 
-def _compact_body(tile, g, W, routing=None):
-    """Shared stable-compaction math (used by both the plain and the
-    fused kernel): route tile columns so lefts land in [0, T) and
-    everything else in [T, 2T), original order inside each.
-
-    ``routing`` (static; None = module default ROUTING) picks the
-    prefix-sum network (above) or the one-hot MXU dots (below).
-
-    tile [W, T] i32, g [1, T] f32 ROW (1.0 = left, valid only) ->
-    [W, 2T].  The row form contracts directly on the lane axis — no
-    [1,T]->[T,1] in-kernel relayout and no column operand from XLA.
-    """
-    if _resolve_routing(routing) == "prefix":
-        return _prefix_compact_body(tile, g, W)
-    T = TILE
-    # strict-lower triangular: Lt[t, b] = 1.0 iff b < t; positions via
-    # MXU dots (inputs 0/1 -> exact at any precision, f32 accumulation)
-    t_i = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
-    b_i = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    lt = (b_i < t_i).astype(jnp.float32)
-    lte = (b_i <= t_i).astype(jnp.float32)
-    # position dots stay f32: their FLOPs are negligible (T-wide
-    # outputs) and Mosaic rejects bf16 dots with unit minor dims
-    # ('vector.broadcast' element-type verification, seen on-chip)
-    contract_lane = (((1,), (1,)), ((), ()))
-    lpos = jax.lax.dot_general(
-        lt, g, contract_lane,
-        preferred_element_type=jnp.float32)  # [T, 1] lefts before t
-    # inclusive count recovers the column-form flag without a relayout:
-    # g_col[t] = lefts(<=t) - lefts(<t) in {0.0, 1.0}
-    lpos_inc = jax.lax.dot_general(
-        lte, g, contract_lane, preferred_element_type=jnp.float32)
-    g_col = lpos_inc - lpos  # [T, 1]
-    rpos = jax.lax.dot_general(
-        lt, 1.0 - g, contract_lane,
-        preferred_element_type=jnp.float32)
-    # arithmetic select (g_col is exact 0/1 f32); the +T right-half
-    # offset is applied in INT after the cast — written as rpos + T it
-    # gets folded into the dot's accumulator init, which Mosaic rejects
-    # ("only neutral accumulator supported for float reduction")
-    pos = (g_col * lpos + (1.0 - g_col) * rpos).astype(jnp.int32)
-    pos = pos + (1 - g_col.astype(jnp.int32)) * T
-
-    return _route_bytes(tile, pos, W)
-
-
-def _route_bytes(tile, pos, W):
-    """Apply the one-hot routing matrix built from ``pos`` [T, 1] to the
-    four i32 byte planes.  The BYTE routing dots carry ~all the
-    compaction FLOPs (O(n*T) per level): bf16 inputs + f32 accumulation
-    are EXACT here — bytes are integers < 256 (8 mantissa bits suffice)
-    and the one-hot gives each output cell exactly one nonzero addend —
-    while cutting the MXU pass count 3x vs f32's bf16x3 decomposition
-    (these dots profiled ~1.2 s/tree of device time at 10M rows)."""
-    T = TILE
-    hot = (pos == jax.lax.broadcasted_iota(jnp.int32, (T, 2 * T), 1)
-           ).astype(jnp.bfloat16)  # [T, 2T] routing matrix
-    comp = jnp.zeros((W, 2 * T), jnp.int32)
-    for b in range(4):
-        byte = ((tile >> (8 * b)) & 0xFF).astype(jnp.bfloat16)
-        m = jax.lax.dot_general(
-            byte, hot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # [W, 2T]
-        comp = comp | (m.astype(jnp.int32) << (8 * b))
-    return comp
-
-
-def _compact_body_col(tile, g, W):
-    """Column-operand variant of the ONE-HOT _compact_body (g [T, 1]
-    f32): used by partition_window's ``routing="onehot"`` kernel, whose
-    go flags arrive as an explicit vector (a [nt, T] row-block operand
-    is not a legal Mosaic block shape — sublane dim 1 — while the
-    [cap, 1] column's (T, 1) block is).  The prefix path has no column
-    variant: its compress network runs on the lane axis, so
-    partition_window ships the go row sublane-aligned instead (see
-    _compact_kernel_prefix)."""
-    T = TILE
-    t_i = jax.lax.broadcasted_iota(jnp.int32, (T, T), 0)
-    b_i = jax.lax.broadcasted_iota(jnp.int32, (T, T), 1)
-    lt = (b_i < t_i).astype(jnp.float32)
-    lpos = jax.lax.dot_general(
-        lt, g, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)  # [T, 1] lefts before t
-    rpos = jax.lax.dot_general(
-        lt, 1.0 - g, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    pos = jnp.where(g > 0, lpos, rpos + T).astype(jnp.int32)  # [T, 1]
-    return _route_bytes(tile, pos, W)
-
-
-def _compact_kernel(win_ref, gcol_ref, out_ref, *, W):
-    """One grid step = one [W, T] tile: MXU one-hot stable compaction
-    (partition_window, ``routing="onehot"``).
-
-    win_ref  [W, T] i32    : this tile of the record window
-    gcol_ref [T, 1] i32    : go flags (1 = left, valid only)
-    out_ref  [1, W, 2T] i32: lefts compacted to [0, T), everything else
-                             to [T, 2T), original order inside each
-
-    Placement at the (unaligned) global run offsets happens in an XLA
-    dynamic-update-slice scan outside — Mosaic DMA slices must be
-    128-lane aligned, which arbitrary compaction offsets are not.
-    """
-    out_ref[0] = _compact_body_col(
-        win_ref[...], gcol_ref[...].astype(jnp.float32), W)
-
-
-def _compact_kernel_prefix(win_ref, grow_ref, out_ref, *, W):
-    """One grid step = one [W, T] tile: prefix-sum stable compaction
-    (partition_window, ``routing="prefix"``).  Same grid and output
-    contract as _compact_kernel, but the go flags arrive as ROW 0 of a
+def _compact_kernel(win_ref, grow_ref, out_ref):
+    """One grid step = one [W, T] tile of partition_window: stable
+    compaction, lefts to [0, T) and everything else to [T, 2T) of
+    ``out_ref`` [1, W, 2T].  The go flags arrive as ROW 0 of a
     sublane-aligned [8, T] operand — the compress network runs on the
     lane axis, and a bare [1, cap] row block (sublane dim 1) is not
-    Mosaic-legal while the one-hot path's [cap, 1] column would need an
-    in-kernel relayout to reach the lanes."""
-    out_ref[0] = _prefix_compact_body(win_ref[...], grow_ref[0:1, :], W)
-
+    Mosaic-legal."""
+    out_ref[0] = _compact_body(win_ref[...], grow_ref[0:1, :])
 
 
 def _hist_tile_body(tile, scal_i_ref, hacc_set, *, W, F, k, Bp,
@@ -535,36 +382,25 @@ def _hist_tile_body(tile, scal_i_ref, hacc_set, *, W, F, k, Bp,
             hacc_set(fi, contrib0)
 
 
-# NOTE on lineage: the round-4 fused compact+hist kernel pair
-# (_compact_hist_kernel / partition_hist_window) was deleted in round 5
-# — split_step_window superseded it (ADVICE r4).  Through round 6 every
-# surviving compaction path routed via the one-hot MXU dots; round 7
-# added the prefix-sum routing above and made it the default, keeping
-# one-hot selectable (LGBM_TPU_REC_ROUTING / the kernels' ``routing=``
-# static arg) as the A/B baseline and chip-validated fallback.  See the
-# module docstring for the two strategies' cost model.
-
-
 def _run_offsets(cl, cr):
     """Exclusive per-tile start offsets of the left/right runs within
-    their halves, from the per-tile left/right counts [nt].  ONE
-    definition of the offset convention — place_runs, split_step_window
-    and partition_window all consume it, so the three (previously
-    duplicated) constructions cannot drift apart."""
+    their halves, from the per-tile left/right counts [nt]."""
     loff = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(cl)])[:-1]
     roff = jnp.concatenate([jnp.zeros(1, jnp.int32), jnp.cumsum(cr)])[:-1]
     return loff, roff
 
 
-def _xla_place(rec, win, comp, loff, roff, nleft, iota, valid, do_split,
-               begin, cap, leaf_row=-1, left_leaf=None, right_leaf=None):
+def _xla_place(rec, comp, loff, roff, begin, pcnt, nleft, do_split, cap,
+               leaf_row, left_leaf, right_leaf):
     """Reference XLA placement: scan-of-DUS run packing + roll/merge +
-    optional leaf-id stamping + window write-back.  Shared by
-    partition_window, split_step_window, and
-    place_runs' interpret fallback — the hardware path (ops.record
-    place_runs kernel) is parity-checked against THIS implementation."""
+    optional leaf-id stamping + window write-back: place_runs'
+    interpret-mode fallback, so CPU tests run THIS and not the kernel
+    (analysis/kernel_parity.py holds the kernel to numpy on the chip)."""
     T = TILE
     W = rec.shape[0]
+    win = jax.lax.dynamic_slice(rec, (0, begin), (W, cap))
+    iota = jnp.arange(cap, dtype=jnp.int32)
+    valid = (iota < pcnt).astype(jnp.int32)
 
     def place(carry, x):
         lbuf, rbuf = carry
@@ -591,96 +427,6 @@ def _xla_place(rec, win, comp, loff, roff, nleft, iota, valid, do_split,
     return jax.lax.dynamic_update_slice(rec, out, (0, begin))
 
 
-def _write_window_kernel(scal_ref, prev_ref, cur_ref, rec_in_ref,
-                         rec_out_ref, *, nt):
-    """One grid step rewrites ONE T-lane block of the record that the
-    window [begin, begin+cap) touches: the window content is rotated
-    into block alignment (pltpu.roll by begin%T, dynamic) and merged
-    with the block's OLD content outside the window bounds.  Everything
-    uses supported constructs — dynamic BLOCK index maps, roll, and
-    arithmetic selects; no manual DMA (Mosaic rejects dynamically
-    lane-sliced HBM DMAs outright, aligned or not — probed on chip).
-
-    scal [3]: (begin // T, begin % T, last content block — the r == 0
-    surplus step clamps onto it, see write_window)
-    prev/cur: window blocks i-1 and i (the rotated block straddles two)
-    rec_in/rec_out: the SAME aliased record block at begin//T + i
-    """
-    T = TILE
-    i = pl.program_id(0)
-    r = scal_ref[1]
-
-    # A no-op grid step happens only when r == 0 (the window spans
-    # exactly nt blocks and step nt is surplus).  Its block index is
-    # CLAMPED onto the last content block; writing there would clobber
-    # the previous step's output with stale input (the aliased input
-    # block is not re-fetched on a same-index revisit), so skip.
-    @pl.when(i * T - r < nt * T)
-    def _():
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-        # source index into the window for lane t: i*T + t - r; valid
-        # (= inside the window) iff 0 <= idx < cap == nt*T
-        idx = i * T + lane - r
-        new_mask = ((idx >= 0) & (idx < nt * T)).astype(jnp.int32)
-        both = jnp.concatenate([prev_ref[...], cur_ref[...]], axis=1)
-        shifted = pltpu.roll(both, r, axis=1)[:, T:]
-        old = rec_in_ref[...]
-        rec_out_ref[...] = shifted * new_mask + old * (1 - new_mask)
-
-
-# opt-in escape hatch (on by default once chip-validated by
-# tools/tpu_parity_check.py check_writeback)
-ALIASED_WRITEBACK = _os.environ.get("LGBM_TPU_ALIASED_WRITEBACK", "1") != "0"
-
-
-@phase_scope("partition")
-def write_window(rec, out_win, begin, cap: int, interpret: bool = False):
-    """rec[:, begin:begin+cap] = out_win, with rec aliased in place so
-    the record threads tier-cond boundaries copy-free (the round-4
-    profile showed the plain dynamic-update-slice write-back forcing a
-    full-record copy, ~95 ms/tree at 1M, while the aliased histogram
-    buffer threaded the same conds copy-free).
-
-    Interpret mode (CPU tests) uses the semantically identical
-    dynamic-update-slice — the interpreter maps aliased outputs onto
-    read-only numpy views."""
-    if interpret or not ALIASED_WRITEBACK:
-        return jax.lax.dynamic_update_slice(rec, out_win, (0, begin))
-    W, n_pad = rec.shape
-    T = TILE
-    nt = cap // T
-    nb = nt + 1  # the rotated window straddles up to nt+1 blocks
-    scal = jnp.stack([
-        (begin // T).astype(jnp.int32),
-        (begin % T).astype(jnp.int32),
-        # last CONTENT block: the surplus step (r == 0 only) clamps
-        # here, revisiting a written block (and skipping its write)
-        # instead of touching a pristine one
-        ((begin + cap - 1) // T).astype(jnp.int32),
-    ])
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((W, T), lambda i, s: (0, jnp.maximum(i - 1, 0))),
-            pl.BlockSpec((W, T), lambda i, s: (0, jnp.minimum(i, nt - 1))),
-            pl.BlockSpec(
-                (W, T),
-                lambda i, s: (0, jnp.minimum(s[0] + i, s[2]))),
-        ],
-        out_specs=pl.BlockSpec(
-            (W, T), lambda i, s: (0, jnp.minimum(s[0] + i, s[2]))),
-    )
-    with phase_scope(f"partition.write.cap{cap}"):
-        return pl.pallas_call(
-            functools.partial(_write_window_kernel, nt=nt),
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct(rec.shape, rec.dtype),
-            input_output_aliases={3: 0},  # rec (incl. the prefetch arg)
-            interpret=interpret,
-        )(scal, out_win, out_win, rec)
-
-
 # Tiles between two folds of the split step's small accumulator into
 # its two-float running sum (see _fold_hacc): 8,192 rows at TILE=512.
 FOLD_TILES = 16
@@ -704,15 +450,15 @@ def _fold_hacc(hacc_ref, hhi_ref, hlo_ref):
 
 def _split_tile(tile, scal_i_ref, small_left, j, comp_ref, cnt_ref,
                 hacc_ref, hhi_ref, hlo_ref, *,
-                W, F, k, Bp, fgroup, routing=None):
+                W, F, k, Bp, fgroup):
     """Per-tile work of the split step: ONE in-kernel go computation
     (no [cap, 1] column operand from XLA — see _tile_go) shared by the
-    compaction (prefix or one-hot, per ``routing``), the per-tile
-    left-count output, and the histogram accumulation of the SMALLER
+    compaction, the per-tile left-count output, and the histogram
+    accumulation of the SMALLER
     child (``small_left`` 1.0 or 0.0: the left-going rows, or the valid
     rows that do not go left).  ``j`` is the tile ordinal (validity)."""
     govf, valid = _tile_go(tile, scal_i_ref, j, F=F, k=k)
-    comp_ref[0] = _compact_body(tile, govf, W, routing=routing)
+    comp_ref[0] = _compact_body(tile, govf)
     cnt_ref[...] = jnp.zeros((1, 128), jnp.int32) + jnp.sum(
         govf).astype(jnp.int32)
 
@@ -731,16 +477,15 @@ def _split_tile(tile, scal_i_ref, small_left, j, comp_ref, cnt_ref,
 
 def _split_step_kernel(
     scal_i_ref, scal_f_ref, *refs,
-    W, F, k, Bp, fgroup=8, direct_read=False, routing=None,
+    W, F, k, Bp, fgroup=8, direct_read=False,
 ):
-    """The WHOLE split step in one launch: per-tile MXU compaction +
+    """The WHOLE split step in one launch: per-tile compaction +
     smaller-child histogram accumulation (steps 0..nt-1), then subtract +
     two-child search + in-place histogram-buffer row updates (steps nt
-    and nt+1) — the union of the tile compaction and
-    pallas_search._fused_kernel, eliminating one launch (13-14 us on a
-    v5e for a Mosaic call of zero or one grid step, measured back to
-    back in a fori_loop: PERF.md, PR 27) plus the [Fp, 4, Bp] h_small
-    round trip through HBM per split.
+    and nt+1).  One launch and not two: a Mosaic call of zero or one
+    grid step costs 13-14 us on a v5e (measured back to back in a
+    fori_loop: PERF.md, PR 27), and the [Fp, 4, Bp] h_small never makes
+    a round trip through HBM.
 
     ``nt`` is the LIVE tile count, a run-time value (scal_i[10]; the
     grid is ``nt + 2 (+1 direct)`` steps, a dynamic bound): one body
@@ -837,7 +582,7 @@ def _split_step_kernel(
                 tile = ra * m + rb * (1 - m)
                 _split_tile(tile, scal_i_ref, small_left, i - 1,
                             comp_ref, cnt_ref, *sums, W=W, F=F, k=k,
-                            Bp=Bp, fgroup=fgroup, routing=routing)
+                            Bp=Bp, fgroup=fgroup)
 
             prev_ref[...] = cur
     else:
@@ -851,7 +596,7 @@ def _split_step_kernel(
             hists_out_ref[0] = hrow_ref[0]
             _split_tile(win_ref[...], scal_i_ref, small_left, i,
                         comp_ref, cnt_ref, *sums, W=W, F=F, k=k, Bp=Bp,
-                        fgroup=fgroup, routing=routing)
+                        fgroup=fgroup)
 
     @pl.when(i >= nt + off)
     def _():
@@ -999,14 +744,13 @@ def _place_table(begin, nleft, cl, cr, loff, roff,
 @phase_scope("partition")
 def place_runs(
     rec,  # [W, n_pad] i32 — DONATED, aliased in place
-    comp,  # [nt, W, 2T] i32 — the split kernel's compacted tiles
-    go,  # [cap] i32 decision column, or None when ``counts`` is given
+    comp,  # [nt, W, 2T] i32 — the compacted tiles
+    counts,  # (cl [nt], cr [nt]): each tile's left and right run length
     begin, pcnt, nleft, do_split,
     left_leaf, right_leaf,
     cap: int,
     leaf_row: int,
     interpret: bool = False,
-    counts=None,  # (cl [nt], cr [nt]) from the split kernel's cnt out
     live_tiles=None,  # run-time tile count <= cap // TILE (None = all)
 ):
     """Scatter the compacted runs into the record, aliased in place.
@@ -1021,25 +765,15 @@ def place_runs(
     W, n_pad = rec.shape
     T = TILE
     nt = cap // T
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    valid = (iota < pcnt).astype(jnp.int32)
     live = _live_tiles(live_tiles, nt)
-    if counts is not None:
-        cl, cr = counts
-    else:
-        gov = jnp.asarray(go).astype(jnp.int32) * valid
-        kt = gov.reshape(nt, T)
-        cl = jnp.sum(kt, axis=1, dtype=jnp.int32)
-        cr = jnp.sum(valid.reshape(nt, T) - kt, axis=1, dtype=jnp.int32)
+    cl, cr = counts
     loff, roff = _run_offsets(cl, cr)
 
     if interpret:
         # reference placement (the XLA path the kernel replaces)
-        win = jax.lax.dynamic_slice(rec, (0, begin), (W, cap))
         return _xla_place(
-            rec, win, comp, loff, roff, nleft, iota, valid, do_split,
-            begin, cap, leaf_row=leaf_row, left_leaf=left_leaf,
-            right_leaf=right_leaf)
+            rec, comp, loff, roff, begin, pcnt, nleft, do_split, cap,
+            leaf_row, left_leaf, right_leaf)
 
     steps = _place_table(begin, nleft, cl, cr, loff, roff,
                          left_leaf, right_leaf, do_split, nt, live)
@@ -1102,8 +836,7 @@ def _tile_counts(cnt, pcnt, live, nt: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("F", "cap", "k", "fgroup", "return_comp",
-                     "interpret", "routing"),
+    static_argnames=("F", "cap", "k", "fgroup", "interpret"),
     donate_argnums=(0,),
 )
 @phase_scope("split_step")
@@ -1117,18 +850,16 @@ def split_step_window(
     meta,  # [Fp, 4] — pallas_search._pack_meta
     F: int, cap: int, k: int,
     fgroup: int = 8,
-    return_comp: bool = False,
     interpret: bool = False,
-    routing: str | None = None,  # compaction routing (None = ROUTING)
     live_tiles=None,  # run-time tile count <= cap // TILE (None = all)
 ):
     """One-launch split step over window [begin, begin+cap): compaction
     + smaller-child histogram + subtract + two-child search + in-place
-    hists-row updates.  Returns (hists', rec', nleft, res[2, 16]) — or,
-    with ``return_comp``, (hists', comp, nleft, res, cl, cr, rec_pass)
-    where ``rec_pass`` is the kernel's aliased record pass-through that
-    MUST feed place_runs (feeding the original ``rec`` reintroduces
-    the full-record copy this chain eliminates).
+    hists-row updates.  Returns (hists', comp, nleft, res[2, 16], cl,
+    cr, rec_pass): the compacted tiles and their run lengths for
+    place_runs, and ``rec_pass``, the kernel's aliased record
+    pass-through that MUST feed place_runs (feeding the original
+    ``rec`` reintroduces the full-record copy this chain eliminates).
 
     The split decision AND the per-tile left counts live entirely in
     the kernel (_tile_go + the cnt output): the XLA side touches the
@@ -1149,11 +880,6 @@ def split_step_window(
     Tiles past the live count are never written, so their counts are
     masked here before anything reads them.
 
-    The child leaf ids are stamped into the record's leaf-id row (see
-    rec_height).  With ``return_comp`` the XLA placement (scan-of-DUS +
-    roll/merge) is SKIPPED and the raw compacted tiles come back for
-    ops.record.place_runs — the aliased placement kernel that replaces
-    that whole chain.
     """
     W, n_pad = rec.shape
     T = TILE
@@ -1243,7 +969,7 @@ def split_step_window(
         outs = pl.pallas_call(
             functools.partial(
                 _split_step_kernel, W=W, F=F, k=k, Bp=Bp,
-                fgroup=fgroup, direct_read=direct_read, routing=routing),
+                fgroup=fgroup, direct_read=direct_read),
             grid_spec=grid_spec,
             out_shape=out_shape,
             input_output_aliases=aliases,
@@ -1256,28 +982,14 @@ def split_step_window(
         rec_pass = rec
 
     cl, cr, nleft = _tile_counts(cnt, pcnt, live, nt)
-
-    if return_comp:
-        return hists_new, comp, nleft, res, cl, cr, rec_pass
-
-    loff, roff = _run_offsets(cl, cr)
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    valid = (iota < pcnt).astype(jnp.int32)
-    win = (data_in[0] if not direct_read
-           else jax.lax.dynamic_slice(rec_pass, (0, begin), (W, cap)))
-    rec2 = _xla_place(
-        rec_pass, win, comp, loff, roff, nleft, iota, valid, do_split,
-        begin, cap, leaf_row=num_words(F, k) + 4,
-        left_leaf=parent_slot, right_leaf=new_slot)
-    return hists_new, rec2, nleft, res
+    return hists_new, comp, nleft, res, cl, cr, rec_pass
 
 
 @functools.partial(
-    jax.jit, static_argnames=("cap", "leaf_row", "direct", "interpret",
-                              "routing"))
+    jax.jit, static_argnames=("cap", "leaf_row", "interpret"))
 @phase_scope("partition")
 def partition_window(
-    rec: jax.Array,  # [W, n_pad] i32 (aliased in-kernel when direct)
+    rec: jax.Array,  # [W, n_pad] i32
     go: jax.Array,  # [cap] i32: left-going (valid rows only)
     begin: jax.Array,
     pcnt: jax.Array,
@@ -1286,9 +998,7 @@ def partition_window(
     left_leaf: jax.Array | None = None,
     right_leaf: jax.Array | None = None,
     leaf_row: int = -1,  # record row to stamp child leaf ids into
-    direct: bool = False,  # aliased in-kernel placement (place_runs)
     interpret: bool = False,
-    routing: str | None = None,  # compaction routing (None = ROUTING)
 ):
     """Stably partition window [begin, begin+cap) of ``rec``: the
     parent's rows [0, pcnt) become left-rows ++ right-rows (original
@@ -1297,9 +1007,7 @@ def partition_window(
     exactly.  Returns (rec', nleft).  DataPartition::Split
     (data_partition.hpp:91-139) re-designed for the TPU memory system.
     With ``leaf_row`` >= 0 the child leaf ids are stamped over the
-    parent's kept range (see rec_height's leaf-id row).  ``routing``
-    picks the compaction strategy (module docstring); both produce
-    bitwise-identical results (tests/test_partition_routing.py).
+    parent's kept range (see rec_height's leaf-id row).
     """
     W = rec.shape[0]
     T = TILE
@@ -1307,11 +1015,10 @@ def partition_window(
     nt = cap // T
 
     win = jax.lax.dynamic_slice(rec, (0, begin), (W, cap))
-    iota = jnp.arange(cap, dtype=jnp.int32)
     # i32 from the start: pred (1-bit) arrays at [cap, 1]-ish shapes
     # bounce between bit layouts (measured ~80-100 ms/tree of copies;
     # callers pass go as i32 via serial._go_i32)
-    valid = (iota < pcnt).astype(jnp.int32)
+    valid = (jnp.arange(cap, dtype=jnp.int32) < pcnt).astype(jnp.int32)
     gov = jnp.asarray(go).astype(jnp.int32) * valid
     nleft = jnp.sum(gov, dtype=jnp.int32)
 
@@ -1322,39 +1029,24 @@ def partition_window(
     # each right-run's valid prefix lands at the right global offset;
     # the garbage beyond total-valid-rights is cut by the final selects
     cr = jnp.sum(valid.reshape(nt, T) - kt, axis=1, dtype=jnp.int32)
-    loff, roff = _run_offsets(cl, cr)
 
-    if _resolve_routing(routing) == "prefix":
-        # go flags ride ROW 0 of a sublane-aligned [8, cap] operand
-        # (see _compact_kernel_prefix); rows 1-7 are zero padding
-        kernel, flags = _compact_kernel_prefix, jnp.pad(
-            gov[None], ((0, 7), (0, 0)))
-        flag_spec = pl.BlockSpec((8, T), lambda i: (0, i))
-    else:
-        kernel, flags = _compact_kernel, gov.reshape(cap, 1)
-        flag_spec = pl.BlockSpec((T, 1), lambda i: (i, 0))
+    # go flags ride ROW 0 of a sublane-aligned [8, cap] operand (see
+    # _compact_kernel); rows 1-7 are zero padding
+    flags = jnp.pad(gov[None], ((0, 7), (0, 0)))
     with phase_scope(f"partition.compact.cap{cap}"):
         comp = pl.pallas_call(
-            functools.partial(kernel, W=W),
+            _compact_kernel,
             grid=(nt,),
-            in_specs=[pl.BlockSpec((W, T), lambda i: (0, i)), flag_spec],
+            in_specs=[pl.BlockSpec((W, T), lambda i: (0, i)),
+                      pl.BlockSpec((8, T), lambda i: (0, i))],
             out_specs=pl.BlockSpec((1, W, 2 * T), lambda i: (i, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((nt, W, 2 * T), jnp.int32),
             interpret=interpret,
         )(win, flags)
 
-    if direct and not interpret:
-        # aliased in-kernel placement: no scan-of-DUS and no copy of
-        # the record through downstream cond boundaries (place_runs
-        # itself falls back to _xla_place under interpret)
-        rec2 = place_runs(
-            rec, comp, gov, begin, pcnt, nleft, do_split,
-            left_leaf, right_leaf, cap=cap, leaf_row=leaf_row,
-            interpret=interpret)
-        return rec2, nleft
-
-    rec2 = _xla_place(
-        rec, win, comp, loff, roff, nleft, iota, valid, do_split, begin,
-        cap, leaf_row=leaf_row, left_leaf=left_leaf,
-        right_leaf=right_leaf)
+    # aliased in-kernel placement (the XLA reference under interpret)
+    rec2 = place_runs(
+        rec, comp, (cl, cr), begin, pcnt, nleft, do_split,
+        left_leaf, right_leaf, cap=cap, leaf_row=leaf_row,
+        interpret=interpret)
     return rec2, nleft

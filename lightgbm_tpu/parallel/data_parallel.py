@@ -191,11 +191,7 @@ def data_parallel_sharded(
         # the per-split shard search: ONE Pallas launch on TPU (the
         # jnp search compiles to ~60 small fusions, ~1.6 ms/split —
         # round-3 profile), the jnp reference path elsewhere/under f64.
-        # The knob is serial.py's import-time _KERN_ENV so a mid-process
-        # env flip can't leave DP and serial searches in different modes.
-        from ..learners.serial import _KERN_ENV
-
-        use_kernel_search = on_tpu() and _KERN_ENV
+        use_kernel_search = on_tpu()
 
         def search2_fn(hl, hr, lsg, lsh, lc, rsg, rsh, rc, can,
                        _fm, _nb, _ic, prm):

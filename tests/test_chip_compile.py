@@ -1,11 +1,11 @@
 """Compile the CHIP program with no chip.
 
-Tier-1 runs on the CPU, where the library picks segment-sum histograms
-and the jax.numpy search; on a TPU it picks the raw-layout Pallas path
-— a different program, which no CPU test executes.  libtpu can still
-compile it: select the TPU paths (device.assume_platform), lower the
-serial grower for a described v5e topology, and let Mosaic accept or
-refuse every kernel.  A refusal then fails here, not in a chip call.
+Tier-1 runs on the CPU, where the library selects the canonical grower
+(segment-sum histograms, the jax.numpy search); on a TPU it selects the
+fused grower (learners/fused.py) — a different program, whose kernels
+the CPU runs only interpreted.  libtpu can still compile it: select the
+TPU paths (device.assume_platform), lower the selected grower for a
+described v5e topology, and let Mosaic accept or refuse every kernel.  A refusal then fails here, not in a chip call.
 This says nothing about what the kernels compute (chip_smoke.py does).
 """
 
@@ -21,6 +21,7 @@ from lightgbm_tpu import device
 from lightgbm_tpu.config import Config
 from lightgbm_tpu.io.dataset import BinnedDataset
 from lightgbm_tpu.io.metadata import Metadata
+from lightgbm_tpu.learners import fused
 from lightgbm_tpu.models.gbdt import GBDT
 from lightgbm_tpu.objectives import create_objective
 
@@ -38,8 +39,8 @@ def topo():
 
 @pytest.fixture(scope="module")
 def grower(topo):
-    """``(lowered, compiled, jaxpr)`` of the serial grower for one v5e
-    chip."""
+    """``(lowered, compiled, jaxpr)`` of the selected grower for one
+    v5e chip."""
     n, F = 100_000, 28
     rng = np.random.RandomState(0)
     X = rng.randn(n, F).astype(np.float32)
@@ -50,7 +51,8 @@ def grower(topo):
         ds = BinnedDataset.from_matrix(X, Metadata(label=y), config=cfg)
         gbdt = GBDT(cfg, ds, create_objective(cfg, ds.metadata, n))
         grow = gbdt._grow  # functools.partial over the jitted grow_tree
-        assert grow.keywords["hist_fn_raw"] is not None  # the chip path
+        assert gbdt._grower == ("fused", "")
+        assert grow.func is fused.grow_tree  # the chip's grower
         on_chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
         args = jax.tree.map(
             lambda a: jax.ShapeDtypeStruct(
@@ -90,9 +92,9 @@ def test_scopes_and_kernel_names_in_the_compiled_grower(grower):
              set(re.findall(r'op_name="([^"]*)"', text))} - {None}
     scopes = {scope for scope, _ in found}
     assert scopes <= set(dt.SCOPE_NAMES), scopes - set(dt.SCOPE_NAMES)
-    # the fused path holds no _tier_chain: one launch pair a split at a
-    # run-time tile count (lgbm.grow.tier stays in the table for the
-    # paths that keep the chain: search hooks, pooled, canonical)
+    # the fused grower holds no _tier_chain: one launch pair a split at
+    # a run-time tile count (lgbm.grow.tier stays in the table for the
+    # canonical grower, which keeps the chain)
     assert scopes >= {s for s in dt.SCOPE_NAMES if ".grow." in s} - {
         "lgbm.grow.tier"} | {
         "lgbm.histogram", "lgbm.split_step", "lgbm.partition",
@@ -108,6 +110,28 @@ def test_scopes_and_kernel_names_in_the_compiled_grower(grower):
             r"|histogram\.cap\d+)(\.\d+)?", name), name
     assert sum(n.startswith("lgbm.split_step") for n in calls) == 1
     assert sum(n.startswith("lgbm.histogram.cap") for n in calls) == 1
+
+
+def test_the_compiled_grower_is_the_program_the_cells_ran_at_pr29(grower):
+    """PR 30 split ``grow_tree`` into two growers and moved nothing the
+    chip runs: the compiled program at this shape has the parent's
+    (``ea604a5``) three Mosaic calls, its one ``while`` (the split
+    loop) and no ``conditional`` anywhere, in or out of the loop (its
+    optimized HLO was the parent's instruction for instruction, 1,961 of
+    them: PERF.md, PR 30).  A ``lax.cond`` that comes back into
+    learners/fused.py shows here before it costs a record copy a split."""
+    from lightgbm_tpu.obs import device_time as dt
+
+    module = grower[1].runtime_executable().hlo_modules()[0]
+    prog = dt.program_of_module(module.as_serialized_hlo_module_proto())
+    count = {op: sum(ins.opcode == op for ins in prog.instrs.values())
+             for op in ("while", "conditional")}
+    assert count == {"while": 1, "conditional": 0}, count
+    assert sum(ins.target == "tpu_custom_call"
+               for ins in prog.instrs.values()) == 3
+    with open(fused.__file__) as fh:
+        source = fh.read()
+    assert "lax.cond" not in source and "_tier_chain" not in source
 
 
 def _eqns(jaxpr):

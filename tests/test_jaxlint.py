@@ -464,18 +464,18 @@ def test_donation_drop_is_detected():
     W = rec_mod.rec_height(4, 4)
     rec = jnp.zeros((W, 2 * T), jnp.int32)
     comp = jnp.zeros((1, W, 2 * T), jnp.int32)
-    go = jnp.zeros(T, jnp.int32)
+    counts = (jnp.full(1, T // 2, jnp.int32),) * 2  # a tile's run lengths
 
     def call_place(rec_):
         return rec_mod.place_runs(
-            rec_, comp, go, jnp.int32(0), jnp.int32(T), jnp.int32(T // 2),
+            rec_, comp, counts, jnp.int32(0), jnp.int32(T), jnp.int32(T // 2),
             jnp.bool_(True), jnp.int32(0), jnp.int32(1),
             cap=T, leaf_row=rec_mod.num_words(4, 4) + 4, interpret=True)
 
     # donating entry point: aliasing present
     ops, has_alias, warn, mem = _compile_entry(
         rec_mod.place_runs.lower(
-            rec, comp, go, jnp.int32(0), jnp.int32(T), jnp.int32(T // 2),
+            rec, comp, counts, jnp.int32(0), jnp.int32(T), jnp.int32(T // 2),
             jnp.bool_(True), jnp.int32(0), jnp.int32(1),
             cap=T, leaf_row=rec_mod.num_words(4, 4) + 4, interpret=True))
     assert has_alias and not warn
@@ -510,9 +510,9 @@ def test_record_multi_use_is_detected():
     W = rec_mod.rec_height(4, 4)
     rec = jnp.zeros((W, 2 * T), jnp.int32)
     comp = jnp.zeros((1, W, 2 * T), jnp.int32)
-    go = jnp.zeros(T, jnp.int32)
+    counts = (jnp.full(1, T // 2, jnp.int32),) * 2  # a tile's run lengths
     kw = dict(cap=T, leaf_row=rec_mod.num_words(4, 4) + 4, interpret=False)
-    args = (comp, go, jnp.int32(0), jnp.int32(T), jnp.int32(T // 2),
+    args = (comp, counts, jnp.int32(0), jnp.int32(T), jnp.int32(T // 2),
             jnp.bool_(True), jnp.int32(0), jnp.int32(1))
 
     def good(rec_):

@@ -8,8 +8,8 @@ serves every leaf size and the grower launches it outside any
   ``nleft`` and ``res`` of a launch over every tile of the window;
 * what the launch never wrote (``comp`` tiles and count groups past the
   live count) is masked before anything reads it;
-* a 3-tree model grown through the fused path equals the canonical
-  path's.
+* a 3-tree model grown by the fused grower equals the canonical
+  grower's.
 
 The hardware-only halves (the dynamic Mosaic grid, the aliased
 placement's run-time step count) are compiled by
@@ -60,15 +60,15 @@ def _split(rec, hists, scal_f, meta, begin, pcnt, do_split, live):
         jnp.array(hists), rec, jnp.int32(begin), jnp.int32(pcnt),
         jnp.bool_(do_split), jnp.int32(2), jnp.int32(7), jnp.bool_(False),
         jnp.int32(0), jnp.int32(2), scal_f, meta, F=_F, cap=_CAP, k=k,
-        return_comp=True, interpret=True, live_tiles=live)
+        interpret=True, live_tiles=live)
     return hs, comp, nleft, res, cl, cr, rec_pass
 
 
 def _place(rec_pass, comp, cl, cr, begin, pcnt, nleft, do_split, live):
     return R.place_runs(
-        jnp.array(rec_pass), comp, None, jnp.int32(begin), jnp.int32(pcnt),
-        nleft, jnp.bool_(do_split), jnp.int32(0), jnp.int32(2), cap=_CAP,
-        leaf_row=_LEAF_ROW, interpret=True, counts=(cl, cr),
+        jnp.array(rec_pass), comp, (cl, cr), jnp.int32(begin),
+        jnp.int32(pcnt), nleft, jnp.bool_(do_split), jnp.int32(0),
+        jnp.int32(2), cap=_CAP, leaf_row=_LEAF_ROW, interpret=True,
         live_tiles=live)
 
 
@@ -173,7 +173,8 @@ def test_place_table_live_steps_are_a_prefix():
 
 
 def _grow3(raw):
-    """Three boosting rounds of the grower on integer-valued gradients
+    """Three boosting rounds of a grower (``raw``: the fused one, else
+    the canonical one) on integer-valued gradients
     (exact in float32 under any accumulation order, as in
     tests/test_opt_layout.py): each round's gradients come from the
     leaves of the round before."""
@@ -190,9 +191,9 @@ def _grow3(raw):
 
 
 def test_three_tree_model_fused_equals_canonical():
-    """The fused path (raw kernels in interpret mode: one split-step
+    """The fused grower (kernels in interpret mode: one split-step
     launch and one placement a split, at the run-time tile count) grows
-    the canonical path's three trees."""
+    the canonical grower's three trees."""
     for (t0, l0), (t1, l1) in zip(_grow3(raw=False), _grow3(raw=True)):
         assert int(t0.num_leaves) == int(t1.num_leaves) > 4
         np.testing.assert_array_equal(

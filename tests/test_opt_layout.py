@@ -1,13 +1,14 @@
-"""The raw-layout opt path (grow_tree ``opt`` mode: raw [Fp, 4, Bp]
-histogram kernel + raw Pallas search, both in interpret mode on CPU)
-must grow the same trees as the canonical [F, B, 3] path."""
+"""The fused grower (learners/fused.py: raw [Fp, 4, Bp] histograms,
+the one-launch split step and the placement, all in interpret mode on
+the CPU) must grow the same trees as the canonical grower
+(learners/serial.py, [F, B, 3])."""
 
 import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from lightgbm_tpu.learners import fused
 from lightgbm_tpu.learners.serial import grow_tree, TreeLearnerParams
-from lightgbm_tpu.ops.pallas_histogram import histogram_single_leaf_raw
 
 
 def params(min_data=1, min_hess=0.0, l1=0.0, l2=0.0, min_gain=0.0,
@@ -17,17 +18,11 @@ def params(min_data=1, min_hess=0.0, l1=0.0, l2=0.0, min_gain=0.0,
         jnp.float32(l2), jnp.float32(min_gain), jnp.int32(max_depth))
 
 
-def _raw_hist_fn(num_bins):
-    def fn(bins_T, grad, hess, mask):
-        return histogram_single_leaf_raw(
-            bins_T, grad, hess, mask, num_bins=num_bins, interpret=True)
-    return fn
-
-
 def _grow(bins, grad, hess, num_bins, raw, max_leaves=16, bag=None,
-          is_cat=None, pool=0, **kw):
+          is_cat=None, **kw):
+    """``raw``: the fused grower; else the canonical one."""
     n, F = bins.shape
-    return grow_tree(
+    return (fused.grow_tree if raw else grow_tree)(
         jnp.asarray(bins.T.astype(np.uint8)),
         jnp.asarray(grad, jnp.float32),
         jnp.asarray(hess, jnp.float32),
@@ -39,14 +34,12 @@ def _grow(bins, grad, hess, num_bins, raw, max_leaves=16, bag=None,
         params(**kw),
         num_bins=num_bins,
         max_leaves=max_leaves,
-        hist_pool=pool,
-        hist_fn_raw=_raw_hist_fn(num_bins) if raw else None,
     )
 
 
 def _mk(n=4000, F=7, num_bins=23, seed=0):
     """Integer-valued grad/hess: histogram partial sums are then exact
-    in f32 under ANY accumulation order, so the opt path (MXU
+    in f32 under ANY accumulation order, so the fused grower (MXU
     triangular-dot suffix sums) and the canonical path (sequential
     reverse cumsum) compute bitwise-identical gains and must grow
     IDENTICAL trees — no tolerance needed, no near-tie flakiness."""
@@ -90,15 +83,6 @@ def test_opt_with_bagging_and_categorical():
     np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
 
 
-def test_opt_with_hist_pool():
-    bins, grad, hess = _mk(seed=2)
-    t0, l0 = _grow(bins, grad, hess, 23, raw=False, pool=4)
-    t1, l1 = _grow(bins, grad, hess, 23, raw=True, pool=4)
-    np.testing.assert_array_equal(
-        np.asarray(t0.split_feature), np.asarray(t1.split_feature))
-    np.testing.assert_array_equal(np.asarray(l0), np.asarray(l1))
-
-
 def test_opt_u16_bins_and_feature_mask():
     """max_bin > 256 stores u16 bins (2 per record word, k=2): the
     packed-record path must match the canonical path there too, and
@@ -111,7 +95,7 @@ def test_opt_u16_bins_and_feature_mask():
     fmask = np.array([True, False, True, True, False])
 
     def grow(raw):
-        return grow_tree(
+        return (fused.grow_tree if raw else grow_tree)(
             jnp.asarray(bins.T.astype(np.uint16)),
             jnp.asarray(grad), jnp.asarray(hess),
             jnp.ones(n, jnp.float32),
@@ -121,7 +105,6 @@ def test_opt_u16_bins_and_feature_mask():
             params(min_data=3),
             num_bins=num_bins,
             max_leaves=16,
-            hist_fn_raw=_raw_hist_fn(num_bins) if raw else None,
         )
 
     t0, l0 = grow(False)

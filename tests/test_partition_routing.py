@@ -1,26 +1,35 @@
-"""Bitwise parity of the two partition-routing strategies (ISSUE 12).
+"""The record's stable compaction against a numpy stable partition.
 
-``onehot`` (the round-3 [TILE, 2*TILE] MXU routing dots) and ``prefix``
-(lane-cumsum destination offsets + the staged-shift compress network,
-the import default since PR 12) must produce BYTE-IDENTICAL partitioned
-records — the compacted runs' garbage tails may differ, but everything
-the placement keeps must match exactly.  Property-style: random go
-patterns across TILE in {128, 256, 512}, ragged window caps, all-left /
-all-right / empty-leaf edges, and with the bagging-mask word populated.
+``partition_window`` (prefix-sum routing: lane-cumsum destination
+offsets + the staged-shift compress network, then the placement) must
+give BYTE for byte the record a numpy stable partition of the same
+window gives: random go patterns across TILE in {128, 256, 512}, ragged
+window caps, all-left / all-right / empty-leaf edges, an interior
+window, and with the bagging-mask word populated; and so must the fused
+split step's launch pair, whose histograms are held to float64 numpy.
+(Until PR 30 these cases pinned the prefix routing to a one-hot MXU
+routing: a path against a path.)
 
 The tests call ``partition_window.__wrapped__`` (the un-jitted body):
 the jit cache keys on shapes/static args but NOT on the module TILE
 global, so a monkeypatched TILE would silently hit a stale trace.
 """
 
+import os
+import re
+
 import numpy as np
 import pytest
 
 import jax.numpy as jnp
 
+import lightgbm_tpu
 import lightgbm_tpu.ops.record as R
+from lightgbm_tpu.analysis.kernel_parity import (
+    _fused_split, _np_hist, _np_partition)
 
 _F, _B = 6, 16
+_LEAF_ROW = R.num_words(_F, R.bins_per_word(jnp.uint8)) + 4
 
 
 def _mkrec(n, n_pad, seed=0, bag_frac=None):
@@ -40,16 +49,23 @@ def _mkrec(n, n_pad, seed=0, bag_frac=None):
     return rec
 
 
-def _partition_bytes(rec, go, begin, pcnt, cap, routing, do_split=True,
-                     leaf_row=None):
-    k = R.bins_per_word(jnp.uint8)
+def _partition(rec, go, begin, pcnt, cap, do_split=True):
+    """(record bytes, nleft) of partition_window on one window."""
     out, nleft = R.partition_window.__wrapped__(
-        rec, jnp.asarray(go, jnp.int32), jnp.int32(begin),
+        jnp.array(rec),  # called eagerly, place_runs donates its record
+        jnp.asarray(go, jnp.int32), jnp.int32(begin),
         jnp.int32(pcnt), jnp.bool_(do_split), cap,
         left_leaf=jnp.int32(0), right_leaf=jnp.int32(1),
-        leaf_row=(R.num_words(_F, k) + 4 if leaf_row is None else leaf_row),
-        interpret=True, routing=routing)
+        leaf_row=_LEAF_ROW, interpret=True)
     return np.asarray(out).tobytes(), int(nleft)
+
+
+def _numpy(rec, go, begin, pcnt, do_split=True):
+    """The same, from a numpy stable partition."""
+    if not do_split:
+        return np.asarray(rec).tobytes(), int(np.sum(go[:pcnt]))
+    out, nleft = _np_partition(rec, go, begin, pcnt, _LEAF_ROW, 0, 1)
+    return out.tobytes(), nleft
 
 
 @pytest.fixture(autouse=True)
@@ -62,7 +78,7 @@ def _restore_tile(monkeypatch):
 
 
 @pytest.mark.parametrize("tile", [128, 256, 512])
-def test_routing_parity_random_windows(tile, monkeypatch):
+def test_compaction_matches_numpy_random_windows(tile, monkeypatch):
     """Random go patterns over multi-tile windows, ragged pcnt."""
     monkeypatch.setattr(R, "TILE", tile)
     rng = np.random.RandomState(tile)
@@ -71,13 +87,12 @@ def test_routing_parity_random_windows(tile, monkeypatch):
     rec = _mkrec(n, cap + tile, seed=tile, bag_frac=0.7)
     for trial in range(3):
         go = (rng.rand(cap) < rng.choice([0.1, 0.5, 0.9])).astype(np.int32)
-        a = _partition_bytes(rec, go, 0, n, cap, "onehot")
-        b = _partition_bytes(rec, go, 0, n, cap, "prefix")
-        assert a == b, (tile, trial)
+        assert _partition(rec, go, 0, n, cap) == _numpy(rec, go, 0, n), (
+            tile, trial)
 
 
 @pytest.mark.parametrize("tile", [128, 512])
-def test_routing_parity_edges(tile, monkeypatch):
+def test_compaction_matches_numpy_edges(tile, monkeypatch):
     """All-left, all-right, empty leaf, and a no-op split."""
     monkeypatch.setattr(R, "TILE", tile)
     cap = 2 * tile
@@ -91,61 +106,80 @@ def test_routing_parity_edges(tile, monkeypatch):
          n, False),                            # do_split = False no-op
     ]
     for go, pcnt, do_split in cases:
-        a = _partition_bytes(rec, go, 0, pcnt, cap, "onehot",
-                             do_split=do_split)
-        b = _partition_bytes(rec, go, 0, pcnt, cap, "prefix",
-                             do_split=do_split)
-        assert a == b, (tile, pcnt, do_split)
+        assert (_partition(rec, go, 0, pcnt, cap, do_split=do_split)
+                == _numpy(rec, go, 0, pcnt, do_split=do_split)), (
+            tile, pcnt, do_split)
     # the all-left case really moved every valid row left
-    go = np.ones(cap, np.int32)
-    _, nleft = _partition_bytes(rec, go, 0, n, cap, "prefix")
+    _, nleft = _partition(rec, np.ones(cap, np.int32), 0, n, cap)
     assert nleft == n
 
 
-def test_routing_parity_interior_window(monkeypatch):
+def test_compaction_matches_numpy_interior_window():
     """A window that does not start at the record origin (begin > 0,
-    unaligned to TILE is not legal — begin is tile-aligned in the tier
-    chain — but a nonzero begin exercises the write-back offsets)."""
+    tile-aligned as in the tier chain): the placement's offsets."""
     tile = R.TILE
     cap = 2 * tile
     n = 3 * tile
     rec = _mkrec(n, n + cap, seed=3, bag_frac=0.6)
     rng = np.random.RandomState(4)
     go = rng.randint(0, 2, cap).astype(np.int32)
-    a = _partition_bytes(rec, go, tile, cap - 100, cap, "onehot")
-    b = _partition_bytes(rec, go, tile, cap - 100, cap, "prefix")
-    assert a == b
+    assert (_partition(rec, go, tile, cap - 100, cap)
+            == _numpy(rec, go, tile, cap - 100))
 
 
-def test_split_step_window_routing_parity():
-    """The fused mega-kernel path: all four outputs (hists, rec, nleft,
-    res) byte-identical across routings at the hlo_audit pinned shape."""
+def test_fused_split_step_matches_numpy():
+    """The fused grower's launch pair at the hlo_audit pinned shape:
+    record and ``nleft`` equal a numpy stable partition's, both
+    children's histogram rows a float64 numpy histogram's."""
+    from lightgbm_tpu.analysis.hlo_audit import _B as B, _F as F
     from lightgbm_tpu.analysis.hlo_audit import _split_step_inputs
 
-    outs = {}
-    for routing in ("onehot", "prefix"):
-        # fresh inputs per routing: hists is donated
-        rec, hists, scal_f, meta, s, cap, k = _split_step_inputs()
-        o = R.split_step_window(
-            hists, rec, s["begin"], s["pcnt"], s["do_split"], s["f"],
-            s["thr"], s["is_cat"], s["parent_slot"], s["new_slot"],
-            scal_f, meta, F=4, cap=cap, k=k, interpret=True,
-            routing=routing)
-        outs[routing] = [np.asarray(x) for x in o]
-    for name, a, b in zip(("hists", "rec", "nleft", "res"),
-                          outs["onehot"], outs["prefix"]):
-        assert a.tobytes() == b.tobytes(), name
+    rec, hists, scal_f, meta, s, cap, k = _split_step_inputs()
+    n, f, thr = int(s["pcnt"]), int(s["f"]), int(s["thr"])
+    bins, g, h, m = (np.asarray(x) for x in R.unpack_window(
+        rec[:, :n], F, k, jnp.uint8))
+    left = bins[f] <= thr
+    parent = _np_hist(bins, g, h, m, B)
+    hists = hists.at[0, :F, :3, :B].set(jnp.asarray(parent, jnp.float32))
+    hists2, rec2, nleft, _, cl = _fused_split(
+        rec, hists, 0, n, f, thr, 0, 1, scal_f, meta, F, cap,
+        s["live_tiles"], True)
+    want_rec, want_nl = _np_partition(
+        rec, left, 0, n, R.num_words(F, k) + 4, 0, 1)
+    assert int(nleft) == want_nl == int(np.asarray(cl).sum())
+    assert np.asarray(rec2).tobytes() == want_rec.tobytes()
+    for row, side in ((0, left), (1, ~left)):
+        np.testing.assert_allclose(
+            np.asarray(hists2[row, :F, :3, :B]),
+            _np_hist(bins, g, h, m * side, B), rtol=0, atol=1e-4)
 
 
-def test_routing_knob_validates():
-    """The import-time knob only accepts the two strategies, and the
-    module default is one of them (prefix since PR 12)."""
-    assert R.ROUTING in ("onehot", "prefix")
-    with pytest.raises(Exception):
-        R.partition_window.__wrapped__(
-            _mkrec(64, 2 * R.TILE), jnp.zeros(R.TILE, jnp.int32),
-            jnp.int32(0), jnp.int32(64), jnp.bool_(True), R.TILE,
-            interpret=True, routing="bogus")
+_ENV_NAMES_LEFT = {
+    # read in models/gbdt.py; the cell that can judge each is named in
+    # ROADMAP.md queue 3, item 5
+    "LGBM_TPU_STOP_LAG", "LGBM_TPU_PREDICT_MATMUL",
+    "LGBM_TPU_PREDICT_ROW_CHUNK", "LGBM_TPU_FOREST_MAX_ROWS",
+    # the chaos hook (resilience/faults.py), named in gbdt.py's comments
+    "LGBM_TPU_FAULT",
+}
+
+
+def test_no_environment_selector_on_the_growers_path():
+    """No module under ops/ or learners/ reads (or names) an
+    ``LGBM_TPU_*`` environment variable, and models/ only the four
+    that wait for a cell to judge them and the chaos hook."""
+    root = os.path.dirname(lightgbm_tpu.__file__)
+    found = {}
+    for sub in ("ops", "learners", "models"):
+        for dirpath, _, files in os.walk(os.path.join(root, sub)):
+            for name in files:
+                if name.endswith(".py"):
+                    with open(os.path.join(dirpath, name)) as fh:
+                        for env in re.findall(r"LGBM_TPU_[A-Z0-9_]+",
+                                              fh.read()):
+                            found.setdefault(sub, set()).add(env)
+    assert not found.get("ops") and not found.get("learners"), found
+    assert found.get("models", set()) <= _ENV_NAMES_LEFT, found
 
 
 def test_prefix_lane_cumsum_matches_numpy():

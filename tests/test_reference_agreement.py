@@ -3,7 +3,8 @@
 Tier-1 compared the program's paths with each other for a long time and
 passed while every objective but L2 grew a wrong first leaf (ROADMAP,
 queue 3, item 1).  Here three trees go through ``Booster.update`` on the
-default float32 path at each benchmark configuration's rehearsal size, the
+default float32 path (and once more through the fused grower a TPU chip
+runs, interpreted) at each benchmark configuration's rehearsal size, the
 plain reference (``benchmarks/references/gbdt_replay.py``: numpy, float64
 sums, nothing of the program) follows them with the configuration's own
 objective, and ``check.verdict`` holds the comparison to the
@@ -50,8 +51,9 @@ def harness():
     sys.path.remove(BENCH)
 
 
-def numbers_of(harness, config: str) -> tuple[dict, dict]:
-    """``(numbers, limits)`` of three trees at the rehearsal size."""
+def numbers_of(harness, config: str, rows=None) -> tuple[dict, dict]:
+    """``(numbers, limits)`` of three trees at the rehearsal size, or at
+    ``rows`` rows."""
     cells, check, entry, train_steady = harness
     workload = config + ".train"
     cell = cells.assemble({"name": workload, "config": config,
@@ -59,6 +61,8 @@ def numbers_of(harness, config: str) -> tuple[dict, dict]:
                           cells.benchmark())
     run = entry.Run(entry.parse(["--workload", workload, "--seed", str(SEED),
                                  "--seconds", "0", "--rehearsal"]), cell)
+    if rows is not None:
+        run.generator_params["rows"] = rows
     run.traffic = {**run.traffic, "quiet_trees": 0,
                    "min_warmup_trees": run.traffic["checked_trees"]}
     state = train_steady.first_trees(run, train_steady.setup(run))
@@ -67,9 +71,30 @@ def numbers_of(harness, config: str) -> tuple[dict, dict]:
             check.limits_of(config))
 
 
+# rows of the fused grower's case: its kernels run interpreted here, a
+# tile at a time, and 12,000 rows keep a case near 12 s
+FUSED_ROWS = 12_000
+
+
 @pytest.mark.parametrize("config", configurations())
-def test_three_trees_agree_with_the_plain_reference(harness, config):
-    numbers, limits = numbers_of(harness, config)
+@pytest.mark.parametrize("grower", ["canonical", "fused"])
+def test_three_trees_agree_with_the_plain_reference(
+        harness, config, grower, monkeypatch):
+    """``canonical``: what the library selects on the CPU.  ``fused``:
+    the grower a TPU chip runs (learners/fused.py), its kernels in
+    interpret mode -- the path every ledger line comes from, held to the
+    plain reference off the chip too."""
+    from lightgbm_tpu.learners import fused
+    from lightgbm_tpu.models.gbdt import GBDT
+
+    rows = None
+    if grower == "fused":
+        monkeypatch.setattr(
+            GBDT, "select_grower", lambda self, row_mask=False: ("fused", ""))
+        rows = FUSED_ROWS
+    traces = fused.grow_tree._cache_size()
+    numbers, limits = numbers_of(harness, config, rows)
+    assert (fused.grow_tree._cache_size() > traces) == (grower == "fused")
     correct, table = harness[1].verdict(numbers, limits)
     assert correct, table
     assert numbers["count_mismatch"] == 0

@@ -1,0 +1,85 @@
+"""The one place that chooses the leaf-wise grower
+(``GBDT.select_grower``, models/gbdt.py): one case a row of its table.
+
+The fused grower (learners/fused.py) is what a TPU chip runs for serial
+float32 leaf-wise training; everything else gets the canonical grower
+(learners/serial.py), and the booster says why.  The TPU rows run under
+``device.assume_platform("tpu")``: the selection reads what it can
+observe, and no kernel is compiled here.
+"""
+
+import numpy as np
+import pytest
+
+from lightgbm_tpu import device
+from lightgbm_tpu.config import Config
+from lightgbm_tpu.io.dataset import BinnedDataset
+from lightgbm_tpu.io.metadata import Metadata
+from lightgbm_tpu.learners import fused, serial
+from lightgbm_tpu.models import gbdt as gbdt_mod
+from lightgbm_tpu.objectives import create_objective
+
+
+def _booster(platform, block_bytes=None, monkeypatch=None, **params):
+    n, F = 600, 5
+    rng = np.random.RandomState(0)
+    X = rng.randn(n, F).astype(np.float32)
+    y = (X[:, 0] + X[:, 1] > 0).astype(np.float32)
+    cfg = Config(objective="binary", num_leaves=7, min_data_in_leaf=5,
+                 **params)
+    if block_bytes is not None:
+        monkeypatch.setattr(fused, "HIST_BLOCK_BYTES_MAX", block_bytes)
+    with device.assume_platform(platform):
+        ds = BinnedDataset.from_matrix(X, Metadata(label=y), config=cfg)
+        return gbdt_mod.GBDT(cfg, ds, create_objective(cfg, ds.metadata, n))
+
+
+# (id, platform, config, which, a piece of the reason, the grow callable)
+_TABLE = [
+    ("tpu-serial-f32", "tpu", {}, "fused", "", fused.grow_tree),
+    ("cpu", "cpu", {}, "canonical", "platform=cpu", serial.grow_tree),
+    ("float64", "tpu", {"hist_dtype": "float64"}, "canonical",
+     "hist_dtype=float64", serial.grow_tree),
+    ("pool", "tpu", {"histogram_pool_size": 0.01}, "canonical",
+     "histogram_pool_size=0.01", serial.grow_tree),
+    ("hybrid", "tpu", {"tree_growth": "hybrid"}, "canonical",
+     "tree_growth=hybrid", None),
+    ("tree_learner=data", "tpu", {"tree_learner": "data"}, "canonical",
+     "tree_learner=data over 8 devices", None),
+    ("block-too-large", "tpu", {}, "canonical",
+     "over the split step's 4096 bytes", serial.grow_tree),
+]
+
+
+@pytest.mark.parametrize(
+    "platform,params,which,why,grow", [row[1:] for row in _TABLE],
+    ids=[row[0] for row in _TABLE])
+def test_select_grower(platform, params, which, why, grow, monkeypatch):
+    block = 4096 if "4096" in why else None
+    g = _booster(platform, block, monkeypatch, **params)
+    assert g._grower[0] == which and why in g._grower[1], g._grower
+    with device.assume_platform(platform):
+        assert g.select_grower() == g._grower  # asked again, same answer
+    if grow is not None:
+        assert g._grow.func is grow
+        assert "hist_fn_raw" not in g._grow.keywords
+    else:  # another learner's callable holds the canonical grower
+        assert getattr(g._grow, "func", None) not in (
+            fused.grow_tree, serial.grow_tree)
+    if which == "canonical" and "pool" in why:
+        assert g._grow.keywords["hist_pool"] >= 2
+    # the booster said which grower and why, once, at INFO
+    said = [m for m in gbdt_mod._LOGGED_PATHS if f"grower={which}" in m]
+    assert any(why in m for m in said), gbdt_mod._LOGGED_PATHS
+
+
+def test_a_row_mask_selects_the_canonical_grower():
+    """cv's bin-once path: the booster the chip would give the fused
+    grower re-selects when a base row mask arrives."""
+    g = _booster("tpu")
+    assert g._grower[0] == "fused" and g._grow.func is fused.grow_tree
+    with device.assume_platform("tpu"):
+        g.set_base_row_mask(np.arange(600) % 3 > 0)
+    assert g._grower == ("canonical", "base row mask")
+    assert g._grow.func is serial.grow_tree
+    assert g._grow.keywords["choice_by_mask_counts"] is True

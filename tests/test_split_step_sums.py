@@ -54,7 +54,7 @@ def _step(rec, parent, n, thr, lc, rc):
     meta = _pack_meta(jnp.ones(_F, bool), jnp.full(_F, _B, jnp.int32),
                       jnp.zeros(_F, bool), _FP)
     cap = R.round_up(n, _T)
-    hs, _, nleft, _ = R.split_step_window(
+    hs, _, nleft, *_ = R.split_step_window(
         jnp.asarray(hists), rec, jnp.int32(0), jnp.int32(n), jnp.bool_(True),
         jnp.int32(2), jnp.int32(thr), jnp.bool_(False), jnp.int32(0),
         jnp.int32(2), scal_f, meta, F=_F, cap=cap, k=_K, interpret=True)

@@ -6,7 +6,7 @@ Runs on whatever devices exist: a 1-device mesh on the real chip times
 the DP loop STRUCTURE (collectives degenerate but the program is the
 per-shard program: record compaction kernel + window histogram via the
 reduce-scatter hook + Pallas shard search + canonical buffer updates);
-the serial fast path (mega kernel) on the same rows is the yardstick.
+the serial fast path (the fused grower) on the same rows is the yardstick.
 
 Env: DPB_ROWS (default 1M), DPB_TREES (default 12), DPB_MODES
 (comma list from {serial,dp_record,dp_canonical}).

@@ -1,7 +1,7 @@
 """Bisect the DP-vs-serial on-chip gap: time grow_tree variants that
 add the data-parallel structure one piece at a time.
 
-  serial_opt    — default serial fast path (mega kernel)
+  serial_opt    — the fused grower (learners/fused.py)
   hooks_nomesh  — record partition + DP-style hooks (pallas search2 via
                   canonical layout, jnp root search) but NO shard_map:
                   isolates hook structure from SPMD
@@ -90,14 +90,11 @@ def main():
         "DB_MODES", "serial_opt,hooks_nomesh,dp_record").split(",")
 
     if "serial_opt" in modes:
-        from lightgbm_tpu.ops.pallas_histogram import (
-            make_single_hist_fn_raw)
+        from lightgbm_tpu.learners import fused
 
-        raw = make_single_hist_fn_raw(B)
-        timeit("serial_opt", lambda: grow_tree(
+        timeit("serial_opt", lambda: fused.grow_tree(
             bins_T, grad, hess, bag, fmask, nbpf, is_cat, params,
-            num_bins=B, max_leaves=L, hist_fn=hist_local,
-            hist_fn_raw=raw)[0].num_leaves)
+            num_bins=B, max_leaves=L)[0].num_leaves)
 
     if "hooks_nomesh" in modes:
         timeit("hooks_nomesh", lambda: grow_tree(
