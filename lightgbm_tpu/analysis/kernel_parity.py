@@ -17,7 +17,10 @@ share no code with it, on whatever backend is present:
   split  — the split step's record, counts and both children's
            histogram rows, and the root histogram kernel, vs a numpy
            stable partition and float64 numpy histograms (a demoted MXU
-           precision in the un-annotated one-hot dots shows there)
+           precision in the un-annotated one-hot dots shows there), with
+           the smaller child a third, under 5% (left, then right, at an
+           unaligned begin) and half of the parent; prints how many
+           histogram tiles the kernel ran of the parent's
   place  — place_runs (aliased placement) vs the numpy stable
            partition, at the static tile count and, as the grower
            launches it, over a wider window at a run-time tile count
@@ -65,15 +68,15 @@ def _np_partition(rec, go, begin, pcnt, leaf_row, left_leaf, right_leaf):
 def _fused_split(rec, hists, begin, pcnt, f, thr, left_leaf, right_leaf,
                  scal, meta, F, cap, live_tiles, interpret):
     """The fused grower's launch pair on one window (uint8 bins).
-    Returns (hists', rec', nleft, res, cl)."""
+    Returns (hists', rec', nleft, res, cl, histogram tiles run)."""
     import jax.numpy as jnp
 
     from ..ops.record import (
-        bins_per_word, num_words, place_runs, split_step_window)
+        bins_per_word, num_words, place_runs, split_step_counted)
 
     k = bins_per_word(jnp.uint8)
     i32 = jnp.int32
-    hists2, comp, nleft, res, cl, cr, rec_pass = split_step_window(
+    hists2, comp, nleft, res, cl, cr, rec_pass, ran = split_step_counted(
         jnp.array(hists), rec, i32(begin), i32(pcnt), jnp.bool_(True), i32(f),
         i32(thr), jnp.bool_(False), i32(left_leaf), i32(right_leaf),
         scal, meta, F=F, cap=cap, k=k, interpret=interpret,
@@ -83,12 +86,13 @@ def _fused_split(rec, hists, begin, pcnt, f, thr, left_leaf, right_leaf,
         jnp.bool_(True), i32(left_leaf), i32(right_leaf), cap=cap,
         leaf_row=num_words(F, k) + 4, interpret=interpret,
         live_tiles=live_tiles)
-    return hists2, rec2, nleft, res, cl
+    return hists2, rec2, nleft, res, cl, int(ran)
 
 
-def _split_case(rng, F, n, num_bins, integer, bag_frac, tie=None):
-    """One leaf of ``n`` rows and everything the split step needs of
-    it: returns (bins, g, h, bag, rec, meta)."""
+def _split_case(rng, F, n, num_bins, integer, bag_frac, tie=None, begin=0):
+    """One leaf of ``n`` rows, ``begin`` columns into its record, and
+    everything the split step needs of it: returns (bins, g, h, bag,
+    rec, meta)."""
     import jax.numpy as jnp
 
     from ..ops.pallas_search import _pack_meta
@@ -104,8 +108,10 @@ def _split_case(rng, F, n, num_bins, integer, bag_frac, tie=None):
         g = rng.randn(n).astype(np.float32)
         h = (rng.rand(n) + 0.5).astype(np.float32)
     bag = (rng.rand(n) < bag_frac).astype(np.float32)
-    rec = build_record(jnp.asarray(bins), jnp.asarray(g), jnp.asarray(h),
-                       jnp.asarray(bag), round_up(n, TILE) + TILE)
+    rec = build_record(
+        jnp.asarray(np.pad(bins, ((0, 0), (begin, 0)))),
+        *(jnp.asarray(np.pad(v, (begin, 0))) for v in (g, h, bag)),
+        round_up(n + begin, TILE) + TILE)
     meta = _pack_meta(jnp.ones(F, bool), jnp.full(F, num_bins, jnp.int32),
                       jnp.zeros(F, bool), round_up(F, 8))
     return bins, g, h, bag, rec, meta
@@ -148,7 +154,7 @@ def check_search(rng, log=print, interpret=False) -> bool:
             jnp.float32(1.0), *[jnp.float32(x) for x in (*tot[0], *tot[1])],
             prm.min_data_in_leaf, prm.min_sum_hessian_in_leaf,
             prm.lambda_l1, prm.lambda_l2, prm.min_gain_to_split)
-        _, _, _, res, _ = _fused_split(
+        _, _, _, res, _, _ = _fused_split(
             rec, _root_hists(bins, g, h, bag, B, 3, interpret), 0, n, f,
             thr, 0, 1, scal, meta, F, round_up(n, TILE), None, interpret)
         ref = find_best_split_leaves(
@@ -185,43 +191,56 @@ def check_split(rng, log=print, interpret=False) -> bool:
     from ..ops.pallas_search import _pack_scal
     from ..ops.record import TILE, bins_per_word, num_words, round_up
 
-    F, n, num_bins = 11, 5000, 37
-    f, thr = 4, 11
-    bins, g, h, bag, rec, meta = _split_case(
-        rng, F, n, num_bins, False, 0.8)
-    hists0 = _root_hists(bins, g, h, bag, num_bins, 7, interpret)
-    left = bins[f] <= thr
-    # the smaller child by bagged count is the one the kernel sums
-    cnt = [float((bag * m).sum()) for m in (left, ~left)]
-    scal = _pack_scal(*[jnp.float32(x) for x in (
-        1.0, 1., 2., cnt[0], -1., 2., cnt[1], 20., 1e-3, 0., 0., 0.)])
-    hists, rec2, nleft, _, _ = _fused_split(
-        rec, hists0, 0, n, f, thr, 0, 1, scal, meta, F, round_up(n, TILE),
-        None, interpret)
-
-    ok = True
+    F, n, num_bins = 11, 5000, 36
+    f = 4
     lr = num_words(F, bins_per_word(jnp.uint8)) + 4
-    want_rec, want_nl = _np_partition(rec, left, 0, n, lr, 0, 1)
-    if int(nleft) != want_nl:
-        log(f"  split nleft mismatch: {int(nleft)} vs {want_nl}")
-        ok = False
-    if not np.array_equal(np.asarray(rec2), want_rec):
-        log("  split record differs from the numpy stable partition")
-        ok = False
-    # both histogram kernels against float64 numpy: root (single-leaf
-    # kernel) and the two children (in-kernel tile histogram + subtract)
-    want = [_np_hist(bins, g, h, bag * m, num_bins)
-            for m in (np.ones(n), left, ~left)]
-    got = [np.asarray(hists0[0]), np.asarray(hists[0]), np.asarray(hists[1])]
-    d_ref = max(float(np.abs(gk[:F, :3, :num_bins] - w).max())
-                for gk, w in zip(got, want))
-    if d_ref > HIST_REF_TOL:
-        log(f"  split hists vs float64 numpy diff {d_ref} "
-            f"(> {HIST_REF_TOL}: MXU precision demoted?)")
-        ok = False
-    log(f"split parity: {'OK' if ok else 'FAIL'} (nleft={int(nleft)}, "
-        f"hist maxdiff kernel-float64={d_ref:.2e})")
-    return ok
+    all_ok = True
+    # (threshold, begin): the smaller child a third of the parent; under
+    # 5% of it, left and then right, the window unaligned; half of it
+    for thr, begin in ((11, 0), (0, 777), (34, 1291), (17, 0)):
+        bins, g, h, bag, rec, meta = _split_case(
+            rng, F, n, num_bins, False, 0.8, begin=begin)
+        hists0 = _root_hists(bins, g, h, bag, num_bins, 7, interpret)
+        left = bins[f] <= thr
+        # the smaller child by bagged count is the one the kernel sums
+        cnt = [float((bag * m).sum()) for m in (left, ~left)]
+        small = int((left if cnt[0] <= cnt[1] else ~left).sum())
+        scal = _pack_scal(*[jnp.float32(x) for x in (
+            1.0, 1., 2., cnt[0], -1., 2., cnt[1], 20., 1e-3, 0., 0., 0.)])
+        hists, rec2, nleft, _, _, ran = _fused_split(
+            rec, hists0, begin, n, f, thr, 0, 1, scal, meta, F,
+            round_up(n, TILE), None, interpret)
+
+        ok = True
+        want_rec, want_nl = _np_partition(rec, left, begin, n, lr, 0, 1)
+        if int(nleft) != want_nl:
+            log(f"  split nleft mismatch: {int(nleft)} vs {want_nl}")
+            ok = False
+        if not np.array_equal(np.asarray(rec2), want_rec):
+            log("  split record differs from the numpy stable partition")
+            ok = False
+        # both histogram kernels against float64 numpy: root (single-leaf
+        # kernel) and the two children (the smaller from its staged rows,
+        # the larger by subtraction)
+        want = [_np_hist(bins, g, h, bag * m, num_bins)
+                for m in (np.ones(n), left, ~left)]
+        got = [np.asarray(hists0[0]), np.asarray(hists[0]),
+               np.asarray(hists[1])]
+        d_ref = max(float(np.abs(gk[:F, :3, :num_bins] - w).max())
+                    for gk, w in zip(got, want))
+        if d_ref > HIST_REF_TOL:
+            log(f"  split hists vs float64 numpy diff {d_ref} "
+                f"(> {HIST_REF_TOL}: MXU precision demoted?)")
+            ok = False
+        if ran != -(-small // TILE):
+            log(f"  split ran {ran} histogram tiles for {small} rows")
+            ok = False
+        log(f"split parity: {'OK' if ok else 'FAIL'} (nleft={int(nleft)} of "
+            f"{n}, smaller child {small / n:.1%}, hist tiles / parent tiles "
+            f"= {ran} / {-(-n // TILE)}, hist maxdiff kernel-float64="
+            f"{d_ref:.2e})")
+        all_ok &= ok
+    return all_ok
 
 
 def check_place(rng, log=print, interpret=False) -> bool:
@@ -286,7 +305,7 @@ def check_place(rng, log=print, interpret=False) -> bool:
                 # slots 3 and 5 are written by the kernel's hists index
                 # maps — allocate past them (Pallas does not
                 # bounds-check them)
-                _, got, nl, _, cl = _fused_split(
+                _, got, nl, _, cl, _ = _fused_split(
                     rec, jnp.zeros((7, Fp, 4, Bp), jnp.float32), begin, n,
                     f, thr, 3, 5, scal, meta, F, cap_w, live, interpret)
                 cl = np.asarray(cl)

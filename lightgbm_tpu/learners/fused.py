@@ -53,9 +53,12 @@ from . import tables
 from .serial import TreeLearnerParams, default_search_fn
 
 # The whole [Fp, 4, Bp] block of a leaf is resident in the split step's
-# VMEM (five of them): past this many bytes of one block the kernel is
-# not offered.  The constant admits every Fp <= 1024 at 256 bins while
-# the deviceless compile refuses F = 384 (ROADMAP queue 2, item 3).
+# VMEM (five of them, beside the [W, 2*TILE] staging buffer of the
+# smaller child's rows): past this many bytes of one block the kernel
+# is not offered.  The constant admits every Fp <= 1024 at 256 bins
+# while the deviceless compile refuses F = 264 (256 is the widest it
+# takes; 264 before the staging: PERF.md section 7, row 5; ROADMAP
+# queue 2, item 3).
 HIST_BLOCK_BYTES_MAX = 1 << 22
 
 

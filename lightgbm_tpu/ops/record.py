@@ -24,15 +24,25 @@ Who calls what:
 
  *  the FUSED grower (learners/fused.py, what a TPU chip runs) splits a
     leaf with one launch pair: ``split_step_window`` — per [W, TILE]
-    tile of the leaf's window the go flags, a stable compaction, the
-    tile's left count and the smaller child's histogram, then the
-    sibling by subtraction, both children's split search and the two
-    ``hists`` rows in place — and ``place_runs``, in-order sliced DMA
-    landing each tile's left/right runs at their global offsets in the
-    ALIASED record (later tiles overwrite earlier garbage tails because
-    TPU grids execute sequentially).  Both take the window's tile count
-    as an OPERAND (a dynamic Mosaic grid), so one compiled body serves
+    tile of the leaf's window the go flags, a stable compaction and the
+    tile's left count; the smaller child's histogram; then the sibling
+    by subtraction, both children's split search and the two ``hists``
+    rows in place — and ``place_runs``, in-order sliced DMA landing
+    each tile's left/right runs at their global offsets in the ALIASED
+    record (later tiles overwrite earlier garbage tails because TPU
+    grids execute sequentially).  Both take the window's tile count as
+    an OPERAND (a dynamic Mosaic grid), so one compiled body serves
     every leaf size and no ``lax.cond`` stands round them.
+
+    Where the smaller child's histogram comes from: the compaction has
+    already laid that child's rows of a tile side by side, so each tile
+    step appends them to a [W, 2*TILE] staging buffer in VMEM
+    (_split_tile), and the one-hot body (_hist_tile_body) runs on a
+    FULL tile of staged rows whenever one has filled, and once on the
+    remainder before the search: ``ceil(rows / TILE)`` bodies a split
+    (the kernel counts them: _hist_tiles), where summing every tile of
+    the parent with the sibling masked ran 2.7 times as many (PERF.md,
+    PR 31).
  *  the canonical grower under ``record_mode`` (learners/serial.py, the
     parallel learners) partitions with ``partition_window`` — the same
     compaction as a kernel of its own plus ``place_runs`` — and reads a
@@ -200,9 +210,8 @@ def _tile_go(tile, scal_i_ref, i, *, F, k):
     (profiled ~300 ms/tree at 10M rows: {0,1:T(1,128)} ->
     {1,0:T(8,128)} relayouts of every tier's column).
 
-    Returns two [1, T] f32 rows: 1.0 = left AND valid (rows past pcnt
-    are 0), and the validity row itself (the rows of the parent that go
-    RIGHT are ``valid - go``).
+    Returns one [1, T] f32 row: 1.0 = left AND valid (rows past pcnt
+    are 0).
     scal_i layout: (.., .., .., .., f, thr, is_cat, pcnt) — indices 4-7.
     """
     T = TILE
@@ -226,7 +235,7 @@ def _tile_go(tile, scal_i_ref, i, *, F, k):
     # ARITHMETIC select: an i1-on-i1 arith.select fails legalization
     go = is_cat * (fv == thr).astype(jnp.int32) + (1 - is_cat) * (
         fv <= thr).astype(jnp.int32)
-    return (go * valid).astype(jnp.float32), valid.astype(jnp.float32)
+    return (go * valid).astype(jnp.float32)
 
 
 def _lane_cumsum(g):
@@ -322,17 +331,15 @@ def _compact_kernel(win_ref, grow_ref, out_ref):
     out_ref[0] = _compact_body(win_ref[...], grow_ref[0:1, :])
 
 
-def _hist_tile_body(tile, scal_i_ref, hacc_set, *, W, F, k, Bp,
-                    govf, fgroup=8):
-    """Shared child histogram accumulation over one [W, T] record
-    tile (used by _split_step_kernel via _split_tile).  ``govf`` [1, T]
-    flags the rows of the child that is accumulated: the smaller one's,
-    from the SAME decision row the compaction used (_tile_go); stats
-    stack on sublanes; the one-hot is born transposed against a sublane
-    iota and contracts the shared lane axis on the MXU — no relayouts.
+def _hist_tile_body(tile, hacc_set, *, F, k, Bp, live, fgroup=8):
+    """Histogram accumulation over one [W, T] tile of the smaller
+    child's STAGED rows (_split_step_kernel's one call).  ``live``
+    [1, T] flags the lanes that hold a row: all of them on a full
+    staged tile, the first ``fill`` at the drain.  Stats stack on
+    sublanes; the one-hot is born transposed against a sublane iota and
+    contracts the shared lane axis on the MXU — no relayouts.
 
     ``hacc_set(fi, contrib)`` accumulates [4, Bp] into feature row fi.
-    scal_i layout: (.., .., .., .., f, thr, is_cat, pcnt) — indices 4-7.
     """
     from .pallas_histogram import merge_stats, split_stats
 
@@ -346,7 +353,7 @@ def _hist_tile_body(tile, scal_i_ref, hacc_set, *, W, F, k, Bp,
         tile[Wb + 1: Wb + 2, :], jnp.float32)
     mrow = jax.lax.bitcast_convert_type(
         tile[Wb + 2: Wb + 3, :], jnp.float32)
-    mw = mrow * govf  # bagging mask restricted to the accumulated child
+    mw = mrow * live  # the bagging mask, on the lanes that hold a row
     # exact three-piece bf16 split of the stat rows: one MXU pass at
     # float32 accuracy (see pallas_histogram.split_stats)
     stats = split_stats(jnp.concatenate(
@@ -427,8 +434,9 @@ def _xla_place(rec, comp, loff, roff, begin, pcnt, nleft, do_split, cap,
     return jax.lax.dynamic_update_slice(rec, out, (0, begin))
 
 
-# Tiles between two folds of the split step's small accumulator into
-# its two-float running sum (see _fold_hacc): 8,192 rows at TILE=512.
+# Histogram tiles (of the smaller child's staged rows) between two folds
+# of the split step's small accumulator into its two-float running sum
+# (see _fold_hacc): 8,192 rows at TILE=512.
 FOLD_TILES = 16
 
 
@@ -448,42 +456,54 @@ def _fold_hacc(hacc_ref, hhi_ref, hlo_ref):
     hacc_ref[...] = jnp.zeros_like(hacc_ref)
 
 
-def _split_tile(tile, scal_i_ref, small_left, j, comp_ref, cnt_ref,
-                hacc_ref, hhi_ref, hlo_ref, *,
-                W, F, k, Bp, fgroup):
+def _split_tile(tile, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
+                stage_ref, fill_ref, *, F, k):
     """Per-tile work of the split step: ONE in-kernel go computation
     (no [cap, 1] column operand from XLA — see _tile_go) shared by the
-    compaction, the per-tile left-count output, and the histogram
-    accumulation of the SMALLER
-    child (``small_left`` 1.0 or 0.0: the left-going rows, or the valid
-    rows that do not go left).  ``j`` is the tile ordinal (validity)."""
-    govf, valid = _tile_go(tile, scal_i_ref, j, F=F, k=k)
-    comp_ref[0] = _compact_body(tile, govf)
-    cnt_ref[...] = jnp.zeros((1, 128), jnp.int32) + jnp.sum(
-        govf).astype(jnp.int32)
+    compaction, the per-tile left-count output, and the STAGING of the
+    smaller child's rows for the histogram (``small_left_b``: the
+    left-going rows, or the valid rows that do not go left).  ``j`` is
+    the tile ordinal (validity).
 
-    def hacc_set(fi, contrib):
-        hacc_ref[fi] = hacc_ref[fi] + contrib
+    The compaction has already put the child's rows of this tile side by
+    side: the lefts in lanes [0, T) of the compacted tile, the valid
+    rights as a PREFIX of [T, 2T) (the invalid tail follows them).  That
+    run is appended at lane ``fill`` of ``stage_ref`` [W, 2T] — one
+    dynamic roll, two lane masks, as the direct read and _place_kernel
+    move unaligned runs — so the histogram body (_split_step_kernel)
+    runs on full tiles of the child's rows and never sees the sibling's.
+    ``fill < T`` on entry and the run is at most T long, so the append
+    fits and at most one full tile is due after it."""
+    T = TILE
+    govf = _tile_go(tile, scal_i_ref, j, F=F, k=k)
+    comp = _compact_body(tile, govf)
+    comp_ref[0] = comp
+    nleft = jnp.sum(govf).astype(jnp.int32)
+    cnt_ref[...] = jnp.zeros((1, 128), jnp.int32) + nleft
 
-    _hist_tile_body(tile, scal_i_ref, hacc_set, W=W, F=F, k=k,
-                    Bp=Bp, fgroup=fgroup,
-                    govf=small_left * govf
-                    + (1.0 - small_left) * (valid - govf))
-
-    @pl.when(j % FOLD_TILES == FOLD_TILES - 1)
-    def _():
-        _fold_hacc(hacc_ref, hhi_ref, hlo_ref)
+    nvalid = jnp.clip(scal_i_ref[7] - j * T, 0, T)
+    run = jnp.where(small_left_b, nleft, nvalid - nleft)
+    half = jnp.where(small_left_b, comp[:, :T], comp[:, T:])
+    fill = fill_ref[0]
+    end = fill + run
+    rolled = pltpu.roll(half, fill, axis=1)  # lane t -> (t + fill) % T
+    lane = jax.lax.broadcasted_iota(jnp.int32, half.shape, 1)
+    stage_ref[:, :T] = jnp.where(
+        (lane >= fill) & (lane < end), rolled, stage_ref[:, :T])
+    stage_ref[:, T:] = jnp.where(lane < end - T, rolled, stage_ref[:, T:])
+    fill_ref[0] = end
 
 
 def _split_step_kernel(
     scal_i_ref, scal_f_ref, *refs,
     W, F, k, Bp, fgroup=8, direct_read=False,
 ):
-    """The WHOLE split step in one launch: per-tile compaction +
-    smaller-child histogram accumulation (steps 0..nt-1), then subtract +
-    two-child search + in-place histogram-buffer row updates (steps nt
-    and nt+1).  One launch and not two: a Mosaic call of zero or one
-    grid step costs 13-14 us on a v5e (measured back to back in a
+    """The WHOLE split step in one launch: per-tile compaction and
+    staging of the smaller child's rows, its histogram over full tiles
+    of them (steps 0..nt-1; the remainder drains at step nt), then
+    subtract + two-child search + in-place histogram-buffer row updates
+    (steps nt and nt+1).  One launch and not two: a Mosaic call of zero
+    or one grid step costs 13-14 us on a v5e (measured back to back in a
     fori_loop: PERF.md, PR 27), and the [Fp, 4, Bp] h_small never makes
     a round trip through HBM.
 
@@ -515,17 +535,29 @@ def _split_step_kernel(
     hists_out  : left row at the search step, right row on the last
     cnt_ref    : [1, 128] i32 per tile — lane 0 carries this tile's
                  LEFT count, so the XLA side derives cl/cr/nleft with
-                 no go vector (and no record read) at all
+                 no go vector (and no record read) at all; lane 1 of the
+                 LAST live tile's group carries how many histogram tile
+                 bodies the launch ran (_hist_tiles)
     hacc_ref   : VMEM scratch — the smaller child's histogram over the
-                 last few tiles (which child: the one with fewer bagged
-                 rows by the search's own exact counts, scal_f[3] and
-                 [7], as LightGBM takes the smaller leaf's rows and its
-                 sibling by subtraction: a sibling got from the LARGER
-                 child keeps the absolute rounding of two large sums in
-                 bins a hundredth their size), then the right-child
-                 stash between the last two steps
+                 last few staged tiles (which child: the one with fewer
+                 bagged rows by the search's own exact counts, scal_f[3]
+                 and [7], as LightGBM takes the smaller leaf's rows and
+                 its sibling by subtraction: a sibling got from the
+                 LARGER child keeps the absolute rounding of two large
+                 sums in bins a hundredth their size), then the
+                 right-child stash between the last two steps
     hhi_ref, hlo_ref : VMEM scratch — that histogram's running sum as
                  two floats (_fold_hacc)
+    stage_ref  : VMEM scratch [W, 2T] — the smaller child's rows,
+                 compacted across tiles (_split_tile appends, the body
+                 below takes lanes [0, T) and moves [T, 2T) down).  The
+                 one-hot body is bound by the VPU and costs the same for
+                 a row of the sibling as for one of the child: over every
+                 parent tile with the sibling masked it was 2.7 times
+                 the rows, 856 of the step's 1,141 ms a tree at 7.5M x
+                 100 (PERF.md, PR 31)
+    fill_ref   : SMEM scratch [2] — rows waiting in ``stage_ref``
+                 (< T between steps), and the histogram tiles run
     """
     from .pallas_search import (
         K_EPSILON, _child_search, _head_of, _tail_of, _tri)
@@ -533,10 +565,11 @@ def _split_step_kernel(
     if direct_read:
         (rec_ref, hrow_ref, meta_ref, hists_out_ref,
          comp_ref, res_ref, cnt_ref, rec_out_ref, hacc_ref,
-         hhi_ref, hlo_ref, prev_ref) = refs
+         hhi_ref, hlo_ref, stage_ref, fill_ref, prev_ref) = refs
     else:
         (win_ref, hrow_ref, meta_ref, hists_out_ref, comp_ref,
-         res_ref, cnt_ref, hacc_ref, hhi_ref, hlo_ref) = refs
+         res_ref, cnt_ref, hacc_ref, hhi_ref, hlo_ref, stage_ref,
+         fill_ref) = refs
 
     T = TILE
     i = pl.program_id(0)
@@ -547,14 +580,20 @@ def _split_step_kernel(
     last_step = nt + 1 + off
 
     small_left_b = scal_f_ref[3] <= scal_f_ref[7]
-    small_left = jnp.where(small_left_b, 1.0, 0.0)
     sums = (hacc_ref, hhi_ref, hlo_ref)
+    tile_args = (scal_i_ref, small_left_b)
+    tile_refs = (comp_ref, cnt_ref, stage_ref, fill_ref)
 
     @pl.when(i == 0)
     def _():
         hacc_ref[...] = jnp.zeros_like(hacc_ref)
         hhi_ref[...] = jnp.zeros_like(hhi_ref)
         hlo_ref[...] = jnp.zeros_like(hlo_ref)
+        # zeroed, not left as found: a lane past ``fill`` is masked by a
+        # multiplication, which a NaN would survive
+        stage_ref[...] = jnp.zeros_like(stage_ref)
+        fill_ref[0] = 0
+        fill_ref[1] = 0
 
     if direct_read:
         @pl.when(i <= nt)
@@ -567,7 +606,10 @@ def _split_step_kernel(
 
             @pl.when(i >= 1)
             def _():
-                hists_out_ref[0] = hrow_ref[0]
+                # (no parent pass-through into hists_out here, as the
+                # interpreted branch below needs: Mosaic writes an
+                # output block back when its index moves, and the search
+                # step fills this one before it does)
                 r = scal_i_ref[9]
                 # tile lanes [0, T-r) from prev[:, r:], lanes [T-r, T)
                 # from cur[:, :r): both the same right-rotation by
@@ -580,9 +622,7 @@ def _split_step_kernel(
                 lane = jax.lax.broadcasted_iota(jnp.int32, (W, T), 1)
                 m = (lane < (T - r)).astype(jnp.int32)
                 tile = ra * m + rb * (1 - m)
-                _split_tile(tile, scal_i_ref, small_left, i - 1,
-                            comp_ref, cnt_ref, *sums, W=W, F=F, k=k,
-                            Bp=Bp, fgroup=fgroup)
+                _split_tile(tile, *tile_args, i - 1, *tile_refs, F=F, k=k)
 
             prev_ref[...] = cur
     else:
@@ -594,15 +634,38 @@ def _split_step_kernel(
             # step) is an identity write, never garbage over a row the
             # search still needs
             hists_out_ref[0] = hrow_ref[0]
-            _split_tile(win_ref[...], scal_i_ref, small_left, i,
-                        comp_ref, cnt_ref, *sums, W=W, F=F, k=k, Bp=Bp,
-                        fgroup=fgroup)
+            _split_tile(win_ref[...], *tile_args, i, *tile_refs, F=F, k=k)
+
+    # The histogram body's ONE call: on a full tile of staged rows as
+    # soon as a tile step has filled one, and at the search step on
+    # whatever is left (``fill < T`` there: every tile step that filled
+    # a tile has emptied it here).
+    fill = fill_ref[0]
+
+    @pl.when((fill >= T) | ((i == search_step) & (fill > 0)))
+    def _():
+        def hacc_set(fi, contrib):
+            hacc_ref[fi] = hacc_ref[fi] + contrib
+
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        _hist_tile_body(stage_ref[:, :T], hacc_set, F=F, k=k, Bp=Bp,
+                        fgroup=fgroup, live=(lane < fill).astype(jnp.float32))
+        stage_ref[:, :T] = stage_ref[:, T:]
+        fill_ref[0] = jnp.maximum(fill - T, 0)
+        ran = fill_ref[1] + 1
+        fill_ref[1] = ran
+
+        @pl.when(ran % FOLD_TILES == 0)
+        def _():
+            _fold_hacc(*sums)
 
     @pl.when(i >= nt + off)
     def _():
-        # tail steps revisit tile nt-1's count block: identity rewrite
-        # so interpret mode never flushes it unwritten
-        cnt_ref[...] = cnt_ref[...]
+        # tail steps revisit tile nt-1's count block: an identity
+        # rewrite, so interpret mode never flushes it unwritten, with
+        # the histogram tiles run in its spare lane 1
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+        cnt_ref[...] = jnp.where(lane == 1, fill_ref[1], cnt_ref[...])
         if direct_read:
             rec_out_ref[...] = rec_ref[...]
 
@@ -834,13 +897,22 @@ def _tile_counts(cnt, pcnt, live, nt: int):
     return cl, cr, jnp.sum(cl, dtype=jnp.int32)
 
 
+def _hist_tiles(cnt, live):
+    """How many histogram tile bodies the launch ran, from lane 1 of
+    the last live tile's group of the split kernel's count row:
+    ``ceil(smaller child's rows / TILE)``, where a launch that summed
+    every parent tile with the sibling masked ran ``live`` of them."""
+    return jax.lax.dynamic_index_in_dim(
+        cnt[0], (live - 1) * 128 + 1, keepdims=False)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("F", "cap", "k", "fgroup", "interpret"),
     donate_argnums=(0,),
 )
 @phase_scope("split_step")
-def split_step_window(
+def split_step_counted(
     hists,  # [P, Fp, 4, Bp] f32 — DONATED, rows updated in place
     rec,  # [W, n_pad] i32
     begin, pcnt, do_split,
@@ -856,10 +928,12 @@ def split_step_window(
     """One-launch split step over window [begin, begin+cap): compaction
     + smaller-child histogram + subtract + two-child search + in-place
     hists-row updates.  Returns (hists', comp, nleft, res[2, 16], cl,
-    cr, rec_pass): the compacted tiles and their run lengths for
-    place_runs, and ``rec_pass``, the kernel's aliased record
+    cr, rec_pass, hist_tiles): the compacted tiles and their run
+    lengths for place_runs, ``rec_pass``, the kernel's aliased record
     pass-through that MUST feed place_runs (feeding the original
-    ``rec`` reintroduces the full-record copy this chain eliminates).
+    ``rec`` reintroduces the full-record copy this chain eliminates),
+    and the number of histogram tile bodies the launch ran (_hist_tiles;
+    the grower takes ``split_step_window``, which leaves it out).
 
     The split decision AND the per-tile left counts live entirely in
     the kernel (_tile_go + the cnt output): the XLA side touches the
@@ -951,8 +1025,10 @@ def split_step_window(
             # single-use — see the kernel docstring's copy note
             pl.BlockSpec((W, T), _rec_idx),
         ] if direct_read else []),
-        scratch_shapes=[pltpu.VMEM((Fp, 4, Bp), jnp.float32)] * 3 + (
-            [pltpu.VMEM((W, T), jnp.int32)] if direct_read else []),
+        scratch_shapes=[pltpu.VMEM((Fp, 4, Bp), jnp.float32)] * 3 + [
+            pltpu.VMEM((W, 2 * T), jnp.int32),  # staged child rows
+            pltpu.SMEM((2,), jnp.int32),  # their count, the tiles run
+        ] + ([pltpu.VMEM((W, T), jnp.int32)] if direct_read else []),
     )
     hists_idx = 2 + len(data_in)  # incl. the 2 prefetch args
     out_shape = [
@@ -982,7 +1058,13 @@ def split_step_window(
         rec_pass = rec
 
     cl, cr, nleft = _tile_counts(cnt, pcnt, live, nt)
-    return hists_new, comp, nleft, res, cl, cr, rec_pass
+    return (hists_new, comp, nleft, res, cl, cr, rec_pass,
+            _hist_tiles(cnt, live))
+
+
+def split_step_window(*args, **kwargs):
+    """``split_step_counted`` without its counter: the grower's call."""
+    return split_step_counted(*args, **kwargs)[:7]
 
 
 @functools.partial(

@@ -134,15 +134,22 @@ def test_the_compiled_grower_is_the_program_the_cells_ran_at_pr29(grower):
     assert "lax.cond" not in source and "_tier_chain" not in source
 
 
-def _eqns(jaxpr):
-    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+def _eqns_under(jaxpr, conds=()):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold,
+    each with the ``cond`` equations it sits under, outermost first."""
     for eqn in jaxpr.eqns:
-        yield eqn
+        yield eqn, conds
+        inner = conds + (eqn,) if eqn.primitive.name == "cond" else conds
         for param in eqn.params.values():
             for sub in param if isinstance(param, (list, tuple)) else [param]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    yield from _eqns(sub)
+                    yield from _eqns_under(sub, inner)
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs its equations hold."""
+    return (eqn for eqn, _ in _eqns_under(jaxpr))
 
 
 def test_the_one_histogram_kernel_keeps_the_bins_row_in_the_lanes(grower):
@@ -167,6 +174,51 @@ def test_the_one_histogram_kernel_keeps_the_bins_row_in_the_lanes(grower):
         e.params["dimension_numbers"] == (((1,), (1,)), ((), ()))
         and [v.aval.shape for v in e.invars] == [(16, C), (256, C)]
         for e in dots), [e.params["dimension_numbers"] for e in dots]
+
+
+def test_the_split_step_sums_full_tiles_of_staged_rows(grower):
+    """The split step's F-feature one-hot body is in the kernel ONCE,
+    under one condition, and that condition reads the staging count (the
+    kernel's SMEM scratch): it runs when the smaller child's compacted
+    rows have filled a tile, or are left over at the search step, and
+    not in the per-tile branch that compacts every tile of the parent.
+    There, with the sibling's rows masked, it was 2.7 times the rows and
+    856 of the step's 1,141 ms a tree at 7.5M x 100 (PERF.md, PR 31).
+    Read from the traced jaxpr, as the root kernel's shape is above."""
+    from lightgbm_tpu.ops import record as R
+
+    F, Bp, T = 28, 256, R.TILE
+    step = [e for e in _eqns(grower[2]) if e.primitive.name == "pallas_call"
+            and "lgbm.split_step.dyn" in str(e.source_info.name_stack)]
+    assert len(step) == 1, [str(e.source_info.name_stack) for e in step]
+    kernel = step[0].params["jaxpr"]
+    body = [(e, conds) for e, conds in _eqns_under(kernel)
+            if e.primitive.name == "dot_general"
+            and [v.aval.shape for v in e.invars] == [(16, T), (Bp, T)]]
+    # one unrolled copy: a dot a feature, one more for the padded ones
+    assert len(body) == F + (R.round_up(F, 8) > F), len(body)
+    assert all(e.params["dimension_numbers"] == (((1,), (1,)), ((), ()))
+               for e, _ in body)
+    assert {len(conds) for _, conds in body} == {1}
+    assert len({id(conds[0]) for _, conds in body}) == 1
+    cond = body[0][1][0]
+    # what the condition is computed from, back to the refs it reads
+    made_by = {v: e for e in kernel.eqns for v in e.outvars}
+    reads, todo = set(), [cond.invars[0]]
+    while todo:
+        eqn = made_by.pop(todo.pop(), None)
+        if eqn is not None:
+            if eqn.primitive.name == "get":
+                reads.add(eqn.invars[0])
+            todo += [v for v in eqn.invars if not hasattr(v, "val")]  # no Literals
+    staged = [v for v in kernel.invars
+              if "smem" in str(v.aval) and v.aval.shape == (2,)]
+    assert len(staged) == 1 and staged[0] in reads, (
+        [str(v.aval) for v in kernel.invars], [str(v.aval) for v in reads])
+    # the per-tile branch holds the compaction's rolls and no such dot
+    tile_work = {id(c) for e, conds in _eqns_under(kernel)
+                 if e.primitive.name == "roll" for c in conds}
+    assert tile_work and id(cond) not in tile_work
 
 
 def _reachable(prog, comps):

@@ -141,7 +141,7 @@ def test_fused_split_step_matches_numpy():
     left = bins[f] <= thr
     parent = _np_hist(bins, g, h, m, B)
     hists = hists.at[0, :F, :3, :B].set(jnp.asarray(parent, jnp.float32))
-    hists2, rec2, nleft, _, cl = _fused_split(
+    hists2, rec2, nleft, _, cl, _ = _fused_split(
         rec, hists, 0, n, f, thr, 0, 1, scal_f, meta, F, cap,
         s["live_tiles"], True)
     want_rec, want_nl = _np_partition(

@@ -3,8 +3,11 @@ SMALLER child's histogram from its rows and the sibling by subtraction,
 its accumulator folds into a two-float running sum, and a numerical left
 side's gradient and hessian are the histogram's own prefix.  Each was a
 way for a small leaf under a large node to inherit the large node's
-absolute rounding once hessians vary (PERF.md, PR 28).  Interpret mode,
-on the CPU.
+absolute rounding once hessians vary (PERF.md, PR 28).  Since PR 31 the
+smaller child's rows are STAGED, compacted across the parent's tiles,
+and the histogram body runs on full tiles of them: held here to float64
+numpy wherever the staging has an edge, with the count of tiles it ran.
+Interpret mode, on the CPU.
 """
 
 import numpy as np
@@ -36,17 +39,25 @@ def _window(n, thr, seed=0):
     return rec, bins, g, h, left
 
 
-def _hist64(bins, g, h, rows):
-    """[F, 3, B] float64 histogram of ``rows`` (bool)."""
+def _hist64(bins, g, h, rows, m=None):
+    """[F, 3, B] float64 histogram of ``rows`` (bool), weighted by the
+    bagging mask ``m``."""
+    m = np.ones_like(g) if m is None else m
     out = np.zeros((_F, 3, _B))
     for f in range(_F):
-        for s, v in enumerate((g, h, np.ones_like(g))):
+        for s, v in enumerate((g * m, h * m, m)):
             out[f, s] = np.bincount(bins[f, rows], v[rows].astype(np.float64),
                                     _B)
     return out
 
 
 def _step(rec, parent, n, thr, lc, rc):
+    return _step_counted(rec, parent, n, thr, lc, rc)[:3]
+
+
+def _step_counted(rec, parent, n, thr, lc, rc, begin=0):
+    """Both children's histograms, ``nleft`` and the histogram tiles the
+    kernel ran."""
     hists = np.zeros((3, _FP, 4, _BP), np.float32)
     hists[0, :_F, :3, :_B] = parent
     scal_f = _pack_scal(*[jnp.float32(x) for x in (
@@ -54,12 +65,13 @@ def _step(rec, parent, n, thr, lc, rc):
     meta = _pack_meta(jnp.ones(_F, bool), jnp.full(_F, _B, jnp.int32),
                       jnp.zeros(_F, bool), _FP)
     cap = R.round_up(n, _T)
-    hs, _, nleft, *_ = R.split_step_window(
-        jnp.asarray(hists), rec, jnp.int32(0), jnp.int32(n), jnp.bool_(True),
-        jnp.int32(2), jnp.int32(thr), jnp.bool_(False), jnp.int32(0),
-        jnp.int32(2), scal_f, meta, F=_F, cap=cap, k=_K, interpret=True)
+    hs, _, nleft, *_, ran = R.split_step_counted(
+        jnp.asarray(hists), rec, jnp.int32(begin), jnp.int32(n),
+        jnp.bool_(True), jnp.int32(2), jnp.int32(thr), jnp.bool_(False),
+        jnp.int32(0), jnp.int32(2), scal_f, meta, F=_F, cap=cap, k=_K,
+        interpret=True)
     hs = np.asarray(hs)
-    return hs[0, :_F, :3, :_B], hs[2, :_F, :3, :_B], int(nleft)
+    return hs[0, :_F, :3, :_B], hs[2, :_F, :3, :_B], int(nleft), int(ran)
 
 
 @pytest.mark.parametrize("small", ["left", "right"])
@@ -94,6 +106,64 @@ def test_a_bin_of_many_tiles_is_rounded_once():
     assert np.abs(small[:, 1] - want[:, 1].astype(np.float32)).max() <= \
         np.spacing(np.float32(want[:, 1].max()))
     np.testing.assert_array_equal(small[:, 2], want[:, 2])
+
+
+# The smaller child's rows in each of the parent's seven tiles (the last
+# holds 17 live rows): where the staging buffer has an edge.
+_STAGED = {
+    "fills_two_tiles_exactly": (_T, 0, 300, _T - 300, 0, 0, 0),
+    "straddles_inside_a_parent_tile": (300, 400, 0, 0, 100, 0, 5),
+    "full_tiles_back_to_back": (_T, _T, 500, 0, 0, 0, 0),
+    "no_rows_at_all": (0,) * 7,
+    "all_in_the_partly_invalid_last_tile": (0, 0, 0, 0, 0, 0, 9),
+}
+
+
+@pytest.mark.parametrize("begin", [0, 77])
+@pytest.mark.parametrize("small", ["left", "right"])
+@pytest.mark.parametrize("case", sorted(_STAGED))
+def test_the_staged_histogram_is_the_smaller_childs(case, small, begin):
+    """The smaller child's histogram from its staged rows, against
+    float64 numpy: a bagging mask with zeros, a window that starts
+    ``begin`` columns into the record and is followed by another leaf's
+    rows (which would go left, with gradients of 100), ``small`` on
+    either side.  The kernel ran ``ceil(rows / TILE)`` histogram tiles:
+    the full ones as they filled, the remainder at the drain."""
+    per_tile = _STAGED[case]
+    n, thr = 6 * _T + 17, 7
+    rng = np.random.RandomState(len(case))
+    is_small = np.zeros(n, bool)
+    for j, c in enumerate(per_tile):
+        live = min(_T, n - j * _T)
+        is_small[j * _T + rng.choice(live, c, replace=False)] = True
+    left = is_small if small == "left" else ~is_small
+    bins = rng.randint(0, _B, (_F, n)).astype(np.uint8)
+    bins[2] = np.where(left, rng.randint(0, thr + 1, n),
+                       rng.randint(thr + 1, _B, n))
+    g = rng.randn(n).astype(np.float32)
+    h = (0.9987 + 1e-3 * rng.randn(n)).astype(np.float32)
+    m = (rng.rand(n) < 0.8).astype(np.float32)
+    pad = ((0, 0), (begin, _T))  # the columns before, another leaf after
+    rec = R.build_record(
+        jnp.asarray(np.pad(bins, pad)),
+        jnp.asarray(np.pad(g, pad[1], constant_values=100.0)),
+        jnp.asarray(np.pad(h, pad[1], constant_values=1.0)),
+        jnp.asarray(np.pad(m, pad[1], constant_values=1.0)),
+        R.round_up(n, _T) + 3 * _T)
+    parent = _hist64(bins, g, h, np.ones(n, bool), m).astype(np.float32)
+    # the search's counts are bagged ones; the kernel sums the side with
+    # fewer, which the cases keep the one meant
+    lc, rc = (m * left).sum(), (m * ~left).sum()
+    assert (lc <= rc) == (small == "left")
+    hl, hr, nleft, ran = _step_counted(rec, parent, n, thr, lc, rc, begin)
+    assert nleft == left.sum()
+    got_small, got_large = (hl, hr) if small == "left" else (hr, hl)
+    want = _hist64(bins, g, h, is_small, m)
+    size = _hist64(bins, np.abs(g), h, is_small, m)
+    assert (np.abs(got_small - want) <= 3e-7 * size).all()
+    np.testing.assert_array_equal(got_small[:, 2], want[:, 2])
+    assert (parent - got_small).tobytes() == got_large.tobytes()
+    assert ran == -(-sum(per_tile) // _T) <= len(per_tile) + 1
 
 
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])
