@@ -72,6 +72,13 @@ SCOPES = (
     ("lgbm.leaf_update", "_post_grow_step: shrinkage, score update, "
      "thresholds (models/gbdt.py)"),
     ("lgbm.gradients", "the objective's jitted gradient programs"),
+    ("lgbm.rank.sort", "lambdarank's pair-gradient program "
+     "(objectives_rank.py), under lgbm.gradients: the gather of scores "
+     "into [queries, Q], both argsorts and the reorderings by them"),
+    ("lgbm.rank.pairs", "the same program's [C, Q, Q] pair arithmetic "
+     "and row sums, and the lax.map that carries the chunks"),
+    ("lgbm.rank.scatter", "the same program's two .at[idx].add back to "
+     "rows"),
     ("lgbm.predict", "matmul prediction (ops/predict_matmul.py)"),
     ("lgbm.grow.root", "grow_tree before the loop: root histogram, first "
      "search, initial state"),

@@ -268,3 +268,32 @@ def test_record_and_hists_stay_in_the_loop_carry(grower):
     kernels = [ins for ins in body if ins.target == "tpu_custom_call"]
     assert {dt.scope_of(k.op_name)[0] for k in kernels} == {
         "lgbm.split_step", "lgbm.partition"}
+
+
+@pytest.mark.parametrize("queries,Q", [(7109, 128), (100, 1024)])
+def test_the_pair_gradient_program_compiles_for_v5e_with_its_pairs_fused(
+        topo, queries, Q):
+    """``jit__lambdarank_grads`` at ``istella-s-220.train``'s size, for its
+    fullest bucket and its longest: a chunk's ``[C, Q, Q]`` pair tensors
+    are 64 MB each in float32 and a dozen of them are written down in
+    objectives_rank.py; the chip's compiler fuses them into their row
+    sums, and the program's temporaries stay under ONE such tensor (15.5
+    and 0.8 MiB here; PERF.md, PR 32).  Unfused, they would be HBM
+    traffic of a gigabyte a launch."""
+    from lightgbm_tpu import objectives_rank
+
+    n = 2_043_304
+    chunk = max(1, min(queries, (1 << 24) // (Q * Q)))
+    chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
+
+    compiled = objectives_rank._lambdarank_grads.lower(
+        shape((n,), jnp.float32), shape((queries, Q), jnp.int32),
+        shape((queries, Q), jnp.bool_), shape((queries, Q), jnp.int32),
+        shape((queries,), jnp.float32), shape((31,), jnp.float32),
+        shape((Q,), jnp.float32), shape((), jnp.float32), None,
+        num_data=n, chunk=chunk).compile()
+    assert chunk * Q * Q * 4 == 64 << 20
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
