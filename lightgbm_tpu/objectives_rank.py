@@ -19,24 +19,33 @@ Per pair (high=rank i, low=rank j, label_high > label_low):
   lambda_h += -delta_ndcg * p        lambda_l -= -delta_ndcg * p
   hess_{h,l} += 2 * delta_ndcg * p * (2 - p)
 
-Rows of equal score keep their row order (both sorts are stable); the
-reference's ``std::sort`` leaves it undefined.  The plain numpy statement
+Rows of equal score keep their row order (the sort by score is stable);
+the reference's ``std::sort`` leaves it undefined.  The plain numpy statement
 of the same equations, a query at a time, is
 ``benchmarks/references/lambdarank.py``; ``tests/test_rank_reference.py``
 holds this program to it.
 
 One jitted program (``jit__lambdarank_grads``) a length bucket, three
 scopes inside it under ``lgbm.gradients`` (obs/device_time.SCOPES):
-``lgbm.rank.sort`` (the gather of scores into ``[queries, Q]``, both
-argsorts and the reorderings by them), ``lgbm.rank.pairs`` (the
-``[C, Q, Q]`` pair arithmetic and its row sums, and the ``lax.map`` that
-carries the chunks) and ``lgbm.rank.scatter`` (the two ``.at[idx].add``
-back to rows).  ``init`` counts what a tree's gradients cost, once:
-``rank.queries``, ``rank.buckets``, ``rank.launches_per_tree`` (one launch
-a bucket), ``rank.label_pairs`` (pairs of rows of one query whose labels
-differ) and ``rank.pair_slots`` (cells of the padded pair tensors, of
-which each label-ordered pair fills one: their ratio is the padding's
-and the symmetry's waste).
+``lgbm.rank.sort`` (the gather of scores into ``[queries, Q]`` and the
+two ``lax.sort`` calls: every reordering between slot order and rank order
+rides through the sort that defines it as a payload operand, so scores,
+slots, labels and gains go out with the sort by score and the lambdas come
+back with a sort by slot; an XLA gather by the permutation costs 12-30 ns an
+element on the chip, a sort operand next to nothing), ``lgbm.rank.pairs``
+(the ``[C, Q, Q]`` pair arithmetic and its row sums, and the ``lax.map``
+that carries the chunks) and ``lgbm.rank.scatter`` (the sums' way back to
+rows, in the tree's LAST launch: it lays the buckets' sums end to end and
+every row reads its slot, one gather a channel by a map that ``init``
+builds; a scatter-add a bucket was 56 ms a tree on the chip where the
+two gathers are 30, PERF.md, PR 33).  ``init`` counts what a tree's gradients
+cost, once: ``rank.queries``, ``rank.buckets``, ``rank.launches_per_tree``
+(one launch a bucket), ``rank.label_pairs`` (pairs of rows of one query
+whose labels differ), ``rank.pair_slots`` (cells of the padded pair
+tensors, of which each label-ordered pair fills one: their ratio is the
+padding's and the symmetry's waste) and ``rank.row_slots`` (cells of the
+``[queries, Q]`` layouts: what every sort operand and every transfer
+between row order and slot order moves).
 """
 
 from __future__ import annotations
@@ -80,7 +89,6 @@ class LambdarankNDCG(ObjectiveFunction):
             inv_max_dcg[q] = 1.0 / m if m > 0 else 0.0
             per_label = np.bincount(lab.astype(np.int64))
             label_pairs += (len(lab) ** 2 - int((per_label ** 2).sum())) // 2
-        self._gains = jnp.asarray(self._gains_np, jnp.float32)
 
         # bucket queries by next-power-of-two length (min 16): each
         # bucket pads to its own bound, so pair work tracks the actual
@@ -88,104 +96,143 @@ class LambdarankNDCG(ObjectiveFunction):
         bucket_of = np.maximum(
             16, 1 << np.ceil(np.log2(np.maximum(sizes, 1))).astype(np.int64)
         )
+        gains32 = self._gains_np.astype(np.float32)
         self._buckets = []
-        pair_slots = 0
+        # row -> its slot in the buckets' [queries, Q] layouts laid end to end
+        row_slot = np.zeros(num_data, np.int32)
+        pair_slots = row_slots = 0
         for Qb in sorted(set(int(b) for b in bucket_of)):
             qsel = np.flatnonzero(bucket_of == Qb)
             bq = len(qsel)
+            # a query's rows fill its first slots, in row order
             pad_idx = np.full((bq, Qb), num_data, np.int32)
             for i, q in enumerate(qsel):
                 c = int(sizes[q])
                 pad_idx[i, :c] = np.arange(qb[q], qb[q + 1])
-            valid = pad_idx < num_data
+                row_slot[qb[q] : qb[q + 1]] = row_slots + i * Qb + np.arange(c)
             labels_padded = np.where(
-                valid, label_np[np.minimum(pad_idx, num_data - 1)], 0
+                pad_idx < num_data,
+                label_np[np.minimum(pad_idx, num_data - 1)], 0
             ).astype(np.int32)
+            # labels never change: their gains are looked up here, once
+            gains_padded = gains32[np.clip(labels_padded, 0, len(gains32) - 1)]
             # chunk queries to bound the [C, Q, Q] pair tensors to ~64MB
             chunk = max(1, min(bq, (1 << 24) // max(Qb * Qb, 1)))
             self._buckets.append((
                 jnp.asarray(pad_idx),
-                jnp.asarray(valid),
+                jnp.asarray(sizes[qsel], jnp.int32),
                 jnp.asarray(labels_padded),
+                jnp.asarray(gains_padded),
                 jnp.asarray(inv_max_dcg[qsel], jnp.float32),
                 jnp.asarray(position_discounts(Qb), jnp.float32),
                 chunk,
             ))
             pair_slots += -(-bq // chunk) * chunk * Qb * Qb
+            row_slots += bq * Qb
+        self._row_slot = jnp.asarray(row_slot)
         telemetry.count_many({
             "rank.queries": nq,
             "rank.buckets": len(self._buckets),
             "rank.launches_per_tree": len(self._buckets),
             "rank.label_pairs": label_pairs,
             "rank.pair_slots": pair_slots,
+            "rank.row_slots": row_slots,
         })
 
     def get_gradients(self, scores):
-        grad = jnp.zeros(self.num_data, jnp.float32)
-        hess = jnp.zeros(self.num_data, jnp.float32)
-        for pad_idx, valid, labels, imd, discounts, chunk in self._buckets:
-            g, h = _lambdarank_grads(
-                scores, pad_idx, valid, labels, imd, self._gains, discounts,
-                jnp.float32(self.sigmoid), None, self.num_data, chunk,
-            )
-            grad, hess = grad + g, hess + h
+        sigmoid = jnp.float32(self.sigmoid)
+        *first, (*bucket, chunk) = self._buckets
+        sums = tuple(
+            _lambdarank_grads(scores, *b, sigmoid, chunk=c) for *b, c in first)
+        grad, hess = _lambdarank_grads(  # the last launch takes them to rows
+            scores, *bucket, sigmoid, chunk=chunk, before=sums,
+            row_slot=self._row_slot)
         if self.weights is not None:
             grad, hess = grad * self.weights, hess * self.weights
         return grad, hess
 
 
-@functools.partial(jax.jit, static_argnames=("num_data", "chunk"))
+def _to_rank_order(s, slot, *payload):
+    """``s`` by score descending along axis 1, rows of equal score in slot
+    order, and ``slot`` and every ``payload`` array moved with it: they ride
+    the sort as operands.  The sorted ``slot`` is ``order``, rank -> slot,
+    what ``argsort(-s, stable=True)`` gives; the negated key is ``s`` in
+    rank order bit for bit."""
+    key_r, *moved = jax.lax.sort(
+        (-s, slot, *payload), dimension=1, num_keys=1, is_stable=True)
+    return (-key_r, *moved)
+
+
+def _to_slot_order(order, *payload):
+    """``payload`` arrays in rank order, back in slot order: ``order`` is a
+    permutation of the slots, so sorting by it puts every element back
+    where it came from, with no ``argsort(order)`` and no gather by it
+    (no key repeats: a stable sort would carry an index operand more)."""
+    return tuple(jax.lax.sort(
+        (order, *payload), dimension=1, num_keys=1, is_stable=False)[1:])
+
+
+@functools.partial(jax.jit, static_argnames=("chunk",))
 @phase_scope("gradients")
 def _lambdarank_grads(
     scores,
     pad_idx,
-    valid,
+    cnt,
     labels,
-    inv_max_dcg,
     gains,
+    inv_max_dcg,
     discounts,
     sigmoid,
-    weights,
-    num_data: int,
     chunk: int,
+    before=None,
+    row_slot=None,
 ):
+    """One bucket's sums of lambdas and of hessians, ``[queries * Q]`` in
+    slot order; or, given the buckets ``before`` it (their sums) and the
+    ``row_slot`` map, the gradients and hessians of every row.
+    ``pad_idx``, ``labels`` and ``gains`` are ``[queries, Q]`` in slot order
+    (a query's ``cnt`` rows first, then padding that points at row n)."""
+    num_data = scores.shape[0]
     nq, Q = pad_idx.shape
     nchunks = -(-nq // chunk)
     pad_q = nchunks * chunk - nq
     with phase_scope("rank.sort"):
         # pad scores with a sentinel slot at index n
         s_ext = jnp.concatenate([scores, jnp.zeros(1, scores.dtype)])
-        if pad_q:
+        if pad_q:  # queries of no rows fill the last chunk
             pad_idx = jnp.concatenate(
                 [pad_idx, jnp.full((pad_q, Q), num_data, pad_idx.dtype)]
             )
-            valid = jnp.concatenate([valid, jnp.zeros((pad_q, Q), bool)])
+            cnt = jnp.concatenate([cnt, jnp.zeros(pad_q, cnt.dtype)])
             labels = jnp.concatenate(
                 [labels, jnp.zeros((pad_q, Q), labels.dtype)])
+            gains = jnp.concatenate(
+                [gains, jnp.zeros((pad_q, Q), gains.dtype)])
             inv_max_dcg = jnp.concatenate(
                 [inv_max_dcg, jnp.zeros(pad_q, inv_max_dcg.dtype)])
 
     def one_chunk(args):
-        idx, vld, lab, imd = args
+        idx, c, lab, gain, imd = args
         with phase_scope("rank.sort"):
+            slot = jax.lax.broadcasted_iota(jnp.int32, idx.shape, 1)
+            # the first ``c`` slots are the valid ones, and so are the
+            # first ``c`` ranks: valid scores sort ahead of the padding's
+            # -inf, or tie with it and keep their place (a stable sort)
+            vld = slot < c[:, None]
             s = jnp.where(vld, s_ext[idx], -jnp.inf)  # [C, Q]
-            order = jnp.argsort(-s, axis=1, stable=True)  # rank -> slot
-            s_r = jnp.take_along_axis(s, order, axis=1)
-            l_r = jnp.take_along_axis(lab, order, axis=1)
-            v_r = jnp.take_along_axis(vld, order, axis=1)
-            cnt = vld.sum(axis=1)
+            s_r, order, l_r, g_r = _to_rank_order(s, slot, lab, gain)
             best = s_r[:, 0]
-            worst = jnp.take_along_axis(
-                s_r, jnp.maximum(cnt - 1, 0)[:, None], axis=1
-            )[:, 0]
+            worst = jnp.min(jnp.where(vld, s, jnp.inf), axis=1)
         with phase_scope("rank.pairs"):
+            # (a query of no rows reads best -inf, worst inf and NaN
+            # differences: ``cond`` is false on every pair of it, so its
+            # sums are exact zeros all the same)
             regularize = (best != worst)[:, None, None]
-            g_r = gains[jnp.clip(l_r, 0, gains.shape[0] - 1)]
             D = s_r[:, :, None] - s_r[:, None, :]  # s_high - s_low
             cond = (
                 (l_r[:, :, None] > l_r[:, None, :])
-                & v_r[:, :, None]
-                & v_r[:, None, :]
+                & vld[:, :, None]
+                & vld[:, None, :]
             )
             dcg_gap = g_r[:, :, None] - g_r[:, None, :]
             pd = jnp.abs(discounts[None, :, None] - discounts[None, None, :])
@@ -197,25 +244,22 @@ def _lambdarank_grads(
             lam_r = lam.sum(axis=2) - lam.sum(axis=1)  # high gets +, low -
             hes_r = hes.sum(axis=2) + hes.sum(axis=1)
         with phase_scope("rank.sort"):
-            # unsort back to slot order
-            unsort = jnp.argsort(order, axis=1, stable=True)
-            lam_s = jnp.take_along_axis(lam_r, unsort, axis=1)
-            hes_s = jnp.take_along_axis(hes_r, unsort, axis=1)
-        return lam_s, hes_s
+            return _to_slot_order(order, lam_r, hes_r)
 
     with phase_scope("rank.pairs"):  # the loop over chunks itself
-        idx_c = pad_idx.reshape(nchunks, chunk, Q)
-        vld_c = valid.reshape(nchunks, chunk, Q)
-        lab_c = labels.reshape(nchunks, chunk, Q)
-        imd_c = inv_max_dcg.reshape(nchunks, chunk)
-        lam, hes = jax.lax.map(one_chunk, (idx_c, vld_c, lab_c, imd_c))
+        lam, hes = jax.lax.map(one_chunk, (
+            pad_idx.reshape(nchunks, chunk, Q),
+            cnt.reshape(nchunks, chunk),
+            labels.reshape(nchunks, chunk, Q),
+            gains.reshape(nchunks, chunk, Q),
+            inv_max_dcg.reshape(nchunks, chunk),
+        ))
+        lam = lam.reshape(-1, Q)[:nq].reshape(-1)
+        hes = hes.reshape(-1, Q)[:nq].reshape(-1)
 
+    if row_slot is None:
+        return lam, hes
     with phase_scope("rank.scatter"):
-        flat_idx = pad_idx.reshape(-1)
-        grad = jnp.zeros(num_data + 1, jnp.float32).at[flat_idx].add(
-            lam.reshape(-1))[:num_data]
-        hess = jnp.zeros(num_data + 1, jnp.float32).at[flat_idx].add(
-            hes.reshape(-1))[:num_data]
-        if weights is not None:
-            grad, hess = grad * weights, hess * weights
+        grad = jnp.concatenate([*(b[0] for b in before), lam])[row_slot]
+        hess = jnp.concatenate([*(b[1] for b in before), hes])[row_slot]
     return grad, hess

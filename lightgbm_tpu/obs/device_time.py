@@ -74,11 +74,14 @@ SCOPES = (
     ("lgbm.gradients", "the objective's jitted gradient programs"),
     ("lgbm.rank.sort", "lambdarank's pair-gradient program "
      "(objectives_rank.py), under lgbm.gradients: the gather of scores "
-     "into [queries, Q], both argsorts and the reorderings by them"),
+     "into [queries, Q] and the two lax.sort calls that carry what they "
+     "reorder as operands (scores, slots, labels, gains out by score; "
+     "the row sums back by slot)"),
     ("lgbm.rank.pairs", "the same program's [C, Q, Q] pair arithmetic "
      "and row sums, and the lax.map that carries the chunks"),
-    ("lgbm.rank.scatter", "the same program's two .at[idx].add back to "
-     "rows"),
+    ("lgbm.rank.scatter", "the same program's way back to rows, in a "
+     "tree's last launch: the buckets' [queries, Q] sums laid end to end "
+     "and gathered by the row -> slot map (scatter-adds until PR 33)"),
     ("lgbm.predict", "matmul prediction (ops/predict_matmul.py)"),
     ("lgbm.grow.root", "grow_tree before the loop: root histogram, first "
      "search, initial state"),

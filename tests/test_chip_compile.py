@@ -270,30 +270,63 @@ def test_record_and_hists_stay_in_the_loop_carry(grower):
         "lgbm.split_step", "lgbm.partition"}
 
 
+# istella-s-220.train's buckets, (queries, Q): one launch each, the last
+# of them takes every bucket's sums back to rows
+ISTELLA_BUCKETS = ((189, 16), (1591, 32), (5163, 64), (7109, 128),
+                   (4164, 256), (929, 512), (100, 1024))
+
+
 @pytest.mark.parametrize("queries,Q", [(7109, 128), (100, 1024)])
 def test_the_pair_gradient_program_compiles_for_v5e_with_its_pairs_fused(
         topo, queries, Q):
     """``jit__lambdarank_grads`` at ``istella-s-220.train``'s size, for its
-    fullest bucket and its longest: a chunk's ``[C, Q, Q]`` pair tensors
-    are 64 MB each in float32 and a dozen of them are written down in
-    objectives_rank.py; the chip's compiler fuses them into their row
-    sums, and the program's temporaries stay under ONE such tensor (15.5
-    and 0.8 MiB here; PERF.md, PR 32).  Unfused, they would be HBM
-    traffic of a gigabyte a launch."""
+    fullest bucket and its longest (the tree's last launch): a chunk's
+    ``[C, Q, Q]`` pair tensors are 64 MB each in float32 and a dozen of
+    them are written down in objectives_rank.py; the chip's compiler fuses
+    them into their row sums, and the program's temporaries stay under ONE
+    such tensor (15.5 and 0.8 MiB at PR 32).  Unfused, they would be HBM
+    traffic of a gigabyte a launch.
+
+    And it reorders by its sorts: an XLA gather of a ``[C, Q]`` chunk by a
+    permutation costs the chip 12-30 ns an element (five of them were
+    212 of the program's 267 ms a tree: PERF.md, PR 33), a sort operand
+    next to nothing.  What is left of single-element movement is the
+    transfers between row order and slot order: ONE gather a launch, of
+    the scores ``[n + 1]`` into a chunk, and in the last launch TWO more,
+    of the buckets' sums ``[row_slots]`` into ``[n]`` rows; no scatter."""
     from lightgbm_tpu import objectives_rank
+    from lightgbm_tpu.obs import device_time as dt
 
     n = 2_043_304
     chunk = max(1, min(queries, (1 << 24) // (Q * Q)))
     chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
 
-    def shape(dims, dtype):
+    def shape(dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=chip)
 
+    last = (queries, Q) == ISTELLA_BUCKETS[-1]
+    ends = {}
+    if last:
+        ends = {"before": tuple((shape((q * w,)),) * 2
+                                for q, w in ISTELLA_BUCKETS[:-1]),
+                "row_slot": shape((n,), jnp.int32)}
     compiled = objectives_rank._lambdarank_grads.lower(
-        shape((n,), jnp.float32), shape((queries, Q), jnp.int32),
-        shape((queries, Q), jnp.bool_), shape((queries, Q), jnp.int32),
-        shape((queries,), jnp.float32), shape((31,), jnp.float32),
-        shape((Q,), jnp.float32), shape((), jnp.float32), None,
-        num_data=n, chunk=chunk).compile()
+        shape((n,)), shape((queries, Q), jnp.int32),
+        shape((queries,), jnp.int32), shape((queries, Q), jnp.int32),
+        shape((queries, Q)), shape((queries,)), shape((Q,)), shape(()),
+        chunk=chunk, **ends).compile()
     assert chunk * Q * Q * 4 == 64 << 20
     assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20
+    module = compiled.runtime_executable().hlo_modules()[0]
+    prog = dt.program_of_module(module.as_serialized_hlo_module_proto())
+    ops = {op: [ins for ins in prog.instrs.values() if ins.opcode == op]
+           for op in ("gather", "scatter", "sort")}
+    row_slots = sum(q * w for q, w in ISTELLA_BUCKETS)
+    assert row_slots == 2_938_352  # the cell's ``rank.row_slots``
+    to_rows = (f"f32[{row_slots}]", f"f32[{n}]")
+    assert sorted((prog.instrs[g.operands[0]].shape, g.shape)
+                  for g in ops["gather"]) == sorted(
+        [(f"f32[{n + 1}]", f"f32[{chunk},{Q}]")] + [to_rows] * 2 * last)
+    assert not ops["scatter"]
+    # out: key, slot, labels, gains; back: slot, the two sums
+    assert sorted(len(s.operands) for s in ops["sort"]) == [3, 4]
