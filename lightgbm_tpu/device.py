@@ -38,6 +38,18 @@ def on_tpu() -> bool:
     return platform() == "tpu"
 
 
+def vmem_bytes() -> int:
+    """VMEM of one TensorCore of the chip this process computes on, by
+    Pallas's table of device kinds; a v5e's 128 MiB where no TPU is
+    attached (the chip the deviceless compiles describe)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    try:
+        return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+    except ValueError:  # "Unsupported TPU device kind: cpu"
+        return 128 << 20
+
+
 @contextlib.contextmanager
 def assume_platform(name: str):
     """Select the code paths of platform ``name`` regardless of the

@@ -15,7 +15,12 @@ contiguous one and every buffer updated in place:
   subtraction, both children's split search, the two ``hists`` rows
   written in place) and ``place_runs`` (the compacted runs streamed back
   into the record, leaf ids stamped).  Both take the window's TILE COUNT
-  as an operand and are sized once, at the largest capacity.
+  as an operand and are sized once, at the largest capacity;
+* a table wider than one block of ``hists`` (256 features at 256 bins) is
+  walked in FEATURE CHUNKS by the root histogram, by the split step's
+  subtraction and search, and by its ``hists`` row traffic
+  (``chunking`` below; ops/pallas_histogram.py feature_chunk): one body
+  for every width, one chunk for every table the grower took before.
 
 There is no conditional here, and there must not be one round the
 record or ``hists``: a conditional's result is a buffer of its own, so a
@@ -42,7 +47,8 @@ from ..device import on_tpu
 from ..models.tree import Tree
 from ..obs import telemetry
 from ..obs.device_time import phase_scope
-from ..ops.pallas_histogram import FGROUP, make_single_hist_fn_raw
+from ..ops.pallas_histogram import (
+    FGROUP, feature_chunk, make_single_hist_fn_raw)
 from ..ops.pallas_search import _pack_meta, _pack_scal
 from ..ops import record
 from ..ops.record import (
@@ -52,21 +58,64 @@ from ..ops.record import (
 from . import tables
 from .serial import TreeLearnerParams, default_search_fn
 
-# The whole [Fp, 4, Bp] block of a leaf is resident in the split step's
-# VMEM (five of them, beside the [W, 2*TILE] staging buffer of the
-# smaller child's rows): past this many bytes of one block the kernel
-# is not offered.  The constant admits every Fp <= 1024 at 256 bins
-# while the deviceless compile refuses F = 264 (256 is the widest it
-# takes; 264 before the staging: PERF.md section 7, row 5; ROADMAP
-# queue 2, item 3).
-HIST_BLOCK_BYTES_MAX = 1 << 22
+
+class Chunking(NamedTuple):
+    """How the kernels walk a table's feature axis, and what the split
+    step keeps in VMEM for it (a booster computes it once,
+    ``GBDT._chunking``: ``select_grower`` reads ``fits``, the log line
+    ``said``, and the ``grow.*`` counters are these numbers)."""
+
+    chunk_features: int  # Fc: features a [Fc, 4, Bp] block holds
+    feature_chunks: int  # NC: blocks a launch walks
+    hist_block_bytes: int  # Fc * 4 * Bp * 4
+    record_words: int  # W: the packed record's height
+    vmem_bytes: int  # the wider of the loop's two launches (ops/record.py)
+    vmem_max: int  # what the gate admits: 3/4 of the chip's VMEM
+
+    @property
+    def fits(self) -> bool:
+        return self.vmem_bytes <= self.vmem_max
+
+    @property
+    def said(self) -> str:
+        return (f"{self.feature_chunks} chunk"
+                f"{'s' if self.feature_chunks > 1 else ''} of "
+                f"{self.chunk_features} features, record of "
+                f"{self.record_words} words, split step VMEM "
+                f"{self.vmem_bytes >> 20} of {self.vmem_max >> 20} MiB")
 
 
-def hist_block_fits(num_features: int, num_bins: int) -> bool:
-    """Does a leaf's ``[Fp, 4, Bp]`` float32 block pass the split step's
-    VMEM gate?"""
-    return (round_up(num_features, FGROUP) * round_up(num_bins, 128) * 16
-            <= HIST_BLOCK_BYTES_MAX)
+def chunking(num_features: int, num_bins: int) -> Chunking:
+    """The fused grower's one bound on a table's width, from what is
+    observed: the features, the bins (a uint8 table packs four to a
+    record word, a wider one two) and the chip's VMEM.
+
+    What is resident, in bytes as a function of ``(Fc, Bp, W)``
+    (ops/record.py split_step_vmem_bytes has the terms and what Mosaic
+    asked for beside them): the split step holds the accumulators of
+    EVERY chunk, ``3 * NC * Fc * 4 * Bp * 4``, four ``[Fc, 4, Bp]``
+    blocks of ``hists`` rows, eleven ``[W, TILE]`` blocks of the record
+    and its bodies' temporaries: 9.6 MiB at 100 columns of 256 bins,
+    54.5 MiB at 2,000, and the 96 MiB the gate admits of a v5e's 128 at
+    4,096 columns, where the accumulators are 48 and the record's blocks
+    and working tiles 32 (5,836 columns at 128 bins, where the record's
+    are the most, 45; 2,038 at 512).  ``place_runs`` keeps less at every height
+    (ops/record.py place_vmem_bytes); the root kernel and the search
+    hold one chunk's blocks whatever the width (ops/pallas_histogram.py
+    _hist_pallas_call).  The deviceless compile is the measure:
+    tests/test_chip_compile.py compiles the kernels at the widest table
+    this gate admits; obs/memmodel.py does not guess it."""
+    from ..device import vmem_bytes  # looked up a call: tests stand in
+
+    Fp, Bp = round_up(num_features, FGROUP), round_up(num_bins, 128)
+    Fc, NC = feature_chunk(Fp, Bp)
+    W = record.rec_height(num_features, 4 if num_bins <= 256 else 2)
+    return Chunking(
+        chunk_features=Fc, feature_chunks=NC,
+        hist_block_bytes=Fc * Bp * 16, record_words=W,
+        vmem_bytes=max(record.split_step_vmem_bytes(Fp, Bp, W),
+                       record.place_vmem_bytes(W)),
+        vmem_max=vmem_bytes() * 3 // 4)
 
 
 class _State(NamedTuple):
@@ -110,11 +159,13 @@ def grow_tree(
     cap = max(T, round_up(n, T))
 
     with phase_scope("grow.root"):
-        # constant per tree: the search's [Fp, 4] meta block
-        meta = _pack_meta(
-            feature_mask, num_bins_per_feature, is_categorical,
-            round_up(F, FGROUP))
         hist0 = hist_fn(bins_T, grad, hess, bag_mask)  # [Fp, 4, Bp]
+        # constant per tree: the search's meta, whole feature chunks of
+        # it (padded features never validate)
+        Fp, _, Bp = hist0.shape
+        Fc, NC = feature_chunk(Fp, Bp)
+        meta = _pack_meta(
+            feature_mask, num_bins_per_feature, is_categorical, NC * Fc)
         sum_g0, sum_h0, cnt0 = tables.root_sums(grad, hess, bag_mask)
         # the once-a-tree root search reads the canonical view
         root_best = default_search_fn(

@@ -237,6 +237,15 @@ class GBDT:
         # rollback support: keep per-iteration train score deltas off-device?
         # cheaper: recompute on rollback from stored trees (rare path).
 
+    @functools.cached_property
+    def _chunking(self):
+        """How the fused grower's kernels would walk this table's
+        feature axis (learners/fused.py ``chunking``), once a booster:
+        the selector's gate, the log line and the counters read it."""
+        from ..learners import fused
+
+        return fused.chunking(self.train_set.num_features, self._num_bins)
+
     def select_grower(self, row_mask: bool = False):
         """The ONE place that chooses between the two leaf-wise growers,
         from what it can observe.  Returns ``(which, why)``: ``"fused"``
@@ -264,13 +273,12 @@ class GBDT:
         elif row_mask:
             why = "base row mask"
         else:
-            from ..learners import fused
-
-            if fused.hist_block_fits(F, self._num_bins):
+            plan = self._chunking
+            if plan.fits:
                 return "fused", ""
-            why = (f"a leaf's histogram block at {F} features x "
-                   f"{self._num_bins} bins is over the split step's "
-                   f"{fused.HIST_BLOCK_BYTES_MAX} bytes")
+            why = (f"{F} features x {self._num_bins} bins: {plan.said}, "
+                   "the accumulators of every chunk and the record's "
+                   "blocks past the chip's VMEM")
         return "canonical", why
 
     def _serial_leafwise_grower(self):
@@ -279,6 +287,14 @@ class GBDT:
         if self._grower[0] == "fused":
             from ..learners import fused
 
+            # what the kernels walk, once a booster (obs/telemetry)
+            plan = self._chunking
+            telemetry.count_many({
+                "grow.feature_chunks": plan.feature_chunks,
+                "grow.chunk_features": plan.chunk_features,
+                "grow.hist_block_bytes": plan.hist_block_bytes,
+                "grow.record_words": plan.record_words,
+            })
             return functools.partial(
                 fused.grow_tree,
                 num_bins=self._num_bins,
@@ -471,7 +487,7 @@ class GBDT:
             search, part = "jnp", "leaf-id vector"
         elif which == "fused":
             hist, search = "pallas raw-layout", "pallas (in the split step)"
-            part = "packed record"
+            part = f"packed record, {self._chunking.said}"
         else:
             hist = "pallas" if self._use_pallas_hist() else "segment-sum"
             search = ("pallas" if on_tpu() and not self._use_f64_hist

@@ -33,6 +33,10 @@ Counters maintained by the library itself:
 * ``grow_traces`` / ``dp_grow_traces`` — retraces of the serial /
   data-parallel grow program (incremented at Python trace time inside
   the traced body, so each retrace counts exactly once).
+* ``grow.feature_chunks`` / ``grow.chunk_features`` /
+  ``grow.hist_block_bytes`` / ``grow.record_words`` — how the fused
+  grower's kernels walk the feature axis (learners/fused.py
+  ``chunking``), added once a booster that takes the fused grower.
 * ``host_syncs`` — deliberate device->host materialization points the
   library performs (eval fetches, lagged-stop drains, bench syncs).
 * ``collective_ops`` / ``collective_bytes`` — cross-device collectives

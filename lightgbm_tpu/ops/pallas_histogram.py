@@ -62,6 +62,27 @@ SINGLE_LEAF_CHUNK = 2048
 # the same bf16 dot — one pass, float32 accumulation — and the three
 # partial histograms are added afterwards.
 STAT_ROWS = 16  # 3 pieces x (grad, hess, count, 0), padded to a bf16 tile
+# A leaf's histogram is [Fp, 4, Bp] float32.  The kernels walk the
+# feature axis in chunks of at most this many bytes a block: 256
+# features at 256 bins, the widest block the split step held whole
+# before it had a chunk axis (PERF.md, PR 31), so every table it took
+# then is one chunk now.
+CHUNK_BLOCK_BYTES = 1 << 20
+
+
+def feature_chunk(Fp: int, Bp: int):
+    """``(Fc, NC)``: the features a kernel keeps in VMEM at a time and
+    how many such chunks the ``Fp`` features of a table are, from the
+    block's size alone.  A table of one chunk has ``Fc == Fp``; wider,
+    ``Fc`` is a whole number of the root kernel's LOOP_ROWS steps (a
+    packed uint8 tile of the bins block) and the last chunk may be
+    short: its block hangs over the arrays' edge, where Pallas reads
+    padding and writes nothing."""
+    fc = max(
+        LOOP_ROWS, CHUNK_BLOCK_BYTES // (16 * Bp) // LOOP_ROWS * LOOP_ROWS)
+    if Fp <= fc:
+        return Fp, 1
+    return fc, -(-Fp // fc)
 
 
 def _top16(x):
@@ -97,15 +118,17 @@ FOLD_ROWS = 8192
 
 def _hist_kernel(leaf_of_chunk, bins_ref, stats_ref, out_ref, acc_ref,
                  lo_ref, *, num_f, num_b, chunk):
-    """One grid step = one C-row chunk of a single leaf.
+    """One grid step = one C-row chunk of a single leaf, for one chunk
+    of ``num_f`` features (grid: feature chunks, then row chunks: a
+    feature chunk walks every row before the next begins).
 
-    bins_ref:  [F, C] uint8 (this chunk's bins, feature-major)
+    bins_ref:  [Fc, C] uint8 (this chunk's bins, feature-major)
     stats_ref: [STAT_ROWS, C] bf16 — split_stats of (g*m, h*m, m, 0)
-    out_ref:   [1, F, 4, B] f32 block at row ``leaf_of_chunk[c]`` —
+    out_ref:   [1, Fc, 4, B] f32 block at row ``leaf_of_chunk[c]`` —
                revisited (and therefore VMEM-resident) across all chunks
                of the same leaf.
-    acc_ref:   [F, 4, B] f32 scratch — the last few chunks' sum
-    lo_ref:    [F, 4, B] f32 scratch — what the folds' roundings lost
+    acc_ref:   [Fc, 4, B] f32 scratch — the last few chunks' sum
+    lo_ref:    [Fc, 4, B] f32 scratch — what the folds' roundings lost
 
     The chunks do not add into ``out_ref`` one by one: at nine million
     rows that is 17,000 roundings at the size a bin has reached, and a
@@ -117,8 +140,8 @@ def _hist_kernel(leaf_of_chunk, bins_ref, stats_ref, out_ref, acc_ref,
     back: the bin is the correctly rounded sum of the chunks' partial
     sums.
     """
-    c = pl.program_id(0)
-    last = pl.num_programs(0) - 1
+    c = pl.program_id(1)
+    last = pl.num_programs(1) - 1
     leaf = leaf_of_chunk[c]
     is_first = (c == 0) | (leaf != leaf_of_chunk[jnp.maximum(c - 1, 0)])
     is_last = (c == last) | (leaf != leaf_of_chunk[jnp.minimum(c + 1, last)])
@@ -184,25 +207,34 @@ def _hist_pallas_call(
     leaf_of_chunk, bins_buf, stats_buf, out_leaves, Fp, B, C, n_chunks,
     interpret, raw=False,
 ):
-    """The one pallas_call: one grid step per C-row chunk, output block
-    indexed by the scalar-prefetched chunk->leaf map.  Returns
-    hist[out_leaves, Fp, B, 4] in the CANONICAL bin-major layout — or,
-    with ``raw=True``, the kernel's NATIVE [out_leaves, Fp, 4, B]
-    layout with no relayout at all: the round-3 profile showed the
-    per-split transpose to the canonical layout radiating ~0.5 ms/split
-    of layout-churn fusions through the whole split step."""
-    kernel = functools.partial(_hist_kernel, num_f=Fp, num_b=B, chunk=C)
+    """The one pallas_call: one grid step per feature chunk and C-row
+    chunk, output block indexed by the scalar-prefetched chunk->leaf
+    map.  Returns hist[out_leaves, Fp, B, 4] in the CANONICAL bin-major
+    layout — or, with ``raw=True``, the kernel's NATIVE
+    [out_leaves, Fp, 4, B] layout with no relayout at all: the round-3
+    profile showed the per-split transpose to the canonical layout
+    radiating ~0.5 ms/split of layout-churn fusions through the whole
+    split step.
+
+    Resident in VMEM, with ``(Fc, NC) = feature_chunk(Fp, B)``: the
+    bins block ``Fc * C`` bytes and the stats block ``STAT_ROWS * C *
+    2``, both double-buffered; the output block and the two scratch
+    blocks, ``Fc * 4 * B * 4`` each (the output's twice); and the body's
+    ``[B, C]`` one-hot.  About 5.2 MiB at Fc = 256, B = 256, C = 2048,
+    whatever ``Fp`` is: no width is refused for it."""
+    Fc, NC = feature_chunk(Fp, B)
+    kernel = functools.partial(_hist_kernel, num_f=Fc, num_b=B, chunk=C)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_chunks,),
+        grid=(NC, n_chunks),
         in_specs=[
-            pl.BlockSpec((Fp, C), lambda c, leaf_ref: (0, c)),
-            pl.BlockSpec((STAT_ROWS, C), lambda c, leaf_ref: (0, c)),
+            pl.BlockSpec((Fc, C), lambda fc, c, leaf_ref: (fc, c)),
+            pl.BlockSpec((STAT_ROWS, C), lambda fc, c, leaf_ref: (0, c)),
         ],
         out_specs=pl.BlockSpec(
-            (1, Fp, 4, B), lambda c, leaf_ref: (leaf_ref[c], 0, 0, 0)
+            (1, Fc, 4, B), lambda fc, c, leaf_ref: (leaf_ref[c], fc, 0, 0)
         ),
-        scratch_shapes=[pltpu.VMEM((Fp, 4, B), jnp.float32)] * 2,
+        scratch_shapes=[pltpu.VMEM((Fc, 4, B), jnp.float32)] * 2,
     )
     with phase_scope(f"histogram.cap{n_chunks * C}"):
         out = pl.pallas_call(

@@ -93,11 +93,23 @@ def _pack_meta(feature_mask, num_bins_per_feature, is_categorical, Fp):
 
 
 def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
-                  out_ref, F, B):
+                  out_ref, F, B, f0, best_ref):
     """One child's full search given its stat planes [F, B], their
     exclusive suffix sums and the inclusive prefix sums of gradient and
     hessian (the count's prefix is ``cnt_t - tc``, exact either way);
     writes the child's [1, 16] result row.
+
+    Called once a feature chunk (pallas_histogram.feature_chunk; once
+    in all for a table of one chunk), in ascending feature order, with
+    ``f0`` the chunk's first feature (a run-time scalar) and
+    ``best_ref`` an SMEM [2] f32 scratch that keeps each child's best
+    RAW gain so far: a later chunk's row replaces the kept one only
+    where its raw maximum is strictly greater, so equal maxima keep the
+    smaller feature index, which is what one search over every feature
+    picks.  (Raw, not minus ``gain_shift``: the subtraction can round
+    two distinct maxima into a tie.)  Everything else is a function of
+    one (feature, bin) cell or of one feature's row, so the chunked
+    search is the whole one bit for bit.
 
     Mosaic-friendly shapes only: [F, B] / [F, 1] vectors, TRUE scalars
     from the SMEM-prefetched ``scal_ref`` (scalar splats broadcast
@@ -111,7 +123,8 @@ def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
     # pure logical ops, not where-on-bools: Mosaic cannot truncate the
     # i8 select result back to i1
     in_range = ((iscat & (bins < nb)) | (~iscat & (bins < nb - 1))) & fmask
-    fi = jax.lax.broadcasted_iota(jnp.int32, (F, 1), 0)
+    # the table's feature index, not the chunk's
+    fi = jax.lax.broadcasted_iota(jnp.int32, (F, 1), 0) + f0
     lane16 = jax.lax.broadcasted_iota(jnp.int32, (1, 16), 1)
 
     min_data = scal_ref[8]
@@ -181,34 +194,34 @@ def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
     row = jnp.zeros((1, 16), jnp.float32)
     for j, v in enumerate(vals):
         row = jnp.where(lane16 == j, v, row)
-    out_ref[c:c + 1, :] = row
+    better = (f0 == 0) | (maxg > best_ref[c])
+    out_ref[c:c + 1, :] = jnp.where(better, row, out_ref[c:c + 1, :])
+    best_ref[c] = jnp.where(better, maxg, best_ref[c])
 
 
-def _search2_kernel(scal_ref, hist_ref, meta_ref, out_ref, *, F, B):
-    """One grid step: both children end-to-end.
+def _search2_kernel(scal_ref, hist_ref, meta_ref, out_ref, best_ref,
+                    *, F, B):
+    """One grid step: both children end-to-end on one feature chunk of
+    ``F`` features (the whole table where it is one chunk).
 
-    scal_ref [16]    f32 SMEM  (canL, lsg, lsh, lc, canR, rsg, rsh, rc,
-                                min_data, min_hess, l1, l2, min_gain)
-    hist_ref [6F, B] f32       child-major [c, s, f] rows: g, h, count
-    meta_ref [F, 4]  i32       (feature_mask, nbpf, is_categorical, pad)
-    out_ref  [2, 16] f32
+    scal_ref [16]      f32 SMEM  (canL, lsg, lsh, lc, canR, rsg, rsh, rc,
+                                  min_data, min_hess, l1, l2, min_gain)
+    hist_ref [6, F, B] f32       child-major (c, s) planes: g, h, count
+    meta_ref [F, 4]    i32       (feature_mask, nbpf, is_categorical, pad)
+    out_ref  [2, 16]   f32
+    best_ref [2]       f32 SMEM  (_child_search)
     """
-    h = hist_ref[...]  # [6F, B]
-    # tail[row, t] = sum_{b > t} h[row, b] for ALL six (child, stat) rows
     tri = _tri(B)
-    tail = _tail_of(h, tri)  # [6F, B]
     for c in range(2):
-        base = c * 3 * F
-        head = _head_of(h[base:base + 2 * F], tri)  # gradient, hessian
+        hg, hh, hc = (hist_ref[3 * c + s] for s in range(3))
+        # tail[f, t] = sum_{b > t} h[f, b]; the hessian's seeded with
+        # kEpsilon
         _child_search(
-            c,
-            h[base:base + F], h[base + F:base + 2 * F],
-            h[base + 2 * F:base + 3 * F],
-            tail[base:base + F],
-            tail[base + F:base + 2 * F] + K_EPSILON,  # kEpsilon seed
-            tail[base + 2 * F:base + 3 * F],
-            head[:F], head[F:],
-            scal_ref, meta_ref, out_ref, F, B,
+            c, hg, hh, hc,
+            _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
+            _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
+            scal_ref, meta_ref, out_ref, F, B, pl.program_id(0) * F,
+            best_ref,
         )
 
 
@@ -225,7 +238,14 @@ def search2_pallas(
     """Both children's best splits in one kernel launch; returns two
     scalar SplitResults matching ops/split.find_best_split bit-for-bit
     up to the suffix-sum accumulation order (MXU triangular dot vs
-    sequential cumsum — identical under exact arithmetic)."""
+    sequential cumsum — identical under exact arithmetic).
+
+    The table is searched a feature chunk a grid step
+    (pallas_histogram.feature_chunk; one step for a table of one chunk),
+    the best kept across steps (_child_search): one ``[6, Fc, B]`` block
+    is resident whatever the width."""
+    from .pallas_histogram import feature_chunk
+
     if h_left.dtype != jnp.float32 or h_right.dtype != jnp.float32:
         # a silent astype here would hide precision loss from a future
         # float64 hist_dtype caller; the f64 parity mode must stay on
@@ -235,14 +255,17 @@ def search2_pallas(
             f"{h_left.dtype}/{h_right.dtype}"
         )
     F, B, _ = h_left.shape
+    Fc, NC = feature_chunk(F, B)
     hist = (
         jnp.stack([h_left, h_right])  # [2, F, B, 3]
         .transpose(0, 3, 1, 2)  # [2, 3, F, B] child-major, stat, feature
-        .reshape(6 * F, B)
         .astype(jnp.float32)
+        .reshape(6, F, B)
     )
+    # whole chunks: padded features get feature_mask 0 (_pack_meta)
+    hist = jnp.pad(hist, ((0, 0), (0, NC * Fc - F), (0, 0)))
     meta = _pack_meta(
-        feature_mask, num_bins_per_feature, is_categorical, F)
+        feature_mask, num_bins_per_feature, is_categorical, NC * Fc)
     scal = _pack_scal(
         jnp.asarray(can, jnp.float32), lsg, lsh, lc, rsg, rsh, rc,
         min_data_in_leaf, min_sum_hessian_in_leaf,
@@ -250,15 +273,16 @@ def search2_pallas(
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(1,),
+        grid=(NC,),
         in_specs=[
-            pl.BlockSpec((6 * F, B), lambda i, s: (0, 0)),
-            pl.BlockSpec((F, 4), lambda i, s: (0, 0)),
+            pl.BlockSpec((6, Fc, B), lambda i, s: (0, i, 0)),
+            pl.BlockSpec((Fc, 4), lambda i, s: (i, 0)),
         ],
         out_specs=pl.BlockSpec((2, 16), lambda i, s: (0, 0)),
+        scratch_shapes=[pltpu.SMEM((2,), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_search2_kernel, F=F, B=B),
+        functools.partial(_search2_kernel, F=Fc, B=B),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((2, 16), jnp.float32),
         interpret=interpret,

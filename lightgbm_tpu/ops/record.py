@@ -87,6 +87,16 @@ TILE = 512
 # the transpose), and the 1MB SMEM budget caps one launch at ~16k steps
 # — a 10M-row window has ~78k.  place_runs reads it when it traces.
 PLACE_CHUNK = 16384
+# Mosaic's scoped VMEM when a call asks for nothing.
+VMEM_DEFAULT_BYTES = 16 << 20
+# Packed words (sublane rows of the record) one step of the histogram
+# body's loop takes, LOOP_WORDS * k features unrolled a step.  The step
+# alone on the chip, ms a window split in half at 7.5M x 100 / 400,000 x
+# 2,000 (PERF.md, PR 34): 8 words 99.33 / 85.21, 16 words 96.73 / 83.84,
+# 32 words 96.76 / 101.74 (every feature unrolled, as before the loop,
+# 97.45 at the first; Mosaic takes no partial ``unroll=``).
+LOOP_WORDS = 16
+
 
 def round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
@@ -225,12 +235,14 @@ def _tile_go(tile, scal_i_ref, i, *, F, k):
     valid = ((i * T + lane) < pcnt).astype(jnp.int32)
     fw = f // k
     fs = (f % k) * shift
-    # static compare-select row pick: Mosaic has no dynamic_slice
-    # lowering, and a dynamically-indexed sublane load is the failure
-    # class the histogram kernel's FGROUP loop dodges
-    frow = jnp.zeros((1, T), jnp.int32)
-    for w in range(num_words(F, k)):
-        frow = frow + jnp.where(fw == w, tile[w: w + 1, :], 0)
+    # compare-select row pick: Mosaic has no dynamic_slice lowering,
+    # and a dynamically-indexed sublane load is the failure class the
+    # histogram kernel's FGROUP loop dodges.  One masked sum down the
+    # sublanes: a compare, a select and an add a vreg whatever the
+    # record's height (a slice, a select and an add a WORD would be 500
+    # of each at 2,000 columns)
+    wrow = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 0)
+    frow = jnp.sum(jnp.where(wrow == fw, tile, 0), axis=0, keepdims=True)
     fv = jax.lax.shift_right_logical(frow, fs) & mask_v
     # ARITHMETIC select: an i1-on-i1 arith.select fails legalization
     go = is_cat * (fv == thr).astype(jnp.int32) + (1 - is_cat) * (
@@ -331,15 +343,22 @@ def _compact_kernel(win_ref, grow_ref, out_ref):
     out_ref[0] = _compact_body(win_ref[...], grow_ref[0:1, :])
 
 
-def _hist_tile_body(tile, hacc_set, *, F, k, Bp, live, fgroup=8):
-    """Histogram accumulation over one [W, T] tile of the smaller
+def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
+    """Histogram accumulation over the first [W, T] tile of the smaller
     child's STAGED rows (_split_step_kernel's one call).  ``live``
     [1, T] flags the lanes that hold a row: all of them on a full
     staged tile, the first ``fill`` at the drain.  Stats stack on
     sublanes; the one-hot is born transposed against a sublane iota and
     contracts the shared lane axis on the MXU — no relayouts.
 
-    ``hacc_set(fi, contrib)`` accumulates [4, Bp] into feature row fi.
+    Feature ``fi``'s [4, Bp] is added into ``hacc_ref[fi]``.  The
+    record's whole groups of LOOP_WORDS words (aligned sublane tiles of
+    ``stage_ref``, LOOP_WORDS * k features) are walked in a
+    ``fori_loop`` that unrolls inside a group, as the root kernel does
+    (pallas_histogram._hist_kernel), and the features past the last
+    whole group are unrolled after it: compiled code size stays
+    O(LOOP_WORDS * k) whatever the width, and every width runs the one
+    form.
     """
     from .pallas_histogram import merge_stats, split_stats
 
@@ -348,11 +367,10 @@ def _hist_tile_body(tile, hacc_set, *, F, k, Bp, live, fgroup=8):
     mask_v = (1 << shift) - 1
 
     Wb = num_words(F, k)
-    grow = jax.lax.bitcast_convert_type(tile[Wb: Wb + 1, :], jnp.float32)
-    hrow = jax.lax.bitcast_convert_type(
-        tile[Wb + 1: Wb + 2, :], jnp.float32)
-    mrow = jax.lax.bitcast_convert_type(
-        tile[Wb + 2: Wb + 3, :], jnp.float32)
+    stat = stage_ref[Wb: Wb + 3, :T]
+    grow = jax.lax.bitcast_convert_type(stat[0:1], jnp.float32)
+    hrow = jax.lax.bitcast_convert_type(stat[1:2], jnp.float32)
+    mrow = jax.lax.bitcast_convert_type(stat[2:3], jnp.float32)
     mw = mrow * live  # the bagging mask, on the lanes that hold a row
     # exact three-piece bf16 split of the stat rows: one MXU pass at
     # float32 accuracy (see pallas_histogram.split_stats)
@@ -360,33 +378,49 @@ def _hist_tile_body(tile, hacc_set, *, F, k, Bp, live, fgroup=8):
         [grow * mw, hrow * mw, mw, jnp.zeros_like(mw)], axis=0))
 
     iota_s = jax.lax.broadcasted_iota(jnp.int32, (Bp, T), 0)
+
+    def sums_of(row):
+        onehot = (row == iota_s).astype(jnp.bfloat16)
+        return merge_stats(jax.lax.dot_general(
+            stats, onehot, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ))
+
+    def add(fi, row):
+        hacc_ref[fi] = hacc_ref[fi] + sums_of(row)
+
+    def group(words, f0, count):
+        """Features [f0, f0 + count) from ``words``, whose row 0 holds
+        feature ``f0`` in its low bits."""
+        for j in range(count):
+            add(f0 + j, jax.lax.shift_right_logical(
+                words[j // k: j // k + 1, :], (j % k) * shift) & mask_v)
+
+    LW = LOOP_WORDS
+    steps = F // (LW * k)
+
+    def whole_group(g, _):
+        group(stage_ref[pl.ds(pl.multiple_of(g * LW, LW), LW), :T],
+              g * (LW * k), LW * k)
+        return 0
+
+    if steps:
+        jax.lax.fori_loop(0, steps, whole_group, 0)
+    w0 = steps * LW
+    if w0 < Wb:
+        group(stage_ref[w0: Wb, :T], w0 * k, F - w0 * k)
     # caller-sized histogram block: the padded-feature fill below must
     # cover exactly the caller's round_up(F, fgroup) rows (ADVICE r4 —
     # a literal 8 here would leave rows [round_up(F,8), Fp) zero and
     # break parent-minus-left subtraction consistency for fgroup != 8)
     Fp = round_up(F, fgroup)
-    for fi in range(F):
-        w_idx, sh = fi // k, (fi % k) * shift
-        row = jax.lax.shift_right_logical(
-            tile[w_idx: w_idx + 1, :], sh) & mask_v
-        onehot = (row == iota_s).astype(jnp.bfloat16)
-        contrib = merge_stats(jax.lax.dot_general(
-            stats, onehot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ))
-        hacc_set(fi, contrib)
+    # padded features: bin-0 totals, matching _prep_single_leaf's
+    # zero-padded feature rows (subtract consistency with the buffer's
+    # existing rows)
     if Fp > F:
-        # padded features: bin-0 totals, matching _prep_single_leaf's
-        # zero-padded feature rows (subtract consistency with the
-        # buffer's existing rows)
-        zrow = jnp.zeros((1, T), jnp.int32)
-        onehot0 = (zrow == iota_s).astype(jnp.bfloat16)
-        contrib0 = merge_stats(jax.lax.dot_general(
-            stats, onehot0, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ))
+        contrib0 = sums_of(jnp.zeros((1, T), jnp.int32))
         for fi in range(F, Fp):
-            hacc_set(fi, contrib0)
+            hacc_ref[fi] = hacc_ref[fi] + contrib0
 
 
 def _run_offsets(cl, cr):
@@ -440,7 +474,7 @@ def _xla_place(rec, comp, loff, roff, begin, pcnt, nleft, do_split, cap,
 FOLD_TILES = 16
 
 
-def _fold_hacc(hacc_ref, hhi_ref, hlo_ref):
+def _fold_hacc(hacc_ref, hhi_ref, hlo_ref, Fc, NC):
     """Fold the tiles accumulated in ``hacc_ref`` into the running sum
     ``hhi + hlo`` and clear it.  A float32 accumulator that takes every
     tile itself rounds once a tile at the size the bin has reached, and
@@ -450,10 +484,25 @@ def _fold_hacc(hacc_ref, hhi_ref, hlo_ref):
     happen once in FOLD_TILES tiles and each one's error is kept
     (ops/totals.py two_sum), so the bin the search reads is the correctly rounded sum
     of the tiles' exact partial sums.  The hot per-feature loop is
-    unchanged: it still adds into ``hacc_ref`` alone."""
-    hhi_ref[...], err = two_sum(hhi_ref[...], hacc_ref[...])
-    hlo_ref[...] = hlo_ref[...] + err
-    hacc_ref[...] = jnp.zeros_like(hacc_ref)
+    unchanged: it still adds into ``hacc_ref`` alone.
+
+    Accumulators of ``NC`` feature chunks fold a chunk of ``Fc`` rows
+    at a time, so the fold's temporaries are one chunk's whatever the
+    table's width."""
+    def chunk(c, _):
+        rows = _chunk_rows(c, Fc)
+        hhi_ref[rows], err = two_sum(hhi_ref[rows], hacc_ref[rows])
+        hlo_ref[rows] = hlo_ref[rows] + err
+        hacc_ref[rows] = jnp.zeros((Fc,) + hacc_ref.shape[1:],
+                                   hacc_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, NC, chunk, 0)
+
+
+def _chunk_rows(c, Fc):
+    """Accumulator rows of feature chunk ``c``."""
+    return pl.ds(pl.multiple_of(c * Fc, Fc), Fc)
 
 
 def _split_tile(tile, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
@@ -496,7 +545,7 @@ def _split_tile(tile, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
 
 def _split_step_kernel(
     scal_i_ref, scal_f_ref, *refs,
-    W, F, k, Bp, fgroup=8, direct_read=False,
+    W, F, k, Bp, Fc, NC, fgroup=8, direct_read=False,
 ):
     """The WHOLE split step in one launch: per-tile compaction and
     staging of the smaller child's rows, its histogram over full tiles
@@ -507,8 +556,19 @@ def _split_step_kernel(
     fori_loop: PERF.md, PR 27), and the [Fp, 4, Bp] h_small never makes
     a round trip through HBM.
 
+    The tail walks the feature axis in ``NC`` chunks of ``Fc`` features
+    (pallas_histogram.feature_chunk): ``NC`` search steps, each of which
+    reads one ``[Fc, 4, Bp]`` block of the parent's row, subtracts,
+    writes the left child's block, stashes the right child's and
+    searches both on that chunk (pallas_search._child_search keeps the
+    best across chunks), then ``NC`` steps that write the right child's
+    blocks.  The tile steps see no chunk: compaction, staging and the
+    histogram body run once a split on the whole record height, into
+    accumulators of every chunk.  A table of one chunk (Fp <= Fc) has
+    the two tail steps it always had.
+
     ``nt`` is the LIVE tile count, a run-time value (scal_i[10]; the
-    grid is ``nt + 2 (+1 direct)`` steps, a dynamic bound): one body
+    grid is ``nt + 2 * NC (+1 direct)`` steps, a dynamic bound): one body
     serves every window size, so the grower launches it outside any
     ``lax.cond`` and the record stays in the loop's carry.  Tiles past
     ``nt`` are never visited — their ``comp``/``cnt`` blocks keep
@@ -530,22 +590,25 @@ def _split_step_kernel(
                  vector, a sibling block view) made copy-insertion
                  clone the full record every split (~1-2 s/tree at 10M
                  rows, measured both ways).
-    hrow_ref   : hists row — parent slot until the search step, new
-                 slot on the last
-    hists_out  : left row at the search step, right row on the last
+    hrow_ref   : a [1, Fc, 4, Bp] block of a hists row — the parent
+                 slot's chunk s at search step s (its chunk 0 through
+                 the tile steps), the new slot's at the write steps
+    hists_out  : the left row's chunk at a search step, the right row's
+                 at a write step
     cnt_ref    : [1, 128] i32 per tile — lane 0 carries this tile's
                  LEFT count, so the XLA side derives cl/cr/nleft with
                  no go vector (and no record read) at all; lane 1 of the
                  LAST live tile's group carries how many histogram tile
                  bodies the launch ran (_hist_tiles)
-    hacc_ref   : VMEM scratch — the smaller child's histogram over the
+    hacc_ref   : VMEM scratch [NC * Fc, 4, Bp], as the next two — the
+                 smaller child's histogram over the
                  last few staged tiles (which child: the one with fewer
                  bagged rows by the search's own exact counts, scal_f[3]
                  and [7], as LightGBM takes the smaller leaf's rows and
                  its sibling by subtraction: a sibling got from the
                  LARGER child keeps the absolute rounding of two large
                  sums in bins a hundredth their size), then the
-                 right-child stash between the last two steps
+                 right-child stash, chunk by chunk, for the write steps
     hhi_ref, hlo_ref : VMEM scratch — that histogram's running sum as
                  two floats (_fold_hacc)
     stage_ref  : VMEM scratch [W, 2T] — the smaller child's rows,
@@ -558,6 +621,8 @@ def _split_step_kernel(
                  100 (PERF.md, PR 31)
     fill_ref   : SMEM scratch [2] — rows waiting in ``stage_ref``
                  (< T between steps), and the histogram tiles run
+    best_ref   : SMEM scratch [2] f32, last — each child's best raw
+                 gain over the chunks searched so far
     """
     from .pallas_search import (
         K_EPSILON, _child_search, _head_of, _tail_of, _tri)
@@ -565,19 +630,19 @@ def _split_step_kernel(
     if direct_read:
         (rec_ref, hrow_ref, meta_ref, hists_out_ref,
          comp_ref, res_ref, cnt_ref, rec_out_ref, hacc_ref,
-         hhi_ref, hlo_ref, stage_ref, fill_ref, prev_ref) = refs
+         hhi_ref, hlo_ref, stage_ref, fill_ref, prev_ref, best_ref) = refs
     else:
         (win_ref, hrow_ref, meta_ref, hists_out_ref, comp_ref,
          res_ref, cnt_ref, hacc_ref, hhi_ref, hlo_ref, stage_ref,
-         fill_ref) = refs
+         fill_ref, best_ref) = refs
 
     T = TILE
     i = pl.program_id(0)
     do_split = scal_i_ref[3] > 0
     nt = scal_i_ref[10]
     off = 1 if direct_read else 0  # pipeline offset of the tile steps
-    search_step = nt + off
-    last_step = nt + 1 + off
+    search_step = nt + off  # the first of NC
+    write_step = search_step + NC  # the first of NC
 
     small_left_b = scal_f_ref[3] <= scal_f_ref[7]
     sums = (hacc_ref, hhi_ref, hlo_ref)
@@ -644,11 +709,8 @@ def _split_step_kernel(
 
     @pl.when((fill >= T) | ((i == search_step) & (fill > 0)))
     def _():
-        def hacc_set(fi, contrib):
-            hacc_ref[fi] = hacc_ref[fi] + contrib
-
         lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-        _hist_tile_body(stage_ref[:, :T], hacc_set, F=F, k=k, Bp=Bp,
+        _hist_tile_body(stage_ref, hacc_ref, F=F, k=k, Bp=Bp,
                         fgroup=fgroup, live=(lane < fill).astype(jnp.float32))
         stage_ref[:, :T] = stage_ref[:, T:]
         fill_ref[0] = jnp.maximum(fill - T, 0)
@@ -657,7 +719,7 @@ def _split_step_kernel(
 
         @pl.when(ran % FOLD_TILES == 0)
         def _():
-            _fold_hacc(*sums)
+            _fold_hacc(*sums, Fc, NC)
 
     @pl.when(i >= nt + off)
     def _():
@@ -671,14 +733,19 @@ def _split_step_kernel(
 
     @pl.when(i == search_step)
     def _():
-        parent = hrow_ref[0]  # [Fp, 4, Bp]
-        _fold_hacc(*sums)
-        h_small = hhi_ref[...] + hlo_ref[...]
+        _fold_hacc(*sums, Fc, NC)
+
+    @pl.when((i >= search_step) & (i < write_step))
+    def _():
+        c = i - search_step
+        rows = _chunk_rows(c, Fc)
+        parent = hrow_ref[0]  # [Fc, 4, Bp]
+        h_small = hhi_ref[rows] + hlo_ref[rows]
         h_large = parent - h_small
         h_left = jnp.where(small_left_b, h_small, h_large)
         h_right = jnp.where(small_left_b, h_large, h_small)
         hists_out_ref[0] = jnp.where(do_split, h_left, parent)
-        hacc_ref[...] = h_right  # stash for the final step
+        hacc_ref[rows] = h_right  # stash for the write steps
 
         B = Bp
         tri = _tri(B)
@@ -689,12 +756,13 @@ def _split_step_kernel(
                 cc, hg, hh, hc,
                 _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
                 _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
-                scal_f_ref, meta_ref, res_ref, hacc_ref.shape[0], B,
+                scal_f_ref, meta_ref, res_ref, Fc, B, c * Fc, best_ref,
             )
 
-    @pl.when(i == last_step)
+    @pl.when(i >= write_step)
     def _():
-        hists_out_ref[0] = jnp.where(do_split, hacc_ref[...], hrow_ref[0])
+        rows = _chunk_rows(i - write_step, Fc)
+        hists_out_ref[0] = jnp.where(do_split, hacc_ref[rows], hrow_ref[0])
 
 
 def _place_kernel(sp_ref, comp_ref, rec_in_ref, rec_out_ref, *,
@@ -840,6 +908,8 @@ def place_runs(
 
     steps = _place_table(begin, nleft, cl, cr, loff, roff,
                          left_leaf, right_leaf, do_split, nt, live)
+    params = pltpu.CompilerParams(
+        vmem_limit_bytes=max(VMEM_DEFAULT_BYTES, place_vmem_bytes(W)))
     total = 4 * nt
     for lo in range(0, total, PLACE_CHUNK):
         sl = steps[:, lo: lo + PLACE_CHUNK]
@@ -868,6 +938,7 @@ def place_runs(
                 grid_spec=grid_spec,
                 out_shape=jax.ShapeDtypeStruct((W, n_pad), jnp.int32),
                 input_output_aliases={2: 0},  # rec (incl. the prefetch arg)
+                compiler_params=params,
                 interpret=interpret,
             )(sl, comp, rec)
     return rec
@@ -904,6 +975,54 @@ def _hist_tiles(cnt, live):
     every parent tile with the sibling masked ran ``live`` of them."""
     return jax.lax.dynamic_index_in_dim(
         cnt[0], (live - 1) * 128 + 1, keepdims=False)
+
+
+def place_vmem_bytes(W: int) -> int:
+    """What a ``place_runs`` launch keeps in VMEM on the chip: the
+    ``[1, W, 2 * TILE]`` comp block and the record block in and out,
+    all double-buffered (8 ``[W, TILE]`` blocks), the merge's working
+    tiles (given 4) and 2 MiB for the rest.  One ``[W, TILE]`` block
+    under the split step's sum at every height, so the grower's gate
+    reads that one (learners/fused.py chunking)."""
+    return 12 * W * TILE * 4 + (2 << 20)
+
+
+def split_step_vmem_bytes(Fp: int, Bp: int, W: int) -> int:
+    """What ``split_step_counted`` keeps in VMEM on the chip, in bytes,
+    with ``(Fc, NC) = feature_chunk(Fp, Bp)``, ``blk = Fc * 4 * Bp * 4``
+    (a ``[Fc, 4, Bp]`` float32 block of a leaf's histogram) and ``rec =
+    W * TILE * 4`` (a ``[W, TILE]`` block of the record):
+
+    * ``3 * NC * blk``: the accumulators ``hacc``, ``hhi``, ``hlo`` of
+      every chunk (the tile steps fill them all at once);
+    * ``4 * blk``: the parent's block in and the child's block out, both
+      double-buffered;
+    * ``11 * rec``: the record block in and out (double-buffered, 4),
+      the ``[1, W, 2 * TILE]`` ``comp`` block out (double-buffered, 4),
+      the ``[W, 2 * TILE]`` staging buffer (2) and ``prev`` (1);
+    * what no spec names, GIVEN and not derived: the search's
+      ``[Fc, Bp]`` planes of both children and the fold's, ``8 * blk``;
+      the compaction's and the row pick's working tiles, ``5 * rec``;
+      the search's two ``[Bp, Bp]`` triangular matrices; 2 MiB for the
+      small blocks (meta, counts, the one-hot).
+
+    The given terms are held against what Mosaic asks for, read off
+    deviceless v5e compiles at falling limits (PERF.md, PR 34; MiB,
+    need / this sum): 100 columns of 256 bins 7.0 / 9.6; 264, 18.6 /
+    22.8; 1,000, 28.5 / 34.5; 2,000, 46.0 / 54.5; 4,364, 92.9 / 102.8;
+    3,000 of 128 bins 48.9 / 55.9; 6,656, 96.1 / 105.4; 300 of 1,024
+    bins 32.9 / 42.0; 1,344, 92.0 / 106.2.  Mosaic's need grows with the
+    record's height by about 11 ``rec`` up to 512 words and by about 15
+    past them.  tests/test_chip_compile.py compiles the step under this limit at
+    the widest table the grower's gate admits, a record-bound one and
+    one of uint16 bins among them."""
+    from .pallas_histogram import feature_chunk
+
+    Fc, NC = feature_chunk(Fp, Bp)
+    blk = Fc * 4 * Bp * 4
+    rec = W * TILE * 4
+    return ((3 * NC + 12) * blk + 16 * rec + 2 * Bp * Bp * 4
+            + (2 << 20))
 
 
 @functools.partial(
@@ -954,7 +1073,14 @@ def split_step_counted(
     Tiles past the live count are never written, so their counts are
     masked here before anything reads them.
 
+    What is resident in VMEM, as a function of ``(Fc, Bp, W)`` and the
+    chunk count: ``split_step_vmem_bytes``.  That sum, or Mosaic's
+    default of 16 MiB where it is less, is the call's
+    ``vmem_limit_bytes`` at every width (a compile parameter derived
+    from the block sizes).
     """
+    from .pallas_histogram import feature_chunk
+
     W, n_pad = rec.shape
     T = TILE
     assert cap % T == 0, (cap, T)
@@ -962,6 +1088,11 @@ def split_step_counted(
     nt = cap // T
     nblocks = n_pad // T
     P, Fp, _, Bp = hists.shape
+    Fc, NC = feature_chunk(Fp, Bp)
+    if meta.shape[0] < NC * Fc:
+        # whole chunks of meta: a block past its edge would read
+        # padding where a zero feature mask must stand
+        meta = jnp.pad(meta, ((0, NC * Fc - meta.shape[0]), (0, 0)))
 
     i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
     b0 = i32(begin) // T
@@ -995,21 +1126,31 @@ def split_step_counted(
                 lambda i, si, sf: (0, jnp.minimum(i, si[10] - 1))),
         ]
 
+    def _chunk_idx(i, si):
+        """The feature chunk of tail step ``i``: 0 .. NC-1 over the
+        search steps, again over the write steps (0 through the tile
+        steps, so the first search step finds its block fetched)."""
+        s = i - (si[10] + off)
+        return jnp.clip(jnp.where(s < NC, s, s - NC), 0, NC - 1)
+
+    def _hists_idx(i, si, searched):
+        """The hists block of step ``i``: ``searched`` (the parent's
+        slot) up to the last search step, the new slot's after."""
+        return (jnp.where(i < si[10] + off + NC, searched, si[2]),
+                _chunk_idx(i, si), 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(live + 2 + off,),  # a DYNAMIC bound: see the docstring
+        # a DYNAMIC bound: see the docstring
+        grid=(live + 2 * NC + off,),
         in_specs=data_specs + [
-            pl.BlockSpec(
-                (1, Fp, 4, Bp),
-                lambda i, si, sf: (
-                    jnp.where(i <= si[10] + off, si[0], si[2]), 0, 0, 0)),
-            pl.BlockSpec((Fp, 4), lambda i, si, sf: (0, 0)),
+            pl.BlockSpec((1, Fc, 4, Bp),
+                         lambda i, si, sf: _hists_idx(i, si, si[0])),
+            pl.BlockSpec((Fc, 4), lambda i, si, sf: (_chunk_idx(i, si), 0)),
         ],
         out_specs=[
-            pl.BlockSpec(
-                (1, Fp, 4, Bp),
-                lambda i, si, sf: (
-                    jnp.where(i <= si[10] + off, si[1], si[2]), 0, 0, 0)),
+            pl.BlockSpec((1, Fc, 4, Bp),
+                         lambda i, si, sf: _hists_idx(i, si, si[1])),
             pl.BlockSpec((1, W, 2 * T),
                          lambda i, si, sf: (_tile_idx(i, si), 0, 0)),
             pl.BlockSpec((2, 16), lambda i, si, sf: (0, 0)),
@@ -1025,11 +1166,15 @@ def split_step_counted(
             # single-use — see the kernel docstring's copy note
             pl.BlockSpec((W, T), _rec_idx),
         ] if direct_read else []),
-        scratch_shapes=[pltpu.VMEM((Fp, 4, Bp), jnp.float32)] * 3 + [
+        scratch_shapes=[pltpu.VMEM((NC * Fc, 4, Bp), jnp.float32)] * 3 + [
             pltpu.VMEM((W, 2 * T), jnp.int32),  # staged child rows
             pltpu.SMEM((2,), jnp.int32),  # their count, the tiles run
-        ] + ([pltpu.VMEM((W, T), jnp.int32)] if direct_read else []),
+        ] + ([pltpu.VMEM((W, T), jnp.int32)] if direct_read else []) + [
+            # each child's best raw gain over the chunks searched
+            pltpu.SMEM((2,), jnp.float32)],
     )
+    params = pltpu.CompilerParams(vmem_limit_bytes=max(
+        VMEM_DEFAULT_BYTES, split_step_vmem_bytes(Fp, Bp, W)))
     hists_idx = 2 + len(data_in)  # incl. the 2 prefetch args
     out_shape = [
         jax.ShapeDtypeStruct((P, Fp, 4, Bp), jnp.float32),
@@ -1044,11 +1189,12 @@ def split_step_counted(
     with phase_scope("split_step.dyn"):
         outs = pl.pallas_call(
             functools.partial(
-                _split_step_kernel, W=W, F=F, k=k, Bp=Bp,
+                _split_step_kernel, W=W, F=F, k=k, Bp=Bp, Fc=Fc, NC=NC,
                 fgroup=fgroup, direct_read=direct_read),
             grid_spec=grid_spec,
             out_shape=out_shape,
             input_output_aliases=aliases,
+            compiler_params=params,
             interpret=interpret,
         )(scal_i, scal_f, *data_in, hists, meta)
     if direct_read:

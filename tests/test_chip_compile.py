@@ -195,8 +195,12 @@ def test_the_split_step_sums_full_tiles_of_staged_rows(grower):
     body = [(e, conds) for e, conds in _eqns_under(kernel)
             if e.primitive.name == "dot_general"
             and [v.aval.shape for v in e.invars] == [(16, T), (Bp, T)]]
-    # one unrolled copy: a dot a feature, one more for the padded ones
-    assert len(body) == F + (R.round_up(F, 8) > F), len(body)
+    # one copy: a dot a feature of a word group (the loop over whole
+    # groups of LOOP_WORDS words; 28 features are under one) and of the
+    # words after the last whole group, one more for the padded features
+    group = 4 * R.LOOP_WORDS
+    assert len(body) == (group if F >= group else 0) + F % group + (
+        R.round_up(F, 8) > F), len(body)
     assert all(e.params["dimension_numbers"] == (((1,), (1,)), ((), ()))
                for e, _ in body)
     assert {len(conds) for _, conds in body} == {1}
@@ -212,7 +216,8 @@ def test_the_split_step_sums_full_tiles_of_staged_rows(grower):
                 reads.add(eqn.invars[0])
             todo += [v for v in eqn.invars if not hasattr(v, "val")]  # no Literals
     staged = [v for v in kernel.invars
-              if "smem" in str(v.aval) and v.aval.shape == (2,)]
+              if "smem" in str(v.aval) and v.aval.shape == (2,)
+              and v.aval.dtype == jnp.int32]
     assert len(staged) == 1 and staged[0] in reads, (
         [str(v.aval) for v in kernel.invars], [str(v.aval) for v in reads])
     # the per-tile branch holds the compaction's rolls and no such dot
@@ -240,14 +245,17 @@ def test_record_and_hists_stay_in_the_loop_carry(grower):
     ``conditional`` round the kernels cost two whole-record copies a
     split, 55% of a tree at 7.5M x 100 (PERF.md, PR 26 and 27).  Read
     with obs/device_time's HLO reader from the executable's own module."""
+    _the_carry_is_clean(grower[1], n=100_000, F=28, L=63)
+
+
+def _the_carry_is_clean(compiled, n, F, L):
     from lightgbm_tpu.obs import device_time as dt
     from lightgbm_tpu.ops import record as R
 
-    n, F, L = 100_000, 28, 63
     n_rec = R.round_up(n, R.TILE)
     record = f"s32[{R.rec_height(F, 4)},{2 * n_rec}]"
     hists = f"f32[{L},{R.round_up(F, 8)},4,256]"
-    module = grower[1].runtime_executable().hlo_modules()[0]
+    module = compiled.runtime_executable().hlo_modules()[0]
     prog = dt.program_of_module(module.as_serialized_hlo_module_proto())
     loops = [ins for ins in prog.instrs.values() if ins.opcode == "while"
              and (dt.scope_of(ins.op_name) or ("",))[0] == "lgbm.grow.loop"]
@@ -268,6 +276,134 @@ def test_record_and_hists_stay_in_the_loop_carry(grower):
     kernels = [ins for ins in body if ins.target == "tpu_custom_call"]
     assert {dt.scope_of(k.op_name)[0] for k in kernels} == {
         "lgbm.split_step", "lgbm.partition"}
+
+
+def _shape(topo):
+    chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+    return lambda dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=chip)
+
+
+def _compile_kernels(topo, F, bins):
+    """The root kernel and the split step with its placement, alone, at
+    ``F`` columns of ``bins`` bins (uint16 bins, two to a record word,
+    past 256) for a v5e; the step must still be ONE call."""
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    from lightgbm_tpu.ops import record as R
+
+    shape = _shape(topo)
+    n, L, k = 20_480, 8, 4 if bins <= 256 else 2
+    W, Fp, Bp = R.rec_height(F, k), R.round_up(F, 8), R.round_up(bins, 128)
+    PH.histogram_single_leaf_raw.lower(
+        shape((F, n), jnp.uint8 if k == 4 else jnp.uint16), shape((n,)),
+        shape((n,)), shape((n,)), num_bins=bins, interpret=False).compile()
+
+    def step(hists, rec, scal_f, meta, i):
+        hists, comp, nleft, res, cl, cr, rec, _ = R.split_step_counted(
+            hists, rec, i, i, i > 0, i, i, i > 3, i, i + 1, scal_f, meta,
+            F=F, cap=n, k=k, interpret=False, live_tiles=i)
+        rec = R.place_runs(
+            rec, comp, (cl, cr), i, i, nleft, i > 0, i, i + 1, cap=n,
+            leaf_row=R.num_words(F, k) + 4, interpret=False, live_tiles=i)
+        return hists, rec, res
+
+    lowered = jax.jit(step, donate_argnums=(0, 1)).lower(
+        shape((L, Fp, 4, Bp)), shape((W, 2 * n), jnp.int32), shape((16,)),
+        shape((Fp, 4), jnp.int32), shape((), jnp.int32))
+    assert lowered.as_text().count("tpu_custom_call") == 2
+    lowered.compile()
+
+
+# Past one feature chunk (256 columns at 256 bins; 264 is the first
+# width the parent's split step refused), at a width that is no whole
+# number of chunks, at epsilon-2000.train's, and ONE chunk of 512
+# columns at 128 bins, whose 136-word record is past what the step held
+# before (Mosaic's default refuses it).
+@pytest.mark.parametrize("F,bins", [(264, 255), (1000, 255), (2000, 255),
+                                    (512, 127)])
+def test_wide_tables_compile_for_v5e(topo, F, bins):
+    """The root kernel and the split step with its placement at 256
+    bins, alone, for a table wider than one ``[Fc, 4, Bp]`` block: Mosaic
+    takes them (the split step's accumulators of every chunk and the
+    record's blocks under the ``vmem_limit_bytes`` that
+    ``split_step_vmem_bytes`` derives, learners/fused.py ``chunking``
+    admitting the width), and the step is still ONE call."""
+    from lightgbm_tpu.ops import record as R
+
+    Fp, Bp = R.round_up(F, 8), R.round_up(bins, 128)
+    with device.assume_platform("tpu"):
+        plan = fused.chunking(F, bins)
+    assert plan.fits and plan.record_words == R.rec_height(F, 4) > 64
+    assert plan.feature_chunks == -(-Fp * Bp // (1 << 16))
+    assert plan.vmem_bytes > 16 << 20  # past Mosaic's default: asked for
+    _compile_kernels(topo, F, bins)
+
+
+# 256 bins: the accumulators of every chunk fill the VMEM first; 128:
+# the record's blocks (the tallest record the gate admits); 512: uint16
+# bins, two to a word, chunks of 128 features.
+@pytest.mark.parametrize("bins", [255, 127, 511])
+def test_the_widest_table_the_gate_admits_compiles_for_v5e(topo, bins):
+    """``select_grower``'s gate (learners/fused.py ``chunking``) is a
+    sum with given terms, not a compile: here its EDGE is compiled, the
+    widest table it admits at each kind of bound, so no width it offers
+    the fused grower reaches Mosaic to be refused there."""
+    with device.assume_platform("tpu"):
+        lo, hi = 256, 1 << 15  # admitted, refused
+        assert fused.chunking(lo, bins).fits
+        assert not fused.chunking(hi, bins).fits
+        while hi - lo > 1:
+            mid = (lo + hi) // 2
+            lo, hi = (mid, hi) if fused.chunking(mid, bins).fits else (lo, mid)
+        plan = fused.chunking(lo, bins)
+    assert plan.vmem_max == 96 << 20 and plan.vmem_bytes > 90 << 20, plan
+    assert lo > 2000 and plan.feature_chunks >= 12, plan
+    _compile_kernels(topo, lo, bins)
+
+
+# a width that is no multiple of 8 sublanes, bins that are no multiple of
+# 128 lanes, and a table past one chunk
+@pytest.mark.parametrize("F,B", [(28, 256), (12, 255), (300, 256)])
+def test_the_standalone_search_compiles_for_v5e(topo, F, B):
+    """``search2_pallas`` (the canonical and the data-parallel growers'
+    search on a chip: no benchmark cell runs it) in its one layout, a
+    ``[6, Fc, B]`` block a grid step."""
+    from lightgbm_tpu.ops.pallas_search import search2_pallas
+
+    shape = _shape(topo)
+    search2_pallas.lower(
+        shape((F, B, 3)), shape((F, B, 3)), *[shape(())] * 6,
+        shape((), jnp.bool_), shape((F,), jnp.bool_),
+        shape((F,), jnp.int32), shape((F,), jnp.bool_), *[shape(())] * 5,
+        interpret=False).compile()
+
+
+def test_the_grower_at_2000_columns_keeps_its_carry(topo):
+    """``jit_grow_tree`` at epsilon-2000.train's width (fewer rows and
+    leaves): eight feature chunks through the root kernel, the split
+    step and its search, and still three Mosaic calls, one ``while``, no
+    ``conditional``, and neither the 512-word record nor ``hists``
+    copied in the loop."""
+    from lightgbm_tpu.learners.serial import TreeLearnerParams
+    from lightgbm_tpu.obs import device_time as dt
+
+    shape = _shape(topo)
+    F, n, L = 2000, 20_480, 15
+    params = TreeLearnerParams(*[shape(())] * 5, shape((), jnp.int32))
+    with device.assume_platform("tpu"):
+        compiled = fused.grow_tree.lower(
+            shape((F, n), jnp.uint8), shape((n,)), shape((n,)), shape((n,)),
+            shape((F,), jnp.bool_), shape((F,), jnp.int32),
+            shape((F,), jnp.bool_), params, num_bins=255,
+            max_leaves=L).compile()
+    module = compiled.runtime_executable().hlo_modules()[0]
+    prog = dt.program_of_module(module.as_serialized_hlo_module_proto())
+    count = {op: sum(ins.opcode == op for ins in prog.instrs.values())
+             for op in ("while", "conditional")}
+    assert count == {"while": 1, "conditional": 0}, count
+    assert sum(ins.target == "tpu_custom_call"
+               for ins in prog.instrs.values()) == 3
+    _the_carry_is_clean(compiled, n=n, F=F, L=L)
 
 
 # istella-s-220.train's buckets, (queries, Q): one launch each, the last

@@ -20,15 +20,15 @@ from lightgbm_tpu.models import gbdt as gbdt_mod
 from lightgbm_tpu.objectives import create_objective
 
 
-def _booster(platform, block_bytes=None, monkeypatch=None, **params):
-    n, F = 600, 5
+def _booster(platform, vmem=None, monkeypatch=None, F=5, **params):
+    n = 600
     rng = np.random.RandomState(0)
     X = rng.randn(n, F).astype(np.float32)
     y = (X[:, 0] + X[:, 1] > 0).astype(np.float32)
     cfg = Config(objective="binary", num_leaves=7, min_data_in_leaf=5,
                  **params)
-    if block_bytes is not None:
-        monkeypatch.setattr(fused, "HIST_BLOCK_BYTES_MAX", block_bytes)
+    if vmem is not None:
+        monkeypatch.setattr(device, "vmem_bytes", lambda: vmem)
     with device.assume_platform(platform):
         ds = BinnedDataset.from_matrix(X, Metadata(label=y), config=cfg)
         return gbdt_mod.GBDT(cfg, ds, create_objective(cfg, ds.metadata, n))
@@ -46,17 +46,20 @@ _TABLE = [
      "tree_growth=hybrid", None),
     ("tree_learner=data", "tpu", {"tree_learner": "data"}, "canonical",
      "tree_learner=data over 8 devices", None),
-    ("block-too-large", "tpu", {}, "canonical",
-     "over the split step's 4096 bytes", serial.grow_tree),
+    ("vmem-too-small", "tpu", {}, "canonical",
+     "split step VMEM 3 of 1 MiB", serial.grow_tree),
 ]
+
+# (features, chunks of 256 features, record words, MiB the step keeps)
+_WIDE = [(264, 2, 72, 22), (1000, 4, 256, 34), (2000, 8, 512, 54)]
 
 
 @pytest.mark.parametrize(
     "platform,params,which,why,grow", [row[1:] for row in _TABLE],
     ids=[row[0] for row in _TABLE])
 def test_select_grower(platform, params, which, why, grow, monkeypatch):
-    block = 4096 if "4096" in why else None
-    g = _booster(platform, block, monkeypatch, **params)
+    vmem = 2 << 20 if "of 1 MiB" in why else None
+    g = _booster(platform, vmem, monkeypatch, **params)
     assert g._grower[0] == which and why in g._grower[1], g._grower
     with device.assume_platform(platform):
         assert g.select_grower() == g._grower  # asked again, same answer
@@ -83,3 +86,32 @@ def test_a_row_mask_selects_the_canonical_grower():
     assert g._grower == ("canonical", "base row mask")
     assert g._grow.func is serial.grow_tree
     assert g._grow.keywords["choice_by_mask_counts"] is True
+
+
+@pytest.mark.parametrize("F,chunks,words,mib", _WIDE)
+def test_wide_tables_get_the_fused_grower_and_the_bound_is_named(
+        F, chunks, words, mib, monkeypatch):
+    """Past one ``[Fc, 4, Bp]`` block the kernels walk feature chunks
+    (learners/fused.py ``chunking``): the selector offers the fused
+    grower at every width the split step's VMEM takes, says the
+    chunking in the booster's log line and in the ``grow.*`` counters,
+    and on a chip with less VMEM names the bound instead of leaving the
+    table to Mosaic."""
+    from lightgbm_tpu.obs import telemetry
+
+    said = (f"{chunks} chunks of 256 features, record of {words} words, "
+            f"split step VMEM {mib} of ")
+    counters = {"grow.feature_chunks": chunks, "grow.chunk_features": 256,
+                "grow.hist_block_bytes": 1 << 20, "grow.record_words": words}
+    tel = telemetry.get_telemetry()
+    before = {name: tel.counter(name) for name in counters}
+    g = _booster("tpu", F=F)
+    assert g._grower == ("fused", "") and g._grow.func is fused.grow_tree
+    assert any(said + "96 MiB" in m and "grower=fused" in m
+               for m in gbdt_mod._LOGGED_PATHS), gbdt_mod._LOGGED_PATHS
+    assert {name: tel.counter(name) - before[name]
+            for name in counters} == counters  # once a booster
+    small = (mib - 1) * 4 // 3 << 20  # the gate admits three quarters
+    g = _booster("tpu", small, monkeypatch, F=F)
+    assert g._grower[0] == "canonical", g._grower
+    assert said in g._grower[1] and "past the chip's VMEM" in g._grower[1]
