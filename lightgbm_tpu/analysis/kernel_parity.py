@@ -19,12 +19,17 @@ share no code with it, on whatever backend is present:
            stable partition and float64 numpy histograms (a demoted MXU
            precision in the un-annotated one-hot dots shows there), with
            the smaller child a third, under 5% (left, then right, at an
-           unaligned begin) and half of the parent; prints how many
+           unaligned begin) and half of the parent, then the same at
+           2,000 columns (a 512-word record, eight feature chunks) and
+           on a leaf whose tiles are all-left, mixed and all-right (the
+           compaction's lane gathers at both ends of the heights the
+           cells run, and at runs of 0 and TILE lanes); prints how many
            histogram tiles the kernel ran of the parent's
   place  — place_runs (aliased placement) vs the numpy stable
            partition, at the static tile count and, as the grower
            launches it, over a wider window at a run-time tile count
-           (dynamic Mosaic grids, parked chunks, several launches)
+           (dynamic Mosaic grids, parked chunks, several launches);
+           one trial's leaf has all-left and all-right tiles
 
 Each check prints one summary line through ``log`` and returns
 True/False.  ``chip_smoke.py`` is the caller; on a TPU
@@ -89,10 +94,19 @@ def _fused_split(rec, hists, begin, pcnt, f, thr, left_leaf, right_leaf,
     return hists2, rec2, nleft, res, cl, int(ran)
 
 
-def _split_case(rng, F, n, num_bins, integer, bag_frac, tie=None, begin=0):
+def _runs(n, num_bins, head):
+    """A bins row whose first ``head`` rows hold bin 0 and the rest the
+    last bin: split at threshold 0, the tiles before ``head`` are
+    all-left, the one it falls in is mixed, the rest all-right."""
+    return np.where(np.arange(n) < head, 0, num_bins - 1).astype(np.uint8)
+
+
+def _split_case(rng, F, n, num_bins, integer, bag_frac, tie=None, begin=0,
+                runs=None):
     """One leaf of ``n`` rows, ``begin`` columns into its record, and
     everything the split step needs of it: returns (bins, g, h, bag,
-    rec, meta)."""
+    rec, meta).  ``runs`` = (feature, head) lays that feature out as
+    ``_runs`` does."""
     import jax.numpy as jnp
 
     from ..ops.pallas_search import _pack_meta
@@ -101,6 +115,8 @@ def _split_case(rng, F, n, num_bins, integer, bag_frac, tie=None, begin=0):
     bins = rng.randint(0, num_bins, (F, n)).astype(np.uint8)
     if tie is not None:
         bins[tie[1]] = bins[tie[0]]
+    if runs is not None:
+        bins[runs[0]] = _runs(n, num_bins, runs[1])
     if integer:  # exact under ANY accumulation order
         g = rng.randint(-8, 9, n).astype(np.float32)
         h = rng.randint(1, 5, n).astype(np.float32)
@@ -191,15 +207,21 @@ def check_split(rng, log=print, interpret=False) -> bool:
     from ..ops.pallas_search import _pack_scal
     from ..ops.record import TILE, bins_per_word, num_words, round_up
 
-    F, n, num_bins = 11, 5000, 36
+    n, num_bins = 5000, 36
     f = 4
-    lr = num_words(F, bins_per_word(jnp.uint8)) + 4
     all_ok = True
-    # (threshold, begin): the smaller child a third of the parent; under
-    # 5% of it, left and then right, the window unaligned; half of it
-    for thr, begin in ((11, 0), (0, 777), (34, 1291), (17, 0)):
+    # (columns, threshold, begin, head of a runs feature): the smaller
+    # child a third of the parent; under 5% of it, left and then right,
+    # the window unaligned; half of it; a third at 2,000 columns (512
+    # words); two all-left tiles, a mixed one and seven all-right
+    for F, thr, begin, head in (
+            (11, 11, 0, None), (11, 0, 777, None), (11, 34, 1291, None),
+            (11, 17, 0, None), (2000, 11, 777, None),
+            (11, 0, 1291, 2 * TILE + 100)):
+        lr = num_words(F, bins_per_word(jnp.uint8)) + 4
         bins, g, h, bag, rec, meta = _split_case(
-            rng, F, n, num_bins, False, 0.8, begin=begin)
+            rng, F, n, num_bins, False, 0.8, begin=begin,
+            runs=None if head is None else (f, head))
         hists0 = _root_hists(bins, g, h, bag, num_bins, 7, interpret)
         left = bins[f] <= thr
         # the smaller child by bagged count is the one the kernel sums
@@ -235,10 +257,10 @@ def check_split(rng, log=print, interpret=False) -> bool:
         if ran != -(-small // TILE):
             log(f"  split ran {ran} histogram tiles for {small} rows")
             ok = False
-        log(f"split parity: {'OK' if ok else 'FAIL'} (nleft={int(nleft)} of "
-            f"{n}, smaller child {small / n:.1%}, hist tiles / parent tiles "
-            f"= {ran} / {-(-n // TILE)}, hist maxdiff kernel-float64="
-            f"{d_ref:.2e})")
+        log(f"split parity: {'OK' if ok else 'FAIL'} ({F} columns, "
+            f"nleft={int(nleft)} of {n}, smaller child {small / n:.1%}, "
+            f"hist tiles / parent tiles = {ran} / {-(-n // TILE)}, "
+            f"hist maxdiff kernel-float64={d_ref:.2e})")
         all_ok &= ok
     return all_ok
 
@@ -267,9 +289,12 @@ def check_place(rng, log=print, interpret=False) -> bool:
                 (9, 5000, 33, 1291, 0.97),  # nearly-all-left
                 (5, 2000, 16, 300, 0.0),   # all-right
                 (7, 3000, 17, 133, 0.4),   # multi-chunk placement
+                (9, 5000, 33, 777, None),  # all-left and all-right tiles
         )):
             record.PLACE_CHUNK = 8 if trial == 4 else chunk0
             bins = rng.randint(0, num_bins, (F, n)).astype(np.uint8)
+            if frac is None:
+                bins[2], frac = _runs(n, num_bins, 3 * TILE + 57), 0.0
             g = rng.randn(n).astype(np.float32)
             h = (rng.rand(n) + 0.5).astype(np.float32)
             # room for the wider window of the run-time-count launch

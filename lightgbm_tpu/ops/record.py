@@ -49,13 +49,15 @@ Who calls what:
     child's window back with ``unpack_window`` for its ``hist_fn``.
 
 The compaction is per-tile prefix-sum routing: a lane cumsum over the
-go bitmask yields each column's destination offset directly, and the
-columns move through an LSB-first staged-shift compress network
-(Hacker's Delight 7-4), ``2*ceil(log2(TILE))`` roll+select steps on the
-VPU.  (A one-hot routing matrix on the MXU, O(TILE^2) a tile, was the
-first design; it read 8.3% and 9.7% slower a tree than this one in the
-benchmark's two cells, PERF.md PR 30, and is gone.)  Zero per-element
-descriptors anywhere.
+go bitmask yields each column's destination offset directly; an
+LSB-first staged-shift compress network (Hacker's Delight 7-4, a digit
+of base SCAN_RADIX a round) runs on ONE row of packed lane ids a child,
+which leaves in every destination lane the lane it takes; and the
+tile's words follow by lane gathers (_compact_body).  (Every word of
+the tile rode both networks until PR 35; a one-hot routing matrix on the
+MXU, O(TILE^2) a tile, was the first design and read 8.3% and 9.7%
+slower a tree than that in the benchmark's two cells, PERF.md PR 30.)
+Zero per-element descriptors anywhere.
 
 Off the chip (``interpret=True``) two branches differ from what the
 chip runs: ``split_step_window`` reads a materialised window slice
@@ -250,60 +252,111 @@ def _tile_go(tile, scal_i_ref, i, *, F, k):
     return (go * valid).astype(jnp.float32)
 
 
+# Lane offsets one round of the prefix sum and of the compress network
+# looks across: 16 = four bits of the shift a round, fifteen rotates that
+# do not wait for one another.  A round is one trip through the rotate
+# unit, and the trips are what a tile of 32 words waits for: 18 rounds
+# of one rotate each were 0.95 us of its 2.22 a tile.  The step alone, ms
+# a window of 14,649 / 3,991 parent tiles of 32 / 64 words split 3% left
+# (PERF.md, PR 35): radix 2 32.54 / -, 4 26.56 / 9.95, 8 25.64 / 9.56,
+# 16 24.97 / 9.43, 32 27.51 / 10.07 (the parent form 35.76 / 13.24).
+SCAN_RADIX = 16
+
+
 def _lane_cumsum(g):
     """Inclusive prefix sum along the LANE axis of a [1, T] i32 row:
-    ceil(log2(T)) Hillis-Steele roll+mask stages.  Mosaic has no
-    reliable cumsum lowering on the lane axis; ``pltpu.roll`` plus an
-    iota mask (arithmetic, no i1 select) is the portable scan — and it
-    runs identically under interpret mode, so CPU parity tests exercise
-    the same math the chip does."""
+    ceil(log_R(T)) rounds (R = SCAN_RADIX) in which lane t adds lanes
+    t - s, t - 2s, .. t - (R-1)s of the round before, s = R^round: a
+    Hillis-Steele scan of radix R.  Mosaic has no reliable cumsum
+    lowering on the lane axis; ``pltpu.roll`` plus an iota mask is the
+    portable scan — and it runs identically under interpret mode, so
+    CPU parity tests exercise the same math the chip does."""
     T = g.shape[-1]
     lane = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1)
     c = g
     step = 1
     while step < T:
-        # lane t accumulates lane t-step; the iota mask zeroes the
-        # wrapped lanes (< step), so the circular roll acts as a shift.
-        # jnp.where (i1 pred, i32 operands — the _tile_go row-pick
-        # pattern) instead of a cast-and-multiply: a select lowers with
-        # no convert op, keeping the hlo_audit convert budget tight
-        c = c + jnp.where(lane >= step, pltpu.roll(c, step, axis=1), 0)
-        step *= 2
+        # the iota mask zeroes the wrapped lanes (< off), so the
+        # circular roll acts as a shift.  jnp.where (i1 pred, i32
+        # operands — the _tile_go row-pick pattern) instead of a
+        # cast-and-multiply: a select lowers with no convert op,
+        # keeping the hlo_audit convert budget tight
+        c = c + sum(
+            jnp.where(lane >= off, pltpu.roll(c, off, axis=1), 0)
+            for off in range(step, min(SCAN_RADIX * step, T), step))
+        step *= SCAN_RADIX
     return c
 
 
-def _compress_half(tile, live, shift, nbits):
-    """Stable left-compaction of the ``live`` columns of one [R, T]
-    tile: column t moves LEFT by ``shift[t]`` lanes (its lane minus its
-    prefix-sum destination), applied as LSB-first staged moves of 2^j
-    lanes — the Hacker's Delight 7-4 'compress' network.  Monotone
-    zero-count shifts make the stages conflict-free: a live column with
-    bit j still pending sits at lane >= 2^j (its destination is >= 0),
-    so no live column ever wraps or lands on another live column.
+def _source_lanes(live, shift):
+    """Which lane each destination lane of a stable left-compaction
+    takes: row ``r`` of the result holds, for every lane of the run of
+    ``live[r]`` columns compacted to [0, count) in their order, the lane
+    the column came from (0 past the run: in bounds, and garbage).
 
-    The shift row rides the tile (one extra sublane) so it moves WITH
-    its column; ``live`` [1, T] i32 gates every move — vacated lanes
-    carry stale values but a dead live flag, and dead lanes can never
-    move or be kept.  Returns [R, T] with the live columns compacted to
-    [0, count) in original order and GARBAGE beyond — every consumer
-    masks or overwrites garbage lanes via the run counts.
-    """
-    R = tile.shape[0]
-    T = tile.shape[-1]
-    work = jnp.concatenate([tile, shift], axis=0)  # [R+1, T]
-    for j in range(nbits):
-        step = 1 << j
-        # left-rotate by ``step``: lane t sees lane t+step (pltpu.roll
-        # shifts toward higher lanes, so rotate by T-step)
-        r_work = pltpu.roll(work, T - step, axis=1)
-        r_live = pltpu.roll(live, T - step, axis=1)
-        move_in = r_live * ((r_work[R: R + 1, :] >> j) & 1)  # [1, T]
-        stay = live * (1 - ((work[R: R + 1, :] >> j) & 1))
-        # arithmetic select (move_in is exact 0/1); stay and move_in
-        # are disjoint on live lanes by the conflict-freedom argument
-        work = move_in * r_work + (1 - move_in) * work
-        live = jnp.maximum(move_in, stay)
-    return work[:R]
+    Column t moves LEFT by ``shift[r, t]`` lanes (its lane minus its
+    prefix-sum destination), applied LSB-first a DIGIT of the shift a
+    round (base SCAN_RADIX; the Hacker's Delight 7-4 'compress' network
+    is base 2) to ONE packed word a lane, the source lane in the low
+    bits and the pending shift above them: a rotate moves both, and
+    every row of the operand is a network of its own in the same vregs.
+    In the round of digit position p a lane takes the word d * R^p lanes
+    to its right whose digit is d, for d = 1 .. R-1.  Monotone
+    zero-count shifts make the rounds conflict-free: a live column with
+    digit d pending sits at lane >= d * R^p (its destination is >= 0),
+    so no live column ever wraps, and two columns never land on one
+    lane since they never do in the base-2 network this composes.  A
+    lane is DEAD when its word is 0: a dead lane has no pending digit,
+    so it never moves, and a lane a column has left is cleared unless
+    another moves in.  i1 selects on int32 operands throughout (the
+    form _lane_cumsum has), no multiply."""
+    T = live.shape[-1]
+    nbits = (T - 1).bit_length()
+    digit_bits = (SCAN_RADIX - 1).bit_length()
+    lane = jax.lax.broadcasted_iota(jnp.int32, live.shape, 1)
+    work = jnp.where(live > 0, lane | (shift << nbits), 0)
+    for j in range(0, nbits, digit_bits):
+        at = nbits + j  # where this round's digit sits in the word
+        digit = ((1 << min(digit_bits, nbits - j)) - 1) << at
+        # a column whose digit is pending leaves its lane
+        out = jnp.where((work & digit) != 0, 0, work)
+        for d in range(1, (digit >> at) + 1):
+            # left-rotate by d * 2^j: lane t sees lane t + d * 2^j
+            # (pltpu.roll shifts toward higher lanes)
+            r_work = pltpu.roll(work, T - (d << j), axis=1)
+            out = jnp.where((r_work & digit) == (d << at), r_work, out)
+        work = out
+    return work & (T - 1)
+
+
+# lanes of a vreg: what one ``tpu.dynamic_gather`` reaches along the
+# lane axis (Mosaic refuses a gather across a wider operand)
+GATHER_LANES = 128
+
+
+def _gather_lanes(tile, src):
+    """``out[:, t] = tile[:, src[0, t]]`` for a [W, T] tile and one
+    [1, T] row of source lanes of a LEFT-compaction (``t <= src[0, t] <
+    T`` on the run; past it any lane in [0, T), and garbage comes out):
+    a block of GATHER_LANES destination lanes takes its words from each
+    source block by one lane gather (``tpu.dynamic_gather`` on the
+    chip: a ``vperm`` a vreg; plain jax interpreted) and keeps those
+    whose source lies in it.  No column moves right, so the source
+    blocks below the destination's hold nothing it takes: ten gathers
+    a sublane group at T = 512, not sixteen."""
+    W, T = tile.shape
+    G = GATHER_LANES
+    outs = []
+    for d in range(0, T, G):
+        idx = jnp.broadcast_to(src[:, d: d + G], (W, G))
+        within = idx & (G - 1)
+        out = None
+        for s in range(d, T, G):
+            got = jnp.take_along_axis(
+                tile[:, s: s + G], within, axis=1, mode="promise_in_bounds")
+            out = got if out is None else jnp.where(idx >= s, got, out)
+        outs.append(out)
+    return jnp.concatenate(outs, axis=1)
 
 
 def _compact_body(tile, g):
@@ -311,9 +364,15 @@ def _compact_body(tile, g):
     plain and the fused kernel).  A lane cumsum of the go row yields
     destination offsets directly — lefts land at ``cumsum(go)-1``,
     everything else (the invalid tail included) at ``cumsum(1-go)-1``
-    in the right half — and the columns move through two compress
-    networks (2*ceil(log2(T)) roll+select stages on the VPU:
-    O(TILE*log TILE) work a tile).  The i32 words move untouched, so
+    in the right half.  The permutation is COMPUTED on one two-row
+    operand (_source_lanes: the left run's compress network in row 0,
+    the right run's in row 1, four vregs whatever the record's height)
+    and APPLIED once, by lane gathers of the tile (_gather_lanes).
+    Rolling and blending every word of the tile through 18 stages, as
+    this did up to PR 34, cost a parent tile of 32 / 64 / 512 words 2.44
+    / 3.32 / 22.7 us in the split step where this costs 1.70 / 2.36 /
+    13.1 (the step alone on the chip: PERF.md, PR 35; at 512 words the
+    tile spilled at every stage).  The i32 words move untouched, so
     routed content is exact by construction.
 
     tile [W, T] i32, g [1, T] 0/1 row (f32 or i32; 1 = left AND valid)
@@ -322,15 +381,18 @@ def _compact_body(tile, g):
     """
     T = tile.shape[-1]
     gi = g.astype(jnp.int32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
     csum = _lane_cumsum(gi)  # inclusive left count per lane
-    nbits = (T - 1).bit_length()
+    lane = jax.lax.broadcasted_iota(jnp.int32, (2, T), 1)
+    right = jax.lax.broadcasted_iota(jnp.int32, (2, T), 0)
     # go column at lane t: dest = csum[t]-1, shift = t - csum[t] + 1
     # (= non-go count strictly below t); non-go column: dest =
     # t - csum[t], shift = csum[t] (= go count strictly below t)
-    left = _compress_half(tile, gi, lane - csum + 1, nbits)
-    right = _compress_half(tile, 1 - gi, csum, nbits)
-    return jnp.concatenate([left, right], axis=1)
+    src = _source_lanes(
+        jnp.where(right == 0, gi, 1 - gi),
+        jnp.where(right == 0, lane - csum + 1, csum))
+    return jnp.concatenate(
+        [_gather_lanes(tile, src[0:1]), _gather_lanes(tile, src[1:2])],
+        axis=1)
 
 
 def _compact_kernel(win_ref, grow_ref, out_ref):
@@ -341,6 +403,25 @@ def _compact_kernel(win_ref, grow_ref, out_ref):
     lane axis, and a bare [1, cap] row block (sublane dim 1) is not
     Mosaic-legal."""
     out_ref[0] = _compact_body(win_ref[...], grow_ref[0:1, :])
+
+
+def compact_tiles(win, go, interpret: bool = False):
+    """``_compact_body`` over every tile of a [W, cap] window: go [cap]
+    i32 0/1 (1 = left AND valid) -> comp [cap // TILE, W, 2 * TILE]."""
+    W, cap = win.shape
+    T = TILE
+    # go flags ride ROW 0 of a sublane-aligned [8, cap] operand (see
+    # _compact_kernel); rows 1-7 are zero padding
+    flags = jnp.pad(go[None], ((0, 7), (0, 0)))
+    return pl.pallas_call(
+        _compact_kernel,
+        grid=(cap // T,),
+        in_specs=[pl.BlockSpec((W, T), lambda i: (0, i)),
+                  pl.BlockSpec((8, T), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((1, W, 2 * T), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((cap // T, W, 2 * T), jnp.int32),
+        interpret=interpret,
+    )(win, flags)
 
 
 def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
@@ -1258,19 +1339,8 @@ def partition_window(
     # the garbage beyond total-valid-rights is cut by the final selects
     cr = jnp.sum(valid.reshape(nt, T) - kt, axis=1, dtype=jnp.int32)
 
-    # go flags ride ROW 0 of a sublane-aligned [8, cap] operand (see
-    # _compact_kernel); rows 1-7 are zero padding
-    flags = jnp.pad(gov[None], ((0, 7), (0, 0)))
     with phase_scope(f"partition.compact.cap{cap}"):
-        comp = pl.pallas_call(
-            _compact_kernel,
-            grid=(nt,),
-            in_specs=[pl.BlockSpec((W, T), lambda i: (0, i)),
-                      pl.BlockSpec((8, T), lambda i: (0, i))],
-            out_specs=pl.BlockSpec((1, W, 2 * T), lambda i: (i, 0, 0)),
-            out_shape=jax.ShapeDtypeStruct((nt, W, 2 * T), jnp.int32),
-            interpret=interpret,
-        )(win, flags)
+        comp = compact_tiles(win, gov, interpret=interpret)
 
     # aliased in-kernel placement (the XLA reference under interpret)
     rec2 = place_runs(

@@ -9,7 +9,9 @@ described v5e topology, and let Mosaic accept or refuse every kernel.  A refusal
 This says nothing about what the kernels compute (chip_smoke.py does).
 """
 
+import os
 import re
+import sys
 
 import numpy as np
 import pytest
@@ -25,9 +27,20 @@ from lightgbm_tpu.learners import fused
 from lightgbm_tpu.models.gbdt import GBDT
 from lightgbm_tpu.objectives import create_objective
 
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
+import kernel_bundles  # noqa: E402  (tools/)
+
 
 @pytest.fixture(scope="module")
-def topo():
+def llo_dir(tmp_path_factory):
+    """Where this process's libtpu writes its final schedules, asked for
+    before it loads (tools/kernel_bundles.py: two small files a kernel
+    or fusion of every compile below)."""
+    return kernel_bundles.enable(str(tmp_path_factory.mktemp("llo")))
+
+
+@pytest.fixture(scope="module")
+def topo(llo_dir):
     from jax.experimental import topologies
 
     try:
@@ -378,24 +391,31 @@ def test_the_standalone_search_compiles_for_v5e(topo, F, B):
         interpret=False).compile()
 
 
-def test_the_grower_at_2000_columns_keeps_its_carry(topo):
-    """``jit_grow_tree`` at epsilon-2000.train's width (fewer rows and
-    leaves): eight feature chunks through the root kernel, the split
-    step and its search, and still three Mosaic calls, one ``while``, no
-    ``conditional``, and neither the 512-word record nor ``hists``
-    copied in the loop."""
+@pytest.fixture(scope="module")
+def wide_grower(topo):
+    """``(compiled, jaxpr)`` of ``jit_grow_tree`` at epsilon-2000.train's
+    width (fewer rows and leaves), from abstract shapes."""
     from lightgbm_tpu.learners.serial import TreeLearnerParams
-    from lightgbm_tpu.obs import device_time as dt
 
     shape = _shape(topo)
     F, n, L = 2000, 20_480, 15
     params = TreeLearnerParams(*[shape(())] * 5, shape((), jnp.int32))
     with device.assume_platform("tpu"):
-        compiled = fused.grow_tree.lower(
+        traced = fused.grow_tree.trace(
             shape((F, n), jnp.uint8), shape((n,)), shape((n,)), shape((n,)),
             shape((F,), jnp.bool_), shape((F,), jnp.int32),
-            shape((F,), jnp.bool_), params, num_bins=255,
-            max_leaves=L).compile()
+            shape((F,), jnp.bool_), params, num_bins=255, max_leaves=L)
+        return traced.lower().compile(), traced.jaxpr.jaxpr
+
+
+def test_the_grower_at_2000_columns_keeps_its_carry(wide_grower):
+    """``jit_grow_tree`` at epsilon-2000.train's width: eight feature
+    chunks through the root kernel, the split step and its search, and
+    still three Mosaic calls, one ``while``, no ``conditional``, and
+    neither the 512-word record nor ``hists`` copied in the loop."""
+    from lightgbm_tpu.obs import device_time as dt
+
+    compiled = wide_grower[0]
     module = compiled.runtime_executable().hlo_modules()[0]
     prog = dt.program_of_module(module.as_serialized_hlo_module_proto())
     count = {op: sum(ins.opcode == op for ins in prog.instrs.values())
@@ -403,7 +423,54 @@ def test_the_grower_at_2000_columns_keeps_its_carry(topo):
     assert count == {"while": 1, "conditional": 0}, count
     assert sum(ins.target == "tpu_custom_call"
                for ins in prog.instrs.values()) == 3
-    _the_carry_is_clean(compiled, n=n, F=F, L=L)
+    _the_carry_is_clean(compiled, n=20_480, F=2000, L=15)
+
+
+@pytest.mark.parametrize("which,F", [("grower", 28), ("wide_grower", 2000)])
+def test_the_split_step_permutes_a_tile_by_lane_gathers(request, which, F):
+    """The split step's kernel computes a tile's permutation on rows of
+    control words and applies it by lane gathers (ops/record.py
+    _source_lanes, _gather_lanes): its jaxpr holds ``gather`` equations
+    of ``[W, 128]`` blocks, ten a half, and the only lane rotates of an
+    operand as tall as the record are the three that move whole runs
+    (two for the direct read's unaligned tile, one for the staging
+    append); every other rotate is of the prefix sum's one row or the
+    two compress networks' two.  Rolling and blending all ``W + 1``
+    rows through 18 stages cost a tile of 512 words 22.7 us in the step
+    where this costs 13.1 (PERF.md, PR 35)."""
+    from lightgbm_tpu.ops import record as R
+
+    jaxpr = request.getfixturevalue(which)[-1]
+    W, T, G = R.rec_height(F, 4), R.TILE, R.GATHER_LANES
+    step = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"
+            and "lgbm.split_step.dyn" in str(e.source_info.name_stack)]
+    assert len(step) == 1, [str(e.source_info.name_stack) for e in step]
+    body = list(_eqns(step[0].params["jaxpr"]))
+    gathers = [e for e in body if e.primitive.name == "gather"]
+    blocks = T // G
+    assert len(gathers) == 2 * blocks * (blocks + 1) // 2, len(gathers)
+    assert {tuple(v.aval.shape for v in e.invars) for e in gathers} == {
+        ((W, G), (W, G, 1))}
+    rolled = [e.invars[0].aval.shape for e in body
+              if e.primitive.name == "roll"]
+    assert sorted(s for s in rolled if s[0] > 2) == [(W, T)] * 3, rolled
+    assert {s for s in rolled if s[0] <= 2} == {(1, T), (2, T)}, rolled
+
+
+def test_the_compaction_is_scheduled_well_under_the_parents_bundles(
+        topo, llo_dir):
+    """tools/kernel_bundles.py reads libtpu's final schedule of
+    ``_compact_body`` alone at the tall cells' 32 words: under 1,000
+    bundles a tile where rolling the whole tile through the networks
+    took 1,198 (PERF.md, PR 35: at 512 words the parent's count came
+    within 2% of the chip's time; at 32 the chip waits on the rotate
+    unit, which no schedule shows).  A form that spills, or a multiply
+    where a select stood, shows here at no chip time."""
+    kernel_bundles.read(llo_dir)  # what the compiles before this wrote
+    kernel_bundles.compact(_shape(topo), 32).compile()
+    found = kernel_bundles.read(llo_dir)
+    calls = [n for name, n in found.items() if name.startswith("compact_tiles")]
+    assert len(calls) == 1 and 100 < calls[0] < 1000, found
 
 
 # istella-s-220.train's buckets, (queries, Q): one launch each, the last

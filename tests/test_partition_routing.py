@@ -200,3 +200,35 @@ def test_prefix_lane_cumsum_matches_numpy():
             out_shape=jax.ShapeDtypeStruct((1, T), jnp.int32),
             interpret=True)(jnp.asarray(g)))
         np.testing.assert_array_equal(got, np.cumsum(g[0])[None])
+
+
+# go share of the tile: none, one lane, 3%, half, 97%, all
+_GO_SHARES = (0.0, "one", 0.03, 0.5, 0.97, 1.0)
+
+
+@pytest.mark.parametrize("tail", [0, 57], ids=["whole", "invalid_tail"])
+@pytest.mark.parametrize("share", _GO_SHARES, ids=[str(s) for s in _GO_SHARES])
+@pytest.mark.parametrize("W", [8, 32, 64, 136])
+def test_compact_body_matches_a_numpy_stable_partition(W, share, tail):
+    """``_compact_body`` alone (the permutation computed on one two-row
+    operand, applied by lane gathers) through an interpreted one-tile
+    call: the lefts, in order, fill the left half from lane 0 and
+    everything else, the invalid tail last, the right half, word for
+    word; the lanes past each run are garbage and not compared.  Record
+    heights of the narrow fixtures, of the cells (32, 64 words) and one
+    that is no power of two (512 columns of 128 bins)."""
+    T = R.TILE
+    rng = np.random.RandomState(W * 7 + tail)
+    tile = rng.randint(-2**31, 2**31, (W, T), dtype=np.int64).astype(np.int32)
+    valid = np.arange(T) < T - tail
+    if share == "one":
+        go = np.arange(T) == rng.randint(T - tail)
+    else:
+        go = rng.rand(T) < share
+    go = (go & valid).astype(np.int32)  # 1 = left AND valid, as _tile_go's
+    comp = np.asarray(R.compact_tiles(
+        jnp.asarray(tile), jnp.asarray(go), interpret=True))[0]
+    nleft = int(go.sum())
+    assert comp.shape == (W, 2 * T)
+    np.testing.assert_array_equal(comp[:, :nleft], tile[:, go == 1])
+    np.testing.assert_array_equal(comp[:, T: 2 * T - nleft], tile[:, go == 0])
