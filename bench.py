@@ -249,10 +249,7 @@ def warm_until_compile_stable(step, max_warm: int | None = None,
     two iterations: the stability test needs a baseline before a slow
     (lazily-compiling) iteration can be told apart from steady state.
 
-    Returns ``(warmed_iters, compile_stable)``.  Shared by the bench
-    warm-up and tools/telemetry_overhead.py so the committed overhead
-    proof warms under exactly the discipline of the headline it
-    certifies."""
+    Returns ``(warmed_iters, compile_stable)``."""
     from lightgbm_tpu.analysis.recompile import compile_counter
 
     if max_warm is None:
@@ -432,7 +429,7 @@ def _emit_result(out: dict, info: dict, key: str) -> None:
     failure included — the driver contract is one JSON line, whatever
     happens)."""
     try:
-        from lightgbm_tpu.obs import RunManifest, telemetry
+        from lightgbm_tpu.obs import RunManifest
         from lightgbm_tpu.obs import memory as obs_memory
 
         # device-memory evidence ships INSIDE the row like the warm-up
@@ -463,7 +460,6 @@ def _emit_result(out: dict, info: dict, key: str) -> None:
         manifest.write(path)
         repo = os.path.dirname(os.path.abspath(__file__))
         out["manifest"] = os.path.relpath(path, repo)
-        telemetry.emit_if_json()
     except Exception as e:
         log(f"manifest write failed: {type(e).__name__}: {e}")
     print(json.dumps(out), flush=True)

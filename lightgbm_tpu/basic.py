@@ -22,14 +22,15 @@ from .metrics import Metric, create_metrics
 from .models.dart import create_boosting
 from .models.gbdt import GBDT
 from .objectives import create_objective
+from .obs import telemetry
 
 
 class LightGBMError(Exception):
     """Error raised by the framework (reference basic.py:45)."""
 
 
-def _to_2d_float(data) -> np.ndarray:
-    arr = np.asarray(data, dtype=np.float64)
+def _to_2d_float(data, dtype=np.float64) -> np.ndarray:
+    arr = np.asarray(data, dtype=dtype)
     if arr.ndim == 1:
         arr = arr.reshape(-1, 1)
     if arr.ndim != 2:
@@ -37,13 +38,15 @@ def _to_2d_float(data) -> np.ndarray:
     return arr
 
 
-def _densify(data) -> np.ndarray:
-    """Accept numpy / pandas / scipy-sparse row data (basic.py:472-927)."""
+def _densify(data, dtype=np.float64) -> np.ndarray:
+    """Accept numpy / pandas / scipy-sparse row data (basic.py:472-927).
+    ``dtype=None`` keeps the data's own: ``Dataset.construct`` leaves the
+    one conversion to the binner, which makes it under a span."""
     if hasattr(data, "toarray"):  # scipy CSR/CSC
-        return _to_2d_float(data.toarray())
+        return _to_2d_float(data.toarray(), dtype)
     if hasattr(data, "values") and not isinstance(data, np.ndarray):  # pandas
-        return _to_2d_float(np.asarray(data.values, dtype=np.float64))
-    return _to_2d_float(data)
+        return _to_2d_float(data.values, dtype)
+    return _to_2d_float(data, dtype)
 
 
 class Dataset:
@@ -84,9 +87,19 @@ class Dataset:
 
     # ------------------------------------------------------------ construct
     def construct(self) -> BinnedDataset:
-        """Build the binned dataset lazily (basic.py:1014-1036)."""
-        if self._inner is not None:
-            return self._inner
+        """Build the binned dataset lazily (basic.py:1014-1036).  The
+        first call is the span ``lgbm.setup.ingest`` (host wall time;
+        a reference set is built before it, under its own)."""
+        if self._inner is None:
+            ref_inner = (self.reference.construct()
+                         if self.reference is not None else None)
+            with telemetry.span("lgbm.setup.ingest"):
+                self._inner = self._ingest(ref_inner)
+            if self.free_raw_data:
+                self.data = None
+        return self._inner
+
+    def _ingest(self, ref_inner: Optional[BinnedDataset]) -> BinnedDataset:
         params = key_alias_transform(dict(self.params))
         params.setdefault("max_bin", self.max_bin)
         cfg = Config.from_dict(params)
@@ -107,66 +120,56 @@ class Dataset:
                 raise LightGBMError(
                     f"categorical_feature name not in feature_name: {e}"
                 ) from None
-        meta_kwargs = dict(
-            label=None if self.label is None else np.asarray(self.label),
-            weights=self.weight,
-            init_score=self.init_score,
-        )
-        meta = Metadata(**meta_kwargs)
-        if self.group is not None:
-            meta.set_field("group", np.asarray(self.group))
+        with telemetry.span("lgbm.setup.ingest.metadata"):
+            meta = Metadata(
+                label=None if self.label is None else np.asarray(self.label),
+                weights=self.weight,
+                init_score=self.init_score,
+            )
+            if self.group is not None:
+                meta.set_field("group", np.asarray(self.group))
 
-        ref_inner = self.reference.construct() if self.reference is not None else None
         if isinstance(self.data, str):
-            self._inner = BinnedDataset.from_file(
+            inner = BinnedDataset.from_file(
                 self.data, config=cfg, reference=ref_inner,
                 categorical_features=cats or None,
             )
             if meta.label is not None:
-                self._inner.metadata.set_field("label", meta.label)
+                inner.metadata.set_field("label", meta.label)
             for field in ("weight", "init_score"):
                 v = meta.get_field(field)
                 if v is not None:
-                    self._inner.metadata.set_field(field, v)
+                    inner.metadata.set_field(field, v)
             if meta.query_boundaries is not None:
-                self._inner.metadata.query_boundaries = meta.query_boundaries
-                self._inner.metadata._finish()
-        elif hasattr(self.data, "tocsr"):  # scipy sparse: O(nnz) ingest,
+                inner.metadata.query_boundaries = meta.query_boundaries
+                inner.metadata._finish()
+            return inner
+        if meta.label is None:
+            raise LightGBMError("label should not be None for training data")
+        if hasattr(self.data, "tocsr"):  # scipy sparse: O(nnz) ingest,
             # never densified to f64 (reference SparseBin path,
             # sparse_bin.hpp; round 1 called .toarray() here)
-            if meta.label is None:
-                raise LightGBMError("label should not be None for training data")
             csr = self.data.tocsr()
             indptr = np.asarray(csr.indptr, dtype=np.int64)
             indices = np.asarray(csr.indices, dtype=np.int64)
             values = np.asarray(csr.data, dtype=np.float64)
             if ref_inner is not None:
-                self._inner = ref_inner.align_with_csr(
-                    indptr, indices, values, meta
-                )
-            else:
-                self._inner = BinnedDataset.from_csr(
-                    indptr, indices, values, csr.shape[1], meta, config=cfg,
-                    categorical_features=cats,
-                    feature_names=self.feature_name,
-                )
-        else:
-            X = _densify(self.data)
-            if meta.label is None:
-                raise LightGBMError("label should not be None for training data")
-            if ref_inner is not None:
-                self._inner = ref_inner.align_with(X, meta)
-            else:
-                self._inner = BinnedDataset.from_matrix(
-                    X,
-                    meta,
-                    config=cfg,
-                    categorical_features=cats,
-                    feature_names=self.feature_name,
-                )
-        if self.free_raw_data:
-            self.data = None
-        return self._inner
+                return ref_inner.align_with_csr(indptr, indices, values, meta)
+            return BinnedDataset.from_csr(
+                indptr, indices, values, csr.shape[1], meta, config=cfg,
+                categorical_features=cats,
+                feature_names=self.feature_name,
+            )
+        X = _densify(self.data, dtype=None)
+        if ref_inner is not None:
+            return ref_inner.align_with(X, meta)
+        return BinnedDataset.from_matrix(
+            X,
+            meta,
+            config=cfg,
+            categorical_features=cats,
+            feature_names=self.feature_name,
+        )
 
     def create_valid(self, data, label=None, weight=None, group=None,
                      init_score=None, params=None) -> "Dataset":
@@ -322,16 +325,19 @@ class Booster:
             if not isinstance(train_set, Dataset):
                 raise LightGBMError("Training data should be Dataset instance")
             cfg = Config.from_dict(self.params)
-            inner_train = train_set.construct()
-            objective = None
-            if cfg.objective != "none":
-                objective = create_objective(cfg, inner_train.metadata, inner_train.num_data)
-            self._gbdt = create_boosting(cfg, inner_train, objective)
-            self.config = cfg
-            self._train_dataset = train_set
-            if cfg.input_model:
-                init = Booster(model_file=cfg.input_model)
-                self._gbdt.merge_from(init._gbdt, prepend=True)
+            inner_train = train_set.construct()  # under its own span
+            with telemetry.span("lgbm.setup.booster"):
+                objective = None
+                if cfg.objective != "none":
+                    with telemetry.span("lgbm.setup.booster.objective"):
+                        objective = create_objective(
+                            cfg, inner_train.metadata, inner_train.num_data)
+                self._gbdt = create_boosting(cfg, inner_train, objective)
+                self.config = cfg
+                self._train_dataset = train_set
+                if cfg.input_model:
+                    init = Booster(model_file=cfg.input_model)
+                    self._gbdt.merge_from(init._gbdt, prepend=True)
         elif model_file is not None:
             with open(model_file, "r") as fh:
                 model_str = fh.read()

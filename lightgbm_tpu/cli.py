@@ -326,7 +326,6 @@ def _write_train_manifest(cfg: Config, booster: GBDT, train_s: float,
                     f"rank {dist.process_index()}: published telemetry "
                     f"snapshot to {xdir}; rank 0 writes the merged "
                     "manifest")
-                telemetry.emit_if_json()
                 return
             try:
                 snaps = dist.gather_rank_snapshots(
@@ -368,7 +367,6 @@ def _write_train_manifest(cfg: Config, booster: GBDT, train_s: float,
             # debug line a tool can parse out of the CLI log
             Log.debug("telemetry " + json.dumps(
                 telemetry.get_telemetry().snapshot(), sort_keys=True))
-        telemetry.emit_if_json()
     except Exception as e:
         Log.warning(f"run manifest write failed: {type(e).__name__}: {e}")
 

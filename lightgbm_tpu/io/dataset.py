@@ -225,6 +225,21 @@ class BinnedDataset:
             f"Column_{i}" for i in range(num_total_features)
         ]
 
+    def _count_ingest(self, sample_rows: int,
+                      float64_bytes: int) -> "BinnedDataset":
+        """The ``ingest.*`` counters, once a dataset, from the loader
+        that binned it: the table's shape, the rows the bin finder
+        sampled, the float64 values held at once, the bins kept."""
+        telemetry.count_many({
+            "ingest.rows": self.num_data,
+            "ingest.columns": self.num_total_features,
+            "ingest.used_columns": self.num_features,
+            "ingest.sample_rows": sample_rows,
+            "ingest.float64_bytes": float64_bytes,
+            "ingest.bin_bytes": self.X_bin.nbytes,
+        })
+        return self
+
     # ---------------------------------------------------------------- props
     @property
     def is_sparse(self) -> bool:
@@ -301,16 +316,20 @@ class BinnedDataset:
         column, trivial ones dropped here) skips bin finding — used by the
         distributed loader where mappers must be rank-consistent."""
         config = config or Config()
-        X = np.ascontiguousarray(X, dtype=np.float64)
+        with telemetry.span("lgbm.setup.ingest.float64"):
+            X = np.ascontiguousarray(X, dtype=np.float64)
         n, f_total = X.shape
+        sample_rows = 0
         if mappers_all is None:
-            sample_idx = _sample_row_indices(n, config)
-            mappers_all = find_bin_mappers(
-                X[sample_idx],
-                total_sample_cnt=len(sample_idx),
-                max_bin=config.max_bin,
-                categorical_features=categorical_features,
-            )
+            with telemetry.span("lgbm.setup.ingest.find_bins"):
+                sample_idx = _sample_row_indices(n, config)
+                sample_rows = len(sample_idx)
+                mappers_all = find_bin_mappers(
+                    X[sample_idx],
+                    total_sample_cnt=sample_rows,
+                    max_bin=config.max_bin,
+                    categorical_features=categorical_features,
+                )
         if len(mappers_all) != f_total:
             raise ValueError(
                 f"mappers_all covers {len(mappers_all)} columns, data has {f_total}"
@@ -334,11 +353,16 @@ class BinnedDataset:
                 f"max 65536 bins per feature (uint16 storage) — lower "
                 f"max_bin or bin_construct_sample_cnt")
         dtype = np.uint8 if max_nb <= 256 else np.uint16
-        X_bin = np.empty((n, len(used_mappers)), dtype=dtype)
-        _encode_bins(X, used_map, used_mappers, X_bin)
+        with telemetry.span("lgbm.setup.ingest.encode"):
+            X_bin = np.empty((n, len(used_mappers)), dtype=dtype)
+            _encode_bins(X, used_map, used_mappers, X_bin)
+            float64_bytes = X.nbytes
+            # a float64 copy made above goes back to the system here, under
+            # the span and not at the return: 0.08 s a GB on the chip's host
+            del X
         return BinnedDataset(
             X_bin, used_mappers, used_map, f_total, metadata, feature_names
-        )
+        )._count_ingest(sample_rows, float64_bytes)
 
     @staticmethod
     def from_csr(
@@ -366,8 +390,10 @@ class BinnedDataset:
 
         config = config or Config()
         n = len(indptr) - 1
+        sample_rows = 0
         if mappers_all is None:
             sample_idx = _sample_row_indices(n, config)
+            sample_rows = len(sample_idx)
             mappers_all = find_bin_mappers_csr(
                 indptr, indices, values, num_cols, sample_idx,
                 max_bin=config.max_bin,
@@ -388,7 +414,7 @@ class BinnedDataset:
         X_bin = sb if keep_sparse else sb.toarray()
         return BinnedDataset(
             X_bin, used_mappers, used_map, num_cols, metadata, feature_names
-        )
+        )._count_ingest(sample_rows, values.nbytes)
 
     def align_with(
         self, X: np.ndarray, metadata: Metadata
@@ -759,7 +785,7 @@ class BinnedDataset:
             )
         ds = BinnedDataset(
             X_bin, used_mappers, used_map, len(feat_cols), meta, fnames
-        )
+        )._count_ingest(len(sample_idx), sample_raw.nbytes)
         if config.is_save_binary_file:
             ds.save_binary(path + ".bin")
         return ds

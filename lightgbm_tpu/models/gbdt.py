@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..analysis import recompile
 from ..config import Config
 from ..device import enable_compile_cache, on_tpu
 from ..device import platform as device_platform
@@ -123,6 +124,7 @@ class GBDT:
         objective: Optional[ObjectiveFunction] = None,
     ):
         enable_compile_cache()  # lazy, TPU-gated, once
+        recompile.install()  # the compile.* seconds by program, likewise
         self.config = config
         self.num_class = int(config.num_class)
         self.learning_rate = float(config.learning_rate)
@@ -180,38 +182,45 @@ class GBDT:
             self.sigmoid = self.objective.sigmoid
 
         self._num_bins = max(int(train_set.max_num_bin), 2)
-        self._nbpf = jnp.asarray(train_set.num_bins_per_feature)
-        self._is_cat = jnp.asarray(train_set.is_categorical)
-        self._learner_params = TreeLearnerParams.from_config(self.config)
-        self._real_feat = train_set.real_feature_indices
-        self._bin_thresholds = train_set.bin_thresholds_real()
-        self._bounds_mat, self._real_feat_dev = pack_threshold_bounds(
-            self._bin_thresholds, self._real_feat)
         # mesh learners set these in _create_tree_learner: how the
         # [F, n] binned matrix and [n]-shaped row vectors lie over the
         # mesh.  None = everything on the default device (serial).
         self._bins_sharding = self._row_sharding = None
         self._learner_devices = 1
-        self._grower = self.select_grower()
-        self._grow = self._create_tree_learner()
-        # device copy cached ON the dataset: cv folds / train_many models
-        # constructed over the same BinnedDataset share one upload.  Under
-        # a mesh learner the matrix goes to its shards ONCE here, so the
-        # per-tree jit finds every operand already in place.
-        self._bins_T = train_set.dense_bins_T_device(self._bins_sharding)
-        self._log_paths_once()
+        with telemetry.span("lgbm.setup.booster.learner"):
+            self._learner_params = TreeLearnerParams.from_config(self.config)
+            self._grower = self.select_grower()
+            self._grow = self._create_tree_learner()
+        # host transpose plus device_put, none waited for: host wall
+        # time like every span
+        with telemetry.span("lgbm.setup.booster.upload"):
+            self._nbpf = jnp.asarray(train_set.num_bins_per_feature)
+            self._is_cat = jnp.asarray(train_set.is_categorical)
+            self._real_feat = train_set.real_feature_indices
+            self._bin_thresholds = train_set.bin_thresholds_real()
+            self._bounds_mat, self._real_feat_dev = pack_threshold_bounds(
+                self._bin_thresholds, self._real_feat)
+            # device copy cached ON the dataset: cv folds / train_many
+            # models constructed over the same BinnedDataset share one
+            # upload.  Under a mesh learner the matrix goes to its shards
+            # ONCE here, so the per-tree jit finds every operand already
+            # in place.
+            self._bins_T = train_set.dense_bins_T_device(self._bins_sharding)
 
-        K = self.num_class
-        init = train_set.metadata.init_score
-        if init is not None:
-            scores = np.asarray(init, np.float32).reshape(K, n) if K > 1 else np.asarray(
-                init, np.float32
-            ).reshape(1, n)
-        else:
-            scores = np.zeros((K, n), np.float32)
-        self._scores = self._by_row(scores)
-        self._bag_mask = self._by_row(np.ones(n, np.float32))
-        self._bag_cnt = n
+            K = self.num_class
+            init = train_set.metadata.init_score
+            if init is not None:
+                scores = np.asarray(init, np.float32).reshape(K, n)
+            else:
+                scores = np.zeros((K, n), np.float32)
+            self._scores = self._by_row(scores)
+            self._bag_mask = self._by_row(np.ones(n, np.float32))
+            self._bag_cnt = n
+            telemetry.count("setup.upload_bytes", sum(
+                a.nbytes for a in (
+                    self._bins_T, self._scores, self._bag_mask, self._nbpf,
+                    self._is_cat, self._bounds_mat, self._real_feat_dev)))
+        self._log_paths_once()
         # memory-census owner tags (obs/memory.py).  Getters resolve
         # the CURRENT attributes at census time, so the per-iteration
         # reassignment of _scores stays covered; the registry keeps
@@ -230,9 +239,10 @@ class GBDT:
                            getattr(b, "_valid_scores", []),
                            getattr(b, "_valid_bins", []))),
         )
-        self.train_metrics = create_metrics(
-            self.config, train_set.metadata, n
-        )
+        with telemetry.span("lgbm.setup.booster.metrics"):
+            self.train_metrics = create_metrics(
+                self.config, train_set.metadata, n
+            )
         obs_memory.phase_boundary("binning")
         # rollback support: keep per-iteration train score deltas off-device?
         # cheaper: recompute on rollback from stored trees (rare path).
@@ -695,11 +705,20 @@ class GBDT:
         host can feed the device, not device time (the distinction the
         jaxlint ``wallclock-without-sync`` rule exists to protect).
         Synced per-tree times come from the bench harness's own timed
-        loop; device time by scope from obs.device_time.  The four host
-        phases of an iteration are spans (``lgbm.host.gradients``,
-        ``.grow``, ``.stop_check``, ``.post_grow``): with a profiler
+        loop; device time by scope from obs.device_time.  Every
+        statement of an iteration is under a host span
+        (``lgbm.host.gradients``, ``.sample``, ``.grow``,
+        ``.stop_check``, ``.post_grow``, ``.book``): with a profiler
         session open they stand on the trace's host plane, where
-        obs.device_time puts the device's idle gaps down to them."""
+        obs.device_time puts the device's idle gaps down to them.
+        Iteration 0 of a booster, where every program traces and
+        compiles, is the span ``lgbm.setup.first_iter`` besides."""
+        if self.iter_ == 0:
+            with telemetry.span("lgbm.setup.first_iter"):
+                return self._booked_iter(grad, hess)
+        return self._booked_iter(grad, hess)
+
+    def _booked_iter(self, grad, hess) -> bool:
         t0 = time.perf_counter()
         try:
             # chaos hook (LGBM_TPU_FAULT=oom_dispatch): fake
@@ -715,10 +734,11 @@ class GBDT:
                 predict_params=self._memmodel_params())
             raise
         finally:
-            telemetry.count("train_iters")
-            telemetry.record_value(
-                "tree_dispatch_s", time.perf_counter() - t0)
-            obs_memory.phase_boundary("train")
+            with telemetry.span("lgbm.host.book"):
+                telemetry.count("train_iters")
+                telemetry.record_value(
+                    "tree_dispatch_s", time.perf_counter() - t0)
+                obs_memory.phase_boundary("train")
 
     def _memmodel_params(self) -> Optional[dict]:
         """This booster's shape in obs/memmodel.predict vocabulary
@@ -825,11 +845,14 @@ class GBDT:
                 self._pending_stop.clear()
                 return "stop"
         if grad is None or hess is None:
-            scores = self._scores if K > 1 else self._scores[0]
+            # the span holds the slice before and the reshapes after: each
+            # is a program launch of its own (0.4-1.3 ms of host time on the
+            # chip, PERF.md section 6, PR 36)
             with telemetry.span("lgbm.host.gradients"):
+                scores = self._scores if K > 1 else self._scores[0]
                 grad, hess = self.objective.get_gradients(scores)
-            if K == 1:
-                grad, hess = grad[None, :], hess[None, :]
+                if K == 1:
+                    grad, hess = grad[None, :], hess[None, :]
         else:
             grad = jnp.asarray(grad, jnp.float32).reshape(K, self.num_data)
             hess = jnp.asarray(hess, jnp.float32).reshape(K, self.num_data)
@@ -854,11 +877,12 @@ class GBDT:
             if skip_iter:
                 return "skip"
 
-        self._update_bagging()
-        # per-class feature samples drawn in k-order BEFORE any growth:
-        # same _feat_rng consumption sequence as the sequential k-loop
-        # (nothing else draws between them), so stacked == loop trees
-        fmasks = [self._sample_features() for _ in range(K)]
+        with telemetry.span("lgbm.host.sample"):
+            self._update_bagging()
+            # per-class feature samples drawn in k-order BEFORE any growth:
+            # same _feat_rng consumption sequence as the sequential k-loop
+            # (nothing else draws between them), so stacked == loop trees
+            fmasks = [self._sample_features() for _ in range(K)]
         return grad, hess, fmasks, nf_snap
 
     def _forest_finish_tree(self, k: int, tree, leaf_id) -> bool:
@@ -903,7 +927,8 @@ class GBDT:
                 self._valid_scores[vi] = self._valid_scores[vi].at[k].add(
                     predict_binned(tree, self._valid_bins[vi])
                 )
-        self.models.append(tree)
+        with telemetry.span("lgbm.host.book"):
+            self.models.append(tree)
         return could_split
 
     def _forest_finish_iter(self, grown, nf_snap) -> bool:

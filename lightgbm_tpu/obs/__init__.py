@@ -59,7 +59,6 @@ from .telemetry import (  # noqa: F401
     collective_stats,
     count,
     count_many,
-    emit_if_json,
     enabled,
     get_telemetry,
     host_sync,

@@ -31,7 +31,7 @@ import sys
 import time
 from typing import Any, Dict, Optional
 
-from .telemetry import get_telemetry
+from .telemetry import get_telemetry, setup_timeline
 
 SCHEMA = "lightgbm-tpu/run-manifest/v1"
 
@@ -153,6 +153,10 @@ class RunManifest:
     # manifest_memory_section()): hbm gauges, boundary watermarks,
     # owner-tagged census summary.  Optional in v1 like ``ranks``.
     memory: dict = dataclasses.field(default_factory=dict)
+    # the ``lgbm.setup.*`` spans in order of start (telemetry.
+    # setup_timeline): ingest, booster construction, the first
+    # iteration.  Optional in v1 like ``ranks``.
+    setup: list = dataclasses.field(default_factory=list)
     schema: str = SCHEMA
 
     @classmethod
@@ -184,6 +188,7 @@ class RunManifest:
             extra=dict(extra or {}),
             ranks=list(ranks or []),
             memory=dict(memory or {}),
+            setup=setup_timeline(snap),
         )
 
     def to_dict(self) -> dict:

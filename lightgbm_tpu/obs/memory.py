@@ -68,9 +68,9 @@ _last_census: Optional[dict] = None
 
 
 def set_enabled(on: bool) -> None:
-    """Runtime A/B switch for the sampling half (watermark sampling and
-    census-on-boundary); used by tools/telemetry_overhead.py --memory.
-    Explicit census / stats calls still work while disabled."""
+    """Runtime switch for the sampling half (watermark sampling and
+    census-on-boundary).  Explicit census / stats calls still work
+    while disabled."""
     global _enabled
     _enabled = bool(on)
 

@@ -13,15 +13,22 @@ Design constraints, in order:
   one uncontended-lock acquisition and one dict add (the lock arrived
   with the multi-threaded serving tier — see the :class:`Telemetry`
   docstring).  Nothing here touches a device array, forces a sync, or
-  allocates per-iteration beyond a float append.  The bound is itself
-  an acceptance criterion (``tools/telemetry_overhead.py``, ≤2% at the
-  100k driver-like shape, artifact in ``.bench/``).
+  allocates per-iteration beyond a float append.  What it costs is
+  measured where it counts: on the chip, parent against change against
+  ``LGBM_TPU_TELEMETRY=off`` in one call (PERF.md section 6).
 * **Honesty about async dispatch.**  Host-side span times measure
   *dispatch* wall time, not device time — ``train_one_iter`` returns
   before the chip finishes (the same hazard the jaxlint
   ``wallclock-without-sync`` rule flags).  Spans are therefore labeled
   host-wall; phase-attributed *device* time comes from the profiler
   trace (:mod:`lightgbm_tpu.obs.device_time`), never from host timers.
+  No span holds a ``block_until_ready``: an asynchronous ``device_put``
+  under ``lgbm.setup.booster.upload`` is what it is.
+* **A timeline, not only totals.**  Every span keeps its first start as
+  seconds since the package's import began (``first_start_s``), so the
+  ``lgbm.setup.*`` spans of ingest, booster construction and the first
+  iteration read in order (:func:`setup_timeline`; docs/observability.md
+  "Set-up timeline").
 * **No jax import at module import.**  Tools (benchdiff, jaxlint) read
   telemetry data structures without paying a jax import; the compile
   counter bridges to :mod:`lightgbm_tpu.analysis.recompile` lazily.
@@ -30,6 +37,25 @@ Counters maintained by the library itself:
 
 * ``backend_compiles`` — XLA backend compiles (snapshot-time bridge to
   ``analysis/recompile.py``'s process-wide listener; cache hits are 0).
+* ``compile.trace_s.<fun>`` / ``compile.lower_s.<fun>`` /
+  ``compile.backend_s.<fun>`` — seconds jax spent tracing ``<fun>`` to
+  a jaxpr, lowering it to MLIR, and in ``compile_or_get_cached`` (XLA's
+  and Mosaic's compile on a miss of the persistent cache, the retrieval
+  on a hit), by jitted program (``jit_grow_tree``); ``compile.programs``
+  counts the last.  ``compile.cache_hits`` / ``.cache_misses`` /
+  ``.cache_retrieval_s`` / ``.time_saved_s`` are the persistent cache's
+  own events, over all programs.  Written by the same listener from
+  ``jax.monitoring`` while telemetry is on; a function traced inside
+  another's trace is inside the outer ``trace_s`` too, so sum over the
+  programs that have a ``backend_s``.
+* ``setup.import_s`` / ``setup.import_unix_s`` — what importing the
+  package took (jax included when the package is what loads it) and
+  ``time.time()`` at its start: the anchor of every ``first_start_s``
+  on a harness's own clock.
+* ``ingest.rows`` / ``.columns`` / ``.used_columns`` / ``.sample_rows``
+  / ``.float64_bytes`` / ``.bin_bytes`` — once a dataset binned
+  (io/dataset.py); ``setup.upload_bytes`` — host bytes a booster sent
+  to the device (models/gbdt.py ``reset_training_data``).
 * ``grow_traces`` / ``dp_grow_traces`` — retraces of the serial /
   data-parallel grow program (incremented at Python trace time inside
   the traced body, so each retrace counts exactly once).
@@ -45,14 +71,15 @@ Counters maintained by the library itself:
   ``tools/collective_count.py``).
 
 Env: ``LGBM_TPU_TELEMETRY`` = ``on`` (default) | ``off`` | ``json``
-(``json`` additionally emits one structured JSON line to stderr when an
-entry point calls :func:`emit`).  Read once at import (jit caches do
-not key on env — same convention the env-read-at-trace rule enforces);
-:func:`set_enabled` is the runtime override the overhead A/B uses.
+(``json`` additionally emits one structured JSON line to stderr at
+process exit, whatever embeds the package).  Read once at import (jit
+caches do not key on env — same convention the env-read-at-trace rule
+enforces); :func:`set_enabled` is the runtime override tests use.
 """
 
 from __future__ import annotations
 
+import atexit
 import bisect
 import json
 import re
@@ -61,6 +88,7 @@ import time
 from os import environ as _environ
 from typing import Dict, List, Optional
 
+from .. import _IMPORT_T0
 from ..analysis import lockcheck
 
 # read once at import — see module docstring
@@ -80,17 +108,22 @@ DEFAULT_LATENCY_BOUNDS = (
 
 class SpanStat:
     """Accumulated wall time of one named span (host-wall, see module
-    docstring for the async-dispatch caveat)."""
+    docstring for the async-dispatch caveat).  ``first_start_s`` is the
+    span's first start, in seconds since the package's import began."""
 
-    __slots__ = ("total_s", "count", "min_s", "max_s")
+    __slots__ = ("total_s", "count", "min_s", "max_s", "first_start_s")
 
     def __init__(self) -> None:
         self.total_s = 0.0
         self.count = 0
         self.min_s = float("inf")
         self.max_s = 0.0
+        self.first_start_s = 0.0
 
-    def add(self, dt: float) -> None:
+    def add(self, dt: float, t0: float) -> None:
+        """``t0``: the ``time.perf_counter()`` at which the span began."""
+        if not self.count:
+            self.first_start_s = t0 - _IMPORT_T0
         self.total_s += dt
         self.count += 1
         if dt < self.min_s:
@@ -104,6 +137,7 @@ class SpanStat:
             "count": self.count,
             "min_s": round(self.min_s, 6) if self.count else 0.0,
             "max_s": round(self.max_s, 6),
+            "first_start_s": round(self.first_start_s, 6),
         }
 
 
@@ -231,7 +265,7 @@ class _Span:
         dt = time.perf_counter() - self._t0
         if self._note:
             self._note.__exit__(*exc)
-        self._tel._record_span(self._name, dt)
+        self._tel._record_span(self._name, dt, self._t0)
 
 
 class _NullSpan:
@@ -259,8 +293,7 @@ class Telemetry:
     from many request threads at once, where ``d[k] = d.get(k) + n``
     LOSES increments and a ``/v1/stats`` snapshot could see the rows
     counter ahead of the requests counter it rode in with.  An
-    uncontended ``threading.Lock`` is tens of nanoseconds — re-proven
-    below the noise floor by ``tools/telemetry_overhead.py`` — and in
+    uncontended ``threading.Lock`` is tens of nanoseconds, and in
     exchange :meth:`snapshot` is one consistent cut: everything it
     returns was simultaneously true.  Related adds that must move
     together go through :meth:`count_many` (one acquisition).
@@ -287,12 +320,12 @@ class Telemetry:
             return _NULL_SPAN
         return _Span(self, name)
 
-    def _record_span(self, name: str, dt: float) -> None:
+    def _record_span(self, name: str, dt: float, t0: float) -> None:
         with self._lock:
             st = self._spans.get(name)
             if st is None:
                 st = self._spans.setdefault(name, SpanStat())
-            st.add(dt)
+            st.add(dt, t0)
 
     def count(self, name: str, n: float = 1) -> None:
         """Monotonic counter add (no-op when disabled)."""
@@ -352,8 +385,7 @@ class Telemetry:
         feeding its reservoir AND its histogram — the serving scatter
         path records five series per request (four stages + the
         end-to-end), and five-times-two separate acquisitions were the
-        dominant tracing cost on the 1-core container (measured by
-        ``tools/telemetry_overhead.py --serving``)."""
+        dominant tracing cost on the 1-core container."""
         if not self.enabled:
             return
         with self._lock:
@@ -406,7 +438,9 @@ class Telemetry:
         process-wide listener at snapshot time (importing jax only if
         the process already did — the listener installs on first use by
         whoever counts compiles, and a process that never imported jax
-        has by definition compiled nothing).
+        has by definition compiled nothing).  The ``compile.*`` seconds
+        by program need no bridge: the same listener adds them here as
+        jax reports them.
         """
         with self._lock:
             counters = dict(self._counters)
@@ -437,15 +471,47 @@ class Telemetry:
             self._histograms.clear()
 
     def emit(self, stream=None) -> None:
-        """One JSON line of the full snapshot (``LGBM_TPU_TELEMETRY=json``
-        consumers; also the ``verbose>=2`` structured tail)."""
+        """One JSON line of the full snapshot with the set-up timeline
+        as its ``setup`` key (what ``LGBM_TPU_TELEMETRY=json`` prints to
+        stderr at process exit)."""
         stream = sys.stderr if stream is None else stream
-        print(json.dumps({"lgbm_tpu_telemetry": self.snapshot()},
-                         sort_keys=True),
+        snap = self.snapshot()
+        snap["setup"] = setup_timeline(snap)
+        print(json.dumps({"lgbm_tpu_telemetry": snap}, sort_keys=True),
               file=stream, flush=True)
 
 
+def setup_timeline(snapshot: dict) -> List[dict]:
+    """The ``lgbm.setup.*`` spans of a snapshot in order of first start:
+    ``name``, ``start_s`` (since the package's import began; add the
+    counter ``setup.import_unix_s`` for the wall clock), ``seconds`` and
+    ``count``, ``parent`` (the longest dotted prefix that is itself a
+    recorded span, else None) and ``uncovered_s``: the seconds of the
+    span that none of its children covers, so a leaf's own seconds and
+    a parent's remainder with no name.  Host wall time, like every
+    span: the sum of ``uncovered_s`` is the top-level spans' total."""
+    spans = {k: v for k, v in snapshot["spans"].items()
+             if k.startswith("lgbm.setup.")}
+    rows = {}
+    for name, st in spans.items():
+        parent = name.rpartition(".")[0]
+        while parent and parent not in spans:
+            parent = parent.rpartition(".")[0]
+        rows[name] = {"name": name, "start_s": st["first_start_s"],
+                      "seconds": st["total_s"], "count": st["count"],
+                      "parent": parent or None,
+                      "uncovered_s": st["total_s"]}
+    for row in rows.values():
+        if row["parent"]:
+            rows[row["parent"]]["uncovered_s"] -= row["seconds"]
+    for row in rows.values():  # children round to 1 us each
+        row["uncovered_s"] = max(round(row["uncovered_s"], 6), 0.0)
+    return sorted(rows.values(), key=lambda r: (r["start_s"], r["name"]))
+
+
 _TELEMETRY = Telemetry(enabled=TELEMETRY_MODE != "off")
+if TELEMETRY_MODE == "json":
+    atexit.register(_TELEMETRY.emit)
 
 
 def get_telemetry() -> Telemetry:
@@ -454,7 +520,7 @@ def get_telemetry() -> Telemetry:
 
 
 def set_enabled(flag: bool) -> None:
-    """Runtime enable/disable (the overhead A/B measurement switch)."""
+    """Runtime enable/disable."""
     _TELEMETRY.enabled = bool(flag)
 
 
@@ -493,13 +559,6 @@ def record_sample_lists(samples: Dict[str, List[float]]) -> None:
 
 def host_sync(n: int = 1) -> None:
     _TELEMETRY.host_sync(n)
-
-
-def emit_if_json(stream=None) -> None:
-    """Emit the snapshot line iff LGBM_TPU_TELEMETRY=json (entry points
-    call this unconditionally at the end of a run)."""
-    if TELEMETRY_MODE == "json":
-        _TELEMETRY.emit(stream)
 
 
 # ------------------------------------------------------- collectives (HLO)

@@ -30,8 +30,7 @@ AND fixed-bucket histogram (the ``/metrics`` exposition).
 
 Env: ``LGBM_TPU_TRACING`` = ``on`` (default) | ``off``, read once at
 import (the repo's env-knob convention); :func:`set_enabled` is the
-runtime switch the tracing-overhead A/B (``tools/telemetry_overhead.py
---serving``) flips.  Off means: no ids minted, no stage clocks read —
+runtime switch.  Off means: no ids minted, no stage clocks read —
 the ``PredictionResult`` then carries an empty trace id and no stages.
 
 No jax import; nothing here touches a device array.

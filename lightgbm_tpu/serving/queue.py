@@ -484,8 +484,7 @@ class MicroBatchQueue:
         # per-request samples accumulate host-side and commit in ONE
         # store-lock acquisition after the scatter: the dispatcher's
         # critical path pays a fixed tracing cost per batch, not per
-        # coalesced request (the tools/telemetry_overhead.py --serving
-        # A/B is the proof this stays below run-to-run noise)
+        # coalesced request
         samples: Dict[str, List[float]] = {"serving.request_s": []}
         for r in batch:
             out = vals[lo:lo + r.n]

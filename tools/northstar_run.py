@@ -264,7 +264,7 @@ def main() -> None:
 
     atomic_write_json(artifact, result, sort_keys=False)
     try:  # self-describing evidence next to the artifact (obs)
-        from lightgbm_tpu.obs import RunManifest, manifest_path, telemetry
+        from lightgbm_tpu.obs import RunManifest, manifest_path
 
         manifest = RunManifest.collect(
             "northstar",
@@ -279,7 +279,6 @@ def main() -> None:
             per_tree_reservoir="tree_dispatch_s",
         )
         log(f"manifest: {manifest.write(manifest_path(artifact))}")
-        telemetry.emit_if_json()
     except Exception as e:
         log(f"manifest write failed: {type(e).__name__}: {e}")
     print(json.dumps(result), flush=True)
