@@ -48,7 +48,7 @@ from ..models.tree import Tree
 from ..obs import telemetry
 from ..obs.device_time import phase_scope
 from ..ops.pallas_histogram import (
-    FGROUP, feature_chunk, make_single_hist_fn_raw)
+    FGROUP, feature_chunk, make_single_hist_fn_raw, onehot_planes)
 from ..ops.pallas_search import _pack_meta, _pack_scal
 from ..ops import record
 from ..ops.record import (
@@ -71,6 +71,7 @@ class Chunking(NamedTuple):
     record_words: int  # W: the packed record's height
     vmem_bytes: int  # the wider of the loop's two launches (ops/record.py)
     vmem_max: int  # what the gate admits: 3/4 of the chip's VMEM
+    onehot_planes: int  # H: 128-bin planes of the one-hot body's dot
 
     @property
     def fits(self) -> bool:
@@ -82,7 +83,8 @@ class Chunking(NamedTuple):
                 f"{'s' if self.feature_chunks > 1 else ''} of "
                 f"{self.chunk_features} features, record of "
                 f"{self.record_words} words, split step VMEM "
-                f"{self.vmem_bytes >> 20} of {self.vmem_max >> 20} MiB")
+                f"{self.vmem_bytes >> 20} of {self.vmem_max >> 20} MiB, "
+                f"one-hot of {self.onehot_planes} x 128 bins")
 
 
 def chunking(num_features: int, num_bins: int) -> Chunking:
@@ -115,7 +117,8 @@ def chunking(num_features: int, num_bins: int) -> Chunking:
         hist_block_bytes=Fc * Bp * 16, record_words=W,
         vmem_bytes=max(record.split_step_vmem_bytes(Fp, Bp, W),
                        record.place_vmem_bytes(W)),
-        vmem_max=vmem_bytes() * 3 // 4)
+        vmem_max=vmem_bytes() * 3 // 4,
+        onehot_planes=onehot_planes(Bp))
 
 
 class _State(NamedTuple):

@@ -304,6 +304,7 @@ class GBDT:
                 "grow.chunk_features": plan.chunk_features,
                 "grow.hist_block_bytes": plan.hist_block_bytes,
                 "grow.record_words": plan.record_words,
+                "grow.onehot_planes": plan.onehot_planes,
             })
             return functools.partial(
                 fused.grow_tree,

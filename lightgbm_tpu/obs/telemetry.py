@@ -60,9 +60,11 @@ Counters maintained by the library itself:
   data-parallel grow program (incremented at Python trace time inside
   the traced body, so each retrace counts exactly once).
 * ``grow.feature_chunks`` / ``grow.chunk_features`` /
-  ``grow.hist_block_bytes`` / ``grow.record_words`` — how the fused
-  grower's kernels walk the feature axis (learners/fused.py
-  ``chunking``), added once a booster that takes the fused grower.
+  ``grow.hist_block_bytes`` / ``grow.record_words`` /
+  ``grow.onehot_planes`` — how the fused grower's kernels walk the
+  feature axis and split the bin axis (learners/fused.py ``chunking``;
+  ops/pallas_histogram.py ``bin_sums``), added once a booster that
+  takes the fused grower.
 * ``host_syncs`` — deliberate device->host materialization points the
   library performs (eval fetches, lagged-stop drains, bench syncs).
 * ``collective_ops`` / ``collective_bytes`` — cross-device collectives

@@ -18,13 +18,16 @@ This module reformulates the histogram as dense MXU work:
 Total work is O(n x F x B) MACs per tree LEVEL — independent of the
 number of leaves — plus one stable sort of the leaf ids.
 
-One kernel.  The one-hot is built TRANSPOSED (``[B, C]``): a ``[1, C]``
-feature row stays in the lanes and is compared against a SUBLANE iota,
-and ``stats[16, C]`` and ``onehot[B, C]`` contract the shared lane axis
-on the MXU -> ``[16, B]``, the fused split step's form
-(ops/record.py _hist_tile_body).  Reshaping the row to ``[C, 1]``
-against a lane iota instead is a lane->sublane relayout per feature per
-chunk (PERF.md, PR 29); tests/test_chip_compile.py keeps it out.
+One kernel, and one one-hot body (``bin_sums``), the fused split
+step's too (ops/record.py _hist_tile_body).  The one-hot is built
+TRANSPOSED: a ``[1, C]`` feature row stays in the lanes and its low
+seven bits are compared against a SUBLANE iota of 128 rows; the bin's
+high bits select which plane's copy of the stat rows a lane keeps, and
+``masked stats[16 * H, C]`` and ``onehot[128, C]`` contract the shared
+lane axis on the MXU -> ``[16 * H, 128]``, the ``H = B / 128`` planes
+of ``[16, B]``.  Reshaping the row to ``[C, 1]`` against a lane iota
+instead is a lane->sublane relayout per feature per chunk (PERF.md, PR
+29); tests/test_chip_compile.py keeps it out.
 """
 
 from __future__ import annotations
@@ -44,7 +47,9 @@ DEFAULT_CHUNK = 1024
 FGROUP = 8  # the feature axis is padded to a multiple of this
 # Feature rows per step of the kernel's loop.  Measured alone on a v5e at
 # 7.5M x 100 and 8.92M x 81 (PERF.md, PR 29): 8 rows a step 153.1 / 154.2
-# ms, 32 rows 138.3 / 138.8, every row unrolled 133.6 / 134.6.
+# ms, 32 rows 138.3 / 138.8, every row unrolled 133.6 / 134.6 (under the
+# ``[256, C]`` one-hot of the time: 71.8 ms at 32 rows with bin_sums'
+# 128, PR 37; the other steps not timed again).
 LOOP_ROWS = 4 * FGROUP
 # Rows per grid step of the single-leaf calls (the depth-wise call keeps
 # DEFAULT_CHUNK).  Same measurement, 32 rows a step: 512 138.3 / 138.8
@@ -111,6 +116,64 @@ def merge_stats(o, axis=0):
     return p[0] + p[1] + p[2]
 
 
+# Rows of the one-hot: a bin's low seven bits.  ``_pad_pow`` makes every
+# bin axis a whole number of such planes.
+PLANE_BITS = 7
+PLANE_BINS = 1 << PLANE_BITS
+
+
+def onehot_planes(Bp: int) -> int:
+    """How many planes of PLANE_BINS bins the one-hot body splits a bin
+    axis of ``Bp`` into (1: nothing to split)."""
+    return Bp // PLANE_BINS
+
+
+def bin_sums(stats, Bp: int):
+    """The one-hot body of both histogram kernels (_hist_kernel here,
+    ops/record.py _hist_tile_body): ``stats`` [STAT_ROWS, T] bf16
+    (split_stats) -> the function taking a ``[1, T]`` int32 row of bins
+    (in the lanes) to its ``[4, Bp]`` float32 sums.
+
+    A bin is ``b = PLANE_BINS * h + l``.  The one-hot is ``[128, T]``,
+    the low bits ``l`` against a sublane iota; the high bits ride the
+    dot's other operand, the stat rows where ``h_t == h`` and zero
+    elsewhere stacked a plane after another: ``hist[s, 128 h + l] =
+    sum_t (stats[s, t] * [h_t == h]) * [l_t == l]``.  The products that
+    reach a bin are the rows of that bin, each an exact bf16 piece or an
+    exact zero, so the sums are the ``[Bp, T]`` one-hot's, for half the
+    compares, selects and packs at 256 bins and a quarter at 512 (the
+    VPU building the one-hot bounds both kernels, not the dot: PERF.md,
+    PR 29, 31, 37).  The planes' ``[4, 128]`` results lie side by side
+    on whole vregs: the ``[4, Bp]`` block keeps its layout.  One plane
+    has nothing to split, and the operand is ``stats`` itself (a mask
+    that is all true read 7% more bundles in the schedule)."""
+    T = stats.shape[1]
+    H = onehot_planes(Bp)
+    iota_s = jax.lax.broadcasted_iota(jnp.int32, (PLANE_BINS, T), 0)
+    zero = jnp.zeros_like(stats) if H > 1 else None
+    lane_dot = functools.partial(
+        jax.lax.dot_general,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+    def sums_of(row):
+        if H == 1:
+            return merge_stats(
+                lane_dot(stats, (row == iota_s).astype(jnp.bfloat16)))
+        high = row >> PLANE_BITS
+        planes = lane_dot(  # [STAT_ROWS * H, 128]
+            jax.lax.concatenate([jax.lax.select(
+                jax.lax.broadcast_in_dim(high == h, stats.shape, (0, 1)),
+                stats, zero) for h in range(H)], 0),
+            ((row & (PLANE_BINS - 1)) == iota_s).astype(jnp.bfloat16))
+        # side by side on the lanes, [STAT_ROWS, Bp], then the pieces
+        return merge_stats(jax.lax.concatenate(
+            [jax.lax.slice_in_dim(planes, STAT_ROWS * h, STAT_ROWS * (h + 1))
+             for h in range(H)], 1))
+
+    return sums_of
+
+
 # Rows between two folds of the kernel's small accumulator into the
 # output block (see _hist_kernel), whatever the chunk.
 FOLD_ROWS = 8192
@@ -152,8 +215,7 @@ def _hist_kernel(leaf_of_chunk, bins_ref, stats_ref, out_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         lo_ref[...] = jnp.zeros_like(lo_ref)
 
-    stats = stats_ref[...]  # [STAT_ROWS, C]
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (num_b, chunk), 0)
+    sums_of = bin_sums(stats_ref[...], num_b)  # [1, C] -> [4, B]
 
     # int8 VMEM rows are 4-packed per sublane, so a dynamically-indexed
     # SINGLE-row vector.load cannot be proven aligned by Mosaic ("index
@@ -166,13 +228,8 @@ def _hist_kernel(leaf_of_chunk, bins_ref, stats_ref, out_ref, acc_ref,
     def rows(f0, count):
         blk = bins_ref[pl.ds(f0, count), :].astype(jnp.int32)
         for i in range(count):
-            row = blk[i: i + 1, :]  # [1, C] — stays in the lanes
-            onehot = (row == iota_s).astype(jnp.bfloat16)  # [B, C]
-            contrib = merge_stats(jax.lax.dot_general(
-                stats, onehot, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ))  # [4, B]
-            acc_ref[f0 + i] = acc_ref[f0 + i] + contrib
+            # a [1, C] row — stays in the lanes
+            acc_ref[f0 + i] = acc_ref[f0 + i] + sums_of(blk[i: i + 1, :])
 
     steps, rest = divmod(num_f, LOOP_ROWS)
 
@@ -220,8 +277,9 @@ def _hist_pallas_call(
     bins block ``Fc * C`` bytes and the stats block ``STAT_ROWS * C *
     2``, both double-buffered; the output block and the two scratch
     blocks, ``Fc * 4 * B * 4`` each (the output's twice); and the body's
-    ``[B, C]`` one-hot.  About 5.2 MiB at Fc = 256, B = 256, C = 2048,
-    whatever ``Fp`` is: no width is refused for it."""
+    ``[128, C]`` one-hot and ``[16 * B / 128, C]`` masked stat rows.
+    About 5 MiB at Fc = 256, B = 256, C = 2048, whatever ``Fp`` is: no
+    width is refused for it."""
     Fc, NC = feature_chunk(Fp, B)
     kernel = functools.partial(_hist_kernel, num_f=Fc, num_b=B, chunk=C)
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -96,7 +96,9 @@ VMEM_DEFAULT_BYTES = 16 << 20
 # alone on the chip, ms a window split in half at 7.5M x 100 / 400,000 x
 # 2,000 (PERF.md, PR 34): 8 words 99.33 / 85.21, 16 words 96.73 / 83.84,
 # 32 words 96.76 / 101.74 (every feature unrolled, as before the loop,
-# 97.45 at the first; Mosaic takes no partial ``unroll=``).
+# 97.45 at the first; Mosaic takes no partial ``unroll=``).  Read under
+# the ``[256, TILE]`` one-hot of the time; PR 37's body runs nearer its
+# schedule than that one did and the step was not timed again.
 LOOP_WORDS = 16
 
 
@@ -430,7 +432,8 @@ def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
     [1, T] flags the lanes that hold a row: all of them on a full
     staged tile, the first ``fill`` at the drain.  Stats stack on
     sublanes; the one-hot is born transposed against a sublane iota and
-    contracts the shared lane axis on the MXU — no relayouts.
+    contracts the shared lane axis on the MXU — no relayouts
+    (pallas_histogram.bin_sums: the root kernel's body).
 
     Feature ``fi``'s [4, Bp] is added into ``hacc_ref[fi]``.  The
     record's whole groups of LOOP_WORDS words (aligned sublane tiles of
@@ -441,7 +444,7 @@ def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
     O(LOOP_WORDS * k) whatever the width, and every width runs the one
     form.
     """
-    from .pallas_histogram import merge_stats, split_stats
+    from .pallas_histogram import bin_sums, split_stats
 
     T = TILE
     shift = 32 // k
@@ -458,14 +461,7 @@ def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
     stats = split_stats(jnp.concatenate(
         [grow * mw, hrow * mw, mw, jnp.zeros_like(mw)], axis=0))
 
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (Bp, T), 0)
-
-    def sums_of(row):
-        onehot = (row == iota_s).astype(jnp.bfloat16)
-        return merge_stats(jax.lax.dot_general(
-            stats, onehot, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ))
+    sums_of = bin_sums(stats, Bp)  # [1, T] bins -> [4, Bp]
 
     def add(fi, row):
         hacc_ref[fi] = hacc_ref[fi] + sums_of(row)

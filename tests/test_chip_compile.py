@@ -9,6 +9,7 @@ described v5e topology, and let Mosaic accept or refuse every kernel.  A refusal
 This says nothing about what the kernels compute (chip_smoke.py does).
 """
 
+import functools
 import os
 import re
 import sys
@@ -168,12 +169,15 @@ def _eqns(jaxpr):
 def test_the_one_histogram_kernel_keeps_the_bins_row_in_the_lanes(grower):
     """The grower holds ONE standalone histogram call, and its body
     builds the one-hot transposed: every dot contracts the lane axis of
-    ``stats[16, C]`` and ``onehot[B, C]``, and nothing in it has the
-    ``[C, 1]`` shape of a bins row turned onto the sublanes: that
-    relayout, once a feature a chunk, made the root histogram three
-    times the price (453 -> 138 ms alone at 7.5M x 100: PERF.md, PR 29).
-    Read from the traced program's jaxpr: the Mosaic body in the lowered
-    text is serialized bytecode."""
+    the stat rows and the one-hot, and nothing in it has the ``[C, 1]``
+    shape of a bins row turned onto the sublanes: that relayout, once a
+    feature a chunk, made the root histogram three times the price (453
+    -> 138 ms alone at 7.5M x 100: PERF.md, PR 29).  Since PR 37 the
+    one-hot is 128 rows, a bin's low seven bits, and the two planes of
+    256 bins ride the other operand (ops/pallas_histogram.py bin_sums:
+    ``[16 * H, C]`` masked stat rows; the VPU building a ``[256, C]``
+    one-hot was the body's bound).  Read from the traced program's
+    jaxpr: the Mosaic body in the lowered text is serialized bytecode."""
     hist = [e for e in _eqns(grower[2]) if e.primitive.name == "pallas_call"
             and "lgbm.histogram.cap" in str(e.source_info.name_stack)]
     assert len(hist) == 1, [str(e.source_info.name_stack) for e in hist]
@@ -185,7 +189,7 @@ def test_the_one_histogram_kernel_keeps_the_bins_row_in_the_lanes(grower):
     dots = [e for e in body if e.primitive.name == "dot_general"]
     assert dots and all(
         e.params["dimension_numbers"] == (((1,), (1,)), ((), ()))
-        and [v.aval.shape for v in e.invars] == [(16, C), (256, C)]
+        and [v.aval.shape for v in e.invars] == [(32, C), (128, C)]
         for e in dots), [e.params["dimension_numbers"] for e in dots]
 
 
@@ -207,7 +211,8 @@ def test_the_split_step_sums_full_tiles_of_staged_rows(grower):
     kernel = step[0].params["jaxpr"]
     body = [(e, conds) for e, conds in _eqns_under(kernel)
             if e.primitive.name == "dot_general"
-            and [v.aval.shape for v in e.invars] == [(16, T), (Bp, T)]]
+            and [v.aval.shape for v in e.invars] == [
+                (16 * (Bp // 128), T), (128, T)]]
     # one copy: a dot a feature of a word group (the loop over whole
     # groups of LOOP_WORDS words; 28 features are under one) and of the
     # words after the last whole group, one more for the padded features
@@ -216,6 +221,10 @@ def test_the_split_step_sums_full_tiles_of_staged_rows(grower):
         R.round_up(F, 8) > F), len(body)
     assert all(e.params["dimension_numbers"] == (((1,), (1,)), ((), ()))
                for e, _ in body)
+    # and no dot of the kernel is the old body's, a [Bp, T] one-hot
+    assert not [e for e, _ in _eqns_under(kernel)
+                if e.primitive.name == "dot_general"
+                and e.invars[1].aval.shape == (Bp, T)]
     assert {len(conds) for _, conds in body} == {1}
     assert len({id(conds[0]) for _, conds in body}) == 1
     cond = body[0][1][0]
@@ -237,6 +246,64 @@ def test_the_split_step_sums_full_tiles_of_staged_rows(grower):
     tile_work = {id(c) for e, conds in _eqns_under(kernel)
                  if e.primitive.name == "roll" for c in conds}
     assert tile_work and id(cond) not in tile_work
+
+
+# 127 bins: one plane, the unsplit body; 511: uint16 bins, four planes
+@pytest.mark.parametrize("bins", [127, 128, 255, 511])
+def test_both_histogram_bodies_split_the_bin_into_planes(bins):
+    """Both kernels' one-hot body at every ``Bp`` (traced alone, the
+    chip's form; nothing compiles): the one-hot operand of every
+    histogram dot is ``[128, lanes]``, the other ``[16 * H, lanes]`` with
+    ``H = Bp // 128``, both contracting their lanes; at one plane
+    (``max_bin`` <= 128) that operand is the sixteen stat rows
+    themselves, the unsplit form, chosen from the static ``Bp`` alone."""
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    from lightgbm_tpu.ops import record as R
+
+    F, n, k = 12, 4096, 4 if bins <= 256 else 2
+    Fp, Bp, T = R.round_up(F, 8), R.round_up(bins, 128), R.TILE
+    H = PH.onehot_planes(Bp)
+    assert H == {127: 1, 128: 1, 255: 2, 511: 4}[bins]
+    f32 = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32)  # noqa: E731
+    i32 = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.int32)  # noqa: E731
+
+    def step(hists, rec, scal_f, meta, i):
+        return R.split_step_counted(
+            hists, rec, i, i, i > 0, i, i, i > 3, i, i + 1, scal_f, meta,
+            F=F, cap=n, k=k, interpret=False, live_tiles=i)
+
+    traced = {
+        PH.SINGLE_LEAF_CHUNK: jax.make_jaxpr(functools.partial(
+            PH.histogram_single_leaf_raw, num_bins=bins, interpret=False))(
+            jax.ShapeDtypeStruct(
+                (F, n), jnp.uint8 if k == 4 else jnp.uint16),
+            f32(n), f32(n), f32(n)),
+        T: jax.make_jaxpr(step)(
+            f32(8, Fp, 4, Bp), i32(R.rec_height(F, k), 2 * n), f32(16),
+            i32(Fp, 4), i32()),
+    }
+    for lanes, jaxpr in traced.items():
+        calls = [e for e in _eqns(jaxpr.jaxpr)
+                 if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        dots = [e for e in _eqns(calls[0].params["jaxpr"])
+                if e.primitive.name == "dot_general"
+                and e.invars[1].aval.dtype == jnp.bfloat16]
+        # a dot a feature: the root kernel walks the padded ones too,
+        # the step sums them once
+        assert len(dots) == (F + (Fp > F) if lanes == T else Fp), len(dots)
+        assert all(
+            e.params["dimension_numbers"] == (((1,), (1,)), ((), ()))
+            and [v.aval.shape for v in e.invars] == [
+                (16 * H, lanes), (128, lanes)]
+            and e.outvars[0].aval.dtype == jnp.float32 for e in dots), [
+            [v.aval.shape for v in e.invars] for e in dots]
+        made = {(v.aval.shape, str(v.aval.dtype))
+                for e in _eqns(calls[0].params["jaxpr"]) for v in e.outvars}
+        assert (lanes, 1) not in {shape for shape, _ in made}
+        # the one-hot, and none as tall as the bin axis beside it
+        assert ((128, lanes), "bfloat16") in made
+        assert H == 1 or ((Bp, lanes), "bfloat16") not in made
 
 
 def _reachable(prog, comps):
@@ -471,6 +538,27 @@ def test_the_compaction_is_scheduled_well_under_the_parents_bundles(
     found = kernel_bundles.read(llo_dir)
     calls = [n for name, n in found.items() if name.startswith("compact_tiles")]
     assert len(calls) == 1 and 100 < calls[0] < 1000, found
+
+
+# (tools/kernel_bundles.py's kernel, columns, the instruction's name, the
+# parent's bundles, the bundles allowed: PERF.md, PR 37)
+@pytest.mark.parametrize("which,F,name,parents,limit", [
+    ("root", 32, "lgbm.histogram.cap", 12_870, 8_500),
+    ("split_step", 100, "lgbm.split_step.dyn", 21_547, 19_000)])
+def test_the_onehot_body_is_scheduled_under_the_parents_bundles(
+        topo, llo_dir, which, F, name, parents, limit):
+    """libtpu's final schedule of the two kernels that hold the one-hot
+    body: the root kernel's step of 32 columns x 2,048 rows read 12,870
+    bundles with a ``[256, lanes]`` one-hot and reads 7,618 with 128 rows
+    and the planes on the stat rows, the split step at 100 columns 21,547
+    and 17,719 (on the chip the body went from 0.169 to 0.086 ns a cell:
+    PERF.md, PR 37).  A body that builds the tall one-hot again, or
+    casts the stat rows a feature, shows here at no chip time."""
+    kernel_bundles.read(llo_dir)  # what the compiles before this wrote
+    kernel_bundles.KERNELS[which](_shape(topo), F).compile()
+    found = kernel_bundles.read(llo_dir)
+    calls = [n for key, n in found.items() if key.startswith(name)]
+    assert len(calls) == 1 and parents // 3 < calls[0] < limit, found
 
 
 # istella-s-220.train's buckets, (queries, Q): one launch each, the last

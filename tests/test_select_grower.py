@@ -20,8 +20,7 @@ from lightgbm_tpu.models import gbdt as gbdt_mod
 from lightgbm_tpu.objectives import create_objective
 
 
-def _booster(platform, vmem=None, monkeypatch=None, F=5, **params):
-    n = 600
+def _booster(platform, vmem=None, monkeypatch=None, F=5, n=600, **params):
     rng = np.random.RandomState(0)
     X = rng.randn(n, F).astype(np.float32)
     y = (X[:, 0] + X[:, 1] > 0).astype(np.float32)
@@ -102,12 +101,14 @@ def test_wide_tables_get_the_fused_grower_and_the_bound_is_named(
     said = (f"{chunks} chunks of 256 features, record of {words} words, "
             f"split step VMEM {mib} of ")
     counters = {"grow.feature_chunks": chunks, "grow.chunk_features": 256,
-                "grow.hist_block_bytes": 1 << 20, "grow.record_words": words}
+                "grow.hist_block_bytes": 1 << 20, "grow.record_words": words,
+                "grow.onehot_planes": 2}
     tel = telemetry.get_telemetry()
     before = {name: tel.counter(name) for name in counters}
     g = _booster("tpu", F=F)
     assert g._grower == ("fused", "") and g._grow.func is fused.grow_tree
-    assert any(said + "96 MiB" in m and "grower=fused" in m
+    assert any(said + "96 MiB, one-hot of 2 x 128 bins" in m
+               and "grower=fused" in m
                for m in gbdt_mod._LOGGED_PATHS), gbdt_mod._LOGGED_PATHS
     assert {name: tel.counter(name) - before[name]
             for name in counters} == counters  # once a booster
@@ -115,3 +116,22 @@ def test_wide_tables_get_the_fused_grower_and_the_bound_is_named(
     g = _booster("tpu", small, monkeypatch, F=F)
     assert g._grower[0] == "canonical", g._grower
     assert said in g._grower[1] and "past the chip's VMEM" in g._grower[1]
+
+
+# (max_bin, rows, the planes the kernels' one-hot body splits Bp into)
+@pytest.mark.parametrize("max_bin,n,planes", [
+    (63, 600, 1), (127, 600, 1), (255, 3000, 2), (511, 6000, 4)])
+def test_the_booster_says_the_onehot_planes(max_bin, n, planes):
+    """``grow.onehot_planes`` is ``Bp // 128`` of the table's widest
+    column (ops/pallas_histogram.py bin_sums: one plane is the unsplit
+    body), in the fused grower's counters and in its log line."""
+    from lightgbm_tpu.obs import telemetry
+
+    tel = telemetry.get_telemetry()
+    before = tel.counter("grow.onehot_planes")
+    g = _booster("tpu", F=3, n=n, max_bin=max_bin)
+    assert g._grower == ("fused", "")
+    assert -(-g._num_bins // 128) == planes, g._num_bins
+    assert tel.counter("grow.onehot_planes") - before == planes
+    assert any(f"one-hot of {planes} x 128 bins" in m and "grower=fused" in m
+               for m in gbdt_mod._LOGGED_PATHS), gbdt_mod._LOGGED_PATHS

@@ -2,12 +2,19 @@
 
 libtpu writes its final schedule when it compiles for a described v5e
 with the LLO dump on; "total scheduled bundles" of a kernel is what its
-straight-line code costs in cycles if nothing stalls (940 MHz on a v5e).
+straight-line code costs in cycles if nothing stalls.  A v5e's clock is
+1.5 GHz (benchmarks/peaks.py: 197 TFLOP/s is four 128 x 128 MXUs at
+that clock); kernels alone on the chip retire 0.94 scheduled bundles a
+ns where the code spills (the compaction at 512 words, PR 35), 1.09-1.11
+in the one-hot body on 256 rows and 1.25-1.36 in the one on 128 (PR 37:
+PERF.md section 5), so a schedule is a floor the chip stays 10-60%
+above, and a RATIO of two schedules of one body is the better forecast.
 It cannot see stalls, the rotate unit's latency, DMA waits or a loop's
-trip count, and it counts every ``pl.when`` branch (PERF.md section 5).
+trip count, and it counts every ``pl.when`` branch.
 
     python tools/kernel_bundles.py compact 32 64 512    # record words
     python tools/kernel_bundles.py split_step 100 2000  # columns
+    python tools/kernel_bundles.py root 32 100          # columns
 """
 
 import glob
@@ -71,6 +78,21 @@ def split_step(shape, F: int):
         shape((Fp, 4), "int32"), shape((), "int32"))
 
 
+def root(shape, F: int, bins: int = 255):
+    """The root histogram's kernel at ``F`` columns of ``bins`` bins
+    (uint16 bins past 256): at most one feature chunk is in the code,
+    one step of LOOP_ROWS features and the features past the last whole
+    step, each over a 2,048-row chunk."""
+    from lightgbm_tpu.ops import pallas_histogram as PH
+    n = 20_480
+    return PH.histogram_single_leaf_raw.lower(
+        shape((F, n), "uint8" if bins <= 256 else "uint16"),
+        shape((n,), "float32"), shape((n,), "float32"),
+        shape((n,), "float32"), num_bins=bins, interpret=False)
+
+
+KERNELS = {"compact": compact, "split_step": split_step, "root": root}
+
 if __name__ == "__main__":
     os.environ["JAX_PLATFORMS"] = "cpu"
     dump = enable(tempfile.mkdtemp(prefix="llo"))
@@ -79,7 +101,7 @@ if __name__ == "__main__":
     chip = jax.sharding.SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
     for size in sys.argv[2:]:
-        {"compact": compact, "split_step": split_step}[sys.argv[1]](
+        KERNELS[sys.argv[1]](
             lambda dims, dtype: jax.ShapeDtypeStruct(
                 dims, dtype, sharding=chip), int(size)).compile()
         print(sys.argv[1], size, read(dump), flush=True)
