@@ -16,7 +16,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 import numpy as np
 
 from .config import Config, key_alias_transform
-from .io.dataset import BinnedDataset
+from .io.dataset import (
+    BinnedDataset, _merge_api_categoricals, _resolve_column_list)
 from .io.metadata import Metadata
 from .metrics import Metric, create_metrics
 from .models.dart import create_boosting
@@ -146,6 +147,21 @@ class Dataset:
             return inner
         if meta.label is None:
             raise LightGBMError("label should not be None for training data")
+        # ``categorical_column`` in params declares columns of in-memory
+        # data too, as the reference takes it: a matrix has no label
+        # column, so its indices are the matrix's own
+        names = (list(self.feature_name)
+                 if isinstance(self.feature_name, (list, tuple)) else None)
+        try:
+            declared = _resolve_column_list(cfg.categorical_column, names)
+        except ValueError as e:
+            raise LightGBMError(
+                f"categorical_column={cfg.categorical_column!r} needs "
+                f"feature_name to hold its names: {e}") from None
+        cats = _merge_api_categoricals(
+            [], declared + list(cats),
+            self.data.shape[1] if hasattr(self.data, "shape")
+            else len(self.data[0]))
         if hasattr(self.data, "tocsr"):  # scipy sparse: O(nnz) ingest,
             # never densified to f64 (reference SparseBin path,
             # sparse_bin.hpp; round 1 called .toarray() here)

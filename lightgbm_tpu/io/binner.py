@@ -8,10 +8,24 @@ vectorized numpy:
   midpoints (bin.cpp:90-99); otherwise greedy equal-frequency binning where
   values whose sample count exceeds the running mean bin size are forced
   into their own bin (bin.cpp:100-153).
-* categorical features: categories sorted by descending count, top
-  ``max_bin`` kept, the rest mapped to the most frequent bin's... dropped
-  to bin of their own absence (reference maps unseen to bin 0 at data-push
-  time; bin.cpp:155-186).
+* categorical features: categories sorted by descending sample count
+  (bin.cpp:155-186), the most frequent KEPT, one bin each, and every
+  other value -- a category past the kept ones, one the sample never
+  met, one a later matrix brings -- in the column's LAST bin, the
+  OTHERS' bin.  A column takes at most ``max_bin`` bins with that one
+  counted, so ``max_bin - 1`` categories are kept where there are more.
+  The split search never offers the others' bin as a left side
+  (ops/split.py: a categorical column's candidates are its bins but the
+  last, as a numerical one's thresholds are), so its rows go right at
+  every split of the column in training, and at prediction ``x == c``
+  is false for them against every kept ``c``: training and prediction
+  route every raw value alike, and the model's text needs no new field.
+  A STATED DEPARTURE from the reference, which keeps ``max_bin``
+  categories and "maps rest to last bin" (SURVEY.md 2.1, BinMapper): the
+  last KEPT category's, at data-push time bin 0, the most frequent
+  one's, so that a tree trained there sends the rest left with a
+  category they are not, and its own prediction sends them right.  Rows
+  of a kept category are binned and routed as they were.
 
 Zero values that were elided from the sample (sparse collection) are
 re-inserted with their count, as the reference does (bin.cpp:48-85).
@@ -23,6 +37,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..obs import telemetry
+
 NUMERICAL = 0
 CATEGORICAL = 1
 
@@ -33,7 +49,8 @@ class BinMapper:
     Attributes
     ----------
     bin_type: NUMERICAL or CATEGORICAL
-    num_bin: number of bins actually used (<= max_bin)
+    num_bin: number of bins actually used (<= max_bin); a categorical
+        column's last bin is the others' (module docstring)
     bin_upper_bound: float64[num_bin] upper bound per bin (numerical);
         last entry is +inf (bin.cpp:99,152)
     bin_to_category / category_to_bin: categorical mappings (bin.cpp:173-180)
@@ -83,8 +100,6 @@ class BinMapper:
             # Treat inf like NaN (excluded from bin finding; at encode
             # time it lands in the last/first bin via the clip), counted
             # so a fleet dashboard sees the degradation
-            from ..obs import telemetry
-
             telemetry.count("nonfinite_feature_values", n_inf)
         vals = vals[np.isfinite(vals)]
         if total_sample_cnt is None:
@@ -138,14 +153,26 @@ class BinMapper:
             # sort by count descending, stable on category id for determinism
             order = np.lexsort((idistinct, -icounts))
             idistinct, icounts = idistinct[order], icounts[order]
-            m.num_bin = min(max_bin, len(idistinct))
-            kept = idistinct[: m.num_bin]
+            kept = idistinct[: max(min(max_bin - 1, len(idistinct)), 1)]
             m.bin_to_category = [int(c) for c in kept]
             m.category_to_bin = {int(c): i for i, c in enumerate(kept)}
-            used_cnt = int(icounts[: m.num_bin].sum())
-            cnt_in_bin0 = sample_size - used_cnt + int(icounts[0])
+            m.num_bin = len(kept) + 1  # the others' bin is the last
+            # once a column, so a dataset's are the sums over its columns
+            telemetry.count_many({
+                "ingest.categorical_columns": 1,
+                "ingest.categories_kept": len(kept),
+                "ingest.categories_overflowed": len(idistinct) - len(kept),
+                "ingest.overflow_sample_rows": int(
+                    icounts[len(kept):].sum()),
+            })
+            cnt_in_bin0 = int(icounts[0])
 
-        m.is_trivial = m.num_bin <= 1
+        if bin_type == CATEGORICAL:
+            # one kept category is a split only against rows the sample
+            # saw in the others' bin (max_bin 2 over many categories)
+            m.is_trivial = len(kept) <= 1 and len(idistinct) == len(kept)
+        else:
+            m.is_trivial = m.num_bin <= 1
         m.sparse_rate = cnt_in_bin0 / max(sample_size, 1)
         return m
 
@@ -161,17 +188,19 @@ class BinMapper:
             bins = np.searchsorted(self.bin_upper_bound, values, side="left")
             return np.clip(bins, 0, self.num_bin - 1).astype(np.int32)
         ivals = np.nan_to_num(values, nan=0.0).astype(np.int64)
-        out = np.zeros(len(ivals), dtype=np.int32)
-        # unseen categories -> bin 0 (reference SparseCategoricalBin pushes
-        # only known categories; dense unknown falls to default bin 0)
-        if self.category_to_bin:
-            cats = np.array(self.bin_to_category, dtype=np.int64)
-            sorter = np.argsort(cats)
-            pos = np.searchsorted(cats[sorter], ivals)
-            pos = np.clip(pos, 0, len(cats) - 1)
-            hit = cats[sorter][pos] == ivals
-            out = np.where(hit, sorter[pos], 0).astype(np.int32)
-        return out
+        # a value that is no kept category goes to the others' bin, the
+        # last: the search never offers it as a left side, so such a row
+        # goes right in training as ``x == c`` sends it at prediction
+        # (module docstring; the reference's bin 0 sent it left with the
+        # most frequent category)
+        others = self.num_bin - 1
+        cats = np.array(self.bin_to_category, dtype=np.int64)
+        if not len(cats):
+            return np.full(len(ivals), others, np.int32)
+        sorter = np.argsort(cats).astype(np.int32)
+        cats = cats[sorter]
+        pos = np.minimum(np.searchsorted(cats, ivals), len(cats) - 1)
+        return np.where(cats[pos] == ivals, sorter[pos], np.int32(others))
 
     def bin_to_value(self, bins: np.ndarray) -> np.ndarray:
         """Representative real value per bin, for model text output the
@@ -180,7 +209,7 @@ class BinMapper:
         if self.bin_type == NUMERICAL:
             return self.bin_upper_bound[np.clip(bins, 0, self.num_bin - 1)]
         arr = np.array(self.bin_to_category, dtype=np.float64)
-        return arr[np.clip(bins, 0, self.num_bin - 1)]
+        return arr[np.clip(bins, 0, len(arr) - 1)]
 
     @property
     def default_bin(self) -> int:
@@ -188,7 +217,7 @@ class BinMapper:
         sparse/elided entries."""
         if self.bin_type == NUMERICAL:
             return int(self.value_to_bin(np.array([0.0]))[0])
-        return int(self.category_to_bin.get(0, 0))
+        return int(self.category_to_bin.get(0, self.num_bin - 1))
 
     # --------------------------------------------------------- serialization
     def to_dict(self) -> dict:
@@ -209,6 +238,11 @@ class BinMapper:
         m.bin_upper_bound = np.asarray(d["bin_upper_bound"], dtype=np.float64)
         m.bin_to_category = [int(c) for c in d.get("bin_to_category", [])]
         m.category_to_bin = {c: i for i, c in enumerate(m.bin_to_category)}
+        if m.bin_type == CATEGORICAL and m.num_bin == len(m.bin_to_category):
+            # saved before the others' bin: give the column one, or the
+            # search would never offer its last kept category and
+            # ``value_to_bin`` would send every unknown value to that bin
+            m.num_bin += 1
         m.is_trivial = bool(d["is_trivial"])
         m.sparse_rate = float(d.get("sparse_rate", 0.0))
         return m

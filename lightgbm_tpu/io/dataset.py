@@ -108,8 +108,12 @@ def _encode_bins(
         else:  # pure-python fallback
             rest = list(zip(num_orig, num_inner)) + rest
 
-    for orig, inner in rest:
-        X_bin[:, inner] = mappers[inner].value_to_bin(X[:, orig])
+    if not rest:
+        return
+    # categorical columns (and every column without the native encoder)
+    with telemetry.span("lgbm.setup.ingest.encode.python"):
+        for orig, inner in rest:
+            X_bin[:, inner] = mappers[inner].value_to_bin(X[:, orig])
 
 
 def _sample_row_indices(n: int, config: Config) -> np.ndarray:
@@ -186,6 +190,7 @@ def _resolve_column_list(spec: str, names: Optional[List[str]],
                          label_col: Optional[int] = None) -> List[int]:
     """List form of :func:`_resolve_column` (same feature-space
     semantics for numeric entries when ``label_col`` is given)."""
+    spec = (spec or "").strip("[]() ")  # a list in ``params`` comes as its str
     if not spec:
         return []
     if spec.startswith("name:"):

@@ -305,6 +305,8 @@ class GBDT:
                 "grow.hist_block_bytes": plan.hist_block_bytes,
                 "grow.record_words": plan.record_words,
                 "grow.onehot_planes": plan.onehot_planes,
+                "grow.categorical_features": int(
+                    self.train_set.is_categorical.sum()),
             })
             return functools.partial(
                 fused.grow_tree,
@@ -509,7 +511,9 @@ class GBDT:
                f"tree_learner={self.config.tree_learner} "
                f"growth={self.config.tree_growth} "
                f"grower={which}{f' ({why})' if why else ''}: "
-               f"histogram={hist}, search={search}, partition={part}"
+               f"histogram={hist}, search={search}, partition={part}, "
+               f"{int(self.train_set.is_categorical.sum())} of "
+               f"{self.train_set.num_features} features categorical"
                + (", pallas kernels interpreted" if not on_tpu()
                   and ("pallas" in hist or "record" in part) else ""))
         if msg not in _LOGGED_PATHS:

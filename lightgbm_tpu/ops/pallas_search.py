@@ -120,9 +120,9 @@ def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
     nb = meta_ref[:, 1:2]  # [F, 1]
     iscat = meta_ref[:, 2:3] > 0  # [F, 1]
     bins = jax.lax.broadcasted_iota(jnp.int32, (F, B), 1)
-    # pure logical ops, not where-on-bools: Mosaic cannot truncate the
-    # i8 select result back to i1
-    in_range = ((iscat & (bins < nb)) | (~iscat & (bins < nb - 1))) & fmask
+    # a column's last bin is never a candidate, whatever its kind
+    # (ops/split.py: a categorical column's is the others' bin)
+    in_range = (bins < nb - 1) & fmask
     # the table's feature index, not the chunk's
     fi = jax.lax.broadcasted_iota(jnp.int32, (F, 1), 0) + f0
     lane16 = jax.lax.broadcasted_iota(jnp.int32, (1, 16), 1)

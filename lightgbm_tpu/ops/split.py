@@ -12,7 +12,10 @@ masked reduction over the whole [F, B] candidate grid:
   cumulative sums, where the reference takes ``leaf totals - right`` in
   float64: in float32 that subtraction hands a node's absolute rounding
   to a small left child.  Counts are exact and stay ``total - right``.
-* categorical: one-vs-rest — "left" is the single bin == threshold.
+* categorical: one-vs-rest — "left" is the single bin == threshold,
+  any bin but the column's last, the others' bin (io/binner.py: values
+  that are no kept category, which go right in training as ``x == c``
+  sends them at prediction).
 * gain/leaf-output formulas with L1/L2 regularization mirror
   GetLeafSplitGain / CalculateSplittedLeafOutput
   (feature_histogram.hpp:290-313).
@@ -127,9 +130,10 @@ def find_best_split(
     right_h, right_c = right[..., 1], right[..., 2]
 
     # ---- validity (feature_histogram.hpp:133-142, 199-208)
-    is_cat = is_categorical[:, None]
+    # a column's last bin is never a candidate: no rows lie right of a
+    # numerical column's, and a categorical column's is the others' bin
     nb = num_bins_per_feature[:, None]
-    in_range = jnp.where(is_cat, bins[None, :] < nb, bins[None, :] < nb - 1)
+    in_range = bins[None, :] < nb - 1
     valid = (
         in_range
         & feature_mask[:, None]

@@ -69,11 +69,62 @@ def test_categorical_binning():
     m = BinMapper.find(vals, max_bin=3, bin_type=CATEGORICAL)
     assert m.bin_type == CATEGORICAL
     assert m.num_bin == 3
-    # sorted by count desc: 3, 7, 1 kept; 9 dropped -> bin 0
-    assert m.bin_to_category == [3, 7, 1]
+    # sorted by count desc: 3, 7 kept; 1, 9 and values never met share
+    # the column's last bin, the others' (io/binner.py), within max_bin
+    assert m.bin_to_category == [3, 7]
     np.testing.assert_array_equal(
-        m.value_to_bin(np.array([3.0, 7.0, 1.0, 9.0])), [0, 1, 2, 0]
+        m.value_to_bin(np.array([3.0, 7.0, 1.0, 9.0, 42.0, -5.0])),
+        [0, 1, 2, 2, 2, 2]
     )
+    # fewer categories than bins: all kept, the others' bin still last
+    m = BinMapper.find(vals, max_bin=255, bin_type=CATEGORICAL)
+    assert m.bin_to_category == [3, 7, 1, 9] and m.num_bin == 5
+    np.testing.assert_array_equal(
+        m.value_to_bin(np.array([9.0, 2.0, 1e7])), [3, 4, 4])
+
+
+def test_categorical_ids_may_be_wide_and_sparse():
+    """One encoder whatever the ids' span: hashes or keys of a large
+    table are looked up as label encodings are."""
+    ids = np.array([7, 1 << 40, -(1 << 33), 12345678901], np.float64)
+    vals = np.repeat(ids, [40, 30, 20, 10])
+    m = BinMapper.find(vals, max_bin=255, bin_type=CATEGORICAL)
+    assert m.bin_to_category == [int(v) for v in ids] and m.num_bin == 5
+    np.testing.assert_array_equal(
+        m.value_to_bin(np.array([ids[2], ids[0], 8.0, 1e15, ids[1],
+                                 np.nan])),
+        [2, 0, 4, 4, 1, 4])
+
+
+def test_one_kept_category_splits_against_overflow_rows_only():
+    """``max_bin`` 2 over several categories keeps one, and that one
+    against the others' bin is a split; a column of a single category
+    has nothing to split and is dropped as before."""
+    vals = np.array([3.0] * 50 + [7.0] * 30 + [1.0] * 20)
+    m = BinMapper.find(vals, max_bin=2, bin_type=CATEGORICAL)
+    assert m.bin_to_category == [3] and m.num_bin == 2
+    assert not m.is_trivial
+    np.testing.assert_array_equal(
+        m.value_to_bin(np.array([3.0, 7.0, 1.0])), [0, 1, 1])
+    assert BinMapper.find(np.full(100, 3.0), max_bin=2,
+                          bin_type=CATEGORICAL).is_trivial
+    assert BinMapper.find(np.full(100, 3.0), max_bin=255,
+                          bin_type=CATEGORICAL).is_trivial
+
+
+def test_a_mapper_saved_before_the_others_bin_gains_one():
+    """A dict from before PR 38 has ``num_bin == len(bin_to_category)``:
+    loaded, its unknown values must not share the last kept category's
+    bin, which the search never offers."""
+    legacy = {"bin_type": CATEGORICAL, "num_bin": 3,
+              "bin_upper_bound": [float("inf")],
+              "bin_to_category": [3, 7, 1], "is_trivial": False}
+    m = BinMapper.from_dict(legacy)
+    assert m.num_bin == 4
+    np.testing.assert_array_equal(
+        m.value_to_bin(np.array([3.0, 7.0, 1.0, 9.0])), [0, 1, 2, 3])
+    again = BinMapper.from_dict(m.to_dict())  # today's dicts: unchanged
+    assert again.num_bin == 4 and again.bin_to_category == [3, 7, 1]
 
 
 def test_serialization_roundtrip():

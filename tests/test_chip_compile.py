@@ -493,6 +493,55 @@ def test_the_grower_at_2000_columns_keeps_its_carry(wide_grower):
     _the_carry_is_clean(compiled, n=20_480, F=2000, L=15)
 
 
+def test_a_table_with_categorical_columns_compiles_for_v5e(topo):
+    """``jit_grow_tree`` at airline-13.train's width (fewer rows and
+    leaves): thirteen columns, six of them declared categorical, two with
+    more categories than bins, so the record is SIXTEEN words (one trip
+    of the kernels' LOOP_WORDS loop; the cells before it hold 32 to 512)
+    and ``is_categorical`` is set where every other cell passes zeros.
+    ``select_grower`` takes the fused grower, Mosaic takes its kernels,
+    and the ``==`` routing and the one-vs-rest search ride the kernels
+    the numerical cells run: three Mosaic calls, one ``while``, no
+    ``conditional``, the record and ``hists`` never copied."""
+    from lightgbm_tpu.obs import device_time as dt
+
+    n, F, L = 20_480, 13, 15
+    cats = [1, 2, 3, 6, 9, 10]
+    rng = np.random.RandomState(0)
+    X = rng.randn(n, F).astype(np.float32)
+    for j, card in zip(cats, (12, 31, 7, 29, 340, 340)):
+        X[:, j] = rng.randint(0, card, n)
+    y = (X[:, 0] + (X[:, 9] % 7 < 3) > 0.5).astype(np.float32)
+    cfg = Config(objective="binary", num_leaves=L, max_bin=255,
+                 min_data_in_leaf=100)
+    with device.assume_platform("tpu"):
+        ds = BinnedDataset.from_matrix(X, Metadata(label=y), config=cfg,
+                                       categorical_features=cats)
+        gbdt = GBDT(cfg, ds, create_objective(cfg, ds.metadata, n))
+        assert gbdt._grower == ("fused", "")
+        assert gbdt._chunking.record_words == 16
+        assert np.flatnonzero(np.asarray(gbdt._is_cat)).tolist() == cats
+        # both overflowing columns: 254 kept and the others' bin
+        assert np.asarray(gbdt._nbpf)[[9, 10]].tolist() == [255, 255]
+        grow = gbdt._grow
+        on_chip = jax.sharding.SingleDeviceSharding(topo.devices[0])
+        args = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                jnp.shape(a), jnp.result_type(a), sharding=on_chip),
+            (gbdt._bins_T, jnp.zeros(n, jnp.float32),
+             jnp.zeros(n, jnp.float32), gbdt._bag_mask, jnp.ones(F, bool),
+             gbdt._nbpf, gbdt._is_cat, gbdt._learner_params))
+        compiled = grow.func.lower(*args, **grow.keywords).compile()
+    module = compiled.runtime_executable().hlo_modules()[0]
+    prog = dt.program_of_module(module.as_serialized_hlo_module_proto())
+    count = {op: sum(ins.opcode == op for ins in prog.instrs.values())
+             for op in ("while", "conditional")}
+    assert count == {"while": 1, "conditional": 0}, count
+    assert sum(ins.target == "tpu_custom_call"
+               for ins in prog.instrs.values()) == 3
+    _the_carry_is_clean(compiled, n=n, F=F, L=L)
+
+
 @pytest.mark.parametrize("which,F", [("grower", 28), ("wide_grower", 2000)])
 def test_the_split_step_permutes_a_tile_by_lane_gathers(request, which, F):
     """The split step's kernel computes a tile's permutation on rows of

@@ -6,7 +6,8 @@ queue 3, item 1).  Here three trees go through ``Booster.update`` on the
 default float32 path (and once more through the fused grower a TPU chip
 runs, interpreted) at each benchmark configuration's rehearsal size, the
 plain reference (``benchmarks/references/gbdt_replay.py``: numpy, float64
-sums, nothing of the program) follows them with the configuration's own
+sums, nothing of the program; one-vs-rest where the cell's driver binds
+``onevsrest_replay.py``) follows them with the configuration's own
 objective, and ``check.verdict`` holds the comparison to the
 configuration's own ``limits/<config>.json``: the comparison that decides
 ``correct`` on the chip, at a size the CPU can run.
@@ -45,29 +46,30 @@ def harness():
     import cells
     import check
     import run as entry
-    from drivers import train_steady
 
-    yield cells, check, entry, train_steady
+    yield cells, check, entry
     sys.path.remove(BENCH)
 
 
 def numbers_of(harness, config: str, rows=None) -> tuple[dict, dict]:
     """``(numbers, limits)`` of three trees at the rehearsal size, or at
     ``rows`` rows."""
-    cells, check, entry, train_steady = harness
-    workload = config + ".train"
-    cell = cells.assemble({"name": workload, "config": config,
-                           "traffic": "train_steady", "chips": 1},
-                          cells.benchmark())
+    cells, check, entry = harness
+    # the configuration's own cell: its traffic file names the driver, and
+    # the driver binds the plain reference the trees are held to
+    bench = cells.benchmark()
+    work = next(w for w in bench["workloads"] if w["config"] == config)
+    workload = work["name"]
+    cell = cells.assemble(work, bench)
+    driver = cells.plugin("drivers", cell["traffic"]["driver"])
     run = entry.Run(entry.parse(["--workload", workload, "--seed", str(SEED),
                                  "--seconds", "0", "--rehearsal"]), cell)
     if rows is not None:
         run.generator_params["rows"] = rows
     run.traffic = {**run.traffic, "quiet_trees": 0,
                    "min_warmup_trees": run.traffic["checked_trees"]}
-    state = train_steady.first_trees(run, train_steady.setup(run))
-    return (train_steady.compared(run, state,
-                                  train_steady.reference(run, state)),
+    state = driver.first_trees(run, driver.setup(run))
+    return (driver.compared(run, state, driver.reference(run, state)),
             check.limits_of(config))
 
 
@@ -123,6 +125,59 @@ def test_a_bfloat16_histogram_fails_the_same_comparison(
         jax.clear_caches()
     correct, table = harness[1].verdict(numbers, limits)
     assert not correct, table
+
+
+def test_a_program_that_ignores_the_declaration_is_not_timed(harness):
+    """``airline-13.train``'s driver looks at what the program binned
+    before it builds the booster: where the columns the configuration
+    declares categorical were binned as numbers (the parent of PR 38
+    ignored ``categorical_column`` for a matrix), the run ends at once
+    with no result, as a program that cannot run the configuration."""
+    cells, _, entry = harness
+    bench = cells.benchmark()
+    work = next(w for w in bench["workloads"] if w["config"] == "airline-13")
+    cell = cells.assemble(work, bench)
+    driver = cells.plugin("drivers", cell["traffic"]["driver"])
+    params = dict(cell["config"]["params"])
+    assert params.pop("categorical_column") == "1,2,3,6,9,10"
+    cell["config"] = {**cell["config"], "params": params}
+    run = entry.Run(entry.parse(["--workload", work["name"], "--seed",
+                                 str(SEED), "--seconds", "0",
+                                 "--rehearsal"]), cell)
+    with driver.bound(), pytest.raises(SystemExit, match="nothing was run"):
+        driver.setup(run)
+
+
+def test_a_kept_list_one_category_short_is_not_correct(harness):
+    """The one-vs-rest reference searches the kept categories the
+    program hands it, so ``airline-13.train``'s driver holds the lists to
+    the raw matrix's own counts: Origin with 253 kept where the bin
+    sample met more than 254 fails ``kept_categories_off`` and nothing
+    else."""
+    cells, check, entry = harness
+    bench = cells.benchmark()
+    work = next(w for w in bench["workloads"] if w["config"] == "airline-13")
+    cell = cells.assemble(work, bench)
+    driver = cells.plugin("drivers", cell["traffic"]["driver"])
+    run = entry.Run(entry.parse(["--workload", work["name"], "--seed",
+                                 str(SEED), "--seconds", "0",
+                                 "--rehearsal"]), cell)
+    run.traffic = {**run.traffic, "quiet_trees": 0, "min_warmup_trees": 1,
+                   "checked_trees": 1}
+    with driver.bound():
+        state = driver.first_trees(run, driver.setup(run))
+    limits = check.limits_of("airline-13")
+    sound = driver.compared(run, dict(state), driver.reference(run, state))
+    assert check.verdict(sound, limits)[0]
+    origin = next(i for i, (col, _) in enumerate(state["bounds"])
+                  if col == 9)
+    assert len(state["bounds"][origin][1]) == 254
+    state["bounds"][origin] = (9, state["bounds"][origin][1][:-1])
+    short = driver.compared(run, dict(state), driver.reference(run, state))
+    correct, table = check.verdict(short, limits)
+    assert not correct and short["kept_categories_off"] == 1
+    assert [k for k, row in table.items()
+            if not row["value"] <= row["limit"]] == ["kept_categories_off"]
 
 
 def test_the_new_cell_rehearses_correct_through_run_py():
