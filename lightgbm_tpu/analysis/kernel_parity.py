@@ -26,10 +26,12 @@ share no code with it, on whatever backend is present:
            cells run, and at runs of 0 and TILE lanes); prints how many
            histogram tiles the kernel ran of the parent's
   place  — place_runs (aliased placement) vs the numpy stable
-           partition, at the static tile count and, as the grower
+           partition and vs the XLA reference placement on the same
+           compacted tiles, at the static tile count and, as the grower
            launches it, over a wider window at a run-time tile count
-           (dynamic Mosaic grids, parked chunks, several launches);
-           one trial's leaf has all-left and all-right tiles
+           (a dynamic Mosaic grid); begins at 0, mid-block and T - 1,
+           nleft 0 and pcnt, a window inside one block, a leaf with
+           all-left and all-right tiles, and do_split false
 
 Each check prints one summary line through ``log`` and returns
 True/False.  ``chip_smoke.py`` is the caller; on a TPU
@@ -266,89 +268,100 @@ def check_split(rng, log=print, interpret=False) -> bool:
 
 
 def check_place(rng, log=print, interpret=False) -> bool:
-    """place_runs (aliased placement kernel) vs a numpy stable partition
-    — the hardware-only path (interpret falls back to the XLA
-    reference)."""
+    """place_runs (the aliased placement kernel) vs a numpy stable
+    partition AND vs the XLA reference placement (``_xla_place``, which
+    the CPU grower runs) fed the same compacted tiles, bit for bit —
+    the hardware-only path (interpret falls back to that reference).
+    A launch with ``do_split`` false must leave the record as it was."""
     import jax.numpy as jnp
 
-    from ..ops import record
     from ..ops.pallas_search import _pack_meta, _pack_scal
     from ..ops.record import (
-        TILE, bins_per_word, build_record, num_words, round_up)
+        TILE, bins_per_word, build_record, num_words, place_runs, round_up,
+        split_step_counted)
 
-    # the last trial runs with a tiny step-table chunk so the
-    # multi-launch chunk-boundary path (forced adv=1 per launch) is
-    # pinned at test size — place_runs reads record.PLACE_CHUNK when it
-    # traces, and the trial's unique shape forces a fresh trace
     ok = True
-    chunk0 = record.PLACE_CHUNK
-    try:
-        for trial, (F, n, num_bins, begin, frac) in enumerate((
-                (9, 5000, 33, 0, 0.5),
-                (9, 5000, 33, 777, 0.2),   # unaligned begin, unbalanced
-                (9, 5000, 33, 1291, 0.97),  # nearly-all-left
-                (5, 2000, 16, 300, 0.0),   # all-right
-                (7, 3000, 17, 133, 0.4),   # multi-chunk placement
-                (9, 5000, 33, 777, None),  # all-left and all-right tiles
-        )):
-            record.PLACE_CHUNK = 8 if trial == 4 else chunk0
-            bins = rng.randint(0, num_bins, (F, n)).astype(np.uint8)
-            if frac is None:
-                bins[2], frac = _runs(n, num_bins, 3 * TILE + 57), 0.0
-            g = rng.randn(n).astype(np.float32)
-            h = (rng.rand(n) + 0.5).astype(np.float32)
-            # room for the wider window of the run-time-count launch
-            total = round_up(n + begin, TILE) + 3 * TILE
-            rec = build_record(
-                jnp.asarray(np.pad(bins, ((0, 0), (begin, 0)))),
-                jnp.asarray(np.pad(g, (begin, 0))),
-                jnp.asarray(np.pad(h, (begin, 0))),
-                jnp.ones(n + begin, jnp.float32), total)
-            cap = round_up(n, TILE)
-            thr = int(num_bins * frac)
-            f = 2
-            go = bins[f] <= thr
-            lr = num_words(F, bins_per_word(jnp.uint8)) + 4
-            want, want_nl = _np_partition(rec, go, begin, n, lr, 3, 5)
-            want_cl = np.pad(go, (0, cap - n)).reshape(-1, TILE).sum(axis=1)
+    i32 = jnp.int32
+    for trial, (F, n, num_bins, begin, frac) in enumerate((
+            (9, 5000, 33, 0, 0.5),
+            (9, 5000, 33, 777, 0.2),   # unaligned begin, unbalanced
+            (9, 5000, 33, 1291, 0.97),  # nearly-all-left
+            (5, 2000, 16, 300, 0.0),   # all-right: nleft = 0
+            (7, 3000, 17, TILE - 1, 1.0),  # all-left: nleft = pcnt
+            (9, 5000, 33, 777, None),  # all-left and all-right tiles
+            (9, 300, 33, 100, 0.5),  # a window inside one block
+            # lefts end and rights begin in one block, begin % T = T - 1
+            (7, 3000, 17, 2 * TILE - 1, 0.4),
+    )):
+        bins = rng.randint(0, num_bins, (F, n)).astype(np.uint8)
+        if frac is None:
+            bins[2], frac = _runs(n, num_bins, 3 * TILE + 57), 0.0
+        g = rng.randn(n).astype(np.float32)
+        h = (rng.rand(n) + 0.5).astype(np.float32)
+        # room for the wider window of the run-time-count launch
+        total = round_up(n + begin, TILE) + 3 * TILE
+        rec = build_record(
+            jnp.asarray(np.pad(bins, ((0, 0), (begin, 0)))),
+            jnp.asarray(np.pad(g, (begin, 0))),
+            jnp.asarray(np.pad(h, (begin, 0))),
+            jnp.ones(n + begin, jnp.float32), total)
+        cap = round_up(n, TILE)
+        thr = min(int(num_bins * frac), num_bins - 1)
+        f = 2
+        go = bins[f] <= thr
+        k = bins_per_word(jnp.uint8)
+        lr = num_words(F, k) + 4
+        want, want_nl = _np_partition(rec, go, begin, n, lr, 3, 5)
+        want_cl = np.pad(go, (0, cap - n)).reshape(-1, TILE).sum(axis=1)
 
-            Fp, Bp = round_up(F, 8), round_up(num_bins, 128)
-            meta = _pack_meta(jnp.ones(F, bool),
-                              jnp.full(F, num_bins, jnp.int32),
-                              jnp.zeros(F, bool), Fp)
-            scal = _pack_scal(*[jnp.float32(x) for x in
-                                (1., 0., 1., 9., 0., 1., 9., 1., 1e-3,
-                                 0., 0., 0.)])
-            # the static tile count, then the grower's launch pair: a
-            # window two tiles wider than the leaf, visited over the
-            # live tiles only (a run-time grid; chunks past the live
-            # steps run one parked step)
-            for what, cap_w, live in (
-                    ("", cap, None),
-                    (" (live tiles)", cap + 2 * TILE,
-                     jnp.int32(cap // TILE))):
-                # slots 3 and 5 are written by the kernel's hists index
-                # maps — allocate past them (Pallas does not
-                # bounds-check them)
-                _, got, nl, _, cl, _ = _fused_split(
-                    rec, jnp.zeros((7, Fp, 4, Bp), jnp.float32), begin, n,
-                    f, thr, 3, 5, scal, meta, F, cap_w, live, interpret)
-                cl = np.asarray(cl)
-                if (int(nl) != want_nl
-                        or not np.array_equal(cl[:cap // TILE], want_cl)
-                        or cl[cap // TILE:].any()):
-                    log(f"  place trial {trial}{what}: nleft {int(nl)} vs "
-                        f"{want_nl}, or the kernel's tile counts differ")
-                    ok = False
-                got = np.asarray(got)
-                if not np.array_equal(got, want):
-                    bad = [r for r in range(want.shape[0])
-                           if not np.array_equal(got[r], want[r])]
+        Fp, Bp = round_up(F, 8), round_up(num_bins, 128)
+        meta = _pack_meta(jnp.ones(F, bool),
+                          jnp.full(F, num_bins, jnp.int32),
+                          jnp.zeros(F, bool), Fp)
+        scal = _pack_scal(*[jnp.float32(x) for x in
+                            (1., 0., 1., 9., 0., 1., 9., 1., 1e-3,
+                             0., 0., 0.)])
+        # the static tile count, then the grower's launch pair: a window
+        # two tiles wider than the leaf, visited over the live tiles only
+        # (a run-time grid)
+        for what, cap_w, live in (
+                ("", cap, None),
+                (" (live tiles)", cap + 2 * TILE, i32(cap // TILE))):
+            # slots 3 and 5 are written by the kernel's hists index maps
+            # — allocate past them (Pallas does not bounds-check them)
+            _, comp, nl, _, cl, cr, rec_pass, _ = split_step_counted(
+                jnp.zeros((7, Fp, 4, Bp), jnp.float32), rec, i32(begin),
+                i32(n), jnp.bool_(True), i32(f), i32(thr), jnp.bool_(False),
+                i32(3), i32(5), scal, meta, F=F, cap=cap_w, k=k,
+                interpret=interpret, live_tiles=live)
+            cl_np = np.asarray(cl)
+            if (int(nl) != want_nl
+                    or not np.array_equal(cl_np[:cap // TILE], want_cl)
+                    or cl_np[cap // TILE:].any()):
+                log(f"  place trial {trial}{what}: nleft {int(nl)} vs "
+                    f"{want_nl}, or the kernel's tile counts differ")
+                ok = False
+            before = np.asarray(rec_pass)
+
+            def place(split, reference):
+                return np.asarray(place_runs(
+                    jnp.array(rec_pass), comp, (cl, cr), i32(begin), i32(n),
+                    nl, jnp.bool_(split), i32(3), i32(5), cap=cap_w,
+                    leaf_row=lr, interpret=interpret or reference,
+                    live_tiles=live))
+
+            got = place(True, False)
+            for name, ref in (("numpy", want), ("_xla_place",
+                                                place(True, True))):
+                if not np.array_equal(got, ref):
+                    bad = [r for r in range(ref.shape[0])
+                           if not np.array_equal(got[r], ref[r])]
                     log(f"  place trial {trial}{what}: record rows "
-                        f"differ {bad}")
+                        f"differ from {name}'s {bad}")
                     ok = False
-    finally:
-        record.PLACE_CHUNK = chunk0
+            if not np.array_equal(place(False, False), before):
+                log(f"  place trial {trial}{what}: do_split false wrote")
+                ok = False
     log(f"place parity: {'OK' if ok else 'FAIL'}")
     return ok
 

@@ -13,9 +13,11 @@ contiguous one and every buffer updated in place:
 * a split is ONE launch pair: ``split_step_window`` (stable compaction of
   the parent's window, the smaller child's histogram, the sibling by
   subtraction, both children's split search, the two ``hists`` rows
-  written in place) and ``place_runs`` (the compacted runs streamed back
-  into the record, leaf ids stamped).  Both take the window's TILE COUNT
-  as an operand and are sized once, at the largest capacity;
+  written in place) and ``place_runs`` (one grid step a tile: both
+  compacted runs appended to two VMEM write rings, leaf ids stamped, and
+  written back into the record as whole aligned blocks).  Both take the
+  window's TILE COUNT as an operand and are sized once, at the largest
+  capacity;
 * a table wider than one block of ``hists`` (256 features at 256 bins) is
   walked in FEATURE CHUNKS by the root histogram, by the split step's
   subtraction and search, and by its ``hists`` row traffic

@@ -296,6 +296,7 @@ class GBDT:
         growth."""
         if self._grower[0] == "fused":
             from ..learners import fused
+            from ..ops import record
 
             # what the kernels walk, once a booster (obs/telemetry)
             plan = self._chunking
@@ -304,6 +305,9 @@ class GBDT:
                 "grow.chunk_features": plan.chunk_features,
                 "grow.hist_block_bytes": plan.hist_block_bytes,
                 "grow.record_words": plan.record_words,
+                "grow.place_steps_per_tile": record.PLACE_STEPS_PER_TILE,
+                "grow.place_launches_per_split":
+                    record.PLACE_LAUNCHES_PER_SPLIT,
                 "grow.onehot_planes": plan.onehot_planes,
                 "grow.categorical_features": int(
                     self.train_set.is_categorical.sum()),
@@ -500,7 +504,11 @@ class GBDT:
             search, part = "jnp", "leaf-id vector"
         elif which == "fused":
             hist, search = "pallas raw-layout", "pallas (in the split step)"
-            part = f"packed record, {self._chunking.said}"
+            from ..ops import record
+
+            part = (f"packed record, {self._chunking.said}, placement "
+                    f"{record.PLACE_STEPS_PER_TILE} step a tile in "
+                    f"{record.PLACE_LAUNCHES_PER_SPLIT} launch a split")
         else:
             hist = "pallas" if self._use_pallas_hist() else "segment-sum"
             search = ("pallas" if on_tpu() and not self._use_f64_hist

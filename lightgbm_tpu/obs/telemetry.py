@@ -64,7 +64,10 @@ Counters maintained by the library itself:
   ``grow.onehot_planes`` — how the fused grower's kernels walk the
   feature axis and split the bin axis (learners/fused.py ``chunking``;
   ops/pallas_histogram.py ``bin_sums``), added once a booster that
-  takes the fused grower.
+  takes the fused grower; beside them ``grow.place_steps_per_tile`` and
+  ``grow.place_launches_per_split`` (1 and 1: ops/record.py
+  ``PLACE_STEPS_PER_TILE``; the step table before PR 39 took 4 and up to
+  8).
 * ``host_syncs`` — deliberate device->host materialization points the
   library performs (eval fetches, lagged-stop drains, bench syncs).
 * ``collective_ops`` / ``collective_bytes`` — cross-device collectives

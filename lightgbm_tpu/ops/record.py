@@ -27,12 +27,15 @@ Who calls what:
     tile of the leaf's window the go flags, a stable compaction and the
     tile's left count; the smaller child's histogram; then the sibling
     by subtraction, both children's split search and the two ``hists``
-    rows in place — and ``place_runs``, in-order sliced DMA landing
-    each tile's left/right runs at their global offsets in the ALIASED
-    record (later tiles overwrite earlier garbage tails because TPU
-    grids execute sequentially).  Both take the window's tile count as
-    an OPERAND (a dynamic Mosaic grid), so one compiled body serves
-    every leaf size and no ``lax.cond`` stands round them.
+    rows in place — and ``place_runs``, one grid step a tile: the
+    tile's compacted block is read once, its left run appended to a
+    VMEM write ring of the left child and its right run to one of the
+    right child, and each ring leaves as whole T-aligned blocks, DMA'd
+    into the ALIASED record (_place_kernel; the blocks shared with the
+    leaves either side, and the one where the lefts end and the rights
+    begin, are each written once, at the end).  Both take the window's
+    tile count as an OPERAND (a dynamic Mosaic grid), so one compiled
+    body serves every leaf size and no ``lax.cond`` stands round them.
 
     Where the smaller child's histogram comes from: the compaction has
     already laid that child's rows of a tile side by side, so each tile
@@ -64,7 +67,8 @@ chip runs: ``split_step_window`` reads a materialised window slice
 where the chip roll-merges two aligned blocks of the aliased record,
 and ``place_runs`` returns the XLA reference placement (``_xla_place``)
 where the chip runs its kernel.  analysis/kernel_parity.py holds both
-to numpy on the chip.
+to numpy on the chip; tests/test_place_kernel.py runs the placement
+kernel interpreted against ``_xla_place``.
 """
 
 from __future__ import annotations
@@ -79,18 +83,18 @@ from jax.experimental.pallas import tpu as pltpu
 from ..obs.device_time import phase_scope
 from .totals import two_sum
 
-# partition tile width; larger tiles halve the placement-scan step
+# partition tile width; larger tiles halve both tile passes' grid step
 # count at one extra compress stage per doubling.  A positive multiple
 # of 128 (Mosaic lane alignment: the kernels' DMA offsets and the
 # cap % TILE asserts both require it).
 TILE = 512
-# place_runs step-table chunk per launch: a [8, steps] i32 SMEM prefetch
-# block is 32B/step (SMEM pads the minor dim to 128 lanes per ROW, hence
-# the transpose), and the 1MB SMEM budget caps one launch at ~16k steps
-# — a 10M-row window has ~78k.  place_runs reads it when it traces.
-PLACE_CHUNK = 16384
 # Mosaic's scoped VMEM when a call asks for nothing.
 VMEM_DEFAULT_BYTES = 16 << 20
+# What a split's placement takes (the ``grow.place_*`` counters): one
+# grid step a live parent tile, plus a closing step, in one launch
+# (_place_call).
+PLACE_STEPS_PER_TILE = 1
+PLACE_LAUNCHES_PER_SPLIT = 1
 # Packed words (sublane rows of the record) one step of the histogram
 # body's loop takes, LOOP_WORDS * k features unrolled a step.  The step
 # alone on the chip, ms a window split in half at 7.5M x 100 / 400,000 x
@@ -595,8 +599,8 @@ def _split_tile(tile, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
     side: the lefts in lanes [0, T) of the compacted tile, the valid
     rights as a PREFIX of [T, 2T) (the invalid tail follows them).  That
     run is appended at lane ``fill`` of ``stage_ref`` [W, 2T] — one
-    dynamic roll, two lane masks, as the direct read and _place_kernel
-    move unaligned runs — so the histogram body (_split_step_kernel)
+    dynamic roll, two lane masks, as the direct read and _place_kernel's
+    rings move unaligned runs — so the histogram body (_split_step_kernel)
     runs on full tiles of the child's rows and never sees the sibling's.
     ``fill < T`` on entry and the run is at most T long, so the append
     fits and at most one full tile is due after it."""
@@ -842,107 +846,222 @@ def _split_step_kernel(
         hists_out_ref[0] = jnp.where(do_split, hacc_ref[rows], hrow_ref[0])
 
 
-def _place_kernel(sp_ref, comp_ref, rec_in_ref, rec_out_ref, *,
-                  W, leaf_row):
-    """Placement-only kernel: stream the compacted left/right runs into
-    the ALIASED record at their (arbitrary, unaligned) destinations —
-    replacing the XLA scan-of-DUS + roll/merge chain AND the full-record
-    copy its dynamic-update-slice forced at a cond boundary.
+# SMEM state of a placement launch (_place_kernel): per child run s (0
+# left, 1 right) its block, ring half and fill at 3*s .. 3*s+2, then a
+# flag a half whose write is in flight, at _PEND + 2*s + half, then
+# whether the right run's first block is held in ``mid``.
+_PEND = 6
+_MID_HELD = 10
+# DMA semaphores: one a ring half of each run (2*s + half), then the
+# two edge blocks (the reads at the first step, the writes at the last)
+_SEM_EDGE = 4
 
-    Step table sp [8, steps] i32 (see _place_table): per step one run
-    half lands in one T-lane rec block; block indices are monotone, so
-    each block is flushed exactly once after its last write.  On an
-    index advance the merge base is the freshly fetched block; on a
-    revisit it is the still-resident out block.  Child leaf ids are
-    stamped into the record's leaf-id row as part of the same write.
-    The grid (how many of the table's steps run) is a run-time value.
-    """
+
+def _place_kernel(cl_ref, sc_ref, comp_ref, rec_in_ref, rec_ref,
+                  lbuf, rbuf, mid, edge, st, sem, *, W, leaf_row, nblocks):
+    """The placement: ONE grid step a live parent tile, then one closing
+    step.  Step ``j`` reads tile ``j``'s ``[W, 2T]`` compacted block once
+    and appends its left run (``cl[j]`` lanes of ``comp[:, :T]``) to the
+    left child's write buffer and its valid right run (the prefix of
+    ``comp[:, T:]`` below ``pcnt``) to the right child's, each the way
+    _split_tile stages the smaller child's rows: a roll to the fill and
+    two lane masks, the child's leaf id stamped into ``leaf_row``.
+
+    A write buffer is a RING of two ``[W, T]`` halves.  Lefts land in
+    ``[begin, begin + nleft)`` and rights in ``[begin + nleft, begin +
+    pcnt)``, so each run starts at a lane of a T-aligned block of the
+    record and the ring's current half IS that block: when it fills, one
+    DMA writes it to its aligned place in the record (``pl.ANY``,
+    aliased in to out) and the run goes on in the other half.  The write
+    is waited on only when the run next needs that half, a step later at
+    the soonest, so no step waits on a write it issued itself.  Block,
+    half and fill of both runs are running sums in SMEM (``st``), from
+    the left counts that come as scalar prefetch: no step table.
+
+    Three blocks hold lanes of other data, and each is written ONCE:
+
+    * the first left block, whose lanes below ``begin % T`` belong to the
+      leaf before: read into the left ring at the first step;
+    * the last right block, whose lanes from ``(begin + pcnt) % T`` on
+      belong to the leaf after: read into ``edge`` at the first step;
+    * the block where the lefts end and the rights begin: the right
+      run's first full block waits in ``mid`` (the lefts below it are
+      not all placed before the last tile), and the closing step merges
+      the left ring's last lanes into it.
+
+    A window inside one block is the case where all three coincide: the
+    closing step writes that block from the left ring, the right ring and
+    ``edge``.  ``active`` false (``do_split`` false, or no rows) writes
+    nothing, in a grid of one step.
+
+    cl [nt] i32: each tile's left count.  sc [7] i32: (begin, pcnt,
+    nleft, active, left leaf, right leaf, live tiles)."""
     T = TILE
     i = pl.program_id(0)
-    # the table is stored TRANSPOSED [8, steps]: a [steps, 8] SMEM
-    # prefetch array pads its minor dim to 128 lanes (16x the bytes);
-    # huge windows additionally CHUNK the table across multiple launches
-    # to stay inside the 1MB SMEM budget (see place_runs)
-    en = sp_ref[6, i] > 0
+    begin, pcnt, nleft = sc_ref[0], sc_ref[1], sc_ref[2]
+    active = sc_ref[3] > 0
+    live = sc_ref[6]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (W, T), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (W, T), 0)
+    bufs = (lbuf, rbuf)
 
-    def _merge(base):
-        half = sp_ref[1, i] & 1
-        comp = comp_ref[0]  # [W, 2T]
-        content = comp[:, :T] * (1 - half) + comp[:, T:] * half
-        rolled = pltpu.roll(content, sp_ref[2, i], axis=1)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-        mask = ((lane >= sp_ref[3, i]) & (lane < sp_ref[4, i])
-                ).astype(jnp.int32)
-        rowsel = (jax.lax.broadcasted_iota(jnp.int32, (W, 1), 0)
-                  == leaf_row).astype(jnp.int32)
-        stamped = rowsel * sp_ref[7, i] + (1 - rowsel) * rolled
-        return mask * stamped + (1 - mask) * base
+    def block(ref, b):  # the record's T-aligned block ``b``
+        return ref.at[:, pl.ds(pl.multiple_of(b * T, T), T)]
 
-    @pl.when(en & (sp_ref[5, i] > 0))
+    def write(src, b, s):
+        return pltpu.make_async_copy(src, block(rec_ref, b), sem.at[s])
+
+    def wait_half(s, h):
+        @pl.when(st[_PEND + 2 * s + h] > 0)
+        def _():
+            write(bufs[s].at[h], 0, 2 * s + h).wait()
+            st[_PEND + 2 * s + h] = 0
+
+    @pl.when(active & (i == 0))
     def _():
-        rec_out_ref[...] = _merge(rec_in_ref[...])
+        e_left, e_right = begin + nleft, begin + pcnt
+        st[0], st[1], st[2] = begin // T, 0, begin % T
+        st[3], st[4], st[5] = e_left // T, 0, e_left % T
+        for x in range(_PEND, _MID_HELD + 1):
+            st[x] = 0
+        first = pltpu.make_async_copy(block(rec_in_ref, begin // T),
+                                      lbuf.at[0], sem.at[_SEM_EDGE])
+        last = pltpu.make_async_copy(
+            block(rec_in_ref, jnp.minimum(e_right // T, nblocks - 1)),
+            edge, sem.at[_SEM_EDGE + 1])
+        first.start()
+        last.start()
+        first.wait()
+        last.wait()
 
-    @pl.when(en & (sp_ref[5, i] == 0))
+    def append(s, half, run, leaf):
+        buf = bufs[s]
+        blk, h, fill = st[3 * s], st[3 * s + 1], st[3 * s + 2]
+        end = fill + run
+        rolled = pltpu.roll(half, fill, axis=1)  # lane t -> (t + fill) % T
+        if leaf_row >= 0:
+            rolled = jnp.where(row == leaf_row, leaf, rolled)
+        buf[h] = jnp.where((lane >= fill) & (lane < end), rolled, buf[h])
+
+        @pl.when(end >= T)
+        def _():
+            o = 1 - h
+            wait_half(s, o)  # issued at an earlier step
+            buf[o] = jnp.where(lane < end - T, rolled, buf[o])
+            if s == 0:
+                write(buf.at[h], blk, h).start()
+                st[_PEND + h] = 1
+            else:
+                held = st[_MID_HELD] > 0
+
+                @pl.when(held)
+                def _():
+                    write(buf.at[h], blk, 2 + h).start()
+                    st[_PEND + 2 + h] = 1
+
+                @pl.when(jnp.logical_not(held))
+                def _():
+                    mid[...] = buf[h]
+                    st[_MID_HELD] = 1
+
+            st[3 * s], st[3 * s + 1] = blk + 1, o
+
+        st[3 * s + 2] = jnp.where(end >= T, end - T, end)
+
+    @pl.when(active & (i < live))
     def _():
-        rec_out_ref[...] = _merge(rec_out_ref[...])
+        comp = comp_ref[0]  # [W, 2T], read once
+        nl = cl_ref[i]
+        append(0, comp[:, :T], nl, sc_ref[4])
+        append(1, comp[:, T:], jnp.clip(pcnt - i * T, 0, T) - nl, sc_ref[5])
 
-    @pl.when((i == 0) & jnp.logical_not(en))
+    @pl.when(active & (i == live))
     def _():
-        # a launch whose first step is disabled (a no-op split, or a
-        # chunk wholly past the live steps) must still write the parked
-        # block once or the grid-end flush emits garbage
-        rec_out_ref[...] = rec_in_ref[...]
+        for s in range(2):
+            for h in range(2):
+                wait_half(s, h)
+        lblk, lf = st[0], st[2]
+        rblk, rf = st[3], st[5]
+        lo, ro = lbuf[st[1]], rbuf[st[4]]
+
+        @pl.when(st[_MID_HELD] > 0)
+        def _():
+            # lefts below ``lf``, the right run's first block above
+            mid[...] = jnp.where(lane < lf, lo, mid[...])
+            w = write(mid, lblk, _SEM_EDGE)
+            w.start()
+
+            @pl.when(rf > 0)
+            def _():
+                edge[...] = jnp.where(lane < rf, ro, edge[...])
+                w2 = write(edge, rblk, _SEM_EDGE + 1)
+                w2.start()
+                w2.wait()
+
+            w.wait()
+
+        @pl.when((st[_MID_HELD] == 0) & (rf > 0))
+        def _():
+            # the right run never filled its first block: lefts, rights
+            # and the leaf after share it (rblk == lblk)
+            edge[...] = jnp.where(lane < lf, lo,
+                                  jnp.where(lane < rf, ro, edge[...]))
+            w = write(edge, rblk, _SEM_EDGE + 1)
+            w.start()
+            w.wait()
 
 
-def _place_table(begin, nleft, cl, cr, loff, roff,
-                 left_leaf, right_leaf, do_split, nt, live):
-    """[8, 4*nt] i32 placement step table, one COLUMN a step (rows:
-    0 rec block, 1 comp tile*2 + half, 2 roll, 3/4 lane range, 5 merge
-    from the fetched block, 6 enabled, 7 leaf id).  Lefts stream to
-    [begin, begin+nleft), rights to [begin+nleft, begin+pcnt); each
-    tile's run may straddle two blocks (lower + upper step).  The
-    ``2*live`` left steps come first and the right steps follow them
-    directly, so the ``4*live`` live steps are a PREFIX of the table
-    (what lets place_runs run a run-time number of them); block indices
-    are forward-filled monotone."""
+def _place_call(rec, comp, cl, scal, *, leaf_row: int,
+                interpret: bool = False):
+    """The placement launch (_place_kernel) on the record ``rec`` [W,
+    n_pad], aliased in to out; ``scal`` as the kernel's ``sc``.  One
+    grid step a live tile and a closing one, or one step that writes
+    nothing."""
+    W, n_pad = rec.shape
     T = TILE
+    live = scal[6]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(jnp.where(scal[3] > 0, live + 1, 1),),
+        in_specs=[
+            # the closing step keeps the last tile's block: no fetch
+            pl.BlockSpec((1, W, 2 * T),
+                         lambda i, cl, sc: (jnp.minimum(i, sc[6] - 1), 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((2, W, T), jnp.int32),  # left ring
+            pltpu.VMEM((2, W, T), jnp.int32),  # right ring
+            pltpu.VMEM((W, T), jnp.int32),  # mid
+            pltpu.VMEM((W, T), jnp.int32),  # edge
+            pltpu.SMEM((16,), jnp.int32),
+            pltpu.SemaphoreType.DMA((_SEM_EDGE + 2,)),
+        ],
+    )
+    with phase_scope("partition.place.dyn"):
+        return pl.pallas_call(
+            functools.partial(_place_kernel, W=W, leaf_row=leaf_row,
+                              nblocks=n_pad // T),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((W, n_pad), jnp.int32),
+            input_output_aliases={3: 0},  # rec (incl. the prefetch args)
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
+                VMEM_DEFAULT_BYTES, place_vmem_bytes(W))),
+            interpret=interpret,
+        )(cl, scal, comp, rec)
 
-    def run_steps(gbase, counts, offs, half_flag, leaf_val):
-        g = gbase + offs
-        b = g // T
-        s_ = g % T
-        end = s_ + counts
-        spill = end - T
-        has_lo = (counts > 0).astype(jnp.int32)
-        has_up = (spill > 0).astype(jnp.int32)
-        j2 = jnp.arange(nt, dtype=jnp.int32) * 2 + half_flag
-        zeros = jnp.zeros_like(b)
-        leaf = jnp.full_like(b, leaf_val)
-        lower = (b, j2, s_, s_, jnp.minimum(end, T), zeros, has_lo, leaf)
-        upper = (b + has_up, j2, s_, zeros, jnp.maximum(spill, 0), zeros,
-                 has_up, leaf)
-        # [8, nt, 2] -> [8, 2*nt]: a tile's lower step, then its upper
-        return jnp.stack(
-            [jnp.stack(lower), jnp.stack(upper)], axis=2).reshape(8, 2 * nt)
 
-    stepsL = run_steps(begin, cl, loff, 0, left_leaf)
-    stepsR = run_steps(begin + nleft, cr, roff, 1, right_leaf)
-    # counts past the live tiles are zero (_tile_counts), so every left
-    # step the right block overwrites, and every right step that lands
-    # past 4*live, is a disabled one
-    steps = jax.lax.dynamic_update_slice(
-        jnp.concatenate([stepsL, jnp.zeros_like(stepsR)], axis=1),
-        stepsR, (0, 2 * live))
-    enable = steps[6] * do_split.astype(jnp.int32)
-    park = (begin // T).astype(jnp.int32)
-    idx_seq = jnp.where(enable > 0, steps[0], -1)
-    idx_ff = jax.lax.cummax(
-        jnp.concatenate([park[None], idx_seq])[None], axis=1)[0][1:]
-    adv = (jnp.concatenate([park[None], idx_ff])[:-1] != idx_ff
-           ).astype(jnp.int32)
-    # (each launch's first enabled step is forced to adv=1 in
-    # place_runs' chunk loop — chunk 0 covers the park-index case)
-    return steps.at[0].set(idx_ff).at[5].set(adv).at[6].set(enable)
+def place_scalars(begin, pcnt, nleft, do_split, left_leaf, right_leaf,
+                  live):
+    """The placement kernel's ``sc`` operand (see _place_kernel)."""
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    pcnt = i32(pcnt)
+    active = (jnp.asarray(do_split) & (pcnt > 0)).astype(jnp.int32)
+    return jnp.stack([
+        i32(begin), pcnt, i32(nleft), active,
+        i32(0 if left_leaf is None else left_leaf),
+        i32(0 if right_leaf is None else right_leaf), i32(live)])
 
 
 @functools.partial(
@@ -961,64 +1080,31 @@ def place_runs(
     interpret: bool = False,
     live_tiles=None,  # run-time tile count <= cap // TILE (None = all)
 ):
-    """Scatter the compacted runs into the record, aliased in place.
+    """Place the compacted runs into the record, aliased in place: ONE
+    launch a split (_place_call), one grid step a live tile.
     ``live_tiles`` (an operand, as in split_step_window: it must cover
-    ``pcnt``, and ``counts`` past it must be zero) bounds how many of
-    the step table's ``4 * cap // TILE`` steps run: each launch takes a
-    run-time step count, and a table chunk wholly past the live steps
-    runs one parked step.  Interpret mode falls back to the
-    (bit-identical, slower) XLA scan-of-DUS placement so CPU tests stay
-    meaningful; hardware parity of the kernel path is pinned by
-    analysis/kernel_parity.py."""
-    W, n_pad = rec.shape
-    T = TILE
-    nt = cap // T
+    ``pcnt``, and ``counts`` past it must be zero) is how many tiles the
+    launch visits.  The kernel takes the left counts alone: a tile's
+    valid rights are what ``pcnt`` leaves of it.  Interpret mode runs
+    the (bit-identical, slower) XLA reference placement, so the CPU
+    grower stays meaningful; tests/test_place_kernel.py holds the kernel,
+    interpreted, to it, and analysis/kernel_parity.py holds the kernel
+    to numpy on the chip."""
+    nt = cap // TILE
     live = _live_tiles(live_tiles, nt)
     cl, cr = counts
-    loff, roff = _run_offsets(cl, cr)
 
     if interpret:
         # reference placement (the XLA path the kernel replaces)
+        loff, roff = _run_offsets(cl, cr)
         return _xla_place(
             rec, comp, loff, roff, begin, pcnt, nleft, do_split, cap,
             leaf_row, left_leaf, right_leaf)
-
-    steps = _place_table(begin, nleft, cl, cr, loff, roff,
-                         left_leaf, right_leaf, do_split, nt, live)
-    params = pltpu.CompilerParams(
-        vmem_limit_bytes=max(VMEM_DEFAULT_BYTES, place_vmem_bytes(W)))
-    total = 4 * nt
-    for lo in range(0, total, PLACE_CHUNK):
-        sl = steps[:, lo: lo + PLACE_CHUNK]
-        en_c = sl[6]
-        # each launch's first enabled step must merge from the freshly
-        # fetched block: the previous launch's writes are flushed to
-        # HBM at ITS grid end, not resident in this launch's windows
-        first_c = ((jnp.cumsum(en_c) == 1) & (en_c > 0)).astype(jnp.int32)
-        sl = sl.at[5].set(jnp.maximum(sl[5], first_c))
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            # at least one step, the parked block's identity write (a
-            # zero-step launch costs 13.4 us against this one's 14.3)
-            grid=(jnp.clip(4 * live - lo, 1, sl.shape[1]),),
-            in_specs=[
-                pl.BlockSpec(
-                    (1, W, 2 * T),
-                    lambda i, sp: (sp[1, i] >> 1, 0, 0)),
-                pl.BlockSpec((W, T), lambda i, sp: (0, sp[0, i])),
-            ],
-            out_specs=pl.BlockSpec((W, T), lambda i, sp: (0, sp[0, i])),
-        )
-        with phase_scope("partition.place.dyn"):
-            rec = pl.pallas_call(
-                functools.partial(_place_kernel, W=W, leaf_row=leaf_row),
-                grid_spec=grid_spec,
-                out_shape=jax.ShapeDtypeStruct((W, n_pad), jnp.int32),
-                input_output_aliases={2: 0},  # rec (incl. the prefetch arg)
-                compiler_params=params,
-                interpret=interpret,
-            )(sl, comp, rec)
-    return rec
+    return _place_call(
+        rec, comp, jnp.asarray(cl, jnp.int32),
+        place_scalars(begin, pcnt, nleft, do_split, left_leaf, right_leaf,
+                      live),
+        leaf_row=leaf_row)
 
 
 def _live_tiles(live_tiles, nt: int):
@@ -1055,12 +1141,15 @@ def _hist_tiles(cnt, live):
 
 
 def place_vmem_bytes(W: int) -> int:
-    """What a ``place_runs`` launch keeps in VMEM on the chip: the
-    ``[1, W, 2 * TILE]`` comp block and the record block in and out,
-    all double-buffered (8 ``[W, TILE]`` blocks), the merge's working
-    tiles (given 4) and 2 MiB for the rest.  One ``[W, TILE]`` block
-    under the split step's sum at every height, so the grower's gate
-    reads that one (learners/fused.py chunking)."""
+    """What a ``place_runs`` launch keeps in VMEM on the chip, in
+    ``[W, TILE]`` blocks of the record: the ``[1, W, 2 * TILE]`` comp
+    block in, double-buffered (4); the two write rings (4); ``mid`` and
+    ``edge`` (2); the appends' working tiles, GIVEN (2); and 2 MiB for
+    the rest.  Mosaic asked for 12.97 MiB at 512 words (this sum: 14.0)
+    and 24.1 at 1,032 (26.2), read off deviceless v5e compiles at
+    falling limits (PERF.md, PR 39).  Four blocks under the split step's
+    sum at every height, so the grower's gate reads that one
+    (learners/fused.py chunking; tests/test_place_kernel.py)."""
     return 12 * W * TILE * 4 + (2 << 20)
 
 

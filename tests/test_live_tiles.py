@@ -11,9 +11,9 @@ serves every leaf size and the grower launches it outside any
 * a 3-tree model grown by the fused grower equals the canonical
   grower's.
 
-The hardware-only halves (the dynamic Mosaic grid, the aliased
-placement's run-time step count) are compiled by
-tests/test_chip_compile.py and executed by analysis/kernel_parity.py.
+The hardware-only halves (the dynamic Mosaic grids) are compiled by
+tests/test_chip_compile.py and executed by analysis/kernel_parity.py;
+the placement kernel runs interpreted in tests/test_place_kernel.py.
 """
 
 import numpy as np
@@ -137,39 +137,6 @@ def test_unwritten_tiles_are_masked(inputs, pcnt, begin, do_split):
     got = np.asarray(_place(rec_pass, jnp.asarray(comp_g), cl2, cr2, b, p,
                             nleft2, do_split, live))
     assert got.tobytes() == want.tobytes()
-
-
-def test_place_table_live_steps_are_a_prefix():
-    """The step table place_runs' launches walk: the ``4 * live`` live
-    steps come first (lefts, then rights directly after them), every
-    enabled step is among them, block indices never go back, and with
-    the full count the layout is lefts in the first half, rights in the
-    second."""
-    nt, T = 8, _T
-    rng = np.random.RandomState(3)
-    for live, begin in ((1, 5), (3, T + 77), (8, 2 * T - 1), (8, 0)):
-        pcnt = live * T - rng.randint(0, T)
-        tile = np.arange(nt)
-        vt = np.clip(pcnt - tile * T, 0, T)
-        cl = (rng.rand(nt) * (vt + 1)).astype(np.int32).clip(0, vt)
-        cr = vt - cl
-        loff, roff = R._run_offsets(jnp.asarray(cl), jnp.asarray(cr))
-        steps = np.asarray(R._place_table(
-            jnp.int32(begin), jnp.int32(cl.sum()), jnp.asarray(cl),
-            jnp.asarray(cr), loff, roff, jnp.int32(4), jnp.int32(9),
-            jnp.bool_(True), nt, jnp.int32(live)))
-        assert steps.shape == (8, 4 * nt)
-        en = steps[6] > 0
-        assert not en[4 * live:].any()
-        assert (np.diff(steps[0]) >= 0).all()
-        half = steps[1] & 1
-        assert not half[:2 * live][en[:2 * live]].any()  # lefts first
-        assert half[2 * live:4 * live][en[2 * live:4 * live]].all()
-        # every row of the window is written exactly once
-        lanes = (steps[4] - steps[3])[en].sum()
-        assert lanes == pcnt, (lanes, pcnt)
-        assert set(steps[7][en & (half == 0)]) <= {4}
-        assert set(steps[7][en & (half == 1)]) <= {9}
 
 
 def _grow3(raw):

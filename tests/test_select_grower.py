@@ -102,12 +102,14 @@ def test_wide_tables_get_the_fused_grower_and_the_bound_is_named(
             f"split step VMEM {mib} of ")
     counters = {"grow.feature_chunks": chunks, "grow.chunk_features": 256,
                 "grow.hist_block_bytes": 1 << 20, "grow.record_words": words,
-                "grow.onehot_planes": 2}
+                "grow.onehot_planes": 2, "grow.place_steps_per_tile": 1,
+                "grow.place_launches_per_split": 1}
     tel = telemetry.get_telemetry()
     before = {name: tel.counter(name) for name in counters}
     g = _booster("tpu", F=F)
     assert g._grower == ("fused", "") and g._grow.func is fused.grow_tree
-    assert any(said + "96 MiB, one-hot of 2 x 128 bins" in m
+    assert any(said + "96 MiB, one-hot of 2 x 128 bins, placement 1 step "
+               "a tile in 1 launch a split" in m
                and "grower=fused" in m
                for m in gbdt_mod._LOGGED_PATHS), gbdt_mod._LOGGED_PATHS
     assert {name: tel.counter(name) - before[name]

@@ -15,6 +15,7 @@ trip count, and it counts every ``pl.when`` branch.
     python tools/kernel_bundles.py compact 32 64 512    # record words
     python tools/kernel_bundles.py split_step 100 2000  # columns
     python tools/kernel_bundles.py root 32 100          # columns
+    python tools/kernel_bundles.py place 16 32 512      # record words
 """
 
 import glob
@@ -91,7 +92,24 @@ def root(shape, F: int, bins: int = 255):
         shape((n,), "float32"), num_bins=bins, interpret=False)
 
 
-KERNELS = {"compact": compact, "split_step": split_step, "root": root}
+def place(shape, W: int):
+    """The placement's whole kernel on a record of ``W`` words."""
+    import jax
+    from lightgbm_tpu.ops import record as R
+    n = 20_480
+
+    def run(rec, comp, cl, i):
+        return R.place_runs(
+            rec, comp, (cl, cl), i, i, i, i > 0, i, i + 1, cap=n,
+            leaf_row=W - 4, interpret=False, live_tiles=i)
+
+    return jax.jit(run, donate_argnums=0).lower(
+        shape((W, 2 * n), "int32"), shape((n // R.TILE, W, 2 * R.TILE), "int32"),
+        shape((n // R.TILE,), "int32"), shape((), "int32"))
+
+
+KERNELS = {"compact": compact, "split_step": split_step, "root": root,
+           "place": place}
 
 if __name__ == "__main__":
     os.environ["JAX_PLATFORMS"] = "cpu"
