@@ -31,6 +31,8 @@ from .metadata import Metadata
 from .parser import ParseError, parse_file
 
 BINARY_MAGIC = "lightgbm_tpu_binned_dataset_v1"
+# rows of one column that the numpy encoder bins in one task (_encode_bins)
+ENCODE_BLOCK_ROWS = 1 << 21
 
 
 def _finite_label_mask(label_col: np.ndarray, config: Config, path: str,
@@ -110,10 +112,28 @@ def _encode_bins(
 
     if not rest:
         return
-    # categorical columns (and every column without the native encoder)
+    # categorical columns (and every column without the native encoder):
+    # numpy, a block of rows of a column a task, on threads (its sorts
+    # and searches let go of the GIL); every value is binned alone, so the
+    # blocks' results are the whole column's
+    n = X.shape[0]
+    tasks = [(orig, inner, lo) for orig, inner in rest
+             for lo in range(0, n, ENCODE_BLOCK_ROWS)]
+
+    def encode(task):
+        orig, inner, lo = task
+        hi = min(lo + ENCODE_BLOCK_ROWS, n)
+        X_bin[lo:hi, inner] = mappers[inner].value_to_bin(X[lo:hi, orig])
+
     with telemetry.span("lgbm.setup.ingest.encode.python"):
-        for orig, inner in rest:
-            X_bin[:, inner] = mappers[inner].value_to_bin(X[:, orig])
+        if len(tasks) == 1:
+            encode(tasks[0])
+            return
+        from concurrent.futures import ThreadPoolExecutor
+
+        with ThreadPoolExecutor(min(len(tasks), os.cpu_count() or 1)) as pool:
+            for _ in pool.map(encode, tasks):
+                pass
 
 
 def _sample_row_indices(n: int, config: Config) -> np.ndarray:

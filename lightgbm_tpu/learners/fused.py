@@ -35,6 +35,34 @@ carry through aliased calls and are never copied.
 The grower takes no hooks, no resume, no pool and no row-mask mode:
 learners/serial.py serves those, and ``models/gbdt.py select_grower`` is
 the one place that chooses between the two.
+
+DATA-PARALLEL (``axis``: the body under ``jax.shard_map`` over a row
+mesh, parallel/data_parallel.py).  Each chip holds a contiguous share of
+the rows, its own record and its own ``pos_mat`` (its leaves' windows in
+its record); ``hists``, ``best_mat`` and the node tables are the same on
+every chip, because every chip searches the same summed histograms.  The
+exchange is one ``psum`` of a ``[Fp, 4, Bp]`` block under
+``lgbm.grow.exchange``: of the root histogram once a tree, and of the
+smaller child's every split, between the launch that compacts and sums
+this chip's rows (``split_hist_counted``) and the one that subtracts and
+searches (``split_search``: the one-chip split step's tail as a launch of
+its own, ``lgbm.split_step.search``).  Placement stays local.  The root's
+sums of gradients and hessians are the one-chip totals bit for bit
+(ops/totals.py).  With ``axis`` None nothing of this is traced: the
+one-chip program is the same program (tests/test_chip_compile.py holds
+its digest).
+
+Counts.  A chip's count channel is exact (it holds at most 2**24 rows:
+learners/serial.py check_count_envelope, per shard), and every count the
+search compares against something that can flip it (``min_data_in_leaf``,
+the smaller-child choice) is one below 2**24 or a tie that every chip
+breaks alike, so the summed float32 channel serves the search.  The
+TREE's counts are exact at any height: each chip's count of the smaller
+child (the count channel of feature 0, an exact float32 integer) rides
+the exchanged block as two 12-bit pieces in channel 3, which the
+histograms never use, and comes back summed in int32; a leaf's count is
+kept in ``pos_mat``'s third row, a node's in ``node_cnt``, and the tree
+carries them as int32.
 """
 
 from __future__ import annotations
@@ -55,8 +83,9 @@ from ..ops.pallas_search import _pack_meta, _pack_scal
 from ..ops import record
 from ..ops.record import (
     bins_per_word, build_record, num_words, place_runs, round_up,
-    split_step_window,
+    split_hist_counted, split_search, split_step_window,
 )
+from ..ops.totals import root_totals
 from . import tables
 from .serial import TreeLearnerParams, default_search_fn
 
@@ -133,11 +162,36 @@ class _State(NamedTuple):
     tree_i: jax.Array
     tree_f: jax.Array
     nleaves: jax.Array  # scalar int32 used-leaf count
+    # [L - 1] i32 rows of every internal node over all chips; None (no
+    # leaf of the carry) on one device, where tree_f holds the count
+    node_cnt: jax.Array | None = None
+
+
+# Each chip's row count rides the exchanged block in channel 3 (always 0
+# in a histogram: ops/pallas_histogram.py split_stats) as two pieces of
+# COUNT_BITS bits, each summed exactly in float32 over up to 2**12 chips.
+COUNT_BITS = 12
+
+
+def exchange(h: jax.Array, axis: str):
+    """Sum a ``[Fp, 4, Bp]`` histogram block over the chips of ``axis``,
+    in ONE collective: ``(summed block, rows counted over every chip)``,
+    the count exact in int32.  A chip's count is its count channel of
+    feature 0 (every row is in one of its bins), exact below 2**24."""
+    with phase_scope("grow.exchange"):
+        cnt = jnp.sum(h[0, 2]).astype(jnp.int32)
+        lo = (1 << COUNT_BITS) - 1
+        h = h.at[0, 3, :2].set(
+            jnp.stack([cnt >> COUNT_BITS, cnt & lo]).astype(h.dtype))
+        h = jax.lax.psum(h, axis)
+        hi_lo = h[0, 3, :2].astype(jnp.int32)
+        return h.at[0, 3, :2].set(0.0), (hi_lo[0] << COUNT_BITS) + hi_lo[1]
 
 
 # The benchmark reads the program by this function's name
 # (``jit_grow_tree``) and its ops by the ``lgbm.*`` scopes below.
-@functools.partial(jax.jit, static_argnames=("num_bins", "max_leaves"))
+@functools.partial(jax.jit,
+                   static_argnames=("num_bins", "max_leaves", "axis"))
 def grow_tree(
     bins_T: jax.Array,  # [F, n] feature-major binned matrix
     grad: jax.Array,  # [n] f32
@@ -149,8 +203,10 @@ def grow_tree(
     params: TreeLearnerParams,
     num_bins: int,
     max_leaves: int,
+    axis: str | None = None,  # the row axis of a data-parallel mesh
 ) -> Tuple[Tree, jax.Array]:
-    """Grow one tree; returns (tree, final leaf_id per row)."""
+    """Grow one tree; returns (tree, final leaf_id per row).  Under
+    ``axis`` the arrays are this chip's shard (the module's docstring)."""
     # Python here runs once per TRACE: counts grow-program retraces
     telemetry.count("grow_traces")
     assert grad.dtype == jnp.float32, grad.dtype
@@ -171,7 +227,14 @@ def grow_tree(
         Fc, NC = feature_chunk(Fp, Bp)
         meta = _pack_meta(
             feature_mask, num_bins_per_feature, is_categorical, NC * Fc)
-        sum_g0, sum_h0, cnt0 = tables.root_sums(grad, hess, bag_mask)
+        if axis is None:
+            sum_g0, sum_h0, cnt0 = tables.root_sums(grad, hess, bag_mask)
+            count0 = node_cnt = None
+        else:
+            hist0, count0 = exchange(hist0, axis)
+            sum_g0, sum_h0 = root_totals(grad, hess, bag_mask, axis)
+            cnt0 = count0.astype(jnp.float32)
+            node_cnt = jnp.zeros(L - 1, jnp.int32)
         # the once-a-tree root search reads the canonical view
         root_best = default_search_fn(
             hist0[:F, :3, :num_bins].transpose(0, 2, 1),
@@ -180,7 +243,7 @@ def grow_tree(
             feature_mask, num_bins_per_feature, is_categorical, params,
         )
         best_mat, pos_mat, tree_i, tree_f = tables.root_tables(
-            root_best, hist0.dtype, L, n)
+            root_best, hist0.dtype, L, n, count0)
         state = _State(
             rec=build_record(
                 bins_T, grad, hess, bag_mask, round_up(n, T) + cap),
@@ -190,6 +253,7 @@ def grow_tree(
             tree_i=tree_i,
             tree_f=tree_f,
             nleaves=jnp.int32(1),
+            node_cnt=node_cnt,
         )
 
     @phase_scope("grow.book")
@@ -214,18 +278,41 @@ def grow_tree(
         # updates it in place (a materialized window + go vector forced
         # a full-record copy per split, ~1 s/tree at 10M rows)
         live_tiles = -(-c.pcnt // T)
-        hists, comp, nleft, res, cl, cr, rec_pass = split_step_window(
-            state.hists, state.rec, c.begin, c.pcnt, do_split,
-            c.f, c.thr, c.is_cat, best_leaf, new_leaf,
-            scal_f, meta, F=F, cap=cap, k=k, fgroup=FGROUP,
-            interpret=interpret, live_tiles=live_tiles,
-        )
+        if axis is None:
+            hists, comp, nleft, res, cl, cr, rec_pass = split_step_window(
+                state.hists, state.rec, c.begin, c.pcnt, do_split,
+                c.f, c.thr, c.is_cat, best_leaf, new_leaf,
+                scal_f, meta, F=F, cap=cap, k=k, fgroup=FGROUP,
+                interpret=interpret, live_tiles=live_tiles,
+            )
+        else:
+            h_small, comp, nleft, cl, cr, rec_pass, _ = split_hist_counted(
+                state.rec, c.begin, c.pcnt, do_split, c.f, c.thr,
+                c.is_cat, scal_f, F=F, cap=cap, k=k, Fp=Fp, Bp=Bp,
+                fgroup=FGROUP, interpret=interpret, live_tiles=live_tiles,
+            )
+            h_small, small = exchange(h_small, axis)
+            hists, res = split_search(
+                state.hists, h_small, best_leaf, new_leaf, do_split,
+                scal_f, meta, interpret=interpret)
         rec = place_runs(
             rec_pass, comp, (cl, cr), c.begin, c.pcnt, nleft, do_split,
             best_leaf, new_leaf, cap=cap, leaf_row=num_words(F, k) + 4,
             interpret=interpret, live_tiles=live_tiles,
         )
         nright = c.pcnt - nleft
+        node_cnt = state.node_cnt
+        if axis is None:
+            left_rows, right_rows = nleft, nright
+        else:
+            # the children's rows over every chip, from the smaller one's
+            # (the kernel's own choice) and the parent's, kept in the gate
+            # row: exact in int32 at any height
+            left_rows = jnp.where(c.lc <= c.rc, small, c.gate - small)
+            right_rows = c.gate - left_rows
+            node_cnt = jax.lax.dynamic_update_slice(
+                node_cnt, jnp.where(do_split, c.gate, node_cnt[step])[None],
+                (step,))
         # the search results come out of the kernel ALREADY in the
         # best_mat row layout -- no unpack/repack
         dt = c.bcol.dtype
@@ -233,12 +320,13 @@ def grow_tree(
             state.best_mat, state.pos_mat, state.tree_i, state.tree_f, c,
             step, best_leaf, new_leaf, do_split,
             res[0, :11].astype(dt), res[1, :11].astype(dt),
-            nleft, nright, nleft, nright,
+            nleft, nright, left_rows, right_rows,
         )
         return _State(
             rec=rec, pos_mat=pos_mat, hists=hists, best_mat=best_mat,
             tree_i=tree_i, tree_f=tree_f,
             nleaves=state.nleaves + do_split.astype(jnp.int32),
+            node_cnt=node_cnt,
         )
 
     def body(step, state):
@@ -251,5 +339,8 @@ def grow_tree(
     with phase_scope("grow.unpack"):
         tree = tables.unpack_tree(
             state.nleaves, state.best_mat, state.tree_i, state.tree_f, L)
+        if axis is not None:
+            tree = tree._replace(internal_count=state.node_cnt,
+                                 leaf_count=state.pos_mat[2])
         leaf_id = tables.leaf_ids_from_record(state.rec, F, k, n)
     return tree, leaf_id

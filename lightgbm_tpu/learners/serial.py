@@ -68,25 +68,42 @@ from .tables import _BROWS, _sr_row
 TIER_SPACING = 2
 
 
-# leaf_count/internal_count ride the histogram count channel, which is
-# float32 under the default hist_dtype: integers are exact in float32
-# only up to 2**24, so a single leaf holding more than ~16.7M rows
-# would silently round its count (and the min_data_in_leaf comparisons
-# on it).  Row count bounds every leaf count, so the envelope is
-# checked once per reset_training_data against n (ADVICE r5).
+# A histogram's count channel is float32 under the default hist_dtype:
+# integers are exact in float32 only up to 2**24, so a histogram over more
+# rows than that could round a bin's count (and the leaf counts and the
+# min_data_in_leaf comparisons read from it).  What bounds a histogram is
+# the rows one device sums: all of them on one device, one shard's where
+# the fused grower deals rows to the chips of a mesh (models/gbdt.py
+# _count_shards), so the envelope is checked once per
+# reset_training_data against the rows of a shard.
 F32_COUNT_EXACT_ROWS = 1 << 24
 
 
-def check_count_envelope(num_rows: int, hist_dtype: str) -> None:
-    """Reject datasets whose row count can overflow the float32
-    integer-exact range in the count channel."""
-    if hist_dtype == "float32" and num_rows > F32_COUNT_EXACT_ROWS:
+def check_count_envelope(num_rows: int, hist_dtype: str,
+                         shards: int = 1) -> None:
+    """Reject a table whose rows a shard can overflow the float32
+    integer-exact range of the histogram count channel.
+
+    Per shard, not per table, because that is all any one histogram
+    holds where the rows are dealt to ``shards`` chips and summed by the
+    data-parallel fused grower (learners/fused.py): each chip's channel
+    counts at most ``ceil(num_rows / shards)`` rows, exactly, and the
+    TREE's counts are summed over the chips in int32, exact at any
+    height.  The search reads the summed float32 channel, which may round
+    a count past 2**24, and that is enough: every comparison that can
+    fail on a count (``min_data_in_leaf``, the smaller-child choice)
+    holds a count below 2**24 on one side, which float32 holds exactly,
+    and every chip reads the same sums and decides alike."""
+    per_shard = -(-int(num_rows) // max(int(shards), 1))
+    if hist_dtype == "float32" and per_shard > F32_COUNT_EXACT_ROWS:
+        where = (f"num_data={num_rows} over {shards} shards is {per_shard} "
+                 "rows a shard" if shards > 1 else f"num_data={num_rows}")
         raise ValueError(
-            f"num_data={num_rows} exceeds the float32 integer-exact "
-            f"envelope ({F32_COUNT_EXACT_ROWS} = 2**24) for the "
-            "histogram count channel: leaf_count/internal_count could "
-            "round silently.  Set hist_dtype=float64 (the reference's "
-            "double accumulation) for datasets this large.")
+            f"{where}, past the float32 integer-exact envelope "
+            f"({F32_COUNT_EXACT_ROWS} = 2**24) of the histogram count "
+            "channel: its counts could round silently.  Deal the rows to "
+            "more chips (tree_learner=data on the fused grower), or set "
+            "hist_dtype=float64 (the reference's double accumulation).")
 
 
 class TreeLearnerParams(NamedTuple):

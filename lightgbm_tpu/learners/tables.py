@@ -74,9 +74,13 @@ def root_sums(grad, hess, bag_mask):
     return sum_g0, sum_h0, jnp.sum(bag_mask)
 
 
-def root_tables(root_best: SplitResult, acc_dt, L: int, n: int):
+def root_tables(root_best: SplitResult, acc_dt, L: int, n: int,
+                gate=None):
     """(best_mat, pos_mat, tree_i, tree_f) of a tree that is its root:
-    leaf 0 holds every row and ``root_best``."""
+    leaf 0 holds every row and ``root_best``.  ``gate`` is the root's
+    entry in pos_mat's third row, ``n`` where None (the data-parallel
+    fused grower keeps there a leaf's bagged row count over every chip,
+    in int32: learners/fused.py)."""
     best_mat = (
         jnp.zeros((_BROWS, L), acc_dt)
         .at[_BG].set(K_MIN_SCORE)
@@ -86,7 +90,8 @@ def root_tables(root_best: SplitResult, acc_dt, L: int, n: int):
     best_mat = jax.lax.dynamic_update_slice(
         best_mat, _sr_row(root_best, acc_dt)[:, None], (0, 0))
     # root gate: every shard's padded local row count is the same n
-    pos_mat = jnp.zeros((3, L), jnp.int32).at[1, 0].set(n).at[2, 0].set(n)
+    pos_mat = jnp.zeros((3, L), jnp.int32).at[1, 0].set(n).at[2, 0].set(
+        n if gate is None else gate)
     tree_i = jnp.zeros((5, L), jnp.int32).at[0].set(-1)
     tree_f = jnp.zeros((3, L), jnp.float32)
     return best_mat, pos_mat, tree_i, tree_f

@@ -174,7 +174,6 @@ class GBDT:
         self.train_set = train_set
         self.objective = objective
         n = train_set.num_data
-        check_count_envelope(n, self.config.hist_dtype)
         self.num_data = n
         self.max_feature_idx = train_set.num_total_features - 1
         self.feature_names = list(train_set.feature_names)
@@ -190,10 +189,15 @@ class GBDT:
         with telemetry.span("lgbm.setup.booster.learner"):
             self._learner_params = TreeLearnerParams.from_config(self.config)
             self._grower = self.select_grower()
+            check_count_envelope(n, self.config.hist_dtype,
+                                 self._count_shards())
             self._grow = self._create_tree_learner()
         # host transpose plus device_put, none waited for: host wall
-        # time like every span
-        with telemetry.span("lgbm.setup.booster.upload"):
+        # time like every span; ``.shard`` where the rows go to the
+        # shards of a mesh
+        with telemetry.span("lgbm.setup.booster.upload"
+                            if self._row_sharding is None
+                            else "lgbm.setup.booster.shard"):
             self._nbpf = jnp.asarray(train_set.num_bins_per_feature)
             self._is_cat = jnp.asarray(train_set.is_categorical)
             self._real_feat = train_set.real_feature_indices
@@ -259,9 +263,12 @@ class GBDT:
     def select_grower(self, row_mask: bool = False):
         """The ONE place that chooses between the two leaf-wise growers,
         from what it can observe.  Returns ``(which, why)``: ``"fused"``
-        (learners/fused.py: serial float32 training on a TPU chip) or
-        ``"canonical"`` (learners/serial.py: everything else) with the
-        first condition that ruled the fused one out.  The raw
+        (learners/fused.py: float32 training on a TPU chip, or with
+        ``tree_learner=data`` on the chips of one process, rows sharded
+        over them: ``_mesh_devices``) or ``"canonical"``
+        (learners/serial.py: everything else, the feature- and
+        voting-parallel learners and more than one process among it)
+        with the first condition that ruled the fused one out.  The raw
         ``[Fp, 4, Bp]`` histogram layout exists only inside the fused
         grower, so ``histogram_pool_size`` selects the canonical one.
         (learners/fused.py, and Pallas with it, is imported where it is
@@ -271,9 +278,12 @@ class GBDT:
         if not on_tpu():
             why = f"platform={device_platform()}"
         elif jax.process_count() > 1 or not (
-                cfg.tree_learner == "serial" or len(jax.devices()) == 1):
+                cfg.tree_learner in ("serial", "data")
+                or len(jax.devices()) == 1):
             why = (f"tree_learner={cfg.tree_learner} over "
-                   f"{jax.device_count()} devices")
+                   f"{jax.device_count()} devices"
+                   + (f" of {jax.process_count()} processes"
+                      if jax.process_count() > 1 else ""))
         elif cfg.tree_growth != "leafwise":
             why = f"tree_growth={cfg.tree_growth}"
         elif not self._use_pallas_hist():
@@ -291,27 +301,50 @@ class GBDT:
                    "blocks past the chip's VMEM")
         return "canonical", why
 
+    def _mesh_devices(self) -> int:
+        """The devices a mesh learner of this process spreads over: every
+        local device, or ``num_machines`` of them where that is set."""
+        nd = len(jax.devices())
+        if self.config.num_machines > 1:
+            nd = min(nd, self.config.num_machines)
+        return nd
+
+    def _count_shards(self) -> int:
+        """How many shards the rows are dealt to where every histogram's
+        count channel holds one shard's rows: the fused grower's chips
+        under ``tree_learner=data`` (learners/fused.py sums the TREE's
+        counts in int32), else 1."""
+        if (self._grower[0] == "fused" and self.config.tree_learner == "data"
+                and self._mesh_devices() > 1):
+            return self._mesh_devices()
+        return 1
+
+    def _count_fused_plan(self) -> None:
+        """What the fused grower's kernels walk, once a booster
+        (obs/telemetry)."""
+        from ..ops import record
+
+        plan = self._chunking
+        telemetry.count_many({
+            "grow.feature_chunks": plan.feature_chunks,
+            "grow.chunk_features": plan.chunk_features,
+            "grow.hist_block_bytes": plan.hist_block_bytes,
+            "grow.record_words": plan.record_words,
+            "grow.place_steps_per_tile": record.PLACE_STEPS_PER_TILE,
+            "grow.place_launches_per_split":
+                record.PLACE_LAUNCHES_PER_SPLIT,
+            "grow.onehot_planes": plan.onehot_planes,
+            "grow.categorical_features": int(
+                self.train_set.is_categorical.sum()),
+        })
+
     def _serial_leafwise_grower(self):
         """The grow callable of ``self._grower`` for serial leaf-wise
         growth."""
         if self._grower[0] == "fused":
             from ..learners import fused
-            from ..ops import record
 
-            # what the kernels walk, once a booster (obs/telemetry)
-            plan = self._chunking
-            telemetry.count_many({
-                "grow.feature_chunks": plan.feature_chunks,
-                "grow.chunk_features": plan.chunk_features,
-                "grow.hist_block_bytes": plan.hist_block_bytes,
-                "grow.record_words": plan.record_words,
-                "grow.place_steps_per_tile": record.PLACE_STEPS_PER_TILE,
-                "grow.place_launches_per_split":
-                    record.PLACE_LAUNCHES_PER_SPLIT,
-                "grow.onehot_planes": plan.onehot_planes,
-                "grow.categorical_features": int(
-                    self.train_set.is_categorical.sum()),
-            })
+            self._count_fused_plan()
             return functools.partial(
                 fused.grow_tree,
                 num_bins=self._num_bins,
@@ -401,9 +434,7 @@ class GBDT:
         )
         from ..parallel.mesh import ROW_AXIS
 
-        nd = len(jax.devices())
-        if self.config.num_machines > 1:
-            nd = min(nd, self.config.num_machines)
+        nd = self._mesh_devices()
         mesh = data_mesh(num_devices=nd)
         if tl == "feature":
             # every device holds all rows and searches a feature shard
@@ -433,6 +464,19 @@ class GBDT:
                 hist_pool=self._hist_pool_slots(),
             )
         self._set_placement(mesh, ROW_AXIS, nd)
+        if self._grower[0] == "fused":
+            from ..parallel.data_parallel import (
+                make_fused_data_parallel_grower)
+
+            self._count_fused_plan()
+            telemetry.count_many({
+                "dp.shards": nd,
+                "dp.rows_per_shard": -(-self.train_set.num_data // nd),
+                "dp.exchange_bytes_per_split": self._exchange_bytes(),
+                "dp.collectives_per_split": 1,
+            })
+            return make_fused_data_parallel_grower(
+                mesh, num_bins=self._num_bins, max_leaves=self.max_leaves)
         if tl == "voting":
             return make_voting_parallel_grower(
                 mesh,
@@ -450,6 +494,13 @@ class GBDT:
             sorted_hist=self._use_pallas_hist(),
             hist_pool=self._hist_pool_slots(),
         )
+
+    def _exchange_bytes(self) -> int:
+        """Bytes of the one block the fused data-parallel grower sums over
+        the chips a split: the smaller child's ``[NC * Fc, 4, Bp]``
+        float32 histogram (learners/fused.py ``exchange``)."""
+        plan = self._chunking
+        return plan.feature_chunks * plan.hist_block_bytes
 
     def _set_placement(self, mesh, row_axis, row_shards: int) -> None:
         """Record how a mesh learner's operands lie over ``mesh``: rows
@@ -509,6 +560,11 @@ class GBDT:
             part = (f"packed record, {self._chunking.said}, placement "
                     f"{record.PLACE_STEPS_PER_TILE} step a tile in "
                     f"{record.PLACE_LAUNCHES_PER_SPLIT} launch a split")
+            if not serial:
+                nd = self._learner_devices
+                part += (f", rows over {nd} devices "
+                         f"({-(-self.num_data // nd)} a shard), one all-reduce"
+                         f" of {self._exchange_bytes()} B a split")
         else:
             hist = "pallas" if self._use_pallas_hist() else "segment-sum"
             search = ("pallas" if on_tpu() and not self._use_f64_hist
@@ -647,7 +703,8 @@ class GBDT:
         grower for it: the child-choice criterion switches to masked
         counts (choice_by_mask_counts in learners/serial.py explains why
         positional counts would break the subset-parity contract)."""
-        if not (self._grower[0] == "fused"
+        if self._learner_devices > 1 or not (
+                self._grower[0] == "fused"
                 or getattr(self._grow, "func", None) is grow_tree):
             raise ValueError(
                 "set_base_row_mask requires the serial leaf-wise tree "

@@ -11,6 +11,7 @@ Python fallback, so the framework works without a toolchain.
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 from typing import List, Optional, Tuple
@@ -31,24 +32,50 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
+def _fresh() -> bool:
+    """Is the built library there and no older than its source?"""
+    return os.path.exists(_LIB_PATH) and not (
+        os.path.exists(_SRC)
+        and os.path.getmtime(_SRC) > os.path.getmtime(_LIB_PATH))
+
+
 def _build() -> bool:
+    """Build the library where it is missing or stale.  Several processes
+    (a test run's workers, in a fresh checkout) can find it missing at
+    once: they take turns on a lock file beside it, each looks again
+    once it holds the lock, and a build writes a file of its own and
+    renames it into place, so no process loads a library another is
+    still writing (one that did fell back to Python for its life)."""
     if not os.path.exists(_SRC):
         return False
     os.makedirs(_LIB_DIR, exist_ok=True)
-    # the Makefile is the single source of truth for compile flags
-    makefile_dir = os.path.dirname(_SRC)
-    if os.path.exists(os.path.join(makefile_dir, "Makefile")):
-        cmd = ["make", "-C", makefile_dir, "--always-make"]
-    else:
-        cmd = ["g++", "-O3", "-std=c++17", "-Wall", "-fPIC", "-fopenmp",
-               "-shared", "-o", _LIB_PATH, _SRC]
+    # a lock file, never written: closing it lets the next process in
+    lock = os.open(os.path.join(_LIB_DIR, ".build.lock"),
+                   os.O_CREAT | os.O_RDWR, 0o644)
     try:
-        proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
-    except (OSError, subprocess.TimeoutExpired):
-        return False
-    if proc.returncode != 0 or not os.path.exists(_LIB_PATH):
-        Log.warning(f"native build failed, using python IO: {proc.stderr[:500]}")
-        return False
+        fcntl.flock(lock, fcntl.LOCK_EX)
+        if _fresh():
+            return True
+        tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+        # the Makefile is the single source of truth for compile flags
+        makefile_dir = os.path.dirname(_SRC)
+        if os.path.exists(os.path.join(makefile_dir, "Makefile")):
+            cmd = ["make", "-C", makefile_dir, "--always-make", f"OUT={tmp}"]
+        else:
+            cmd = ["g++", "-O3", "-std=c++17", "-Wall", "-fPIC", "-fopenmp",
+                   "-shared", "-o", tmp, _SRC]
+        try:
+            proc = subprocess.run(cmd, capture_output=True, text=True,
+                                  timeout=120)
+        except (OSError, subprocess.TimeoutExpired):
+            return False
+        if proc.returncode != 0 or not os.path.exists(tmp):
+            Log.warning(
+                f"native build failed, using python IO: {proc.stderr[:500]}")
+            return False
+        os.replace(tmp, _LIB_PATH)
+    finally:
+        os.close(lock)
     return True
 
 
@@ -60,12 +87,8 @@ def _load() -> Optional[ctypes.CDLL]:
         _tried = True
         if os.environ.get("LIGHTGBM_TPU_NO_NATIVE"):
             return None
-        if not os.path.exists(_LIB_PATH) or (
-            os.path.exists(_SRC)
-            and os.path.getmtime(_SRC) > os.path.getmtime(_LIB_PATH)
-        ):
-            if not _build():
-                return None
+        if not _fresh() and not _build():
+            return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
         except OSError as e:

@@ -98,6 +98,10 @@ SCOPES = (
      "tree_i, tree_f column updates (learners/tables.py), pool "
      "bookkeeping"),
     ("lgbm.grow.unpack", "grow_tree after the loop: Tree unpack, leaf_id"),
+    ("lgbm.grow.exchange", "the data-parallel fused grower's collectives "
+     "(learners/fused.py exchange, ops/totals.py): the psum of the root's "
+     "and of every split's smaller-child [Fp, 4, Bp] histogram, the "
+     "root totals' pmax and psums; none on one device"),
 )
 SCOPE_NAMES = tuple(s for s, _ in SCOPES)
 UNATTRIBUTED = "unattributed"

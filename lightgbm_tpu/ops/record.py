@@ -37,6 +37,13 @@ Who calls what:
     tile count as an OPERAND (a dynamic Mosaic grid), so one compiled
     body serves every leaf size and no ``lax.cond`` stands round them.
 
+    The DATA-PARALLEL fused grower (learners/fused.py under a mesh) cuts
+    that split step in two at the child's histogram, which it sums over
+    the chips between them: ``split_hist_counted`` (the same kernel with
+    ``exchange``: the tile steps, then the histogram out) and
+    ``split_search`` (_split_search_kernel: the subtraction, search and
+    row writes of the split step's tail, ``lgbm.split_step.search``).
+
     Where the smaller child's histogram comes from: the compaction has
     already laid that child's rows of a tile side by side, so each tile
     step appends them to a [W, 2*TILE] staging buffer in VMEM
@@ -624,9 +631,41 @@ def _split_tile(tile, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
     fill_ref[0] = end
 
 
+def _subtract_and_search(c, rows, parent, h_small, small_left_b, do_split,
+                         hists_out_ref, stash_ref, scal_f_ref, meta_ref,
+                         res_ref, best_ref, *, Fc, Bp):
+    """Search step ``c`` of a split (``rows``: its chunk's accumulator
+    rows, _chunk_rows): feature chunk ``c`` of the larger child by
+    subtraction from the parent's block, the left child's block written,
+    the right's stashed for the write steps, and both searched on the
+    chunk (pallas_search._child_search keeps the best across chunks).
+    The tail of _split_step_kernel and the search steps of
+    _split_search_kernel."""
+    from .pallas_search import (
+        K_EPSILON, _child_search, _head_of, _tail_of, _tri)
+
+    h_large = parent - h_small
+    h_left = jnp.where(small_left_b, h_small, h_large)
+    h_right = jnp.where(small_left_b, h_large, h_small)
+    hists_out_ref[0] = jnp.where(do_split, h_left, parent)
+    stash_ref[rows] = h_right  # stash for the write steps
+
+    B = Bp
+    tri = _tri(B)
+    for cc in range(2):
+        side = (h_left, h_right)[cc]
+        hg, hh, hc = side[:, 0, :], side[:, 1, :], side[:, 2, :]
+        _child_search(
+            cc, hg, hh, hc,
+            _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
+            _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
+            scal_f_ref, meta_ref, res_ref, Fc, B, c * Fc, best_ref,
+        )
+
+
 def _split_step_kernel(
     scal_i_ref, scal_f_ref, *refs,
-    W, F, k, Bp, Fc, NC, fgroup=8, direct_read=False,
+    W, F, k, Bp, Fc, NC, fgroup=8, direct_read=False, exchange=False,
 ):
     """The WHOLE split step in one launch: per-tile compaction and
     staging of the smaller child's rows, its histogram over full tiles
@@ -704,11 +743,23 @@ def _split_step_kernel(
                  (< T between steps), and the histogram tiles run
     best_ref   : SMEM scratch [2] f32, last — each child's best raw
                  gain over the chunks searched so far
-    """
-    from .pallas_search import (
-        K_EPSILON, _child_search, _head_of, _tail_of, _tri)
 
-    if direct_read:
+    With ``exchange`` (the data-parallel grower: the child's histogram is
+    summed over the chips before anything subtracts from it) the launch
+    stops at the histogram: its ``NC`` tail steps write the smaller
+    child's local histogram, a chunk a step, to ``hsmall_ref`` in place
+    of ``hrow_ref``, ``meta_ref``, ``hists_out_ref``, ``res_ref`` and
+    ``best_ref``, and _split_search_kernel does the rest.
+    """
+    if exchange:
+        hrow_ref = meta_ref = hists_out_ref = res_ref = best_ref = None
+        if direct_read:
+            (rec_ref, hsmall_ref, comp_ref, cnt_ref, rec_out_ref, hacc_ref,
+             hhi_ref, hlo_ref, stage_ref, fill_ref, prev_ref) = refs
+        else:
+            (win_ref, hsmall_ref, comp_ref, cnt_ref, hacc_ref, hhi_ref,
+             hlo_ref, stage_ref, fill_ref) = refs
+    elif direct_read:
         (rec_ref, hrow_ref, meta_ref, hists_out_ref,
          comp_ref, res_ref, cnt_ref, rec_out_ref, hacc_ref,
          hhi_ref, hlo_ref, stage_ref, fill_ref, prev_ref, best_ref) = refs
@@ -779,7 +830,8 @@ def _split_step_kernel(
             # intermediate writeback (interpret mode flushes every
             # step) is an identity write, never garbage over a row the
             # search still needs
-            hists_out_ref[0] = hrow_ref[0]
+            if not exchange:
+                hists_out_ref[0] = hrow_ref[0]
             _split_tile(win_ref[...], *tile_args, i, *tile_refs, F=F, k=k)
 
     # The histogram body's ONE call: on a full tile of staged rows as
@@ -816,29 +868,24 @@ def _split_step_kernel(
     def _():
         _fold_hacc(*sums, Fc, NC)
 
+    if exchange:
+        # the child's histogram leaves, a chunk a step, for the exchange
+        @pl.when(i >= search_step)
+        def _():
+            rows = _chunk_rows(i - search_step, Fc)
+            hsmall_ref[...] = hhi_ref[rows] + hlo_ref[rows]
+        return
+
     @pl.when((i >= search_step) & (i < write_step))
     def _():
         c = i - search_step
         rows = _chunk_rows(c, Fc)
         parent = hrow_ref[0]  # [Fc, 4, Bp]
         h_small = hhi_ref[rows] + hlo_ref[rows]
-        h_large = parent - h_small
-        h_left = jnp.where(small_left_b, h_small, h_large)
-        h_right = jnp.where(small_left_b, h_large, h_small)
-        hists_out_ref[0] = jnp.where(do_split, h_left, parent)
-        hacc_ref[rows] = h_right  # stash for the write steps
-
-        B = Bp
-        tri = _tri(B)
-        for cc in range(2):
-            side = (h_left, h_right)[cc]
-            hg, hh, hc = side[:, 0, :], side[:, 1, :], side[:, 2, :]
-            _child_search(
-                cc, hg, hh, hc,
-                _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
-                _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
-                scal_f_ref, meta_ref, res_ref, Fc, B, c * Fc, best_ref,
-            )
+        _subtract_and_search(
+            c, rows, parent, h_small, small_left_b, do_split,
+            hists_out_ref, hacc_ref, scal_f_ref, meta_ref, res_ref,
+            best_ref, Fc=Fc, Bp=Bp)
 
     @pl.when(i >= write_step)
     def _():
@@ -1245,6 +1292,20 @@ def split_step_counted(
     ``vmem_limit_bytes`` at every width (a compile parameter derived
     from the block sizes).
     """
+    return _split_step_call(
+        hists, rec, begin, pcnt, do_split, f, thr, is_cat, parent_slot,
+        new_slot, scal_f, meta, F=F, cap=cap, k=k, fgroup=fgroup,
+        interpret=interpret, live_tiles=live_tiles)
+
+
+def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
+                     parent_slot, new_slot, scal_f, meta, *, F, cap, k,
+                     fgroup, interpret, live_tiles, exchange=False,
+                     Fp=None, Bp=None):
+    """The split step's launch (``split_step_counted``); with
+    ``exchange`` the launch that stops at the smaller child's histogram
+    (``split_hist_counted``: ``hists`` and ``meta`` are None, ``Fp`` and
+    ``Bp`` say the histogram's shape)."""
     from .pallas_histogram import feature_chunk
 
     W, n_pad = rec.shape
@@ -1253,9 +1314,10 @@ def split_step_counted(
     assert n_pad % T == 0, (n_pad, T)
     nt = cap // T
     nblocks = n_pad // T
-    P, Fp, _, Bp = hists.shape
+    if not exchange:
+        P, Fp, _, Bp = hists.shape
     Fc, NC = feature_chunk(Fp, Bp)
-    if meta.shape[0] < NC * Fc:
+    if not exchange and meta.shape[0] < NC * Fc:
         # whole chunks of meta: a block past its edge would read
         # padding where a zero feature mask must stand
         meta = jnp.pad(meta, ((0, NC * Fc - meta.shape[0]), (0, 0)))
@@ -1305,6 +1367,58 @@ def split_step_counted(
         return (jnp.where(i < si[10] + off + NC, searched, si[2]),
                 _chunk_idx(i, si), 0, 0)
 
+    tile_specs = [
+        pl.BlockSpec((1, W, 2 * T),
+                     lambda i, si, sf: (_tile_idx(i, si), 0, 0)),
+        # counts ride the LANE axis: a (1, 128) block on [1, nt*128] is
+        # Mosaic-legal (major dim == array dim), a [nt, 128] row-per-tile
+        # layout is not (sublane dim 1)
+        pl.BlockSpec((1, 128), lambda i, si, sf: (0, _tile_idx(i, si))),
+    ]
+    # aliased identity pass-through of the record (same block walk as the
+    # input view): the output VALUE feeds place_runs so every link of the
+    # record chain is single-use — see the kernel docstring's copy note
+    rec_specs = [pl.BlockSpec((W, T), _rec_idx)] if direct_read else []
+    scratch = [pltpu.VMEM((NC * Fc, 4, Bp), jnp.float32)] * 3 + [
+        pltpu.VMEM((W, 2 * T), jnp.int32),  # staged child rows
+        pltpu.SMEM((2,), jnp.int32),  # their count, the tiles run
+    ] + ([pltpu.VMEM((W, T), jnp.int32)] if direct_read else [])
+    tile_shapes = [jax.ShapeDtypeStruct((nt, W, 2 * T), jnp.int32),
+                   jax.ShapeDtypeStruct((1, nt * 128), jnp.int32)]
+    rec_shapes = ([jax.ShapeDtypeStruct((W, n_pad), jnp.int32)]
+                  if direct_read else [])
+    params = pltpu.CompilerParams(vmem_limit_bytes=max(
+        VMEM_DEFAULT_BYTES, split_step_vmem_bytes(Fp, Bp, W)))
+    kernel = functools.partial(
+        _split_step_kernel, W=W, F=F, k=k, Bp=Bp, Fc=Fc, NC=NC,
+        fgroup=fgroup, direct_read=direct_read, exchange=exchange)
+    if exchange:
+        # the tile steps, then one step a chunk that writes the child's
+        # histogram: no hists row, no search
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(live + NC + off,),
+            in_specs=data_specs,
+            out_specs=[pl.BlockSpec(
+                (Fc, 4, Bp), lambda i, si, sf: (_chunk_idx(i, si), 0, 0))]
+            + tile_specs + rec_specs,
+            scratch_shapes=scratch,
+        )
+        with phase_scope("split_step.dyn"):
+            outs = pl.pallas_call(
+                kernel, grid_spec=grid_spec,
+                out_shape=[jax.ShapeDtypeStruct((NC * Fc, 4, Bp),
+                                                jnp.float32)]
+                + tile_shapes + rec_shapes,
+                input_output_aliases={2: 3} if direct_read else {},
+                compiler_params=params, interpret=interpret,
+            )(scal_i, scal_f, *data_in)
+        h_small, comp, cnt = outs[:3]
+        rec_pass = outs[3] if direct_read else rec
+        cl, cr, nleft = _tile_counts(cnt, pcnt, live, nt)
+        return (h_small, comp, nleft, cl, cr, rec_pass,
+                _hist_tiles(cnt, live))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         # a DYNAMIC bound: see the docstring
@@ -1317,46 +1431,26 @@ def split_step_counted(
         out_specs=[
             pl.BlockSpec((1, Fc, 4, Bp),
                          lambda i, si, sf: _hists_idx(i, si, si[1])),
-            pl.BlockSpec((1, W, 2 * T),
-                         lambda i, si, sf: (_tile_idx(i, si), 0, 0)),
+            tile_specs[0],
             pl.BlockSpec((2, 16), lambda i, si, sf: (0, 0)),
-            # counts ride the LANE axis: a (1, 128) block on [1, nt*128]
-            # is Mosaic-legal (major dim == array dim), a [nt, 128]
-            # row-per-tile layout is not (sublane dim 1)
-            pl.BlockSpec((1, 128),
-                         lambda i, si, sf: (0, _tile_idx(i, si))),
-        ] + ([
-            # aliased identity pass-through of the record (same block
-            # walk as the input view): the output VALUE feeds
-            # place_runs so every link of the record chain is
-            # single-use — see the kernel docstring's copy note
-            pl.BlockSpec((W, T), _rec_idx),
-        ] if direct_read else []),
-        scratch_shapes=[pltpu.VMEM((NC * Fc, 4, Bp), jnp.float32)] * 3 + [
-            pltpu.VMEM((W, 2 * T), jnp.int32),  # staged child rows
-            pltpu.SMEM((2,), jnp.int32),  # their count, the tiles run
-        ] + ([pltpu.VMEM((W, T), jnp.int32)] if direct_read else []) + [
-            # each child's best raw gain over the chunks searched
-            pltpu.SMEM((2,), jnp.float32)],
+            tile_specs[1],
+        ] + rec_specs,
+        # each child's best raw gain over the chunks searched, last
+        scratch_shapes=scratch + [pltpu.SMEM((2,), jnp.float32)],
     )
-    params = pltpu.CompilerParams(vmem_limit_bytes=max(
-        VMEM_DEFAULT_BYTES, split_step_vmem_bytes(Fp, Bp, W)))
     hists_idx = 2 + len(data_in)  # incl. the 2 prefetch args
     out_shape = [
         jax.ShapeDtypeStruct((P, Fp, 4, Bp), jnp.float32),
-        jax.ShapeDtypeStruct((nt, W, 2 * T), jnp.int32),
+        tile_shapes[0],
         jax.ShapeDtypeStruct((2, 16), jnp.float32),
-        jax.ShapeDtypeStruct((1, nt * 128), jnp.int32),
-    ]
+        tile_shapes[1],
+    ] + rec_shapes
     aliases = {hists_idx: 0}
     if direct_read:
-        out_shape.append(jax.ShapeDtypeStruct((W, n_pad), jnp.int32))
         aliases[2] = 4  # recA -> rec pass-through
     with phase_scope("split_step.dyn"):
         outs = pl.pallas_call(
-            functools.partial(
-                _split_step_kernel, W=W, F=F, k=k, Bp=Bp, Fc=Fc, NC=NC,
-                fgroup=fgroup, direct_read=direct_read),
+            kernel,
             grid_spec=grid_spec,
             out_shape=out_shape,
             input_output_aliases=aliases,
@@ -1372,6 +1466,130 @@ def split_step_counted(
     cl, cr, nleft = _tile_counts(cnt, pcnt, live, nt)
     return (hists_new, comp, nleft, res, cl, cr, rec_pass,
             _hist_tiles(cnt, live))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("F", "cap", "k", "Fp", "Bp", "fgroup",
+                              "interpret"))
+@phase_scope("split_step")
+def split_hist_counted(
+    rec, begin, pcnt, do_split, f, thr, is_cat, scal_f,
+    F: int, cap: int, k: int, Fp: int, Bp: int,
+    fgroup: int = 8,
+    interpret: bool = False,
+    live_tiles=None,
+):
+    """The first half of ``split_step_counted`` for the data-parallel
+    grower: the compaction of this chip's window of the parent and this
+    chip's histogram of the smaller child, which the search's own counts
+    in ``scal_f`` choose (so every chip sums the same child).  Returns
+    ``(h_small [NC * Fc, 4, Bp], comp, nleft, cl, cr, rec_pass,
+    hist_tiles)``; the caller sums ``h_small`` over the chips and hands
+    it to ``split_search``.  One launch, under the split step's own name
+    (``lgbm.split_step.dyn``)."""
+    return _split_step_call(
+        None, rec, begin, pcnt, do_split, f, thr, is_cat, 0, 0, scal_f,
+        None, F=F, cap=cap, k=k, fgroup=fgroup, interpret=interpret,
+        live_tiles=live_tiles, exchange=True, Fp=Fp, Bp=Bp)
+
+
+def _split_search_kernel(scal_i_ref, scal_f_ref, hrow_ref, hsmall_ref,
+                         meta_ref, hists_out_ref, res_ref, stash_ref,
+                         best_ref, *, Fc, NC, Bp):
+    """The second half of the split step (_split_step_kernel's tail) on a
+    child's histogram summed over the chips: ``NC`` search steps
+    (_subtract_and_search), then ``NC`` steps that write the right
+    child's blocks.  ``scal_i`` [3]: (parent slot, new slot, do_split)."""
+    i = pl.program_id(0)
+    do_split = scal_i_ref[2] > 0
+    small_left_b = scal_f_ref[3] <= scal_f_ref[7]
+
+    @pl.when(i < NC)
+    def _():
+        rows = _chunk_rows(i, Fc)
+        _subtract_and_search(
+            i, rows, hrow_ref[0], hsmall_ref[...], small_left_b, do_split,
+            hists_out_ref, stash_ref, scal_f_ref, meta_ref, res_ref,
+            best_ref, Fc=Fc, Bp=Bp)
+
+    @pl.when(i >= NC)
+    def _():
+        rows = _chunk_rows(i - NC, Fc)
+        hists_out_ref[0] = jnp.where(do_split, stash_ref[rows], hrow_ref[0])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",),
+                   donate_argnums=(0,))
+@phase_scope("split_step")
+def split_search(hists, h_small, parent_slot, new_slot, do_split, scal_f,
+                 meta, interpret: bool = False):
+    """The second half of ``split_step_counted`` for the data-parallel
+    grower: the larger child by subtraction from the parent's ``hists``
+    row, both children searched, the two rows written in place, from
+    ``h_small`` [NC * Fc, 4, Bp], the smaller child's histogram summed
+    over the chips.  One launch of ``2 * NC`` steps, named
+    ``lgbm.split_step.search``.  Returns ``(hists', res [2, 16])``."""
+    from .pallas_histogram import feature_chunk
+
+    P, Fp, _, Bp = hists.shape
+    Fc, NC = feature_chunk(Fp, Bp)
+    if meta.shape[0] < NC * Fc:
+        meta = jnp.pad(meta, ((0, NC * Fc - meta.shape[0]), (0, 0)))
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    scal_i = jnp.stack([i32(parent_slot), i32(new_slot), i32(do_split)])
+
+    def chunk(i):
+        return jnp.where(i < NC, i, i - NC)
+
+    def row(i, si):
+        """The hists block of step ``i``: the parent's row through the
+        search steps, the new slot's through the write steps."""
+        return (jnp.where(i < NC, si[0], si[1]), chunk(i), 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(2 * NC,),
+        in_specs=[
+            pl.BlockSpec((1, Fc, 4, Bp), lambda i, si, sf: row(i, si)),
+            pl.BlockSpec((Fc, 4, Bp),
+                         lambda i, si, sf: (jnp.minimum(i, NC - 1), 0, 0)),
+            pl.BlockSpec((Fc, 4),
+                         lambda i, si, sf: (jnp.minimum(i, NC - 1), 0)),
+        ],
+        out_specs=[
+            pl.BlockSpec((1, Fc, 4, Bp), lambda i, si, sf: row(i, si)),
+            pl.BlockSpec((2, 16), lambda i, si, sf: (0, 0)),
+        ],
+        scratch_shapes=[pltpu.VMEM((NC * Fc, 4, Bp), jnp.float32),
+                        pltpu.SMEM((2,), jnp.float32)],
+    )
+    with phase_scope("split_step.search"):
+        hists_new, res = pl.pallas_call(
+            functools.partial(_split_search_kernel, Fc=Fc, NC=NC, Bp=Bp),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((P, Fp, 4, Bp), jnp.float32),
+                       jax.ShapeDtypeStruct((2, 16), jnp.float32)],
+            input_output_aliases={2: 0},
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=max(
+                VMEM_DEFAULT_BYTES, split_search_vmem_bytes(Fp, Bp))),
+            interpret=interpret,
+        )(scal_i, scal_f, hists, h_small, meta)
+    return hists_new, res
+
+
+def split_search_vmem_bytes(Fp: int, Bp: int) -> int:
+    """What a ``split_search`` launch keeps in VMEM, with the terms of
+    ``split_step_vmem_bytes``: the stash of every chunk (``NC * blk``),
+    the parent's block in and the child's out and the summed child's
+    block in, double-buffered (``6 * blk``), the search's planes
+    (``8 * blk``, given), its two ``[Bp, Bp]`` matrices and 2 MiB: under
+    ``split_step_vmem_bytes`` at every width, so the grower's gate reads
+    that one (learners/fused.py chunking)."""
+    from .pallas_histogram import feature_chunk
+
+    Fc, NC = feature_chunk(Fp, Bp)
+    blk = Fc * 4 * Bp * 4
+    return (NC + 14) * blk + 2 * Bp * Bp * 4 + (2 << 20)
 
 
 def split_step_window(*args, **kwargs):

@@ -50,9 +50,14 @@ def two_sum(a, b):
     return s, (a - (s - bb)) + (b - bb)
 
 
-def exact_sums(x: jax.Array) -> jax.Array:
+def exact_sums(x: jax.Array, axis_name: str | None = None) -> jax.Array:
     """Sums over the last axis of ``x`` [..., n] in ``x``'s float dtype,
-    exact up to the grid the module's docstring states."""
+    exact up to the grid the module's docstring states.  With
+    ``axis_name`` the rows are sharded over that mesh axis and the sum is
+    over every chip's: the grid is the largest magnitude of all of them
+    (``pmax``) and the integer digit sums are added across the chips
+    (``psum``, exact in int32), so the result is the one a single device
+    holding every row reads, bit for bit."""
     dt = x.dtype
     levels = 3 if dt == jnp.float32 else 5
     n = x.shape[-1]
@@ -60,6 +65,9 @@ def exact_sums(x: jax.Array) -> jax.Array:
     if pad:
         x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
     top = _pow2_above(jnp.max(jnp.abs(x), axis=-1, keepdims=True))
+    if axis_name is not None:
+        with phase_scope("grow.exchange"):
+            top = jax.lax.pmax(top, axis_name)
     rest = x
     hi = jnp.zeros(x.shape[:-1], dt)
     lo = jnp.zeros(x.shape[:-1], dt)
@@ -75,6 +83,9 @@ def exact_sums(x: jax.Array) -> jax.Array:
         lower = blocks - (upper << HALF_BITS)
         for part, weight in ((lower, 1.0), (upper, float(1 << HALF_BITS))):
             s = jnp.sum(part, axis=-1)  # int32: fits for n < 2^31
+            if axis_name is not None:
+                with phase_scope("grow.exchange"):
+                    s = jax.lax.psum(s, axis_name)
             # two exact float pieces of an int32
             top16 = s >> 16
             terms.append(((s - (top16 << 16)).astype(dt), q[..., 0] * weight))
@@ -87,10 +98,13 @@ def exact_sums(x: jax.Array) -> jax.Array:
     return hi + lo
 
 
-def root_totals(grad: jax.Array, hess: jax.Array, mask: jax.Array):
-    """``(Σ grad·mask, Σ hess·mask)`` over the last axis."""
+def root_totals(grad: jax.Array, hess: jax.Array, mask: jax.Array,
+                axis_name: str | None = None):
+    """``(Σ grad·mask, Σ hess·mask)`` over the last axis (and over the
+    chips of ``axis_name``: ``exact_sums``)."""
     with phase_scope("root_totals"):
         dt = jnp.promote_types(grad.dtype, jnp.float32)
         s = exact_sums(jnp.stack(
-            [(grad * mask).astype(dt), (hess * mask).astype(dt)], axis=-2))
+            [(grad * mask).astype(dt), (hess * mask).astype(dt)], axis=-2),
+            axis_name)
         return s[..., 0], s[..., 1]

@@ -1,7 +1,14 @@
 """Data-parallel tree learner: rows sharded over the mesh.
 
+Two growers stand here, and ``models/gbdt.py select_grower`` chooses.
+On the chips of one process ``make_fused_data_parallel_grower`` (at the
+end) runs the fused grower itself a chip (learners/fused.py with
+``axis``: one histogram all-reduce a split).  Everything else -- the
+CPU, more than one process (parallel/multihost.py), depth-wise and hybrid
+growth -- runs the canonical grower through the hooks below.
+
 TPU-native re-design of DataParallelTreeLearner
-(src/treelearner/data_parallel_tree_learner.cpp):
+(src/treelearner/data_parallel_tree_learner.cpp), the canonical hooks:
 
 * rows are sharded over the mesh's row axis — the analog of the
   per-machine row partition at load (dataset_loader.cpp:500-605);
@@ -305,5 +312,33 @@ def make_data_parallel_grower(
     sharded = data_parallel_sharded(
         mesh, num_bins, max_leaves, axis=axis, growth=growth,
         sorted_hist=sorted_hist, hist_pool=hist_pool, record=record,
+    )
+    return row_padded_grower(sharded, mesh.shape[axis])
+
+
+def make_fused_data_parallel_grower(mesh, num_bins: int, max_leaves: int,
+                                    axis: str = ROW_AXIS):
+    """The fused grower (learners/fused.py) over the chips of ``mesh``,
+    rows sharded on ``axis``: what ``tree_learner=data`` runs on the chips
+    of one host (``models/gbdt.py select_grower``).  Each chip grows the
+    tree on its contiguous share of the rows; one ``psum`` of the smaller
+    child's ``[Fp, 4, Bp]`` histogram a split (and of the root's once a
+    tree) is all that crosses the chips (the module docstring of
+    learners/fused.py).  Rows that do not divide the chips are padded
+    with bag mask 0 (``row_padded_grower``), and the program keeps the
+    one-chip grower's name, ``jit_grow_tree``."""
+    from ..learners import fused
+
+    def body(bins_T, grad, hess, bag_mask, fmask, nbpf, is_cat, params):
+        return fused.grow_tree(
+            bins_T, grad, hess, bag_mask, fmask, nbpf, is_cat, params,
+            num_bins=num_bins, max_leaves=max_leaves, axis=axis)
+
+    sharded = jax.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(None, axis), P(axis), P(axis), P(axis), P(), P(), P(),
+                  P()),
+        out_specs=(P(), P(axis)),
+        check_vma=False,
     )
     return row_padded_grower(sharded, mesh.shape[axis])

@@ -45,8 +45,11 @@ def row_padded_grower(sharded_fn, num_shards: int):
     import jax
     import jax.numpy as jnp
 
+    # named as the one-device growers are: every grow program is
+    # ``jit_grow_tree`` to the profiler and the compile counters
     @jax.jit
-    def grow(bins_T, grad, hess, bag_mask, feature_mask, nbpf, is_cat, params):
+    def grow_tree(bins_T, grad, hess, bag_mask, feature_mask, nbpf, is_cat,
+                  params):
         n = bins_T.shape[1]
         pad = (-n) % num_shards
         if pad:
@@ -59,4 +62,4 @@ def row_padded_grower(sharded_fn, num_shards: int):
         )
         return tree, leaf_id[:n]
 
-    return grow
+    return grow_tree

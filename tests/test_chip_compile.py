@@ -10,6 +10,7 @@ This says nothing about what the kernels compute (chip_smoke.py does).
 """
 
 import functools
+import hashlib
 import os
 import re
 import sys
@@ -27,6 +28,7 @@ from lightgbm_tpu.io.metadata import Metadata
 from lightgbm_tpu.learners import fused
 from lightgbm_tpu.models.gbdt import GBDT
 from lightgbm_tpu.objectives import create_objective
+from lightgbm_tpu.ops import record as R
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
 import kernel_bundles  # noqa: E402  (tools/)
@@ -110,10 +112,11 @@ def test_scopes_and_kernel_names_in_the_compiled_grower(grower):
     # a run-time tile count (lgbm.grow.tier stays in the table for the
     # canonical grower, which keeps the chain)
     assert scopes >= {s for s in dt.SCOPE_NAMES if ".grow." in s} - {
-        "lgbm.grow.tier"} | {
+        "lgbm.grow.tier", "lgbm.grow.exchange"} | {
         "lgbm.histogram", "lgbm.split_step", "lgbm.partition",
         "lgbm.split_search", "lgbm.root_totals"}
     assert "lgbm.grow.tier" not in scopes
+    assert "lgbm.grow.exchange" not in scopes  # one device: no collective
     calls = re.findall(
         r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
         text, re.M)
@@ -328,7 +331,7 @@ def test_record_and_hists_stay_in_the_loop_carry(grower):
     _the_carry_is_clean(grower[1], n=100_000, F=28, L=63)
 
 
-def _the_carry_is_clean(compiled, n, F, L):
+def _the_carry_is_clean(compiled, n, F, L, searched=False):
     from lightgbm_tpu.obs import device_time as dt
     from lightgbm_tpu.ops import record as R
 
@@ -356,6 +359,109 @@ def _the_carry_is_clean(compiled, n, F, L):
     kernels = [ins for ins in body if ins.target == "tpu_custom_call"]
     assert {dt.scope_of(k.op_name)[0] for k in kernels} == {
         "lgbm.split_step", "lgbm.partition"}
+    # one launch of the split step a split, or its two halves
+    assert len(kernels) == (3 if searched else 2), [k.name for k in kernels]
+
+
+# The one-chip grow program at the ``grower`` fixture's shape as its
+# parent compiled it, before the data-parallel path shared its body
+# (f4d3413): sha256 of the optimized HLO, the Mosaic kernels' bodies
+# included, with the debug tables (file names, lines, stack frames), every
+# op's metadata and the kernels' source locations cut.  A change to the
+# one-chip program on purpose records its new digest here.
+ONE_CHIP_GROW_PROGRAM = (
+    "60ba0a83f2fe304b48b14384f1826dfb33e38d30531fec36ec5f09ed3bebaf09")
+
+
+def _kernel_asm(body: str) -> str:
+    """A Mosaic call's serialized kernel as MLIR text with no source
+    locations (the bytecode carries the path and line of every op)."""
+    import base64
+
+    from jaxlib.mlir import ir
+
+    with ir.Context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        module = ir.Module.parse(base64.b64decode(body))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+def program_digest(compiled) -> str:
+    text = re.sub(r"metadata=\{[^}]*\}", "", compiled.as_text())
+    if "StackFrames" in text:
+        text = text[text.index("\n\n", text.index("StackFrames")):]
+    text = re.sub(r'"body":"([^"]+)"', lambda m: '"body":"%s"' % (
+        hashlib.sha256(_kernel_asm(m.group(1)).encode()).hexdigest()), text)
+    return hashlib.sha256(text.encode()).hexdigest()
+
+
+def test_the_one_chip_program_is_its_parents(grower):
+    """learners/fused.py's data-parallel path (``axis``) traces nothing
+    on one device: the compiled one-chip program is the parent's, kernel
+    for kernel and instruction for instruction."""
+    assert program_digest(grower[1]) == ONE_CHIP_GROW_PROGRAM
+
+
+@pytest.fixture(scope="module")
+def sharded_grower(topo):
+    """The data-parallel fused grower compiled for the four chips of a
+    v5e 2x2 (parallel/data_parallel.py make_fused_data_parallel_grower):
+    ``(compiled, rows a chip, features, leaves)``."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from lightgbm_tpu.learners.serial import TreeLearnerParams
+    from lightgbm_tpu.parallel.data_parallel import (
+        make_fused_data_parallel_grower)
+
+    n, F, L = 4 * 16_384, 13, 31
+    mesh = Mesh(np.asarray(topo.devices), ("row",))
+    rows, whole = NamedSharding(mesh, P("row")), NamedSharding(mesh, P())
+
+    def S(dims, dtype=jnp.float32, sharding=whole):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    params = TreeLearnerParams(*[S(())] * 5 + [S((), jnp.int32)])
+    args = (S((F, n), jnp.uint8, NamedSharding(mesh, P(None, "row"))),
+            S((n,), sharding=rows), S((n,), sharding=rows),
+            S((n,), sharding=rows), S((F,), jnp.bool_), S((F,), jnp.int32),
+            S((F,), jnp.bool_), params)
+    with device.assume_platform("tpu"):
+        grow = make_fused_data_parallel_grower(mesh, num_bins=255,
+                                               max_leaves=L)
+        lowered = grow.trace(*args).lower()
+    return lowered.compile(), n // 4, F, L
+
+
+def test_the_data_parallel_grower_compiles_for_four_v5e_chips(
+        sharded_grower):
+    """Mosaic takes both halves of the split step (the compaction and
+    histogram launch, ``lgbm.split_step.dyn``, and the subtraction and
+    search, ``lgbm.split_step.search``) beside the root kernel and the
+    placement; the split loop holds ONE all-reduce, of the smaller
+    child's ``[Fp, 4, Bp]`` block, and carries each chip's record and the
+    summed ``hists`` with no copy, as the one-chip loop does."""
+    from lightgbm_tpu.obs import device_time as dt
+
+    compiled, rows, F, L = sharded_grower
+    _the_carry_is_clean(compiled, n=rows, F=F, L=L, searched=True)
+    text = compiled.as_text()
+    calls = re.findall(
+        r"^\s*(?:ROOT )?%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+        text, re.M)
+    assert sorted(re.sub(r"(\.\d+)+$", "", c) for c in calls) == [
+        "lgbm.histogram.cap16384", "lgbm.partition.place.dyn",
+        "lgbm.split_step.dyn", "lgbm.split_step.search"], calls
+    module = compiled.runtime_executable().hlo_modules()[0]
+    prog = dt.program_of_module(module.as_serialized_hlo_module_proto())
+    loop = [ins for ins in prog.instrs.values() if ins.opcode == "while"]
+    inside = _reachable(prog, loop[0].called)
+    reduces = [ins for ins in prog.instrs.values()
+               if ins.opcode.startswith("all-reduce")]
+    assert [ins.shape for ins in reduces if ins.comp in inside] == [
+        f"f32[{R.round_up(F, 8)},4,256]"], [
+        (ins.name, ins.shape) for ins in reduces]
+    assert all(dt.scope_of(ins.op_name)[0] == "lgbm.grow.exchange"
+               for ins in reduces), [ins.op_name for ins in reduces]
 
 
 def _shape(topo):

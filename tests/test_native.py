@@ -159,3 +159,30 @@ def test_native_short_rows_pad_nan(tmp_path):
     m = native.parse_file(p, "csv", False)
     assert m.shape == (2, 3)
     assert np.isnan(m[1, 2])
+
+
+def test_concurrent_builds_leave_one_whole_library(tmp_path, monkeypatch):
+    """Workers of a test run in a fresh checkout all find the library
+    missing at once.  They take turns on a lock, the first builds into a
+    file of its own and renames it into place, and the rest find it
+    there: every one of them loads a whole library (a worker that loaded
+    one half-written fell back to Python and recorded the ingest span
+    ``lgbm.setup.ingest.encode.python`` no other worker had)."""
+    import ctypes
+    import threading
+
+    import lightgbm_tpu.native as nat
+
+    monkeypatch.setattr(nat, "_LIB_DIR", str(tmp_path))
+    monkeypatch.setattr(nat, "_LIB_PATH", str(tmp_path / "liblgbm_native.so"))
+    built = []
+    workers = [threading.Thread(target=lambda: built.append(nat._build()))
+               for _ in range(4)]
+    for w in workers:
+        w.start()
+    for w in workers:
+        w.join()
+    assert built == [True] * 4
+    assert sorted(p.name for p in tmp_path.iterdir()) == [
+        ".build.lock", "liblgbm_native.so"]
+    assert ctypes.CDLL(nat._LIB_PATH).lgbm_value_to_bin is not None

@@ -43,8 +43,12 @@ _TABLE = [
      "histogram_pool_size=0.01", serial.grow_tree),
     ("hybrid", "tpu", {"tree_growth": "hybrid"}, "canonical",
      "tree_growth=hybrid", None),
-    ("tree_learner=data", "tpu", {"tree_learner": "data"}, "canonical",
-     "tree_learner=data over 8 devices", None),
+    ("tree_learner=data", "tpu", {"tree_learner": "data"}, "fused", "",
+     None),
+    ("tree_learner=feature", "tpu", {"tree_learner": "feature"},
+     "canonical", "tree_learner=feature over 8 devices", None),
+    ("tree_learner=voting", "tpu", {"tree_learner": "voting"}, "canonical",
+     "tree_learner=voting over 8 devices", None),
     ("vmem-too-small", "tpu", {}, "canonical",
      "split step VMEM 3 of 1 MiB", serial.grow_tree),
 ]
@@ -73,6 +77,48 @@ def test_select_grower(platform, params, which, why, grow, monkeypatch):
     # the booster said which grower and why, once, at INFO
     said = [m for m in gbdt_mod._LOGGED_PATHS if f"grower={which}" in m]
     assert any(why in m for m in said), gbdt_mod._LOGGED_PATHS
+
+
+@pytest.mark.parametrize("devices", [2, 4, 8])
+def test_data_parallel_on_one_process_takes_the_fused_grower(devices):
+    """``tree_learner=data`` over the chips of one process: the fused
+    grower with the rows dealt to ``devices`` shards
+    (parallel/data_parallel.py make_fused_data_parallel_grower), and the
+    booster's log line and ``dp.*`` counters say the shards, the rows of
+    the fullest and the one block a split sums over the chips."""
+    from lightgbm_tpu.obs import telemetry
+
+    tel = telemetry.get_telemetry()
+    names = ("dp.shards", "dp.rows_per_shard", "dp.exchange_bytes_per_split",
+             "dp.collectives_per_split")
+    before = {name: tel.counter(name) for name in names}
+    n = 600
+    g = _booster("tpu", tree_learner="data", num_machines=devices, n=n)
+    assert g._grower == ("fused", "")
+    assert g._learner_devices == devices and g._count_shards() == devices
+    assert g._grow.__name__ == "grow_tree"  # the program jit_grow_tree
+    rows, block = -(-n // devices), 8 * 4 * 256 * 4  # [Fp, 4, Bp] f32
+    assert {name: tel.counter(name) - before[name] for name in names} == {
+        "dp.shards": devices, "dp.rows_per_shard": rows,
+        "dp.exchange_bytes_per_split": block, "dp.collectives_per_split": 1}
+    assert any(f"devices={devices} of 8 tree_learner=data" in m
+               and "grower=fused" in m
+               and f"rows over {devices} devices ({rows} a shard), one "
+               f"all-reduce of {block} B a split" in m
+               for m in gbdt_mod._LOGGED_PATHS), gbdt_mod._LOGGED_PATHS
+    # cv's row mask is the one-device learners' alone
+    with device.assume_platform("tpu"), pytest.raises(ValueError):
+        g.set_base_row_mask(np.arange(n) % 3 > 0)
+
+
+def test_more_than_one_process_keeps_the_canonical_grower(monkeypatch):
+    """Across processes the rows are each process's own ingest and the
+    fused grower has no path: the selector says so."""
+    g = _booster("tpu", tree_learner="data")
+    monkeypatch.setattr(gbdt_mod.jax, "process_count", lambda: 2)
+    with device.assume_platform("tpu"):
+        which, why = g.select_grower()
+    assert which == "canonical" and "of 2 processes" in why, why
 
 
 def test_a_row_mask_selects_the_canonical_grower():

@@ -21,10 +21,13 @@ from lightgbm_tpu.obs import RunManifest, telemetry
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-# objective -> rows: the three objectives the benchmark's cells run,
-# each at a shape of its own (and one no other test file uses), so the
-# grow program really traces and compiles in this process for each
-ROWS = {"regression": 1531, "binary": 1543, "lambdarank": 1549}
+# case -> rows: the three objectives the benchmark's cells run, and the
+# binary one with its rows dealt to the host's devices (tree_learner=data,
+# a row count that divides them), each at a shape of its own (and one no
+# other test file uses), so the grow program really traces and compiles
+# in this process for each
+ROWS = {"regression": 1531, "binary": 1543, "lambdarank": 1549,
+        "binary-data": 1552}
 COLUMNS, DEAD_COLUMN = 9, 4
 PARAMS = {"num_leaves": 11, "max_bin": 37, "min_data_in_leaf": 5,
           "verbose": -1}
@@ -37,11 +40,21 @@ SETUP_SPANS = (
     INGEST + ".find_bins", INGEST + ".encode",
     BOOSTER, BOOSTER + ".objective", BOOSTER + ".learner",
     BOOSTER + ".upload", BOOSTER + ".metrics", FIRST_ITER)
+# where the rows go to the shards of a mesh, the upload is named so
+SHARDED = {BOOSTER + ".upload": BOOSTER + ".shard"}
 EPS = 2e-6  # a snapshot rounds seconds to the microsecond
 
 
-def make_table(objective: str, n: int = 0):
-    n = n or ROWS[objective]
+def spans_of(case: str) -> tuple:
+    """The set-up spans a case records."""
+    if case.endswith("-data"):
+        return tuple(SHARDED.get(name, name) for name in SETUP_SPANS)
+    return SETUP_SPANS
+
+
+def make_table(case: str, n: int = 0):
+    n = n or ROWS[case]
+    objective = case.partition("-")[0]
     rng = np.random.default_rng(n)
     X = rng.standard_normal((n, COLUMNS)).astype(np.float32)
     X[:, DEAD_COLUMN] = 1.0  # a trivial column: binned away
@@ -58,12 +71,14 @@ def make_table(objective: str, n: int = 0):
     return X, y, kwargs
 
 
-def set_up_and_update(objective: str):
+def set_up_and_update(case: str):
     """``Dataset`` + ``Booster`` + two ``update()`` calls, the way
     ``engine.train`` and the benchmark make them; the snapshot after
     each ``update()``."""
-    X, y, kwargs = make_table(objective)
-    params = {**PARAMS, "objective": objective}
+    X, y, kwargs = make_table(case)
+    params = {**PARAMS, "objective": case.partition("-")[0]}
+    if case.endswith("-data"):
+        params["tree_learner"] = "data"
     ds = lgb.Dataset(X, label=y, params=params, **kwargs)
     booster = lgb.Booster(params=params, train_set=ds)
     booster.update()
@@ -82,11 +97,13 @@ def run(request):
         X, first, second = set_up_and_update(request.param)
     finally:
         telemetry.set_enabled(was)
-    return {"X": X, "first": first, "second": second}
+    return {"X": X, "first": first, "second": second,
+            "spans": spans_of(request.param)}
 
 
 @pytest.mark.parametrize("name", SETUP_SPANS)
 def test_setup_span_once_and_inside_its_parent(run, name):
+    name = run["spans"][SETUP_SPANS.index(name)]
     spans = run["second"]["spans"]
     assert name in spans, sorted(spans)
     st = spans[name]
@@ -102,7 +119,7 @@ def test_setup_span_once_and_inside_its_parent(run, name):
 
 def test_timeline_orders_spans_and_reports_uncovered(run):
     rows = telemetry.setup_timeline(run["second"])
-    assert sorted(r["name"] for r in rows) == sorted(SETUP_SPANS)
+    assert sorted(r["name"] for r in rows) == sorted(run["spans"])
     starts = [r["start_s"] for r in rows]
     assert starts == sorted(starts)
     by_name = {r["name"]: r for r in rows}
