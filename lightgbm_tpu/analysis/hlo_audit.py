@@ -220,7 +220,11 @@ def _measure_grow_forest_batched() -> dict:
             "has_alias": has_alias, "memory": mem}
 
 
-def _split_step_inputs():
+def _split_step_inputs(tiles=1, tail=0, blocks_of=1):
+    """A leaf of ``tiles`` tiles less ``tail`` rows at the record's
+    origin, in a window and a record of whole blocks of ``blocks_of``
+    tiles (the audit's: one tile, a one-tile window, ``n_pad = 2 *
+    TILE``, so one tile a grid step)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -228,14 +232,15 @@ def _split_step_inputs():
     from ..ops.pallas_search import _pack_meta, _pack_scal
 
     T = rec_mod.TILE
-    cap, n = T, T  # one-tile window; n_pad = 2 * TILE
+    n = tiles * T - tail
+    cap = rec_mod.round_up(tiles, blocks_of) * T
     k = rec_mod.bins_per_word(jnp.uint8)
     rng = np.random.RandomState(0)
     bins_T = jnp.asarray(rng.randint(0, _B, size=(_F, n)).astype(np.uint8))
     grad = jnp.asarray(rng.randn(n).astype(np.float32))
     rec = rec_mod.build_record(
         bins_T, grad, jnp.ones(n, jnp.float32), jnp.ones(n, jnp.float32),
-        2 * T)
+        cap + blocks_of * T)
     Fp = rec_mod.round_up(_F, 8)
     Bp = rec_mod.round_up(_B, 128)
     hists = jnp.zeros((2, Fp, 4, Bp), jnp.float32)
@@ -252,9 +257,8 @@ def _split_step_inputs():
         is_cat=jnp.bool_(False), parent_slot=jnp.int32(0),
         new_slot=jnp.int32(1),
         # the grower's form of the two launches: the tile count is an
-        # operand (here every tile of the window), the grid a run-time
-        # bound
-        live_tiles=jnp.int32(cap // T))
+        # operand (here the leaf's tiles), the grid a run-time bound
+        live_tiles=jnp.int32(tiles))
     return rec, hists, scal_f, meta, scalars, cap, k
 
 

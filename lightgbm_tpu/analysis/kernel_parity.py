@@ -40,6 +40,9 @@ True/False.  ``chip_smoke.py`` is the caller; on a TPU
 
 from __future__ import annotations
 
+import functools
+from typing import NamedTuple
+
 import numpy as np
 
 #: max |kernel - float64 numpy| — float32 accumulation of a few hundred
@@ -72,18 +75,49 @@ def _np_partition(rec, go, begin, pcnt, leaf_row, left_leaf, right_leaf):
     return out, nleft
 
 
+class Split(NamedTuple):
+    """What ``_fused_split`` returns: the step's outputs and the placed
+    record."""
+
+    hists: object
+    rec: object
+    nleft: object
+    res: object
+    cl: object
+    cr: object
+    comp: object
+    ran: int  # histogram tiles run
+
+
+@functools.lru_cache(maxsize=None)
+def _split_step_at(tiles_per_step):
+    """The split step as the grower launches it (``None``: the parent
+    tiles a grid step its record's height gives) or at
+    ``tiles_per_step``, which nothing but tests and timings sets."""
+    import jax
+
+    from ..ops.record import _split_step_call, split_step_counted
+
+    if tiles_per_step is None:
+        return split_step_counted
+    return jax.jit(
+        functools.partial(_split_step_call, fgroup=8,
+                          tiles_per_step=tiles_per_step),
+        static_argnames=("F", "cap", "k", "interpret"), donate_argnums=(0,))
+
+
 def _fused_split(rec, hists, begin, pcnt, f, thr, left_leaf, right_leaf,
-                 scal, meta, F, cap, live_tiles, interpret):
-    """The fused grower's launch pair on one window (uint8 bins).
-    Returns (hists', rec', nleft, res, cl, histogram tiles run)."""
+                 scal, meta, F, cap, live_tiles, interpret,
+                 tiles_per_step=None) -> Split:
+    """The fused grower's launch pair on one window (uint8 bins)."""
     import jax.numpy as jnp
 
-    from ..ops.record import (
-        bins_per_word, num_words, place_runs, split_step_counted)
+    from ..ops.record import bins_per_word, num_words, place_runs
 
     k = bins_per_word(jnp.uint8)
     i32 = jnp.int32
-    hists2, comp, nleft, res, cl, cr, rec_pass, ran = split_step_counted(
+    hists2, comp, nleft, res, cl, cr, rec_pass, ran = _split_step_at(
+        tiles_per_step)(
         jnp.array(hists), rec, i32(begin), i32(pcnt), jnp.bool_(True), i32(f),
         i32(thr), jnp.bool_(False), i32(left_leaf), i32(right_leaf),
         scal, meta, F=F, cap=cap, k=k, interpret=interpret,
@@ -93,7 +127,17 @@ def _fused_split(rec, hists, begin, pcnt, f, thr, left_leaf, right_leaf,
         jnp.bool_(True), i32(left_leaf), i32(right_leaf), cap=cap,
         leaf_row=num_words(F, k) + 4, interpret=interpret,
         live_tiles=live_tiles)
-    return hists2, rec2, nleft, res, cl, int(ran)
+    return Split(hists2, rec2, nleft, res, cl, cr, comp, int(ran))
+
+
+def _block(F):
+    """Columns of the split step's block at ``F`` columns of uint8 bins:
+    the window and the record come in whole blocks, as the grower's do
+    (learners/fused.py), so the launch takes the parent tiles a grid step
+    the record's height gives."""
+    from ..ops.record import TILE, rec_height, split_tiles
+
+    return TILE * split_tiles(rec_height(F, 4))
 
 
 def _runs(n, num_bins, head):
@@ -112,7 +156,7 @@ def _split_case(rng, F, n, num_bins, integer, bag_frac, tie=None, begin=0,
     import jax.numpy as jnp
 
     from ..ops.pallas_search import _pack_meta
-    from ..ops.record import TILE, build_record, round_up
+    from ..ops.record import build_record, round_up
 
     bins = rng.randint(0, num_bins, (F, n)).astype(np.uint8)
     if tie is not None:
@@ -129,7 +173,7 @@ def _split_case(rng, F, n, num_bins, integer, bag_frac, tie=None, begin=0,
     rec = build_record(
         jnp.asarray(np.pad(bins, ((0, 0), (begin, 0)))),
         *(jnp.asarray(np.pad(v, (begin, 0))) for v in (g, h, bag)),
-        round_up(n + begin, TILE) + TILE)
+        round_up(n + begin, _block(F)) + _block(F))
     meta = _pack_meta(jnp.ones(F, bool), jnp.full(F, num_bins, jnp.int32),
                       jnp.zeros(F, bool), round_up(F, 8))
     return bins, g, h, bag, rec, meta
@@ -152,7 +196,7 @@ def check_search(rng, log=print, interpret=False) -> bool:
     from ..config import Config
     from ..learners.serial import TreeLearnerParams
     from ..ops.pallas_search import _pack_scal, _unpack
-    from ..ops.record import TILE, round_up
+    from ..ops.record import round_up
     from ..ops.split import find_best_split_leaves
 
     F, n, B = 12, 3000, 64
@@ -172,9 +216,10 @@ def check_search(rng, log=print, interpret=False) -> bool:
             jnp.float32(1.0), *[jnp.float32(x) for x in (*tot[0], *tot[1])],
             prm.min_data_in_leaf, prm.min_sum_hessian_in_leaf,
             prm.lambda_l1, prm.lambda_l2, prm.min_gain_to_split)
-        _, _, _, res, _, _ = _fused_split(
+        res = _fused_split(
             rec, _root_hists(bins, g, h, bag, B, 3, interpret), 0, n, f,
-            thr, 0, 1, scal, meta, F, round_up(n, TILE), None, interpret)
+            thr, 0, 1, scal, meta, F, round_up(n, _block(F)), None,
+            interpret).res
         ref = find_best_split_leaves(
             jnp.asarray(np.stack([s.transpose(0, 2, 1) for s in sides]),
                         jnp.float32),
@@ -231,9 +276,10 @@ def check_split(rng, log=print, interpret=False) -> bool:
         small = int((left if cnt[0] <= cnt[1] else ~left).sum())
         scal = _pack_scal(*[jnp.float32(x) for x in (
             1.0, 1., 2., cnt[0], -1., 2., cnt[1], 20., 1e-3, 0., 0., 0.)])
-        hists, rec2, nleft, _, _, ran = _fused_split(
+        got = _fused_split(
             rec, hists0, begin, n, f, thr, 0, 1, scal, meta, F,
-            round_up(n, TILE), None, interpret)
+            round_up(n, _block(F)), None, interpret)
+        hists, rec2, nleft, ran = got.hists, got.rec, got.nleft, got.ran
 
         ok = True
         want_rec, want_nl = _np_partition(rec, left, begin, n, lr, 0, 1)
@@ -260,6 +306,7 @@ def check_split(rng, log=print, interpret=False) -> bool:
             log(f"  split ran {ran} histogram tiles for {small} rows")
             ok = False
         log(f"split parity: {'OK' if ok else 'FAIL'} ({F} columns, "
+            f"{_block(F) // TILE} tiles a grid step, "
             f"nleft={int(nleft)} of {n}, smaller child {small / n:.1%}, "
             f"hist tiles / parent tiles = {ran} / {-(-n // TILE)}, "
             f"hist maxdiff kernel-float64={d_ref:.2e})")
@@ -298,14 +345,16 @@ def check_place(rng, log=print, interpret=False) -> bool:
             bins[2], frac = _runs(n, num_bins, 3 * TILE + 57), 0.0
         g = rng.randn(n).astype(np.float32)
         h = (rng.rand(n) + 0.5).astype(np.float32)
-        # room for the wider window of the run-time-count launch
-        total = round_up(n + begin, TILE) + 3 * TILE
+        # room for the wider window of the run-time-count launch, in
+        # whole blocks of the split step's tiles a grid step
+        blk = _block(F)
+        total = round_up(n + begin, blk) + 2 * blk
         rec = build_record(
             jnp.asarray(np.pad(bins, ((0, 0), (begin, 0)))),
             jnp.asarray(np.pad(g, (begin, 0))),
             jnp.asarray(np.pad(h, (begin, 0))),
             jnp.ones(n + begin, jnp.float32), total)
-        cap = round_up(n, TILE)
+        cap = round_up(n, blk)
         thr = min(int(num_bins * frac), num_bins - 1)
         f = 2
         go = bins[f] <= thr
@@ -322,11 +371,11 @@ def check_place(rng, log=print, interpret=False) -> bool:
                             (1., 0., 1., 9., 0., 1., 9., 1., 1e-3,
                              0., 0., 0.)])
         # the static tile count, then the grower's launch pair: a window
-        # two tiles wider than the leaf, visited over the live tiles only
+        # a block wider than the leaf, visited over the live tiles only
         # (a run-time grid)
         for what, cap_w, live in (
                 ("", cap, None),
-                (" (live tiles)", cap + 2 * TILE, i32(cap // TILE))):
+                (" (live tiles)", cap + blk, i32(cap // TILE))):
             # slots 3 and 5 are written by the kernel's hists index maps
             # — allocate past them (Pallas does not bounds-check them)
             _, comp, nl, _, cl, cr, rec_pass, _ = split_step_counted(

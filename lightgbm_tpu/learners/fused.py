@@ -216,8 +216,11 @@ def grow_tree(
     hist_fn = make_single_hist_fn_raw(num_bins)
     k = bins_per_word(bins_T.dtype)
     T = record.TILE
-    # every row in one window: the root split's, and the buffers' size
-    cap = max(T, round_up(n, T))
+    # every row in one window: the root split's, and the buffers' size;
+    # in whole blocks of the split step's tiles a grid step, so that
+    # every block of its last step lies inside the buffers
+    block = T * record.split_tiles(record.rec_height(F, k))
+    cap = max(block, round_up(n, block))
 
     with phase_scope("grow.root"):
         hist0 = hist_fn(bins_T, grad, hess, bag_mask)  # [Fp, 4, Bp]
@@ -246,7 +249,7 @@ def grow_tree(
             root_best, hist0.dtype, L, n, count0)
         state = _State(
             rec=build_record(
-                bins_T, grad, hess, bag_mask, round_up(n, T) + cap),
+                bins_T, grad, hess, bag_mask, round_up(n, block) + cap),
             pos_mat=pos_mat,
             hists=jnp.zeros((L,) + hist0.shape, hist0.dtype).at[0].set(hist0),
             best_mat=best_mat,

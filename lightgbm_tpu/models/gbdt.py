@@ -330,6 +330,8 @@ class GBDT:
             "grow.chunk_features": plan.chunk_features,
             "grow.hist_block_bytes": plan.hist_block_bytes,
             "grow.record_words": plan.record_words,
+            "grow.split_tiles_per_step": record.split_tiles(
+                plan.record_words),
             "grow.place_steps_per_tile": record.PLACE_STEPS_PER_TILE,
             "grow.place_launches_per_split":
                 record.PLACE_LAUNCHES_PER_SPLIT,
@@ -557,9 +559,11 @@ class GBDT:
             hist, search = "pallas raw-layout", "pallas (in the split step)"
             from ..ops import record
 
+            K = record.split_tiles(self._chunking.record_words)
             part = (f"packed record, {self._chunking.said}, placement "
                     f"{record.PLACE_STEPS_PER_TILE} step a tile in "
-                    f"{record.PLACE_LAUNCHES_PER_SPLIT} launch a split")
+                    f"{record.PLACE_LAUNCHES_PER_SPLIT} launch a split, "
+                    f"split step {K} tile{'s' if K > 1 else ''} a grid step")
             if not serial:
                 nd = self._learner_devices
                 part += (f", rows over {nd} devices "

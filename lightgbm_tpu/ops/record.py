@@ -25,7 +25,9 @@ Who calls what:
  *  the FUSED grower (learners/fused.py, what a TPU chip runs) splits a
     leaf with one launch pair: ``split_step_window`` — per [W, TILE]
     tile of the leaf's window the go flags, a stable compaction and the
-    tile's left count; the smaller child's histogram; then the sibling
+    tile's left count, K tiles a grid step (split_tiles: 4 up to 64
+    words, 1 past 128, from the record's height alone); the smaller
+    child's histogram; then the sibling
     by subtraction, both children's split search and the two ``hists``
     rows in place — and ``place_runs``, one grid step a tile: the
     tile's compacted block is read once, its left run appended to a
@@ -46,13 +48,13 @@ Who calls what:
 
     Where the smaller child's histogram comes from: the compaction has
     already laid that child's rows of a tile side by side, so each tile
-    step appends them to a [W, 2*TILE] staging buffer in VMEM
-    (_split_tile), and the one-hot body (_hist_tile_body) runs on a
-    FULL tile of staged rows whenever one has filled, and once on the
-    remainder before the search: ``ceil(rows / TILE)`` bodies a split
-    (the kernel counts them: _hist_tiles), where summing every tile of
-    the parent with the sibling masked ran 2.7 times as many (PERF.md,
-    PR 31).
+    step appends its K tiles' runs, in tile order, to a [K+1, W, TILE]
+    staging buffer in VMEM (_stage), and the one-hot body
+    (_hist_tile_body) runs on a FULL tile of staged rows for each one
+    that has filled, and once on the remainder before the search:
+    ``ceil(rows / TILE)`` bodies a split whatever K (the kernel counts
+    them: _hist_tiles), where summing every tile of the parent with the
+    sibling masked ran 2.7 times as many (PERF.md, PR 31).
  *  the canonical grower under ``record_mode`` (learners/serial.py, the
     parallel learners) partitions with ``partition_window`` — the same
     compaction as a kernel of its own plus ``place_runs`` — and reads a
@@ -63,15 +65,19 @@ go bitmask yields each column's destination offset directly; an
 LSB-first staged-shift compress network (Hacker's Delight 7-4, a digit
 of base SCAN_RADIX a round) runs on ONE row of packed lane ids a child,
 which leaves in every destination lane the lane it takes; and the
-tile's words follow by lane gathers (_compact_body).  (Every word of
-the tile rode both networks until PR 35; a one-hot routing matrix on the
-MXU, O(TILE^2) a tile, was the first design and read 8.3% and 9.7%
-slower a tree than that in the benchmark's two cells, PERF.md PR 30.)
-Zero per-element descriptors anywhere.
+tile's words follow by lane gathers (_compact_tiles).  The K tiles of a
+grid step stack their rows in the sublanes of one [2K, TILE] operand,
+so one prefix sum and one network, six trips through the rotate unit,
+serve all K (_source_rows).  (Every word of the tile rode both networks
+until PR 35; a one-hot routing matrix on the MXU, O(TILE^2) a tile, was
+the first design and read 8.3% and 9.7% slower a tree than that in the
+benchmark's two cells, PERF.md PR 30.)  Zero per-element descriptors
+anywhere.
 
 Off the chip (``interpret=True``) two branches differ from what the
 chip runs: ``split_step_window`` reads a materialised window slice
-where the chip roll-merges two aligned blocks of the aliased record,
+where the chip roll-merges two aligned [W, K*TILE] blocks of the
+aliased record,
 and ``place_runs`` returns the XLA reference placement (``_xla_place``)
 where the chip runs its kernel.  analysis/kernel_parity.py holds both
 to numpy on the chip; tests/test_place_kernel.py runs the placement
@@ -111,6 +117,27 @@ PLACE_LAUNCHES_PER_SPLIT = 1
 # the ``[256, TILE]`` one-hot of the time; PR 37's body runs nearer its
 # schedule than that one did and the step was not timed again.
 LOOP_WORDS = 16
+# Parent tiles a grid step of the split step takes (split_tiles): as many
+# as keep a ``[W, K * TILE]`` block of the record within SPLIT_BLOCK_WORDS
+# words a lane, at most SPLIT_TILES_MAX.  K tiles share one prefix sum and
+# one compress network (_source_rows), whose rotates are the same vregs
+# for up to four tiles as for one; the gathers and the staging stay per
+# tile.
+SPLIT_BLOCK_WORDS = 256
+SPLIT_TILES_MAX = 4
+
+
+def split_tiles(W: int) -> int:
+    """Parent tiles a grid step of the split step takes at a record of
+    ``W`` words: 4 up to 64 words, 2 up to 128, then 1 (at 512 words the
+    compaction's network is 3% of a tile: PERF.md, PR 35).  The grower
+    pads its window and record to whole blocks of ``split_tiles(W) *
+    TILE`` columns (learners/fused.py); a caller whose buffers hold fewer
+    gets the largest power of two they hold (_split_step_call)."""
+    K = SPLIT_TILES_MAX
+    while K > 1 and K * W > SPLIT_BLOCK_WORDS:
+        K //= 2
+    return K
 
 
 def round_up(x: int, m: int) -> int:
@@ -228,26 +255,26 @@ def unpack_window(win: jax.Array, F: int, k: int, bin_dtype):
     return bins, g, h, m
 
 
-def _tile_go(tile, scal_i_ref, i, *, F, k):
-    """Left-going flags of one [W, T] record tile, recomputed IN-KERNEL
-    from the split scalars — the [cap, 1] go-column operand this
-    replaces cost a layout copy per split per tier on the XLA side
-    (profiled ~300 ms/tree at 10M rows: {0,1:T(1,128)} ->
-    {1,0:T(8,128)} relayouts of every tier's column).
+def _tile_go(tile, scal_i_ref, col0, *, F, k):
+    """Left-going flags of a [W, lanes] block of the window whose lane 0
+    is window column ``col0``, recomputed IN-KERNEL from the split
+    scalars — the [cap, 1] go-column operand this replaces cost a layout
+    copy per split per tier on the XLA side (profiled ~300 ms/tree at 10M
+    rows: {0,1:T(1,128)} -> {1,0:T(8,128)} relayouts of every tier's
+    column).
 
-    Returns one [1, T] f32 row: 1.0 = left AND valid (rows past pcnt
+    Returns one [1, lanes] f32 row: 1.0 = left AND valid (rows past pcnt
     are 0).
     scal_i layout: (.., .., .., .., f, thr, is_cat, pcnt) — indices 4-7.
     """
-    T = TILE
     f = scal_i_ref[4]
     thr = scal_i_ref[5]
     is_cat = scal_i_ref[6]
     pcnt = scal_i_ref[7]
     shift = 32 // k
     mask_v = (1 << shift) - 1
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-    valid = ((i * T + lane) < pcnt).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile.shape[-1]), 1)
+    valid = ((col0 + lane) < pcnt).astype(jnp.int32)
     fw = f // k
     fs = (f % k) * shift
     # compare-select row pick: Mosaic has no dynamic_slice lowering,
@@ -277,9 +304,10 @@ SCAN_RADIX = 16
 
 
 def _lane_cumsum(g):
-    """Inclusive prefix sum along the LANE axis of a [1, T] i32 row:
-    ceil(log_R(T)) rounds (R = SCAN_RADIX) in which lane t adds lanes
-    t - s, t - 2s, .. t - (R-1)s of the round before, s = R^round: a
+    """Inclusive prefix sum along the LANE axis of each row of an
+    [rows, T] i32 array: ceil(log_R(T)) rounds (R = SCAN_RADIX) in which
+    lane t adds lanes t - s, t - 2s, .. t - (R-1)s of the round before,
+    s = R^round: a
     Hillis-Steele scan of radix R.  Mosaic has no reliable cumsum
     lowering on the lane axis; ``pltpu.roll`` plus an iota mask is the
     portable scan — and it runs identically under interpret mode, so
@@ -372,74 +400,98 @@ def _gather_lanes(tile, src):
     return jnp.concatenate(outs, axis=1)
 
 
-def _compact_body(tile, g):
-    """Stable compaction of one tile by prefix sums (shared by the
-    plain and the fused kernel).  A lane cumsum of the go row yields
-    destination offsets directly — lefts land at ``cumsum(go)-1``,
-    everything else (the invalid tail included) at ``cumsum(1-go)-1``
-    in the right half.  The permutation is COMPUTED on one two-row
-    operand (_source_lanes: the left run's compress network in row 0,
-    the right run's in row 1, four vregs whatever the record's height)
-    and APPLIED once, by lane gathers of the tile (_gather_lanes).
-    Rolling and blending every word of the tile through 18 stages, as
-    this did up to PR 34, cost a parent tile of 32 / 64 / 512 words 2.44
-    / 3.32 / 22.7 us in the split step where this costs 1.70 / 2.36 /
-    13.1 (the step alone on the chip: PERF.md, PR 35; at 512 words the
-    tile spilled at every stage).  The i32 words move untouched, so
-    routed content is exact by construction.
-
-    tile [W, T] i32, g [1, T] 0/1 row (f32 or i32; 1 = left AND valid)
-    -> [W, 2T]: lefts compacted to [0, T), everything else to [T, 2T),
-    original order inside each, garbage lanes beyond each run.
-    """
-    T = tile.shape[-1]
-    gi = g.astype(jnp.int32)
-    csum = _lane_cumsum(gi)  # inclusive left count per lane
-    lane = jax.lax.broadcasted_iota(jnp.int32, (2, T), 1)
-    right = jax.lax.broadcasted_iota(jnp.int32, (2, T), 0)
+def _source_rows(gos):
+    """The source lanes (_source_lanes) of K tiles' stable compactions
+    from their go rows, a list of K [1, T] 0/1 i32 rows: a [2K, T] array
+    whose row 2t is tile t's left run and row 2t+1 its right run.  ONE
+    prefix sum and ONE compress network serve all K tiles: both run on a
+    [2K, T] operand whose rows 2t and 2t+1 hold tile t's go row.  A vreg
+    has eight sublanes and a lone row pair filled two of them, so up to
+    four tiles ride the vregs one did, through the same chain of six
+    trips through the rotate unit (PERF.md section 6)."""
+    K = len(gos)
+    T = gos[0].shape[-1]
+    row = jax.lax.broadcasted_iota(jnp.int32, (2 * K, T), 0)
+    g = jnp.broadcast_to(gos[0], (2 * K, T))
+    for t in range(1, K):
+        g = jnp.where(row >= 2 * t, gos[t], g)
+    csum = _lane_cumsum(g)  # inclusive left count per lane
+    lane = jax.lax.broadcasted_iota(jnp.int32, (2 * K, T), 1)
+    right = (row & 1) == 1
     # go column at lane t: dest = csum[t]-1, shift = t - csum[t] + 1
     # (= non-go count strictly below t); non-go column: dest =
     # t - csum[t], shift = csum[t] (= go count strictly below t)
-    src = _source_lanes(
-        jnp.where(right == 0, gi, 1 - gi),
-        jnp.where(right == 0, lane - csum + 1, csum))
-    return jnp.concatenate(
-        [_gather_lanes(tile, src[0:1]), _gather_lanes(tile, src[1:2])],
-        axis=1)
+    return _source_lanes(jnp.where(right, 1 - g, g),
+                         jnp.where(right, csum, lane - csum + 1))
+
+
+def _compact_tiles(tiles, gos):
+    """Stable compaction of the K tiles of a [W, K*T] block by prefix
+    sums (shared by the plain and the fused kernel).  A lane cumsum of a
+    tile's go row yields destination offsets directly — lefts land at
+    ``cumsum(go)-1``, everything else (the invalid tail included) at
+    ``cumsum(1-go)-1`` in the right half.  The permutations are COMPUTED
+    on one [2K, T] operand of control words (_source_rows: four vregs
+    whatever the record's height, up to four tiles) and APPLIED once a
+    tile, by lane gathers (_gather_lanes).  Rolling and blending every
+    word of the tile through 18 stages, as this did up to PR 34, cost a
+    parent tile of 32 / 64 / 512 words 2.44 / 3.32 / 22.7 us in the split
+    step where one tile's network cost 1.70 / 2.36 / 13.1 (the step alone
+    on the chip: PERF.md, PR 35; at 512 words the tile spilled at every
+    stage).  The i32 words move untouched, so routed content is exact by
+    construction.
+
+    tiles [W, K*T] i32, gos: K [1, T] 0/1 rows (f32 or i32; 1 = left
+    AND valid), a tile's each -> K [W, 2T] blocks: lefts compacted to
+    [0, T), everything else to [T, 2T), original order inside each,
+    garbage lanes beyond each run.
+    """
+    T = TILE
+    K = len(gos)
+    src = _source_rows([g.astype(jnp.int32) for g in gos])
+    return [jnp.concatenate(
+        [_gather_lanes(tiles[:, t * T: (t + 1) * T], src[r: r + 1])
+         for r in (2 * t, 2 * t + 1)], axis=1) for t in range(K)]
 
 
 def _compact_kernel(win_ref, grow_ref, out_ref):
-    """One grid step = one [W, T] tile of partition_window: stable
+    """One grid step = K [W, T] tiles of partition_window: stable
     compaction, lefts to [0, T) and everything else to [T, 2T) of
-    ``out_ref`` [1, W, 2T].  The go flags arrive as ROW 0 of a
-    sublane-aligned [8, T] operand — the compress network runs on the
+    ``out_ref`` [K, W, 2T].  The go flags arrive as ROW 0 of a
+    sublane-aligned [8, K*T] operand — the compress network runs on the
     lane axis, and a bare [1, cap] row block (sublane dim 1) is not
     Mosaic-legal."""
-    out_ref[0] = _compact_body(win_ref[...], grow_ref[0:1, :])
-
-
-def compact_tiles(win, go, interpret: bool = False):
-    """``_compact_body`` over every tile of a [W, cap] window: go [cap]
-    i32 0/1 (1 = left AND valid) -> comp [cap // TILE, W, 2 * TILE]."""
-    W, cap = win.shape
     T = TILE
+    gos = [grow_ref[0:1, t * T: (t + 1) * T] for t in range(out_ref.shape[0])]
+    for t, comp in enumerate(_compact_tiles(win_ref[...], gos)):
+        out_ref[t] = comp
+
+
+def compact_tiles(win, go, interpret: bool = False, tiles: int = 1):
+    """``_compact_tiles`` over every tile of a [W, cap] window, ``tiles``
+    a grid step (partition_window takes one; tools/kernel_bundles.py
+    schedules the split step's K): go [cap] i32 0/1 (1 = left AND valid)
+    -> comp [cap // TILE, W, 2 * TILE]."""
+    W, cap = win.shape
+    KT = tiles * TILE
     # go flags ride ROW 0 of a sublane-aligned [8, cap] operand (see
     # _compact_kernel); rows 1-7 are zero padding
     flags = jnp.pad(go[None], ((0, 7), (0, 0)))
     return pl.pallas_call(
         _compact_kernel,
-        grid=(cap // T,),
-        in_specs=[pl.BlockSpec((W, T), lambda i: (0, i)),
-                  pl.BlockSpec((8, T), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((1, W, 2 * T), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((cap // T, W, 2 * T), jnp.int32),
+        grid=(cap // KT,),
+        in_specs=[pl.BlockSpec((W, KT), lambda i: (0, i)),
+                  pl.BlockSpec((8, KT), lambda i: (0, i))],
+        out_specs=pl.BlockSpec((tiles, W, 2 * TILE), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((cap // TILE, W, 2 * TILE), jnp.int32),
         interpret=interpret,
     )(win, flags)
 
 
 def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
-    """Histogram accumulation over the first [W, T] tile of the smaller
-    child's STAGED rows (_split_step_kernel's one call).  ``live``
+    """Histogram accumulation over the first [W, T] block of the smaller
+    child's STAGED rows, ``stage_ref[0]`` (_split_step_kernel's one
+    call).  ``live``
     [1, T] flags the lanes that hold a row: all of them on a full
     staged tile, the first ``fill`` at the drain.  Stats stack on
     sublanes; the one-hot is born transposed against a sublane iota and
@@ -462,7 +514,7 @@ def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
     mask_v = (1 << shift) - 1
 
     Wb = num_words(F, k)
-    stat = stage_ref[Wb: Wb + 3, :T]
+    stat = stage_ref[0, Wb: Wb + 3]
     grow = jax.lax.bitcast_convert_type(stat[0:1], jnp.float32)
     hrow = jax.lax.bitcast_convert_type(stat[1:2], jnp.float32)
     mrow = jax.lax.bitcast_convert_type(stat[2:3], jnp.float32)
@@ -488,7 +540,7 @@ def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
     steps = F // (LW * k)
 
     def whole_group(g, _):
-        group(stage_ref[pl.ds(pl.multiple_of(g * LW, LW), LW), :T],
+        group(stage_ref[0, pl.ds(pl.multiple_of(g * LW, LW), LW)],
               g * (LW * k), LW * k)
         return 0
 
@@ -496,7 +548,7 @@ def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
         jax.lax.fori_loop(0, steps, whole_group, 0)
     w0 = steps * LW
     if w0 < Wb:
-        group(stage_ref[w0: Wb, :T], w0 * k, F - w0 * k)
+        group(stage_ref[0, w0: Wb], w0 * k, F - w0 * k)
     # caller-sized histogram block: the padded-feature fill below must
     # cover exactly the caller's round_up(F, fgroup) rows (ADVICE r4 —
     # a literal 8 here would leave rows [round_up(F,8), Fp) zero and
@@ -593,42 +645,57 @@ def _chunk_rows(c, Fc):
     return pl.ds(pl.multiple_of(c * Fc, Fc), Fc)
 
 
-def _split_tile(tile, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
-                stage_ref, fill_ref, *, F, k):
-    """Per-tile work of the split step: ONE in-kernel go computation
-    (no [cap, 1] column operand from XLA — see _tile_go) shared by the
-    compaction, the per-tile left-count output, and the STAGING of the
-    smaller child's rows for the histogram (``small_left_b``: the
-    left-going rows, or the valid rows that do not go left).  ``j`` is
-    the tile ordinal (validity).
-
-    The compaction has already put the child's rows of this tile side by
-    side: the lefts in lanes [0, T) of the compacted tile, the valid
-    rights as a PREFIX of [T, 2T) (the invalid tail follows them).  That
-    run is appended at lane ``fill`` of ``stage_ref`` [W, 2T] — one
-    dynamic roll, two lane masks, as the direct read and _place_kernel's
-    rings move unaligned runs — so the histogram body (_split_step_kernel)
-    runs on full tiles of the child's rows and never sees the sibling's.
-    ``fill < T`` on entry and the run is at most T long, so the append
-    fits and at most one full tile is due after it."""
+def _split_tiles(tiles, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
+                 stage_ref, fill_ref, *, F, k):
+    """Per-step work of the split step on K parent tiles, the [W, K*T]
+    block ``tiles`` (``j``: the step's ordinal, so its tiles are jK ..
+    jK+K-1): ONE in-kernel go computation (no [cap, 1] column operand
+    from XLA — see _tile_go) shared by the compaction, the per-tile
+    left-count output, and the STAGING of the smaller child's rows for
+    the histogram (``small_left_b``: the left-going rows, or the valid
+    rows that do not go left).  Each tile's compacted block and left
+    count are what a step of one tile writes for it."""
     T = TILE
-    govf = _tile_go(tile, scal_i_ref, j, F=F, k=k)
-    comp = _compact_body(tile, govf)
-    comp_ref[0] = comp
-    nleft = jnp.sum(govf).astype(jnp.int32)
-    cnt_ref[...] = jnp.zeros((1, 128), jnp.int32) + nleft
+    K = tiles.shape[-1] // T
+    go = _tile_go(tiles, scal_i_ref, j * (K * T), F=F, k=k)
+    gos = [go[:, t * T: (t + 1) * T] for t in range(K)]
+    group = jax.lax.broadcasted_iota(jnp.int32, (1, K * 128), 1) // 128
+    counts = jnp.zeros((1, K * 128), jnp.int32)
+    for t, comp in enumerate(_compact_tiles(tiles, gos)):
+        comp_ref[t] = comp
+        nleft = jnp.sum(gos[t]).astype(jnp.int32)
+        counts = jnp.where(group == t, nleft, counts)
+        nvalid = jnp.clip(scal_i_ref[7] - (j * K + t) * T, 0, T)
+        _stage(jnp.where(small_left_b, comp[:, :T], comp[:, T:]),
+               jnp.where(small_left_b, nleft, nvalid - nleft),
+               stage_ref, fill_ref)
+    cnt_ref[...] = counts
 
-    nvalid = jnp.clip(scal_i_ref[7] - j * T, 0, T)
-    run = jnp.where(small_left_b, nleft, nvalid - nleft)
-    half = jnp.where(small_left_b, comp[:, :T], comp[:, T:])
+
+def _stage(half, run, stage_ref, fill_ref):
+    """Append the first ``run`` lanes of ``half`` [W, T] to the smaller
+    child's staged rows, ``stage_ref`` [K+1, W, T] holding ``fill_ref[0]``
+    of them.  The compaction has already put the child's rows of a tile
+    side by side: the lefts in lanes [0, T) of the compacted tile, the
+    valid rights as a PREFIX of [T, 2T) (the invalid tail follows them).
+    That run lands at lane ``fill % T`` of block ``fill // T`` and runs
+    into the next — one dynamic roll, two lane masks, as the direct read
+    and _place_kernel's rings move unaligned runs — so the histogram body
+    (_split_step_kernel) runs on full tiles of the child's rows and never
+    sees the sibling's.  ``fill < T`` when a step begins and a run is at
+    most T long, so a step's K appends fit in K+1 blocks and leave at
+    most K full tiles due."""
+    T = TILE
     fill = fill_ref[0]
-    end = fill + run
-    rolled = pltpu.roll(half, fill, axis=1)  # lane t -> (t + fill) % T
+    q = fill // T
+    at = fill - q * T
+    end = at + run  # in block q's lanes
+    rolled = pltpu.roll(half, at, axis=1)  # lane t -> (t + at) % T
     lane = jax.lax.broadcasted_iota(jnp.int32, half.shape, 1)
-    stage_ref[:, :T] = jnp.where(
-        (lane >= fill) & (lane < end), rolled, stage_ref[:, :T])
-    stage_ref[:, T:] = jnp.where(lane < end - T, rolled, stage_ref[:, T:])
-    fill_ref[0] = end
+    stage_ref[q] = jnp.where((lane >= at) & (lane < end), rolled,
+                             stage_ref[q])
+    stage_ref[q + 1] = jnp.where(lane < end - T, rolled, stage_ref[q + 1])
+    fill_ref[0] = fill + run
 
 
 def _subtract_and_search(c, rows, parent, h_small, small_left_b, do_split,
@@ -668,10 +735,11 @@ def _split_step_kernel(
     W, F, k, Bp, Fc, NC, fgroup=8, direct_read=False, exchange=False,
 ):
     """The WHOLE split step in one launch: per-tile compaction and
-    staging of the smaller child's rows, its histogram over full tiles
-    of them (steps 0..nt-1; the remainder drains at step nt), then
-    subtract + two-child search + in-place histogram-buffer row updates
-    (steps nt and nt+1).  One launch and not two: a Mosaic call of zero
+    staging of the smaller child's rows, K parent tiles a tile step
+    (split_tiles), its histogram over full tiles of them (steps
+    0..nt-1; the remainder drains at step nt), then subtract + two-child
+    search + in-place histogram-buffer row updates (steps nt and nt+1).
+    One launch and not two: a Mosaic call of zero
     or one grid step costs 13-14 us on a v5e (measured back to back in a
     fori_loop: PERF.md, PR 27), and the [Fp, 4, Bp] h_small never makes
     a round trip through HBM.
@@ -687,23 +755,25 @@ def _split_step_kernel(
     accumulators of every chunk.  A table of one chunk (Fp <= Fc) has
     the two tail steps it always had.
 
-    ``nt`` is the LIVE tile count, a run-time value (scal_i[10]; the
-    grid is ``nt + 2 * NC (+1 direct)`` steps, a dynamic bound): one body
-    serves every window size, so the grower launches it outside any
-    ``lax.cond`` and the record stays in the loop's carry.  Tiles past
-    ``nt`` are never visited — their ``comp``/``cnt`` blocks keep
+    ``nt`` is the count of TILE STEPS, ``ceil(live tiles / K)``, a
+    run-time value (scal_i[10]; the grid is ``nt + 2 * NC (+1 direct)``
+    steps, a dynamic bound): one body serves every window size, so the
+    grower launches it outside any ``lax.cond`` and the record stays in
+    the loop's carry.  Tiles past the live count inside the last step
+    are invalid by ``pcnt`` (no lefts, no staged rows); tiles past the
+    last step are never visited — their ``comp``/``cnt`` blocks keep
     whatever the buffer held, and split_step_window masks them.
 
     scal_i [11]: (parent_slot, left_slot, new_slot, do_split, f, thr,
-                 is_cat, pcnt, begin//T, begin%T, live tiles)
+                 is_cat, pcnt, begin//KT, begin%KT, tile steps)
     scal_f [16]: pallas_search._pack_scal layout
-    win_ref    : the [W, T] window tile (non-direct mode).  With
-                 ``direct_read`` the RECORD itself is the (single,
-                 ALIASED) data operand: each step fetches one T-aligned
-                 block and writes it back unchanged through the aliased
-                 output, and the unaligned window tile i-1 is
-                 roll-merged from the PREVIOUS block (VMEM scratch) and
-                 the current one — the grid gains one pipeline step.
+    win_ref    : the [W, K*T] window block of K tiles (non-direct mode).
+                 With ``direct_read`` the RECORD itself is the (single,
+                 ALIASED) data operand: each step fetches one
+                 KT-aligned block and writes it back unchanged through
+                 the aliased output, and the unaligned window block i-1
+                 is roll-merged from the PREVIOUS block (VMEM scratch)
+                 and the current one — the grid gains one pipeline step.
                  The single-mention aliased pass-through is what lets
                  XLA chain the record in place through place_runs: any
                  second read of the record (a window slice, a go
@@ -715,11 +785,13 @@ def _split_step_kernel(
                  the tile steps), the new slot's at the write steps
     hists_out  : the left row's chunk at a search step, the right row's
                  at a write step
-    cnt_ref    : [1, 128] i32 per tile — lane 0 carries this tile's
-                 LEFT count, so the XLA side derives cl/cr/nleft with
-                 no go vector (and no record read) at all; lane 1 of the
-                 LAST live tile's group carries how many histogram tile
-                 bodies the launch ran (_hist_tiles)
+    comp_ref   : [K, W, 2T] — the step's tiles' compacted blocks
+    cnt_ref    : [1, K*128] i32, a 128-lane group a tile — lane 0
+                 carries the tile's LEFT count, so the XLA side derives
+                 cl/cr/nleft with no go vector (and no record read) at
+                 all; lane 1 of every group of the LAST step's block
+                 carries how many histogram tile bodies the launch ran
+                 (_hist_tiles)
     hacc_ref   : VMEM scratch [NC * Fc, 4, Bp], as the next two — the
                  smaller child's histogram over the
                  last few staged tiles (which child: the one with fewer
@@ -731,14 +803,14 @@ def _split_step_kernel(
                  right-child stash, chunk by chunk, for the write steps
     hhi_ref, hlo_ref : VMEM scratch — that histogram's running sum as
                  two floats (_fold_hacc)
-    stage_ref  : VMEM scratch [W, 2T] — the smaller child's rows,
-                 compacted across tiles (_split_tile appends, the body
-                 below takes lanes [0, T) and moves [T, 2T) down).  The
-                 one-hot body is bound by the VPU and costs the same for
-                 a row of the sibling as for one of the child: over every
-                 parent tile with the sibling masked it was 2.7 times
-                 the rows, 856 of the step's 1,141 ms a tree at 7.5M x
-                 100 (PERF.md, PR 31)
+    stage_ref  : VMEM scratch [K+1, W, T] — the smaller child's rows,
+                 compacted across tiles (_stage appends a tile's run,
+                 the body below takes block 0 and moves the rest down).
+                 The one-hot body is bound by the VPU and costs the same
+                 for a row of the sibling as for one of the child: over
+                 every parent tile with the sibling masked it was 2.7
+                 times the rows, 856 of the step's 1,141 ms a tree at
+                 7.5M x 100 (PERF.md, PR 31)
     fill_ref   : SMEM scratch [2] — rows waiting in ``stage_ref``
                  (< T between steps), and the histogram tiles run
     best_ref   : SMEM scratch [2] f32, last — each child's best raw
@@ -769,6 +841,7 @@ def _split_step_kernel(
          fill_ref, best_ref) = refs
 
     T = TILE
+    K = stage_ref.shape[0] - 1  # parent tiles a tile step
     i = pl.program_id(0)
     do_split = scal_i_ref[3] > 0
     nt = scal_i_ref[10]
@@ -808,18 +881,19 @@ def _split_step_kernel(
                 # output block back when its index moves, and the search
                 # step fills this one before it does)
                 r = scal_i_ref[9]
-                # tile lanes [0, T-r) from prev[:, r:], lanes [T-r, T)
-                # from cur[:, :r): both the same right-rotation by
-                # (T - r) % T (dynamic shifts are the one dynamic-lane
+                # block lanes [0, KT-r) from prev[:, r:], lanes [KT-r,
+                # KT) from cur[:, :r): both the same right-rotation by
+                # (KT - r) % KT (dynamic shifts are the one dynamic-lane
                 # primitive Mosaic supports)
+                KT = K * T
                 prev = prev_ref[...]
-                sh = jax.lax.rem(T - r, T)
+                sh = jax.lax.rem(KT - r, KT)
                 ra = pltpu.roll(prev, sh, 1)
                 rb = pltpu.roll(cur, sh, 1)
-                lane = jax.lax.broadcasted_iota(jnp.int32, (W, T), 1)
-                m = (lane < (T - r)).astype(jnp.int32)
-                tile = ra * m + rb * (1 - m)
-                _split_tile(tile, *tile_args, i - 1, *tile_refs, F=F, k=k)
+                lane = jax.lax.broadcasted_iota(jnp.int32, (W, KT), 1)
+                m = (lane < (KT - r)).astype(jnp.int32)
+                tiles = ra * m + rb * (1 - m)
+                _split_tiles(tiles, *tile_args, i - 1, *tile_refs, F=F, k=k)
 
             prev_ref[...] = cur
     else:
@@ -832,35 +906,41 @@ def _split_step_kernel(
             # search still needs
             if not exchange:
                 hists_out_ref[0] = hrow_ref[0]
-            _split_tile(win_ref[...], *tile_args, i, *tile_refs, F=F, k=k)
+            _split_tiles(win_ref[...], *tile_args, i, *tile_refs, F=F, k=k)
 
-    # The histogram body's ONE call: on a full tile of staged rows as
-    # soon as a tile step has filled one, and at the search step on
-    # whatever is left (``fill < T`` there: every tile step that filled
-    # a tile has emptied it here).
-    fill = fill_ref[0]
+    # The histogram body's ONE call, in a loop of K turns: on a full
+    # tile of staged rows for each one a tile step has filled (at most
+    # K), and at the search step on whatever is left (``fill < T``
+    # there: every tile step that filled a tile has emptied it here).
+    def drain(_, carry):
+        fill = fill_ref[0]
 
-    @pl.when((fill >= T) | ((i == search_step) & (fill > 0)))
-    def _():
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-        _hist_tile_body(stage_ref, hacc_ref, F=F, k=k, Bp=Bp,
-                        fgroup=fgroup, live=(lane < fill).astype(jnp.float32))
-        stage_ref[:, :T] = stage_ref[:, T:]
-        fill_ref[0] = jnp.maximum(fill - T, 0)
-        ran = fill_ref[1] + 1
-        fill_ref[1] = ran
-
-        @pl.when(ran % FOLD_TILES == 0)
+        @pl.when((fill >= T) | ((i == search_step) & (fill > 0)))
         def _():
-            _fold_hacc(*sums, Fc, NC)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+            _hist_tile_body(
+                stage_ref, hacc_ref, F=F, k=k, Bp=Bp, fgroup=fgroup,
+                live=(lane < fill).astype(jnp.float32))
+            stage_ref[:K] = stage_ref[1:]
+            fill_ref[0] = jnp.maximum(fill - T, 0)
+            ran = fill_ref[1] + 1
+            fill_ref[1] = ran
+
+            @pl.when(ran % FOLD_TILES == 0)
+            def _():
+                _fold_hacc(*sums, Fc, NC)
+
+        return carry
+
+    jax.lax.fori_loop(0, K, drain, 0)
 
     @pl.when(i >= nt + off)
     def _():
-        # tail steps revisit tile nt-1's count block: an identity
-        # rewrite, so interpret mode never flushes it unwritten, with
-        # the histogram tiles run in its spare lane 1
-        lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
-        cnt_ref[...] = jnp.where(lane == 1, fill_ref[1], cnt_ref[...])
+        # tail steps revisit the last tile step's count block: an
+        # identity rewrite, so interpret mode never flushes it unwritten,
+        # with the histogram tiles run in each group's spare lane 1
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, K * 128), 1)
+        cnt_ref[...] = jnp.where(lane % 128 == 1, fill_ref[1], cnt_ref[...])
         if direct_read:
             rec_out_ref[...] = rec_ref[...]
 
@@ -911,7 +991,7 @@ def _place_kernel(cl_ref, sc_ref, comp_ref, rec_in_ref, rec_ref,
     and appends its left run (``cl[j]`` lanes of ``comp[:, :T]``) to the
     left child's write buffer and its valid right run (the prefix of
     ``comp[:, T:]`` below ``pcnt``) to the right child's, each the way
-    _split_tile stages the smaller child's rows: a roll to the fill and
+    _stage stages the smaller child's rows: a roll to the fill and
     two lane masks, the child's leaf id stamped into ``leaf_row``.
 
     A write buffer is a RING of two ``[W, T]`` halves.  Lefts land in
@@ -1203,21 +1283,23 @@ def place_vmem_bytes(W: int) -> int:
 def split_step_vmem_bytes(Fp: int, Bp: int, W: int) -> int:
     """What ``split_step_counted`` keeps in VMEM on the chip, in bytes,
     with ``(Fc, NC) = feature_chunk(Fp, Bp)``, ``blk = Fc * 4 * Bp * 4``
-    (a ``[Fc, 4, Bp]`` float32 block of a leaf's histogram) and ``rec =
-    W * TILE * 4`` (a ``[W, TILE]`` block of the record):
+    (a ``[Fc, 4, Bp]`` float32 block of a leaf's histogram), ``rec =
+    W * TILE * 4`` (a ``[W, TILE]`` block of the record) and ``K =
+    split_tiles(W)`` tiles a step:
 
     * ``3 * NC * blk``: the accumulators ``hacc``, ``hhi``, ``hlo`` of
       every chunk (the tile steps fill them all at once);
     * ``4 * blk``: the parent's block in and the child's block out, both
       double-buffered;
-    * ``11 * rec``: the record block in and out (double-buffered, 4),
-      the ``[1, W, 2 * TILE]`` ``comp`` block out (double-buffered, 4),
-      the ``[W, 2 * TILE]`` staging buffer (2) and ``prev`` (1);
+    * ``(10 * K + 1) * rec``: the ``[W, K * TILE]`` record block in and
+      out (double-buffered, 4K), the ``[K, W, 2 * TILE]`` ``comp`` block
+      out (double-buffered, 4K), the ``[K + 1, W, TILE]`` staging buffer
+      (K + 1) and ``prev`` (K);
     * what no spec names, GIVEN and not derived: the search's
       ``[Fc, Bp]`` planes of both children and the fold's, ``8 * blk``;
-      the compaction's and the row pick's working tiles, ``5 * rec``;
-      the search's two ``[Bp, Bp]`` triangular matrices; 2 MiB for the
-      small blocks (meta, counts, the one-hot).
+      the compaction's and the row pick's working tiles, ``5 * K *
+      rec``; the search's two ``[Bp, Bp]`` triangular matrices; 2 MiB
+      for the small blocks (meta, counts, the one-hot).
 
     The given terms are held against what Mosaic asks for, read off
     deviceless v5e compiles at falling limits (PERF.md, PR 34; MiB,
@@ -1228,13 +1310,16 @@ def split_step_vmem_bytes(Fp: int, Bp: int, W: int) -> int:
     record's height by about 11 ``rec`` up to 512 words and by about 15
     past them.  tests/test_chip_compile.py compiles the step under this limit at
     the widest table the grower's gate admits, a record-bound one and
-    one of uint16 bins among them."""
+    one of uint16 bins among them.  A record taller than 128 words
+    takes one tile a step (split_tiles), so the gate's edge is where it
+    was with one tile a step at every height."""
     from .pallas_histogram import feature_chunk
 
     Fc, NC = feature_chunk(Fp, Bp)
     blk = Fc * 4 * Bp * 4
     rec = W * TILE * 4
-    return ((3 * NC + 12) * blk + 16 * rec + 2 * Bp * Bp * 4
+    K = split_tiles(W)
+    return ((3 * NC + 12) * blk + (15 * K + 1) * rec + 2 * Bp * Bp * 4
             + (2 << 20))
 
 
@@ -1270,8 +1355,8 @@ def split_step_counted(
     The split decision AND the per-tile left counts live entirely in
     the kernel (_tile_go + the cnt output): the XLA side touches the
     record only through the kernel's block reads (on hardware, two
-    T-aligned blocks roll-merged per tile — no materialized window
-    slice), which is what lets the aliased placement (place_runs)
+    aligned [W, K*TILE] blocks roll-merged a grid step — no materialized
+    window slice), which is what lets the aliased placement (place_runs)
     update the record in place instead of paying a full-record copy
     per split.
 
@@ -1301,11 +1386,14 @@ def split_step_counted(
 def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
                      parent_slot, new_slot, scal_f, meta, *, F, cap, k,
                      fgroup, interpret, live_tiles, exchange=False,
-                     Fp=None, Bp=None):
+                     Fp=None, Bp=None, tiles_per_step=None):
     """The split step's launch (``split_step_counted``); with
     ``exchange`` the launch that stops at the smaller child's histogram
     (``split_hist_counted``: ``hists`` and ``meta`` are None, ``Fp`` and
-    ``Bp`` say the histogram's shape)."""
+    ``Bp`` say the histogram's shape).  ``tiles_per_step`` (tests and
+    kernel-alone timings only) overrides K, which is ``split_tiles`` of
+    the record's height, halved until ``cap`` and ``n_pad`` hold whole
+    blocks of K tiles."""
     from .pallas_histogram import feature_chunk
 
     W, n_pad = rec.shape
@@ -1313,7 +1401,14 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
     assert cap % T == 0, (cap, T)
     assert n_pad % T == 0, (n_pad, T)
     nt = cap // T
-    nblocks = n_pad // T
+    K = tiles_per_step
+    if K is None:
+        K = split_tiles(W)
+        while nt % K or (n_pad // T) % K:
+            K //= 2
+    assert nt % K == 0 and (n_pad // T) % K == 0, (nt, n_pad // T, K)
+    KT = K * T
+    nblocks = n_pad // KT
     if not exchange:
         P, Fp, _, Bp = hists.shape
     Fc, NC = feature_chunk(Fp, Bp)
@@ -1323,34 +1418,35 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
         meta = jnp.pad(meta, ((0, NC * Fc - meta.shape[0]), (0, 0)))
 
     i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
-    b0 = i32(begin) // T
-    roff_in = i32(begin) % T
+    b0 = i32(begin) // KT
+    roff_in = i32(begin) % KT
     live = _live_tiles(live_tiles, nt)
+    steps = (live + K - 1) // K  # tile steps, K live tiles each but the last
     scal_i = jnp.stack([
         i32(parent_slot), i32(parent_slot), i32(new_slot), i32(do_split),
         jnp.maximum(i32(f), 0), i32(thr), i32(is_cat), i32(pcnt),
-        b0, roff_in, live])
+        b0, roff_in, steps])
 
     direct_read = not interpret
     off = 1 if direct_read else 0  # pipeline offset (see the kernel)
     # block walk of the single aliased record view: b0, b0+1, ..,
-    # b0+live (clamped), parked on the last block for the tail steps
+    # b0+steps (clamped), parked on the last block for the tail steps
     def _rec_idx(i, si, sf):
         return (0, jnp.minimum(si[8] + jnp.minimum(i, si[10]), nblocks - 1))
 
-    def _tile_idx(i, si):  # comp/cnt block of the tile done at step i
+    def _tile_idx(i, si):  # comp/cnt block of the tiles done at step i
         return jnp.clip(i - off, 0, si[10] - 1)
 
     if direct_read:
         data_in = [rec]
-        data_specs = [pl.BlockSpec((W, T), _rec_idx)]
+        data_specs = [pl.BlockSpec((W, KT), _rec_idx)]
     else:
         # interpret fallback: materialized window slice (pltpu.roll
         # paths are hardware-only; CPU tests keep the reference DS)
         data_in = [jax.lax.dynamic_slice(rec, (0, begin), (W, cap))]
         data_specs = [
             pl.BlockSpec(
-                (W, T),
+                (W, KT),
                 lambda i, si, sf: (0, jnp.minimum(i, si[10] - 1))),
         ]
 
@@ -1368,21 +1464,21 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
                 _chunk_idx(i, si), 0, 0)
 
     tile_specs = [
-        pl.BlockSpec((1, W, 2 * T),
+        pl.BlockSpec((K, W, 2 * T),
                      lambda i, si, sf: (_tile_idx(i, si), 0, 0)),
-        # counts ride the LANE axis: a (1, 128) block on [1, nt*128] is
+        # counts ride the LANE axis: a (1, K*128) block on [1, nt*128] is
         # Mosaic-legal (major dim == array dim), a [nt, 128] row-per-tile
         # layout is not (sublane dim 1)
-        pl.BlockSpec((1, 128), lambda i, si, sf: (0, _tile_idx(i, si))),
+        pl.BlockSpec((1, K * 128), lambda i, si, sf: (0, _tile_idx(i, si))),
     ]
     # aliased identity pass-through of the record (same block walk as the
     # input view): the output VALUE feeds place_runs so every link of the
     # record chain is single-use — see the kernel docstring's copy note
-    rec_specs = [pl.BlockSpec((W, T), _rec_idx)] if direct_read else []
+    rec_specs = [pl.BlockSpec((W, KT), _rec_idx)] if direct_read else []
     scratch = [pltpu.VMEM((NC * Fc, 4, Bp), jnp.float32)] * 3 + [
-        pltpu.VMEM((W, 2 * T), jnp.int32),  # staged child rows
+        pltpu.VMEM((K + 1, W, T), jnp.int32),  # staged child rows
         pltpu.SMEM((2,), jnp.int32),  # their count, the tiles run
-    ] + ([pltpu.VMEM((W, T), jnp.int32)] if direct_read else [])
+    ] + ([pltpu.VMEM((W, KT), jnp.int32)] if direct_read else [])
     tile_shapes = [jax.ShapeDtypeStruct((nt, W, 2 * T), jnp.int32),
                    jax.ShapeDtypeStruct((1, nt * 128), jnp.int32)]
     rec_shapes = ([jax.ShapeDtypeStruct((W, n_pad), jnp.int32)]
@@ -1397,7 +1493,7 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
         # histogram: no hists row, no search
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(live + NC + off,),
+            grid=(steps + NC + off,),
             in_specs=data_specs,
             out_specs=[pl.BlockSpec(
                 (Fc, 4, Bp), lambda i, si, sf: (_chunk_idx(i, si), 0, 0))]
@@ -1422,7 +1518,7 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         # a DYNAMIC bound: see the docstring
-        grid=(live + 2 * NC + off,),
+        grid=(steps + 2 * NC + off,),
         in_specs=data_specs + [
             pl.BlockSpec((1, Fc, 4, Bp),
                          lambda i, si, sf: _hists_idx(i, si, si[0])),

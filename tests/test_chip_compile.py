@@ -169,6 +169,36 @@ def _eqns(jaxpr):
     return (eqn for eqn, _ in _eqns_under(jaxpr))
 
 
+def _refs_read(jaxpr, var):
+    """The vars of ``jaxpr`` that ``var`` is computed from through ref
+    reads (``get``), across the jaxprs its equations hold: an input of a
+    loop's or a branch's jaxpr stands for its equation's operand."""
+    made_by, outer = {}, {}
+    for eqn in _eqns(jaxpr):
+        made_by.update((v, eqn) for v in eqn.outvars)
+        subs = [getattr(p, "jaxpr", p) for param in eqn.params.values()
+                for p in (param if isinstance(param, (list, tuple))
+                          else [param])]
+        for sub in subs:
+            if hasattr(sub, "invars"):
+                ops = eqn.invars[len(eqn.invars) - len(sub.invars):]
+                outer.update(zip(sub.invars, ops))
+    reads, todo = set(), [var]
+    while todo:
+        v = todo.pop()
+        while v in outer:
+            v = outer[v]
+        eqn = made_by.pop(v, None)
+        if eqn is not None:
+            if eqn.primitive.name == "get":
+                ref = eqn.invars[0]
+                while ref in outer:
+                    ref = outer[ref]
+                reads.add(ref)
+            todo += [u for u in eqn.invars if not hasattr(u, "val")]  # no Literals
+    return reads
+
+
 def test_the_one_histogram_kernel_keeps_the_bins_row_in_the_lanes(grower):
     """The grower holds ONE standalone histogram call, and its body
     builds the one-hot transposed: every dot contracts the lane axis of
@@ -199,12 +229,13 @@ def test_the_one_histogram_kernel_keeps_the_bins_row_in_the_lanes(grower):
 def test_the_split_step_sums_full_tiles_of_staged_rows(grower):
     """The split step's F-feature one-hot body is in the kernel ONCE,
     under one condition, and that condition reads the staging count (the
-    kernel's SMEM scratch): it runs when the smaller child's compacted
-    rows have filled a tile, or are left over at the search step, and
-    not in the per-tile branch that compacts every tile of the parent.
-    There, with the sibling's rows masked, it was 2.7 times the rows and
-    856 of the step's 1,141 ms a tree at 7.5M x 100 (PERF.md, PR 31).
-    Read from the traced jaxpr, as the root kernel's shape is above."""
+    kernel's SMEM scratch): it runs, in a loop of K turns, once for each
+    tile the smaller child's compacted rows have filled, or on the rows
+    left over at the search step, and not in the per-step branch that
+    compacts the parent's tiles.  There, with the sibling's rows masked,
+    it was 2.7 times the rows and 856 of the step's 1,141 ms a tree at
+    7.5M x 100 (PERF.md, PR 31).  Read from the traced jaxpr, as the
+    root kernel's shape is above."""
     from lightgbm_tpu.ops import record as R
 
     F, Bp, T = 28, 256, R.TILE
@@ -232,14 +263,7 @@ def test_the_split_step_sums_full_tiles_of_staged_rows(grower):
     assert len({id(conds[0]) for _, conds in body}) == 1
     cond = body[0][1][0]
     # what the condition is computed from, back to the refs it reads
-    made_by = {v: e for e in kernel.eqns for v in e.outvars}
-    reads, todo = set(), [cond.invars[0]]
-    while todo:
-        eqn = made_by.pop(todo.pop(), None)
-        if eqn is not None:
-            if eqn.primitive.name == "get":
-                reads.add(eqn.invars[0])
-            todo += [v for v in eqn.invars if not hasattr(v, "val")]  # no Literals
+    reads = _refs_read(kernel, cond.invars[0])
     staged = [v for v in kernel.invars
               if "smem" in str(v.aval) and v.aval.shape == (2,)
               and v.aval.dtype == jnp.int32]
@@ -335,8 +359,9 @@ def _the_carry_is_clean(compiled, n, F, L, searched=False):
     from lightgbm_tpu.obs import device_time as dt
     from lightgbm_tpu.ops import record as R
 
-    n_rec = R.round_up(n, R.TILE)
-    record = f"s32[{R.rec_height(F, 4)},{2 * n_rec}]"
+    W = R.rec_height(F, 4)
+    n_rec = R.round_up(n, R.TILE * R.split_tiles(W))
+    record = f"s32[{W},{2 * n_rec}]"
     hists = f"f32[{L},{R.round_up(F, 8)},4,256]"
     module = compiled.runtime_executable().hlo_modules()[0]
     prog = dt.program_of_module(module.as_serialized_hlo_module_proto())
@@ -370,7 +395,7 @@ def _the_carry_is_clean(compiled, n, F, L, searched=False):
 # op's metadata and the kernels' source locations cut.  A change to the
 # one-chip program on purpose records its new digest here.
 ONE_CHIP_GROW_PROGRAM = (
-    "60ba0a83f2fe304b48b14384f1826dfb33e38d30531fec36ec5f09ed3bebaf09")
+    "fcec606f8f6ea8b1541a4ae6578d088ae3922032a052f5c244f12ee8b3de47a5")
 
 
 def _kernel_asm(body: str) -> str:
@@ -650,67 +675,89 @@ def test_a_table_with_categorical_columns_compiles_for_v5e(topo):
 
 @pytest.mark.parametrize("which,F", [("grower", 28), ("wide_grower", 2000)])
 def test_the_split_step_permutes_a_tile_by_lane_gathers(request, which, F):
-    """The split step's kernel computes a tile's permutation on rows of
-    control words and applies it by lane gathers (ops/record.py
-    _source_lanes, _gather_lanes): its jaxpr holds ``gather`` equations
-    of ``[W, 128]`` blocks, ten a half, and the only lane rotates of an
-    operand as tall as the record are the three that move whole runs
-    (two for the direct read's unaligned tile, one for the staging
-    append); every other rotate is of the prefix sum's one row or the
-    two compress networks' two.  Rolling and blending all ``W + 1``
-    rows through 18 stages cost a tile of 512 words 22.7 us in the step
-    where this costs 13.1 (PERF.md, PR 35)."""
+    """The split step's kernel computes its K tiles' permutations on rows
+    of control words and applies them by lane gathers (ops/record.py
+    _source_rows, _gather_lanes): its jaxpr holds ``gather`` equations
+    of ``[W, 128]`` blocks, ten a half of each of the K tiles, and the
+    only lane rotates of an operand as tall as the record are those that
+    move whole runs (two for the direct read's unaligned ``[W, K *
+    TILE]`` block, one a tile for the staging append); every other
+    rotate is of the ``[2K, TILE]`` operand on which ONE prefix sum and
+    ONE compress network serve the K tiles (two rows a tile).  Rolling
+    and blending all ``W + 1`` rows through 18 stages cost a tile of 512
+    words 22.7 us in the step where one tile's network costs 13.1
+    (PERF.md, PR 35)."""
     from lightgbm_tpu.ops import record as R
 
     jaxpr = request.getfixturevalue(which)[-1]
     W, T, G = R.rec_height(F, 4), R.TILE, R.GATHER_LANES
+    K = R.split_tiles(W)
+    assert K == {28: 4, 2000: 1}[F]
     step = [e for e in _eqns(jaxpr) if e.primitive.name == "pallas_call"
             and "lgbm.split_step.dyn" in str(e.source_info.name_stack)]
     assert len(step) == 1, [str(e.source_info.name_stack) for e in step]
     body = list(_eqns(step[0].params["jaxpr"]))
     gathers = [e for e in body if e.primitive.name == "gather"]
     blocks = T // G
-    assert len(gathers) == 2 * blocks * (blocks + 1) // 2, len(gathers)
+    assert len(gathers) == K * 2 * blocks * (blocks + 1) // 2, len(gathers)
     assert {tuple(v.aval.shape for v in e.invars) for e in gathers} == {
         ((W, G), (W, G, 1))}
     rolled = [e.invars[0].aval.shape for e in body
               if e.primitive.name == "roll"]
-    assert sorted(s for s in rolled if s[0] > 2) == [(W, T)] * 3, rolled
-    assert {s for s in rolled if s[0] <= 2} == {(1, T), (2, T)}, rolled
+    assert sorted(s for s in rolled if s[0] == W) == sorted(
+        [(W, K * T)] * 2 + [(W, T)] * K), rolled
+    assert {s for s in rolled if s[0] != W} == {(2 * K, T)}, rolled
 
 
-def test_the_compaction_is_scheduled_well_under_the_parents_bundles(
-        topo, llo_dir):
-    """tools/kernel_bundles.py reads libtpu's final schedule of
-    ``_compact_body`` alone at the tall cells' 32 words: under 1,000
-    bundles a tile where rolling the whole tile through the networks
-    took 1,198 (PERF.md, PR 35: at 512 words the parent's count came
-    within 2% of the chip's time; at 32 the chip waits on the rotate
-    unit, which no schedule shows).  A form that spills, or a multiply
-    where a select stood, shows here at no chip time."""
+def _compaction_bundles(topo, llo_dir, tiles):
     kernel_bundles.read(llo_dir)  # what the compiles before this wrote
-    kernel_bundles.compact(_shape(topo), 32).compile()
+    kernel_bundles.compact(_shape(topo), 32, tiles).compile()
     found = kernel_bundles.read(llo_dir)
     calls = [n for name, n in found.items() if name.startswith("compact_tiles")]
-    assert len(calls) == 1 and 100 < calls[0] < 1000, found
+    assert len(calls) == 1, found
+    return calls[0]
 
 
-# (tools/kernel_bundles.py's kernel, columns, the instruction's name, the
-# parent's bundles, the bundles allowed: PERF.md, PR 37)
-@pytest.mark.parametrize("which,F,name,parents,limit", [
-    ("root", 32, "lgbm.histogram.cap", 12_870, 8_500),
-    ("split_step", 100, "lgbm.split_step.dyn", 21_547, 19_000)])
+@pytest.mark.parametrize("tiles", [1, R.split_tiles(32)])
+def test_the_compaction_is_scheduled_well_under_the_parents_bundles(
+        topo, llo_dir, tiles):
+    """tools/kernel_bundles.py reads libtpu's final schedule of the
+    compaction (``_compact_tiles``) alone at the tall cells' 32 words:
+    one tile under 1,000 bundles where rolling the whole tile through the
+    networks took 1,198 (PERF.md, PR 35: at 512 words the parent's count
+    came within 2% of the chip's time; at 32 the chip waits on the
+    rotate unit, which no schedule shows); and at the K tiles a grid
+    step the split step takes at 32 words, whose prefix sum and compress
+    network serve all K in the vregs one used, at most three quarters of
+    one tile's bundles a parent tile (737 and 320 when K = 4 came in:
+    PERF.md section 6).  A form that spills, or a multiply where a select
+    stood, or a network a tile, shows here at no chip time."""
+    one = _compaction_bundles(topo, llo_dir, 1)
+    assert 100 < one < 1000, one
+    if tiles > 1:
+        assert _compaction_bundles(topo, llo_dir, tiles) / tiles <= 0.75 * one
+
+
+# (tools/kernel_bundles.py's kernel, columns and options, the
+# instruction's name, the parent's bundles, the bundles allowed: PERF.md,
+# PR 37)
+@pytest.mark.parametrize("which,F,kw,name,parents,limit", [
+    ("root", 32, {}, "lgbm.histogram.cap", 12_870, 8_500),
+    ("split_step", 100, {"tiles": 1}, "lgbm.split_step.dyn", 21_547,
+     19_000)])
 def test_the_onehot_body_is_scheduled_under_the_parents_bundles(
-        topo, llo_dir, which, F, name, parents, limit):
+        topo, llo_dir, which, F, kw, name, parents, limit):
     """libtpu's final schedule of the two kernels that hold the one-hot
     body: the root kernel's step of 32 columns x 2,048 rows read 12,870
     bundles with a ``[256, lanes]`` one-hot and reads 7,618 with 128 rows
-    and the planes on the stat rows, the split step at 100 columns 21,547
-    and 17,719 (on the chip the body went from 0.169 to 0.086 ns a cell:
-    PERF.md, PR 37).  A body that builds the tall one-hot again, or
-    casts the stat rows a feature, shows here at no chip time."""
+    and the planes on the stat rows, the split step at 100 columns (at
+    one parent tile a grid step: four add three tiles' compaction beside
+    the one body) 21,547 and 17,719 (on the chip the body went from 0.169
+    to 0.086 ns a cell: PERF.md, PR 37).  A body that builds the tall
+    one-hot again, or casts the stat rows a feature, shows here at no
+    chip time."""
     kernel_bundles.read(llo_dir)  # what the compiles before this wrote
-    kernel_bundles.KERNELS[which](_shape(topo), F).compile()
+    kernel_bundles.KERNELS[which](_shape(topo), F, **kw).compile()
     found = kernel_bundles.read(llo_dir)
     calls = [n for key, n in found.items() if key.startswith(name)]
     assert len(calls) == 1 and parents // 3 < calls[0] < limit, found

@@ -127,30 +127,49 @@ def test_compaction_matches_numpy_interior_window():
             == _numpy(rec, go, tile, cap - 100))
 
 
-def test_fused_split_step_matches_numpy():
-    """The fused grower's launch pair at the hlo_audit pinned shape:
-    record and ``nleft`` equal a numpy stable partition's, both
-    children's histogram rows a float64 numpy histogram's."""
+# (parent tiles a grid step, the leaf's tiles): 1, K-1, K, K+1 and 2K+1
+_K_LIVE = [(K, live) for K in (1, 2, 4)
+           for live in sorted({1, K - 1, K, K + 1, 2 * K + 1} - {0})]
+
+
+@pytest.mark.parametrize("K,live", _K_LIVE,
+                         ids=[f"K{K}-live{live}" for K, live in _K_LIVE])
+def test_fused_split_step_matches_numpy(K, live):
+    """The fused grower's launch pair at the hlo_audit table, on a leaf
+    of ``live`` tiles (the last short by 57 rows) at K parent tiles a
+    grid step: record and ``nleft`` equal a numpy stable partition's,
+    both children's histogram rows a float64 numpy histogram's, the
+    histogram tiles run ``ceil(rows / TILE)``, and everything the step
+    returns is K = 1's bit for bit (the compacted tiles the placement
+    reads, the counts, the histogram rows)."""
     from lightgbm_tpu.analysis.hlo_audit import _B as B, _F as F
     from lightgbm_tpu.analysis.hlo_audit import _split_step_inputs
 
-    rec, hists, scal_f, meta, s, cap, k = _split_step_inputs()
+    rec, hists, scal_f, meta, s, cap, k = _split_step_inputs(
+        tiles=live, tail=57, blocks_of=4)
     n, f, thr = int(s["pcnt"]), int(s["f"]), int(s["thr"])
     bins, g, h, m = (np.asarray(x) for x in R.unpack_window(
         rec[:, :n], F, k, jnp.uint8))
     left = bins[f] <= thr
     parent = _np_hist(bins, g, h, m, B)
     hists = hists.at[0, :F, :3, :B].set(jnp.asarray(parent, jnp.float32))
-    hists2, rec2, nleft, _, cl, _ = _fused_split(
+    got, one = (_fused_split(
         rec, hists, 0, n, f, thr, 0, 1, scal_f, meta, F, cap,
-        s["live_tiles"], True)
+        s["live_tiles"], True, tiles_per_step=tiles) for tiles in (K, 1))
+    assert np.asarray(got.comp)[:live].tobytes() == \
+        np.asarray(one.comp)[:live].tobytes()
+    for name in ("cl", "cr", "nleft", "hists", "res"):
+        assert np.asarray(getattr(got, name)).tobytes() == \
+            np.asarray(getattr(one, name)).tobytes(), name
     want_rec, want_nl = _np_partition(
         rec, left, 0, n, R.num_words(F, k) + 4, 0, 1)
-    assert int(nleft) == want_nl == int(np.asarray(cl).sum())
-    assert np.asarray(rec2).tobytes() == want_rec.tobytes()
+    assert int(got.nleft) == want_nl == int(np.asarray(got.cl).sum())
+    assert np.asarray(got.rec).tobytes() == want_rec.tobytes()
+    # the search's counts tie, so the kernel sums the left child
+    assert got.ran == one.ran == -(-want_nl // R.TILE)
     for row, side in ((0, left), (1, ~left)):
         np.testing.assert_allclose(
-            np.asarray(hists2[row, :F, :3, :B]),
+            np.asarray(got.hists[row, :F, :3, :B]),
             _np_hist(bins, g, h, m * side, B), rtol=0, atol=1e-4)
 
 
@@ -210,7 +229,7 @@ _GO_SHARES = (0.0, "one", 0.03, 0.5, 0.97, 1.0)
 @pytest.mark.parametrize("share", _GO_SHARES, ids=[str(s) for s in _GO_SHARES])
 @pytest.mark.parametrize("W", [8, 32, 64, 136])
 def test_compact_body_matches_a_numpy_stable_partition(W, share, tail):
-    """``_compact_body`` alone (the permutation computed on one two-row
+    """``_compact_tiles`` on one tile (the permutation computed on one two-row
     operand, applied by lane gathers) through an interpreted one-tile
     call: the lefts, in order, fill the left half from lane 0 and
     everything else, the invalid tail last, the right half, word for

@@ -18,6 +18,7 @@ from lightgbm_tpu.io.metadata import Metadata
 from lightgbm_tpu.learners import fused, serial
 from lightgbm_tpu.models import gbdt as gbdt_mod
 from lightgbm_tpu.objectives import create_objective
+from lightgbm_tpu.ops import record as R
 
 
 def _booster(platform, vmem=None, monkeypatch=None, F=5, n=600, **params):
@@ -54,7 +55,8 @@ _TABLE = [
 ]
 
 # (features, chunks of 256 features, record words, MiB the step keeps)
-_WIDE = [(264, 2, 72, 22), (1000, 4, 256, 34), (2000, 8, 512, 54)]
+# (K = 2 parent tiles a split step's grid step at 72 words, 1 past 128)
+_WIDE = [(264, 2, 72, 24), (1000, 4, 256, 34), (2000, 8, 512, 54)]
 
 
 @pytest.mark.parametrize(
@@ -149,13 +151,16 @@ def test_wide_tables_get_the_fused_grower_and_the_bound_is_named(
     counters = {"grow.feature_chunks": chunks, "grow.chunk_features": 256,
                 "grow.hist_block_bytes": 1 << 20, "grow.record_words": words,
                 "grow.onehot_planes": 2, "grow.place_steps_per_tile": 1,
-                "grow.place_launches_per_split": 1}
+                "grow.place_launches_per_split": 1,
+                "grow.split_tiles_per_step": R.split_tiles(words)}
     tel = telemetry.get_telemetry()
     before = {name: tel.counter(name) for name in counters}
     g = _booster("tpu", F=F)
     assert g._grower == ("fused", "") and g._grow.func is fused.grow_tree
+    tiles = "2 tiles" if words <= 128 else "1 tile"
     assert any(said + "96 MiB, one-hot of 2 x 128 bins, placement 1 step "
-               "a tile in 1 launch a split" in m
+               f"a tile in 1 launch a split, split step {tiles} a grid step"
+               in m
                and "grower=fused" in m
                for m in gbdt_mod._LOGGED_PATHS), gbdt_mod._LOGGED_PATHS
     assert {name: tel.counter(name) - before[name]
@@ -164,6 +169,26 @@ def test_wide_tables_get_the_fused_grower_and_the_bound_is_named(
     g = _booster("tpu", small, monkeypatch, F=F)
     assert g._grower[0] == "canonical", g._grower
     assert said in g._grower[1] and "past the chip's VMEM" in g._grower[1]
+
+
+# (features, the record's words, parent tiles a grid step of the split
+# step): the narrow cells' heights take four, a taller record fewer
+@pytest.mark.parametrize("F,words,tiles", [
+    (13, 16, 4), (220, 64, 4), (300, 80, 2), (600, 160, 1)])
+def test_the_booster_says_the_split_tiles_a_step(F, words, tiles):
+    """``grow.split_tiles_per_step`` is ``split_tiles`` of the record's
+    height alone, counted once a booster and named in the log line."""
+    from lightgbm_tpu.obs import telemetry
+
+    tel = telemetry.get_telemetry()
+    before = tel.counter("grow.split_tiles_per_step")
+    g = _booster("tpu", F=F)
+    assert g._grower == ("fused", "")
+    assert g._chunking.record_words == words
+    assert tel.counter("grow.split_tiles_per_step") - before == tiles
+    said = f"split step {tiles} tile{'s' if tiles > 1 else ''} a grid step"
+    assert any(said in m and "grower=fused" in m
+               for m in gbdt_mod._LOGGED_PATHS), gbdt_mod._LOGGED_PATHS
 
 
 # (max_bin, rows, the planes the kernels' one-hot body splits Bp into)

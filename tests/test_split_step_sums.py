@@ -16,6 +16,7 @@ import pytest
 import jax.numpy as jnp
 
 import lightgbm_tpu.ops.record as R
+from lightgbm_tpu.analysis.kernel_parity import _fused_split, _np_partition
 from lightgbm_tpu.ops.pallas_search import (
     _pack_meta, _pack_scal, search2_pallas)
 from lightgbm_tpu.ops.split import find_best_split
@@ -109,7 +110,8 @@ def test_a_bin_of_many_tiles_is_rounded_once():
 
 
 # The smaller child's rows in each of the parent's seven tiles (the last
-# holds 17 live rows): where the staging buffer has an edge.
+# holds 17 live rows): where the staging buffer has an edge.  At two and
+# four tiles a grid step each edge falls inside a step or on its border.
 _STAGED = {
     "fills_two_tiles_exactly": (_T, 0, 300, _T - 300, 0, 0, 0),
     "straddles_inside_a_parent_tile": (300, 400, 0, 0, 100, 0, 5),
@@ -117,25 +119,50 @@ _STAGED = {
     "no_rows_at_all": (0,) * 7,
     "all_in_the_partly_invalid_last_tile": (0, 0, 0, 0, 0, 0, 9),
 }
+# And on leaves of 1, K-1, K, K+1 and 2K+1 tiles at K tiles a grid step,
+# two fifths of every tile's rows: a tile of staged rows fills in the
+# third parent tile of each two and a half.
+_TWO_FIFTHS = "two_fifths_of_every_tile"
+_CASES = [(case, K, len(_STAGED[case])) for case in sorted(_STAGED)
+          for K in (1, 2, 4)] + [
+    (_TWO_FIFTHS, K, live) for K in (1, 2, 4)
+    for live in sorted({1, K - 1, K, K + 1, 2 * K + 1} - {0})]
+# Every case's window and record: whole blocks of four tiles, with room
+# for ``begin`` and another leaf after the window.
+_CAP, _N_PAD = 12 * _T, 16 * _T
+
+
+def _per_tile(case, live):
+    """The smaller child's rows in each of a leaf's ``live`` tiles, the
+    last of which holds 17 rows."""
+    if case == _TWO_FIFTHS:
+        return (2 * _T // 5,) * (live - 1) + (7,)
+    return _STAGED[case]
 
 
 @pytest.mark.parametrize("begin", [0, 77])
 @pytest.mark.parametrize("small", ["left", "right"])
-@pytest.mark.parametrize("case", sorted(_STAGED))
-def test_the_staged_histogram_is_the_smaller_childs(case, small, begin):
+@pytest.mark.parametrize("case,K,live", _CASES,
+                         ids=[f"{c}-K{K}-live{live}" for c, K, live in _CASES])
+def test_the_staged_histogram_is_the_smaller_childs(case, K, live, small,
+                                                     begin):
     """The smaller child's histogram from its staged rows, against
-    float64 numpy: a bagging mask with zeros, a window that starts
-    ``begin`` columns into the record and is followed by another leaf's
-    rows (which would go left, with gradients of 100), ``small`` on
-    either side.  The kernel ran ``ceil(rows / TILE)`` histogram tiles:
-    the full ones as they filled, the remainder at the drain."""
-    per_tile = _STAGED[case]
-    n, thr = 6 * _T + 17, 7
-    rng = np.random.RandomState(len(case))
+    float64 numpy, at K parent tiles a grid step on a leaf of ``live``
+    tiles: a bagging mask with zeros, a window that starts ``begin``
+    columns into the record and is followed by another leaf's rows
+    (which would go left, with gradients of 100), ``small`` on either
+    side.  The kernel ran ``ceil(rows / TILE)`` histogram tiles: the full
+    ones as they filled, the remainder at the drain.  The record placed
+    after it is a numpy stable partition's, and what the step returns is
+    K = 1's bit for bit: the compacted tiles the placement reads, the
+    counts, the histogram tiles run, the histogram rows."""
+    per_tile = _per_tile(case, live)
+    n, thr = (live - 1) * _T + 17, 7
+    rng = np.random.RandomState(len(case) + live)
     is_small = np.zeros(n, bool)
     for j, c in enumerate(per_tile):
-        live = min(_T, n - j * _T)
-        is_small[j * _T + rng.choice(live, c, replace=False)] = True
+        rows = min(_T, n - j * _T)
+        is_small[j * _T + rng.choice(rows, c, replace=False)] = True
     left = is_small if small == "left" else ~is_small
     bins = rng.randint(0, _B, (_F, n)).astype(np.uint8)
     bins[2] = np.where(left, rng.randint(0, thr + 1, n),
@@ -148,22 +175,47 @@ def test_the_staged_histogram_is_the_smaller_childs(case, small, begin):
         jnp.asarray(np.pad(bins, pad)),
         jnp.asarray(np.pad(g, pad[1], constant_values=100.0)),
         jnp.asarray(np.pad(h, pad[1], constant_values=1.0)),
-        jnp.asarray(np.pad(m, pad[1], constant_values=1.0)),
-        R.round_up(n, _T) + 3 * _T)
+        jnp.asarray(np.pad(m, pad[1], constant_values=1.0)), _N_PAD)
     parent = _hist64(bins, g, h, np.ones(n, bool), m).astype(np.float32)
     # the search's counts are bagged ones; the kernel sums the side with
     # fewer, which the cases keep the one meant
     lc, rc = (m * left).sum(), (m * ~left).sum()
     assert (lc <= rc) == (small == "left")
-    hl, hr, nleft, ran = _step_counted(rec, parent, n, thr, lc, rc, begin)
-    assert nleft == left.sum()
+    got, one = (_split_at(rec, parent, n, thr, lc, rc, begin, live, tiles)
+                for tiles in (K, 1))
+    assert np.asarray(got.comp)[:live].tobytes() == \
+        np.asarray(one.comp)[:live].tobytes()
+    for name in ("cl", "cr", "nleft", "hists", "res"):
+        assert np.asarray(getattr(got, name)).tobytes() == \
+            np.asarray(getattr(one, name)).tobytes(), name
+    assert int(got.nleft) == left.sum()
+    want_rec, _ = _np_partition(rec, left, begin, n,
+                                R.num_words(_F, _K) + 4, 0, 2)
+    assert np.asarray(got.rec).tobytes() == want_rec.tobytes()
+    hs = np.asarray(got.hists)
+    hl, hr = hs[0, :_F, :3, :_B], hs[2, :_F, :3, :_B]
     got_small, got_large = (hl, hr) if small == "left" else (hr, hl)
     want = _hist64(bins, g, h, is_small, m)
     size = _hist64(bins, np.abs(g), h, is_small, m)
     assert (np.abs(got_small - want) <= 3e-7 * size).all()
     np.testing.assert_array_equal(got_small[:, 2], want[:, 2])
     assert (parent - got_small).tobytes() == got_large.tobytes()
-    assert ran == -(-sum(per_tile) // _T) <= len(per_tile) + 1
+    assert got.ran == one.ran == -(-sum(per_tile) // _T) <= live + 1
+
+
+def _split_at(rec, parent, n, thr, lc, rc, begin, live, tiles):
+    """The launch pair on the leaf at ``tiles`` parent tiles a grid step
+    (analysis/kernel_parity.py ``_fused_split``: the smaller child summed,
+    the rows placed)."""
+    hists = np.zeros((3, _FP, 4, _BP), np.float32)
+    hists[0, :_F, :3, :_B] = parent
+    scal_f = _pack_scal(*[jnp.float32(x) for x in (
+        1., 0., 1., lc, 0., 1., rc, 1., 0., 0., 0., 0.)])
+    meta = _pack_meta(jnp.ones(_F, bool), jnp.full(_F, _B, jnp.int32),
+                      jnp.zeros(_F, bool), _FP)
+    return _fused_split(rec, jnp.asarray(hists), begin, n, 2, thr, 0, 2,
+                        scal_f, meta, _F, _CAP, jnp.int32(live), True,
+                        tiles_per_step=tiles)
 
 
 @pytest.mark.parametrize("impl", ["jnp", "pallas"])

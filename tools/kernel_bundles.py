@@ -13,7 +13,9 @@ It cannot see stalls, the rotate unit's latency, DMA waits or a loop's
 trip count, and it counts every ``pl.when`` branch.
 
     python tools/kernel_bundles.py compact 32 64 512    # record words
+    python tools/kernel_bundles.py compact 32x4         # 4 tiles a step
     python tools/kernel_bundles.py split_step 100 2000  # columns
+    python tools/kernel_bundles.py split_step 100x1     # 1 tile a step
     python tools/kernel_bundles.py root 32 100          # columns
     python tools/kernel_bundles.py place 16 32 512      # record words
 """
@@ -54,24 +56,34 @@ def read(path: str) -> dict:
     return found
 
 
-def compact(shape, W: int):
-    """``_compact_body`` alone on one ``[W, TILE]`` tile, lowered."""
+def compact(shape, W: int, tiles: int = 1):
+    """``_compact_tiles`` alone on one ``[W, tiles * TILE]`` block, what
+    a grid step of the split step compacts (one tile: partition_window's
+    step), lowered; its bundles a parent tile are the count over
+    ``tiles``."""
     import jax
     from lightgbm_tpu.ops import record as R
-    return jax.jit(R.compact_tiles).lower(
-        shape((W, R.TILE), "int32"), shape((R.TILE,), "int32"))
+
+    def compact_tiles(win, go):  # the kernel's name in the schedule
+        return R.compact_tiles(win, go, tiles=tiles)
+
+    return jax.jit(compact_tiles).lower(
+        shape((W, tiles * R.TILE), "int32"), shape((tiles * R.TILE,), "int32"))
 
 
-def split_step(shape, F: int):
-    """The split step's whole kernel at ``F`` columns of 255 bins."""
+def split_step(shape, F: int, tiles=None):
+    """The split step's whole kernel at ``F`` columns of 255 bins, at the
+    parent tiles a grid step its record's height gives (``tiles`` None)
+    or at ``tiles``."""
     import jax
     from lightgbm_tpu.ops import record as R
     n, Fp = 20_480, R.round_up(F, 8)
 
     def step(hists, rec, scal_f, meta, i):
-        return R.split_step_counted(
+        return R._split_step_call(
             hists, rec, i, i, i > 0, i, i, i > 3, i, i + 1, scal_f, meta,
-            F=F, cap=n, k=4, interpret=False, live_tiles=i)
+            F=F, cap=n, k=4, fgroup=8, interpret=False, live_tiles=i,
+            tiles_per_step=tiles)
 
     return jax.jit(step).lower(
         shape((8, Fp, 4, 256), "float32"),
@@ -119,7 +131,9 @@ if __name__ == "__main__":
     chip = jax.sharding.SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
     for size in sys.argv[2:]:
+        # "32x4": 32 words (or columns) at 4 parent tiles a grid step
         KERNELS[sys.argv[1]](
             lambda dims, dtype: jax.ShapeDtypeStruct(
-                dims, dtype, sharding=chip), int(size)).compile()
+                dims, dtype, sharding=chip),
+            *map(int, size.split("x"))).compile()
         print(sys.argv[1], size, read(dump), flush=True)
