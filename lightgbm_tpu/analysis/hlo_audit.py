@@ -224,7 +224,7 @@ def _split_step_inputs(tiles=1, tail=0, blocks_of=1):
     """A leaf of ``tiles`` tiles less ``tail`` rows at the record's
     origin, in a window and a record of whole blocks of ``blocks_of``
     tiles (the audit's: one tile, a one-tile window, ``n_pad = 2 *
-    TILE``, so one tile a grid step)."""
+    TILE``, which it launches at one tile a grid step)."""
     import jax.numpy as jnp
     import numpy as np
 
@@ -278,7 +278,7 @@ def _measure_split_step_window() -> dict:
             hists_, rec_, s["begin"], s["pcnt"], s["do_split"], s["f"],
             s["thr"], s["is_cat"], s["parent_slot"], s["new_slot"],
             scal_f, meta, F=_F, cap=cap, k=k, interpret=True,
-            live_tiles=s["live_tiles"])
+            live_tiles=s["live_tiles"], tiles_per_step=1)
         rec2 = place_runs(
             rec_pass, comp, (cl, cr), s["begin"], s["pcnt"], nleft,
             s["do_split"], s["parent_slot"], s["new_slot"], cap=cap,
@@ -306,7 +306,7 @@ def _measure_split_step_record_chain() -> dict:
             hists_, rec_, s["begin"], s["pcnt"], s["do_split"], s["f"],
             s["thr"], s["is_cat"], s["parent_slot"], s["new_slot"],
             scal_f, meta, F=_F, cap=cap, k=k,
-            interpret=False, live_tiles=s["live_tiles"])
+            interpret=False, live_tiles=s["live_tiles"], tiles_per_step=1)
 
     jaxpr = jax.make_jaxpr(run)(rec, hists)
     uses = _jaxpr_use_count(jaxpr, 0)

@@ -40,7 +40,6 @@ True/False.  ``chip_smoke.py`` is the caller; on a TPU
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple
 
 import numpy as np
@@ -89,39 +88,24 @@ class Split(NamedTuple):
     ran: int  # histogram tiles run
 
 
-@functools.lru_cache(maxsize=None)
-def _split_step_at(tiles_per_step):
-    """The split step as the grower launches it (``None``: the parent
-    tiles a grid step its record's height gives) or at
-    ``tiles_per_step``, which nothing but tests and timings sets."""
-    import jax
-
-    from ..ops.record import _split_step_call, split_step_counted
-
-    if tiles_per_step is None:
-        return split_step_counted
-    return jax.jit(
-        functools.partial(_split_step_call, fgroup=8,
-                          tiles_per_step=tiles_per_step),
-        static_argnames=("F", "cap", "k", "interpret"), donate_argnums=(0,))
-
-
 def _fused_split(rec, hists, begin, pcnt, f, thr, left_leaf, right_leaf,
                  scal, meta, F, cap, live_tiles, interpret,
                  tiles_per_step=None) -> Split:
-    """The fused grower's launch pair on one window (uint8 bins)."""
+    """The fused grower's launch pair on one window (uint8 bins); the
+    split step at the parent tiles a grid step the record's height gives,
+    or at ``tiles_per_step``, which nothing but tests and timings sets."""
     import jax.numpy as jnp
 
-    from ..ops.record import bins_per_word, num_words, place_runs
+    from ..ops.record import (
+        bins_per_word, num_words, place_runs, split_step_counted)
 
     k = bins_per_word(jnp.uint8)
     i32 = jnp.int32
-    hists2, comp, nleft, res, cl, cr, rec_pass, ran = _split_step_at(
-        tiles_per_step)(
+    hists2, comp, nleft, res, cl, cr, rec_pass, ran = split_step_counted(
         jnp.array(hists), rec, i32(begin), i32(pcnt), jnp.bool_(True), i32(f),
         i32(thr), jnp.bool_(False), i32(left_leaf), i32(right_leaf),
         scal, meta, F=F, cap=cap, k=k, interpret=interpret,
-        live_tiles=live_tiles)
+        live_tiles=live_tiles, tiles_per_step=tiles_per_step)
     rec2 = place_runs(
         jnp.array(rec_pass), comp, (cl, cr), i32(begin), i32(pcnt), nleft,
         jnp.bool_(True), i32(left_leaf), i32(right_leaf), cap=cap,

@@ -66,7 +66,7 @@ Counters maintained by the library itself:
   ops/pallas_histogram.py ``bin_sums``), added once a booster that
   takes the fused grower; beside them ``grow.split_tiles_per_step``
   (the parent tiles a grid step of the split step takes, from the
-  record's height alone: 4 up to 64 words, 1 past 128; ops/record.py
+  record's height alone: 4 up to 64 words, 1 past them; ops/record.py
   ``split_tiles``), ``grow.place_steps_per_tile`` and
   ``grow.place_launches_per_split`` (1 and 1: ops/record.py
   ``PLACE_STEPS_PER_TILE``; the step table before PR 39 took 4 and up to

@@ -26,7 +26,7 @@ Who calls what:
     leaf with one launch pair: ``split_step_window`` — per [W, TILE]
     tile of the leaf's window the go flags, a stable compaction and the
     tile's left count, K tiles a grid step (split_tiles: 4 up to 64
-    words, 1 past 128, from the record's height alone); the smaller
+    words, 1 past them, from the record's height alone); the smaller
     child's histogram; then the sibling
     by subtraction, both children's split search and the two ``hists``
     rows in place — and ``place_runs``, one grid step a tile: the
@@ -117,27 +117,19 @@ PLACE_LAUNCHES_PER_SPLIT = 1
 # the ``[256, TILE]`` one-hot of the time; PR 37's body runs nearer its
 # schedule than that one did and the step was not timed again.
 LOOP_WORDS = 16
-# Parent tiles a grid step of the split step takes (split_tiles): as many
-# as keep a ``[W, K * TILE]`` block of the record within SPLIT_BLOCK_WORDS
-# words a lane, at most SPLIT_TILES_MAX.  K tiles share one prefix sum and
-# one compress network (_source_rows), whose rotates are the same vregs
-# for up to four tiles as for one; the gathers and the staging stay per
-# tile.
-SPLIT_BLOCK_WORDS = 256
-SPLIT_TILES_MAX = 4
 
 
 def split_tiles(W: int) -> int:
     """Parent tiles a grid step of the split step takes at a record of
-    ``W`` words: 4 up to 64 words, 2 up to 128, then 1 (at 512 words the
-    compaction's network is 3% of a tile: PERF.md, PR 35).  The grower
-    pads its window and record to whole blocks of ``split_tiles(W) *
-    TILE`` columns (learners/fused.py); a caller whose buffers hold fewer
-    gets the largest power of two they hold (_split_step_call)."""
-    K = SPLIT_TILES_MAX
-    while K > 1 and K * W > SPLIT_BLOCK_WORDS:
-        K //= 2
-    return K
+    ``W`` words: 4 up to 64 words, 1 past them.  K tiles share one prefix
+    sum and one compress network (_source_rows), whose rotates are the
+    same vregs for up to four tiles as for one; the gathers and the
+    staging stay per tile.  At 512 words the network is 3% of a tile
+    (PERF.md, PR 35) and a ``[W, 4 * TILE]`` block 4 MiB, so one.  The
+    grower pads its window and record to whole blocks of
+    ``split_tiles(W) * TILE`` columns (learners/fused.py), and
+    _split_step_call refuses buffers that are not."""
+    return 4 if W <= 64 else 1
 
 
 def round_up(x: int, m: int) -> int:
@@ -488,10 +480,10 @@ def compact_tiles(win, go, interpret: bool = False, tiles: int = 1):
     )(win, flags)
 
 
-def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
-    """Histogram accumulation over the first [W, T] block of the smaller
-    child's STAGED rows, ``stage_ref[0]`` (_split_step_kernel's one
-    call).  ``live``
+def _hist_tile_body(stage_ref, blk, hacc_ref, *, F, k, Bp, live, fgroup=8):
+    """Histogram accumulation over the [W, T] block ``blk`` of the
+    smaller child's STAGED rows, the ring's head (_split_step_kernel's
+    one call).  ``live``
     [1, T] flags the lanes that hold a row: all of them on a full
     staged tile, the first ``fill`` at the drain.  Stats stack on
     sublanes; the one-hot is born transposed against a sublane iota and
@@ -514,7 +506,7 @@ def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
     mask_v = (1 << shift) - 1
 
     Wb = num_words(F, k)
-    stat = stage_ref[0, Wb: Wb + 3]
+    stat = stage_ref[blk, Wb: Wb + 3]
     grow = jax.lax.bitcast_convert_type(stat[0:1], jnp.float32)
     hrow = jax.lax.bitcast_convert_type(stat[1:2], jnp.float32)
     mrow = jax.lax.bitcast_convert_type(stat[2:3], jnp.float32)
@@ -540,7 +532,7 @@ def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
     steps = F // (LW * k)
 
     def whole_group(g, _):
-        group(stage_ref[0, pl.ds(pl.multiple_of(g * LW, LW), LW)],
+        group(stage_ref[blk, pl.ds(pl.multiple_of(g * LW, LW), LW)],
               g * (LW * k), LW * k)
         return 0
 
@@ -548,7 +540,7 @@ def _hist_tile_body(stage_ref, hacc_ref, *, F, k, Bp, live, fgroup=8):
         jax.lax.fori_loop(0, steps, whole_group, 0)
     w0 = steps * LW
     if w0 < Wb:
-        group(stage_ref[0, w0: Wb], w0 * k, F - w0 * k)
+        group(stage_ref[blk, w0: Wb], w0 * k, F - w0 * k)
     # caller-sized histogram block: the padded-feature fill below must
     # cover exactly the caller's round_up(F, fgroup) rows (ADVICE r4 —
     # a literal 8 here would leave rows [round_up(F,8), Fp) zero and
@@ -674,27 +666,33 @@ def _split_tiles(tiles, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
 
 def _stage(half, run, stage_ref, fill_ref):
     """Append the first ``run`` lanes of ``half`` [W, T] to the smaller
-    child's staged rows, ``stage_ref`` [K+1, W, T] holding ``fill_ref[0]``
-    of them.  The compaction has already put the child's rows of a tile
-    side by side: the lefts in lanes [0, T) of the compacted tile, the
-    valid rights as a PREFIX of [T, 2T) (the invalid tail follows them).
-    That run lands at lane ``fill % T`` of block ``fill // T`` and runs
-    into the next — one dynamic roll, two lane masks, as the direct read
-    and _place_kernel's rings move unaligned runs — so the histogram body
+    child's staged rows: ``fill_ref[0]`` of them wait in ``stage_ref``
+    [K+1, W, T], a RING whose head, the block the body takes next, is
+    ``ran % (K+1)`` after ``ran = fill_ref[1]`` bodies (no block moves
+    when one is taken).  The compaction has already put the child's rows
+    of a tile side by side: the lefts in lanes [0, T) of the compacted
+    tile, the valid rights as a PREFIX of [T, 2T) (the invalid tail
+    follows them).  That run lands at lane ``fill % T`` of the ring's
+    block ``fill // T`` from the head and runs into the next — one
+    dynamic roll, two lane masks, as the direct read and _place_kernel's
+    rings move unaligned runs — so the histogram body
     (_split_step_kernel) runs on full tiles of the child's rows and never
     sees the sibling's.  ``fill < T`` when a step begins and a run is at
     most T long, so a step's K appends fit in K+1 blocks and leave at
     most K full tiles due."""
     T = TILE
+    n = stage_ref.shape[0]
     fill = fill_ref[0]
     q = fill // T
     at = fill - q * T
-    end = at + run  # in block q's lanes
+    end = at + run  # in the lanes of the ring's block q
+    b = (fill_ref[1] + q) % n
+    nb = (b + 1) % n
     rolled = pltpu.roll(half, at, axis=1)  # lane t -> (t + at) % T
     lane = jax.lax.broadcasted_iota(jnp.int32, half.shape, 1)
-    stage_ref[q] = jnp.where((lane >= at) & (lane < end), rolled,
-                             stage_ref[q])
-    stage_ref[q + 1] = jnp.where(lane < end - T, rolled, stage_ref[q + 1])
+    stage_ref[b] = jnp.where((lane >= at) & (lane < end), rolled,
+                             stage_ref[b])
+    stage_ref[nb] = jnp.where(lane < end - T, rolled, stage_ref[nb])
     fill_ref[0] = fill + run
 
 
@@ -804,8 +802,9 @@ def _split_step_kernel(
     hhi_ref, hlo_ref : VMEM scratch — that histogram's running sum as
                  two floats (_fold_hacc)
     stage_ref  : VMEM scratch [K+1, W, T] — the smaller child's rows,
-                 compacted across tiles (_stage appends a tile's run,
-                 the body below takes block 0 and moves the rest down).
+                 compacted across tiles: a ring (_stage appends a tile's
+                 run after the fill, the body below takes the head block
+                 and the head moves on; no block is copied).
                  The one-hot body is bound by the VPU and costs the same
                  for a row of the sibling as for one of the child: over
                  every parent tile with the sibling masked it was 2.7
@@ -918,10 +917,10 @@ def _split_step_kernel(
         @pl.when((fill >= T) | ((i == search_step) & (fill > 0)))
         def _():
             lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+            head = fill_ref[1] % (K + 1)  # the ring's (_stage)
             _hist_tile_body(
-                stage_ref, hacc_ref, F=F, k=k, Bp=Bp, fgroup=fgroup,
+                stage_ref, head, hacc_ref, F=F, k=k, Bp=Bp, fgroup=fgroup,
                 live=(lane < fill).astype(jnp.float32))
-            stage_ref[:K] = stage_ref[1:]
             fill_ref[0] = jnp.maximum(fill - T, 0)
             ran = fill_ref[1] + 1
             fill_ref[1] = ran
@@ -1310,7 +1309,7 @@ def split_step_vmem_bytes(Fp: int, Bp: int, W: int) -> int:
     record's height by about 11 ``rec`` up to 512 words and by about 15
     past them.  tests/test_chip_compile.py compiles the step under this limit at
     the widest table the grower's gate admits, a record-bound one and
-    one of uint16 bins among them.  A record taller than 128 words
+    one of uint16 bins among them.  A record taller than 64 words
     takes one tile a step (split_tiles), so the gate's edge is where it
     was with one tile a step at every height."""
     from .pallas_histogram import feature_chunk
@@ -1325,7 +1324,8 @@ def split_step_vmem_bytes(Fp: int, Bp: int, W: int) -> int:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("F", "cap", "k", "fgroup", "interpret"),
+    static_argnames=("F", "cap", "k", "fgroup", "interpret",
+                     "tiles_per_step"),
     donate_argnums=(0,),
 )
 @phase_scope("split_step")
@@ -1341,6 +1341,7 @@ def split_step_counted(
     fgroup: int = 8,
     interpret: bool = False,
     live_tiles=None,  # run-time tile count <= cap // TILE (None = all)
+    tiles_per_step=None,  # K where a test or an audit names it
 ):
     """One-launch split step over window [begin, begin+cap): compaction
     + smaller-child histogram + subtract + two-child search + in-place
@@ -1369,7 +1370,10 @@ def split_step_counted(
     (``ceil(pcnt / TILE)``; clamped to [1, cap // TILE] here); a caller
     with a static window passes nothing and gets ``cap // TILE``.
     Tiles past the live count are never written, so their counts are
-    masked here before anything reads them.
+    masked here before anything reads them.  ``cap`` and the record come
+    in whole blocks of ``split_tiles`` of the record's height, the
+    parent tiles a grid step, as the grower pads them; a test or an
+    audit on smaller buffers names the tiles a step (``tiles_per_step``).
 
     What is resident in VMEM, as a function of ``(Fc, Bp, W)`` and the
     chunk count: ``split_step_vmem_bytes``.  That sum, or Mosaic's
@@ -1380,7 +1384,8 @@ def split_step_counted(
     return _split_step_call(
         hists, rec, begin, pcnt, do_split, f, thr, is_cat, parent_slot,
         new_slot, scal_f, meta, F=F, cap=cap, k=k, fgroup=fgroup,
-        interpret=interpret, live_tiles=live_tiles)
+        interpret=interpret, live_tiles=live_tiles,
+        tiles_per_step=tiles_per_step)
 
 
 def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
@@ -1390,10 +1395,10 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
     """The split step's launch (``split_step_counted``); with
     ``exchange`` the launch that stops at the smaller child's histogram
     (``split_hist_counted``: ``hists`` and ``meta`` are None, ``Fp`` and
-    ``Bp`` say the histogram's shape).  ``tiles_per_step`` (tests and
-    kernel-alone timings only) overrides K, which is ``split_tiles`` of
-    the record's height, halved until ``cap`` and ``n_pad`` hold whole
-    blocks of K tiles."""
+    ``Bp`` say the histogram's shape).  K, the parent tiles a grid step,
+    is ``split_tiles`` of the record's height, or ``tiles_per_step``
+    where a test, an audit or a kernel-alone timing names it; ``cap``
+    and ``n_pad`` must hold whole blocks of K tiles."""
     from .pallas_histogram import feature_chunk
 
     W, n_pad = rec.shape
@@ -1401,12 +1406,10 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
     assert cap % T == 0, (cap, T)
     assert n_pad % T == 0, (n_pad, T)
     nt = cap // T
-    K = tiles_per_step
-    if K is None:
-        K = split_tiles(W)
-        while nt % K or (n_pad // T) % K:
-            K //= 2
-    assert nt % K == 0 and (n_pad // T) % K == 0, (nt, n_pad // T, K)
+    K = tiles_per_step or split_tiles(W)
+    assert nt % K == 0 and (n_pad // T) % K == 0, (
+        "window and record must hold whole blocks of K tiles",
+        nt, n_pad // T, K)
     KT = K * T
     nblocks = n_pad // KT
     if not exchange:
@@ -1566,7 +1569,7 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
 
 @functools.partial(
     jax.jit, static_argnames=("F", "cap", "k", "Fp", "Bp", "fgroup",
-                              "interpret"))
+                              "interpret", "tiles_per_step"))
 @phase_scope("split_step")
 def split_hist_counted(
     rec, begin, pcnt, do_split, f, thr, is_cat, scal_f,
@@ -1574,6 +1577,7 @@ def split_hist_counted(
     fgroup: int = 8,
     interpret: bool = False,
     live_tiles=None,
+    tiles_per_step=None,
 ):
     """The first half of ``split_step_counted`` for the data-parallel
     grower: the compaction of this chip's window of the parent and this
@@ -1586,7 +1590,8 @@ def split_hist_counted(
     return _split_step_call(
         None, rec, begin, pcnt, do_split, f, thr, is_cat, 0, 0, scal_f,
         None, F=F, cap=cap, k=k, fgroup=fgroup, interpret=interpret,
-        live_tiles=live_tiles, exchange=True, Fp=Fp, Bp=Bp)
+        live_tiles=live_tiles, exchange=True, Fp=Fp, Bp=Bp,
+        tiles_per_step=tiles_per_step)
 
 
 def _split_search_kernel(scal_i_ref, scal_f_ref, hrow_ref, hsmall_ref,

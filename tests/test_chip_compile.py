@@ -388,14 +388,14 @@ def _the_carry_is_clean(compiled, n, F, L, searched=False):
     assert len(kernels) == (3 if searched else 2), [k.name for k in kernels]
 
 
-# The one-chip grow program at the ``grower`` fixture's shape as its
-# parent compiled it, before the data-parallel path shared its body
-# (f4d3413): sha256 of the optimized HLO, the Mosaic kernels' bodies
-# included, with the debug tables (file names, lines, stack frames), every
-# op's metadata and the kernels' source locations cut.  A change to the
-# one-chip program on purpose records its new digest here.
+# The one-chip grow program at the ``grower`` fixture's shape, its split
+# step at four parent tiles a grid step with a staging ring: sha256 of
+# the optimized HLO, the Mosaic kernels' bodies included, with the debug
+# tables (file names, lines, stack frames), every op's metadata and the
+# kernels' source locations cut.  A change to the one-chip program on
+# purpose records its new digest here.
 ONE_CHIP_GROW_PROGRAM = (
-    "fcec606f8f6ea8b1541a4ae6578d088ae3922032a052f5c244f12ee8b3de47a5")
+    "4e637c303f3bc32383993af1efc7aec62b7fcd18e4fa90d4c91596d8ce76a3ed")
 
 
 def _kernel_asm(body: str) -> str:

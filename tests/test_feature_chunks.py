@@ -114,7 +114,7 @@ def _split(F, bins, g, h, thr, f_split=2, begin=0):
         hists, rec, jnp.int32(begin), jnp.int32(n), jnp.bool_(True),
         jnp.int32(f_split), jnp.int32(thr), jnp.bool_(False), jnp.int32(0),
         jnp.int32(2), scal_f, meta, F=F, cap=R.round_up(n, _T), k=_K,
-        interpret=True)
+        interpret=True, tiles_per_step=1)  # a window of whole tiles
     return (np.asarray(hs), np.asarray(res), np.asarray(comp), int(nleft),
             int(ran))
 

@@ -60,7 +60,7 @@ def _split(rec, hists, scal_f, meta, begin, pcnt, do_split, live):
         jnp.array(hists), rec, jnp.int32(begin), jnp.int32(pcnt),
         jnp.bool_(do_split), jnp.int32(2), jnp.int32(7), jnp.bool_(False),
         jnp.int32(0), jnp.int32(2), scal_f, meta, F=_F, cap=_CAP, k=k,
-        interpret=True, live_tiles=live)
+        interpret=True, live_tiles=live, tiles_per_step=1)
     return hs, comp, nleft, res, cl, cr, rec_pass
 
 

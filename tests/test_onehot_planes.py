@@ -118,7 +118,7 @@ def _step(bins, g, h, m, num_bins, thr, parent):
         jnp.asarray(hists), rec, jnp.int32(0), jnp.int32(n),
         jnp.bool_(True), jnp.int32(2), jnp.int32(thr), jnp.bool_(False),
         jnp.int32(0), jnp.int32(2), scal_f, meta, F=_F, cap=R.round_up(n, _T),
-        k=k, interpret=True)
+        k=k, interpret=True, tiles_per_step=1)  # a window of whole tiles
     assert int(nleft) == left.sum()
     hs = np.asarray(hs)
     return hs[0], hs[2], int(ran)

@@ -55,8 +55,8 @@ _TABLE = [
 ]
 
 # (features, chunks of 256 features, record words, MiB the step keeps)
-# (K = 2 parent tiles a split step's grid step at 72 words, 1 past 128)
-_WIDE = [(264, 2, 72, 24), (1000, 4, 256, 34), (2000, 8, 512, 54)]
+# (one parent tile a split step's grid step past 64 words)
+_WIDE = [(264, 2, 72, 22), (1000, 4, 256, 34), (2000, 8, 512, 54)]
 
 
 @pytest.mark.parametrize(
@@ -157,9 +157,8 @@ def test_wide_tables_get_the_fused_grower_and_the_bound_is_named(
     before = {name: tel.counter(name) for name in counters}
     g = _booster("tpu", F=F)
     assert g._grower == ("fused", "") and g._grow.func is fused.grow_tree
-    tiles = "2 tiles" if words <= 128 else "1 tile"
     assert any(said + "96 MiB, one-hot of 2 x 128 bins, placement 1 step "
-               f"a tile in 1 launch a split, split step {tiles} a grid step"
+               "a tile in 1 launch a split, split step 1 tile a grid step"
                in m
                and "grower=fused" in m
                for m in gbdt_mod._LOGGED_PATHS), gbdt_mod._LOGGED_PATHS
@@ -172,9 +171,9 @@ def test_wide_tables_get_the_fused_grower_and_the_bound_is_named(
 
 
 # (features, the record's words, parent tiles a grid step of the split
-# step): the narrow cells' heights take four, a taller record fewer
+# step): the narrow cells' heights take four, a taller record one
 @pytest.mark.parametrize("F,words,tiles", [
-    (13, 16, 4), (220, 64, 4), (300, 80, 2), (600, 160, 1)])
+    (13, 16, 4), (220, 64, 4), (300, 80, 1), (600, 160, 1)])
 def test_the_booster_says_the_split_tiles_a_step(F, words, tiles):
     """``grow.split_tiles_per_step`` is ``split_tiles`` of the record's
     height alone, counted once a booster and named in the log line."""

@@ -70,7 +70,7 @@ def _step_counted(rec, parent, n, thr, lc, rc, begin=0):
         jnp.asarray(hists), rec, jnp.int32(begin), jnp.int32(n),
         jnp.bool_(True), jnp.int32(2), jnp.int32(thr), jnp.bool_(False),
         jnp.int32(0), jnp.int32(2), scal_f, meta, F=_F, cap=cap, k=_K,
-        interpret=True)
+        interpret=True, tiles_per_step=1)  # a window of whole tiles
     hs = np.asarray(hs)
     return hs[0, :_F, :3, :_B], hs[2, :_F, :3, :_B], int(nleft), int(ran)
 
