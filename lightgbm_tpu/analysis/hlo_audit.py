@@ -392,7 +392,11 @@ def _measure_predict_matmul() -> dict:
 
 def _measure_post_grow_step() -> dict:
     """The per-tree score update: scores donation must hold (a dropped
-    donation doubles score-buffer traffic every tree)."""
+    donation doubles score-buffer traffic every tree), and the update
+    reads each row's leaf value with no gather of the leaf table
+    (models/tree.py ``leaf_lookup``: an element gather costs about 8 ns
+    a row on a TPU); the gathers left are the threshold finalization's
+    reads of the bounds, and the budget's ceiling is their count."""
     import jax.numpy as jnp
 
     from ..models.gbdt import _post_grow_step

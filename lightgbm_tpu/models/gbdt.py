@@ -44,6 +44,8 @@ from .tree import (
     finalize_thresholds,
     finalize_thresholds_device,
     ensemble_leaves_raw,
+    leaf_lookup,
+    leaf_lookup_path,
     ensemble_sum_binned,
     ensemble_sum_raw,
     pack_threshold_bounds,
@@ -107,7 +109,7 @@ def _post_grow_step(tree, scores, k, leaf_id, rate, bounds_mat, real_feat):
     """Shrinkage + score update + device-side threshold finalization in
     one dispatch (gbdt.cpp:229-247's post-train steps)."""
     tree = tree.shrink(rate)
-    scores = scores.at[k].add(tree.leaf_value[leaf_id])
+    scores = scores.at[k].add(leaf_lookup(tree.leaf_value, leaf_id))
     tree = finalize_thresholds_device(tree, bounds_mat, real_feat)
     return tree, scores
 
@@ -192,6 +194,11 @@ class GBDT:
             check_count_envelope(n, self.config.hist_dtype,
                                  self._count_shards())
             self._grow = self._create_tree_learner()
+            # how _post_grow_step reads each row's leaf value, from the
+            # leaf table's length alone
+            telemetry.count(
+                f"score.leaf_lookup.{leaf_lookup_path(self.max_leaves)}",
+                self.max_leaves)
         # host transpose plus device_put, none waited for: host wall
         # time like every span; ``.shard`` where the rows go to the
         # shards of a mesh
@@ -580,6 +587,8 @@ class GBDT:
                f"growth={self.config.tree_growth} "
                f"grower={which}{f' ({why})' if why else ''}: "
                f"histogram={hist}, search={search}, partition={part}, "
+               f"score update by {leaf_lookup_path(self.max_leaves)} over "
+               f"{self.max_leaves} leaves, "
                f"{int(self.train_set.is_categorical.sum())} of "
                f"{self.train_set.num_features} features categorical"
                + (", pallas kernels interpreted" if not on_tpu()
