@@ -276,8 +276,7 @@ def warm_until_compile_stable(step, max_warm: int | None = None,
     return warmed, False
 
 
-def ours_sec_per_tree(X, y, growth: str, Xv=None, yv=None,
-                      reservoir: str = "tree_s"):
+def ours_sec_per_tree(X, y, Xv=None, yv=None):
     """Train TREES trees; caller has already resolved the backend via
     _init_backend() (so failures here happen ON the resolved platform).
 
@@ -287,10 +286,8 @@ def ours_sec_per_tree(X, y, growth: str, Xv=None, yv=None,
     the timed loop, optional phase breakdown) — the evidence the
     RunManifest and the BENCH json record so a regression like round
     5's (12 lazy compiles inside the timed segment, unrecorded) can
-    never again hide behind a bare s/tree number.  ``reservoir`` names
-    the telemetry reservoir the timed per-tree times land in; a
-    secondary (depthwise) run must NOT share the headline's "tree_s"
-    or the manifest's p50/p99 would blend both growth modes."""
+    never again hide behind a bare s/tree number.  The timed per-tree
+    times land in the telemetry reservoir "tree_s"."""
 
     from lightgbm_tpu.config import Config
     from lightgbm_tpu.io.dataset import BinnedDataset
@@ -298,17 +295,10 @@ def ours_sec_per_tree(X, y, growth: str, Xv=None, yv=None,
     from lightgbm_tpu.models.gbdt import GBDT
     from lightgbm_tpu.objectives import create_objective
 
-    # leaf-wise is the HEADLINE growth mode on every platform: it is the
-    # reference-parity mode (trees match the reference binary; depthwise
-    # trades ~0.01 AUC, BASELINE.md) and on TPU also the fast mode (each
-    # split's histogram is one-hot MXU matmuls over the gathered smaller
-    # child).  Depthwise is reported as a secondary row only — a bench
-    # artifact must never advertise the approximate mode as the result.
     cfg = Config(
         objective="binary", num_leaves=NUM_LEAVES, max_bin=NUM_BINS,
         learning_rate=LEARNING_RATE, min_data_in_leaf=MIN_DATA,
         metric=["auc"],
-        tree_growth=growth,
     )
     from lightgbm_tpu.obs import telemetry
 
@@ -391,7 +381,7 @@ def ours_sec_per_tree(X, y, growth: str, Xv=None, yv=None,
             # per-tree reservoir (manifest p50/p99): dispatch wall
             # for 4 of 5 trees, the 5th absorbs the sync — the p50
             # tracks dispatch cost, the p99 the sync'd envelope
-            telemetry.record_value(reservoir,
+            telemetry.record_value("tree_s",
                                    time.perf_counter() - t_iter)
             if i % 5 == 4 and time.perf_counter() - t0 > BUDGET_S:
                 log(f"budget hit after {done} trees")
@@ -485,10 +475,10 @@ def main() -> None:
             X, y, Xv, yv = make_data(ROWS, n_valid=VROWS)
         else:  # BENCH_VALID=0 disables the out-of-sample column
             (X, y), Xv, yv = make_data(ROWS), None, None
-        growth = os.environ.get("BENCH_GROWTH", "leafwise")
-        ours, auc, valid_auc, info = ours_sec_per_tree(X, y, growth, Xv, yv)
+        ours, auc, valid_auc, info = ours_sec_per_tree(X, y, Xv, yv)
         out["value"] = round(ours, 4)
-        out["growth"] = growth
+        # the trees are leaf-wise, the reference's growth
+        out["growth"] = "leafwise"
         # self-description (VERDICT r5 item 4): the warm-up and compile
         # evidence ships INSIDE the BENCH row, so a number measured over
         # lazy compiles can be seen to be one
@@ -524,16 +514,6 @@ def main() -> None:
             out["valid_auc_delta"] = round(vdelta, 4)
             vgap = float("nan") if vdelta != vdelta else max(0.0, -vdelta)
             out["valid_auc_gap"] = round(vgap, 4)
-        if os.environ.get("BENCH_SECONDARY", "0") != "0":
-            # optional secondary row: the level-synchronous approximation
-            sec, sec_auc, _, _ = ours_sec_per_tree(
-                X, y, "depthwise", reservoir="tree_s_secondary")
-            out["secondary"] = {
-                "growth": "depthwise", "value": round(sec, 4),
-                "train_auc": round(float(sec_auc), 4),
-            }
-            if ref and sec > 0:
-                out["secondary"]["vs_baseline"] = round(ref / sec, 3)
     except Exception as e:
         import traceback
         traceback.print_exc(file=sys.stderr)

@@ -173,11 +173,6 @@ class Config:
     bin_construct_sample_cnt: int = 50000
     is_pre_partition: bool = False
     is_enable_sparse: bool = True
-    # density below which the depthwise histogram switches to the O(nnz)
-    # CSR path (ops/sparse_hist.py; reference ordered_sparse_bin.hpp:79-92
-    # uses sparse_rate >= 0.8 per feature, i.e. density <= 0.2 — this is
-    # the whole-dataset analog, conservative by default)
-    sparse_hist_density: float = 0.05
     # when false, ignore an existing <data>.bin cache (config.h:107)
     enable_load_from_binary_file: bool = True
     use_two_round_loading: bool = False
@@ -210,16 +205,12 @@ class Config:
     feature_fraction: float = 1.0
     max_depth: int = -1
     top_k: int = 20
-    # TPU extension: tree growth strategy.  "leafwise" reproduces the
-    # reference's best-first growth exactly (serial_tree_learner.cpp:116-150);
-    # "depthwise" grows level-by-level (one fused histogram pass per level,
-    # much faster on TPU) while keeping the num_leaves budget via best-gain
-    # masking at the final level.
-    tree_growth: str = "leafwise"
-    # TPU extension: histogram implementation for depthwise growth.
-    # "segment" = jax.ops.segment_sum scatter; "matmul" = leaf-sorted MXU
-    # one-hot matmul Pallas kernel (ops/pallas_histogram.py); "auto" picks
-    # matmul on TPU backends, segment elsewhere.
+    # TPU extension: histogram implementation of the canonical leaf-wise
+    # learners (learners/serial.py and the parallel learners over it).
+    # "segment" = jax.ops.segment_sum scatter; "matmul" = the single-leaf
+    # MXU one-hot matmul Pallas kernel (ops/pallas_histogram.py); "auto"
+    # picks matmul on TPU backends, segment elsewhere.  "segment" on a TPU
+    # selects the canonical grower (models/gbdt.py select_grower).
     hist_impl: str = "auto"
     # TPU extension: histogram accumulation dtype.  The reference always
     # keeps sum_gradients/sum_hessians in double (include/LightGBM/
@@ -259,9 +250,7 @@ class Config:
     drop_seed: int = 4
 
     # ---- tree learner selection (config.cpp:324-335)
-    tree_learner: str = "serial"  # serial | feature | data | voting |
-    # grid (TPU extension: rows x feature-search over a 2-D mesh)
-    grid_feature_shards: int = 2  # feature-axis width of the grid mesh
+    tree_learner: str = "serial"  # serial | feature | data | voting
 
     # ---- network (NetworkConfig, config.h:223-231): on TPU the "machines"
     # are mesh devices; these remain accepted for config compatibility.
@@ -369,7 +358,7 @@ class Config:
     # -- derived flags (CheckParamConflict, config.cpp:136-175)
     @property
     def is_parallel(self) -> bool:
-        return self.tree_learner in ("feature", "data", "voting", "grid")
+        return self.tree_learner in ("feature", "data", "voting")
 
     @property
     def num_leaves_(self) -> int:
@@ -411,20 +400,12 @@ class Config:
 
     def _check_conflicts(self) -> None:
         """Mirror CheckParamConflict (config.cpp:136-175)."""
-        if self.tree_learner not in (
-            "serial", "feature", "data", "voting", "grid"
-        ):
+        if self.tree_learner not in ("serial", "feature", "data", "voting"):
             raise ValueError(f"Unknown tree_learner: {self.tree_learner!r}")
-        if self.grid_feature_shards < 1:
-            raise ValueError(
-                f"grid_feature_shards must be >= 1, got {self.grid_feature_shards}"
-            )
         if self.boosting_type == "gbrt":  # accepted synonym (config.cpp:78)
             self.boosting_type = "gbdt"
         if self.boosting_type not in ("gbdt", "dart"):
             raise ValueError(f"Unknown boosting_type: {self.boosting_type!r}")
-        if self.tree_growth not in ("leafwise", "depthwise", "hybrid"):
-            raise ValueError(f"Unknown tree_growth: {self.tree_growth!r}")
         if self.hist_impl not in ("auto", "segment", "matmul"):
             raise ValueError(f"Unknown hist_impl: {self.hist_impl!r}")
         if self.hist_dtype not in ("float32", "float64"):

@@ -1,4 +1,3 @@
 from .serial import grow_tree, TreeLearnerParams
-from .depthwise import grow_tree_depthwise
 
-__all__ = ["grow_tree", "grow_tree_depthwise", "TreeLearnerParams"]
+__all__ = ["grow_tree", "TreeLearnerParams"]

@@ -10,8 +10,8 @@ There are TWO leaf-wise growers, and ``models/gbdt.py select_grower`` is
 the one place that picks: learners/fused.py is what a TPU chip runs for
 serial float32 training (packed record, one launch pair a split, no
 ``lax.cond``); THIS one serves everything else — the CPU, float64
-accumulation, the parallel learners' hooks, the hybrid resume, the cv
-row mask and ``histogram_pool_size`` — and is the reference the fused
+accumulation, the parallel learners' hooks, the cv row mask and
+``histogram_pool_size`` — and is the reference the fused
 one is pinned to.  Histograms are in the canonical ``[F, B, 3]`` layout
 throughout.
 
@@ -59,10 +59,10 @@ from ..device import on_tpu
 from ..models.tree import Tree
 from ..obs import telemetry
 from ..obs.device_time import phase_scope
-from ..ops.histogram import histogram_by_leaf, histogram_feature_major
+from ..ops.histogram import histogram_feature_major
 from ..ops.split import SplitResult, find_best_split, find_best_split_leaves
 from . import tables
-from .tables import _BROWS, _sr_row
+from .tables import _sr_row
 
 # geometric step between hist/partition tier capacities (_hist_tiers)
 TIER_SPACING = 2
@@ -268,8 +268,7 @@ def default_search_fn(
     static_argnames=(
         "num_bins", "max_leaves", "hist_fn", "reduce_fn", "search_fn",
         "reduce_max_fn", "child_counts_fn", "search2_fn", "hist_pool",
-        "init_hist_fn", "init_search_fn", "record_mode",
-        "choice_by_mask_counts",
+        "record_mode", "choice_by_mask_counts",
     ),
 )
 def grow_tree(
@@ -290,10 +289,6 @@ def grow_tree(
     child_counts_fn=None,
     search2_fn=None,
     hist_pool: int = 0,
-    init_tree=None,
-    init_leaf_id=None,
-    init_hist_fn=None,
-    init_search_fn=None,
     record_mode: bool = False,
     choice_by_mask_counts: bool = False,
 ) -> Tuple[Tree, jax.Array]:
@@ -328,17 +323,7 @@ def grow_tree(
     Histograms of a child's window still flow through ``hist_fn`` (which
     reduce-scatters across the mesh) and searches through the hooks;
     only the partition and the contiguous-window child access change.
-    Float32, unpooled, no resume: otherwise the row permutation runs.
-
-    ``init_tree``/``init_leaf_id`` resume best-first growth from an
-    existing partial tree (the hybrid growth mode, learners/hybrid.py):
-    the persistent partition is rebuilt from the row->leaf map, per-leaf
-    histograms come from one fused pass (``init_hist_fn``, the depthwise
-    level kernel), and the loop continues numbering nodes from
-    ``init_tree.num_leaves - 1``.  Sharded learners resume too:
-    ``init_search_fn`` searches the fused histogram's feature shard and
-    combines, ``reduce_max_fn`` lifts the rebuilt positional counts to
-    cross-shard tier gates.  Exclusive with ``hist_pool``.
+    Float32 and unpooled: otherwise the row permutation runs.
 
     ``hist_pool`` bounds histogram HBM: when ``2 <= hist_pool <
     max_leaves`` only that many leaf histograms stay resident
@@ -367,12 +352,7 @@ def grow_tree(
 
     if hist_fn is None:
         hist_fn = functools.partial(histogram_feature_major, num_bins=num_bins)
-    rec = (
-        record_mode
-        and grad.dtype == jnp.float32
-        and init_tree is None
-        and not pooled
-    )
+    rec = record_mode and grad.dtype == jnp.float32 and not pooled
     if search_fn is None:
         search_fn = default_search_fn
         if search2_fn is None:
@@ -465,133 +445,46 @@ def grow_tree(
 
     with phase_scope("grow.root"):
         P = max(hist_pool, 2) if pooled else L
-        if init_tree is not None:
-            assert not pooled, "init_tree resume is unpooled"
-            acc_dt = jnp.promote_types(grad.dtype, jnp.float32)
-            K0 = init_tree.num_leaves.astype(jnp.int32)
-            lid = init_leaf_id.astype(jnp.int32)
-            # leaf-sorted permutation + contiguous per-leaf ranges from the
-            # row->leaf map (stable: preserves row order within a leaf);
-            # under row sharding these are LOCAL ranges, while the fused
-            # histogram/search below see GLOBAL stats through the hooks
-            order0 = jnp.argsort(lid, stable=True).astype(jnp.int32)
-            counts = jnp.zeros(L, jnp.int32).at[lid].add(1)
-            begin0 = jnp.concatenate(
-                [jnp.zeros(1, jnp.int32),
-                 jnp.cumsum(counts)[:-1].astype(jnp.int32)]
+        # ---- root (BeforeTrain / LeafSplits::Init, leaf_splits.hpp:51-92)
+        hist0 = hist_fn(bins_T, grad, hess, bag_mask)
+        sum_g0, sum_h0, cnt0 = tables.root_sums(grad, hess, bag_mask)
+        if reduce_fn is not None:
+            # one stacked collective for the tree-start allreduce
+            s = reduce_fn(jnp.stack([sum_g0, sum_h0, cnt0]))
+            sum_g0, sum_h0, cnt0 = s[0], s[1], s[2]
+        # hist0's feature extent may be a shard of F (feature-parallel
+        # learner); accumulation dtype follows grad/hess — float64 when
+        # Config.hist_dtype asks for the reference's double accumulation
+        # (include/LightGBM/bin.h:21-22)
+        acc_dt = hist0.dtype
+        root_best = best_for(hist0, sum_g0, sum_h0, cnt0, jnp.int32(0))
+        best_mat0, pos_mat0, tree_i0, tree_f0 = tables.root_tables(
+            root_best, acc_dt, L, n)
+        state = _GrowState(
+            order=build_record(
+                bins_T, grad, hess, bag_mask,
+                _round_up(n, _T) + order_pad,
             )
-            gate0 = counts if reduce_max_fn is None else reduce_max_fn(counts)
-            # every live leaf's histogram in ONE fused pass, through the same
-            # level-histogram kernel the depthwise phase used (the Pallas MXU
-            # sorted kernel on TPU; init_hist_fn has the depthwise hist_fn
-            # signature)
-            if init_hist_fn is None:
-                fused = histogram_by_leaf(
-                    bins_T, lid, grad, hess, bag_mask,
-                    num_bins=num_bins, num_leaves=L,
-                ).astype(acc_dt)
-            else:
-                fused = init_hist_fn(
-                    bins_T, lid, grad, hess, bag_mask, L
-                ).astype(acc_dt)
-            leaf_tot = jnp.sum(fused[:, 0, :, :], axis=1)  # [L, 3]
-            live = jnp.arange(L, dtype=jnp.int32) < K0
-            can0 = live & (
-                (params.max_depth <= 0)
-                | (init_tree.leaf_depth < params.max_depth)
-            )
-            if init_search_fn is not None:
-                # sharded-search learners search their feature shard of the
-                # fused histogram and combine winners in one collective
-                best0 = init_search_fn(
-                    fused, leaf_tot[:, 0], leaf_tot[:, 1], leaf_tot[:, 2],
-                    can0, feature_mask, num_bins_per_feature, is_categorical,
-                    params,
-                )
-            else:
-                best0 = find_best_split_leaves(
-                    fused, leaf_tot[:, 0], leaf_tot[:, 1], leaf_tot[:, 2],
-                    feature_mask, num_bins_per_feature, is_categorical,
-                    params.min_data_in_leaf, params.min_sum_hessian_in_leaf,
-                    params.lambda_l1, params.lambda_l2,
-                    params.min_gain_to_split, can0,
-                )
-            _pad1 = lambda a: jnp.concatenate(  # noqa: E731
-                [a, jnp.zeros(1, a.dtype)])
-            state = _GrowState(
-                order=jnp.concatenate(
-                    [order0, jnp.full(order_pad, n, jnp.int32)]
-                ),
-                pos_mat=jnp.stack([begin0, counts, gate0]),
-                hists=fused,
-                slot_of=jnp.zeros(0, jnp.int32),
-                slot_leaf=jnp.zeros(0, jnp.int32),
-                slot_last=jnp.zeros(0, jnp.int32),
-                best_mat=jnp.concatenate([
-                    _sr_row(best0, acc_dt),
-                    init_tree.leaf_value[None].astype(acc_dt),
-                    init_tree.leaf_count[None].astype(acc_dt),
-                    init_tree.leaf_parent[None].astype(acc_dt),
-                    init_tree.leaf_depth[None].astype(acc_dt),
-                    jnp.zeros((_BROWS - 15, L), acc_dt),
-                ]),
-                tree_i=jnp.stack([
-                    _pad1(init_tree.split_feature),
-                    _pad1(init_tree.threshold_bin),
-                    _pad1(init_tree.decision_type),
-                    _pad1(init_tree.left_child),
-                    _pad1(init_tree.right_child),
-                ]),
-                tree_f=jnp.stack([
-                    _pad1(init_tree.split_gain),
-                    _pad1(init_tree.internal_value),
-                    _pad1(init_tree.internal_count),
-                ]),
-                nleaves=K0,
-            )
-            start_step = K0 - 1
-        else:
-            # ---- root (BeforeTrain / LeafSplits::Init, leaf_splits.hpp:51-92)
-            hist0 = hist_fn(bins_T, grad, hess, bag_mask)
-            sum_g0, sum_h0, cnt0 = tables.root_sums(grad, hess, bag_mask)
-            if reduce_fn is not None:
-                # one stacked collective for the tree-start allreduce
-                s = reduce_fn(jnp.stack([sum_g0, sum_h0, cnt0]))
-                sum_g0, sum_h0, cnt0 = s[0], s[1], s[2]
-            # hist0's feature extent may be a shard of F (feature-parallel
-            # learner); accumulation dtype follows grad/hess — float64 when
-            # Config.hist_dtype asks for the reference's double accumulation
-            # (include/LightGBM/bin.h:21-22)
-            acc_dt = hist0.dtype
-            root_best = best_for(hist0, sum_g0, sum_h0, cnt0, jnp.int32(0))
-            best_mat0, pos_mat0, tree_i0, tree_f0 = tables.root_tables(
-                root_best, acc_dt, L, n)
-            state = _GrowState(
-                order=build_record(
-                    bins_T, grad, hess, bag_mask,
-                    _round_up(n, _T) + order_pad,
-                )
-                if rec
-                else jnp.concatenate(
-                    [
-                        jnp.arange(n, dtype=jnp.int32),
-                        jnp.full(order_pad, n, jnp.int32),
-                    ]
-                ),
-                pos_mat=pos_mat0,
-                hists=jnp.zeros((P,) + hist0.shape, acc_dt).at[0].set(hist0),
-                slot_of=(jnp.full(L, -1, jnp.int32).at[0].set(0) if pooled
-                         else jnp.zeros(0, jnp.int32)),
-                slot_leaf=(jnp.full(P, -1, jnp.int32).at[0].set(0) if pooled
-                           else jnp.zeros(0, jnp.int32)),
-                slot_last=(jnp.full(P, -1, jnp.int32).at[0].set(0) if pooled
-                           else jnp.zeros(0, jnp.int32)),
-                best_mat=best_mat0,
-                tree_i=tree_i0,
-                tree_f=tree_f0,
-                nleaves=jnp.int32(1),
-            )
-            start_step = 0
+            if rec
+            else jnp.concatenate(
+                [
+                    jnp.arange(n, dtype=jnp.int32),
+                    jnp.full(order_pad, n, jnp.int32),
+                ]
+            ),
+            pos_mat=pos_mat0,
+            hists=jnp.zeros((P,) + hist0.shape, acc_dt).at[0].set(hist0),
+            slot_of=(jnp.full(L, -1, jnp.int32).at[0].set(0) if pooled
+                     else jnp.zeros(0, jnp.int32)),
+            slot_leaf=(jnp.full(P, -1, jnp.int32).at[0].set(0) if pooled
+                       else jnp.zeros(0, jnp.int32)),
+            slot_last=(jnp.full(P, -1, jnp.int32).at[0].set(0) if pooled
+                       else jnp.zeros(0, jnp.int32)),
+            best_mat=best_mat0,
+            tree_i=tree_i0,
+            tree_f=tree_f0,
+            nleaves=jnp.int32(1),
+        )
 
     @phase_scope("grow.book")
     def split_branch(state, step, best_leaf, do_split):
@@ -824,15 +717,11 @@ def grow_tree(
         return split_branch(state, jnp.int32(step), best_leaf, do_split)
 
     with phase_scope("grow.loop"):
-        state = jax.lax.fori_loop(start_step, L - 1, body, state)
+        state = jax.lax.fori_loop(0, L - 1, body, state)
 
     with phase_scope("grow.unpack"):
         tree = tables.unpack_tree(
             state.nleaves, state.best_mat, state.tree_i, state.tree_f, L)
-        if init_tree is not None:
-            tree = tree._replace(
-                split_feature_real=init_tree.split_feature_real,
-                threshold_real=init_tree.threshold_real)
         if rec:
             leaf_id = tables.leaf_ids_from_record(state.order, F, k_pack, n)
         else:

@@ -291,8 +291,6 @@ class GBDT:
                    f"{jax.device_count()} devices"
                    + (f" of {jax.process_count()} processes"
                       if jax.process_count() > 1 else ""))
-        elif cfg.tree_growth != "leafwise":
-            why = f"tree_growth={cfg.tree_growth}"
         elif not self._use_pallas_hist():
             why = f"hist_dtype={cfg.hist_dtype} hist_impl={cfg.hist_impl}"
         elif float(cfg.histogram_pool_size) > 0:
@@ -373,16 +371,6 @@ class GBDT:
         SPMD over the local device mesh — the reference's `num_machines`
         world (network.cpp:20-38) is the mesh's row axis."""
         tl = self.config.tree_learner
-        if (self.config.tree_growth == "hybrid"
-                and tl in ("feature", "voting", "grid")
-                and len(jax.devices()) > 1 and jax.process_count() == 1):
-            from ..log import Log
-
-            Log.warning(
-                "tree_growth=hybrid runs on serial and data-parallel "
-                f"learners; tree_learner={tl} uses leaf-wise growth "
-                "(same accuracy, no fused level phase)"
-            )
         if jax.process_count() > 1:
             # true multi-host world (Network::Init analog already ran,
             # parallel/multihost.py): rows are the per-process ingest
@@ -406,7 +394,6 @@ class GBDT:
                 data_mesh(),  # all global devices
                 num_bins=self._num_bins,
                 max_leaves=self.max_leaves,
-                growth=self.config.tree_growth,
                 sorted_hist=self._use_pallas_hist(),
                 hist_pool=self._hist_pool_slots(),
                 # the config's collective deadline guards the sentinel's
@@ -415,25 +402,6 @@ class GBDT:
                 collective_deadline=collective_deadline_s(self.config),
             )
         if tl == "serial" or len(jax.devices()) == 1:
-            if self.config.tree_growth == "depthwise":
-                from ..learners.depthwise import grow_tree_depthwise
-
-                return functools.partial(
-                    grow_tree_depthwise,
-                    num_bins=self._num_bins,
-                    max_leaves=self.max_leaves,
-                    hist_fn=self._depthwise_hist_fn(),
-                )
-            if self.config.tree_growth == "hybrid":
-                from ..learners.hybrid import grow_tree_hybrid
-
-                return functools.partial(
-                    grow_tree_hybrid,
-                    num_bins=self._num_bins,
-                    max_leaves=self.max_leaves,
-                    hist_fn=self._leafwise_hist_fn(),
-                    level_hist_fn=self._depthwise_hist_fn(),
-                )
             return self._serial_leafwise_grower()
         from ..parallel import (
             data_mesh,
@@ -450,25 +418,6 @@ class GBDT:
             self._set_placement(mesh, None, 1)
             return make_feature_parallel_grower(
                 mesh, num_bins=self._num_bins, max_leaves=self.max_leaves,
-                sorted_hist=self._use_pallas_hist(),
-                hist_pool=self._hist_pool_slots(),
-            )
-        if tl == "grid":
-            from ..log import Log
-            from ..parallel import grid_mesh, make_grid_parallel_grower
-
-            c = max(1, min(int(self.config.grid_feature_shards), nd))
-            r = max(1, nd // c)
-            if r * c < nd:
-                Log.warning(
-                    f"grid mesh ({r}x{c}) uses {r * c} of {nd} devices; "
-                    "pick grid_feature_shards dividing the device count"
-                )
-            gmesh = grid_mesh((r, c))
-            self._set_placement(gmesh, ROW_AXIS, r)
-            return make_grid_parallel_grower(
-                gmesh, num_bins=self._num_bins,
-                max_leaves=self.max_leaves,
                 sorted_hist=self._use_pallas_hist(),
                 hist_pool=self._hist_pool_slots(),
             )
@@ -499,7 +448,6 @@ class GBDT:
             mesh,
             num_bins=self._num_bins,
             max_leaves=self.max_leaves,
-            growth=self.config.tree_growth,
             sorted_hist=self._use_pallas_hist(),
             hist_pool=self._hist_pool_slots(),
         )
@@ -559,10 +507,7 @@ class GBDT:
 
         which, why = self._grower
         serial = self._learner_devices == 1
-        if self.config.tree_growth != "leafwise":
-            hist = "pallas sorted" if self._use_pallas_hist() else "segment-sum"
-            search, part = "jnp", "leaf-id vector"
-        elif which == "fused":
+        if which == "fused":
             hist, search = "pallas raw-layout", "pallas (in the split step)"
             from ..ops import record
 
@@ -584,7 +529,6 @@ class GBDT:
         msg = (f"platform={device_platform()} "
                f"devices={self._learner_devices} of {jax.device_count()} "
                f"tree_learner={self.config.tree_learner} "
-               f"growth={self.config.tree_growth} "
                f"grower={which}{f' ({why})' if why else ''}: "
                f"histogram={hist}, search={search}, partition={part}, "
                f"score update by {leaf_lookup_path(self.max_leaves)} over "
@@ -601,20 +545,9 @@ class GBDT:
         """config.histogram_pool_size (MB) -> LRU slot count, the
         reference's sizing rule (serial_tree_learner.cpp:25-37): 0 means
         keep all num_leaves histograms resident.  Applies to every
-        leaf-wise learner (serial and all mesh variants); depth-wise
-        growth builds transient per-level histograms instead of a
-        resident per-leaf buffer, so the bound is moot there."""
+        learner (serial and all mesh variants)."""
         mb = float(self.config.histogram_pool_size)
         if mb <= 0:
-            return 0
-        if self.config.tree_growth in ("depthwise", "hybrid"):
-            from ..log import Log
-
-            Log.warning(
-                f"histogram_pool_size is ignored for tree_growth="
-                f"{self.config.tree_growth} (depthwise levels build "
-                "transient histograms; the hybrid resume runs unpooled)"
-            )
             return 0
         # a pool selects the canonical grower (select_grower), whose
         # slots are [F, B, 3]
@@ -646,31 +579,6 @@ class GBDT:
             from ..ops.histogram import select_single_hist_fn
 
             return select_single_hist_fn(self._num_bins, True)
-        return None  # grower's default segment_sum path
-
-    def _depthwise_hist_fn(self):
-        """Histogram implementation for depthwise growth (config.hist_impl):
-        the leaf-sorted MXU matmul kernel on TPU, segment_sum elsewhere.
-        f64 reference-parity accumulation keeps segment_sum — the Pallas
-        kernels are f32 (same gate as _leafwise_hist_fn).
-
-        Sparse-ingested datasets below Config.sparse_hist_density use
-        the O(nnz) CSR histogram (ops/sparse_hist.py) instead of any
-        O(n*F) dense pass — the reference's OrderedSparseBin role
-        (ordered_sparse_bin.hpp:79-92)."""
-        ds = self.train_set
-        if (ds is not None and ds.is_sparse
-                and self.config.hist_dtype != "float64"):
-            nnz = ds.X_bin.nnz
-            density = nnz / max(1, ds.num_data * ds.num_features)
-            if density <= self.config.sparse_hist_density:
-                from ..ops.sparse_hist import make_sparse_hist_fn
-
-                return make_sparse_hist_fn(ds.X_bin, self._num_bins)
-        if self._use_pallas_hist():
-            from ..ops.pallas_histogram import make_sorted_hist_fn
-
-            return make_sorted_hist_fn(self._num_bins)
         return None  # grower's default segment_sum path
 
     def add_valid_dataset(self, valid_set: BinnedDataset, name: str) -> None:
@@ -862,7 +770,7 @@ class GBDT:
             return False
         if not (cfg.tree_learner == "serial" or len(jax.devices()) == 1):
             return False
-        if jax.process_count() > 1 or cfg.tree_growth != "leafwise":
+        if jax.process_count() > 1:
             return False
         if self._use_f64_hist or self._hist_pool_slots():
             return False

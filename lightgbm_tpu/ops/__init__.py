@@ -1,9 +1,8 @@
-from .histogram import histogram_feature_major, histogram_by_leaf
+from .histogram import histogram_feature_major
 from .split import find_best_split, SplitResult
 
 __all__ = [
     "histogram_feature_major",
-    "histogram_by_leaf",
     "find_best_split",
     "SplitResult",
 ]

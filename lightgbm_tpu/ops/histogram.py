@@ -44,35 +44,6 @@ def histogram_feature_major(
     return jax.vmap(per_feature)(bins_T.astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("num_bins", "num_leaves"))
-@phase_scope("histogram")
-def histogram_by_leaf(
-    bins_T: jax.Array,  # [F, n]
-    leaf_id: jax.Array,  # [n] current leaf per row
-    grad: jax.Array,
-    hess: jax.Array,
-    mask: jax.Array,
-    num_bins: int,
-    num_leaves: int,
-) -> jax.Array:
-    """Level-wise variant: hist[L, F, B, 3] for all leaves in one pass.
-
-    Used by the depthwise grower and the data-parallel learner, where one
-    fused pass per level replaces the reference's per-leaf histogram
-    construction + LRU HistogramPool (feature_histogram.hpp:337-481).
-    """
-    gm = grad * mask
-    hm = hess * mask
-    stats = jnp.stack([gm, hm, mask], axis=-1)  # [n, 3]
-    keys = leaf_id.astype(jnp.int32) * num_bins + bins_T.astype(jnp.int32)  # [F, n]
-
-    def per_feature(k_row):
-        return jax.ops.segment_sum(stats, k_row, num_segments=num_leaves * num_bins)
-
-    out = jax.vmap(per_feature)(keys)  # [F, L*B, 3]
-    return out.reshape(bins_T.shape[0], num_leaves, num_bins, 3).transpose(1, 0, 2, 3)
-
-
 def select_single_hist_fn(num_bins: int, use_pallas: bool):
     """ONE place choosing the per-row-set histogram implementation
     (signature: bins_T, grad, hess, mask -> [F, B, 3]): the single-leaf

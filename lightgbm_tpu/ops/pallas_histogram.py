@@ -1,22 +1,13 @@
-"""Pallas TPU histogram kernel — MXU one-hot matmuls over leaf-sorted rows.
+"""Pallas TPU histogram kernel — MXU one-hot matmuls over one leaf's rows.
 
 The reference's hot loop is a scalar gather-accumulate per row
 (DenseBin::ConstructHistogram, src/io/dense_bin.hpp:39-104).  TPUs have
 no fast scatter, so `jax.ops.segment_sum` (ops/histogram.py) lowers to a
-scatter-add that serializes badly at 10M rows x 64k leaf-bin segments.
-This module reformulates the histogram as dense MXU work:
-
-1. rows are re-ordered so each leaf's rows are contiguous (the same idea
-   as the reference's DataPartition, data_partition.hpp:91-139), with
-   each leaf padded to a multiple of the chunk size C so that
-2. every C-row chunk belongs to exactly ONE leaf, and its histogram is a
-   one-hot matmul on the MXU — no scatter at all, and
-3. chunks of the same leaf are consecutive in the grid, so the Pallas
-   output block (indexed by a scalar-prefetched ``leaf_of_chunk`` map)
-   stays resident in VMEM and accumulates across chunk visits.
-
-Total work is O(n x F x B) MACs per tree LEVEL — independent of the
-number of leaves — plus one stable sort of the leaf ids.
+scatter-add that serializes badly.  This module reformulates the
+histogram of one row set (a leaf's, or the root's) as dense MXU work:
+every C-row chunk's histogram is a one-hot matmul on the MXU, and the
+output block stays resident in VMEM and accumulates across the chunks —
+O(n x F x B) MACs, no scatter at all.
 
 One kernel, and one one-hot body (``bin_sums``), the fused split
 step's too (ops/record.py _hist_tile_body).  The one-hot is built
@@ -43,7 +34,6 @@ from ..device import on_tpu
 from ..obs.device_time import phase_scope
 from .totals import two_sum
 
-DEFAULT_CHUNK = 1024
 FGROUP = 8  # the feature axis is padded to a multiple of this
 # Feature rows per step of the kernel's loop.  Measured alone on a v5e at
 # 7.5M x 100 and 8.92M x 81 (PERF.md, PR 29): 8 rows a step 153.1 / 154.2
@@ -51,9 +41,8 @@ FGROUP = 8  # the feature axis is padded to a multiple of this
 # ``[256, C]`` one-hot of the time: 71.8 ms at 32 rows with bin_sums'
 # 128, PR 37; the other steps not timed again).
 LOOP_ROWS = 4 * FGROUP
-# Rows per grid step of the single-leaf calls (the depth-wise call keeps
-# DEFAULT_CHUNK).  Same measurement, 32 rows a step: 512 138.3 / 138.8
-# ms, 2048 133.3 / 134.1.
+# Rows per grid step of the single-leaf calls.  Same measurement, 32 rows
+# a step: 512 138.3 / 138.8 ms, 2048 133.3 / 134.1.
 SINGLE_LEAF_CHUNK = 2048
 # The one-hot histogram dots carry float32 gradient/hessian sums, and
 # Mosaic runs an un-annotated float32 dot as ONE bf16 MXU pass: measured
@@ -308,80 +297,6 @@ def _hist_pallas_call(
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("num_bins", "num_leaves", "chunk", "interpret"),
-)
-@phase_scope("histogram")
-def histogram_by_leaf_sorted(
-    bins_T: jax.Array,  # [F, n] uint8/uint16 binned matrix, feature-major
-    leaf_id: jax.Array,  # [n] int32 leaf per row
-    grad: jax.Array,  # [n]
-    hess: jax.Array,  # [n]
-    mask: jax.Array,  # [n] 0/1
-    num_bins: int,
-    num_leaves: int,
-    chunk: int = DEFAULT_CHUNK,
-    interpret: bool = False,
-) -> jax.Array:
-    """Drop-in equivalent of ops.histogram.histogram_by_leaf:
-    returns hist[num_leaves, F, num_bins, 3] = (sum_grad, sum_hess, count).
-    """
-    F, n = bins_T.shape
-    L = num_leaves
-    C = chunk
-    B = _pad_pow(num_bins)
-    Fp = ((F + FGROUP - 1) // FGROUP) * FGROUP  # the kernel's grouping
-    if Fp != F:
-        bins_T = jnp.pad(bins_T, ((0, Fp - F), (0, 0)))
-
-    # ---- leaf-sorted order + per-leaf chunk-padded layout
-    leaf_id = leaf_id.astype(jnp.int32)
-    counts = jnp.bincount(leaf_id, length=L)  # [L]
-    # every leaf gets >= 1 chunk so empty leaves still zero-init their
-    # output row (their chunk carries all-zero stats)
-    chunks_per_leaf = jnp.maximum((counts + C - 1) // C, 1)
-    chunk_start = jnp.concatenate(
-        [jnp.zeros(1, counts.dtype), jnp.cumsum(chunks_per_leaf)]
-    )  # [L+1] exclusive chunk offsets
-    n_chunks = (n + C - 1) // C + L  # static capacity (each leaf <=1 partial)
-    n_pad = n_chunks * C
-
-    order = jnp.argsort(leaf_id, stable=True)  # [n]
-    leaf_sorted = leaf_id[order]
-    row_start = jnp.concatenate(
-        [jnp.zeros(1, counts.dtype), jnp.cumsum(counts)]
-    )
-    rank = jnp.arange(n) - row_start[leaf_sorted]  # position within leaf
-    dest = (chunk_start[leaf_sorted] * C + rank).astype(jnp.int32)  # [n]
-
-    # invert dest into a gather map: a [n_pad] 1-D scatter of int32, then
-    # row GATHERS for the big buffers — far cheaper on TPU than scattering
-    # the whole [Fp, n_pad] matrix (pad slots read OOB -> fill 0)
-    src = jnp.full((n_pad,), n, jnp.int32).at[dest].set(
-        order.astype(jnp.int32)
-    )
-    bins_buf = jnp.take(bins_T, src, axis=1, mode="fill", fill_value=0)
-    gm = grad * mask
-    hm = hess * mask
-    stats = split_stats(jnp.stack(
-        [gm, hm, mask, jnp.zeros_like(mask)]).astype(jnp.float32))
-    stats_buf = jnp.take(stats, src, axis=1, mode="fill", fill_value=0)
-
-    # chunk -> leaf map; trailing unused chunks land on the dummy row L
-    cidx = jnp.arange(n_chunks, dtype=chunk_start.dtype)
-    leaf_of_chunk = jnp.clip(
-        jnp.searchsorted(chunk_start, cidx, side="right") - 1, 0, L
-    ).astype(jnp.int32)
-    leaf_of_chunk = jnp.where(cidx < chunk_start[L], leaf_of_chunk, L)
-
-    out = _hist_pallas_call(
-        leaf_of_chunk, bins_buf, stats_buf, L + 1, Fp, B, C, n_chunks,
-        interpret,
-    )  # [L+1, Fp, B, 4]
-    return out[:L, :F, :num_bins, :3]
-
-
-@functools.partial(
     jax.jit, static_argnames=("num_bins", "chunk", "interpret")
 )
 @phase_scope("histogram")
@@ -396,10 +311,9 @@ def histogram_single_leaf(
 ) -> jax.Array:
     """hist[F, num_bins, 3] for a single row set — the leaf-wise
     learner's per-split histogram (DenseBin::ConstructHistogram over the
-    smaller child's gathered rows, dense_bin.hpp:39-104).  Same one-hot
-    MXU matmul as the sorted kernel but with a trivial chunk->leaf map:
-    every chunk accumulates into the one output block, so no sort, no
-    scatter — just O(cap x B x F) dense MACs.
+    smaller child's gathered rows, dense_bin.hpp:39-104): every chunk
+    accumulates into the one output block, so no sort, no scatter — just
+    O(cap x B x F) dense MACs.
     """
     F, cap = bins_T.shape
     bins_T, stats, n_chunks, Fp, B, C = _prep_single_leaf(
@@ -481,8 +395,9 @@ def _single_hist_fn_raw(num_bins: int, chunk: int, interpret: bool):
 def make_single_hist_fn(num_bins: int, chunk: int = SINGLE_LEAF_CHUNK):
     """hist_fn for the leaf-wise grower (signature: bins_T, grad, hess,
     mask -> [F, B, 3]) backed by the single-leaf MXU kernel.  Cached per
-    config so repeated boosters reuse the jit cache (see
-    make_sorted_hist_fn)."""
+    (num_bins, chunk, interpret): the grower jits with hist_fn as a
+    static argument, so returning the SAME closure across boosters (cv
+    folds, repeated train calls) is what keeps the jit cache warm."""
     return _single_hist_fn(num_bins, chunk, not on_tpu())
 
 
@@ -492,29 +407,6 @@ def _single_hist_fn(num_bins: int, chunk: int, interpret: bool):
         return histogram_single_leaf(
             bins_T, grad, hess, mask,
             num_bins=num_bins, chunk=chunk, interpret=interpret,
-        )
-
-    return hist_fn
-
-
-def make_sorted_hist_fn(num_bins: int, chunk: int = DEFAULT_CHUNK):
-    """hist_fn for the depthwise grower (signature: bins_T, leaf_id, grad,
-    hess, mask, num_leaves -> [L, F, B, 3]) backed by the Pallas kernel.
-    Interpret mode is selected off-TPU so tests run anywhere.
-
-    Cached per (num_bins, chunk, interpret): the grower jits with hist_fn
-    as a static argument, so returning the SAME closure across boosters
-    (cv folds, repeated train calls) is what keeps the jit cache warm."""
-    return _sorted_hist_fn(num_bins, chunk, not on_tpu())
-
-
-@functools.lru_cache(maxsize=None)
-def _sorted_hist_fn(num_bins: int, chunk: int, interpret: bool):
-    def hist_fn(bins_T, leaf_id, grad, hess, mask, num_leaves):
-        return histogram_by_leaf_sorted(
-            bins_T, leaf_id, grad, hess, mask,
-            num_bins=num_bins, num_leaves=num_leaves,
-            chunk=chunk, interpret=interpret,
         )
 
     return hist_fn

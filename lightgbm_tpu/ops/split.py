@@ -182,7 +182,8 @@ def find_best_split(
     )
 
 
-# vectorized over leaves (depthwise grower / batched candidate evaluation)
+# vectorized over leaves (the canonical grower's two-child search, the
+# batched forest, analysis/kernel_parity.py)
 find_best_split_leaves = jax.vmap(
     find_best_split,
     in_axes=(0, 0, 0, 0, None, None, None, None, None, None, None, None, 0),

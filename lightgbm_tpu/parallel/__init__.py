@@ -14,7 +14,6 @@ from .mesh import data_mesh, default_device_count  # noqa: F401
 from .data_parallel import make_data_parallel_grower  # noqa: F401
 from .feature_parallel import make_feature_parallel_grower  # noqa: F401
 from .voting_parallel import make_voting_parallel_grower  # noqa: F401
-from .grid_parallel import grid_mesh, make_grid_parallel_grower  # noqa: F401
 
 __all__ = [
     "data_mesh",
@@ -22,6 +21,4 @@ __all__ = [
     "make_data_parallel_grower",
     "make_feature_parallel_grower",
     "make_voting_parallel_grower",
-    "grid_mesh",
-    "make_grid_parallel_grower",
 ]

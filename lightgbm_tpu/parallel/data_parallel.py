@@ -4,8 +4,8 @@ Two growers stand here, and ``models/gbdt.py select_grower`` chooses.
 On the chips of one process ``make_fused_data_parallel_grower`` (at the
 end) runs the fused grower itself a chip (learners/fused.py with
 ``axis``: one histogram all-reduce a split).  Everything else -- the
-CPU, more than one process (parallel/multihost.py), depth-wise and hybrid
-growth -- runs the canonical grower through the hooks below.
+CPU, more than one process (parallel/multihost.py) -- runs the canonical
+grower through the hooks below.
 
 TPU-native re-design of DataParallelTreeLearner
 (src/treelearner/data_parallel_tree_learner.cpp), the canonical hooks:
@@ -49,20 +49,14 @@ match serial trees up to float reduction order.
 
 from __future__ import annotations
 
-import functools
-import os as _os
-
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..device import on_tpu
-from ..learners.depthwise import grow_tree_depthwise
-from ..learners.hybrid import HYBRID_STOP_FACTOR
 from ..learners.serial import grow_tree
 from ..obs import telemetry
 from ..obs.dist import record_collective_site
-from ..ops.histogram import histogram_by_leaf, histogram_feature_major
 from ..ops.split import SplitResult, find_best_split
 from .mesh import ROW_AXIS, row_padded_grower
 from .split_comm import (combine_gathered_split_infos, gather_and_combine,
@@ -71,8 +65,7 @@ from .split_comm import (combine_gathered_split_infos, gather_and_combine,
 
 def data_parallel_sharded(
     mesh, num_bins: int, max_leaves: int, axis: str = ROW_AXIS,
-    growth: str = "leafwise", sorted_hist: bool = False,
-    hist_pool: int = 0, record: bool = True,
+    sorted_hist: bool = False, hist_pool: int = 0, record: bool = True,
 ):
     """The raw shard-mapped grow fn over ``mesh`` (rows sharded on
     ``axis``).  Callers are responsible for row padding / global-array
@@ -82,19 +75,9 @@ def data_parallel_sharded(
 
     num_shards = mesh.shape[axis]
 
-    # per-shard kernels: leaf-wise per-split histogram over the gathered
-    # smaller child, and the depthwise per-level leaf-sorted variant
+    # the per-shard kernel: the per-split histogram over the gathered
+    # smaller child
     hist_local = select_single_hist_fn(num_bins, sorted_hist)
-    if sorted_hist:
-        from ..ops.pallas_histogram import make_sorted_hist_fn
-
-        local_level_hist = make_sorted_hist_fn(num_bins)
-    else:
-        def local_level_hist(bins_T, leaf_id, grad, hess, mask, num_leaves):
-            return histogram_by_leaf(
-                bins_T, leaf_id, grad, hess, mask,
-                num_bins=num_bins, num_leaves=num_leaves,
-            )
 
     def reduce_sum(x):
         return jax.lax.psum(x, axis)
@@ -118,52 +101,6 @@ def data_parallel_sharded(
                 feature=jnp.where(r.feature >= 0, r.feature + start, -1)
             )
 
-        if growth in ("depthwise", "hybrid"):
-            from ..ops.split import find_best_split_leaves
-
-            def level_hist_scatter(bt, lid, g, h, m, num_leaves):
-                # one reduce-scatter per LEVEL of [L, F, B, 3] feature
-                # blocks — the reference's per-level ReduceScatter
-                # (data_parallel_tree_learner.cpp:127-157) at half an
-                # allreduce's bytes; each device keeps [L, F/D, B, 3]
-                hl = local_level_hist(bt, lid, g, h, m, num_leaves)
-                hl = jnp.pad(hl, ((0, 0), (0, pad), (0, 0), (0, 0)))
-                out = jax.lax.psum_scatter(hl, axis, scatter_dimension=1,
-                                           tiled=True)
-                # trace-time site census (obs/dist.py): op identity +
-                # result bytes, once per retrace — the per-op half of
-                # the collectives-per-split contract
-                record_collective_site(
-                    "dp.level_hist_reduce_scatter", "reduce-scatter",
-                    out.size * out.dtype.itemsize)
-                return out
-
-            def search_leaves_fn(hist, sg, sh, c, can, _fm, _nb, _ic, prm):
-                # per-leaf shard search + ONE packed [D, L, 11] combine
-                # (the SplitInfo allreduce,
-                # data_parallel_tree_learner.cpp:192-227)
-                r = find_best_split_leaves(
-                    hist, sg, sh, c,
-                    local(fmask_p), local(nbpf_p), local(iscat_p),
-                    prm.min_data_in_leaf, prm.min_sum_hessian_in_leaf,
-                    prm.lambda_l1, prm.lambda_l2, prm.min_gain_to_split,
-                    can,
-                )
-                r = offset_feature(r)
-                g2 = jax.lax.all_gather(pack_split(r), axis)  # [D, L, 11]
-                record_collective_site(
-                    "dp.split_allgather_leaves", "all-gather",
-                    g2.size * g2.dtype.itemsize)
-                return combine_gathered_split_infos(unpack_split(g2))
-
-            if growth == "depthwise":
-                return grow_tree_depthwise(
-                    bins_T, grad, hess, bag_mask, fmask, nbpf, is_cat,
-                    params,
-                    num_bins=num_bins, max_leaves=max_leaves,
-                    hist_fn=level_hist_scatter,
-                    search_leaves_fn=search_leaves_fn,
-                )
         def hist_scatter(bins_arg, g, h, m):
             # local full-feature partials -> reduce-scatter feature blocks:
             # this device leaves owning the GLOBAL histogram of features
@@ -236,32 +173,6 @@ def data_parallel_sharded(
             m = jnp.max(g, axis=0)
             return s[0], s[1], m[0], m[1]
 
-        if growth == "hybrid":
-            # sharded hybrid: depthwise phase with the per-level
-            # reduce-scatter, then the best-first phase resumes with the
-            # same sharded hooks (learners/hybrid.py semantics)
-            tree1, leaf1 = grow_tree_depthwise(
-                bins_T, grad, hess, bag_mask, fmask, nbpf, is_cat, params,
-                num_bins=num_bins, max_leaves=max_leaves,
-                hist_fn=level_hist_scatter,
-                search_leaves_fn=search_leaves_fn,
-                stop_before_budget=HYBRID_STOP_FACTOR,
-            )
-            return grow_tree(
-                bins_T, grad, hess, bag_mask, fmask, nbpf, is_cat, params,
-                num_bins=num_bins, max_leaves=max_leaves,
-                hist_fn=hist_scatter,
-                reduce_fn=reduce_sum,
-                search_fn=search_fn,
-                search2_fn=search2_fn,
-                child_counts_fn=child_counts_fn,
-                init_tree=tree1,
-                init_leaf_id=leaf1,
-                init_hist_fn=level_hist_scatter,
-                init_search_fn=search_leaves_fn,
-                reduce_max_fn=lambda c: jax.lax.pmax(c, axis),
-            )
-
         return grow_tree(
             bins_T,
             grad,
@@ -297,21 +208,14 @@ def data_parallel_sharded(
 
 def make_data_parallel_grower(
     mesh, num_bins: int, max_leaves: int, axis: str = ROW_AXIS,
-    growth: str = "leafwise", sorted_hist: bool = False,
-    hist_pool: int = 0, record: bool = True,
+    sorted_hist: bool = False, hist_pool: int = 0, record: bool = True,
 ):
     """Build a grow(bins_T, grad, hess, bag_mask, feature_mask,
     num_bins_per_feature, is_categorical, params) -> (tree, leaf_id)
-    callable running the serial growth algorithm SPMD over ``mesh``.
-
-    ``growth="depthwise"`` runs the level-synchronous learner instead:
-    per LEVEL, one psum_scatter of [L, F, B, 3] feature blocks + one
-    packed SplitInfo all_gather (two collectives per level at half an
-    allreduce's histogram bytes — the reference's per-level
-    reduce-scatter + SplitInfo allreduce pattern)."""
+    callable running the serial growth algorithm SPMD over ``mesh``."""
     sharded = data_parallel_sharded(
-        mesh, num_bins, max_leaves, axis=axis, growth=growth,
-        sorted_hist=sorted_hist, hist_pool=hist_pool, record=record,
+        mesh, num_bins, max_leaves, axis=axis, sorted_hist=sorted_hist,
+        hist_pool=hist_pool, record=record,
     )
     return row_padded_grower(sharded, mesh.shape[axis])
 

@@ -16,7 +16,6 @@ import numpy as np
 from jax.sharding import Mesh
 
 ROW_AXIS = "row"  # data-parallel axis (rows sharded)
-FEATURE_AXIS = "feature"  # feature-parallel axis (split search sharded)
 
 
 def default_device_count() -> int:

@@ -260,7 +260,7 @@ def sync_config_across_processes(cfg) -> None:
             "objective", "num_iterations", "learning_rate", "num_leaves_",
             "max_bin", "min_data_in_leaf", "min_sum_hessian_in_leaf",
             "lambda_l1", "lambda_l2", "max_depth", "tree_learner",
-            "tree_growth", "boosting_type", "num_class",
+            "boosting_type", "num_class",
         )
     )
     # crc32 is uint32; mask to int31 so the int32 transport is lossless
@@ -280,8 +280,7 @@ def sync_config_across_processes(cfg) -> None:
 
 def make_multihost_data_parallel_grower(
     mesh, num_bins: int, max_leaves: int, axis: str = ROW_AXIS,
-    growth: str = "leafwise", sorted_hist: bool = False,
-    hist_pool: int = 0, record: bool = True,
+    sorted_hist: bool = False, hist_pool: int = 0, record: bool = True,
     collective_deadline: Optional[float] = None,
 ):
     """Data-parallel grower across processes: each process feeds its
@@ -321,8 +320,8 @@ def make_multihost_data_parallel_grower(
 
     sharded = jax.jit(
         data_parallel_sharded(
-            mesh, num_bins, max_leaves, axis=axis, growth=growth,
-            sorted_hist=sorted_hist, hist_pool=hist_pool, record=record,
+            mesh, num_bins, max_leaves, axis=axis, sorted_hist=sorted_hist,
+            hist_pool=hist_pool, record=record,
         )
     )
     col_s = NamedSharding(mesh, P(None, axis))

@@ -38,9 +38,7 @@ def test_bench_always_emits_json_line(tmp_path):
     assert out["unit"] == "s/tree"
     assert out["value"] > 0, out
     assert out["platform"] == "cpu"
-    # the headline must be the reference-parity growth mode on EVERY
-    # platform (VERDICT r2: a bench may not advertise the approximate
-    # depthwise mode and its ~0.01 AUC gap as the result)
+    # the row says its trees are the reference's leaf-wise ones
     assert out["growth"] == "leafwise"
     assert out["stop_lag"] == 4  # the row says which stop check ran
     # self-description: warm-up + compile evidence inside the row
@@ -99,7 +97,7 @@ def _inprocess_bench_run(bench):
     """One in-process bench measurement at the contract's small shape
     (module constants are patched by the caller)."""
     X, y = bench.make_data(50_000)
-    v, _auc, _vauc, info = bench.ours_sec_per_tree(X, y, "leafwise")
+    v, _auc, _vauc, info = bench.ours_sec_per_tree(X, y)
     assert info["compile_stable"], info
     return v
 

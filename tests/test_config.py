@@ -78,6 +78,40 @@ def test_unknown_param_warns(capsys):
     assert c.num_leaves == 127  # default untouched
 
 
+@pytest.mark.parametrize("key,value", [
+    ("tree_growth", "depthwise"),
+    ("tree_growth", "hybrid"),
+    ("grid_feature_shards", "4"),
+    ("sparse_hist_density", "0.5"),
+])
+def test_growth_settings_are_unknown_and_trees_leafwise(key, value, capsys,
+                                                        monkeypatch):
+    """Trees are leaf-wise only, as the reference grows them: a params
+    dict that still names a growth or grid setting warns by name, once,
+    and grows the trees the same params grow without it."""
+    import numpy as np
+
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import config as config_mod
+
+    monkeypatch.setattr(config_mod, "_warned_unknown_params", set())
+    rng = np.random.RandomState(3)
+    X = rng.randn(400, 5)
+    y = (X[:, 0] + 0.5 * X[:, 1] * X[:, 2] > 0).astype(float)
+    params = {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 5,
+              "verbose": -1}
+
+    def model(p):
+        return lgb.train(p, lgb.Dataset(X, label=y),
+                         num_boost_round=3).model_to_string()
+
+    want = model(params)
+    capsys.readouterr()
+    got = model({**params, key: value})
+    assert capsys.readouterr().err.count(f"Unknown parameter: {key}") == 1
+    assert got == want
+
+
 @pytest.mark.parametrize("bad", [
     {"num_leaves": 1},
     {"feature_fraction": 0.0},
@@ -90,6 +124,7 @@ def test_unknown_param_warns(capsys):
     {"metric_freq": -1},
     {"drop_rate": 2.0},
     {"skip_drop": -0.1},
+    {"tree_learner": "grid"},  # the reference's four learners only
 ])
 def test_value_range_checks(bad):
     """Reference CHECK()s (config.cpp:270-317) are enforced."""

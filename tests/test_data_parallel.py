@@ -139,28 +139,6 @@ def test_dp_gbdt_end_to_end():
     np.testing.assert_allclose(preds["serial"], preds["data"], atol=1e-4)
 
 
-def test_depthwise_data_parallel_matches_single_device():
-    """Depthwise growth under the 8-device mesh: the per-level psum'd
-    histogram must reproduce the single-device depthwise tree."""
-    from lightgbm_tpu.learners.depthwise import grow_tree_depthwise
-
-    num_bins, L = 16, 31
-    args = _random_problem(4096, 6, num_bins, seed=5)
-    params = _params()
-    t1, leaf1 = grow_tree_depthwise(
-        *args, params, num_bins=num_bins, max_leaves=L
-    )
-    mesh = data_mesh()
-    grow = make_data_parallel_grower(
-        mesh, num_bins=num_bins, max_leaves=L, growth="depthwise"
-    )
-    t2, leaf2 = grow(*args, params)
-    _assert_trees_match(t1, t2)
-    # row partition agrees wherever the trees agree structurally
-    same = np.asarray(leaf1) == np.asarray(leaf2)
-    assert same.mean() > 0.99
-
-
 def test_dp_exact_with_float64_histograms():
     """With hist_dtype=float64 (the reference's double accumulation,
     include/LightGBM/bin.h:21-22) parallel trees must be EXACTLY the

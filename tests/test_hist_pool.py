@@ -75,7 +75,7 @@ def test_large_feature_count_trains_in_budget():
     cfg = Config(
         objective="binary", num_leaves=255, max_bin=256,
         min_data_in_leaf=5, histogram_pool_size=64.0,
-        tree_learner="serial", tree_growth="leafwise",
+        tree_learner="serial",
     )
     ds = BinnedDataset.from_matrix(X, Metadata(label=y), config=cfg)
     booster = GBDT(cfg, ds, create_objective(cfg, ds.metadata, n))

@@ -9,10 +9,7 @@ from /root/reference) on the identical synthetic data and parameters:
     train AUC computed from its saved model's raw scores: 0.88901
     (reference run 2026-07, see BASELINE.md)
 
-Leaf-wise (the reference-compatible growth and the TPU bench mode) must
-track it to |dAUC| <= 0.002; depth-wise is a level-synchronous
-approximation (learners/depthwise.py docstring) and gets a documented
-looser bound.
+Leaf-wise growth, the reference's, must track it to |dAUC| <= 0.002.
 """
 
 import numpy as np
@@ -29,35 +26,16 @@ REF_AUC = 0.88901  # reference CLI, 50k rows / 30 trees / 31 leaves
 ROWS, TREES, LEAVES = 50_000, 30, 31
 
 
-@pytest.fixture(scope="module")
-def data():
-    return bench.make_data(ROWS)
-
-
-def _train_auc(X, y, growth):
+@pytest.mark.slow  # tier-1 time budget (ROADMAP verify runs -m 'not slow'; see pyproject)
+def test_leafwise_auc_matches_reference():
+    X, y = bench.make_data(ROWS)
     cfg = Config(objective="binary", num_leaves=LEAVES, max_bin=255,
-                 learning_rate=0.1, min_data_in_leaf=100, metric=["auc"],
-                 tree_growth=growth)
+                 learning_rate=0.1, min_data_in_leaf=100, metric=["auc"])
     ds = BinnedDataset.from_matrix(
         X, Metadata(label=y.astype(np.float32)), config=cfg
     )
     booster = GBDT(cfg, ds, create_objective(cfg, ds.metadata, ds.num_data))
     for _ in range(TREES):
         booster.train_one_iter()
-    return booster.eval_at(0)["auc"]
-
-
-@pytest.mark.slow  # tier-1 time budget (ROADMAP verify runs -m 'not slow'; see pyproject)
-def test_leafwise_auc_matches_reference(data):
-    X, y = data
-    auc = _train_auc(X, y, "leafwise")
+    auc = booster.eval_at(0)["auc"]
     assert abs(auc - REF_AUC) <= 0.002, f"leafwise AUC {auc:.5f} vs {REF_AUC}"
-
-
-@pytest.mark.slow  # tier-1 time budget (ROADMAP verify runs -m 'not slow'; see pyproject)
-def test_depthwise_auc_tracks_reference(data):
-    X, y = data
-    auc = _train_auc(X, y, "depthwise")
-    # level-synchronous growth is NOT node-identical to best-first; the
-    # documented accuracy cost at this scale is ~0.01 AUC
-    assert abs(auc - REF_AUC) <= 0.02, f"depthwise AUC {auc:.5f} vs {REF_AUC}"

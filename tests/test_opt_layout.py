@@ -118,3 +118,43 @@ def test_opt_u16_bins_and_feature_mask():
     # masked features never appear as split features
     used = np.asarray(t1.split_feature)
     assert not np.isin(used[used >= 0], [1, 4]).any()
+
+
+# What a leaf-wise grower must do whatever its layout: stop at
+# ``max_depth``, keep a table with no possible split whole, and spend
+# exactly the ``num_leaves`` budget when every split gains.
+_GROWERS = pytest.mark.parametrize("raw", [False, True],
+                                   ids=["canonical", "fused"])
+
+
+@_GROWERS
+def test_max_depth_bounds_every_leaf(raw):
+    bins, grad, hess = _mk(seed=2)
+    tree, leaf = _grow(bins, grad, hess, 23, raw, max_leaves=31,
+                       max_depth=3)
+    nl = int(tree.num_leaves)
+    assert nl == 8, nl  # the full depth-3 tree, and no deeper
+    np.testing.assert_array_equal(np.asarray(tree.leaf_depth)[:nl],
+                                  np.full(nl, 3))
+    np.testing.assert_array_equal(np.bincount(np.asarray(leaf), minlength=nl),
+                                  np.asarray(tree.leaf_count)[:nl])
+
+
+@_GROWERS
+def test_no_possible_split_gives_one_leaf(raw):
+    n = 500
+    bins = np.zeros((n, 3), int)  # constant columns: nothing to split on
+    grad = np.random.RandomState(0).randint(-8, 9, n).astype(np.float32)
+    hess = np.ones(n, np.float32)
+    tree, leaf = _grow(bins, grad, hess, 4, raw, max_leaves=15)
+    assert int(tree.num_leaves) == 1
+    assert not np.asarray(leaf).any()
+
+
+@_GROWERS
+def test_num_leaves_budget_is_spent_exactly(raw):
+    bins, grad, hess = _mk(seed=4)
+    for budget in (2, 5):
+        tree, leaf = _grow(bins, grad, hess, 23, raw, max_leaves=budget)
+        assert int(tree.num_leaves) == budget
+        assert set(np.unique(np.asarray(leaf))) == set(range(budget))

@@ -1,109 +1,9 @@
-"""Pallas sorted-matmul histogram == segment_sum histogram (interpret
+"""Pallas one-hot matmul histogram == segment_sum histogram (interpret
 mode on CPU; the compiled kernel runs on real TPU only)."""
 
 import numpy as np
 import jax.numpy as jnp
 import pytest
-
-import lightgbm_tpu as lgb
-from lightgbm_tpu.ops.histogram import histogram_by_leaf
-from lightgbm_tpu.ops.pallas_histogram import histogram_by_leaf_sorted
-
-
-def _problem(n, F, B, L, seed=0):
-    rng = np.random.RandomState(seed)
-    return (
-        jnp.asarray(rng.randint(0, B, size=(F, n)).astype(np.uint8)),
-        jnp.asarray(rng.randint(0, L, size=n).astype(np.int32)),
-        jnp.asarray(rng.randn(n).astype(np.float32)),
-        jnp.asarray(np.abs(rng.randn(n)).astype(np.float32)),
-        jnp.asarray((rng.rand(n) > 0.3).astype(np.float32)),
-    )
-
-
-@pytest.mark.parametrize("n,F,B,L,chunk", [
-    (5000, 6, 16, 8, 256),
-    (1000, 3, 32, 4, 128),      # n not divisible by chunk
-    (300, 2, 7, 5, 128),        # B not a lane multiple
-])
-def test_kernel_matches_segment_sum(n, F, B, L, chunk):
-    bins_T, leaf, g, h, m = _problem(n, F, B, L)
-    ref = histogram_by_leaf(bins_T, leaf, g, h, m, num_bins=B, num_leaves=L)
-    got = histogram_by_leaf_sorted(
-        bins_T, leaf, g, h, m, num_bins=B, num_leaves=L,
-        chunk=chunk, interpret=True,
-    )
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=1e-5, atol=1e-4)
-
-
-def test_kernel_empty_and_skewed_leaves():
-    bins_T, _, g, h, m = _problem(2000, 4, 16, 8)
-    for leaf_np in [
-        np.zeros(2000),                       # all rows in leaf 0
-        np.where(np.arange(2000) < 5, 7, 2),  # tiny leaf + empty leaves
-    ]:
-        leaf = jnp.asarray(leaf_np.astype(np.int32))
-        ref = histogram_by_leaf(bins_T, leaf, g, h, m, num_bins=16, num_leaves=8)
-        got = histogram_by_leaf_sorted(
-            bins_T, leaf, g, h, m, num_bins=16, num_leaves=8,
-            chunk=256, interpret=True,
-        )
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-4)
-
-
-def test_depthwise_training_with_matmul_hist():
-    """End-to-end: hist_impl=matmul trains the same model as segment."""
-    rng = np.random.RandomState(11)
-    X = rng.randn(1200, 5)
-    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(float)
-    preds = {}
-    for impl in ("segment", "matmul"):
-        bst = lgb.train(
-            {"objective": "binary", "num_leaves": 7, "min_data_in_leaf": 20,
-             "min_sum_hessian_in_leaf": 1.0, "tree_growth": "depthwise",
-             "hist_impl": impl, "max_bin": 32, "verbose": 0},
-            lgb.Dataset(X, label=y, max_bin=32),
-            num_boost_round=3, verbose_eval=False,
-        )
-        preds[impl] = bst.predict(X)
-    np.testing.assert_allclose(preds["matmul"], preds["segment"],
-                               rtol=1e-4, atol=1e-5)
-
-
-def test_data_parallel_sorted_hist():
-    """psum over the Pallas kernel on the 8-device mesh matches the
-    single-device depthwise tree (review fix: path was unexercised)."""
-    import jax.numpy as jnp
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.learners.depthwise import grow_tree_depthwise
-    from lightgbm_tpu.learners.serial import TreeLearnerParams
-    from lightgbm_tpu.parallel import data_mesh, make_data_parallel_grower
-
-    rng = np.random.RandomState(4)
-    n, F, B, L = 2048, 4, 16, 15
-    bins_T = jnp.asarray(rng.randint(0, B, size=(F, n)).astype(np.uint8))
-    grad = jnp.asarray(rng.randn(n).astype(np.float32))
-    hess = jnp.asarray(np.abs(rng.randn(n)).astype(np.float32) + 0.1)
-    args = (bins_T, grad, hess, jnp.ones(n, jnp.float32),
-            jnp.ones(F, bool), jnp.full(F, B, jnp.int32), jnp.zeros(F, bool))
-    params = TreeLearnerParams.from_config(Config(min_data_in_leaf=20,
-                                                  min_sum_hessian_in_leaf=1e-3))
-    t1, _ = grow_tree_depthwise(*args, params, num_bins=B, max_leaves=L)
-    grow = make_data_parallel_grower(
-        data_mesh(), num_bins=B, max_leaves=L,
-        growth="depthwise", sorted_hist=True,
-    )
-    t2, _ = grow(*args, params)
-    assert int(t1.num_leaves) == int(t2.num_leaves)
-    nl = int(t1.num_leaves)
-    same = sum(
-        int(np.asarray(t1.split_feature)[i]) == int(np.asarray(t2.split_feature)[i])
-        and int(np.asarray(t1.threshold_bin)[i]) == int(np.asarray(t2.threshold_bin)[i])
-        for i in range(nl - 1)
-    )
-    assert same >= nl - 2  # psum reduction-order ulps may flip one near-tie
 
 
 def test_single_leaf_hist_matches_segment():
@@ -139,8 +39,7 @@ def test_leafwise_training_matmul_vs_segment():
     for impl in ("matmul", "segment"):
         bst = engine.train(
             {"objective": "binary", "num_leaves": 15, "verbose": -1,
-             "min_data_in_leaf": 20, "hist_impl": impl,
-             "tree_growth": "leafwise"},
+             "min_data_in_leaf": 20, "hist_impl": impl},
             lgb.Dataset(X, label=y, max_bin=32),
             num_boost_round=3, verbose_eval=False,
         )
@@ -169,8 +68,7 @@ def test_data_parallel_leafwise_matmul_hist():
     )
     t1, _ = grow_tree(*args, params, num_bins=B, max_leaves=L)
     grow = make_data_parallel_grower(
-        data_mesh(), num_bins=B, max_leaves=L,
-        growth="leafwise", sorted_hist=True,
+        data_mesh(), num_bins=B, max_leaves=L, sorted_hist=True,
     )
     t2, _ = grow(*args, params)
     assert int(t1.num_leaves) == int(t2.num_leaves)

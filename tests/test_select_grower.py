@@ -21,16 +21,29 @@ from lightgbm_tpu.objectives import create_objective
 from lightgbm_tpu.ops import record as R
 
 
-def _booster(platform, vmem=None, monkeypatch=None, F=5, n=600, **params):
+def _booster(platform, vmem=None, monkeypatch=None, F=5, n=600, csr=False,
+             **params):
+    """A booster on an ``n`` x ``F`` table; ``csr``: nine cells in ten
+    zero, ingested as CSR and kept sparse (io/sparse.py)."""
     rng = np.random.RandomState(0)
     X = rng.randn(n, F).astype(np.float32)
+    if csr:
+        X[rng.rand(n, F) < 0.9] = 0.0
     y = (X[:, 0] + X[:, 1] > 0).astype(np.float32)
     cfg = Config(objective="binary", num_leaves=7, min_data_in_leaf=5,
                  **params)
     if vmem is not None:
         monkeypatch.setattr(device, "vmem_bytes", lambda: vmem)
     with device.assume_platform(platform):
-        ds = BinnedDataset.from_matrix(X, Metadata(label=y), config=cfg)
+        if csr:
+            rows, cols = np.nonzero(X)
+            indptr = np.concatenate(
+                [[0], np.cumsum(np.bincount(rows, minlength=n))])
+            ds = BinnedDataset.from_csr(indptr, cols, X[rows, cols], F,
+                                        Metadata(label=y), cfg)
+            assert ds.is_sparse
+        else:
+            ds = BinnedDataset.from_matrix(X, Metadata(label=y), config=cfg)
         return gbdt_mod.GBDT(cfg, ds, create_objective(cfg, ds.metadata, n))
 
 
@@ -42,8 +55,10 @@ _TABLE = [
      "hist_dtype=float64", serial.grow_tree),
     ("pool", "tpu", {"histogram_pool_size": 0.01}, "canonical",
      "histogram_pool_size=0.01", serial.grow_tree),
-    ("hybrid", "tpu", {"tree_growth": "hybrid"}, "canonical",
-     "tree_growth=hybrid", None),
+    ("hist_impl=segment", "tpu", {"hist_impl": "segment"}, "canonical",
+     "hist_impl=segment", serial.grow_tree),
+    # sparse ingest reaches the growers through dense bins
+    ("csr-ingested", "tpu", {"csr": True}, "fused", "", fused.grow_tree),
     ("tree_learner=data", "tpu", {"tree_learner": "data"}, "fused", "",
      None),
     ("tree_learner=feature", "tpu", {"tree_learner": "feature"},

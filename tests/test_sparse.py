@@ -206,80 +206,34 @@ def test_sparse_predict_chunked_matches_dense():
     assert p_sparse.shape == (n,)
 
 
-def test_sparse_histogram_matches_dense():
-    """O(nnz) CSR histogram == dense histogram_by_leaf on the densified
-    matrix (ops/sparse_hist.py; reference ordered_sparse_bin.hpp:79-92)."""
-    import jax.numpy as jnp
+@pytest.mark.parametrize("learner", ["serial", "data", "feature", "voting"])
+def test_csr_table_grows_its_dense_twins_trees(learner):
+    """Sparse ingest reaches every leaf-wise learner through dense bins: a
+    CSR-ingested table grows, node for node and bit for bit, the trees of
+    its dense twin, on one device and over the CPU mesh."""
+    import jax
 
-    from lightgbm_tpu.ops.histogram import histogram_by_leaf
-    from lightgbm_tpu.ops.sparse_hist import (
-        entry_rows, sparse_histogram_by_leaf)
-
-    n, f, B, L = 500, 20, 16, 5
-    dense, indptr, cols, _ = _random_csr(n, f, 0.04, seed=4)
-    ds = BinnedDataset.from_csr(
-        indptr, cols, dense[np.nonzero(dense)], f,
-        Metadata(label=np.zeros(n, np.float32)),
-        config=Config(max_bin=B, is_enable_sparse=True),
-    )
-    assert ds.is_sparse
-    sb = ds.X_bin
-    rng = np.random.RandomState(0)
-    leaf_id = rng.randint(0, L, n).astype(np.int32)
-    g = rng.randn(n).astype(np.float32)
-    h = (rng.rand(n) + 0.5).astype(np.float32)
-    m = (rng.rand(n) > 0.3).astype(np.float32)
-
-    got = sparse_histogram_by_leaf(
-        jnp.asarray(entry_rows(np.asarray(sb.indptr))),
-        jnp.asarray(sb.col), jnp.asarray(sb.bin),
-        jnp.asarray(sb.default_bins, jnp.int32),
-        jnp.asarray(leaf_id), jnp.asarray(g), jnp.asarray(h),
-        jnp.asarray(m), num_leaves=L,
-        num_features=ds.num_features, num_bins=ds.max_num_bin,
-    )
-    want = histogram_by_leaf(
-        jnp.asarray(ds.dense_bins().T), jnp.asarray(leaf_id),
-        jnp.asarray(g), jnp.asarray(h), jnp.asarray(m),
-        num_bins=ds.max_num_bin, num_leaves=L,
-    )
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-4)
-
-
-def test_sparse_hist_auto_selected_and_trains():
-    """Depthwise growth on a low-density sparse dataset auto-selects the
-    O(nnz) histogram and matches dense-path training."""
-    scipy_sparse = pytest.importorskip("scipy.sparse")
-    import lightgbm_tpu as lgb
     from lightgbm_tpu.models.gbdt import GBDT
-    from lightgbm_tpu.io.metadata import Metadata as MD
     from lightgbm_tpu.objectives import create_objective
 
-    dense, _, _, _ = _random_csr(600, 40, 0.03, seed=11)
-    y = (dense @ np.arange(40) > 0).astype(np.float32)
+    dense, _, _, _ = _random_csr(512, 24, 0.1, seed=13)
+    y = (dense @ np.arange(24) > 0).astype(np.float32)
     cfg = Config(objective="binary", num_leaves=7, max_bin=16,
-                 min_data_in_leaf=5, tree_growth="depthwise")
-    ds_sp = BinnedDataset.from_csr(
-        *_csr_parts(dense), MD(label=y), config=cfg)
-    assert ds_sp.is_sparse
-    gb = GBDT(cfg, ds_sp, create_objective(cfg, ds_sp.metadata,
-                                           ds_sp.num_data))
-    # the sparse O(nnz) histogram closure must be selected
-    from lightgbm_tpu.ops import sparse_hist  # noqa: F401
-    fn = gb._depthwise_hist_fn()
-    assert fn is not None and fn.__qualname__.startswith(
-        "make_sparse_hist_fn")
-    for _ in range(3):
-        gb.train_one_iter()
-    # dense-path model on the same data must match predictions
-    ds_d = BinnedDataset.from_matrix(dense, MD(label=y), config=cfg)
-    gb2 = GBDT(cfg, ds_d, create_objective(cfg, ds_d.metadata,
-                                           ds_d.num_data))
-    for _ in range(3):
-        gb2.train_one_iter()
-    np.testing.assert_allclose(
-        gb.predict(dense), gb2.predict(dense), rtol=1e-5, atol=1e-6)
+                 min_data_in_leaf=5, tree_learner=learner, top_k=4)
+    ds_csr = BinnedDataset.from_csr(*_csr_parts(dense), Metadata(label=y),
+                                    config=cfg)
+    assert ds_csr.is_sparse
+    trees = []
+    for ds in (ds_csr, BinnedDataset.from_matrix(dense, Metadata(label=y),
+                                                 config=cfg)):
+        gb = GBDT(cfg, ds, create_objective(cfg, ds.metadata, ds.num_data))
+        for _ in range(3):
+            gb.train_one_iter()
+        trees.append(jax.device_get(gb.models))
+    assert [t.num_leaves > 1 for t in trees[0]] == [True] * 3
+    for a, b in zip(*trees):
+        for field, x, z in zip(a._fields, a, b):
+            np.testing.assert_array_equal(x, z, err_msg=field)
 
 
 def _csr_parts(dense):
@@ -288,53 +242,3 @@ def _csr_parts(dense):
     row_lens = np.bincount(rows, minlength=n)
     indptr = np.concatenate([[0], np.cumsum(row_lens)]).astype(np.int64)
     return indptr, cols.astype(np.int64), dense[rows, cols], dense.shape[1]
-
-
-def test_sparse_histogram_default_bin_error_at_scale():
-    """ADVICE r4: the absent-entry (default-bin) mass is reconstructed
-    as leaf_tot - stored_sums in f32 — a difference of two large sums.
-    Pin the RELATIVE error of the default-bin entries at a bench-like
-    row count (500k rows, 2 leaves → ~250k-row sums) against a float64
-    oracle: the error must stay within the f32 accumulation bound of
-    ~sqrt(n_leaf)*eps ≈ 2e-5 relative (measured ~5e-6; same error class
-    as the reference's own sibling subtraction,
-    feature_histogram.hpp:97-106)."""
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.ops.sparse_hist import (
-        entry_rows, sparse_histogram_by_leaf)
-
-    n, f, B, L = 500_000, 4, 16, 2
-    rng = np.random.RandomState(11)
-    # ~1% density CSR, entries biased positive so sums are large (worst
-    # case for cancellation is |remainder| << |leaf_tot|)
-    nnz_per_row = rng.binomial(f, 0.01, n)
-    indptr = np.zeros(n + 1, np.int64)
-    np.cumsum(nnz_per_row, out=indptr[1:])
-    nnz = int(indptr[-1])
-    cols = rng.randint(0, f, nnz).astype(np.int32)
-    bins = rng.randint(1, B, nnz).astype(np.uint8)
-    leaf_id = rng.randint(0, L, n).astype(np.int32)
-    g = (rng.rand(n) + 0.5).astype(np.float32)  # all-positive: big sums
-    h = (rng.rand(n) + 0.5).astype(np.float32)
-    m = np.ones(n, np.float32)
-
-    erow = entry_rows(indptr)
-    default_bins = np.zeros(f, np.int32)
-    got = np.asarray(sparse_histogram_by_leaf(
-        jnp.asarray(erow), jnp.asarray(cols), jnp.asarray(bins),
-        jnp.asarray(default_bins), jnp.asarray(leaf_id), jnp.asarray(g),
-        jnp.asarray(h), jnp.asarray(m), num_leaves=L, num_features=f,
-        num_bins=B,
-    ))
-
-    # float64 oracle for the default-bin mass
-    for lf in range(L):
-        sel = leaf_id == lf
-        tot_g = np.sum(g[sel], dtype=np.float64)
-        for ff in range(f):
-            e_sel = (leaf_id[erow] == lf) & (cols == ff)
-            stored_g = np.sum(g[erow][e_sel], dtype=np.float64)
-            want = tot_g - stored_g
-            rel = abs(got[lf, ff, 0, 0] - want) / max(abs(want), 1.0)
-            assert rel < 2e-5, (lf, ff, got[lf, ff, 0, 0], want, rel)

@@ -93,7 +93,6 @@ def run_ours(Xtr, ytr, Xva, yva) -> dict:
     cfg = Config(
         objective="binary", num_leaves=NUM_LEAVES, max_bin=NUM_BINS,
         learning_rate=0.1, min_data_in_leaf=100, metric=["auc"],
-        tree_growth="leafwise",
     )
     t_wall0 = time.perf_counter()
     t0 = time.perf_counter()
