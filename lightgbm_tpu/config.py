@@ -171,6 +171,10 @@ class Config:
     ignore_column: str = ""
     categorical_column: str = ""
     bin_construct_sample_cnt: int = 50000
+    # a numerical column whose bin sample holds a NaN gets a missing bin
+    # and every split on it a learned default direction (io/binner.py);
+    # false reads every NaN as 0.0
+    use_missing: bool = True
     is_pre_partition: bool = False
     is_enable_sparse: bool = True
     # when false, ignore an existing <data>.bin cache (config.h:107)

@@ -310,6 +310,13 @@ class BinnedDataset:
         return int(self.num_bins_per_feature.max()) if self.num_features else 1
 
     @property
+    def missing_bins(self) -> np.ndarray:
+        """[F] int32: each feature's missing bin (its last), -1 where it
+        has none (io/binner.py)."""
+        return np.array([m.missing_bin for m in self.bin_mappers],
+                        dtype=np.int32)
+
+    @property
     def is_categorical(self) -> np.ndarray:
         return np.array(
             [m.bin_type == CATEGORICAL for m in self.bin_mappers], dtype=bool
@@ -352,7 +359,7 @@ class BinnedDataset:
                 mappers_all = find_bin_mappers(
                     X[sample_idx],
                     total_sample_cnt=sample_rows,
-                    max_bin=config.max_bin,
+                    max_bin=config.max_bin, use_missing=config.use_missing,
                     categorical_features=categorical_features,
                 )
         if len(mappers_all) != f_total:
@@ -421,7 +428,7 @@ class BinnedDataset:
             sample_rows = len(sample_idx)
             mappers_all = find_bin_mappers_csr(
                 indptr, indices, values, num_cols, sample_idx,
-                max_bin=config.max_bin,
+                max_bin=config.max_bin, use_missing=config.use_missing,
                 categorical_features=categorical_features,
             )
         used_map = np.full(num_cols, -1, dtype=np.int64)
@@ -644,13 +651,15 @@ class BinnedDataset:
             if jax.process_count() > 1:
                 mappers_all = distributed_find_bin_mappers(
                     X[sample_idx], rank, config.num_machines,
-                    max_bin=config.max_bin, categorical_features=cat_inner,
+                    max_bin=config.max_bin, use_missing=config.use_missing,
+                    categorical_features=cat_inner,
                     total_sample_cnt=len(sample_idx),
                 )
             else:
                 mappers_all = find_bin_mappers(
                     X[sample_idx], total_sample_cnt=len(sample_idx),
-                    max_bin=config.max_bin, categorical_features=cat_inner,
+                    max_bin=config.max_bin, use_missing=config.use_missing,
+                    categorical_features=cat_inner,
                 )
             X = X[keep]
             meta = meta.subset(keep)
@@ -730,7 +739,7 @@ class BinnedDataset:
             mappers_all = find_bin_mappers(
                 sample_raw,
                 total_sample_cnt=len(sample_idx),
-                max_bin=config.max_bin,
+                max_bin=config.max_bin, use_missing=config.use_missing,
                 categorical_features=cat_inner,
             )
             used_map = np.full(len(feat_cols), -1, dtype=np.int64)
@@ -890,7 +899,8 @@ class BinnedDataset:
             sample_idx = _sample_row_indices(n, config)
             mappers_all = find_bin_mappers_csr(
                 indptr, indices, values, num_cols, sample_idx,
-                max_bin=config.max_bin, categorical_features=cats,
+                max_bin=config.max_bin, use_missing=config.use_missing,
+                categorical_features=cats,
             )
             keep_rows = np.asarray(keep_rows)
             starts = indptr[keep_rows]

@@ -98,6 +98,7 @@ def distributed_find_bin_mappers(
     categorical_features: Sequence[int] = (),
     total_sample_cnt: Optional[int] = None,
     gather_fn: Optional[GatherFn] = None,
+    use_missing: bool = True,
 ) -> List[BinMapper]:
     """Feature-sharded bin finding + mapper allgather
     (dataset_loader.cpp:692-755).
@@ -117,6 +118,7 @@ def distributed_find_bin_mappers(
         total_sample_cnt=total_sample_cnt or len(sample_local),
         max_bin=max_bin,
         categorical_features=[i for i, j in enumerate(mine) if int(j) in cats],
+        use_missing=use_missing,
     )
     payload = json.dumps(
         {"rank": rank, "mappers": [m.to_dict() for m in local]}

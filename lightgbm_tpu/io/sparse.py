@@ -183,6 +183,7 @@ def find_bin_mappers_csr(
     sample_idx: np.ndarray,
     max_bin: int = 256,
     categorical_features: Sequence[int] = (),
+    use_missing: bool = True,
 ) -> List[BinMapper]:
     """Per-column BinMappers from a sampled row subset of a CSR matrix.
 
@@ -211,8 +212,8 @@ def find_bin_mappers_csr(
     for k, j in enumerate(present):
         bt = CATEGORICAL if int(j) in cats else NUMERICAL
         mappers[int(j)] = BinMapper.find(
-            vals_s[bounds[k]:bounds[k + 1]], n_sample, max_bin, bt
-        )
+            vals_s[bounds[k]:bounds[k + 1]], n_sample, max_bin, bt,
+            use_missing)
     return mappers
 
 

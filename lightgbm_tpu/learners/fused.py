@@ -204,12 +204,18 @@ def grow_tree(
     num_bins: int,
     max_leaves: int,
     axis: str | None = None,  # the row axis of a data-parallel mesh
+    missing_bin: jax.Array | None = None,  # [F] int32, -1: none
 ) -> Tuple[Tree, jax.Array]:
     """Grow one tree; returns (tree, final leaf_id per row).  Under
-    ``axis`` the arrays are this chip's shard (the module's docstring)."""
+    ``axis`` the arrays are this chip's shard (the module's docstring).
+    ``missing_bin`` (a table with missing values, io/binner.py): every
+    split's search scores both sides for a column's missing rows and the
+    kernels route them by the node's default direction (ops/record.py
+    _tile_go); None traces none of it, the program of a table without."""
     # Python here runs once per TRACE: counts grow-program retraces
     telemetry.count("grow_traces")
     assert grad.dtype == jnp.float32, grad.dtype
+    missing = missing_bin is not None
     F, n = bins_T.shape
     L = max_leaves
     interpret = not on_tpu()
@@ -229,7 +235,8 @@ def grow_tree(
         Fp, _, Bp = hist0.shape
         Fc, NC = feature_chunk(Fp, Bp)
         meta = _pack_meta(
-            feature_mask, num_bins_per_feature, is_categorical, NC * Fc)
+            feature_mask, num_bins_per_feature, is_categorical, NC * Fc,
+            missing_bin)
         if axis is None:
             sum_g0, sum_h0, cnt0 = tables.root_sums(grad, hess, bag_mask)
             count0 = node_cnt = None
@@ -244,9 +251,13 @@ def grow_tree(
             sum_g0, sum_h0, cnt0,
             (params.max_depth <= 0) | (jnp.int32(0) < params.max_depth),
             feature_mask, num_bins_per_feature, is_categorical, params,
+            missing_bin=missing_bin,
         )
+        root_left = None
+        if missing:
+            root_best, root_left = root_best
         best_mat, pos_mat, tree_i, tree_f = tables.root_tables(
-            root_best, hist0.dtype, L, n, count0)
+            root_best, hist0.dtype, L, n, count0, root_left)
         state = _State(
             rec=build_record(
                 bins_T, grad, hess, bag_mask, round_up(n, block) + cap),
@@ -264,7 +275,7 @@ def grow_tree(
         new_leaf = step + 1
         c = tables.read_split_columns(
             state.best_mat, state.pos_mat, best_leaf, new_leaf,
-            is_categorical)
+            is_categorical, missing_bin)
         with phase_scope("grow.select"):
             # depth gate + per-split scalars for the in-kernel search
             can = (params.max_depth <= 0) | (
@@ -287,17 +298,19 @@ def grow_tree(
                 c.f, c.thr, c.is_cat, best_leaf, new_leaf,
                 scal_f, meta, F=F, cap=cap, k=k, fgroup=FGROUP,
                 interpret=interpret, live_tiles=live_tiles,
+                missing=missing, miss_left=c.miss_left,
             )
         else:
             h_small, comp, nleft, cl, cr, rec_pass, _ = split_hist_counted(
                 state.rec, c.begin, c.pcnt, do_split, c.f, c.thr,
                 c.is_cat, scal_f, F=F, cap=cap, k=k, Fp=Fp, Bp=Bp,
                 fgroup=FGROUP, interpret=interpret, live_tiles=live_tiles,
+                missing=missing, miss_left=c.miss_left,
             )
             h_small, small = exchange(h_small, axis)
             hists, res = split_search(
                 state.hists, h_small, best_leaf, new_leaf, do_split,
-                scal_f, meta, interpret=interpret)
+                scal_f, meta, interpret=interpret, missing=missing)
         rec = place_runs(
             rec_pass, comp, (cl, cr), c.begin, c.pcnt, nleft, do_split,
             best_leaf, new_leaf, cap=cap, leaf_row=num_words(F, k) + 4,
@@ -324,6 +337,7 @@ def grow_tree(
             step, best_leaf, new_leaf, do_split,
             res[0, :11].astype(dt), res[1, :11].astype(dt),
             nleft, nright, left_rows, right_rows,
+            *((res[0, 11], res[1, 11]) if missing else ()),
         )
         return _State(
             rec=rec, pos_mat=pos_mat, hists=hists, best_mat=best_mat,

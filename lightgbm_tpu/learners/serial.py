@@ -182,16 +182,22 @@ def _tier_chain(caps, gate_cnt, branch_fn):
     return fn(None)
 
 
-def _go_i32(fv, thr, is_cat):
+def _go_i32(fv, thr, is_cat, missing=False, miss_left=None):
     """Left-going decision as i32 WITHOUT a bool intermediate: [cap]-ish
     pred tensors bounce between bit layouts on this stack (round-3
-    measured ~80-100 ms/tree of pure copies at 1M rows)."""
+    measured ~80-100 ms/tree of pure copies at 1M rows).  With
+    ``missing`` (static: the table has missing values), ``miss_left`` is
+    the bin that goes left besides ``fv <= thr`` (the column's missing
+    bin where the node sends missing rows left, else -1)."""
     isc = is_cat.astype(jnp.int32)
-    return isc * (fv == thr).astype(jnp.int32) + (1 - isc) * (
-        fv <= thr).astype(jnp.int32)
+    num = (fv <= thr).astype(jnp.int32)
+    if missing:
+        num = jnp.maximum(num, (fv == miss_left).astype(jnp.int32))
+    return isc * (fv == thr).astype(jnp.int32) + (1 - isc) * num
 
 
-def _partition_branch(order, bins_T, f, thr, is_cat, begin, pcnt, do_split, cap):
+def _partition_branch(order, bins_T, f, thr, is_cat, begin, pcnt, do_split,
+                      cap, missing=False, miss_left=None):
     """Stably partition the parent's [begin, begin+pcnt) range of
     ``order`` by the split decision (DataPartition::Split,
     data_partition.hpp:91-139): left-going rows keep their relative
@@ -204,7 +210,10 @@ def _partition_branch(order, bins_T, f, thr, is_cat, begin, pcnt, do_split, cap)
     rows_c = jnp.minimum(rows_p, n - 1)
     frow = jax.lax.dynamic_index_in_dim(bins_T, f, axis=0, keepdims=False)
     vals = frow[rows_c].astype(jnp.int32)
-    go = jnp.where(is_cat, vals == thr, vals <= thr) & validp
+    num = vals <= thr
+    if missing:
+        num = num | (vals == miss_left)
+    go = jnp.where(is_cat, vals == thr, num) & validp
     # dtype pinned: under jax_enable_x64 (hist_dtype=float64) a plain sum
     # promotes to int64 and the int32 leaf_begin/pos_cnt scatters become
     # unsafe casts
@@ -242,10 +251,12 @@ def _child_hist_branch(hist_fn, order, bins_T, grad, hess, bag_mask,
 def default_search_fn(
     hist, sum_grad, sum_hess, count, can_split,
     feature_mask, num_bins_per_feature, is_categorical, params,
+    missing_bin=None,
 ):
     """Local split search over the full feature set (the serial learner's
     FindBestThresholds).  Parallel learners substitute variants that search
-    a feature shard and combine across the mesh."""
+    a feature shard and combine across the mesh.  With ``missing_bin``
+    the result is ``(SplitResult, default_left)`` (ops/split.py)."""
     return find_best_split(
         hist,
         sum_grad,
@@ -260,6 +271,7 @@ def default_search_fn(
         params.lambda_l2,
         params.min_gain_to_split,
         can_split,
+        missing_bin,
     )
 
 
@@ -291,6 +303,7 @@ def grow_tree(
     hist_pool: int = 0,
     record_mode: bool = False,
     choice_by_mask_counts: bool = False,
+    missing_bin=None,
 ) -> Tuple[Tree, jax.Array]:
     """Grow one tree; returns (tree, final leaf_id per row).
 
@@ -336,12 +349,19 @@ def grow_tree(
 
     ``choice_by_mask_counts``: base-row-mask mode (cv bin-once,
     gbdt.set_base_row_mask), see the split step below.
+
+    ``missing_bin`` [F] int32 (-1: none), a table with missing values
+    (io/binner.py): the default searches score both sides for a column's
+    missing rows and the partition routes them by the node's default
+    direction (the parallel learners' own searches take none of it:
+    ``GBDT._refuse_missing``).
     """
     # Python here runs once per TRACE, so this counts grow-program
     # retraces exactly (obs: a timed loop whose grow_traces counter
     # moves is re-tracing — the same hazard the bench warm-up gate and
     # the steady-loop recompile test watch from the compile side)
     telemetry.count("grow_traces")
+    missing = missing_bin is not None
     F, n = bins_T.shape
     L = max_leaves
     # the partition's capacities are the histogram's (the root split
@@ -354,7 +374,8 @@ def grow_tree(
         hist_fn = functools.partial(histogram_feature_major, num_bins=num_bins)
     rec = record_mode and grad.dtype == jnp.float32 and not pooled
     if search_fn is None:
-        search_fn = default_search_fn
+        search_fn = functools.partial(default_search_fn,
+                                      missing_bin=missing_bin)
         if search2_fn is None:
             use_kernel = on_tpu()
 
@@ -376,6 +397,7 @@ def grow_tree(
                         prm.min_sum_hessian_in_leaf,
                         prm.lambda_l1, prm.lambda_l2,
                         prm.min_gain_to_split,
+                        missing_bin,
                     )
                 res = find_best_split_leaves(
                     jnp.stack([hl, hr]),
@@ -386,11 +408,17 @@ def grow_tree(
                     prm.min_data_in_leaf, prm.min_sum_hessian_in_leaf,
                     prm.lambda_l1, prm.lambda_l2, prm.min_gain_to_split,
                     jnp.stack([can, can]),
+                    missing_bin,
                 )
-                return (
-                    SplitResult(*[a[0] for a in res]),
-                    SplitResult(*[a[1] for a in res]),
-                )
+                if not missing:
+                    return (
+                        SplitResult(*[a[0] for a in res]),
+                        SplitResult(*[a[1] for a in res]),
+                    )
+                (res, dl) = res
+                return tuple(
+                    (SplitResult(*[a[i] for a in res]), dl[i])
+                    for i in range(2))
     if rec:
         # record mode: the loop state carries the leaf-sorted PACKED
         # RECORD [W, n_pad] (ops/record.py) instead of the row
@@ -458,8 +486,11 @@ def grow_tree(
         # (include/LightGBM/bin.h:21-22)
         acc_dt = hist0.dtype
         root_best = best_for(hist0, sum_g0, sum_h0, cnt0, jnp.int32(0))
+        root_left = None
+        if missing:
+            root_best, root_left = root_best
         best_mat0, pos_mat0, tree_i0, tree_f0 = tables.root_tables(
-            root_best, acc_dt, L, n)
+            root_best, acc_dt, L, n, default_left=root_left)
         state = _GrowState(
             order=build_record(
                 bins_T, grad, hess, bag_mask,
@@ -498,7 +529,7 @@ def grow_tree(
         new_leaf = step + 1
         c = tables.read_split_columns(
             state.best_mat, state.pos_mat, best_leaf, new_leaf,
-            is_categorical)
+            is_categorical, missing_bin)
         f, thr, is_cat = c.f, c.thr, c.is_cat
         lsg, lsh, lc, rsg, rsh, rc = c.lsg, c.lsh, c.lc, c.rsg, c.rsh, c.rc
         begin, pcnt, gate = c.begin, c.pcnt, c.gate
@@ -509,7 +540,7 @@ def grow_tree(
 
             def _part_rec(cap):
                 fv = extract_feature(state.order, f, begin, cap, k_pack)
-                go = _go_i32(fv, thr, is_cat)
+                go = _go_i32(fv, thr, is_cat, missing, c.miss_left)
                 return partition_window(
                     state.order, go, begin, pcnt, do_split, cap,
                     left_leaf=best_leaf, right_leaf=new_leaf,
@@ -525,7 +556,7 @@ def grow_tree(
                     gate,
                     lambda cap: _partition_branch(
                         state.order, bins_T, f, thr, is_cat, begin, pcnt,
-                        do_split, cap
+                        do_split, cap, missing, c.miss_left
                     ),
                 )
         nright = pcnt - nleft
@@ -636,6 +667,10 @@ def grow_tree(
         best_l_new, best_r_new = best2_for(
             h_left, h_right, lsg, lsh, lc, rsg, rsh, rc, c.depth_child
         )
+        lefts = ()
+        if missing:
+            (best_l_new, dl_l), (best_r_new, dl_r) = best_l_new, best_r_new
+            lefts = (dl_l, dl_r)
 
         # ---- in-place buffer update.  Everything derived from reads
         # of state.hists (the stacked new rows and the child
@@ -697,7 +732,7 @@ def grow_tree(
             state.best_mat, state.pos_mat, state.tree_i, state.tree_f, c,
             step, best_leaf, new_leaf, do_split,
             _sr_row(best_l_new, dt), _sr_row(best_r_new, dt),
-            nleft, nright, nleft_gate, nright_gate,
+            nleft, nright, nleft_gate, nright_gate, *lefts,
         )
         return _GrowState(
             order=order,

@@ -7,8 +7,10 @@ arrays (half the device time at 100k rows / 63 leaves was per-op launch
 gaps from the unpacked representation's ~100 tiny ops a split):
 
     best_mat [16, L] acc_dt : a leaf's best split (rows 0-10, the Pallas
-                              search kernels' result row) and the leaf
-                              half of the Tree (rows 11-14)
+                              search kernels' result row), the leaf
+                              half of the Tree (rows 11-14) and, where
+                              the table has missing values, whether the
+                              best split sends them left (row 15)
     pos_mat  [3, L]  i32    : leaf_begin, pos_cnt, gate_cnt
     tree_i   [5, L]  i32    : node table: feat, thr, dtype, lch, rch
     tree_f   [3, L]  f32    : node table: gain, int_value, int_count
@@ -41,7 +43,11 @@ _BLSG, _BLSH, _BLC = 3, 4, 5
 _BRSG, _BRSH, _BRC = 6, 7, 8
 _BLO, _BRO = 9, 10
 _BLV, _BLCNT, _BLPAR, _BLDEP = 11, 12, 13, 14
+_BDL = 15
 _BROWS = 16
+# decision_type bits (LightGBM 2.x): categorical, default left, and the
+# missing type NaN in bits 2-3
+DT_CATEGORICAL, DT_DEFAULT_LEFT, DT_MISSING_NAN = 1, 2, 8
 
 
 def _sr_row(sr: SplitResult, dt):
@@ -75,12 +81,13 @@ def root_sums(grad, hess, bag_mask):
 
 
 def root_tables(root_best: SplitResult, acc_dt, L: int, n: int,
-                gate=None):
+                gate=None, default_left=None):
     """(best_mat, pos_mat, tree_i, tree_f) of a tree that is its root:
     leaf 0 holds every row and ``root_best``.  ``gate`` is the root's
     entry in pos_mat's third row, ``n`` where None (the data-parallel
     fused grower keeps there a leaf's bagged row count over every chip,
-    in int32: learners/fused.py)."""
+    in int32: learners/fused.py).  ``default_left``: the root split's
+    side for missing rows, where the table has them."""
     best_mat = (
         jnp.zeros((_BROWS, L), acc_dt)
         .at[_BG].set(K_MIN_SCORE)
@@ -89,6 +96,8 @@ def root_tables(root_best: SplitResult, acc_dt, L: int, n: int,
     )
     best_mat = jax.lax.dynamic_update_slice(
         best_mat, _sr_row(root_best, acc_dt)[:, None], (0, 0))
+    if default_left is not None:
+        best_mat = best_mat.at[_BDL, 0].set(default_left.astype(acc_dt))
     # root gate: every shard's padded local row count is the same n
     pos_mat = jnp.zeros((3, L), jnp.int32).at[1, 0].set(n).at[2, 0].set(
         n if gate is None else gate)
@@ -127,13 +136,19 @@ class SplitColumns(NamedTuple):
     begin: jax.Array
     pcnt: jax.Array
     gate: jax.Array
+    # where the table has missing values: the bin that goes left besides
+    # ``bin <= thr`` (the split column's missing bin if the node sends
+    # missing rows left, else -1), and the node's decision_type
+    miss_left: jax.Array | None = None
+    decision: jax.Array | None = None
 
 
 def read_split_columns(best_mat, pos_mat, best_leaf, new_leaf,
-                       is_categorical) -> SplitColumns:
+                       is_categorical, missing_bin=None) -> SplitColumns:
     """ALL per-leaf scalar reads of a split come from four column slices
     (parent + prospective-new-leaf columns of the two packed matrices)
-    instead of ~40 individual [L]-array gathers."""
+    instead of ~40 individual [L]-array gathers.  ``missing_bin`` [F]
+    (-1: none), where the table has missing values."""
     with phase_scope("grow.select"):
         z0 = jnp.int32(0)
         bcol = jax.lax.dynamic_slice(
@@ -144,7 +159,7 @@ def read_split_columns(best_mat, pos_mat, best_leaf, new_leaf,
         pcolN = jax.lax.dynamic_slice(pos_mat, (z0, new_leaf), (3, 1))[:, 0]
 
         f = bcol[_BF].astype(jnp.int32)
-        return SplitColumns(
+        c = SplitColumns(
             bcol=bcol, bcolN=bcolN, pcol=pcol, pcolN=pcolN,
             f=f,
             thr=bcol[_BT].astype(jnp.int32),
@@ -156,11 +171,21 @@ def read_split_columns(best_mat, pos_mat, best_leaf, new_leaf,
             # count) was stored at the split that CREATED this leaf
             begin=pcol[0], pcnt=pcol[1], gate=pcol[2],
         )
+        if missing_bin is None:
+            return c
+        mb = missing_bin[jnp.maximum(f, 0)]
+        dl = (bcol[_BDL] > 0) & (mb >= 0)
+        return c._replace(
+            miss_left=jnp.where(dl, mb, -1),
+            decision=(c.is_cat.astype(jnp.int32)
+                      + jnp.where(mb >= 0, DT_MISSING_NAN, 0)
+                      + jnp.where(dl, DT_DEFAULT_LEFT, 0)))
 
 
 def write_split(best_mat, pos_mat, tree_i, tree_f, c: SplitColumns,
                 node, best_leaf, new_leaf, do_split, rowL, rowR,
-                nleft, nright, nleft_gate, nright_gate):
+                nleft, nright, nleft_gate, nright_gate,
+                default_left_L=None, default_left_R=None):
     """The packed column updates of one split, every store MASKED on
     ``do_split`` (an exhausted tree round-trips its tables unchanged;
     a ``lax.cond`` with an identity branch made XLA copy the carried
@@ -168,15 +193,19 @@ def write_split(best_mat, pos_mat, tree_i, tree_f, c: SplitColumns,
     the tree ride best_mat (two column writes); partition ranges ride
     pos_mat (two column writes); the node half of the tree rides
     tree_i/tree_f (three column read-modify-writes).  ``rowL``/``rowR``
-    are the children's best splits in the kernel-result row layout."""
+    are the children's best splits in the kernel-result row layout, and
+    ``default_left_L``/``_R`` their sides for missing rows where the
+    table has them (best_mat row 15)."""
     z0 = jnp.int32(0)
     bcol = c.bcol
     dt = bcol.dtype
     node_f = node.astype(dt)
     dep_f = c.depth_child.astype(dt)
     zero = jnp.zeros((), dt)
-    tailL = jnp.stack([bcol[_BLO], c.lc, node_f, dep_f, zero])
-    tailR = jnp.stack([bcol[_BRO], c.rc, node_f, dep_f, zero])
+    dlL = zero if default_left_L is None else default_left_L.astype(dt)
+    dlR = zero if default_left_R is None else default_left_R.astype(dt)
+    tailL = jnp.stack([bcol[_BLO], c.lc, node_f, dep_f, dlL])
+    tailR = jnp.stack([bcol[_BRO], c.rc, node_f, dep_f, dlR])
     colL = jnp.where(do_split, jnp.concatenate([rowL, tailL]), bcol)
     colR = jnp.where(do_split, jnp.concatenate([rowR, tailR]), c.bcolN)
     best_mat = jax.lax.dynamic_update_slice(
@@ -213,7 +242,9 @@ def write_split(best_mat, pos_mat, tree_i, tree_f, c: SplitColumns,
     colNd = jnp.where(
         do_split,
         jnp.stack(
-            [c.f, c.thr, c.is_cat.astype(jnp.int32), ~best_leaf, ~new_leaf]),
+            [c.f, c.thr,
+             c.is_cat.astype(jnp.int32) if c.decision is None
+             else c.decision, ~best_leaf, ~new_leaf]),
         colNd,
     )
     tree_i = jax.lax.dynamic_update_slice(tree_i, colNd[:, None], (z0, node))

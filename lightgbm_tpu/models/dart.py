@@ -64,7 +64,7 @@ class DART(GBDT):
             for c in range(K):
                 tree = self.models[i * K + c]
                 self._scores = self._scores.at[c].add(
-                    -predict_binned(tree, self._bins_T.T)
+                    -predict_binned(tree, self._bins_T.T, self._missing_bin)
                 )
 
         # shrinkage for the new tree (dart.hpp:124-132)
@@ -93,13 +93,15 @@ class DART(GBDT):
             for c in range(K):
                 idx = i * K + c
                 tree = self.models[idx]
-                delta = predict_binned(tree, self._bins_T.T)
+                delta = predict_binned(tree, self._bins_T.T,
+                                       self._missing_bin)
                 # train score gets the renormalized tree back
                 self._scores = self._scores.at[c].add(keep * delta)
                 # valid scores still hold the full tree; adjust by (keep-1)
                 for vi in range(len(self.valid_sets)):
                     self._valid_scores[vi] = self._valid_scores[vi].at[c].add(
-                        (keep - 1.0) * predict_binned(tree, self._valid_bins[vi])
+                        (keep - 1.0) * predict_binned(
+                            tree, self._valid_bins[vi], self._missing_bin)
                     )
                 self.models[idx] = tree.shrink(keep)
             if not cfg.uniform_drop and self.tree_weight:

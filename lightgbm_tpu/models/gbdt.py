@@ -165,6 +165,7 @@ class GBDT:
         else:
             self._nf_guard = None
         self._model_version = 0
+        self._missing_bin, self._missing_features = None, 0
         if train_set is not None:
             self.reset_training_data(train_set, objective)
 
@@ -188,6 +189,12 @@ class GBDT:
         # mesh.  None = everything on the default device (serial).
         self._bins_sharding = self._row_sharding = None
         self._learner_devices = 1
+        # each feature's missing bin (io/binner.py), on the device where
+        # any feature has one: the growers and the binned walks take it,
+        # and None keeps every program of a table without
+        mb = train_set.missing_bins
+        self._missing_features = int((mb >= 0).sum())
+        self._missing_bin = jnp.asarray(mb) if self._missing_features else None
         with telemetry.span("lgbm.setup.booster.learner"):
             self._learner_params = TreeLearnerParams.from_config(self.config)
             self._grower = self.select_grower()
@@ -343,6 +350,7 @@ class GBDT:
             "grow.onehot_planes": plan.onehot_planes,
             "grow.categorical_features": int(
                 self.train_set.is_categorical.sum()),
+            "grow.missing_features": self._missing_features,
         })
 
     def _serial_leafwise_grower(self):
@@ -356,6 +364,7 @@ class GBDT:
                 fused.grow_tree,
                 num_bins=self._num_bins,
                 max_leaves=self.max_leaves,
+                **self._missing_kw(),
             )
         return functools.partial(
             grow_tree,
@@ -363,7 +372,26 @@ class GBDT:
             max_leaves=self.max_leaves,
             hist_fn=self._leafwise_hist_fn(),
             hist_pool=self._hist_pool_slots(),
+            **self._missing_kw(),
         )
+
+    def _missing_kw(self) -> dict:
+        """The growers' ``missing_bin`` where the table has missing
+        values, else nothing: a table without compiles the program it
+        always did."""
+        return {} if self._missing_bin is None else {
+            "missing_bin": self._missing_bin}
+
+    def _refuse_missing(self, learner: str) -> None:
+        """A learner that cannot route a missing bin says so; it never
+        reads the NaN as 0.0 behind the user's back."""
+        if self._missing_bin is not None:
+            raise ValueError(
+                f"{self._missing_features} features have a missing bin "
+                f"and {learner} cannot route missing values (only the "
+                "serial learners and tree_learner=data on the fused "
+                "grower can): train with use_missing=false, or with "
+                "tree_learner=serial")
 
     def _create_tree_learner(self):
         """TreeLearner::CreateTreeLearner (tree_learner.cpp:8-20): map
@@ -387,6 +415,7 @@ class GBDT:
                     f"tree_learner={tl} runs data-parallel across "
                     "processes (feature/voting sharding stays intra-host)"
                 )
+            self._refuse_missing("the multi-process data-parallel learner")
             from ..resilience.retry import collective_deadline_s
 
             self._learner_devices = jax.device_count()
@@ -413,6 +442,8 @@ class GBDT:
 
         nd = self._mesh_devices()
         mesh = data_mesh(num_devices=nd)
+        if self._grower[0] != "fused":
+            self._refuse_missing(f"tree_learner={tl} on the canonical grower")
         if tl == "feature":
             # every device holds all rows and searches a feature shard
             self._set_placement(mesh, None, 1)
@@ -434,7 +465,8 @@ class GBDT:
                 "dp.collectives_per_split": 1,
             })
             return make_fused_data_parallel_grower(
-                mesh, num_bins=self._num_bins, max_leaves=self.max_leaves)
+                mesh, num_bins=self._num_bins, max_leaves=self.max_leaves,
+                **self._missing_kw())
         if tl == "voting":
             return make_voting_parallel_grower(
                 mesh,
@@ -534,7 +566,9 @@ class GBDT:
                f"score update by {leaf_lookup_path(self.max_leaves)} over "
                f"{self.max_leaves} leaves, "
                f"{int(self.train_set.is_categorical.sum())} of "
-               f"{self.train_set.num_features} features categorical"
+               f"{self.train_set.num_features} features categorical, "
+               f"{self._missing_features} of {self.train_set.num_features} "
+               "features with a missing bin"
                + (", pallas kernels interpreted" if not on_tpu()
                   and ("pallas" in hist or "record" in part) else ""))
         if msg not in _LOGGED_PATHS:
@@ -774,6 +808,9 @@ class GBDT:
             return False
         if self._use_f64_hist or self._hist_pool_slots():
             return False
+        if self._missing_bin is not None:
+            # learners/forest.py has no missing-value routing
+            return False
         if (self._grower[0] == "fused"
                 or self._leafwise_hist_fn() is not None):
             return False
@@ -916,7 +953,8 @@ class GBDT:
             )
             for vi in range(len(self.valid_sets)):
                 self._valid_scores[vi] = self._valid_scores[vi].at[k].add(
-                    predict_binned(tree, self._valid_bins[vi])
+                    predict_binned(tree, self._valid_bins[vi],
+                                   self._missing_bin)
                 )
         with telemetry.span("lgbm.host.book"):
             self.models.append(tree)
@@ -1086,11 +1124,12 @@ class GBDT:
         self._pending_stop.clear()
         for k, tree in enumerate(last):
             # negative shrinkage = subtraction
-            delta = predict_binned(tree, self._bins_T.T)
+            delta = predict_binned(tree, self._bins_T.T, self._missing_bin)
             self._scores = self._scores.at[k].add(-delta)
             for vi in range(len(self.valid_sets)):
                 self._valid_scores[vi] = self._valid_scores[vi].at[k].add(
-                    -predict_binned(tree, self._valid_bins[vi])
+                    -predict_binned(tree, self._valid_bins[vi],
+                                    self._missing_bin)
                 )
         del self.models[-K:]
         self.iter_ -= 1
@@ -1455,7 +1494,7 @@ class GBDT:
                 continue
             sf_inner[i] = inner
             mapper = self.train_set.bin_mappers[inner]
-            if dt[i] == 1:  # categorical: threshold is the category id
+            if dt[i] & 1:  # categorical: threshold is the category id
                 tb[i] = mapper.category_to_bin.get(int(tr[i]), num_bins)
             else:
                 # threshold_real == bounds[threshold_bin]; recover the bin
@@ -1685,7 +1724,9 @@ def _tree_to_json(tree: Tree, index: int) -> Dict:
             "split_feature": int(sf[i]),
             "split_gain": float(sg[i]),
             "threshold": float(tr[i]),
-            "decision_type": "==" if dt[i] == 1 else "<=",
+            "decision_type": "==" if dt[i] & 1 else "<=",
+            "default_left": bool(dt[i] & 2),
+            "missing_type": "NaN" if dt[i] & 12 == 8 else "None",
             "internal_value": float(iv[i]),
             "internal_count": int(ic[i]),
             "left_child": built[li] if li >= 0 else leaf_node(~li),

@@ -25,8 +25,13 @@ Design notes:
   equal-gain maxima, across features the SMALLEST feature index
   (split_info.hpp:98-103 semantics).
 * Outputs are a [2, 16] f32 row pair (gain, feature, threshold, six
-  stats, two leaf outputs); the host-side wrapper casts feature and
+  stats, two leaf outputs, and with missing values the default
+  direction in lane 11); the host-side wrapper casts feature and
   threshold back to int32 and rebuilds the two SplitResults.
+* Missing values (``missing``, static): meta's fourth column holds each
+  feature's missing bin (-1: none) and every value threshold of such a
+  feature is scored twice, its missing rows right and left, under
+  ops/split.py's tie rule.  Without it nothing of this is traced.
 
 The jnp path in ops/split.py remains the reference implementation (and
 the CPU / float64 path); tests pin this kernel against it in interpret
@@ -77,15 +82,18 @@ def _head_of(x, tri):
     return _tail_of(x, 1.0 - tri)
 
 
-def _pack_meta(feature_mask, num_bins_per_feature, is_categorical, Fp):
+def _pack_meta(feature_mask, num_bins_per_feature, is_categorical, Fp,
+               missing_bin=None):
     """[F] feature metadata -> the kernels' [Fp, 4] i32 operand (padded
-    features get feature_mask 0 and never validate)."""
+    features get feature_mask 0 and never validate); the fourth column
+    is each feature's missing bin where ``missing_bin`` is given."""
     F = feature_mask.shape[0]
     meta = jnp.stack([
         feature_mask.astype(jnp.int32),
         num_bins_per_feature.astype(jnp.int32),
         is_categorical.astype(jnp.int32),
-        jnp.zeros(F, jnp.int32),
+        (jnp.zeros(F, jnp.int32) if missing_bin is None
+         else missing_bin.astype(jnp.int32)),
     ], axis=1)
     if Fp != F:
         meta = jnp.pad(meta, ((0, Fp - F), (0, 0)))
@@ -93,7 +101,7 @@ def _pack_meta(feature_mask, num_bins_per_feature, is_categorical, Fp):
 
 
 def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
-                  out_ref, F, B, f0, best_ref):
+                  out_ref, F, B, f0, best_ref, missing=False):
     """One child's full search given its stat planes [F, B], their
     exclusive suffix sums and the inclusive prefix sums of gradient and
     hessian (the count's prefix is ``cnt_t - tc``, exact either way);
@@ -115,6 +123,13 @@ def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
     from the SMEM-prefetched ``scal_ref`` (scalar splats broadcast
     freely; [1,1]->[F,B] tensor broadcasts do not on this stack), and
     scalar full-array reduces for the winner selection.
+
+    With ``missing`` each feature's missing bin (meta's fourth column)
+    gives a second set of candidates, the bin's sums moved left; ties
+    break by ops/split.py's rule (feature asc; missing-left first,
+    threshold desc; then missing-right, threshold ASC on a column with a
+    missing bin); lane 11 of the row says whether the winner sends them
+    left.
     """
     fmask = meta_ref[:, 0:1] > 0  # [F, 1]
     nb = meta_ref[:, 1:2]  # [F, 1]
@@ -150,24 +165,60 @@ def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
     right_c = jnp.where(iscat, cnt_t - hc, tc)
 
     gain_shift = leaf_gain(sg_t, sh_t)  # scalar
-    gains = leaf_gain(left_g, left_h) + leaf_gain(right_g, right_h)
-    valid = (
-        in_range
-        & (left_c >= min_data) & (right_c >= min_data)
-        & (left_h >= min_hess) & (right_h >= min_hess)
-        & (gains >= gain_shift + min_gain)
-        & can
-    )
-    score = jnp.where(valid, gains, NEG)  # [F, B]
+
+    def scored(left_g, left_h, left_c, right_g, right_h, right_c, allowed):
+        gains = leaf_gain(left_g, left_h) + leaf_gain(right_g, right_h)
+        valid = (
+            allowed
+            & (left_c >= min_data) & (right_c >= min_data)
+            & (left_h >= min_hess) & (right_h >= min_hess)
+            & (gains >= gain_shift + min_gain)
+            & can
+        )
+        return valid, jnp.where(valid, gains, NEG)  # [F, B]
+
+    sides = (left_g, left_h, left_c, right_g, right_h, right_c)
+    valid, score = scored(*sides, in_range)
 
     # deterministic winner: global max; largest t per feature among
     # maxima; smallest such feature
-    maxg = jnp.max(score)  # scalar
-    at_max = (score == maxg) & valid
-    tbest = jnp.max(jnp.where(at_max, bins, -1), axis=1,
-                    keepdims=True)  # [F, 1]
-    fbest = jnp.min(jnp.where(tbest >= 0, fi, BIG))  # scalar
-    thr = jnp.max(jnp.where(fi == fbest, tbest, -1))  # scalar
+    if not missing:
+        maxg = jnp.max(score)  # scalar
+        at_max = (score == maxg) & valid
+        tbest = jnp.max(jnp.where(at_max, bins, -1), axis=1,
+                        keepdims=True)  # [F, 1]
+        fbest = jnp.min(jnp.where(tbest >= 0, fi, BIG))  # scalar
+        thr = jnp.max(jnp.where(fi == fbest, tbest, -1))  # scalar
+    else:
+        # missing rows left: the missing bin's sums move from the right
+        # side to the left (ops/split.py); a right side keeps a value bin
+        mb = meta_ref[:, 3:4]  # [F, 1], -1: no missing bin
+        at_mb = bins == mb
+
+        def of_mb(x):
+            return jnp.sum(jnp.where(at_mb, x, 0.0), axis=1, keepdims=True)
+
+        mg, mh, mc = of_mb(hg), of_mb(hh), of_mb(hc)
+        sides_m = (pg + mg, ph + mh, cnt_t - (tc - mc), tg - mg, th - mh,
+                   tc - mc)
+        valid_m, score_m = scored(
+            *sides_m, (bins < nb - 2) & (mb >= 0) & fmask)
+        maxg = jnp.maximum(jnp.max(score_m), jnp.max(score))
+        tbest_m = jnp.max(jnp.where((score_m == maxg) & valid_m, bins, -1),
+                          axis=1, keepdims=True)
+        # missing rows right: the lowest tied threshold on a column with
+        # a missing bin (LightGBM's forward scan), the highest elsewhere
+        at_r = (score == maxg) & valid
+        hi_r = jnp.max(jnp.where(at_r, bins, -1), axis=1, keepdims=True)
+        lo_r = jnp.min(jnp.where(at_r, bins, BIG), axis=1, keepdims=True)
+        tbest_r = jnp.where((mb >= 0) & (hi_r >= 0), lo_r, hi_r)
+        fbest = jnp.min(jnp.where((tbest_m >= 0) | (tbest_r >= 0), fi, BIG))
+        at_f = fi == fbest
+        dleft = jnp.max(jnp.where(at_f & (tbest_m >= 0), 1, 0)) > 0
+        thr = jnp.max(jnp.where(at_f, jnp.where(dleft, tbest_m, tbest_r),
+                                -1))
+        sides = tuple(jnp.where(dleft, m, x) for m, x in zip(sides_m, sides))
+        left_g, left_h, left_c, right_g, right_h, right_c = sides
 
     sel = (fi == fbest) & (bins == thr)  # [F, B]
 
@@ -189,6 +240,8 @@ def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
         lg, lh, lc, rg, rh, rc,
         leaf_out(lg, lh), leaf_out(rg, rh),
     ]
+    if missing:
+        vals.append(jnp.where(ok & dleft, 1.0, 0.0))
     # assemble the [1, 16] row with lane selects (scalar splats are
     # the one broadcast form this Mosaic supports everywhere)
     row = jnp.zeros((1, 16), jnp.float32)
@@ -200,7 +253,7 @@ def _child_search(c, hg, hh, hc, tg, th, tc, pg, ph, scal_ref, meta_ref,
 
 
 def _search2_kernel(scal_ref, hist_ref, meta_ref, out_ref, best_ref,
-                    *, F, B):
+                    *, F, B, missing=False):
     """One grid step: both children end-to-end on one feature chunk of
     ``F`` features (the whole table where it is one chunk).
 
@@ -221,7 +274,7 @@ def _search2_kernel(scal_ref, hist_ref, meta_ref, out_ref, best_ref,
             _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
             _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
             scal_ref, meta_ref, out_ref, F, B, pl.program_id(0) * F,
-            best_ref,
+            best_ref, missing,
         )
 
 
@@ -233,12 +286,14 @@ def search2_pallas(
     feature_mask, num_bins_per_feature, is_categorical,  # [F]
     min_data_in_leaf, min_sum_hessian_in_leaf,
     lambda_l1, lambda_l2, min_gain_to_split,
+    missing_bin=None,  # [F] int32, -1: none (ops/split.py)
     interpret: bool = False,
 ):
     """Both children's best splits in one kernel launch; returns two
     scalar SplitResults matching ops/split.find_best_split bit-for-bit
     up to the suffix-sum accumulation order (MXU triangular dot vs
-    sequential cumsum — identical under exact arithmetic).
+    sequential cumsum — identical under exact arithmetic).  With
+    ``missing_bin`` each is a ``(SplitResult, default_left)`` pair.
 
     The table is searched a feature chunk a grid step
     (pallas_histogram.feature_chunk; one step for a table of one chunk),
@@ -246,6 +301,7 @@ def search2_pallas(
     is resident whatever the width."""
     from .pallas_histogram import feature_chunk
 
+    missing = missing_bin is not None
     if h_left.dtype != jnp.float32 or h_right.dtype != jnp.float32:
         # a silent astype here would hide precision loss from a future
         # float64 hist_dtype caller; the f64 parity mode must stay on
@@ -265,7 +321,8 @@ def search2_pallas(
     # whole chunks: padded features get feature_mask 0 (_pack_meta)
     hist = jnp.pad(hist, ((0, 0), (0, NC * Fc - F), (0, 0)))
     meta = _pack_meta(
-        feature_mask, num_bins_per_feature, is_categorical, NC * Fc)
+        feature_mask, num_bins_per_feature, is_categorical, NC * Fc,
+        missing_bin)
     scal = _pack_scal(
         jnp.asarray(can, jnp.float32), lsg, lsh, lc, rsg, rsh, rc,
         min_data_in_leaf, min_sum_hessian_in_leaf,
@@ -282,13 +339,15 @@ def search2_pallas(
         scratch_shapes=[pltpu.SMEM((2,), jnp.float32)],
     )
     out = pl.pallas_call(
-        functools.partial(_search2_kernel, F=Fc, B=B),
+        functools.partial(_search2_kernel, F=Fc, B=B, missing=missing),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((2, 16), jnp.float32),
         interpret=interpret,
     )(scal, hist, meta)
 
-    return _unpack(out, 0), _unpack(out, 1)
+    if not missing:
+        return _unpack(out, 0), _unpack(out, 1)
+    return tuple((_unpack(out, i), out[i, 11] > 0) for i in range(2))
 
 
 def _unpack(out, i):

@@ -30,13 +30,14 @@ indexed access at all:
 Leaf values follow as ``hit @ leaf_value`` and leaf indices as
 ``argmax(hit)`` — every step a large, static-shape, fusable dense op.
 
-NaN caveat: the walk routes NaN feature values right (NaN <= t is
-false).  A NaN would poison the selection matmul (0 * NaN = NaN), so
-X is sanitized NaN -> FLT_MAX first, which routes right everywhere a
-finite threshold is used.  (Sole divergence: a NaN in a CATEGORICAL
-feature walks as category 0 in the gather path — int cast of NaN —
-and as INT_MAX here; the reference snapshot predates missing-value
-handling entirely, so neither behavior is load-bearing.)
+Missing values: a NaN would poison the selection matmul (0 * NaN =
+NaN), so X is sanitized NaN -> 0.0 first, which is how a node whose
+column has no missing bin reads a NaN (models/tree.py
+numerical_left; a categorical node reads category 0, as the walk's
+int cast does).  Where the batch holds a NaN, a second one-hot matmul
+of the NaN mask (0/1, exact in one bf16 pass) says which selected
+values were NaN, and a node whose missing type is NaN sends those rows
+by its default direction.
 """
 
 from __future__ import annotations
@@ -46,6 +47,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..models.tree import numerical_left
 from ..obs.device_time import phase_scope
 
 _FLT_MAX = jnp.float32(3.4028235e38)
@@ -53,11 +55,13 @@ _FLT_MAX = jnp.float32(3.4028235e38)
 
 def _sanitize(X):
     """NaN AND +/-inf would poison the selection matmul (0 * inf = NaN
-    contaminates every non-selecting node).  Clamp to +/-FLT_MAX
-    sign-preserving: with finite thresholds, +/-FLT_MAX routes exactly
-    like +/-inf does in the walk path."""
-    return jnp.nan_to_num(X, nan=_FLT_MAX, posinf=_FLT_MAX,
-                          neginf=-_FLT_MAX)
+    contaminates every non-selecting node).  NaN reads 0.0 (the module
+    docstring) and +/-inf clamp to +/-FLT_MAX sign-preserving: with
+    finite thresholds, +/-FLT_MAX routes exactly like +/-inf does in the
+    walk path.  Returns ``(X, NaN mask as bf16, whether any is NaN)``."""
+    nan = jnp.isnan(X)
+    return (jnp.nan_to_num(X, nan=0.0, posinf=_FLT_MAX, neginf=-_FLT_MAX),
+            nan.astype(jnp.bfloat16), jnp.any(nan))
 
 
 @jax.jit
@@ -124,8 +128,10 @@ def build_path_tables(stacked):
     return tuple(o.reshape(lead + o.shape[1:]) for o in out)
 
 
-def _tree_hit(X, feat, thr, is_cat, M, base, depth, valid):
-    """[n, L] bool: which (valid) leaf each row lands in, for one tree."""
+def _tree_hit(Xs, feat, thr, dt, M, base, depth, valid):
+    """[n, L] bool: which (valid) leaf each row lands in, for one tree;
+    ``Xs`` is ``_sanitize``'s triple, ``dt`` the nodes' decision_type."""
+    X, nan, any_nan = Xs
     F = X.shape[1]
     sel = (
         (jnp.maximum(feat, 0)[None, :] == jnp.arange(F, dtype=jnp.int32)[:, None])
@@ -136,10 +142,19 @@ def _tree_hit(X, feat, thr, is_cat, M, base, depth, valid):
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )  # [n, L-1], exact copies of the selected feature values
+    # which selected values were NaN, where the batch holds one (the
+    # predicate is the batch's, unbatched under the per-class vmap)
+    nan_sel = jax.lax.cond(
+        any_nan,
+        lambda: jax.lax.dot_general(
+            nan, sel.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) > 0,
+        lambda: jnp.zeros(vals.shape, bool))
     go = jnp.where(
-        is_cat[None, :],
+        ((dt & 1) == 1)[None, :],
         vals.astype(jnp.int32) == thr.astype(jnp.int32),
-        vals <= thr[None, :],
+        numerical_left(jnp.where(nan_sel, jnp.nan, vals), thr[None, :],
+                       dt[None, :]),
     ).astype(jnp.bfloat16)
     match = jax.lax.dot_general(
         go, M, (((1,), (0,)), ((), ())),
@@ -157,12 +172,12 @@ def ensemble_sum_matmul(tables, stacked, X):
     bitwise identical (one-hot selection and 0/1-weighted leaf-value
     sums are exact)."""
     K, n = stacked.leaf_value.shape[1], X.shape[0]
-    X = _sanitize(X)
+    Xs = _sanitize(X)
 
     def step(acc, xs):
         t, (M, base, depth, valid) = xs
         def one(feat, thr, dt, lv, M, base, depth, valid):
-            hit = _tree_hit(X, feat, thr, dt == 1, M, base, depth, valid)
+            hit = _tree_hit(Xs, feat, thr, dt, M, base, depth, valid)
             return jnp.sum(hit.astype(jnp.float32) * lv[None, :], axis=1)
         out = jax.vmap(one)(
             t.split_feature_real, t.threshold_real, t.decision_type,
@@ -180,13 +195,13 @@ def ensemble_sum_matmul(tables, stacked, X):
 def ensemble_leaves_matmul(tables, stacked, X):
     """Per-tree leaf indices on raw features (flat leading axis [T]) ->
     [T, n] int32 — contract of models/tree.py ensemble_leaves_raw."""
-    X = _sanitize(X)
+    Xs = _sanitize(X)
 
     def step(_, xs):
         t, (M, base, depth, valid) = xs
         hit = _tree_hit(
-            X, t.split_feature_real, t.threshold_real,
-            t.decision_type == 1, M, base, depth, valid,
+            Xs, t.split_feature_real, t.threshold_real,
+            t.decision_type, M, base, depth, valid,
         )
         return None, jnp.argmax(hit, axis=1).astype(jnp.int32)
 

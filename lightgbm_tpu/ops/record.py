@@ -247,7 +247,7 @@ def unpack_window(win: jax.Array, F: int, k: int, bin_dtype):
     return bins, g, h, m
 
 
-def _tile_go(tile, scal_i_ref, col0, *, F, k):
+def _tile_go(tile, scal_i_ref, col0, *, F, k, missing=False):
     """Left-going flags of a [W, lanes] block of the window whose lane 0
     is window column ``col0``, recomputed IN-KERNEL from the split
     scalars — the [cap, 1] go-column operand this replaces cost a layout
@@ -257,7 +257,12 @@ def _tile_go(tile, scal_i_ref, col0, *, F, k):
 
     Returns one [1, lanes] f32 row: 1.0 = left AND valid (rows past pcnt
     are 0).
-    scal_i layout: (.., .., .., .., f, thr, is_cat, pcnt) — indices 4-7.
+    scal_i layout: (.., .., .., .., f, thr, is_cat, pcnt) — indices 4-7;
+    with ``missing`` (static: the table has missing values) a twelfth
+    scalar is the bin that goes left besides ``fv <= thr``: the split
+    column's missing bin where the node sends missing rows left, else -1
+    (a row of the missing bin, the column's last, is right of every
+    threshold).
     """
     f = scal_i_ref[4]
     thr = scal_i_ref[5]
@@ -279,8 +284,13 @@ def _tile_go(tile, scal_i_ref, col0, *, F, k):
     frow = jnp.sum(jnp.where(wrow == fw, tile, 0), axis=0, keepdims=True)
     fv = jax.lax.shift_right_logical(frow, fs) & mask_v
     # ARITHMETIC select: an i1-on-i1 arith.select fails legalization
-    go = is_cat * (fv == thr).astype(jnp.int32) + (1 - is_cat) * (
-        fv <= thr).astype(jnp.int32)
+    if missing:
+        num = jnp.maximum((fv <= thr).astype(jnp.int32),
+                          (fv == scal_i_ref[11]).astype(jnp.int32))
+        go = is_cat * (fv == thr).astype(jnp.int32) + (1 - is_cat) * num
+    else:
+        go = is_cat * (fv == thr).astype(jnp.int32) + (1 - is_cat) * (
+            fv <= thr).astype(jnp.int32)
     return (go * valid).astype(jnp.float32)
 
 
@@ -638,7 +648,7 @@ def _chunk_rows(c, Fc):
 
 
 def _split_tiles(tiles, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
-                 stage_ref, fill_ref, *, F, k):
+                 stage_ref, fill_ref, *, F, k, missing=False):
     """Per-step work of the split step on K parent tiles, the [W, K*T]
     block ``tiles`` (``j``: the step's ordinal, so its tiles are jK ..
     jK+K-1): ONE in-kernel go computation (no [cap, 1] column operand
@@ -649,7 +659,7 @@ def _split_tiles(tiles, scal_i_ref, small_left_b, j, comp_ref, cnt_ref,
     count are what a step of one tile writes for it."""
     T = TILE
     K = tiles.shape[-1] // T
-    go = _tile_go(tiles, scal_i_ref, j * (K * T), F=F, k=k)
+    go = _tile_go(tiles, scal_i_ref, j * (K * T), F=F, k=k, missing=missing)
     gos = [go[:, t * T: (t + 1) * T] for t in range(K)]
     group = jax.lax.broadcasted_iota(jnp.int32, (1, K * 128), 1) // 128
     counts = jnp.zeros((1, K * 128), jnp.int32)
@@ -698,7 +708,7 @@ def _stage(half, run, stage_ref, fill_ref):
 
 def _subtract_and_search(c, rows, parent, h_small, small_left_b, do_split,
                          hists_out_ref, stash_ref, scal_f_ref, meta_ref,
-                         res_ref, best_ref, *, Fc, Bp):
+                         res_ref, best_ref, *, Fc, Bp, missing=False):
     """Search step ``c`` of a split (``rows``: its chunk's accumulator
     rows, _chunk_rows): feature chunk ``c`` of the larger child by
     subtraction from the parent's block, the left child's block written,
@@ -725,12 +735,14 @@ def _subtract_and_search(c, rows, parent, h_small, small_left_b, do_split,
             _tail_of(hg, tri), _tail_of(hh, tri) + K_EPSILON,
             _tail_of(hc, tri), _head_of(hg, tri), _head_of(hh, tri),
             scal_f_ref, meta_ref, res_ref, Fc, B, c * Fc, best_ref,
+            missing,
         )
 
 
 def _split_step_kernel(
     scal_i_ref, scal_f_ref, *refs,
     W, F, k, Bp, Fc, NC, fgroup=8, direct_read=False, exchange=False,
+    missing=False,
 ):
     """The WHOLE split step in one launch: per-tile compaction and
     staging of the smaller child's rows, K parent tiles a tile step
@@ -763,7 +775,10 @@ def _split_step_kernel(
     whatever the buffer held, and split_step_window masks them.
 
     scal_i [11]: (parent_slot, left_slot, new_slot, do_split, f, thr,
-                 is_cat, pcnt, begin//KT, begin%KT, tile steps)
+                 is_cat, pcnt, begin//KT, begin%KT, tile steps), and with
+                 ``missing`` a twelfth: the missing bin that goes left,
+                 or -1 (_tile_go); the search then scores both sides for
+                 the missing rows (pallas_search._child_search)
     scal_f [16]: pallas_search._pack_scal layout
     win_ref    : the [W, K*T] window block of K tiles (non-direct mode).
                  With ``direct_read`` the RECORD itself is the (single,
@@ -892,7 +907,8 @@ def _split_step_kernel(
                 lane = jax.lax.broadcasted_iota(jnp.int32, (W, KT), 1)
                 m = (lane < (KT - r)).astype(jnp.int32)
                 tiles = ra * m + rb * (1 - m)
-                _split_tiles(tiles, *tile_args, i - 1, *tile_refs, F=F, k=k)
+                _split_tiles(tiles, *tile_args, i - 1, *tile_refs, F=F, k=k,
+                             missing=missing)
 
             prev_ref[...] = cur
     else:
@@ -905,7 +921,8 @@ def _split_step_kernel(
             # search still needs
             if not exchange:
                 hists_out_ref[0] = hrow_ref[0]
-            _split_tiles(win_ref[...], *tile_args, i, *tile_refs, F=F, k=k)
+            _split_tiles(win_ref[...], *tile_args, i, *tile_refs, F=F, k=k,
+                         missing=missing)
 
     # The histogram body's ONE call, in a loop of K turns: on a full
     # tile of staged rows for each one a tile step has filled (at most
@@ -964,7 +981,7 @@ def _split_step_kernel(
         _subtract_and_search(
             c, rows, parent, h_small, small_left_b, do_split,
             hists_out_ref, hacc_ref, scal_f_ref, meta_ref, res_ref,
-            best_ref, Fc=Fc, Bp=Bp)
+            best_ref, Fc=Fc, Bp=Bp, missing=missing)
 
     @pl.when(i >= write_step)
     def _():
@@ -1325,7 +1342,7 @@ def split_step_vmem_bytes(Fp: int, Bp: int, W: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("F", "cap", "k", "fgroup", "interpret",
-                     "tiles_per_step"),
+                     "tiles_per_step", "missing"),
     donate_argnums=(0,),
 )
 @phase_scope("split_step")
@@ -1342,6 +1359,8 @@ def split_step_counted(
     interpret: bool = False,
     live_tiles=None,  # run-time tile count <= cap // TILE (None = all)
     tiles_per_step=None,  # K where a test or an audit names it
+    missing: bool = False,  # the table has missing values (_tile_go)
+    miss_left=None,  # with ``missing``: the bin that goes left, or -1
 ):
     """One-launch split step over window [begin, begin+cap): compaction
     + smaller-child histogram + subtract + two-child search + in-place
@@ -1385,13 +1404,14 @@ def split_step_counted(
         hists, rec, begin, pcnt, do_split, f, thr, is_cat, parent_slot,
         new_slot, scal_f, meta, F=F, cap=cap, k=k, fgroup=fgroup,
         interpret=interpret, live_tiles=live_tiles,
-        tiles_per_step=tiles_per_step)
+        tiles_per_step=tiles_per_step, missing=missing, miss_left=miss_left)
 
 
 def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
                      parent_slot, new_slot, scal_f, meta, *, F, cap, k,
                      fgroup, interpret, live_tiles, exchange=False,
-                     Fp=None, Bp=None, tiles_per_step=None):
+                     Fp=None, Bp=None, tiles_per_step=None, missing=False,
+                     miss_left=None):
     """The split step's launch (``split_step_counted``); with
     ``exchange`` the launch that stops at the smaller child's histogram
     (``split_hist_counted``: ``hists`` and ``meta`` are None, ``Fp`` and
@@ -1428,7 +1448,7 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
     scal_i = jnp.stack([
         i32(parent_slot), i32(parent_slot), i32(new_slot), i32(do_split),
         jnp.maximum(i32(f), 0), i32(thr), i32(is_cat), i32(pcnt),
-        b0, roff_in, steps])
+        b0, roff_in, steps] + ([i32(miss_left)] if missing else []))
 
     direct_read = not interpret
     off = 1 if direct_read else 0  # pipeline offset (see the kernel)
@@ -1490,7 +1510,8 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
         VMEM_DEFAULT_BYTES, split_step_vmem_bytes(Fp, Bp, W)))
     kernel = functools.partial(
         _split_step_kernel, W=W, F=F, k=k, Bp=Bp, Fc=Fc, NC=NC,
-        fgroup=fgroup, direct_read=direct_read, exchange=exchange)
+        fgroup=fgroup, direct_read=direct_read, exchange=exchange,
+        missing=missing)
     if exchange:
         # the tile steps, then one step a chunk that writes the child's
         # histogram: no hists row, no search
@@ -1569,7 +1590,7 @@ def _split_step_call(hists, rec, begin, pcnt, do_split, f, thr, is_cat,
 
 @functools.partial(
     jax.jit, static_argnames=("F", "cap", "k", "Fp", "Bp", "fgroup",
-                              "interpret", "tiles_per_step"))
+                              "interpret", "tiles_per_step", "missing"))
 @phase_scope("split_step")
 def split_hist_counted(
     rec, begin, pcnt, do_split, f, thr, is_cat, scal_f,
@@ -1578,6 +1599,8 @@ def split_hist_counted(
     interpret: bool = False,
     live_tiles=None,
     tiles_per_step=None,
+    missing: bool = False,
+    miss_left=None,
 ):
     """The first half of ``split_step_counted`` for the data-parallel
     grower: the compaction of this chip's window of the parent and this
@@ -1591,12 +1614,12 @@ def split_hist_counted(
         None, rec, begin, pcnt, do_split, f, thr, is_cat, 0, 0, scal_f,
         None, F=F, cap=cap, k=k, fgroup=fgroup, interpret=interpret,
         live_tiles=live_tiles, exchange=True, Fp=Fp, Bp=Bp,
-        tiles_per_step=tiles_per_step)
+        tiles_per_step=tiles_per_step, missing=missing, miss_left=miss_left)
 
 
 def _split_search_kernel(scal_i_ref, scal_f_ref, hrow_ref, hsmall_ref,
                          meta_ref, hists_out_ref, res_ref, stash_ref,
-                         best_ref, *, Fc, NC, Bp):
+                         best_ref, *, Fc, NC, Bp, missing=False):
     """The second half of the split step (_split_step_kernel's tail) on a
     child's histogram summed over the chips: ``NC`` search steps
     (_subtract_and_search), then ``NC`` steps that write the right
@@ -1611,7 +1634,7 @@ def _split_search_kernel(scal_i_ref, scal_f_ref, hrow_ref, hsmall_ref,
         _subtract_and_search(
             i, rows, hrow_ref[0], hsmall_ref[...], small_left_b, do_split,
             hists_out_ref, stash_ref, scal_f_ref, meta_ref, res_ref,
-            best_ref, Fc=Fc, Bp=Bp)
+            best_ref, Fc=Fc, Bp=Bp, missing=missing)
 
     @pl.when(i >= NC)
     def _():
@@ -1619,11 +1642,11 @@ def _split_search_kernel(scal_i_ref, scal_f_ref, hrow_ref, hsmall_ref,
         hists_out_ref[0] = jnp.where(do_split, stash_ref[rows], hrow_ref[0])
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",),
+@functools.partial(jax.jit, static_argnames=("interpret", "missing"),
                    donate_argnums=(0,))
 @phase_scope("split_step")
 def split_search(hists, h_small, parent_slot, new_slot, do_split, scal_f,
-                 meta, interpret: bool = False):
+                 meta, interpret: bool = False, missing: bool = False):
     """The second half of ``split_step_counted`` for the data-parallel
     grower: the larger child by subtraction from the parent's ``hists``
     row, both children searched, the two rows written in place, from
@@ -1666,7 +1689,8 @@ def split_search(hists, h_small, parent_slot, new_slot, do_split, scal_f,
     )
     with phase_scope("split_step.search"):
         hists_new, res = pl.pallas_call(
-            functools.partial(_split_search_kernel, Fc=Fc, NC=NC, Bp=Bp),
+            functools.partial(_split_search_kernel, Fc=Fc, NC=NC, Bp=Bp,
+                              missing=missing),
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((P, Fp, 4, Bp), jnp.float32),
                        jax.ShapeDtypeStruct((2, 16), jnp.float32)],

@@ -26,6 +26,17 @@ masked reduction over the whole [F, B] candidate grid:
   reproduce this by argmax-ing over (feature asc, bin desc) order.
   Matters for raw-space routing when bins between tied thresholds are
   empty — verified against the reference binary on binary.train.
+* missing values (``missing_bin``, io/binner.py): a numerical column
+  with a missing bin (its last) offers every value threshold twice,
+  its missing rows RIGHT (today's arithmetic: the missing bin is one of
+  the bins above any value threshold) and LEFT (the missing bin's sums
+  moved from the right side to the left).  Ties break as LightGBM 2.x's
+  two scans do, each taking a later candidate only on a strictly
+  greater gain: feature asc; then missing-left candidates, threshold
+  desc (its reverse scan, run first); then missing-right ones, threshold
+  ASC (its forward scan).  A column without a missing bin keeps the one
+  reverse scan, threshold desc.  Without ``missing_bin`` none of it is
+  traced.
 """
 
 from __future__ import annotations
@@ -86,7 +97,11 @@ def find_best_split(
     lambda_l2: jax.Array,
     min_gain_to_split: jax.Array,
     can_split: jax.Array,  # scalar bool (depth / leaf-size gating)
-) -> SplitResult:
+    missing_bin: jax.Array | None = None,  # [F] int32, -1: none
+):
+    """The leaf's best split: a SplitResult, or with ``missing_bin`` the
+    pair ``(SplitResult, default_left)`` (module docstring)."""
+    missing = missing_bin is not None
     F, B, _ = hist.shape
     dt = hist.dtype
     bins = jnp.arange(B, dtype=jnp.int32)
@@ -126,48 +141,81 @@ def find_best_split(
     left = jnp.where(is_cat3, hist, head)  # [F, B, 3]
     right = jnp.where(is_cat3, tot - hist, tail)
 
-    left_h, left_c = left[..., 1], left[..., 2]
-    right_h, right_c = right[..., 1], right[..., 2]
+    def scored(left, right, in_range_of, gain_shift=None):
+        """Gains of every (feature, bin) candidate with ``left`` and
+        ``right`` its sides, K_MIN_SCORE where it is not allowed."""
+        left_h, left_c = left[..., 1], left[..., 2]
+        right_h, right_c = right[..., 1], right[..., 2]
+        # ---- validity (feature_histogram.hpp:133-142, 199-208)
+        valid = (
+            in_range_of()
+            & feature_mask[:, None]
+            & (left_c >= min_data_in_leaf)
+            & (right_c >= min_data_in_leaf)
+            & (left_h >= min_sum_hessian_in_leaf)
+            & (right_h >= min_sum_hessian_in_leaf)
+        )
+        if gain_shift is None:
+            gain_shift = _leaf_split_gain(
+                sum_grad, sum_hess, lambda_l1, lambda_l2)
+        min_gain_shift = gain_shift + min_gain_to_split
+        gains = _leaf_split_gain(
+            left[..., 0], left_h, lambda_l1, lambda_l2
+        ) + _leaf_split_gain(right[..., 0], right_h, lambda_l1, lambda_l2)
+        valid = valid & (gains >= min_gain_shift) & can_split
+        return jnp.where(valid, gains, K_MIN_SCORE), gain_shift
 
-    # ---- validity (feature_histogram.hpp:133-142, 199-208)
     # a column's last bin is never a candidate: no rows lie right of a
     # numerical column's, and a categorical column's is the others' bin
-    nb = num_bins_per_feature[:, None]
-    in_range = bins[None, :] < nb - 1
-    valid = (
-        in_range
-        & feature_mask[:, None]
-        & (left_c >= min_data_in_leaf)
-        & (right_c >= min_data_in_leaf)
-        & (left_h >= min_sum_hessian_in_leaf)
-        & (right_h >= min_sum_hessian_in_leaf)
-    )
-
-    gain_shift = _leaf_split_gain(sum_grad, sum_hess, lambda_l1, lambda_l2)
-    min_gain_shift = gain_shift + min_gain_to_split
-    gains = _leaf_split_gain(
-        left[..., 0], left_h, lambda_l1, lambda_l2
-    ) + _leaf_split_gain(right[..., 0], right_h, lambda_l1, lambda_l2)
-    valid = valid & (gains >= min_gain_shift) & can_split
-    gains = jnp.where(valid, gains, K_MIN_SCORE)
-
-    # argmax over (feature asc, bin desc): reverse the bin axis so the
-    # first maximum is the smallest feature with the LARGEST threshold
-    flat = gains[:, ::-1].reshape(-1)
+    gains, gain_shift = scored(
+        left, right,
+        lambda: bins[None, :] < num_bins_per_feature[:, None] - 1)
+    sides = [left, right]
+    if not missing:
+        # argmax over (feature asc, bin desc): reverse the bin axis so the
+        # first maximum is the smallest feature with the LARGEST threshold
+        flat = gains[:, ::-1].reshape(-1)
+    else:
+        # the missing bin's sums moved to the left side; thresholds up
+        # to the last value bin but one (the right keeps a value bin)
+        nb, mb = num_bins_per_feature[:, None], missing_bin[:, None]
+        hm = jnp.sum(jnp.where((bins[None, :] == mb)[..., None], hist, 0.0),
+                     axis=1, keepdims=True)  # [F, 1, 3], 0 without one
+        tail_m = tail - hm
+        left_m = jnp.concatenate(
+            [head[..., :2] + hm[..., :2], (tot - tail_m)[..., 2:]], axis=-1)
+        gains_m, _ = scored(
+            left_m, tail_m, lambda: (bins[None, :] < nb - 2) & (mb >= 0),
+            gain_shift)
+        sides += [left_m, tail_m]
+        # (feature asc; missing-left, bin desc; missing-right, bin asc
+        # where the column has a missing bin, else desc)
+        flat = jnp.stack([gains_m[:, ::-1],
+                          jnp.where(mb >= 0, gains, gains[:, ::-1])],
+                         axis=1).reshape(-1)
     best = jnp.argmax(flat)
     best_gain_raw = flat[best]
-    feat = (best // B).astype(jnp.int32)
+    per_feature = 2 * B if missing else B
+    feat = (best // per_feature).astype(jnp.int32)
     thr = (B - 1 - best % B).astype(jnp.int32)
     splittable = best_gain_raw > K_MIN_SCORE
 
     # all six winner stats in one dynamic-slice of the stacked tensor
-    lr = jnp.stack([left, right])  # [2, F, B, 3]
+    lr = jnp.stack(sides)  # [2 or 4, F, B, 3]
+    if not missing:
+        first = jnp.int32(0)
+    else:
+        on_left = best % per_feature < B
+        thr = jnp.where(~on_left & (missing_bin[feat] >= 0),
+                        (best % B).astype(jnp.int32), thr)
+        default_left = splittable & on_left
+        first = jnp.where(default_left, 2, 0).astype(jnp.int32)
     pick = jax.lax.dynamic_slice(
-        lr, (jnp.int32(0), feat, thr, jnp.int32(0)), (2, 1, 1, 3)
+        lr, (first, feat, thr, jnp.int32(0)), (2, 1, 1, 3)
     ).reshape(2, 3)
     lg, lh, lc = pick[0, 0], pick[0, 1], pick[0, 2]
     rg, rh, rc = pick[1, 0], pick[1, 1], pick[1, 2]
-    return SplitResult(
+    result = SplitResult(
         gain=jnp.where(splittable, best_gain_raw - gain_shift, K_MIN_SCORE),
         feature=jnp.where(splittable, feat, -1),
         threshold=jnp.where(splittable, thr, 0),
@@ -180,11 +228,16 @@ def find_best_split(
         left_output=_leaf_output(lg, lh, lambda_l1, lambda_l2),
         right_output=_leaf_output(rg, rh, lambda_l1, lambda_l2),
     )
+    return (result, default_left) if missing else result
 
 
 # vectorized over leaves (the canonical grower's two-child search, the
 # batched forest, analysis/kernel_parity.py)
-find_best_split_leaves = jax.vmap(
-    find_best_split,
-    in_axes=(0, 0, 0, 0, None, None, None, None, None, None, None, None, 0),
-)
+_LEAF_AXES = (0, 0, 0, 0, None, None, None, None, None, None, None, None, 0)
+
+
+def find_best_split_leaves(*args):
+    """``find_best_split`` over a leading axis of leaves; a ``missing_bin``
+    after ``can_split`` is shared by them."""
+    return jax.vmap(find_best_split, in_axes=_LEAF_AXES + (None,) * (
+        len(args) - len(_LEAF_AXES)))(*args)

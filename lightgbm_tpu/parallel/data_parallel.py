@@ -221,7 +221,7 @@ def make_data_parallel_grower(
 
 
 def make_fused_data_parallel_grower(mesh, num_bins: int, max_leaves: int,
-                                    axis: str = ROW_AXIS):
+                                    axis: str = ROW_AXIS, missing_bin=None):
     """The fused grower (learners/fused.py) over the chips of ``mesh``,
     rows sharded on ``axis``: what ``tree_learner=data`` runs on the chips
     of one host (``models/gbdt.py select_grower``).  Each chip grows the
@@ -230,13 +230,15 @@ def make_fused_data_parallel_grower(mesh, num_bins: int, max_leaves: int,
     tree) is all that crosses the chips (the module docstring of
     learners/fused.py).  Rows that do not divide the chips are padded
     with bag mask 0 (``row_padded_grower``), and the program keeps the
-    one-chip grower's name, ``jit_grow_tree``."""
+    one-chip grower's name, ``jit_grow_tree``.  ``missing_bin``: as the
+    one-chip grower takes it, the same on every chip."""
     from ..learners import fused
 
     def body(bins_T, grad, hess, bag_mask, fmask, nbpf, is_cat, params):
         return fused.grow_tree(
             bins_T, grad, hess, bag_mask, fmask, nbpf, is_cat, params,
-            num_bins=num_bins, max_leaves=max_leaves, axis=axis)
+            num_bins=num_bins, max_leaves=max_leaves, axis=axis,
+            **({} if missing_bin is None else {"missing_bin": missing_bin}))
 
     sharded = jax.shard_map(
         body, mesh=mesh,

@@ -338,11 +338,17 @@ void lgbm_value_to_bin(const double* X, long n, long f_total,
     const double* row = X + i * f_total;
     for (long j = 0; j < n_used; ++j) {
       double v = row[col_idx[j]];
-      if (std::isnan(v)) v = 0.0;  // reference maps NA to 0 before binning
       const double* b = bounds + bound_offsets[j];
       long nb = bound_offsets[j + 1] - bound_offsets[j];
+      // a NaN last bound marks the column's missing bin (io/binner.py):
+      // a NaN value goes there; elsewhere NA reads as 0 before binning
+      const bool has_missing = nb > 1 && std::isnan(b[nb - 1]);
+      long lo = 0, hi = nb - 1 - (has_missing ? 1 : 0);
+      if (std::isnan(v)) {
+        if (has_missing) lo = hi = nb - 1;
+        v = 0.0;
+      }
       // first bound >= v (upper_bound[k-1] < v <= upper_bound[k])
-      long lo = 0, hi = nb - 1;
       while (lo < hi) {
         long mid = (lo + hi) >> 1;
         if (b[mid] < v)

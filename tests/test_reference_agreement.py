@@ -189,6 +189,75 @@ def test_a_kept_list_one_category_short_is_not_correct(harness):
             if not row["value"] <= row["limit"]] == ["kept_categories_off"]
 
 
+def _bosch(harness, params=None, seed=SEED):
+    """``bosch-968.train``'s driver and a rehearsal run of it, with the
+    configuration's ``params`` replaced by ``params`` where given."""
+    cells, _, entry = harness
+    bench = cells.benchmark()
+    work = next(w for w in bench["workloads"] if w["config"] == "bosch-968")
+    cell = cells.assemble(work, bench)
+    if params is not None:
+        cell["config"] = {**cell["config"], "params": params}
+    run = entry.Run(entry.parse(["--workload", work["name"], "--seed",
+                                 str(seed), "--seconds", "0",
+                                 "--rehearsal"]), cell)
+    run.traffic = {**run.traffic, "quiet_trees": 0,
+                   "min_warmup_trees": run.traffic["checked_trees"]}
+    return cells.plugin("drivers", cell["traffic"]["driver"]), run
+
+
+def test_a_program_that_bins_nan_as_zero_is_not_timed(harness):
+    """``bosch-968.train``'s driver module looks at what the program
+    binned before any booster: where a column with NaN cells has no
+    missing bin (a program without missing bins reads NaN as 0.0; here
+    ``use_missing=false`` stands in for it), the run ends at once with
+    no result."""
+    params = {**harness[0].read_json(BENCH, "configs", "bosch-968.json")[
+        "params"], "use_missing": False}
+    driver, run = _bosch(harness, params)
+    with driver.bound(), pytest.raises(SystemExit, match="nothing was run"):
+        driver.setup(run)
+
+
+def test_a_missing_row_sent_the_other_way_is_not_correct(harness):
+    """The planted fault of the mechanism: one split of each tree sends
+    its missing rows to the side the program did not choose, in the
+    replay that stands in the program's place.  The comparison must fail
+    on it, on the counts of the rows the split moved."""
+    _, check, _ = harness
+    driver, run = _bosch(harness)
+    with driver.bound():
+        state = driver.first_trees(run, driver.setup(run))
+    ref = driver.reference(run, state)
+    limits = check.limits_of("bosch-968")
+    assert check.verdict(driver.compared(run, state, ref), limits)[0]
+    planted = driver.compared(run, state, ref, fault="altered_default")
+    correct, table = check.verdict(planted, limits)
+    assert not correct and planted["count_mismatch"] > 0, table
+
+
+def test_a_search_without_missing_left_splits_is_not_correct(harness):
+    """The planted fault of the search: no split that sends its missing
+    rows left is offered, in the replay that stands in the program's
+    place.  It changes an answer only where such a split is the best:
+    at this seed's first root (at ``SEED`` no sampled node's best is one,
+    and the fault reads 0 there), whose search ``root_argmax_gap`` holds,
+    and at a node below it, which ``resolved_node_argmax_gap`` holds."""
+    _, check, _ = harness
+    driver, run = _bosch(harness, seed=2_200_015_838)
+    with driver.bound():
+        state = driver.first_trees(run, driver.setup(run))
+    ref = driver.reference(run, state)
+    limits = check.limits_of("bosch-968")
+    assert check.verdict(driver.compared(run, state, ref), limits)[0]
+    planted = driver.compared(run, state, ref, fault="withheld_left")
+    correct, table = check.verdict(planted, limits)
+    assert not correct, table
+    assert planted["root_argmax_gap"] > limits["root_argmax_gap"]["limit"]
+    assert planted["resolved_node_argmax_gap"] > limits[
+        "resolved_node_argmax_gap"]["limit"], table
+
+
 def test_the_new_cell_rehearses_correct_through_run_py():
     """``benchmarks/run.py`` itself, as the driver starts it, finds the
     cell's files by the names in BENCHMARK.json and reads ``correct``."""
